@@ -9,7 +9,7 @@ makes resize a *live reconfiguration*:
 - ``CompileCache``: an in-process LRU of AOT-compiled executables keyed
   by ``fingerprint(mesh shape, abstract state/batch shapes, donation
   signature, strategy fingerprint)``, with an optional on-disk layer
-  (``jax.experimental.serialize_executable`` behind version guards —
+  (``jax.experimental.serialize_executable`` through
   ``common.jax_compat``) so a replacement worker warm-starts from a
   peer's serialized executable.  A generic ``get_or_build`` memo rides
   along for callables that cannot be serialized (lazily-jitted eval
@@ -101,9 +101,9 @@ class CompileCache:
       (jit wrappers, eval steps) — never touches disk;
     - ``get_or_compile``: for AOT ``Compiled`` executables; misses
       consult the on-disk layer before building, and fresh builds are
-      serialized back (both legs best-effort behind the version guards
-      in ``common.jax_compat`` — a jaxlib without executable
-      serialization silently degrades to memory-only).
+      serialized back (both legs best-effort through
+      ``common.jax_compat`` — a program that cannot be pickled
+      degrades to memory-only).
 
     Hit/miss counters land in an ``accel.profiler.PipelineStats`` when
     one is attached, so ``compile_cache_hit_pct`` rides the same record

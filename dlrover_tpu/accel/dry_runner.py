@@ -578,9 +578,7 @@ def compiled_cost(
         )
         x, y = make_batch(batch, seq)
         compiled = step_fn.lower(abstract_state(), x, y).compile()
-        from dlrover_tpu.common.jax_compat import cost_analysis_dict
-
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis() or {}
         ma = compiled.memory_analysis()
         report.flops_per_device = float(ca.get("flops", 0.0))
         report.bytes_per_device = float(ca.get("bytes accessed", 0.0))

@@ -37,11 +37,19 @@ PEAK_TFLOPS = {
 
 
 def chip_peak_tflops(device) -> Optional[float]:
-    kind = getattr(device, "device_kind", "").lower()
+    """bf16 peak of ``device``. None off the TPU: a CPU run has no MFU.
+    A TPU whose kind is not in the table is an error, not a silent
+    ``mfu_pct=None``."""
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind.lower()
     for key in sorted(PEAK_TFLOPS, key=len, reverse=True):
         if key in kind:
             return PEAK_TFLOPS[key]
-    return None
+    raise KeyError(
+        f"no bf16 peak known for TPU device_kind {device.device_kind!r}; "
+        f"add it to PEAK_TFLOPS"
+    )
 
 
 @dataclass
@@ -371,9 +379,8 @@ def measure_step(
     """Time a compiled train step and report achieved TFLOP/s + MFU.
 
     The (state, metrics) chain is forced by materializing the LAST
-    iteration's metrics on the host — ``block_until_ready`` alone has
-    been observed returning before execution finished on tunneled
-    runtimes, inflating MFU past 100%.
+    iteration's metrics on the host, so the timing covers execution and
+    not only dispatch.
     """
     import jax
 
@@ -498,10 +505,10 @@ def module_breakdown(
         t0 = time.perf_counter()
         for _ in range(iters):
             r = fn(*args)
-        # force through a scalar readback (tunneled runtimes return from
-        # block_until_ready early). The slice happens DEVICE-side: a
-        # np.asarray(leaf) here would drag the whole leaf over the
-        # (slow) d2h link and bill it to the module being timed
+        # close the timing with a scalar readback that depends on the
+        # result. The slice happens DEVICE-side: a np.asarray(leaf)
+        # here would drag the whole leaf over the d2h link and bill it
+        # to the module being timed
         leaf = jax.tree_util.tree_leaves(r)[0]
         float(jnp.ravel(leaf)[0].astype(jnp.float32))
         dt = (time.perf_counter() - t0) / iters

@@ -629,7 +629,7 @@ class CheckpointEngine:
     def wait_staging(self, timeout: float = 60.0):
         """Join in-flight ``block=False`` staging threads. Call before
         process exit: a daemon thread doing D2H against a runtime that is
-        tearing down aborts the process (observed as rc=134)."""
+        tearing down can abort the process."""
         deadline = time.time() + timeout
         for t in self._staging_threads:
             t.join(timeout=max(0.0, deadline - time.time()))

@@ -318,19 +318,26 @@ def build_train_step(
     once-per-mesh log naming the axes. ``batch_pad`` is the
     micro-batch rebalance (zero-weight pad rows; see
     ``pad_row_weights``)."""
+    # the state leaves the step in the layout it is initialized and
+    # restored in. Left to GSPMD, the outputs of a sharded mesh drift
+    # (replicated 1-D params and their moments came back sharded over
+    # fsdp): the second step then recompiles for the new argument
+    # layout, and a restarted worker's fresh target no longer matches
+    # what was staged to shm, so the restore falls through to storage.
+    # With ``offload_opt_state`` the opt tree is the MIXED one from
+    # offload_shardings: host-kind tensors, device-kind scalars
+    # (identical to the device tree off TPU, where placement is a
+    # numeric no-op — host_offload.py). Callers that already computed
+    # state_shardings pass its opt_state through ``opt_shardings``.
+    st_sh = state_shardings(
+        cfg, mesh, tx, rules, offload_opt_state=offload_opt_state
+    )
     opt_sh = None
     if offload_opt_state:
-        # the MIXED tree from offload_shardings: host-kind tensors,
-        # device-kind scalars (identical to the device tree off TPU,
-        # where placement is a numeric no-op — host_offload.py).
-        # Callers that already computed state_shardings pass its
-        # opt_state through ``opt_shardings`` to skip the re-trace.
         opt_sh = (
             opt_shardings
             if opt_shardings is not None
-            else state_shardings(
-                cfg, mesh, tx, rules, offload_opt_state=True
-            ).opt_state
+            else st_sh.opt_state
         )
 
     # grad_slices: DCN slice count of a hybrid dp axis
@@ -413,9 +420,8 @@ def build_train_step(
         the model-sharded matmuls keep their native partitioned
         schedule instead of being computed replicated per device —
         each dp rank here is the whole tp submesh."""
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from dlrover_tpu.common.jax_compat import shard_map
 
         kw = {}
         if plan.three_d:
@@ -423,10 +429,10 @@ def build_train_step(
             # for the matmuls (the sync itself later goes FULLY
             # manual in _sync_grads_3d — psum_scatter cannot run in
             # a partial-manual region)
-            kw["axis_names"] = ("dp", "fsdp")
+            kw["axis_names"] = frozenset({"dp", "fsdp"})
             batch_spec = P(("dp", "fsdp"))
         elif plan.auto_axes:
-            kw["axis_names"] = ("dp",)
+            kw["axis_names"] = frozenset({"dp"})
             batch_spec = P(("dp",))  # tp/sp/ep sharding rides as auto
         else:
             batch_spec = P(("dp", "fsdp"), "sp")
@@ -493,9 +499,9 @@ def build_train_step(
         rank 0's backward still reaches every rank's experts through
         the all-to-all transpose, and the ep-replicated dense grads
         are shared back with one selection psum."""
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
-        from dlrover_tpu.common.jax_compat import shard_map
         from dlrover_tpu.parallel.grad_sync import sync_local_tree
 
         p_leaves, p_def = jax.tree_util.tree_flatten(state.params)
@@ -815,7 +821,13 @@ def build_train_step(
     donate_argnums = ((0,) if donate else ()) + (
         (1, 2) if donate_inputs else ()
     )
-    return jax.jit(train_step, donate_argnums=donate_argnums)
+    # params and optimizer state are pinned; the residual and the
+    # metrics are left to the compiler (None = unspecified)
+    return jax.jit(
+        train_step,
+        donate_argnums=donate_argnums,
+        out_shardings=(st_sh, None),
+    )
 
 
 def shard_batch(batch, mesh):

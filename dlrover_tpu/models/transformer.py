@@ -217,17 +217,77 @@ def _rope(x, positions, theta: float, layout: str = "bthd"):
     ).astype(x.dtype)
 
 
-def _causal_attention(q, k, v, layout: str = "bthd"):
+def _causal_attention(q, k, v, mesh=None, layout: str = "bthd"):
     """Single-shard causal attention, [B,T,H,D] or [B,H,T,D].
 
     Dispatches to the Pallas flash-attention kernel on TPU (fused
     single-program kernels at short seq, block-tiled streaming beyond)
     and the materialized-score jnp path elsewhere —
     ops/flash_attention.py owns both and their shared numerics.
+
+    Wherever GSPMD still owns a mesh axis the call runs under
+    ``shard_map``, batch over the data axes and heads over tp:
+    attention is independent per example and head, and GSPMD cannot
+    partition a Mosaic kernel on its own (the TPU lowering refuses:
+    "Mosaic kernels cannot be automatically partitioned"). With a
+    ``mesh`` that is all of its axes; with ``mesh=None`` inside the
+    explicit gradient sync's partial-manual region (dp manual, tp left
+    to GSPMD) it is the axes that region left auto. The pipeline's
+    regions, which track varying axes in their types, keep the plain
+    call. ``shard_map`` needs the batch and the heads to divide evenly;
+    a batch that does not (a small accumulation microbatch) stays with
+    GSPMD, which pads — fine for the jnp path, an error on the TPU
+    where the kernel would be refused.
     """
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
     from dlrover_tpu.ops.flash_attention import flash_attention
 
-    return flash_attention(q, k, v, causal=True, layout=layout)
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, layout=layout)
+
+    def spec(batch, heads):
+        if layout == "bhtd":
+            return P(batch, heads, None, None)
+        return P(batch, None, heads, None)
+
+    if mesh is None:
+        auto = jax.sharding.get_abstract_mesh().auto_axes
+        # no region around us, a fully manual one, or one whose types
+        # track varying axes (an untracked map inside would break them)
+        if not auto or jax.typeof(q).vma:
+            return attend(q, k, v)
+        # every axis mentioned: one left out would read as replicated
+        # and cost a psum in the backward pass
+        rest = tuple(a for a in auto if a != "tp") or None
+        inner = spec(rest, "tp" if "tp" in auto else None)
+        return shard_map(
+            attend, in_specs=(inner,) * 3, out_specs=inner,
+            axis_names=frozenset(auto), check_vma=False,
+        )(q, k, v)
+    if mesh.size == 1:
+        return attend(q, k, v)
+    data = mesh.shape["dp"] * mesh.shape["fsdp"]
+    heads_axis = 1 if layout == "bhtd" else 2
+    if q.shape[0] % data or any(
+        x.shape[heads_axis] % mesh.shape["tp"] for x in (q, k)
+    ):
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                f"attention batch {q.shape[0]} and heads "
+                f"{q.shape[heads_axis]}/{k.shape[heads_axis]} do not "
+                f"divide dp*fsdp={data} and tp={mesh.shape['tp']} of "
+                f"mesh {dict(mesh.shape)}: the Pallas kernel cannot be "
+                "partitioned unevenly; pick a (micro)batch and a tp "
+                "that divide"
+            )
+        return attend(q, k, v)
+    both = spec(("dp", "fsdp"), "tp")
+    return shard_map(
+        attend, mesh=mesh, in_specs=(both,) * 3, out_specs=both,
+        check_vma=False,
+    )(q, k, v)
 
 
 def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions):
@@ -249,7 +309,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions):
         # 1/sqrt(d) into q, so flash and ring paths need no new plumbing
         q = q * (cfg.mup_attn_scale * cfg.head_dim**0.5)
     if not sp:
-        o = _causal_attention(q, k, v, layout="bhtd")
+        o = _causal_attention(q, k, v, mesh, layout="bhtd")
     elif cfg.sp_scheme == "ulysses":
         from dlrover_tpu.parallel.ulysses import ulysses_self_attention
 

@@ -26,7 +26,7 @@ Design:
   under the Pallas interpreter on CPU via
   ``jax_compat.pallas_interpret_mode`` so tier-1 runs everywhere.
   ``DLROVER_TPU_EMB_KERNEL=jnp`` selects a pure ``jnp.take``/``.at[]``
-  fallback (also the automatic fallback if a kernel fails to trace).
+  path (the default off the TPU; a kernel that fails raises).
 - Missing rows FAULT IN from the host store (full rows incl. slots via
   ``export_rows`` — a state read, no freq/ts bump); LRU victims spill
   back with an **async D2H**: the evicted rows are handed to a drain
@@ -76,8 +76,8 @@ def _bucket(n: int, floor: int = 64) -> int:
 class _Kernels:
     """Pallas gather/scatter over a ``[capacity, row_floats]`` table,
     one row per grid step, slots scalar-prefetched so the index map can
-    address HBM before the body runs. Falls back to jnp take/at ops on
-    any trace failure (logged once) — same numerics, no kernel.
+    address HBM before the body runs. A kernel that fails to build or
+    run raises: the jnp take/at path is a mode, never a silent drop.
 
     Mode resolution (``DLROVER_TPU_EMB_KERNEL`` overrides): ``auto``
     compiles the Pallas kernels on TPU and uses the jnp path on CPU —
@@ -124,13 +124,11 @@ class _Kernels:
             self._scatter_calls[key] = fn
         return fn(table, jnp.asarray(slots, jnp.int32), rows)
 
-    def _fall_back(self, why: Exception):
-        logger.warning(
-            f"embedding pallas kernels unavailable on this backend "
-            f"({why!r}); falling back to jnp gather/scatter"
-        )
-        self.mode = "jnp"
-
+    # The kernels see the table as ``[capacity, 1, row_floats]``: the
+    # TPU lowering wants the last two dims of a block to be multiples of
+    # (8, 128) or the array's own, and a one-row block of the 2-D table
+    # is neither (Mosaic refuses it). Each call is jitted so the reshape
+    # is part of the program, not an eager copy of the table.
     def _build_gather(self, n: int, capacity: int, row_floats: int):
         import jax
         from jax.experimental import pallas as pl
@@ -139,19 +137,23 @@ class _Kernels:
         def kernel(_slots_ref, table_ref, out_ref):
             out_ref[...] = table_ref[...]
 
+        row = (1, 1, row_floats)
         gs = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
-            in_specs=[
-                pl.BlockSpec((1, row_floats), lambda i, s: (s[i], 0))
-            ],
-            out_specs=pl.BlockSpec((1, row_floats), lambda i, s: (i, 0)),
+            in_specs=[pl.BlockSpec(row, lambda i, s: (s[i], 0, 0))],
+            out_specs=pl.BlockSpec(row, lambda i, s: (i, 0, 0)),
         )
-        return pl.pallas_call(
+        call = pl.pallas_call(
             kernel,
             grid_spec=gs,
-            out_shape=jax.ShapeDtypeStruct((n, row_floats), np.float32),
+            out_shape=jax.ShapeDtypeStruct((n,) + row[1:], np.float32),
             interpret=pallas_interpret_mode(),
+        )
+        return jax.jit(
+            lambda slots, table: call(
+                slots, table.reshape((capacity,) + row[1:])
+            ).reshape(n, row_floats)
         )
 
     def _build_scatter(self, n: int, capacity: int, row_floats: int):
@@ -162,26 +164,35 @@ class _Kernels:
         def kernel(_slots_ref, rows_ref, _table_ref, out_ref):
             out_ref[...] = rows_ref[...]
 
+        row = (1, 1, row_floats)
         gs = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, row_floats), lambda i, s: (i, 0)),
-                pl.BlockSpec((1, row_floats), lambda i, s: (s[i], 0)),
+                pl.BlockSpec(row, lambda i, s: (i, 0, 0)),
+                pl.BlockSpec(row, lambda i, s: (s[i], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, row_floats), lambda i, s: (s[i], 0)),
+            out_specs=pl.BlockSpec(row, lambda i, s: (s[i], 0, 0)),
         )
         # the table (input 2, counting the scalar-prefetch arg) aliases
-        # the output: untouched rows persist, addressed rows are
-        # overwritten in place — no table-sized copy per step
-        return pl.pallas_call(
+        # the output and is donated: untouched rows persist, addressed
+        # rows are overwritten in place — no table-sized copy per step
+        call = pl.pallas_call(
             kernel,
             grid_spec=gs,
             out_shape=jax.ShapeDtypeStruct(
-                (capacity, row_floats), np.float32
+                (capacity,) + row[1:], np.float32
             ),
             input_output_aliases={2: 0},
             interpret=pallas_interpret_mode(),
+        )
+        return jax.jit(
+            lambda slots, rows, table: call(
+                slots,
+                rows.reshape((n,) + row[1:]),
+                table.reshape((capacity,) + row[1:]),
+            ).reshape(capacity, row_floats),
+            donate_argnums=(2,),
         )
 
     def gather(self, table, slots_np: np.ndarray):
@@ -194,19 +205,11 @@ class _Kernels:
         key = (len(slots_np),) + table.shape
         call = self._gather_calls.get(key)
         if call is None:
-            try:
-                call = self._build_gather(
-                    len(slots_np), table.shape[0], table.shape[1]
-                )
-            except Exception as e:  # jaxlib without pallas support
-                self._fall_back(e)
-                return self._gather_jnp(table, jnp.asarray(slots_np))
+            call = self._build_gather(
+                len(slots_np), table.shape[0], table.shape[1]
+            )
             self._gather_calls[key] = call
-        try:
-            return call(jnp.asarray(slots_np, jnp.int32), table)
-        except Exception as e:
-            self._fall_back(e)
-            return self._gather_jnp(table, jnp.asarray(slots_np))
+        return call(jnp.asarray(slots_np, jnp.int32), table)
 
     def scatter(self, table, slots_np: np.ndarray, rows):
         """table[slots[i]] = rows[i], in place (aliased); returns the
@@ -219,21 +222,11 @@ class _Kernels:
         key = (len(slots_np),) + table.shape
         call = self._scatter_calls.get(key)
         if call is None:
-            try:
-                call = self._build_scatter(
-                    len(slots_np), table.shape[0], table.shape[1]
-                )
-            except Exception as e:
-                self._fall_back(e)
-                return self._scatter_jnp(
-                    table, jnp.asarray(slots_np), rows
-                )
+            call = self._build_scatter(
+                len(slots_np), table.shape[0], table.shape[1]
+            )
             self._scatter_calls[key] = call
-        try:
-            return call(jnp.asarray(slots_np, jnp.int32), rows, table)
-        except Exception as e:
-            self._fall_back(e)
-            return self._scatter_jnp(table, jnp.asarray(slots_np), rows)
+        return call(jnp.asarray(slots_np, jnp.int32), rows, table)
 
 
 # -- stats -------------------------------------------------------------------

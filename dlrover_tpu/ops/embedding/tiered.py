@@ -357,7 +357,7 @@ class TieredKvEmbedding:
 
 
 class NativeTieredKvEmbedding:
-    """Hybrid embedding storage with the tier manager NATIVE (VERDICT
+    """Hybrid embedding storage with the tier manager NATIVE (review
     r4 missing #6; parity: tfplus hybrid_embedding table_manager.h:547,
     storage_table.h:199): hot→cold eviction and cold→hot fault-in move
     rows entirely inside the C++ layer (one pass over the hash buckets

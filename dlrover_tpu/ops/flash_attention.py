@@ -52,10 +52,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlrover_tpu.common.jax_compat import (
-    pallas_tpu_compiler_params as _compiler_params,
-)
-
 MaskFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp()=0 without NaN risk
@@ -234,7 +230,7 @@ def _fwd_pallas(
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel",
                 "parallel",
@@ -460,7 +456,7 @@ def _fused_fwd_call(qt, kt, vt, offsets, *, causal, mask_fn, sm_scale,
             jax.ShapeDtypeStruct((B, H, T, D), qt.dtype),
             jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_FUSED_VMEM_LIMIT,
         ),
@@ -494,7 +490,7 @@ def _fused_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
             jax.ShapeDtypeStruct((B, H, T, D), qt.dtype),
             jax.ShapeDtypeStruct((B, H, T, D), qt.dtype),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_FUSED_VMEM_LIMIT,
         ),
@@ -758,7 +754,7 @@ def _bwd_pallas(
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel",
                 "parallel",
@@ -804,7 +800,7 @@ def _bwd_pallas(
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel",
                 "parallel",
@@ -998,7 +994,7 @@ def flash_attention_fwd_chunked(
     per-device attention after its all-to-all) otherwise drops to the
     streaming kernels for the WHOLE sequence, paying a different
     kernel strategy than ring attention's naturally-chunked hops — the
-    like-for-like gap VERDICT r4 #8 flagged. Causal chunks below the
+    like-for-like gap review r4 #8 flagged. Causal chunks below the
     diagonal are skipped entirely (the work-skipping a causal streaming
     grid does with masked blocks)."""
     B, Tq, H, D = q.shape

@@ -715,7 +715,7 @@ def adamw_8bit_flat(
     fused concat each — the per-leaf slices back out fuse into the
     apply. The per-leaf (tree) form dispatches ~5 kernels per leaf,
     ~800 launches on GPT-2 XL, measured 170-200 ms against a 38 ms
-    flat-buffer roofline (VERDICT r3 #1); this form closes that gap.
+    flat-buffer roofline (review r3 #1); this form closes that gap.
     ``group_elems`` bounds the transient HBM (one group's grad concat +
     delta at a time) — a single 1.5B flat buffer OOMed next to bf16
     params+grads.

@@ -702,17 +702,11 @@ def plan_buckets(
                 f"topk_block must be >= 1, got {topk_block}"
             )
     if auto_axes and compress != "none":
-        from dlrover_tpu.common.jax_compat import (
-            supports_auto_axis_residual_shardings,
+        raise ValueError(
+            "model-sharded plans (dp x tp/sp/ep, 3d) do not support "
+            "int8 compression (the residual would cross GSPMD axes "
+            "with unstable auto-axis shardings)"
         )
-
-        if not supports_auto_axis_residual_shardings():
-            raise ValueError(
-                "model-sharded plans (dp x tp/sp/ep, 3d) do not "
-                "support int8 compression on this jaxlib (the "
-                "residual would cross GSPMD axes with unstable "
-                "auto-axis shardings)"
-            )
     if auto_axes and fsdp > 1 and kind != "3d":
         raise ValueError(
             "a dp x tp/sp plan supports no fsdp leg (only the fully-"
@@ -819,8 +813,8 @@ def resolve_auto_compress(
     bandwidth ratio (observed rail rates fold into the model, so the
     policy tracks what the links actually deliver):
 
-    - model-sharded plans (``auto_axes``): "none" — the residual gate
-      (``supports_auto_axis_residual_shardings``) owns that decision;
+    - model-sharded plans (``auto_axes``): "none" — the residual
+      cannot live across steps on a partial-manual region;
     - hybrid dp axis (``slices > 1``): the DCN shard leg exists —
       sparsify it (int8+topk) when DCN is severely outmatched
       (ratio >= ``AUTO_TOPK_RATIO``), quantize it at
@@ -959,8 +953,7 @@ def _localize_tp(params_shape, tp: int, cfg):
 
 
 # once-per-process visibility for the model-sharded compression gate
-# (the capability probe keeps it closed on today's jaxlib; a noisy
-# per-plan log would drown candidate search)
+# (a noisy per-plan log would drown candidate search)
 _MODEL_SHARD_COMPRESS_LOGGED = False
 
 
@@ -985,32 +978,19 @@ def _plan_for_mode(
             auto_axes=mode.auto_axes,
         )
     if mode.kind in ("tp", "ep", "3d") and grad_compress != "none":
-        from dlrover_tpu.common.jax_compat import (
-            supports_auto_axis_residual_shardings,
-        )
         from dlrover_tpu.common.log import default_logger as logger
 
-        if mode.kind != "3d" and supports_auto_axis_residual_shardings():
-            # a jaxlib with stable auto-axis residual shardings can
-            # carry EF state across steps on the partial-manual psum
-            # paths; only flat int8 applies there (tp/ep plans force
-            # slices=1, so there is no DCN shard leg to sparsify)
-            grad_compress = "int8"
-        else:
-            # the residual would inherit unstable auto-axis shardings
-            # across steps (invalidating AOT executables); run the
-            # explicit path uncompressed instead of falling back
-            # entirely
-            if not _MODEL_SHARD_COMPRESS_LOGGED:
-                _MODEL_SHARD_COMPRESS_LOGGED = True
-                logger.info(
-                    f"grad_sync: int8 compression is not supported "
-                    f"on model-sharded ({mode.kind}) meshes on this "
-                    f"jaxlib (supports_auto_axis_residual_shardings "
-                    f"= False); running the explicit bucketed sync "
-                    f"at fp32"
-                )
-            grad_compress = "none"
+        # the residual would inherit unstable auto-axis shardings
+        # across steps (invalidating AOT executables); run the
+        # explicit path uncompressed instead of falling back entirely
+        if not _MODEL_SHARD_COMPRESS_LOGGED:
+            _MODEL_SHARD_COMPRESS_LOGGED = True
+            logger.info(
+                f"grad_sync: int8 compression is not supported on "
+                f"model-sharded ({mode.kind}) meshes; running the "
+                f"explicit bucketed sync at fp32"
+            )
+        grad_compress = "none"
     if mode.kind == "ep":
         # the fully-manual (dp, ep) path has its own split plan
         # (ep-local expert leaves + dense leaves)
@@ -1681,26 +1661,8 @@ def _sync_one_bucket(
             x, "fsdp", scatter_dimension=0, tiled=True
         )
     if plan.auto_psum:
-        if plan.compressed:
-            # only reachable when supports_auto_axis_residual_
-            # shardings() passes (plan construction forces "none"
-            # otherwise): the bucketed psum ships int8 at a shared
-            # scale with the same EF construction as the flat path
-            xx = x + residual if residual is not None else x
-            scale = jax.lax.pmax(
-                jnp.max(jnp.abs(xx)), plan.stack_axes
-            ) / 127.0
-            scale = jnp.maximum(scale, jnp.float32(1e-20))
-            q = jnp.clip(
-                jnp.round(xx / scale), -127, 127
-            ).astype(jnp.int8)
-            new_residual = xx - q.astype(jnp.float32) * scale
-            full = (
-                jax.lax.psum(q.astype(jnp.int32), "dp")
-                .astype(jnp.float32) * scale
-            )
-        else:
-            full, new_residual = jax.lax.psum(x, "dp"), residual
+        # plan construction refuses compression on auto-axis plans
+        full, new_residual = jax.lax.psum(x, "dp"), residual
     elif plan.two_level:
         full, new_residual = _dp_leg_2level(x, residual, plan, legs)
     else:
@@ -1752,9 +1714,8 @@ def sync_grads(
     """
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from dlrover_tpu.common.jax_compat import shard_map
 
     if plan.three_d:
         out = _sync_grads_3d(stacked_grads, mesh, plan)
@@ -1796,7 +1757,7 @@ def sync_grads(
     if plan.auto_axes:
         # manual over dp only; tp/sp stay GSPMD ("auto") axes so the
         # sharded matmuls around this sync keep their native schedule
-        kw["axis_names"] = ("dp",)
+        kw["axis_names"] = frozenset({"dp"})
     out_specs = (
         tuple(bucket_out for _ in plan.buckets),
         tuple(stacked for _ in res_in),
@@ -1853,9 +1814,8 @@ def _sync_grads_3d(stacked_grads: Any, mesh, plan: BucketPlan):
     would double-count tp-replicated leaves)."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from dlrover_tpu.common.jax_compat import shard_map
 
     leaves, treedef = jax.tree_util.tree_flatten(stacked_grads)
     if len(leaves) != len(plan.leaf_shapes):
@@ -2400,9 +2360,8 @@ def _measure_ep_sync(
 
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from dlrover_tpu.common.jax_compat import shard_map
 
     def _global(shape, dim):
         return tuple(
@@ -2473,9 +2432,8 @@ def _measure_pp_sync(
 
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from dlrover_tpu.common.jax_compat import shard_map
 
     stage_zeros = [
         jax.device_put(

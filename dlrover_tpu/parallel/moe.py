@@ -224,7 +224,7 @@ def moe_layer_local(
 def moe_layer(params: MoEParams, x, mesh, **kw):
     """Global wrapper: x [B, S, model] sharded (batch→(dp,fsdp), seq→sp);
     expert weights sharded over ep on their first axis."""
-    from dlrover_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     xspec = P(("dp", "fsdp"), "sp", None)

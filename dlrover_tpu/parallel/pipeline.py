@@ -53,10 +53,8 @@ from __future__ import annotations
 from typing import Any, Optional, Tuple
 
 import jax
-
-from dlrover_tpu.common.jax_compat import pcast, shard_map
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dlrover_tpu.models.config import TransformerConfig
@@ -303,7 +301,7 @@ def pipeline_forward(
         stages_loc = jax.tree_util.tree_map(lambda a: a[0], stages)
         idx = lax.axis_index("pp")
         perm = [(i, (i + 1) % pp) for i in range(pp)]
-        x_loc = pcast(x_mb, ("pp",), to="varying")
+        x_loc = lax.pcast(x_mb, ("pp",), to="varying")
         state = jnp.zeros_like(x_loc[0])
         outputs = jnp.zeros_like(x_loc)
 
@@ -586,7 +584,7 @@ def pipeline_value_and_grad_1f1b(
         bwd_perm = [((i + 1) % pp, i) for i in range(pp)]
 
         def vary(a):
-            return pcast(
+            return lax.pcast(
                 a, ("pp", "dp") if local_dp else ("pp",), to="varying"
             )
 
@@ -905,7 +903,7 @@ def pipeline_value_and_grad_gpipe_sync(
         perm = [(i, (i + 1) % pp) for i in range(pp)]
 
         def vary(a):
-            return pcast(a, ("pp", "dp"), to="varying")
+            return lax.pcast(a, ("pp", "dp"), to="varying")
 
         tok_loc = vary(tok_all)
         tgt_loc = vary(tgt_all)

@@ -281,7 +281,7 @@ def ring_self_attention(
     ``use_kernel=None`` auto-picks the Pallas-kernel ring on TPU and the
     jnp ring elsewhere (kernels run under the slow interpreter off-TPU).
     """
-    from dlrover_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if use_kernel is None:

@@ -471,9 +471,8 @@ def _time_allreduce(
     4-byte collective."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from dlrover_tpu.common.jax_compat import shard_map
 
     n = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     group_n = len(groups[0]) if groups else n

@@ -110,7 +110,7 @@ def ulysses_self_attention(
     ``ring_self_attention``: shards [B,S,H,D] over the mesh
     (batch→(dp,fsdp), seq→sp, heads→tp) and runs the two-collective
     schedule."""
-    from dlrover_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(("dp", "fsdp"), "sp", "tp", None)

@@ -16,7 +16,8 @@ from dataclasses import dataclass
 
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
-from dlrover_tpu.utils.device import configure_devices
+from dlrover_tpu.utils.device import check_devices, configure_devices
+from dlrover_tpu.utils.env import framework_root
 
 
 @dataclass
@@ -59,8 +60,8 @@ def elastic_context() -> ElasticContext:
 _initialized = False
 
 
-def enable_compile_cache(cache_dir: str = "") -> str:
-    """Point JAX's persistent compilation cache at a job-stable dir.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache.
 
     The elasticity hard part SURVEY.md §7 calls out: a restarted worker's
     first step recompiles the whole train program (tens of seconds to
@@ -68,42 +69,53 @@ def enable_compile_cache(cache_dir: str = "") -> str:
     restart into the SAME world size replays the compiled executable from
     disk, and each previously-seen world size after a scale event is a
     cache hit too (entries are keyed on the program, which includes mesh
-    shape). Returns the cache dir in use, "" when disabled via
+    shape).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that
+    directory and no other is set here. Otherwise the cache lives at one
+    fixed path inside the checkout: the path is part of what a restarted
+    worker must find again, so it is never a temporary name. Returns the
+    directory in use, "" when disabled via
     ``DLROVER_TPU_COMPILE_CACHE=off``.
     """
-    env = os.getenv("DLROVER_TPU_COMPILE_CACHE", "")
-    if env == "off":
+    if os.getenv("DLROVER_TPU_COMPILE_CACHE", "") == "off":
         return ""
-    cache_dir = env or cache_dir or "/tmp/dlrover_tpu/compile_cache"
-    from dlrover_tpu.common.jax_compat import (
-        enable_persistent_compilation_cache,
-    )
+    import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
+    cache_dir = os.getenv("JAX_COMPILATION_CACHE_DIR", "")
+    if not cache_dir:
+        cache_dir = os.path.join(framework_root(), ".compile_cache")
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache everything that took meaningful compile time, not only the
-    # multi-minute programs (defaults skip sub-second compiles); the
-    # knobs are version-guarded in jax_compat
-    if not enable_persistent_compilation_cache(
-        cache_dir, min_compile_secs=0.5, min_entry_bytes=0
-    ):
-        return ""
+    # multi-minute programs (defaults skip sub-second compiles)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return cache_dir
 
 
 def init_elastic(timeout_secs: int = 300) -> ElasticContext:
-    """Configure devices and join the JAX distributed system.
+    """Configure devices and join the JAX distributed system, once per
+    process (later calls only return the context).
 
-    Safe to call for single-process jobs (no-op init). Fast re-init after a
-    restart is just process re-exec + this call — the agent already
-    re-assigned ``process_id``/``coordinator_addr`` for the new world;
-    the persistent compilation cache turns the post-restart recompile
-    into a disk read.
+    The one place a training process is set up before it touches a
+    device: ``ElasticTrainer`` calls it, and a script that uses JAX
+    before it builds a trainer calls it first. Safe for single-process
+    jobs (no distributed init). Fast re-init after a restart is just
+    process re-exec + this call — the agent already re-assigned
+    ``process_id``/``coordinator_addr`` for the new world; the
+    persistent compilation cache turns the post-restart recompile into
+    a disk read.
     """
     global _initialized
     ctx = elastic_context()
-    configure_devices()  # honors DLROVER_TPU_DEVICE_SPEC before backend init
+    if _initialized:
+        return ctx
+    # configuration first, nothing here may bring the backend up:
+    # jax.distributed.initialize refuses to run after it
+    configure_devices()  # honors DLROVER_TPU_DEVICE_SPEC
     enable_compile_cache()
-    if ctx.is_distributed and not _initialized:
+    if ctx.is_distributed:
         import jax
 
         logger.info(
@@ -117,5 +129,6 @@ def init_elastic(timeout_secs: int = 300) -> ElasticContext:
             process_id=ctx.process_id,
             initialization_timeout=timeout_secs,
         )
-        _initialized = True
+    check_devices()  # the backend may come up now
+    _initialized = True
     return ctx

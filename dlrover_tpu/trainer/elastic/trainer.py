@@ -303,6 +303,14 @@ class ElasticTrainer:
     ):
         import jax
 
+        from dlrover_tpu.trainer.elastic.distributed import init_elastic
+
+        # before anything touches a device: the agent's device spec
+        # (asking for the chip and not getting it is an error), the
+        # persistent compile cache, so a restarted worker does not
+        # recompile, and the distributed system. A no-op where the
+        # script has called it already
+        init_elastic()
         self.tcfg = trainer_cfg or TrainerConfig()
         self._metrics_hook = metrics_hook
         # kept for the resize path: a new mesh rebuilds the accel

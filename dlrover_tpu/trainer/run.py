@@ -80,8 +80,8 @@ def parse_args(argv=None):
     p.add_argument(
         "--auto-config",
         action="store_true",
-        help="infer nnodes from NODE_NUM, nproc-per-node from the local "
-        "TPU count, and enable network-check for jobs of >=4 nodes "
+        help="infer nnodes from NODE_NUM, run one worker per TPU host, "
+        "and enable network-check for jobs of >=4 nodes "
         "(parity: dlrover-run --auto-config)",
     )
     p.add_argument(
@@ -170,20 +170,22 @@ def _run_network_check(args, client: MasterClient) -> bool:
 def auto_configure(args):
     """--auto-config (parity: elastic_run.py:33-40 + ElasticLaunchConfig
     .auto_configure_params training.py:140): nnodes from the platform's
-    NODE_NUM env (the operator sets it on every pod), nproc-per-node
-    from the locally visible accelerator count, and network-check on
-    for jobs of >= 4 nodes."""
+    NODE_NUM env (the operator sets it on every pod), one worker per
+    host on TPU (a JAX process owns every chip of its host; the agent
+    gives its workers nothing to split them by), nproc-per-node from a
+    ``cpu:N`` spec, and network-check on for jobs of >= 4 nodes."""
     try:
         node_num = int(os.getenv(NodeEnv.NODE_NUM, "0") or "0")
     except ValueError:
         node_num = 0  # templated-but-unset env: fall back to --nnodes
     if node_num > 0:
         args.nnodes = str(node_num)
-    from dlrover_tpu.utils.device import local_device_count
+    from dlrover_tpu.utils.device import DEVICE_SPEC_ENV, cpu_spec_count
 
-    n = local_device_count(args.device_spec)
-    if n > 0:
-        args.nproc_per_node = n
+    spec = args.device_spec or os.getenv(DEVICE_SPEC_ENV, "")
+    args.nproc_per_node = (
+        cpu_spec_count(spec) if spec.startswith("cpu") else 1
+    )
     # gate on the RESOLVED min_nodes, not only the env-derived node_num:
     # `--auto-config --nnodes=8` without the platform env must still turn
     # the health check on (parity: training.py:154 gates on min_nodes)

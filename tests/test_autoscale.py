@@ -162,7 +162,7 @@ class TestLocalProcessScaler:
 class TestHangRecovery:
     def test_hang_restarts_workers_then_survives(self, master3):
         """Hang → restart order via heartbeat channel; job keeps running
-        (the reference's behavior; VERDICT weak #6: exiting is the
+        (the reference's behavior; review weak #6: exiting is the
         anti-goodput outcome)."""
         master, _ = master3
         node = _set_running(master, 0)

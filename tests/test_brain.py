@@ -450,7 +450,7 @@ def test_master_env_wiring_reports_job_end(brain, monkeypatch):
 
 
 class TestBrainIngestion:
-    """VERDICT r4 #7: the Brain watches node events ITSELF (ref
+    """review r4 #7: the Brain watches node events ITSELF (ref
     brain/pkg/server/server.go:176 watch manager -> mysql.go:339 sink)
     — raw pod lifecycle drives the datastore and cross-job
     bad-node exclusion with NO job master involved."""

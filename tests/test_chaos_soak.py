@@ -48,7 +48,7 @@ def test_chaos_soak(tmp_path):
 
 @pytest.mark.slow
 def test_slice_unit_failover(tmp_path):
-    """Slice-level elasticity (VERDICT r4 #4, SURVEY §5 "slice-level
+    """Slice-level elasticity (review r4 #4, SURVEY §5 "slice-level
     failure"): a 4-node job with node_unit=2 (two 2-host TPU slices)
     loses one WHOLE slice — both of its nodes SIGKILL'd — and must (a)
     re-freeze the surviving world at a node_unit multiple (2, never 3:

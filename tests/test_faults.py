@@ -417,6 +417,16 @@ class TestChaosMatrix:
     fault is detected, restore falls back to the newest verified step,
     and training resumed from it reproduces the clean run exactly."""
 
+    @pytest.fixture(autouse=True)
+    def _own_job(self, monkeypatch):
+        """A job name nothing else serves: a launcher that another test
+        file left winding down in this xdist worker still answers on the
+        worker's checkpoint endpoints, and the engine would then take
+        the agent path (seen once under six workers)."""
+        monkeypatch.setenv(
+            "DLROVER_TPU_JOB_NAME", f"chaosmatrix{os.getpid()}"
+        )
+
     def _ckptr(self, tmp_path):
         AsyncCheckpointSaver.reset()  # force the sync (no-agent) path
         ckptr = FlashCheckpointer(str(tmp_path / "ckpt"))

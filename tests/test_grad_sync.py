@@ -45,6 +45,42 @@ def _fp32_tiny(**kw):
     )
 
 
+def _first_moment(state):
+    """Adam's mu leaves after ONE step from zero: (1 - b1) * grad."""
+    nodes = jax.tree_util.tree_leaves(
+        state.opt_state, is_leaf=lambda t: hasattr(t, "mu")
+    )
+    (adam,) = [t for t in nodes if hasattr(t, "mu")]
+    return jax.tree_util.tree_leaves(adam.mu)
+
+
+def _assert_same_adam_step(sa, sb, atol, lr=1e-2, eps=1e-8):
+    """Two programs that compute the same gradient in another summation
+    order, compared after one AdamW step. The gradients must agree to
+    fp32 rounding everywhere. The params must agree to ``atol`` wherever
+    Adam's ``g / (|g| + eps)`` is well conditioned; where a nonzero |g|
+    is within 100x of eps that quotient turns a 1e-10 rounding
+    difference into a visible one (measured on jax 0.9.0: one element of
+    8192 with g = -4.6e-9 in both programs, apart by 5.8e-11, moved
+    2.7e-5), so there the bound is the largest step Adam can take,
+    ``lr``."""
+    for a, c, ma, mc in zip(
+        jax.tree_util.tree_leaves(sa.params),
+        jax.tree_util.tree_leaves(sb.params),
+        _first_moment(sa),
+        _first_moment(sb),
+    ):
+        a, c = np.asarray(a), np.asarray(c)
+        ga, gc = np.asarray(ma) / 0.1, np.asarray(mc) / 0.1
+        np.testing.assert_allclose(ga, gc, rtol=1e-4, atol=1e-7)
+        ill = (np.minimum(np.abs(ga), np.abs(gc)) <= 100 * eps) & (
+            (ga != 0) | (gc != 0)
+        )
+        np.testing.assert_allclose(a[~ill], c[~ill], atol=atol)
+        np.testing.assert_allclose(a[ill], c[ill], atol=lr)
+        assert ill.mean() < 0.01  # the carve-out stays a carve-out
+
+
 # -- bucket planning --------------------------------------------------------
 class TestBucketPlan:
     def test_partitions_whole_tree_in_order(self):
@@ -200,13 +236,7 @@ class TestTrainStepSync:
         assert abs(
             float(m0["grad_norm"]) - float(m1["grad_norm"])
         ) < 1e-4
-        for a, c in zip(
-            jax.tree_util.tree_leaves(s0.params),
-            jax.tree_util.tree_leaves(s1.params),
-        ):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(c), atol=1e-5
-            )
+        _assert_same_adam_step(s0, s1, atol=1e-5)
 
     def test_grad_accum_syncs_once_per_step(self):
         """The K× wire saving: under grad_accum=K the explicit path
@@ -263,13 +293,7 @@ class TestTrainStepSync:
             grad_accum=4,
         )(state, b["x"], b["y"])
         assert abs(float(m1["loss"]) - float(m4["loss"])) < 1e-5
-        for a, c in zip(
-            jax.tree_util.tree_leaves(s1.params),
-            jax.tree_util.tree_leaves(s4.params),
-        ):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(c), atol=2e-5
-            )
+        _assert_same_adam_step(s1, s4, atol=2e-5)
 
     def test_int8_error_feedback_convergence_parity(self):
         """The bench gate in test form: int8+EF training tracks the
@@ -459,13 +483,7 @@ class TestGradAccumEquivalence:
             cfg, mesh, tx, donate=False, grad_accum=4
         )(state, b["x"], b["y"])
         assert abs(float(m1["loss"]) - float(m4["loss"])) < 1e-5
-        for a, c in zip(
-            jax.tree_util.tree_leaves(s1.params),
-            jax.tree_util.tree_leaves(s4.params),
-        ):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(c), atol=2e-5
-            )
+        _assert_same_adam_step(s1, s4, atol=2e-5)
 
 
 # -- satellite: PipelineStats coverage --------------------------------------

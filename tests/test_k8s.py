@@ -533,7 +533,7 @@ def test_exclusion_rides_scaleplan_cr_through_operator():
 
 
 class TestOperatorProductionSemantics:
-    """VERDICT r4 #6: watch-driven reconcile, status conditions and
+    """review r4 #6: watch-driven reconcile, status conditions and
     ownerReference GC (ref elasticjob_controller.go:287 conditions,
     master.go:289 SetControllerReference)."""
 

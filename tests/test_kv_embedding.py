@@ -361,7 +361,7 @@ class TestShardedKvEmbedding:
 
     def test_reshard_roundtrip_no_loss_no_dup(self, dim):
         """N → M → N with training in between: every row preserved
-        exactly once (the VERDICT done-criterion)."""
+        exactly once (the review done-criterion)."""
         svc = ElasticPsService()
         e = ShardedKvEmbedding(3, dim, seed=5, version_service=svc)
         keys = np.arange(1000, dtype=np.int64)

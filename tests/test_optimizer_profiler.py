@@ -220,3 +220,25 @@ def test_module_breakdown_measures_each_module():
     assert bwd["block_fwd_bwd"].gflops == pytest.approx(
         3 * bwd["block_fwd"].gflops, rel=0.05
     )
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+@pytest.mark.parametrize(
+    "kind, peak",
+    [("TPU v5 lite", 197.0), ("TPU v5p", 459.0), ("TPU v4", 275.0)],
+)
+def test_chip_peak_known_tpu_kinds(kind, peak):
+    assert chip_peak_tflops(_FakeDevice("tpu", kind)) == peak
+
+
+def test_chip_peak_is_none_off_the_tpu_and_an_error_for_an_unknown_tpu():
+    """A CPU run has no MFU; a TPU the table does not know must not
+    silently turn MFU into None."""
+    assert chip_peak_tflops(_FakeDevice("cpu", "cpu")) is None
+    with pytest.raises(KeyError, match="TPU v9"):
+        chip_peak_tflops(_FakeDevice("tpu", "TPU v9 mega"))

@@ -263,7 +263,7 @@ def test_1f1b_training_matches_gpipe():
     "schedule,v", [("gpipe", 1), ("1f1b", 1), ("interleaved", 2)]
 )
 def test_pipeline_composes_with_tp(schedule, v):
-    """True 3D parallelism: pp×tp×dp on one mesh (VERDICT r3 missing#2,
+    """True 3D parallelism: pp×tp×dp on one mesh (review r3 missing#2,
     the repo's answer to the reference's DS-3D
     ds_3d_parallel_optimization.py). The pipeline body is manual over pp
     ONLY — tp must stay GSPMD-auto inside the stages. Proof obligations:
@@ -332,10 +332,8 @@ def test_pp_bytes_accessed_does_not_blow_up():
     x, y = _batch(cfg, batch=8, seq=16)
 
     def compiled_bytes(step, state):
-        from dlrover_tpu.common.jax_compat import cost_analysis_dict
-
         c = step.lower(state, x, y).compile()
-        return float(cost_analysis_dict(c).get("bytes accessed", 0.0))
+        return float(c.cost_analysis().get("bytes accessed", 0.0))
 
     mesh1 = build_mesh(MeshConfig(dp=8))
     s1, _ = init_sharded_state(jax.random.PRNGKey(0), cfg, mesh1, tx)

@@ -1,4 +1,4 @@
-"""Cross-host coworker data plane (VERDICT r4 #5).
+"""Cross-host coworker data plane (review r4 #5).
 
 Ref: atorch feeds preprocessed batches from coworker hosts over gRPC
 into training-host shared memory (distributed.py:489,
@@ -133,7 +133,7 @@ class TestMasterMediatedDiscovery:
 
 @pytest.mark.slow
 def test_data_node_feeds_two_trainer_nodes(tmp_path):
-    """The VERDICT r4 #5 e2e: a dedicated data node (coworker
+    """The review r4 #5 e2e: a dedicated data node (coworker
     preprocessors + TCP server) feeds TWO trainer nodes of a real
     LocalCluster job; trainers discover it through the master KV store
     and drain batches through their local shm rings. Every batch lands

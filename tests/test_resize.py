@@ -695,6 +695,11 @@ class TestTrainerResize:
         # 1 device per worker at this density, so worker counts map
         # 1:1 to device counts
         monkeypatch.setenv(NodeEnv.NUM_PROCESSES, str(len(jax.devices())))
+        # a world size for the candidate mapping only: this process is
+        # set up already and must not try to join a distributed system
+        from dlrover_tpu.trainer.elastic import distributed
+
+        monkeypatch.setattr(distributed, "_initialized", True)
         t = _make_trainer(
             tcfg={"speculative_compile": True},
             eval_dataset=_Tokens(n=16),

@@ -1,7 +1,7 @@
 """Sparse DCN gradient sync (ISSUE 18): EF-composed block top-k on the
 two-level sync's slow (cross-slice) leg, the ``grad_compress="auto"``
 policy that picks a mode per mesh from the measured ICI:DCN ratio, the
-``supports_auto_axis_residual_shardings`` capability gate, and the
+gate that keeps model-sharded plans uncompressed, and the
 observed rail-rate EWMA that folds realized striped-transfer throughput
 back into the link-cost model."""
 
@@ -16,9 +16,6 @@ import optax
 import pytest
 
 from dlrover_tpu.accel.strategy import Strategy
-from dlrover_tpu.common.jax_compat import (
-    supports_auto_axis_residual_shardings,
-)
 from dlrover_tpu.models import tiny
 from dlrover_tpu.models.train import (
     build_train_step,
@@ -254,28 +251,11 @@ class TestAutoCompressPolicy:
         assert s2.grad_compress == "auto" and s2.comm_overlap
 
 
-# -- capability probe (satellite: int8-on-tp future gate) --------------------
-class TestAutoAxisResidualProbe:
-    def test_answers_false_today(self, monkeypatch):
-        monkeypatch.delenv(
-            "DLROVER_TPU_AUTO_AXIS_RESIDUAL", raising=False
-        )
-        # every shipped jaxlib re-derives residual shardings across
-        # steps on partial-manual regions — the gate must stay closed
-        assert supports_auto_axis_residual_shardings() is False
-
-    def test_env_override(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_AUTO_AXIS_RESIDUAL", "1")
-        assert supports_auto_axis_residual_shardings() is True
-        monkeypatch.setenv("DLROVER_TPU_AUTO_AXIS_RESIDUAL", "0")
-        assert supports_auto_axis_residual_shardings() is False
-
+# -- model-sharded plans never compress --------------------------------------
+class TestModelShardedCompressGate:
     def test_tp_compress_forced_off_and_logs_once(self, monkeypatch):
         from dlrover_tpu.common import log as log_mod
 
-        monkeypatch.delenv(
-            "DLROVER_TPU_AUTO_AXIS_RESIDUAL", raising=False
-        )
         monkeypatch.setattr(
             gs, "_MODEL_SHARD_COMPRESS_LOGGED", False
         )
@@ -294,28 +274,10 @@ class TestAutoAxisResidualProbe:
         p1 = resolve_plan(cfg, s)
         p2 = resolve_plan(cfg, s)
         assert p1.compress == "none" and p2.compress == "none"
-        hits = [
-            m
-            for m in msgs
-            if "supports_auto_axis_residual_shardings" in m
-        ]
+        hits = [m for m in msgs if "int8 compression is not" in m]
         assert len(hits) == 1  # once per process, not per plan
 
-    def test_probe_enables_int8_on_tp(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_AUTO_AXIS_RESIDUAL", "1")
-        monkeypatch.setattr(
-            gs, "_MODEL_SHARD_COMPRESS_LOGGED", False
-        )
-        s = Strategy(
-            mesh=MeshConfig(dp=2, tp=2),
-            comm_overlap=True,
-            grad_compress="int8",
-        )
-        plan = resolve_plan(_fp32_tiny(), s)
-        assert plan is not None and plan.compress == "int8"
-
-    def test_3d_stays_off_even_with_probe(self, monkeypatch):
-        monkeypatch.setenv("DLROVER_TPU_AUTO_AXIS_RESIDUAL", "1")
+    def test_3d_stays_off(self, monkeypatch):
         monkeypatch.setattr(
             gs, "_MODEL_SHARD_COMPRESS_LOGGED", False
         )

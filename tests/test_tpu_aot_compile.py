@@ -1,0 +1,247 @@
+"""The Pallas kernels, compiled by the installed Mosaic for a v5e chip that
+is described and not attached (on-chip-measurement guide, section 2,
+rehearsal 3). Nothing runs: a pass says the chip's compiler accepts the
+kernel at this shape, never that it is right or fast.
+
+Every kernel picks ``interpret`` from the backend, which is the CPU
+here, so an unsteered compile would compile the *interpreted* kernel
+and prove nothing: each test steers the kernel to its compiled form
+itself and asserts ``tpu_custom_call`` in the lowered text.
+
+One file, topology in a module-scoped fixture: only the xdist worker
+that runs this file loads the TPU's library (see the guide for why it
+must never happen at import, in conftest.py or in a second file).
+"""
+
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# `dlrover_tpu.ops.flash_attention` the attribute is the function
+# (ops/__init__ re-exports it); the module has to be asked for by name
+fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile_for_chip(fn, *shapes):
+    lowered = jax.jit(fn).lower(*shapes)
+    assert "tpu_custom_call" in lowered.as_text(), (
+        "the interpreted kernel was lowered: the test did not steer "
+        "the kernel to its compiled form"
+    )
+    compiled = lowered.compile()
+    assert compiled.memory_analysis() is not None
+    return compiled
+
+
+# [B, H, T, D] in the kernel-native layout
+ATTENTION_SHAPES = {
+    # fused path: T <= _FUSED_MAX_T
+    "gpt2_124m": (8, 12, 1024, 64),
+    # 25 heads against _head_chunk
+    "gpt2_xl": (4, 25, 1024, 64),
+    # streaming path, 512-blocks
+    "llama2_7b": (1, 32, 4096, 128),
+}
+
+
+@pytest.mark.parametrize("name", list(ATTENTION_SHAPES))
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    shape = ATTENTION_SHAPES[name]
+    assert (shape[2] <= fa._FUSED_MAX_T) == (name != "llama2_7b")
+    qkv = [
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    ] * 3
+
+    def attend(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, force="pallas", layout="bhtd"
+        )
+
+    if direction == "fwd":
+        _compile_for_chip(attend, *qkv)
+    else:
+        _compile_for_chip(
+            jax.grad(
+                lambda q, k, v: attend(q, k, v)
+                .astype(jnp.float32)
+                .sum(),
+                argnums=(0, 1, 2),
+            ),
+            *qkv,
+        )
+
+
+# the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here
+ADAM_LEAVES = {
+    "gpt2_wte": (50257, 768),
+    "gpt2_xl_mlp": (1600, 6400),
+}
+
+
+@pytest.mark.parametrize("leaf", list(ADAM_LEAVES))
+@pytest.mark.parametrize("variant", ["adamw_8bit", "adamw_8bit_flat"])
+def test_adam8_kernel_compiles(leaf, variant, one_chip):
+    from dlrover_tpu.ops import quantized_optim
+
+    tx = getattr(quantized_optim, variant)(1e-3, use_pallas=True)
+    params = {
+        "w": jax.ShapeDtypeStruct(
+            ADAM_LEAVES[leaf], jnp.float32, sharding=one_chip
+        )
+    }
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(tx.init, params),
+    )
+    _compile_for_chip(tx.update, params, state, params)
+
+
+@pytest.mark.parametrize("dim", [64, 128])
+@pytest.mark.parametrize("op", ["gather", "scatter"])
+def test_device_tier_kernel_compiles(op, dim, one_chip, monkeypatch):
+    from dlrover_tpu.ops.embedding.device_tier import _Kernels
+
+    monkeypatch.setenv("DLROVER_TPU_PALLAS", "compile")
+    n, capacity = 4096, 1 << 16
+    kernels = _Kernels("pallas")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slots = sds((n,), jnp.int32)
+    table = sds((capacity, dim), jnp.float32)
+    if op == "gather":
+        _compile_for_chip(
+            kernels._build_gather(n, capacity, dim), slots, table
+        )
+    else:
+        _compile_for_chip(
+            kernels._build_scatter(n, capacity, dim),
+            slots, sds((n, dim), jnp.float32), table,
+        )
+
+
+def test_sharded_attention_compiles_for_four_chips(topo, monkeypatch):
+    """On a mesh of several chips the model's attention runs the kernel
+    under shard_map: GSPMD refuses to partition a Mosaic kernel by
+    itself, which is what every dp/fsdp/tp step hit on the TPU before."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dlrover_tpu.models.transformer import _causal_attention
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), devices=topo.devices[:4])
+    sharding = NamedSharding(mesh, P(("dp", "fsdp"), "tp", None, None))
+    qkv = [
+        jax.ShapeDtypeStruct(
+            ATTENTION_SHAPES["gpt2_124m"], jnp.bfloat16, sharding=sharding
+        )
+    ] * 3
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        jax.jit(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, layout="bhtd"
+            )
+        ).lower(*qkv)
+    _compile_for_chip(
+        lambda q, k, v: _causal_attention(q, k, v, mesh, layout="bhtd"),
+        *qkv,
+    )
+
+
+def test_explicit_sync_step_over_dp_x_tp_compiles_for_four_chips(
+    topo, monkeypatch
+):
+    """The explicit gradient sync (``comm_overlap``) on a dp x tp mesh
+    calls the model inside a region that is manual over dp only; the
+    kernel then sits under a nested ``shard_map`` over the axes that
+    region left to GSPMD. Before, the whole step was refused."""
+    import dataclasses
+
+    from dlrover_tpu.accel.dry_runner import _build
+    from dlrover_tpu.accel.strategy import Strategy
+    from dlrover_tpu.models.config import gpt2_small
+    from dlrover_tpu.models.train import batch_sharding
+    from dlrover_tpu.parallel.mesh import MeshConfig
+    from dlrover_tpu.trainer.elastic.trainer import build_optimizer
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # published width and heads; one layer and a small vocabulary (one
+    # that tp divides) keep the compile to a few seconds
+    cfg = dataclasses.replace(gpt2_small(), num_layers=1, vocab_size=1024)
+    strategy = Strategy(mesh=MeshConfig(dp=2, tp=2), comm_overlap=True)
+    _, mesh, step_fn, _, _, abstract_state = _build(
+        strategy, cfg, build_optimizer("adamw", lr=3e-4),
+        topo.devices[:4], donate=False, donate_inputs=False,
+    )
+    x = jax.ShapeDtypeStruct(
+        (8, 1024), jnp.int32, sharding=batch_sharding(mesh)
+    )
+    lowered = step_fn.lower(abstract_state(), x, x)
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text
+    # collectives written by the program itself, before GSPMD has
+    # partitioned anything: the explicit sync is what was lowered
+    assert "stablehlo.all_reduce" in text
+    lowered.compile()
+
+
+def test_uneven_attention_on_a_tpu_mesh_names_what_does_not_divide(
+    topo, monkeypatch
+):
+    """A batch the data axes do not divide (a small accumulation
+    microbatch) cannot go under ``shard_map``; on the TPU the kernel
+    would be refused by the compiler, so the model says which sizes to
+    change instead."""
+    from dlrover_tpu.models.transformer import _causal_attention
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(fsdp=4), devices=topo.devices[:4])
+    qkv = [jax.ShapeDtypeStruct((2, 12, 1024, 64), jnp.bfloat16)] * 3
+    with pytest.raises(ValueError, match=r"batch 2 .* dp\*fsdp=4"):
+        jax.eval_shape(
+            lambda q, k, v: _causal_attention(q, k, v, mesh, layout="bhtd"),
+            *qkv,
+        )

@@ -53,29 +53,29 @@ def timed_step(step_fn, state, label):
 
 def attn_variant(name):
     if name == "fused":  # the default dispatch (fused short-seq kernels)
-        return lambda q, k, v, layout="bthd": flash_attention(
+        return lambda q, k, v, mesh=None, layout="bthd": flash_attention(
             q, k, v, causal=True, layout=layout)
     if name == "flash512":
-        return lambda q, k, v, layout="bthd": flash_attention(
+        return lambda q, k, v, mesh=None, layout="bthd": flash_attention(
             q, k, v, causal=True, block_q=512, block_k=512,
             layout=layout, allow_fused=False)
     if name == "flash256":
-        return lambda q, k, v, layout="bthd": flash_attention(
+        return lambda q, k, v, mesh=None, layout="bthd": flash_attention(
             q, k, v, causal=True, block_q=256, block_k=256,
             layout=layout, allow_fused=False)
     if name == "flash128":
-        return lambda q, k, v, layout="bthd": flash_attention(
+        return lambda q, k, v, mesh=None, layout="bthd": flash_attention(
             q, k, v, causal=True, block_q=128, block_k=128,
             layout=layout, allow_fused=False)
     if name == "jnp":
-        return lambda q, k, v, layout="bthd": flash_attention(
+        return lambda q, k, v, mesh=None, layout="bthd": flash_attention(
             q, k, v, causal=True, force="reference", layout=layout)
     if name == "stock":
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention as stock_fa,
         )
 
-        def f(q, k, v, layout="bthd"):
+        def f(q, k, v, mesh=None, layout="bthd"):
             # stock kernel wants [B, H, T, D]
             if layout == "bhtd":
                 return stock_fa(

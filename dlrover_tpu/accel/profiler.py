@@ -123,6 +123,26 @@ class PipelineStats:
     # aside — the ratio is the compression win)
     grad_bytes_wire: int = 0
     grad_bytes_raw: int = 0
+    # -- the last restore's phases (ckpt/engine.py CheckpointEngine.load
+    # times them where they happen; the trainer folds the record in) --
+    restore_source: int = 0  # 0 none / 1 agent shm / 2 storage
+    restore_bytes: int = 0
+    # choosing the committed step: the storage tracker's newest step
+    # that verifies (the repairing rank reads and checksums its files)
+    restore_storage_verify_s: float = 0.0
+    # the fleet's agreement on the storage step and on the shm step
+    restore_agree_s: float = 0.0
+    restore_lock_wait_s: float = 0.0  # blocking acquire of the shard lock
+    restore_shm_verify_s: float = 0.0  # crc pass over the shm records
+    restore_storage_read_s: float = 0.0  # 0 on the shm path
+    # records -> device, to block_until_ready of the restored state
+    restore_h2d_s: float = 0.0
+
+    def set_restore(self, record: Optional[Dict[str, float]]):
+        """Fold ``CheckpointEngine.last_restore`` in (None = no load)."""
+        for key, value in (record or {}).items():
+            if key in RESTORE_FIELDS:
+                setattr(self, key, value)
 
     @property
     def prefetch_overlap_pct(self) -> Optional[float]:
@@ -191,6 +211,9 @@ class PipelineStats:
             "grad_bytes_raw": self.grad_bytes_raw,
             "grad_bytes_wire_vs_raw": self.grad_bytes_wire_vs_raw,
         }
+        for key in RESTORE_FIELDS:
+            value = getattr(self, key)
+            d[key] = round(value, 4) if isinstance(value, float) else value
         return d
 
     def summary(self) -> str:
@@ -226,6 +249,15 @@ class PipelineStats:
             if self.grad_bytes_raw
             else (f", grad sync{path}" if self.grad_sync_path else "")
         )
+        restore = (
+            f", restored {self.restore_bytes >> 20} MiB from "
+            f"{('-', 'shm', 'storage')[self.restore_source]} (verify "
+            f"{self.restore_storage_verify_s + self.restore_shm_verify_s:.2f}"
+            f" s, read {self.restore_storage_read_s:.2f} s, to device "
+            f"{self.restore_h2d_s:.2f} s)"
+            if self.restore_source
+            else ""
+        )
         return (
             f"prefetch {self.prefetch_hits}h/{self.prefetch_misses}m"
             f" ({'-' if ov is None else ov}% overlap), "
@@ -233,8 +265,141 @@ class PipelineStats:
             f"chunks ({self.stage_block_s * 1e3:.1f} ms on critical "
             f"path, {self.stage_commits} commits), donated "
             f"{self.donated_bytes >> 20} MiB over {self.donated_steps} "
-            f"steps ({self.safe_steps} safe){resize}{gsync}"
+            f"steps ({self.safe_steps} safe){resize}{gsync}{restore}"
         )
+
+
+RESTORE_FIELDS = (
+    "restore_source", "restore_bytes", "restore_storage_verify_s",
+    "restore_agree_s", "restore_lock_wait_s", "restore_shm_verify_s",
+    "restore_storage_read_s", "restore_h2d_s",
+)
+
+
+# -- the profiler's clock and XLA's compile work ---------------------------
+
+
+def install_profiler_mirror(tracer=None):
+    """Give the span tracer its twin on the profiler's clock
+    (``SpanTracer.set_mirror``): every span becomes a
+    ``jax.profiler.TraceAnnotation`` of the same name, a ``step`` span
+    that carries a host-side ``step_num`` a ``StepTraceAnnotation``
+    ("train"). Called once by the process that holds the chip; whoever
+    starts ``jax.profiler`` later (``ProfilerCapture``, a benchmark)
+    then finds the host's spans on the train thread's line of
+    ``/host:CPU``, on the clock of ``XLA Ops``. With no session live
+    an annotation is one atomic load."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+    from dlrover_tpu.obs.trace import get_tracer
+
+    def annotation(name, attrs):
+        if name == "step" and attrs and "step_num" in attrs:
+            return StepTraceAnnotation("train", step_num=attrs["step_num"])
+        return TraceAnnotation(name)
+
+    (tracer if tracer is not None else get_tracer()).set_mirror(annotation)
+
+
+class CompileMeter:
+    """Process-wide totals of XLA's compile work, from ``jax.monitoring``
+    (seconds in the backend's compile and in retrieval from the
+    persistent cache, cache hits and misses), and the list of
+    ``build:<what>`` spans that bracket the trainer's build sites with
+    the change of those totals across each: which programs an
+    incarnation compiled, and which it found in the cache."""
+
+    _DURATIONS = {
+        "/jax/core/compile/backend_compile_duration": "compile_s",
+        "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    }
+    _EVENTS = {
+        "/jax/compilation_cache/cache_hits": "cache_hits",
+        "/jax/compilation_cache/cache_misses": "cache_misses",
+    }
+
+    def __init__(self):
+        self.totals: Dict[str, float] = {
+            "compile_s": 0.0, "retrieval_s": 0.0, "compiles": 0,
+            "cache_hits": 0, "cache_misses": 0,
+        }
+        self.builds: List[Dict[str, Any]] = []
+        self._installed = False
+
+    def install(self):
+        if self._installed:
+            return
+        self._installed = True
+        import jax.monitoring
+
+        def on_duration(event, secs, **_):
+            key = self._DURATIONS.get(event)
+            if key is not None:
+                self.totals[key] += secs
+                if key == "compile_s":
+                    self.totals["compiles"] += 1
+
+        def on_event(event, **_):
+            key = self._EVENTS.get(event)
+            if key is not None:
+                self.totals[key] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(on_event)
+
+    def build(self, what: str) -> "_BuildSpan":
+        """``with meter.build("init"):`` — a ``build:init`` span whose
+        attributes are the change of the totals across it."""
+        self.install()
+        return _BuildSpan(self, what)
+
+
+class _BuildSpan:
+    __slots__ = ("_meter", "_what", "_before", "_t0", "_span")
+
+    def __init__(self, meter: CompileMeter, what: str):
+        self._meter = meter
+        self._what = what
+
+    def __enter__(self):
+        from dlrover_tpu.obs.trace import span
+
+        self._before = dict(self._meter.totals)
+        self._span = span(f"build:{self._what}")
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        row = {
+            k: round(v - self._before[k], 4)
+            for k, v in self._meter.totals.items()
+        }
+        row["seconds"] = round(time.perf_counter() - self._t0, 4)
+        self._span.set(**row)
+        self._span.end()
+        self._meter.builds.append({"what": self._what, **row})
+        return False
+
+
+def describe_builds(rows: List[Dict[str, Any]]) -> str:
+    """One clause per ``build:<what>`` span: its wall seconds, the
+    programs that went through XLA's compile-or-load and their seconds
+    (a cache hit is counted there too, with its retrieval), and the
+    persistent cache's hits and misses."""
+    return "; ".join(
+        f"{b['what']} {b['seconds']:.2f}s ("
+        f"{b['compiles']:.0f} programs {b['compile_s']:.2f}s, "
+        f"{b['cache_hits']:.0f} hits {b['retrieval_s']:.2f}s, "
+        f"{b['cache_misses']:.0f} misses)"
+        for b in rows
+    )
+
+
+_compile_meter = CompileMeter()
+
+
+def compile_meter() -> CompileMeter:
+    return _compile_meter
 
 
 @dataclass

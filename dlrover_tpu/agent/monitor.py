@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import threading
 import time
 
 from dlrover_tpu.common.constants import ConfigPath
@@ -55,7 +56,11 @@ def atomic_write_json(path: str, payload, durable: bool = False) -> None:
     d = os.path.dirname(path)
     if d:
         os.makedirs(d, exist_ok=True)
-    tmp = f"{path}.tmp.{os.getpid()}"
+    # one temporary name per WRITER, not per process: two threads of one
+    # process publish the same file (the trainer's span heartbeat and
+    # its loop's report), and with a shared name one's rename would
+    # take the other's file from under it
+    tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
     with open(tmp, "w") as f:
         json.dump(payload, f)
         if durable:

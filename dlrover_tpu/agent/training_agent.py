@@ -19,6 +19,7 @@ the goodput-critical state machine:
 
 from __future__ import annotations
 
+import json
 import os
 import signal
 import socket
@@ -38,6 +39,7 @@ from dlrover_tpu.common.constants import (
     TrainingExceptionLevel,
 )
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.obs.trace import TimedSpan, span
 from dlrover_tpu.utils.env import ensure_framework_on_pythonpath
 
 
@@ -305,9 +307,14 @@ class ElasticTrainingAgent:
         world = self._rendezvous()
         self._start_workers(world)
         last_heartbeat = 0.0
+        last_poll = time.monotonic()
         while not self._stop_event.is_set():
             time.sleep(spec.monitor_interval)
             state = self._monitor_workers()
+            # how long the workers went unwatched before this poll: a
+            # death it finds happened somewhere in that tick
+            now = time.monotonic()
+            tick_s, last_poll = now - last_poll, now
 
             if time.time() - last_heartbeat > 15:
                 last_heartbeat = time.time()
@@ -319,7 +326,9 @@ class ElasticTrainingAgent:
                     self._stop_workers()
                     return RunResult(WorkerState.STOPPED, self._restart_count)
                 if action == "restart":
-                    self._restart_workers(count_restart=False)
+                    self._restart_workers(
+                        count_restart=False, reason="master_request"
+                    )
                     continue
 
             if state == WorkerState.SUCCEEDED:
@@ -345,7 +354,10 @@ class ElasticTrainingAgent:
                     return RunResult(
                         WorkerState.FAILED, self._restart_count, err
                     )
-                self._restart_workers(count_restart=True)
+                self._restart_workers(
+                    count_restart=True, reason="worker_failure",
+                    tick_s=tick_s,
+                )
                 continue
 
             # membership change: new nodes waiting => restart into a bigger
@@ -359,38 +371,83 @@ class ElasticTrainingAgent:
                     f"node {self._node_rank}: membership change "
                     f"({waiting} nodes waiting); restarting workers"
                 )
-                self._restart_workers(count_restart=False)
+                self._restart_workers(
+                    count_restart=False, reason="membership_change"
+                )
 
         self._stop_workers()
         return RunResult(WorkerState.STOPPED, self._restart_count)
 
-    def _restart_workers(self, count_restart: bool):
+    def _restart_workers(
+        self,
+        count_restart: bool,
+        reason: str = "",
+        tick_s: Optional[float] = None,
+    ):
         """Parity: _restart_workers training.py:652 + save-at-breakpoint
-        (training.py:614-623): persist any in-memory checkpoint first."""
-        if self._ckpt_hook is not None:
-            try:
-                logger.info(f"node {self._node_rank}: save-at-breakpoint")
-                self._ckpt_hook()
-            except Exception as e:
-                logger.warning(f"save-at-breakpoint failed: {e!r}")
-        logger.info(f"node {self._node_rank}: stopping workers for restart")
-        self._stop_workers()
-        logger.info(f"node {self._node_rank}: workers stopped")
-        # a worker killed mid-staging leaves its shm shard lock held;
-        # release orphaned locks before the new generation starts saving
-        # (parity: reset_shared_memory ckpt_saver.py:527)
-        try:
-            from dlrover_tpu.ckpt.saver import AsyncCheckpointSaver
+        (training.py:614-623): persist any in-memory checkpoint first.
 
-            AsyncCheckpointSaver.reset_shared_memory_if_any()
-        except Exception as e:
-            logger.warning(f"shard-lock reset failed: {e!r}")
-        if count_restart:
-            self._restart_count += 1
-        else:
-            self._membership_restarts += 1
-        world = self._rendezvous()
-        self._start_workers(world)
+        The restart is one ``recover`` span with a child per leg, and
+        the legs' seconds go to the log as ONE line (``recovery
+        timeline: {...}``) once the new workers are started: where the
+        time between a death and the new worker's start went.
+        ``tick_s`` is the monitor tick in which the failure was found
+        (the detection's own share, spent before this call)."""
+        timeline: Dict[str, object] = {
+            "reason": reason, "restart": self._restart_count,
+        }
+        if tick_s is not None:
+            timeline["detect_tick_s"] = round(tick_s, 3)
+        t0 = time.monotonic()
+
+        def leg(name):
+            return TimedSpan(timeline, name + "_s")
+
+        with span("recover", **timeline):
+            if self._ckpt_hook is not None:
+                with leg("persist_before_restart"):
+                    try:
+                        logger.info(
+                            f"node {self._node_rank}: save-at-breakpoint"
+                        )
+                        self._ckpt_hook()
+                    except Exception as e:
+                        logger.warning(
+                            f"save-at-breakpoint failed: {e!r}"
+                        )
+            logger.info(
+                f"node {self._node_rank}: stopping workers for restart"
+            )
+            with leg("stop_workers"):
+                self._stop_workers()
+            logger.info(f"node {self._node_rank}: workers stopped")
+            # a worker killed mid-staging leaves its shm shard lock
+            # held; release orphaned locks before the new generation
+            # starts saving (parity: reset_shared_memory
+            # ckpt_saver.py:527)
+            with leg("shm_lock_reset"):
+                try:
+                    from dlrover_tpu.ckpt.saver import (
+                        AsyncCheckpointSaver,
+                    )
+
+                    AsyncCheckpointSaver.reset_shared_memory_if_any()
+                except Exception as e:
+                    logger.warning(f"shard-lock reset failed: {e!r}")
+            if count_restart:
+                self._restart_count += 1
+            else:
+                self._membership_restarts += 1
+            with leg("rendezvous"):
+                world = self._rendezvous()
+            with leg("start_workers"):
+                self._start_workers(world)
+        timeline["total_s"] = time.monotonic() - t0
+        rounded = {
+            k: round(v, 3) if isinstance(v, float) else v
+            for k, v in timeline.items()
+        }
+        logger.info(f"recovery timeline: {json.dumps(rounded)}")
 
     def stop(self):
         self._stop_event.set()

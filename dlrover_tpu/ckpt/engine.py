@@ -40,7 +40,7 @@ from dlrover_tpu.ckpt.sharding import (
     restore_state,
 )
 from dlrover_tpu.ckpt.shm_handler import ShmHandler
-from dlrover_tpu.obs.trace import span
+from dlrover_tpu.obs.trace import TimedSpan, span
 
 
 def _env_int(name: str, default: int) -> int:
@@ -55,6 +55,14 @@ def _overlaps(a, b) -> bool:
     if len(a) != len(b):
         return False
     return all(max(alo, blo) < min(ahi, bhi) for (alo, ahi), (blo, bhi) in zip(a, b)) if a else True
+
+
+def _ready(state: Any) -> Any:
+    """Block until every restored leaf is on its device: the transfer
+    is part of the restore, whoever times it."""
+    import jax
+
+    return jax.block_until_ready(state)
 
 
 class ChunkedStager:
@@ -147,10 +155,11 @@ class ChunkedStager:
         # the plan holds live references to every device shard: the
         # buffers stay alive (and unmutated — jax.Array is immutable)
         # until the drain finishes, whatever the caller does to `state`
-        self._plan = host_shard_plan(state)
-        self._metas = ShmHandler.layout_records(
-            [rec for rec, _ in self._plan]
-        )
+        with span("ckpt_begin_plan"):
+            self._plan = host_shard_plan(state)
+            self._metas = ShmHandler.layout_records(
+                [rec for rec, _ in self._plan]
+            )
         self.total_bytes = sum(m.nbytes for m in self._metas)
         self._staged_bytes = 0
         self.chunks_written = 0
@@ -164,7 +173,8 @@ class ChunkedStager:
         self._inflight = None  # (rec_idx, byte_offset, nbytes, producer)
         self._finished = False
         self._failed = False
-        self._engine._shm.begin_save(max(self.total_bytes, 1))
+        with span("ckpt_begin_shm"):
+            self._engine._shm.begin_save(max(self.total_bytes, 1))
 
     # -- introspection -------------------------------------------------
     @property
@@ -282,18 +292,24 @@ class ChunkedStager:
         """Consume the inflight group (start the next one's D2H first so
         the transfer overlaps this memcpy). Returns bytes written."""
         if self._inflight is None:
-            self._inflight = self._start_next()
+            with span("stage_d2h_issue"):
+                self._inflight = self._start_next()
             if self._inflight is None:
                 return 0
         group = self._inflight
         stripes = self._group_stripes(group)
-        self._inflight = self._start_next()
+        with span("stage_d2h_issue"):
+            self._inflight = self._start_next()
         written = 0
         shm = self._engine._shm
         for idx, offset, nbytes, src in group:
-            data = (
-                src if isinstance(src, np.ndarray) else np.asarray(src)
-            )
+            # the first touch: blocks until the group's device→host
+            # copy (started one group ahead) has landed
+            with span("stage_d2h_wait"):
+                data = (
+                    src if isinstance(src, np.ndarray)
+                    else np.asarray(src)
+                )
             # fold the chunk into the record's running crc BEFORE
             # write_chunk (whose ckpt.shm_stage fault point corrupts):
             # per-record writes are in offset order, so the incremental
@@ -307,20 +323,24 @@ class ChunkedStager:
                 # the single-rail incremental fold
                 from dlrover_tpu.parallel import transfer_sched
 
-                rep = self._striper.run(
-                    lambda rail, off, ln, _o=offset, _f=flat: (
-                        shm.write_chunk(_o + off, _f[off:off + ln])
-                    ),
-                    payload=flat,
-                )
+                # crc and copy run together, chunk by chunk per rail
+                with span("stage_shm_copy", striped=True):
+                    rep = self._striper.run(
+                        lambda rail, off, ln, _o=offset, _f=flat: (
+                            shm.write_chunk(_o + off, _f[off:off + ln])
+                        ),
+                        payload=flat,
+                    )
                 self._crcs[idx] = transfer_sched.crc32_combine(
                     self._crcs.get(idx, 0), rep.crc32, flat.nbytes
                 )
             else:
-                self._crcs[idx] = zlib.crc32(
-                    flat, self._crcs.get(idx, 0)
-                )
-                shm.write_chunk(offset, data)
+                with span("stage_crc"):
+                    self._crcs[idx] = zlib.crc32(
+                        flat, self._crcs.get(idx, 0)
+                    )
+                with span("stage_shm_copy"):
+                    shm.write_chunk(offset, data)
             written += nbytes
         self._staged_bytes += written
         self.chunks_written += 1
@@ -347,7 +367,8 @@ class ChunkedStager:
             with span("ckpt_stage", step=self.step):
                 while not self.done:
                     if self._inflight is None:
-                        self._inflight = self._start_next()
+                        with span("stage_d2h_issue"):
+                            self._inflight = self._start_next()
                         if self._inflight is None:
                             break
                     if budget_s is not None and self._may_defer(
@@ -370,11 +391,13 @@ class ChunkedStager:
                         copied += self._write_one()
                         grant = None
                     else:
-                        with self._stream.transfer(
-                            nbytes,
-                            priority=self._priority,
-                            ignore_window=True,
-                        ) as grant:
+                        with span("stage_grant_wait"):
+                            grant = self._stream.transfer(
+                                nbytes,
+                                priority=self._priority,
+                                ignore_window=True,
+                            )
+                        with grant:
                             copied += self._write_one()
                     if (
                         budget_s is not None
@@ -508,6 +531,8 @@ class CheckpointEngine:
         self._lock: Optional[SharedLock] = None
         self._staging_threads: list = []
         self._active_stager = None
+        # phases of the last ``load`` (None until one ran)
+        self.last_restore: Optional[Dict[str, float]] = None
         if self._agent_mode:
             self._shm = ShmHandler(self.local_rank, create=False)
             self._queue = SharedQueue(saver_mod.CKPT_EVENT_QUEUE)
@@ -589,7 +614,9 @@ class CheckpointEngine:
         (default ``transfer_sched.DEFAULT_STRIPE_MIN_BYTES``)."""
         if self._agent_mode:
             assert self._lock and self._shm and self._queue
-            if not self._lock.acquire(blocking=False):
+            with span("ckpt_begin_lock"):
+                got = self._lock.acquire(blocking=False)
+            if not got:
                 logger.warning(
                     f"step {step}: saver busy persisting a previous "
                     f"checkpoint; skipping this chunked save"
@@ -830,9 +857,19 @@ class CheckpointEngine:
         ranks' shallow check may still name the corrupt newer step —
         without the min they would restore different steps (or read a
         step dir mid-quarantine-rename)."""
-        committed = self._agree_committed(
-            self.latest_verified_step(checkpoint_dir)
-        )
+        # the phases of this load, timed where they happen: the trainer
+        # folds the record into ``PipelineStats`` (``restore_*``), and
+        # each phase is a span named like its field less the ``_s``
+        rec = self.last_restore = {
+            "restore_source": 0, "restore_bytes": 0,
+            "restore_storage_verify_s": 0.0, "restore_agree_s": 0.0,
+            "restore_lock_wait_s": 0.0, "restore_shm_verify_s": 0.0,
+            "restore_storage_read_s": 0.0, "restore_h2d_s": 0.0,
+        }
+        with TimedSpan(rec, "restore_storage_verify_s"):
+            verified = self.latest_verified_step(checkpoint_dir)
+        with TimedSpan(rec, "restore_agree_s"):
+            committed = self._agree_committed(verified)
         # propose this host's usable shm step (-1 = none). The shard lock
         # guards against reading shm mid-rewrite by an in-flight
         # block=False staging thread or the persisting saver; a lock
@@ -841,10 +878,11 @@ class CheckpointEngine:
         records = []
         got_lock = False
         if prefer_memory and self._agent_mode and self._shm is not None:
-            try:
-                got_lock = self._lock.acquire(blocking=True)
-            except (TimeoutError, RuntimeError):
-                got_lock = False
+            with TimedSpan(rec, "restore_lock_wait_s"):
+                try:
+                    got_lock = self._lock.acquire(blocking=True)
+                except (TimeoutError, RuntimeError):
+                    got_lock = False
             if got_lock:
                 try:
                     # zero-copy views: consumed (packed into transfer
@@ -852,9 +890,10 @@ class CheckpointEngine:
                     # lock is released in the finally. verify=True: a
                     # corrupt segment (bit rot, partial staging) raises
                     # ValueError and the proposal downgrades to -1
-                    shm_step, records, _ = self._shm.load_records(
-                        copy=False, verify=True
-                    )
+                    with TimedSpan(rec, "restore_shm_verify_s"):
+                        shm_step, records, _ = self._shm.load_records(
+                            copy=False, verify=True
+                        )
                     if shm_step >= committed and self._shm_covers(
                         records, target
                     ):
@@ -867,13 +906,19 @@ class CheckpointEngine:
             # whatever its agent/lock state — a host that failed to read
             # shm proposes -1 rather than skipping the allgather (which
             # would deadlock the others)
-            agreed = self._all_processes_agree(candidate)
+            with TimedSpan(rec, "restore_agree_s"):
+                agreed = self._all_processes_agree(candidate)
             if agreed and candidate >= 0:
                 for r in records:
                     by_path.setdefault(r.path, []).append(r)
                 try:
-                    state = restore_state(
-                        target, lambda p: by_path.get(p, [])
+                    with TimedSpan(rec, "restore_h2d_s"):
+                        state = _ready(restore_state(
+                            target, lambda p: by_path.get(p, [])
+                        ))
+                    rec["restore_source"] = 1
+                    rec["restore_bytes"] = sum(
+                        int(r.data.nbytes) for r in records
                     )
                     logger.info(f"restored step {candidate} from memory")
                     return candidate, state
@@ -898,6 +943,9 @@ class CheckpointEngine:
                 self._lock.force_release()
         if committed < 0:
             return -1, None
+        # a failed shm attempt's seconds stay in the record: they were
+        # spent, and restore_source says which path gave the state
+        rec["restore_source"] = 2
         return committed, self._load_from_storage(
             target, checkpoint_dir, committed
         )
@@ -964,22 +1012,30 @@ class CheckpointEngine:
         files = [
             f for f in self.storage.listdir(sdir) if f.endswith(".ckpt")
         ]
-        needed = self._filter_needed_shards(sdir, files, target)
+        phases = self.last_restore if self.last_restore is not None else {}
         by_path: Dict[str, list] = {}
-        for fname in needed:
-            payload = self.storage.read_state_dict(
-                os.path.join(sdir, fname)
-            )
-            for m in payload["records"]:
-                rec = ShardRecord(
-                    path=m["path"],
-                    global_shape=tuple(m["global_shape"]),
-                    dtype=m["dtype"],
-                    index=tuple(tuple(i) for i in m["index"]),
-                    data=m["data"],
+        with TimedSpan(phases, "restore_storage_read_s"):
+            needed = self._filter_needed_shards(sdir, files, target)
+            for fname in needed:
+                payload = self.storage.read_state_dict(
+                    os.path.join(sdir, fname)
                 )
-                by_path.setdefault(rec.path, []).append(rec)
-        return restore_state(target, lambda p: by_path.get(p, []))
+                for m in payload["records"]:
+                    rec = ShardRecord(
+                        path=m["path"],
+                        global_shape=tuple(m["global_shape"]),
+                        dtype=m["dtype"],
+                        index=tuple(tuple(i) for i in m["index"]),
+                        data=m["data"],
+                    )
+                    by_path.setdefault(rec.path, []).append(rec)
+                    phases["restore_bytes"] = phases.get(
+                        "restore_bytes", 0
+                    ) + int(getattr(rec.data, "nbytes", 0))
+        with TimedSpan(phases, "restore_h2d_s"):
+            return _ready(
+                restore_state(target, lambda p: by_path.get(p, []))
+            )
 
     def _filter_needed_shards(self, sdir, files, target):
         """Use the .idx sidecars to read only shard files overlapping this

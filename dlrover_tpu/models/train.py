@@ -549,13 +549,14 @@ def build_train_step(
                 # dense grads are nonzero only on ep rank 0 (the loss
                 # seed) — psum over ep is selection, not averaging
                 g_leaves[i] = jax.lax.psum(g_leaves[i], "ep")
-            e_synced, ss_e = sync_local_tree(
-                [g_leaves[i] for i in plan.expert_leaf_ids],
-                plan.expert_plan,
-            )
-            d_synced, ss_d = sync_local_tree(
-                [g_leaves[i] for i in dense_ids], plan.dense_plan
-            )
+            with jax.named_scope("scope/grad_sync"):
+                e_synced, ss_e = sync_local_tree(
+                    [g_leaves[i] for i in plan.expert_leaf_ids],
+                    plan.expert_plan,
+                )
+                d_synced, ss_d = sync_local_tree(
+                    [g_leaves[i] for i in dense_ids], plan.dense_plan
+                )
             out = [None] * len(g_leaves)
             for i, gl in zip(plan.expert_leaf_ids, e_synced):
                 out[i] = gl
@@ -697,17 +698,19 @@ def build_train_step(
                     * sv.reshape((plan.total,) + (1,) * (g.ndim - 1)),
                     g_stacked,
                 )
-            grads, new_residual, gnorm, dev_norms = sync_grads(
-                g_stacked,
-                mesh,
-                plan,
-                residual=residual,
-                device_norms=True,
-            )
+            with jax.named_scope("scope/grad_sync"):
+                grads, new_residual, gnorm, dev_norms = sync_grads(
+                    g_stacked,
+                    mesh,
+                    plan,
+                    residual=residual,
+                    device_norms=True,
+                )
         else:
-            grads, new_residual, gnorm = sync_grads(
-                g_stacked, mesh, plan, residual=residual
-            )
+            with jax.named_scope("scope/grad_sync"):
+                grads, new_residual, gnorm = sync_grads(
+                    g_stacked, mesh, plan, residual=residual
+                )
         grads = jax.tree_util.tree_map(
             lambda g, sh: jax.lax.with_sharding_constraint(g, sh),
             grads,
@@ -759,7 +762,9 @@ def build_train_step(
             (loss, aux), grads = grads_and_loss(
                 state.params, tokens, targets
             )
-        return loss, aux, grads, optax.global_norm(grads), None
+        with jax.named_scope("scope/grad_norm"):
+            gnorm = optax.global_norm(grads)
+        return loss, aux, grads, gnorm, None
 
     def train_step(state: TrainState, tokens, targets):
         dev_norms = None
@@ -781,12 +786,16 @@ def build_train_step(
             from dlrover_tpu.ops.host_offload import fetch_tree
 
             opt_state = fetch_tree(opt_state, opt_sh)
-        updates, new_opt = tx.update(grads, opt_state, state.params)
+        # stable names on the device (see models/transformer.py): the
+        # optimizer pass here holds whatever the chain clips by too
+        with jax.named_scope("scope/optimizer"):
+            updates, new_opt = tx.update(grads, opt_state, state.params)
         if offload_opt_state:
             from dlrover_tpu.ops.host_offload import offload_tree
 
             new_opt = offload_tree(new_opt, opt_sh)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("scope/optimizer"):
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, "grad_norm": gnorm}
         if dev_norms is not None:
             # SDC tier-1 fence input: each lane's LOCAL pre-sync grad

@@ -290,6 +290,7 @@ def _causal_attention(q, k, v, mesh=None, layout: str = "bthd"):
     )(q, k, v)
 
 
+@jax.named_scope("scope/layer/attn")
 def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions):
     h = _norm(x, layer["attn_norm"], cfg)
     sp = mesh is not None and mesh.shape.get("sp", 1) > 1
@@ -340,6 +341,7 @@ def _zero_aux(cfg: Optional[TransformerConfig] = None):
     return aux
 
 
+@jax.named_scope("scope/layer/mlp")
 def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None):
     h = _norm(x, layer["mlp_norm"], cfg)
     if "moe" in layer:
@@ -427,6 +429,7 @@ def _embed_lookup_bwd(mesh, res, dx):
 _embed_lookup.defvjp(_embed_lookup_fwd, _embed_lookup_bwd)
 
 
+@jax.named_scope("scope/embed")
 def embed_tokens(
     params: Params, tokens: jnp.ndarray, cfg: TransformerConfig, mesh=None
 ):
@@ -439,21 +442,30 @@ def embed_tokens(
     return x
 
 
+@jax.named_scope("scope/final_norm")
+def _final_norm(params: Params, x: jnp.ndarray, cfg: TransformerConfig):
+    return _norm(x, params["final_norm"], cfg)
+
+
 def lm_head(params: Params, x: jnp.ndarray, cfg: TransformerConfig):
     """final residual [B,T,D] → logits [B,T,vocab] fp32 (incl. final norm)."""
     dt = _dtype(cfg)
-    x = _norm(x, params["final_norm"], cfg)
-    if cfg.tie_embeddings:
-        w = params["embed"]["tokens"].astype(dt)
-        logits = jnp.einsum("btd,vd->btv", x, w)
-    else:
-        logits = jnp.einsum("btd,dv->btv", x, params["lm_head"].astype(dt))
-    logits = logits.astype(jnp.float32)
-    if cfg.mup_output_mult != 1.0:
-        logits = logits * cfg.mup_output_mult
+    x = _final_norm(params, x, cfg)
+    with jax.named_scope("scope/lm_head"):
+        if cfg.tie_embeddings:
+            w = params["embed"]["tokens"].astype(dt)
+            logits = jnp.einsum("btd,vd->btv", x, w)
+        else:
+            logits = jnp.einsum(
+                "btd,dv->btv", x, params["lm_head"].astype(dt)
+            )
+        logits = logits.astype(jnp.float32)
+        if cfg.mup_output_mult != 1.0:
+            logits = logits * cfg.mup_output_mult
     return logits
 
 
+@jax.named_scope("scope/xent")
 def token_nll(
     logits: jnp.ndarray, targets: jnp.ndarray, row_weights=None
 ) -> jnp.ndarray:
@@ -523,7 +535,7 @@ def forward(
             aux_total = jax.tree_util.tree_map(jnp.add, aux_total, aux)
 
     if return_hidden:
-        return _norm(x, params["final_norm"], cfg), aux_total
+        return _final_norm(params, x, cfg), aux_total
     return lm_head(params, x, cfg), aux_total
 
 

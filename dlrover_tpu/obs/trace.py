@@ -77,9 +77,11 @@ class _OpenSpan:
 
     __slots__ = (
         "_tracer", "name", "start_ns", "depth", "attrs", "_tid", "_done",
+        "_twin",
     )
 
-    def __init__(self, tracer, name, start_ns, depth, attrs, tid):
+    def __init__(self, tracer, name, start_ns, depth, attrs, tid,
+                 twin=None):
         self._tracer = tracer
         self.name = name
         self.start_ns = start_ns
@@ -87,6 +89,17 @@ class _OpenSpan:
         self.attrs = attrs
         self._tid = tid
         self._done = False
+        self._twin = twin  # the mirror's entered context manager
+
+    def _leave_twin(self):
+        twin = self._twin
+        if twin is None:
+            return
+        self._twin = None
+        try:
+            twin.__exit__(None, None, None)
+        except Exception:
+            pass  # the mirror must never hurt the span stream
 
     def set(self, **attrs):
         """Attach/override attributes before the span ends (e.g. the
@@ -138,6 +151,17 @@ class SpanTracer:
         # processes/hosts onto one master-timestamp axis
         self._wall_t0 = time.time()
         self._pid = os.getpid()
+        self._mirror: Optional[Callable[[str, Optional[dict]], Any]] = None
+
+    def set_mirror(
+        self, factory: Optional[Callable[[str, Optional[dict]], Any]]
+    ):
+        """``factory(name, attrs)`` -> context manager entered when a
+        span opens and left when it ends or is cancelled, on the
+        opening thread (None = no mirror). Installed once by the
+        process that holds the chip; spans already open keep whatever
+        twin they were opened with."""
+        self._mirror = factory
 
     # -- hot path ------------------------------------------------------
     def span(self, name: str, **attrs):
@@ -154,25 +178,39 @@ class SpanTracer:
         if stack is None:
             stack = self._stacks[tid] = []
             self._thread_names[tid] = threading.current_thread().name
+        twin = None
+        if self._mirror is not None:
+            try:
+                twin = self._mirror(name, attrs or None)
+                twin.__enter__()
+            except Exception:
+                twin = None
         sp = _OpenSpan(
             self, name, time.monotonic_ns(), len(stack),
-            attrs or None, tid,
+            attrs or None, tid, twin,
         )
         stack.append(sp)
         return sp
+
+    @staticmethod
+    def _unwind(stack, sp: _OpenSpan):
+        """Pop ``sp`` and everything above it (an inner span leaked
+        open: its record is lost, which is the observable symptom of
+        the caller's bug), leaving each one's twin, innermost first."""
+        if stack and sp in stack:
+            while stack:
+                top = stack.pop()
+                top._leave_twin()
+                if top is sp:
+                    return
+        sp._leave_twin()
 
     def _end(self, sp: _OpenSpan):
         if sp._done:
             return  # idempotent: a double end must not duplicate records
         sp._done = True
         dur_ns = time.monotonic_ns() - sp.start_ns
-        stack = self._stacks.get(sp._tid)
-        if stack and sp in stack:
-            # tolerate out-of-order ends (an inner span leaked open):
-            # drop everything above sp — their records are lost, which
-            # is the observable symptom of the caller's bug
-            while stack and stack.pop() is not sp:
-                pass
+        self._unwind(self._stacks.get(sp._tid), sp)
         with self._end_lock:
             self._buf.append(
                 (
@@ -186,10 +224,7 @@ class SpanTracer:
         if sp._done:
             return
         sp._done = True
-        stack = self._stacks.get(sp._tid)
-        if stack and sp in stack:
-            while stack and stack.pop() is not sp:
-                pass
+        self._unwind(self._stacks.get(sp._tid), sp)
 
     def traced(self, name: Optional[str] = None) -> Callable:
         """Decorator form: ``@tracer.traced("load_config")``."""
@@ -435,6 +470,33 @@ def traced(name: Optional[str] = None) -> Callable:
 
 def enable(on: bool = True):
     _default.enabled = bool(on)
+
+
+class TimedSpan:
+    """``with TimedSpan(record, "restore_h2d_s"):`` — one phase of a
+    longer operation, recorded twice: as a span on the default tracer
+    (named like the key less its ``_s``, or ``name``), and as seconds
+    ADDED to ``record[key]``, for whoever folds the record into
+    counters or a log line (a restore's phases, a recovery's legs)."""
+
+    __slots__ = ("_record", "_key", "_name", "_span", "_t0")
+
+    def __init__(self, record: dict, key: str, name: Optional[str] = None):
+        self._record = record
+        self._key = key
+        self._name = name or key.removesuffix("_s")
+
+    def __enter__(self):
+        self._span = _default.span(self._name)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._record[self._key] = self._record.get(self._key, 0.0) + (
+            time.perf_counter() - self._t0
+        )
+        self._span.end()
+        return False
 
 
 def last_open_span(tid: Optional[int] = None) -> Optional[Tuple[str, float]]:

@@ -213,6 +213,7 @@ def _fwd_pallas(
     )
     ot, lse4 = pl.pallas_call(
         kernel,
+        name="flash_attn_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -449,6 +450,7 @@ def _fused_fwd_call(qt, kt, vt, offsets, *, causal, mask_fn, sm_scale,
             sm_scale=sm_scale,
             n_heads=Hc,
         ),
+        name="flash_attn_fused_fwd",
         grid=(B, H // Hc),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), spec, spec, spec],
         out_specs=[spec, row_spec],
@@ -479,6 +481,7 @@ def _fused_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
             sm_scale=sm_scale,
             n_heads=Hc,
         ),
+        name="flash_attn_fused_bwd",
         grid=(B, H // Hc),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -741,6 +744,7 @@ def _bwd_pallas(
 
     dqt = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **common),
+        name="flash_attn_bwd_dq",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -781,6 +785,7 @@ def _bwd_pallas(
     )
     dk_full, dv_full = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **common),
+        name="flash_attn_bwd_dkv",
         grid=(B, H, nk, nq),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -20,6 +20,7 @@ facade owns the full elastic story so a user train script collapses to
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -52,6 +53,10 @@ from dlrover_tpu.obs.trace import SpanHeartbeat, span
 from dlrover_tpu.parallel import transfer_sched
 from dlrover_tpu.trainer.elastic.dataloader import ElasticDataLoader
 from dlrover_tpu.trainer.elastic.sampler import ElasticDistributedSampler
+
+
+# what ``_first_build`` hands back once a program is built
+_NO_BUILD = contextlib.nullcontext()
 
 
 @dataclass
@@ -311,6 +316,23 @@ class ElasticTrainer:
         # recompile, and the distributed system. A no-op where the
         # script has called it already
         init_elastic()
+        from dlrover_tpu.accel.profiler import (
+            compile_meter,
+            install_profiler_mirror,
+        )
+
+        # this process holds the chip: its spans get their twin on the
+        # profiler's clock, so whoever starts jax.profiler later (the
+        # master's `profile` command, a benchmark) finds the host's
+        # spans beside the device's operations in one trace
+        install_profiler_mirror()
+        # build:<what> spans bracket every site that may compile, with
+        # XLA's compile seconds and the persistent cache's hits and
+        # misses across each; the list is logged at the end of __init__
+        # (the steps' own programs build at their first call in train())
+        self._builds = compile_meter()
+        self._builds_logged = len(self._builds.builds)
+        self._built: set = set()
         self.tcfg = trainer_cfg or TrainerConfig()
         self._metrics_hook = metrics_hook
         # kept for the resize path: a new mesh rebuilds the accel
@@ -328,25 +350,26 @@ class ElasticTrainer:
             _sdc.set_enabled(True)
         # async flash staging reads state buffers after the step returns,
         # so the production step must NOT donate them
-        self.accel: AccelerateResult = auto_accelerate(
-            model_cfg,
-            tx,
-            batch=self.tcfg.batch_size,
-            seq=self.tcfg.seq_len,
-            devices=devices,
-            strategy=strategy,
-            donate=False,
-            grad_accum=self.tcfg.grad_accum,
-            optimizations=self._grad_sync_opt_names(),
-            # bucket size only when the trainer's knobs own the sync
-            # config — an explicit Strategy's own grad_bucket_mb wins
-            # otherwise
-            grad_bucket_mb=(
-                self.tcfg.grad_bucket_mb
-                if self._grad_sync_opt_names()
-                else None
-            ),
-        )
+        with self._builds.build("strategy"):
+            self.accel: AccelerateResult = auto_accelerate(
+                model_cfg,
+                tx,
+                batch=self.tcfg.batch_size,
+                seq=self.tcfg.seq_len,
+                devices=devices,
+                strategy=strategy,
+                donate=False,
+                grad_accum=self.tcfg.grad_accum,
+                optimizations=self._grad_sync_opt_names(),
+                # bucket size only when the trainer's knobs own the
+                # sync config — an explicit Strategy's own
+                # grad_bucket_mb wins otherwise
+                grad_bucket_mb=(
+                    self.tcfg.grad_bucket_mb
+                    if self._grad_sync_opt_names()
+                    else None
+                ),
+            )
         self.cfg = self.accel.cfg
         self.mesh = self.accel.mesh
         self._step_fn = self.accel.step_fn
@@ -467,7 +490,8 @@ class ElasticTrainer:
             # a platform that exports the deadline env expects SIGTERM
             # to mean "drain now" — install the handler automatically
             self.install_eviction_handler()
-        self.state = self.accel.init_fn(jax.random.PRNGKey(0))
+        with self._builds.build("init"):
+            self.state = self.accel.init_fn(jax.random.PRNGKey(0))
         self._grad_sync_plan = None
         # MoE capacity rebalancer (ISSUE 13): folds the measured
         # per-expert routing load into a periodic capacity re-split
@@ -490,8 +514,10 @@ class ElasticTrainer:
         # the dry-runner and the auto bucket sizer price wire time
         # from it instead of the flat-ICI constant
         self._link_fp: Optional[str] = None
-        self._setup_link_model()
-        self._setup_grad_sync()
+        with self._builds.build("link_probe"):
+            self._setup_link_model()
+        with self._builds.build("grad_sync"):
+            self._setup_grad_sync()
         self._setup_sdc()
         self._audit_cal_loaded = False
         self._setup_audit_budget()
@@ -540,13 +566,38 @@ class ElasticTrainer:
         self._last_best_save = 0.0
         if self.tcfg.ckpt_dir:
             self._ckptr = FlashCheckpointer(self.tcfg.ckpt_dir)
-            self._maybe_restore()
+            # the restore's own programs (the packed put's unpack, the
+            # residual's zeros) build here
+            with self._builds.build("restore"):
+                self._maybe_restore()
             if self.tcfg.save_best:
                 self._best_dir = os.path.join(
                     self.tcfg.ckpt_dir, "best"
                 )
                 self._best_ckptr = FlashCheckpointer(self._best_dir)
                 self._best_eval_loss = self._load_best_sidecar()
+        self._log_builds("at start")
+
+    def _log_builds(self, when: str):
+        """One line for the build:<what> spans since the last such line:
+        what this incarnation compiled, and what the cache gave it."""
+        from dlrover_tpu.accel.profiler import describe_builds
+
+        rows = self._builds.builds[self._builds_logged:]
+        self._builds_logged = len(self._builds.builds)
+        if rows:
+            logger.info(
+                f"programs built {when}: {describe_builds(rows)}"
+            )
+
+    def _first_build(self, what: str):
+        """``build:<what>`` around the FIRST call of a jitted program
+        (jit compiles, or loads from the cache, inside that call);
+        afterwards nothing: no span, no lookup beyond one set test."""
+        if what in self._built:
+            return _NO_BUILD
+        self._built.add(what)
+        return self._builds.build(what)
 
     # -- measured link-cost model (parallel/topology.py) ----------------
     def _setup_link_model(self):
@@ -954,7 +1005,7 @@ class ElasticTrainer:
         if self._ckptr is not None:
             self._goodput.replay_begin()
             try:
-                tgt, restored = self._ckptr.load_checkpoint(
+                tgt, restored = self._load_checkpoint(
                     self._ckpt_state()
                 )
                 if restored is not None and tgt >= 0:
@@ -1045,6 +1096,7 @@ class ElasticTrainer:
         self._eval_step_fn = None
         self._aot_exec = self._aot_shapes = None
         self._aot_primed = False
+        self._built.clear()  # the new twins build at their first call
         self.pipeline_stats.moe_capacity_resplits += 1
         self._registry.gauge(
             "dlrover_moe_capacity_resplits",
@@ -1164,11 +1216,18 @@ class ElasticTrainer:
         # runs with different (or no) grad-sync settings
         return {"train": strip_residual(self.state), "sampler": samp}
 
+    def _load_checkpoint(self, target):
+        """``load_checkpoint``, with the phases the engine timed folded
+        into ``pipeline_stats`` (``restore_*``)."""
+        out = self._ckptr.load_checkpoint(target)
+        self.pipeline_stats.set_restore(self._ckptr.engine.last_restore)
+        return out
+
     def _maybe_restore(self):
         from dlrover_tpu.agent.monitor import read_runtime_metrics
         from dlrover_tpu.parallel.grad_sync import ensure_residual
 
-        step, restored = self._ckptr.load_checkpoint(self._ckpt_state())
+        step, restored = self._load_checkpoint(self._ckpt_state())
         if restored is not None and step >= 0:
             self.state = ensure_residual(
                 restored["train"], self._grad_sync_plan, self.mesh
@@ -1565,7 +1624,10 @@ class ElasticTrainer:
         losses = []
         for batch in self._eval_batches(max_batches):
             x, y = self._device_batch(batch, for_eval=True)
-            losses.append(float(self._eval_step_fn(self.state.params, x, y)))
+            with self._first_build("eval"):
+                losses.append(
+                    float(self._eval_step_fn(self.state.params, x, y))
+                )
         if not losses:
             # a silent NaN here would poison every later metrics report
             raise ValueError(
@@ -1814,13 +1876,6 @@ class ElasticTrainer:
                 or not self._best_ckptr.staging_in_flight()
             )
         )
-        if not donate and not self._aot_primed:
-            self._prime_step_cache(x, y)
-        fn = (
-            self._donating_step_fn
-            if donate
-            else self._safe_step_for(x, y)
-        )
         stats = self.pipeline_stats
         if donate:
             stats.donated_steps += 1
@@ -1829,7 +1884,22 @@ class ElasticTrainer:
             )
         else:
             stats.safe_steps += 1
-        self.state, metrics = fn(self.state, x, y)
+        # each twin is built by its first call (the safe one ahead of
+        # time through the AOT cache, the donating one inside jit)
+        with self._first_build("step_donating" if donate else "step_safe"):
+            if not donate and not self._aot_primed:
+                self._prime_step_cache(x, y)
+            fn = (
+                self._donating_step_fn
+                if donate
+                else self._safe_step_for(x, y)
+            )
+            # the call of the step function, to its return: argument
+            # handling on the host and the launch queued on the device
+            with span("dispatch"):
+                self.state, metrics = fn(self.state, x, y)
+        if self._builds_logged < len(self._builds.builds):
+            self._log_builds("by a first step")
         return metrics
 
     def _advance_stager(self):
@@ -1873,9 +1943,12 @@ class ElasticTrainer:
                 # a previous stage still draining keeps draining — skip
                 # this interval rather than stall on a forced commit
                 # (same skip-never-block contract as save_to_memory)
+                with span("ckpt_snapshot"):
+                    snapshot = self._ckpt_state()
+                # the engine names the legs of the begin (ckpt_begin_*)
                 self._stager = self._ckptr.begin_chunked_save(
                     step,
-                    self._ckpt_state(),
+                    snapshot,
                     chunk_bytes=self.tcfg.stage_chunk_mb << 20,
                 )
 
@@ -2246,7 +2319,7 @@ class ElasticTrainer:
                         f"ckpt_dir is configured for the host fallback "
                         f"(first: {report.fallback_paths[:3]})"
                     )
-                step0, restored = self._ckptr.load_checkpoint(
+                step0, restored = self._load_checkpoint(
                     {"train": spec, "sampler": self.sampler.state_dict()}
                 )
                 if restored is None or step0 < 0:
@@ -2286,6 +2359,7 @@ class ElasticTrainer:
         )
         self._step_fn = accel.step_fn
         self._eval_step_fn = None  # per-mesh memo re-resolves lazily
+        self._built.clear()  # the new twins build at their first call
         # link model: re-probe ONLY when the device fingerprint changed
         # (docs/elastic-resize.md) — a resize back onto the same
         # hardware reuses the cached probe and costs nothing here
@@ -2661,6 +2735,7 @@ class ElasticTrainer:
             # num_steps stop mid-epoch checkpoints the exact position
             # (modulo the prefetch rewind in _ckpt_state)
             batches = self._epoch_batches(num_steps)
+            host_step = self.global_step
             while True:
                 # step boundary = the preemption arrival point: the
                 # in-flight step is finished, nothing is half-donated.
@@ -2680,7 +2755,10 @@ class ElasticTrainer:
                 # escaping the body must CANCEL the span — a leaked
                 # open frame would poison hang attribution for the
                 # rest of the process (cancel after end is a no-op)
-                step_sp = span("step")
+                # step_num is the host's own count (the last step read
+                # plus one), never a device read: it names the step on
+                # the profiler's clock (StepTraceAnnotation)
+                step_sp = span("step", step_num=host_step + 1)
                 step_t0 = time.perf_counter()
                 try:
                     try:
@@ -2696,36 +2774,43 @@ class ElasticTrainer:
                         # off the inter-step host section
                         transfer_sched.note_compute(True)
                         try:
+                            # holds the `dispatch` span
                             metrics = self._run_step(x, y)
-                            # materializing the step count forces the
-                            # dispatched update on synchronous backends
-                            # — that wall time is compute, so it must
-                            # land inside this span
-                            step = self.global_step
+                            # the loop's one device read per step:
+                            # materializing the step count waits for
+                            # the dispatched update — that wall time
+                            # is compute, so it lands inside this span
+                            with span("device_wait"):
+                                step = host_step = self.global_step
                         finally:
                             transfer_sched.note_compute(False)
                     # interleave checkpoint chunks while the step
                     # computes (the engine emits its own ckpt_stage
-                    # span)
-                    self._advance_stager()
-                    # the per-lane norm vector is detector input, not
-                    # a reporting scalar — pop it before any consumer
-                    # that reports scalars sees it (same contract as
-                    # moe_expert_load)
-                    dev_norms = metrics.pop("sdc_device_norms", None)
-                    if self._sdc is not None:
-                        self._sdc_step(step, metrics, dev_norms)
-                    if self._metrics_hook is not None:
-                        self._metrics_hook(step, metrics)
-                    if (
-                        self._moe_rebalancer is not None
-                        and step % self.tcfg.moe_rebalance_interval
-                        == 0
-                        and "moe_expert_load" in metrics
-                    ):
-                        self._maybe_rebalance_experts(
-                            metrics["moe_expert_load"]
-                        )
+                    # span and the stage_* phases of each chunk)
+                    if self._stager is not None:
+                        with span("stage"):
+                            self._advance_stager()
+                    # what rides every step beside the program: the
+                    # SDC fence, the caller's hook, the MoE rebalance
+                    with span("hooks"):
+                        # the per-lane norm vector is detector input,
+                        # not a reporting scalar — pop it before any
+                        # consumer that reports scalars sees it (same
+                        # contract as moe_expert_load)
+                        dev_norms = metrics.pop("sdc_device_norms", None)
+                        if self._sdc is not None:
+                            self._sdc_step(step, metrics, dev_norms)
+                        if self._metrics_hook is not None:
+                            self._metrics_hook(step, metrics)
+                        if (
+                            self._moe_rebalancer is not None
+                            and step % self.tcfg.moe_rebalance_interval
+                            == 0
+                            and "moe_expert_load" in metrics
+                        ):
+                            self._maybe_rebalance_experts(
+                                metrics["moe_expert_load"]
+                            )
                     if step % self.tcfg.log_interval == 0:
                         # the only host sync in the loop: loss is
                         # materialized at log cadence, not every step

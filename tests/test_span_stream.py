@@ -1,0 +1,483 @@
+"""One span stream that explains a step and a restore (ISSUE 25).
+
+The trainer partitions its ``step`` span (``data_wait`` / ``compute`` =
+``dispatch`` + ``device_wait`` / ``stage`` / ``hooks`` / ``report`` /
+``ckpt_save``), the chunked stager names the phases of a chunk inside
+``ckpt_stage``, ``CheckpointEngine.load`` times its phases into
+``PipelineStats.restore_*``, every span has a twin on the profiler's
+clock (a mirror the process that holds the chip installs), the step
+program carries ``scope/<name>`` scopes as metadata only, and the agent
+logs a recovery as one timeline.
+"""
+
+import json
+import logging
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from dlrover_tpu.accel.profiler import (
+    RESTORE_FIELDS,
+    PipelineStats,
+    compile_meter,
+)
+from dlrover_tpu.accel.strategy import Strategy
+from dlrover_tpu.ckpt.engine import CheckpointEngine
+from dlrover_tpu.ckpt.saver import AsyncCheckpointSaver
+from dlrover_tpu.models import build_train_step, init_sharded_state, tiny
+from dlrover_tpu.obs.metrics import MetricsRegistry, fold_pipeline_stats
+from dlrover_tpu.obs.trace import SpanTracer, get_tracer
+from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, TrainerConfig
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def saver():
+    AsyncCheckpointSaver.reset()
+    s = AsyncCheckpointSaver.start_async_saving_ckpt(local_shard_num=1)
+    yield s
+    AsyncCheckpointSaver.reset()
+
+
+@pytest.fixture
+def tracer():
+    """The process tracer, emptied, with no mirror left behind."""
+    t = get_tracer()
+    t.reset()
+    yield t
+    t.set_mirror(None)
+    t.reset()
+
+
+class _Tokens:
+    def __init__(self, n=512, seq=64, vocab=256, seed=0):
+        rng = np.random.default_rng(seed)
+        self.data = rng.integers(0, vocab, (n, seq + 1), dtype=np.int32)
+
+    def __len__(self):
+        return len(self.data)
+
+    def __getitem__(self, i):
+        return {"x": self.data[i][:-1], "y": self.data[i][1:]}
+
+
+def _records(tracer, tid=None):
+    """``(name, start, end, depth, attrs)`` of the recorded spans."""
+    return [
+        (name, start, start + dur, depth, attrs)
+        for name, t, start, dur, depth, attrs, _seq in tracer.drain(0)[0]
+        if tid is None or t == tid
+    ]
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def _train(tmp_path, steps, **cfg):
+    trainer = ElasticTrainer(
+        tiny(),
+        optax.adamw(1e-3),
+        _Tokens(),
+        TrainerConfig(
+            batch_size=8, seq_len=64, report_metrics=False,
+            log_interval=4, **cfg,
+        ),
+        strategy=Strategy(mesh=MeshConfig()),
+        devices=jax.devices()[:1],
+    )
+    try:
+        trainer.train(num_steps=steps)
+        return trainer, dict(trainer.pipeline_stats.as_dict())
+    finally:
+        trainer.close()
+
+
+# -- 1. the step is partitioned ---------------------------------------------
+@pytest.mark.parametrize("saves", [False, True], ids=["plain", "chunked_save"])
+def test_step_span_is_partitioned(saves, tmp_path, tracer, request):
+    if saves:
+        request.getfixturevalue("saver")
+        cfg = dict(
+            ckpt_dir=str(tmp_path / "ckpt"), save_memory_interval=4,
+            save_storage_interval=10**9, stage_chunk_mb=1,
+            stage_budget_ms=0.0,
+        )
+    else:
+        cfg = {}
+    _trainer, stats = _train(tmp_path, 14, **cfg)
+    main = threading.get_ident()
+    recs = _records(tracer, tid=main)
+    steps = [r for r in recs if r[0] == "step"]
+    assert len(steps) == 14
+    # the host's own count names each step on the profiler's clock
+    assert [s[4]["step_num"] for s in steps] == list(range(1, 15))
+    total = covered = 0
+    for step in steps:
+        kids = [r for r in recs if _inside(r, step) and r[3] == step[3] + 1]
+        names = [k[0] for k in kids]
+        assert names[:2] == ["data_wait", "compute"], names
+        assert "hooks" in names and names[-1] == "ckpt_save"
+        compute = kids[1]
+        inner = [r for r in recs if r is not compute and _inside(r, compute)]
+        by_name = {r[0]: r for r in inner}
+        assert {"dispatch", "device_wait"} <= set(by_name)
+        assert by_name["dispatch"][2] <= by_name["device_wait"][1]
+        total += step[2] - step[1]
+        covered += sum(k[2] - k[1] for k in kids)
+    assert covered / total >= 0.99, covered / total
+    stage = [r for r in recs if r[0] == "stage"]
+    if saves:
+        assert stats["stage_commits"] >= 1 and stage
+        for st in stage:
+            assert any(
+                r[0] == "ckpt_stage" and _inside(r, st) for r in recs
+            )
+            assert any(_inside(st, s) for s in steps)
+    else:
+        assert not stage  # no stager live: no span, not an empty one
+
+
+def test_build_spans_carry_the_compile_counters(tmp_path, tracer):
+    before = len(compile_meter().builds)
+    _train(tmp_path, 3)
+    builds = {
+        r[0]: r[4] for r in _records(tracer) if r[0].startswith("build:")
+    }
+    assert {"build:strategy", "build:init", "build:step_donating"} <= set(
+        builds
+    )
+    # each first call of a program compiled something, and says so
+    for what in ("build:init", "build:step_donating"):
+        assert builds[what]["compiles"] >= 1
+        assert 0 < builds[what]["compile_s"] <= builds[what]["seconds"]
+    rows = compile_meter().builds[before:]
+    assert [r["what"] for r in rows][:2] == ["strategy", "init"]
+
+
+# -- 2. a chunk's phases ----------------------------------------------------
+STAGE_PHASES = (
+    "stage_d2h_issue", "stage_d2h_wait", "stage_crc", "stage_shm_copy",
+    "stage_grant_wait",
+)
+
+
+def test_chunked_save_names_the_phases_of_a_chunk(saver, tmp_path, tracer):
+    engine = CheckpointEngine()
+    stats = PipelineStats()
+    try:
+        state = {
+            f"w{i}": jnp.full((2 << 20,), float(i), jnp.float32)
+            for i in range(6)
+        }
+        stager = engine.begin_chunked_save(
+            1, state, str(tmp_path / "ck"), chunk_bytes=4 << 20
+        )
+        assert stager is not None
+        while not stager.done:
+            stager.advance(budget_s=0.0, stats=stats)
+        assert stager.commit(stats=stats)
+    finally:
+        engine.close()
+    recs = _records(tracer, tid=threading.get_ident())
+    stages = [r for r in recs if r[0] == "ckpt_stage"]
+    phases = [r for r in recs if r[0] in STAGE_PHASES]
+    assert {r[0] for r in phases} == set(STAGE_PHASES)
+    for p in phases:
+        assert any(_inside(p, s) for s in stages), p[0]
+    assert stats.stage_chunks == sum(
+        1 for r in recs if r[0] == "stage_grant_wait"
+    )
+    named = sum(p[2] - p[1] for p in phases) / 1e9
+    assert named <= stats.stage_block_s
+    assert named >= 0.95 * stats.stage_block_s, (named, stats.stage_block_s)
+
+
+# -- 3. the mirror ----------------------------------------------------------
+class _Twin:
+    def __init__(self, log, name, attrs):
+        self.log, self.name, self.attrs = log, name, attrs
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, threading.get_ident()))
+        return False
+
+
+def test_mirror_is_entered_and_left_once_per_span():
+    log = []
+    t = SpanTracer(capacity=64, enabled=True)
+    t.set_mirror(lambda name, attrs: _Twin(log, name, attrs))
+    with t.span("step", step_num=7):
+        with t.span("compute"):
+            pass
+        dropped = t.span("data_wait")
+        dropped.cancel()
+        dropped.cancel()  # a second cancel leaves nothing twice
+    me = threading.get_ident()
+    assert log == [
+        ("enter", "step", me), ("enter", "compute", me),
+        ("exit", "compute", me), ("enter", "data_wait", me),
+        ("exit", "data_wait", me), ("exit", "step", me),
+    ]
+    assert [r[0] for r in t.drain(0)[0]] == ["compute", "step"]
+    # an inner span leaked open: ending the outer one leaves both twins,
+    # innermost first, and a late end of the inner one does nothing more
+    del log[:]
+    outer = t.span("outer")
+    inner = t.span("inner")
+    outer.end()
+    inner.end()
+    assert [e[:2] for e in log] == [
+        ("enter", "outer"), ("enter", "inner"),
+        ("exit", "inner"), ("exit", "outer"),
+    ]
+    assert t.open_spans() == []
+
+
+def test_mirror_faults_and_a_disabled_tracer_cost_the_spans_nothing():
+    calls = []
+
+    def broken(name, attrs):
+        calls.append(name)
+        raise RuntimeError("no profiler here")
+
+    t = SpanTracer(capacity=16, enabled=True)
+    t.set_mirror(broken)
+    with t.span("a"):
+        pass
+    assert calls == ["a"] and [r[0] for r in t.drain(0)[0]] == ["a"]
+    off = SpanTracer(capacity=16, enabled=False)
+    off.set_mirror(broken)
+    assert off.span("a") is off.span("b")  # the shared no-op
+    assert calls == ["a"]
+    t.set_mirror(None)
+    with t.span("b"):
+        pass
+    assert calls == ["a"]
+
+
+def test_trainer_installs_the_profilers_annotations(tmp_path, tracer):
+    _train(tmp_path, 2)
+    twin = tracer._mirror("step", {"step_num": 3})
+    assert isinstance(twin, jax.profiler.StepTraceAnnotation)
+    plain = tracer._mirror("dispatch", None)
+    assert type(plain) is jax.profiler.TraceAnnotation
+    with twin, plain:  # no session live: enters and leaves, records nothing
+        pass
+
+
+def test_tracer_and_agent_import_without_jax():
+    code = (
+        "import sys\n"
+        "import dlrover_tpu.obs.trace\n"
+        "import dlrover_tpu.agent.training_agent\n"
+        "import dlrover_tpu.agent.monitor\n"
+        "bad = [m for m in sys.modules if m == 'jax' or "
+        "m.startswith('jax.')]\n"
+        "assert not bad, bad[:3]\n"
+    )
+    env = dict(os.environ, PYTHONPATH=REPO)
+    subprocess.run([sys.executable, "-c", code], check=True, env=env, cwd=REPO)
+
+
+# -- 4. a restore's phases --------------------------------------------------
+@pytest.mark.parametrize("source", ["shm", "storage"])
+def test_restore_fills_the_pipeline_stats(source, saver, tmp_path, tracer):
+    engine = CheckpointEngine()
+    try:
+        state = {
+            "w": jnp.arange(1 << 18, dtype=jnp.float32),
+            "b": jnp.ones((4096,), jnp.float32),
+            "step": 9,
+        }
+        d = str(tmp_path / "ck")
+        assert engine.begin_chunked_save(9, state, d).commit()
+        deadline = time.time() + 60
+        while engine.latest_step(d) < 9:
+            time.sleep(0.05)
+            assert time.time() < deadline
+        assert engine.last_restore is None
+        t0 = time.perf_counter()
+        step, restored = engine.load(
+            state, d, prefer_memory=(source == "shm")
+        )
+        took = time.perf_counter() - t0
+    finally:
+        engine.close()
+    assert step == 9
+    np.testing.assert_array_equal(np.asarray(restored["w"]), state["w"])
+    rec = engine.last_restore
+    assert set(rec) == set(RESTORE_FIELDS)
+    assert rec["restore_source"] == (1 if source == "shm" else 2)
+    assert rec["restore_bytes"] >= (1 << 20) + 4096 * 4
+    seconds = {k: v for k, v in rec.items() if k.endswith("_s")}
+    assert all(v >= 0 for v in seconds.values())
+    assert 0 < sum(seconds.values()) <= took
+    assert rec["restore_h2d_s"] > 0 and rec["restore_storage_verify_s"] > 0
+    if source == "shm":
+        assert rec["restore_shm_verify_s"] > 0
+        assert rec["restore_storage_read_s"] == 0
+    else:
+        assert rec["restore_storage_read_s"] > 0
+        assert rec["restore_shm_verify_s"] == 0
+    # each phase is a span of the field's name less its unit
+    names = {r[0] for r in _records(tracer)}
+    assert {k[:-2] for k, v in seconds.items() if v > 0} <= names
+    # the trainer's fold: fields of the record, gauges of the registry
+    stats = PipelineStats()
+    stats.set_restore(rec)
+    assert stats.as_dict()["restore_source"] == rec["restore_source"]
+    assert stats.restore_h2d_s == rec["restore_h2d_s"]
+    assert "restored" in stats.summary()
+    registry = MetricsRegistry()
+    fold_pipeline_stats(stats, registry)
+    assert "dlrover_pipeline_restore_h2d_s" in registry.scalars()
+
+
+# -- 5. stable names on the device ------------------------------------------
+SCOPES = (
+    "scope/embed", "scope/layer/attn", "scope/layer/mlp",
+    "scope/final_norm", "scope/lm_head", "scope/xent", "scope/optimizer",
+    "scope/grad_norm",
+)
+
+
+@pytest.fixture(scope="module")
+def lowered_step():
+    mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+    cfg, tx = tiny(), optax.adamw(1e-3)
+    state, _ = init_sharded_state(jax.random.PRNGKey(0), cfg, mesh, tx)
+    x = jnp.zeros((8, 32), jnp.int32)
+    step = build_train_step(cfg, mesh, tx)
+    return step, (state, x, x), step.lower(state, x, x)
+
+
+@pytest.mark.parametrize("scope", SCOPES)
+def test_scope_reaches_the_lowered_step(scope, lowered_step):
+    text = lowered_step[2].as_text(debug_info=True)
+    assert scope in text
+    if scope not in ("scope/optimizer", "scope/grad_norm"):
+        # both passes carry the name: the forward's scope rides through
+        # the differentiation into the backward's operations
+        assert f"jvp({scope})" in text
+        assert f"transpose(jvp({scope}))" in text
+
+
+def test_scopes_are_metadata_only(lowered_step, monkeypatch):
+    step, args, lowered = lowered_step
+    plain = lowered.as_text()
+    assert "scope/" not in plain
+    # the same program traced with every scope a no-op: the text that
+    # decides the compile cache's key does not move
+    from jax._src import source_info_util
+
+    cm = source_info_util.ExtendNameStackContextManager
+    monkeypatch.setattr(cm, "__enter__", lambda self: None)
+    monkeypatch.setattr(cm, "__exit__", lambda self, *exc: None)
+    cfg, tx = tiny(), optax.adamw(1e-3)
+    mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
+    bare = build_train_step(cfg, mesh, tx).lower(*args)
+    assert "scope/" not in bare.as_text(debug_info=True)
+    assert bare.as_text() == plain
+
+
+# -- 6. the agent's recovery as one timeline --------------------------------
+def test_agent_logs_a_recovery_as_one_timeline(tracer):
+    from dlrover_tpu.agent.master_client import MasterClient
+    from dlrover_tpu.agent.training_agent import (
+        ElasticTrainingAgent,
+        WorkerSpec,
+        WorkerState,
+    )
+    from dlrover_tpu.common.log import default_logger
+    from dlrover_tpu.master.local_master import start_local_master
+
+    lines = []
+
+    class _Capture(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    master = start_local_master(node_num=1)
+    for mgr in master.rdzv_managers.values():
+        mgr.update_rdzv_params(min_nodes=1, max_nodes=1, waiting_timeout=0)
+    handler = _Capture()
+    default_logger.addHandler(handler)
+    try:
+        agent = ElasticTrainingAgent(
+            node_rank=0,
+            spec=WorkerSpec(
+                entrypoint=os.path.join(REPO, "tests", "assets", "fail_once.py"),
+                nproc_per_node=1, max_restarts=2, monitor_interval=0.2,
+            ),
+            client=MasterClient(master.addr, node_id=0),
+        )
+        agent.set_checkpoint_hook(lambda: time.sleep(0.05))
+        result = agent.run()
+    finally:
+        default_logger.removeHandler(handler)
+        master.stop()
+    assert result.state == WorkerState.SUCCEEDED and result.restarts == 1
+    found = [m for m in lines if m.startswith("recovery timeline: ")]
+    assert len(found) == 1
+    timeline = json.loads(found[0].split(": ", 1)[1])
+    legs = [
+        "persist_before_restart_s", "stop_workers_s", "shm_lock_reset_s",
+        "rendezvous_s", "start_workers_s",
+    ]
+    assert set(legs) <= set(timeline)
+    assert timeline["reason"] == "worker_failure"
+    assert 0.15 <= timeline["detect_tick_s"] <= 5.0
+    assert timeline["persist_before_restart_s"] >= 0.05
+    assert sum(timeline[k] for k in legs) <= timeline["total_s"] + 0.01
+    recs = _records(tracer)
+    recover = [r for r in recs if r[0] == "recover"]
+    assert len(recover) == 1
+    assert recover[0][4]["detect_tick_s"] == timeline["detect_tick_s"]
+    inside = [r[0] for r in recs if r[0] != "recover" and _inside(r, recover[0])]
+    assert inside == [k[:-2] for k in legs]
+
+
+# -- 7. the telemetry writer's race -----------------------------------------
+def test_two_threads_publish_one_file(tmp_path):
+    """The span heartbeat and the loop's report write one file from two
+    threads of one process: neither may take the other's temporary file
+    from under it (``FileNotFoundError``, 1 run in 47 on the chip)."""
+    from dlrover_tpu.agent.monitor import atomic_write_json
+
+    path = str(tmp_path / "runtime_metrics.json")
+    errors = []
+    go = threading.Event()
+
+    def writer(who):
+        go.wait()
+        try:
+            for i in range(1500):
+                atomic_write_json(path, {"who": who, "i": i, "pad": "x" * 512})
+        except Exception as e:  # noqa: BLE001
+            errors.append(repr(e))
+
+    threads = [threading.Thread(target=writer, args=(w,)) for w in "ab"]
+    for t in threads:
+        t.start()
+    go.set()
+    for t in threads:
+        t.join()
+    assert errors == []
+    with open(path) as f:
+        assert json.load(f)["i"] == 1499
+    assert os.listdir(tmp_path) == ["runtime_metrics.json"]

@@ -96,9 +96,9 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
         )
 
     if direction == "fwd":
-        _compile_for_chip(attend, *qkv)
+        compiled = _compile_for_chip(attend, *qkv)
     else:
-        _compile_for_chip(
+        compiled = _compile_for_chip(
             jax.grad(
                 lambda q, k, v: attend(q, k, v)
                 .astype(jnp.float32)
@@ -107,6 +107,21 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
             ),
             *qkv,
         )
+    # the kernel's name= reaches the HLO instruction's name (and so a
+    # device trace's event): the benchmark's reducer finds the kernels
+    # by it, whatever the compiler numbers them
+    fused = name != "llama2_7b"
+    want = {
+        ("fwd", True): ["flash_attn_fused_fwd"],
+        ("bwd", True): ["flash_attn_fused_fwd", "flash_attn_fused_bwd"],
+        ("fwd", False): ["flash_attn_fwd"],
+        ("bwd", False): [
+            "flash_attn_fwd", "flash_attn_bwd_dq", "flash_attn_bwd_dkv",
+        ],
+    }[direction, fused]
+    text = compiled.as_text()
+    for kernel in want:
+        assert kernel in text, kernel
 
 
 # the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here

@@ -72,6 +72,11 @@ class PipelineStats:
     stage_commits: int = 0
     donated_steps: int = 0
     safe_steps: int = 0  # steps run without donation (staging in flight)
+    # steps at whose ``device_wait`` the step before was still running:
+    # the device had the next step queued and was never left without
+    # work. The train loop keeps one step in flight; the steps not
+    # counted here are the ones the host starved the device before
+    steps_ahead: int = 0
     donated_bytes: int = 0
     # -- elastic-resize fast path (accel/compile_cache, ckpt/reshard) --
     compile_cache_hits: int = 0
@@ -178,6 +183,7 @@ class PipelineStats:
             "stage_commits": self.stage_commits,
             "donated_steps": self.donated_steps,
             "safe_steps": self.safe_steps,
+            "steps_ahead": self.steps_ahead,
             "donated_bytes": self.donated_bytes,
             "compile_cache_hits": self.compile_cache_hits,
             "compile_cache_misses": self.compile_cache_misses,
@@ -265,7 +271,8 @@ class PipelineStats:
             f"chunks ({self.stage_block_s * 1e3:.1f} ms on critical "
             f"path, {self.stage_commits} commits), donated "
             f"{self.donated_bytes >> 20} MiB over {self.donated_steps} "
-            f"steps ({self.safe_steps} safe){resize}{gsync}{restore}"
+            f"steps ({self.safe_steps} safe, {self.steps_ahead} dispatched "
+            f"ahead of the device){resize}{gsync}{restore}"
         )
 
 

@@ -14,6 +14,7 @@ leaves are staged as per-host shard records with global indices
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
@@ -170,7 +171,9 @@ class ChunkedStager:
         # the incremental crc equals the whole-record crc); published
         # with the metas at commit for end-to-end shm integrity
         self._crcs: Dict[int, int] = {}
-        self._inflight = None  # (rec_idx, byte_offset, nbytes, producer)
+        # write groups whose D2H has been issued, oldest first; each a
+        # list of (rec_idx, byte_offset, nbytes, producer)
+        self._inflight: collections.deque = collections.deque()
         self._finished = False
         self._failed = False
         with span("ckpt_begin_shm"):
@@ -184,9 +187,7 @@ class ChunkedStager:
     @property
     def done(self) -> bool:
         """Every byte staged (commit may still be pending)."""
-        return (
-            self._cursor >= len(self._plan) and self._inflight is None
-        )
+        return self._cursor >= len(self._plan) and not self._inflight
 
     @property
     def finished(self) -> bool:
@@ -198,6 +199,16 @@ class ChunkedStager:
     # one group per step
     _DEFER_MIN_BYTES = 1 << 20
 
+    # Write groups kept issued ahead of the one being consumed. The
+    # train loop calls advance() with a step in flight on the device,
+    # and device work runs in stream order: the copies (and slices) of a
+    # group issued now start only when that step ends. Two ahead, the
+    # group consumed in a step was issued two steps ago, so its copy has
+    # had a whole device step to land and a budgeted advance() finds one
+    # group ready on EVERY step (one ahead, every other step: the drain
+    # would take twice the steps).
+    _GROUPS_AHEAD = 2
+
     # -- chunk pipeline ------------------------------------------------
     def _start_next(self):
         """Build the next write group and start its D2H. A group is a
@@ -206,7 +217,16 @@ class ChunkedStager:
         coalesce into one group (a pytree of many tiny leaves must not
         become one chunk per leaf), a record larger than ``chunk_bytes``
         is split into equal-size windows (consistent slice shapes, so the
-        eager slice op compiles once). Returns None at plan's end."""
+        eager slice op compiles once). Returns None at plan's end.
+
+        A whole record is copied to the host as it is, with no
+        computation on the device (its bytes in C order are the same in
+        any shape). The train loop calls this with a step in flight, and
+        the runtime lets 32 computations be in flight at once: the 32nd
+        eager op queued behind a running step blocks the host until that
+        step ends (a group of 32 small leaves stalled a chunk step for
+        110 of 115 ms on the v5e, PERF.md PR 26). Copies to the host do
+        not count against that limit."""
         import jax
 
         group = []
@@ -230,7 +250,7 @@ class ChunkedStager:
                 continue
             if meta.nbytes <= budget and self._elem_off == 0:
                 # whole small record joins the group, no slicing
-                dev = jax.numpy.ravel(src)
+                dev = src
                 lo, hi = 0, n_elems
             elif group:
                 break  # the big record starts its own group next call
@@ -262,7 +282,7 @@ class ChunkedStager:
     def _may_defer(cls, group) -> bool:
         """True when a budgeted advance should leave this group to ride
         the async stream instead of blocking on its transfer."""
-        total = sum(n for _, n, _, _ in group)
+        total = sum(n for _, _, n, _ in group)
         if total < cls._DEFER_MIN_BYTES:
             return False
         for _, _, _, src in group:
@@ -288,23 +308,30 @@ class ChunkedStager:
             and len(self._striper.rails()) >= 2
         )
 
-    def _write_one(self) -> int:
-        """Consume the inflight group (start the next one's D2H first so
-        the transfer overlaps this memcpy). Returns bytes written."""
-        if self._inflight is None:
-            with span("stage_d2h_issue"):
-                self._inflight = self._start_next()
-            if self._inflight is None:
-                return 0
-        group = self._inflight
-        stripes = self._group_stripes(group)
+    def _issue_ahead(self) -> None:
+        """Start the D2H of further write groups until ``_GROUPS_AHEAD``
+        are issued besides the one at the head (or the plan ends)."""
         with span("stage_d2h_issue"):
-            self._inflight = self._start_next()
+            while len(self._inflight) <= self._GROUPS_AHEAD:
+                group = self._start_next()
+                if group is None:
+                    break
+                self._inflight.append(group)
+
+    def _write_one(self) -> int:
+        """Consume the oldest issued group (start the D2H of the groups
+        behind it first so the transfers overlap this memcpy). Returns
+        bytes written."""
+        self._issue_ahead()
+        if not self._inflight:
+            return 0
+        group = self._inflight.popleft()
+        stripes = self._group_stripes(group)
         written = 0
         shm = self._engine._shm
         for idx, offset, nbytes, src in group:
             # the first touch: blocks until the group's device→host
-            # copy (started one group ahead) has landed
+            # copy (started ``_GROUPS_AHEAD`` groups ahead) has landed
             with span("stage_d2h_wait"):
                 data = (
                     src if isinstance(src, np.ndarray)
@@ -363,18 +390,26 @@ class ChunkedStager:
         t0 = time.perf_counter()
         copied = 0
         chunks0 = self.chunks_written
+        # Groups issued by earlier calls. A budgeted call touches no
+        # other: the copy of a group it has just issued queues behind
+        # whatever the device is running and cannot have landed, however
+        # small the group and even where its sources read as ready (a
+        # whole record is the state's own leaf), so touching it would
+        # wait out the step in flight.
+        older = len(self._inflight)
         try:
             with span("ckpt_stage", step=self.step):
                 while not self.done:
-                    if self._inflight is None:
-                        with span("stage_d2h_issue"):
-                            self._inflight = self._start_next()
-                        if self._inflight is None:
+                    if not self._inflight:
+                        self._issue_ahead()
+                        if not self._inflight:
                             break
-                    if budget_s is not None and self._may_defer(
-                        self._inflight
+                    head = self._inflight[0]
+                    if budget_s is not None and (
+                        older <= 0 or self._may_defer(head)
                     ):
                         break  # transfer still riding the async stream
+                    older -= 1
                     # one link grant per chunk: higher-priority traffic
                     # (emergency ckpt, spill backpressure) interleaves
                     # between chunks instead of waiting out the drain
@@ -382,8 +417,8 @@ class ChunkedStager:
                     # host section's own budgeted work on the train
                     # thread — the window gate must defer background
                     # THREADS to it, never it to itself
-                    nbytes = sum(m[2] for m in self._inflight)
-                    if self._group_stripes(self._inflight):
+                    nbytes = sum(m[2] for m in head)
+                    if self._group_stripes(head):
                         # striped group: the per-chunk rail grants
                         # inside the striper are the only arbitration
                         # (holding the stream grant here would deadlock
@@ -472,7 +507,7 @@ class ChunkedStager:
         self._finished = True
         self._failed = True
         self._plan = []
-        self._inflight = None
+        self._inflight.clear()
         self._stream.demand_bytes_per_step = 0
         # force_release, not release: abort may run from a thread other
         # than the acquirer's (same rationale as _stage_and_notify)

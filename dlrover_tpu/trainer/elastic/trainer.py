@@ -294,6 +294,22 @@ def _dense_eval_loss(params, x, y, cfg, mesh):
 
 
 class ElasticTrainer:
+    """The elastic training loop over one model, optimizer and dataset.
+
+    ``metrics_hook(step, metrics)``, when given, is called once per
+    optimizer step, in order, from the train thread. The loop keeps one
+    step in flight (it dispatches step N+1 before it waits for step N),
+    so at the call ``trainer.state`` is step ``step``'s state
+    (``int(trainer.state.step) == step``) and ``metrics`` that step's
+    arrays, and neither has been computed yet: a hook that stores them
+    costs nothing, a hook that reads them (``float(metrics["loss"])``,
+    a digest of ``trainer.state``) waits for that step and loses the
+    overlap for it, nothing else. Exactly one device step completes
+    between two calls. The hook is called again after an eval pass with
+    the eval scalars (no ``"loss"`` key). An exception it raises ends
+    ``train()`` at that step boundary. ``docs/observability.md`` has the
+    whole contract."""
+
     def __init__(
         self,
         model_cfg: TransformerConfig,
@@ -1710,13 +1726,26 @@ class ElasticTrainer:
         """The live EFFECTIVE learning rate (schedule value x the
         master's retune scale) when the optimizer was built with
         ``build_optimizer`` / ``optax.inject_hyperparams``."""
+        return self._lr_value(self._lr_parts())
+
+    def _lr_parts(self) -> Optional[list]:
+        """The device scalars whose product is ``current_lr`` (they
+        live in the state: the next donating step takes them away)."""
         hp = getattr(self.state.opt_state, "hyperparams", None)
         if hp and "learning_rate" in hp:
-            lr = float(hp["learning_rate"])
-            if "retune_scale" in hp:
-                lr *= float(hp["retune_scale"])
-            return lr
+            return [
+                hp[k] for k in ("learning_rate", "retune_scale") if k in hp
+            ]
         return None
+
+    @staticmethod
+    def _lr_value(parts: Optional[list]) -> Optional[float]:
+        if parts is None:
+            return None
+        lr = float(parts[0])
+        for p in parts[1:]:
+            lr *= float(p)
+        return lr
 
     # -- pipelined transfers -------------------------------------------
     def _epoch_batches(self, num_steps: int):
@@ -2721,8 +2750,80 @@ class ElasticTrainer:
                     f"unknown worker command kind {kind!r} (#{cid})"
                 )
 
+    def _wait_for_step(self, done) -> None:
+        """The loop's one wait per step, on the completion token of the
+        step BEFORE the one just dispatched (``done``: an output of it
+        that no later step is given to donate, its loss). A token not
+        yet ready means the device still had work when the next step was
+        queued behind it: ``steps_ahead`` counts those."""
+        import jax
+
+        if done is None:
+            return
+        if not done.is_ready():
+            self.pipeline_stats.steps_ahead += 1
+        jax.block_until_ready(done)
+
+    def _log_step(self, due, t0, start_step):
+        """The log-cadence report of one step, made once that step has
+        been waited for: ``due`` is what the loop put aside at the step
+        itself (its number, its loss, copies of the learning-rate
+        scalars of its state, the eval scalars as they stood)."""
+        step, loss, lr_parts, evals = due
+        # materializing the loss is a host sync only when the report is
+        # made at an exit of the loop; in the loop the step is done
+        with span("host_sync"):
+            loss = float(loss)
+        with span("report"):
+            scalars = {"loss": loss}
+            lr = self._lr_value(lr_parts)
+            if lr is not None:
+                scalars["lr"] = lr
+            scalars.update(evals)
+            # the agent's TrainingMonitor forwards these to the
+            # master's collector (TrainMetricsReport)
+            self._report_metrics(step, scalars)
+            rate = (step - start_step) / max(time.time() - t0, 1e-9)
+            lr_s = f" lr={lr:.2e}" if lr is not None else ""
+            logger.info(
+                f"step {step}: loss={loss:.4f}{lr_s} ({rate:.2f} it/s)"
+            )
+
     def _train_loop(self, num_steps: int, t0, start_step) -> Any:
         import jax
+        import jax.numpy as jnp
+
+        # One step in flight: each iteration dispatches step N+1 and
+        # then waits for step N, so the rest of the iteration (staging,
+        # hooks, report, save, the next batch and the next dispatch)
+        # runs while the device computes N+1. `in_flight` is the
+        # completion token of the step dispatched last, `report_due`
+        # the log-cadence report of a step not yet waited for.
+        in_flight = None
+        report_due = None
+
+        def held_lr():
+            # copies on the device of the learning-rate scalars of
+            # `self.state`: the next step may donate the state that
+            # holds them before the report that wants them is made
+            parts = self._lr_parts()
+            return parts and [jnp.copy(p) for p in parts]
+
+        # builds the copy's program now and not at the first report
+        # step: nothing compiles once a run is warm
+        held_lr()
+
+        def report_if_due():
+            nonlocal report_due
+            if report_due is not None:
+                due, report_due = report_due, None
+                self._log_step(due, t0, start_step)
+
+        def drain():
+            # every exit of the loop: nothing stays in flight, and a
+            # report still due is made
+            jax.block_until_ready(self.state.params)
+            report_if_due()
 
         while self.global_step < num_steps and not self.eviction_pending:
             self.dataloader.load_config()  # master-retuned batch size
@@ -2735,14 +2836,17 @@ class ElasticTrainer:
             # num_steps stop mid-epoch checkpoints the exact position
             # (modulo the prefetch rewind in _ckpt_state)
             batches = self._epoch_batches(num_steps)
+            # a device read, once an epoch; inside it the step's number
+            # is the host's own count
             host_step = self.global_step
             while True:
-                # step boundary = the preemption arrival point: the
-                # in-flight step is finished, nothing is half-donated.
-                # node.preempt `kill` is the scripted hard death the
-                # chaos harness replays; a pending eviction notice
-                # (SIGTERM / env deadline / `evict` command) enters the
-                # graceful drain instead
+                # step boundary = the preemption arrival point: every
+                # step dispatched has its state in `self.state`, at most
+                # one of them still runs on the device, nothing is
+                # half-donated. node.preempt `kill` is the scripted hard
+                # death the chaos harness replays; a pending eviction
+                # notice (SIGTERM / env deadline / `evict` command)
+                # enters the graceful drain instead
                 faults.fire("node.preempt")
                 if self.eviction_pending:
                     break
@@ -2755,9 +2859,9 @@ class ElasticTrainer:
                 # escaping the body must CANCEL the span — a leaked
                 # open frame would poison hang attribution for the
                 # rest of the process (cancel after end is a no-op)
-                # step_num is the host's own count (the last step read
-                # plus one), never a device read: it names the step on
-                # the profiler's clock (StepTraceAnnotation)
+                # step_num is the host's own count, never a device
+                # read: it names the step on the profiler's clock
+                # (StepTraceAnnotation)
                 step_sp = span("step", step_num=host_step + 1)
                 step_t0 = time.perf_counter()
                 try:
@@ -2776,12 +2880,17 @@ class ElasticTrainer:
                         try:
                             # holds the `dispatch` span
                             metrics = self._run_step(x, y)
-                            # the loop's one device read per step:
-                            # materializing the step count waits for
-                            # the dispatched update — that wall time
-                            # is compute, so it lands inside this span
+                            step = host_step = host_step + 1
+                            # the loop's one wait per step, for the
+                            # step BEFORE the one just dispatched: the
+                            # device goes from that step straight into
+                            # this one, and what follows in the
+                            # iteration runs under it. An error the
+                            # device raised in that step surfaces here,
+                            # one iteration late
                             with span("device_wait"):
-                                step = host_step = self.global_step
+                                self._wait_for_step(in_flight)
+                            in_flight = metrics["loss"]
                         finally:
                             transfer_sched.note_compute(False)
                     # interleave checkpoint chunks while the step
@@ -2791,7 +2900,9 @@ class ElasticTrainer:
                         with span("stage"):
                             self._advance_stager()
                     # what rides every step beside the program: the
-                    # SDC fence, the caller's hook, the MoE rebalance
+                    # SDC fence, the caller's hook, the MoE rebalance.
+                    # `self.state` and `metrics` are THIS step's, still
+                    # being computed: whoever reads them waits for them
                     with span("hooks"):
                         # the per-lane norm vector is detector input,
                         # not a reporting scalar — pop it before any
@@ -2811,34 +2922,19 @@ class ElasticTrainer:
                             self._maybe_rebalance_experts(
                                 metrics["moe_expert_load"]
                             )
+                    # a report step before this one has been waited
+                    # for by now: its loss costs no sync
+                    report_if_due()
                     if step % self.tcfg.log_interval == 0:
-                        # the only host sync in the loop: loss is
-                        # materialized at log cadence, not every step
-                        # (async dispatch stays ahead of the host
-                        # otherwise)
-                        with span("host_sync"):
-                            loss = float(metrics["loss"])
-                        with span("report"):
-                            scalars = {"loss": loss}
-                            lr = self.current_lr()
-                            if lr is not None:
-                                scalars["lr"] = lr
-                            if self._last_eval:
-                                scalars.update(self._last_eval)
-                            # the agent's TrainingMonitor forwards
-                            # these to the master's collector
-                            # (TrainMetricsReport)
-                            self._report_metrics(step, scalars)
-                            rate = (step - start_step) / max(
-                                time.time() - t0, 1e-9
-                            )
-                            lr_s = (
-                                f" lr={lr:.2e}" if lr is not None else ""
-                            )
-                            logger.info(
-                                f"step {step}: loss={loss:.4f}{lr_s} "
-                                f"({rate:.2f} it/s)"
-                            )
+                        # no device read here: this step's loss is
+                        # reported once the step has been waited for,
+                        # one iteration on (or at the loop's exit)
+                        report_due = (
+                            step,
+                            metrics["loss"],
+                            held_lr(),
+                            dict(self._last_eval),
+                        )
                     if (
                         self._eval_dataset is not None
                         and self.tcfg.eval_interval
@@ -2863,7 +2959,7 @@ class ElasticTrainer:
                                 f"evals (best {self._best_eval_loss:.4f})"
                             )
                             step_sp.end()
-                            jax.block_until_ready(self.state.params)
+                            drain()
                             return self.state
                     if self._sdc_halt:
                         # tier-3 conviction already rolled the state
@@ -2904,10 +3000,10 @@ class ElasticTrainer:
                 # closes it afterwards
                 break
             self._close_prefetcher()  # fresh buffer per epoch
+        drain()
         if self.eviction_pending:
-            jax.block_until_ready(self.state.params)
             self._drain_for_eviction()
-        jax.block_until_ready(self.state.params)
+            jax.block_until_ready(self.state.params)
         return self.state
 
     def _apply_lr_scale(self, scale: float):

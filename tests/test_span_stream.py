@@ -2,7 +2,10 @@
 
 The trainer partitions its ``step`` span (``data_wait`` / ``compute`` =
 ``dispatch`` + ``device_wait`` / ``stage`` / ``hooks`` / ``report`` /
-``ckpt_save``), the chunked stager names the phases of a chunk inside
+``ckpt_save``; since ISSUE 26 ``device_wait`` is the wait for the step
+BEFORE the one ``dispatch`` just launched, and the report of a step is
+made in the step span after it), the chunked stager names the phases of a
+chunk inside
 ``ckpt_stage``, ``CheckpointEngine.load`` times its phases into
 ``PipelineStats.restore_*``, every span has a twin on the profiler's
 clock (a mirror the process that holds the chip installs), the step
@@ -131,11 +134,21 @@ def test_step_span_is_partitioned(saves, tmp_path, tracer, request):
         compute = kids[1]
         inner = [r for r in recs if r is not compute and _inside(r, compute)]
         by_name = {r[0]: r for r in inner}
+        # the wait comes after the launch, and is for the step before
+        # the one launched: the first step has none to wait for, so its
+        # `device_wait` is empty (the span is there all the same)
         assert {"dispatch", "device_wait"} <= set(by_name)
         assert by_name["dispatch"][2] <= by_name["device_wait"][1]
         total += step[2] - step[1]
         covered += sum(k[2] - k[1] for k in kids)
     assert covered / total >= 0.99, covered / total
+    # the report of steps 4, 8 and 12 (log_interval=4) is made once the
+    # step has been waited for: inside the step span after it
+    reports = [r for r in recs if r[0] == "report"]
+    assert [
+        next(s[4]["step_num"] for s in steps if _inside(r, s))
+        for r in reports
+    ] == [5, 9, 13]
     stage = [r for r in recs if r[0] == "stage"]
     if saves:
         assert stats["stage_commits"] >= 1 and stage

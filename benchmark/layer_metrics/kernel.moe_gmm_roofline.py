@@ -1,0 +1,66 @@
+"""The grouped expert matmuls' share of their roofline: the least time the
+chip could take for the expert projections of the traced steps (the larger
+of operations over the bf16 peak and bytes over HBM bandwidth, both from
+shapes by ``flops_moe.grouped_matmul_work``) over the summed device time
+of the grouped-matmul events, forward, recomputed forward and backward.
+Which bound holds is printed on an earlier line, with the seconds of the
+other ``tpu_custom_call`` events (flash attention's) beside it.
+
+How the events are recognised (looked at by hand in the compiled step
+and in a trace, my chip run, PR 27): on the TPU, XLA lowers
+``lax.ragged_dot`` to custom-calls of its own whose instruction names
+start ``%ragged-dot`` (``%ragged-dot-none.N`` the matmul,
+``%ragged-dot-metadata.N`` the tiling of the groups), with
+``custom_call_target="tpu_custom_call"`` like a Pallas kernel's. They are
+found by the start of that name, and not with ``xplane.kernel_seconds``,
+which also searches an event's HLO text: a fusion that takes a grouped
+matmul's result as an operand holds its name there. Nothing to read where
+the configuration has no experts or the trace holds no such event."""
+
+import json
+
+LAYER = "kernels"
+UNIT = "%"
+MOVES = "tokens_per_s"
+
+PREFIX = "%ragged-dot"
+
+
+def CELLS(cell):
+    return bool(cell.get("moe"))
+
+
+def read(run):
+    import flops
+    import flops_moe
+    import xplane
+
+    m = run.config["model"]
+    if not m.get("num_experts"):
+        return None
+    if not run.trace or not run.trace.get("devices") or not run.peak:
+        return None
+    t = run.window["trace"]
+    steps = t["step_end"] - t["step_begin"]
+    device = run.trace["devices"][0]
+    rows = [r for r in device["ops"] if r["name"].startswith(PREFIX)]
+    found = {
+        "seconds": sum(r["total_s"] for r in rows),
+        "count": sum(r["count"] for r in rows),
+        "names": [r["name"] for r in rows],
+    }
+    if not steps or not found["seconds"]:
+        return None
+    work = flops_moe.grouped_matmul_work(
+        m, run.cell["batch"] * run.cell["seq"]
+    )
+    layers = m["num_layers"] * steps
+    work = {k: v * layers for k, v in work.items()}
+    roof = flops.roofline_seconds(work, run.peak)
+    every = xplane.kernel_seconds(device, ("tpu_custom_call",))
+    print(json.dumps({
+        "grouped_matmul_kernels": found, "roofline": roof,
+        "steps_traced": steps,
+        "other_custom_call_seconds": every["seconds"] - found["seconds"],
+    }), flush=True)
+    return 100.0 * roof["seconds"] / found["seconds"]

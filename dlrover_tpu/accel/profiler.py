@@ -99,6 +99,13 @@ class PipelineStats:
     # moe_rebalance_interval; each one is a step rebuild through the
     # AOT cache)
     moe_capacity_resplits: int = 0
+    # routing of the steps reported at the log cadence (trainer
+    # _log_step, from metrics of a step already waited for): how many
+    # reports, their summed moe_drop_rate, and their summed largest
+    # expert's share of the assignments x num_experts (1.0 = even)
+    moe_reports: int = 0
+    moe_drop_rate_sum: float = 0.0
+    moe_max_load_sum: float = 0.0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was
@@ -199,6 +206,9 @@ class PipelineStats:
             "resize_idle_ranks": self.resize_idle_ranks,
             "resize_mb_pad": self.resize_mb_pad,
             "moe_capacity_resplits": self.moe_capacity_resplits,
+            "moe_reports": self.moe_reports,
+            "moe_drop_rate_sum": round(self.moe_drop_rate_sum, 6),
+            "moe_max_load_sum": round(self.moe_max_load_sum, 6),
             "grad_sync_path": self.grad_sync_path,
             # numeric twin for the metrics registry (fold_pipeline_
             # stats skips strings): 1 = explicit, 0 = gspmd fallback,

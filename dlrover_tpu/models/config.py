@@ -28,9 +28,20 @@ class TransformerConfig:
     rmsnorm: bool = False
     swiglu: bool = False
     tie_embeddings: bool = True
-    # MoE: every `moe_every`-th block uses an expert FFN
+    # eps of every norm; None => 1e-6 under rmsnorm, 1e-5 under LayerNorm
+    norm_eps: Optional[float] = None
+    # RMSNorm over each token's whole query and key projections, all
+    # heads together, before RoPE (OLMoE's QK-norm)
+    qk_norm: bool = False
+    # MoE: every `moe_every`-th block uses an expert FFN (SwiGLU experts
+    # where `swiglu`, the GELU pair where not)
     num_experts: int = 0
     moe_every: int = 2
+    # sizes the per-expert buckets of the all-to-all where experts are
+    # sharded over an ep axis of more than one device, and with them
+    # how many assignments are dropped there. Where every expert is
+    # local (one device, ep=1) the layer is dropless and this has no
+    # effect (parallel/moe.py).
     capacity_factor: float = 1.25
     # per-expert capacity re-split ([num_experts] ints, static): ()
     # keeps the uniform capacity_factor sizing; a non-empty tuple
@@ -44,6 +55,9 @@ class TransformerConfig:
     # (keeps gate logits small; 0 disables)
     moe_top_k: int = 1
     router_z_weight: float = 1e-3
+    # renormalise the k chosen experts' gate values to sum to one
+    # (GShard); False keeps their softmax probabilities (OLMoE)
+    norm_topk_prob: bool = True
     # sequence-parallel attention scheme when the mesh has sp > 1:
     # "ring" (P2P pipeline, any head count) or "ulysses" (two
     # all-to-alls; needs (heads/tp) % sp == 0) — parallel/{ring_

@@ -805,9 +805,10 @@ def build_train_step(
         if cfg.num_experts:
             metrics["moe_balance_loss"] = aux["balance"]
             metrics["moe_z_loss"] = aux["z"]
-            # routing telemetry (ISSUE 13): per-expert primary load
-            # (a [num_experts] vector — consumers that report scalars
-            # must pop it) and the capacity drop rate; the trainer's
+            # routing telemetry (ISSUE 13): each expert's share of
+            # the assignments (a [num_experts] vector — consumers that
+            # report scalars must pop it) and the capacity drop rate
+            # (0 wherever every expert is local); the trainer's
             # CapacityRebalancer periodically turns these into
             # cfg.capacity_splits. forward() SUMS aux across layers,
             # so normalize by the MoE layer count to report true

@@ -87,9 +87,13 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         if not cfg.rmsnorm:
             layer["attn_norm"]["bias"] = jnp.zeros((d,), pd)
             layer["mlp_norm"]["bias"] = jnp.zeros((d,), pd)
+        if cfg.qk_norm:
+            layer["q_norm"] = {"scale": jnp.ones((h, hd), pd)}
+            layer["k_norm"] = {"scale": jnp.ones((kvh, hd), pd)}
         if is_moe_layer(cfg, i):
             layer["moe"] = init_moe_params(
-                next(keys), cfg.num_experts, d, f, dtype=pd
+                next(keys), cfg.num_experts, d, f, dtype=pd,
+                gated=cfg.swiglu,
             )
         elif cfg.swiglu:
             layer["mlp"] = {
@@ -152,11 +156,16 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         if not cfg.rmsnorm:
             layer["attn_norm"]["bias"] = ("norm",)
             layer["mlp_norm"]["bias"] = ("norm",)
+        if cfg.qk_norm:
+            layer["q_norm"] = {"scale": ("heads", "head_dim")}
+            layer["k_norm"] = {"scale": ("kv_heads", "head_dim")}
         if is_moe_layer(cfg, i):
+            up = ("experts", None, "expert_mlp")
             layer["moe"] = MoEParams(
                 gate=(None, None),
-                w_up=("experts", None, "expert_mlp"),
+                w_up=up,
                 w_down=("experts", "expert_mlp", None),
+                w_gate=up if cfg.swiglu else None,
             )
         elif cfg.swiglu:
             layer["mlp"] = {
@@ -186,16 +195,36 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+def _norm_eps(cfg: TransformerConfig) -> float:
+    if cfg.norm_eps is not None:
+        return cfg.norm_eps
+    return 1e-6 if cfg.rmsnorm else 1e-5
+
+
 def _norm(x, p, cfg: TransformerConfig):
     xf = x.astype(jnp.float32)
+    eps = _norm_eps(cfg)
     if cfg.rmsnorm:
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + 1e-6)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
         return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
     mu = jnp.mean(xf, -1, keepdims=True)
     var = jnp.var(xf, -1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + 1e-5)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
     y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
     return y.astype(x.dtype)
+
+
+def _qk_norm(x, p, cfg: TransformerConfig, layout: str = "bthd"):
+    """RMSNorm over a token's WHOLE query (or key) projection, every
+    head together, before RoPE (OLMoE's QK-norm). x: [B,T,H,D] or
+    [B,H,T,D] per layout; the scale is [H,D]."""
+    heads = 1 if layout == "bhtd" else 2
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, axis=(heads, 3), keepdims=True)
+    scale = p["scale"].astype(jnp.float32)
+    if layout == "bhtd":
+        scale = scale[:, None, :]
+    return (xf * jax.lax.rsqrt(ms + _norm_eps(cfg)) * scale).astype(x.dtype)
 
 
 def _rope(x, positions, theta: float, layout: str = "bthd"):
@@ -302,6 +331,9 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions):
     q = jnp.einsum(proj, h, layer["attn"]["wq"].astype(h.dtype))
     k = jnp.einsum(proj, h, layer["attn"]["wk"].astype(h.dtype))
     v = jnp.einsum(proj, h, layer["attn"]["wv"].astype(h.dtype))
+    if cfg.qk_norm:
+        q = _qk_norm(q, layer["q_norm"], cfg, layout)
+        k = _qk_norm(k, layer["k_norm"], cfg, layout)
     if cfg.rope:
         q = _rope(q, positions, cfg.rope_theta, layout)
         k = _rope(k, positions, cfg.rope_theta, layout)
@@ -345,27 +377,24 @@ def _zero_aux(cfg: Optional[TransformerConfig] = None):
 def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None):
     h = _norm(x, layer["mlp_norm"], cfg)
     if "moe" in layer:
-        caps = cfg.capacity_splits or None
-        if mesh is not None:
-            out, aux = moe_layer(
-                layer["moe"], h, mesh,
-                capacity_factor=cfg.capacity_factor,
-                top_k=cfg.moe_top_k,
-                expert_caps=caps,
-            )
+        kw = dict(
+            capacity_factor=cfg.capacity_factor,
+            top_k=cfg.moe_top_k,
+            expert_caps=cfg.capacity_splits or None,
+            normalize=cfg.norm_topk_prob,
+        )
+        if mesh is not None and mesh.size > 1:
+            out, aux = moe_layer(layer["moe"], h, mesh, **kw)
         else:
-            # mesh=None runs inside a manual region; ``moe_axis``
-            # names the manual ep axis when expert weights enter as
-            # LOCAL [E/ep, ...] slices (the explicit-sync path), so
-            # the dispatch/combine all-to-alls still run
+            # one device, or mesh=None inside a manual region;
+            # ``moe_axis`` names the manual ep axis when expert
+            # weights enter as LOCAL [E/ep, ...] slices (the
+            # explicit-sync path), so the dispatch/combine
+            # all-to-alls still run
             B, T, d = h.shape
             out, aux = moe_layer_local(
-                layer["moe"],
-                h.reshape(B * T, d),
-                axis_name=moe_axis,
-                capacity_factor=cfg.capacity_factor,
-                top_k=cfg.moe_top_k,
-                expert_caps=caps,
+                layer["moe"], h.reshape(B * T, d), axis_name=moe_axis,
+                **kw,
             )
             out = out.reshape(B, T, d)
         return x + out, aux
@@ -591,6 +620,9 @@ def _cached_decode_layer(
     q = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wq"].astype(dt))
     k = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wk"].astype(dt))
     v = jnp.einsum("btd,dhk->bthk", h, layer["attn"]["wv"].astype(dt))
+    if cfg.qk_norm:
+        q = _qk_norm(q, layer["q_norm"], cfg)
+        k = _qk_norm(k, layer["k_norm"], cfg)
     if cfg.rope:
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)

@@ -2768,12 +2768,22 @@ class ElasticTrainer:
         """The log-cadence report of one step, made once that step has
         been waited for: ``due`` is what the loop put aside at the step
         itself (its number, its loss, copies of the learning-rate
-        scalars of its state, the eval scalars as they stood)."""
-        step, loss, lr_parts, evals = due
+        scalars of its state, the eval scalars as they stood, and of a
+        MoE model its drop rate and per-expert load)."""
+        step, loss, lr_parts, evals, routing = due
         # materializing the loss is a host sync only when the report is
         # made at an exit of the loop; in the loop the step is done
         with span("host_sync"):
             loss = float(loss)
+            if routing is not None:
+                # copies to the host of the step's own outputs: an op
+                # on the device here would queue behind the step in
+                # flight
+                drop, load = (np.asarray(a) for a in routing)
+                stats = self.pipeline_stats
+                stats.moe_reports += 1
+                stats.moe_drop_rate_sum += float(drop)
+                stats.moe_max_load_sum += float(load.max()) * load.size
         with span("report"):
             scalars = {"loss": loss}
             lr = self._lr_value(lr_parts)
@@ -2934,6 +2944,12 @@ class ElasticTrainer:
                             metrics["loss"],
                             held_lr(),
                             dict(self._last_eval),
+                            (
+                                metrics["moe_drop_rate"],
+                                metrics["moe_expert_load"],
+                            )
+                            if "moe_drop_rate" in metrics
+                            else None,
                         )
                     if (
                         self._eval_dataset is not None

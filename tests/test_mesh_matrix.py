@@ -342,9 +342,10 @@ class TestEPSync:
         plan = plan_for_mesh(cfg, mesh, grad_bucket_mb=1)
         assert isinstance(plan, EPSyncPlan)
         assert plan.ep == 2 and plan.dp == 2
-        # the expert FFN leaves (w_up/w_down per moe layer) are
-        # ep-local; the gate and dense layers are not
-        assert len(plan.expert_leaf_ids) == 2
+        # the expert FFN leaves (w_up/w_down and, the tiny model being
+        # SwiGLU, w_gate of its one moe layer) are ep-local; the router
+        # and dense layers are not
+        assert len(plan.expert_leaf_ids) == 3
         assert all(d == 0 for d in plan.expert_leaf_dims)
         # per-device wire: expert leaves at 1/ep
         assert plan.raw_bytes < plan.expert_plan.raw_bytes * 2 + (

@@ -106,6 +106,13 @@ class PipelineStats:
     moe_reports: int = 0
     moe_drop_rate_sum: float = 0.0
     moe_max_load_sum: float = 0.0
+    # elements of the optimizer's int8 moments (ops/quantized_optim.py
+    # ``Quantized8``, both moments) by where their blocks lie: in the
+    # leaf's own tile order, which the update reads as a bitcast, or in
+    # [nblocks, 128] rows, which cost two relayouts of the leaf a step.
+    # Set once when the trainer has built its state; 0 / 0 for fp32
+    opt_q8_tiles_elems: int = 0
+    opt_q8_blocks_elems: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was
@@ -209,6 +216,8 @@ class PipelineStats:
             "moe_reports": self.moe_reports,
             "moe_drop_rate_sum": round(self.moe_drop_rate_sum, 6),
             "moe_max_load_sum": round(self.moe_max_load_sum, 6),
+            "opt_q8_tiles_elems": self.opt_q8_tiles_elems,
+            "opt_q8_blocks_elems": self.opt_q8_blocks_elems,
             "grad_sync_path": self.grad_sync_path,
             # numeric twin for the metrics registry (fold_pipeline_
             # stats skips strings): 1 = explicit, 0 = gspmd fallback,

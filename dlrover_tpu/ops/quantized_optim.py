@@ -6,27 +6,53 @@ quantization, linear + nonlinear qmaps) backed by the CUDA kernels in
 atorch/atorch/ops/csrc/{quantize.cu,dequantize.cu,quantization_optimizer.cu}.
 
 TPU-native design: optimizer moments are stored as int8 codes + one f32
-scale per 128-element block. The hot path (dequantize -> Adam moment
-update -> requantize -> parameter delta) is a single fused Pallas kernel
-— one HBM read of (g, codes, scales) and one write of (codes', scales',
-update), the same memory-traffic win the reference's fused CUDA kernel
-gets. Block size 128 = one VPU lane row, so per-block reductions
-(max|m|) are single-row reductions with no cross-lane shuffles.
+scale per 128-element block (the same 128 consecutive elements of the
+flattened leaf in every layout below). What runs today:
 
-Quantization is *linear* blockwise (codes = round(x/scale * 127)): on
-TPU a nonlinear 256-entry codebook lookup per element (the reference's
-dynamic map) would serialize into gathers; linear keeps the whole update
-elementwise on the VPU. The f32 scale per 128 values bounds relative
-error to ~0.4% of the block max, and Adam's moments are smooth enough
-that this matches fp32 training loss in the tests.
+- ``adamw_8bit`` with ``use_pallas=False`` (the benchmark's OLMoE cell,
+  and every backend but the TPU by default): plain jnp that XLA fuses. A
+  leaf whose last two dimensions are whole (8, 128) tiles keeps its
+  moments in ``TILES`` layout, the leaf's own tile order in HBM: the
+  update then views the gradient and hands back delta through a reshape +
+  transpose that the TPU compiler takes as a bitcast, the per-block
+  maximum is a lane reduce over the last axis, and decay and
+  ``apply_updates`` fuse onto delta, which never exists in HBM. XLA still
+  makes four passes a leaf (two block-maximum reduces, the parameter,
+  the requantise): 24 bytes an element with a bf16 gradient, against 14
+  for one pass. Any other leaf (1-D, odd widths) takes ``BLOCKS``:
+  ``[nblocks, 128]`` rows, padded; on the TPU that flattening is a
+  physical relayout of the gradient and another of delta (an (8, 128)
+  tiled ``[..., 1024]`` array does not lie in rows of 128): 22 of the 81 ms
+  of the OLMoE cell's optimizer pass when every leaf took it (PERF.md §6,
+  PR 28).
+  The layout follows the leaf because the leaf is what the gradient, the
+  parameter and the apply already are; it is decided from the shape, not
+  by an argument.
+- ``adamw_8bit`` with ``use_pallas=True`` (the default on the TPU): the
+  tree kernel, one ``pallas_call`` a leaf over ``BLOCKS`` rows (g, codes,
+  scales in; codes', scales', delta out), between the same two relayouts.
+- ``adamw_8bit_flat``: big leaves packed into a few flat buffers, one
+  aliased Pallas pass a group with dense ("wide") scales; the path
+  ``bench.py`` runs. ``bits=4``: jnp only, over ``BLOCKS`` rows.
 
-The same math runs as plain jnp off-TPU (``use_pallas=False`` or CPU
-backend), so numerics are identical across paths.
+Block size 128 = one lane row, so a block's maximum is a reduction along
+lanes; whether that is cheap depends on the layout above, not on the
+block size alone.
+
+Quantization is blockwise through a sqrt map (``_sqrt_map_quant``: codes
+= round(sign(y) sqrt|y| * 127), y = x / block max): on TPU a nonlinear
+256-entry codebook lookup per element (the reference's dynamic map) would
+serialize into gathers; the sqrt map keeps the whole update elementwise
+on the VPU and keeps small second moments from rounding to zero.
+
+The same math (``_adam8_block_math``, ``_sqrt_map_*``) runs in every
+path, so numerics agree across them up to rounding ties.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -47,31 +73,46 @@ _ROWS = 256  # rows per pallas grid step (256*128 elems/step), tree form
 _FLAT_ROWS = 2048
 
 
+# where a Quantized8's 128-element blocks lie: static aux data that
+# ``_layout_for`` decides from the leaf's shape, never an argument. For a
+# leaf [..., R, C]:
+BLOCKS = "blocks"  # codes [nblocks, BLOCK], scales [nblocks, 1]
+# codes [..., R/8, C/BLOCK, 8, BLOCK], scales [..., R/8, C/BLOCK, 8]
+TILES = "tiles"
+_SUBLANES = 8  # rows of one f32 (8, 128) tile
+
+
 @jax.tree_util.register_pytree_node_class
 class Quantized8:
-    """Blockwise linearly quantized tensor: ``x ~ codes * scales / qmax``.
+    """Blockwise quantized tensor: ``x ~ sqrt-map(codes) * scales``.
 
-    ``codes``/``scales`` are pytree children; ``shape``/``signed`` are
-    static aux data so jit never traces them.
+    ``codes``/``scales`` are pytree children; ``shape``/``signed``/
+    ``layout`` are static aux data so jit never traces them. Block ``b``
+    holds elements ``[128 b, 128 b + 128)`` of the flattened leaf in
+    either layout; ``TILES`` stores them in the order the leaf's own
+    (8, 128) tiles lie in HBM (``_to_tiles``).
     """
 
-    def __init__(self, codes, scales, shape, signed):
-        self.codes = codes  # int8 [nblocks, BLOCK]
-        self.scales = scales  # f32 [nblocks, 1]
+    def __init__(self, codes, scales, shape, signed, layout=BLOCKS):
+        self.codes = codes  # int8, see BLOCKS / TILES
+        self.scales = scales  # f32
         self.shape = tuple(shape)
         self.signed = bool(signed)
+        self.layout = layout
 
     def tree_flatten(self):
-        return (self.codes, self.scales), (self.shape, self.signed)
+        return (self.codes, self.scales), (
+            self.shape, self.signed, self.layout,
+        )
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], children[1], aux[0], aux[1])
+        return cls(children[0], children[1], *aux)
 
     def __repr__(self):
         return (
             f"Quantized8(shape={self.shape}, signed={self.signed}, "
-            f"nblocks={self.codes.shape[0]})"
+            f"layout={self.layout}, codes={tuple(self.codes.shape)})"
         )
 
 
@@ -88,6 +129,35 @@ def _from_blocks(blocks, shape):
     for d in shape:
         n *= d
     return blocks.reshape(-1)[:n].reshape(shape)
+
+
+def _layout_for(shape) -> str:
+    """``TILES`` where the leaf's last two dimensions are whole (8, 128)
+    tiles: then ``_to_tiles`` is the array as it lies in HBM. Anything
+    else (1-D leaves, odd widths) takes the padded ``BLOCKS`` path."""
+    if len(shape) < 2 or shape[-1] % BLOCK or shape[-2] % _SUBLANES:
+        return BLOCKS
+    return TILES
+
+
+def _to_tiles(x):
+    """``[..., R, C]`` → ``[..., R/8, C/128, 8, 128]``: one (8, 128) tile
+    in the last two dimensions, tiles in row-major order — byte for byte
+    the f32 leaf under the TPU's (8, 128) tiling, so XLA takes the view as
+    a bitcast where ``_to_blocks`` moves every byte. Row ``r`` of tile
+    ``[..., i, j]`` is elements ``[128 j, 128 j + 128)`` of leaf row
+    ``8 i + r``: the same 128 elements as one row of ``_to_blocks``, so
+    scales and codes are the same numbers in another order. The leading
+    dimensions stay as they are: flattened into one, the view is still
+    a bitcast but XLA no longer fuses ``apply_updates`` onto ``delta``
+    (seen in the compile of a ``[64, 2048, 1024]`` leaf for a v5e)."""
+    *lead, R, C = x.shape
+    tiled = x.reshape(*lead, R // _SUBLANES, _SUBLANES, C // BLOCK, BLOCK)
+    return tiled.swapaxes(-3, -2)
+
+
+def _from_tiles(t, shape):
+    return t.swapaxes(-3, -2).reshape(shape)
 
 
 def _sqrt_map_quant(x, signed, qmax):
@@ -155,14 +225,38 @@ def _dequant_block_math_wide(codes, s2d):
     return (y3 * s2d[:, :, None]).reshape(R, BLOCK)
 
 
-def quantize_8bit(x, signed: bool = True) -> Quantized8:
-    codes, scales = _quant_block_math(
-        _to_blocks(x.astype(jnp.float32)), signed
-    )
-    return Quantized8(codes, scales, tuple(x.shape), signed)
+# -- "tiles" scale layout ----------------------------------------------------
+# The block math wants a trailing-1 scale to broadcast over a block's 128
+# lanes; at rest that 1 would pad to a whole lane row (the blowup the wide
+# layout above avoids), so a TILES leaf keeps [..., R/8, C/128, 8].
+def _quant_block_math_tiles(x, signed):
+    codes, scale = _quant_block_math(x, signed)
+    return codes, scale[..., 0]
+
+
+def _dequant_block_math_tiles(codes, scales):
+    return _dequant_block_math(codes, scales[..., None])
+
+
+def quantize_8bit(
+    x, signed: bool = True, layout: str | None = None
+) -> Quantized8:
+    """Quantize a leaf; the layout follows its shape (``_layout_for``)
+    unless the caller's kernel wants ``BLOCKS``."""
+    layout = layout or _layout_for(x.shape)
+    x = x.astype(jnp.float32)
+    if layout == TILES:
+        codes, scales = _quant_block_math_tiles(_to_tiles(x), signed)
+    else:
+        codes, scales = _quant_block_math(_to_blocks(x), signed)
+    return Quantized8(codes, scales, tuple(x.shape), signed, layout)
 
 
 def dequantize_8bit(q: Quantized8):
+    if q.layout == TILES:
+        return _from_tiles(
+            _dequant_block_math_tiles(q.codes, q.scales), q.shape
+        )
     return _from_blocks(_dequant_block_math(q.codes, q.scales), q.shape)
 
 
@@ -291,10 +385,18 @@ def _adam8_update_pallas(
 def _adam8_update_jnp(
     g_blocks, mq, vq, scalars, b1, b2, classic_eps=True
 ):
+    """``g_blocks`` is the gradient in the moments' own view: ``[nblocks,
+    BLOCK]`` rows for a ``BLOCKS`` (or the flat path's wide) state,
+    ``_to_tiles(g)`` for a ``TILES`` one. ``delta`` comes back in the
+    same view: the block math reduces over the last axis and broadcasts
+    a block's scale along it in every layout."""
     lrA, invbc2, eps = scalars[0], scalars[1], scalars[2]
-    wide = mq.scales.shape[-1] == BLOCK  # flat path's dense scale layout
-    dequant = _dequant_block_math_wide if wide else _dequant_block_math
-    quant = _quant_block_math_wide if wide else _quant_block_math
+    if mq.layout == TILES:
+        dequant, quant = _dequant_block_math_tiles, _quant_block_math_tiles
+    elif mq.scales.shape[-1] == BLOCK:  # flat path's dense scale layout
+        dequant, quant = _dequant_block_math_wide, _quant_block_math_wide
+    else:
+        dequant, quant = _dequant_block_math, _quant_block_math
     m = dequant(mq.codes, mq.scales)
     v = dequant(vq.codes, vq.scales)
     m_new, v_new, delta = _adam8_block_math(
@@ -303,8 +405,8 @@ def _adam8_update_jnp(
     mc, ms = quant(m_new, signed=True)
     vc, vs = quant(v_new, signed=False)
     return (
-        Quantized8(mc, ms, mq.shape, True),
-        Quantized8(vc, vs, vq.shape, False),
+        Quantized8(mc, ms, mq.shape, True, mq.layout),
+        Quantized8(vc, vs, vq.shape, False, vq.layout),
         delta,
     )
 
@@ -482,6 +584,20 @@ def _adam8_update_pallas_flat(
     )
 
 
+def layout_elems(opt_state) -> dict:
+    """Elements held by the state's ``Quantized8`` moments, by layout
+    tag (both moments counted; nothing for an fp32 optimizer): what
+    ``PipelineStats.opt_q8_tiles_elems`` / ``opt_q8_blocks_elems``
+    report, so a leaf that fell back to the relayout path is seen."""
+    elems = {TILES: 0, BLOCKS: 0}
+    for q in jax.tree.leaves(
+        opt_state, is_leaf=lambda x: isinstance(x, Quantized8)
+    ):
+        if isinstance(q, Quantized8):
+            elems[q.layout] += math.prod(q.shape)
+    return elems
+
+
 class Adam8State(NamedTuple):
     count: jnp.ndarray
     mu: optax.Updates  # pytree of Quantized8
@@ -527,11 +643,6 @@ def adamw_8bit(
         )
     classic = eps_root == 0.0
     eps_val = eps if classic else eps_root
-    # bits=4 packs the FIRST moment into nibbles; the second moment
-    # stays int8 (see _adam4_update_jnp) → 1.5 bytes/param of state
-    quantize_m = quantize_8bit if bits == 8 else quantize_4bit
-    quantize_v = quantize_8bit
-
     def _pallas_enabled():
         if bits != 8:
             return False
@@ -540,15 +651,26 @@ def adamw_8bit(
         return jax.default_backend() == "tpu"
 
     def init_fn(params):
+        # the Pallas tree kernel and the 4-bit update take [nblocks, BLOCK]
+        # rows; the jnp 8-bit update reads a leaf where it lies, so there
+        # the layout follows the leaf's shape (``_layout_for``)
+        layout = BLOCKS if _pallas_enabled() or bits == 4 else None
+
         def _init_m(p):
+            zeros = jnp.zeros_like(p, jnp.float32)
             if p.size < min_quantized_size:
-                return jnp.zeros_like(p, jnp.float32)
-            return quantize_m(jnp.zeros_like(p, jnp.float32), True)
+                return zeros
+            # bits=4 packs the FIRST moment into nibbles; the second
+            # stays int8 (see _adam4_update_jnp) → 1.5 bytes/param
+            if bits == 4:
+                return quantize_4bit(zeros, True)
+            return quantize_8bit(zeros, True, layout)
 
         def _init_v(p):
+            zeros = jnp.zeros_like(p, jnp.float32)
             if p.size < min_quantized_size:
-                return jnp.zeros_like(p, jnp.float32)
-            return quantize_v(jnp.zeros_like(p, jnp.float32), False)
+                return zeros
+            return quantize_8bit(zeros, False, layout)
 
         return Adam8State(
             count=jnp.zeros((), jnp.int32),
@@ -571,21 +693,31 @@ def adamw_8bit(
                     g, m, v, lrA, invbc2, eps_val, b1, b2, classic
                 )
                 return delta.astype(g.dtype), m_new, v_new
-            g_blocks = _to_blocks(g.astype(jnp.float32))
+            # the gradient in the moments' own view, delta back through
+            # its inverse. For TILES both are bitcasts on the TPU: decay,
+            # later scales and apply_updates fuse onto delta, which then
+            # never exists in HBM; _to_blocks moves every byte, twice
+            tiles = isinstance(m, Quantized8) and m.layout == TILES
+            g32 = g.astype(jnp.float32)
+            g_view = _to_tiles(g32) if tiles else _to_blocks(g32)
             if isinstance(m, Quantized4):
                 mq, vq, delta = _adam4_update_jnp(
-                    g_blocks, m, v, scalars, b1, b2, classic
+                    g_view, m, v, scalars, b1, b2, classic
                 )
-            elif _pallas_enabled():
+            elif _pallas_enabled() and not tiles:
                 mq, vq, delta = _adam8_update_pallas(
-                    g_blocks, m, v, scalars, b1, b2, interpret=False,
+                    g_view, m, v, scalars, b1, b2, interpret=False,
                     classic_eps=classic,
                 )
             else:
                 mq, vq, delta = _adam8_update_jnp(
-                    g_blocks, m, v, scalars, b1, b2, classic
+                    g_view, m, v, scalars, b1, b2, classic
                 )
-            return _from_blocks(delta, g.shape).astype(g.dtype), mq, vq
+            if tiles:
+                delta = _from_tiles(delta, g.shape)
+            else:
+                delta = _from_blocks(delta, g.shape)
+            return delta.astype(g.dtype), mq, vq
 
         flat_g, treedef = jax.tree.flatten(grads)
         flat_m = treedef.flatten_up_to(state.mu)

@@ -542,8 +542,16 @@ class ElasticTrainer:
             for x in jax.tree_util.tree_leaves(self.state)
             if hasattr(x, "dtype")
         )
-        from dlrover_tpu.ops.quantized_optim import Adam8FlatState
+        from dlrover_tpu.ops.quantized_optim import (
+            BLOCKS,
+            TILES,
+            Adam8FlatState,
+            layout_elems,
+        )
 
+        q8 = layout_elems(self.state.opt_state)
+        self.pipeline_stats.opt_q8_tiles_elems = q8[TILES]
+        self.pipeline_stats.opt_q8_blocks_elems = q8[BLOCKS]
         m = self.accel.strategy.mesh
         has_flat = any(
             isinstance(x, Adam8FlatState)
@@ -602,8 +610,18 @@ class ElasticTrainer:
         rows = self._builds.builds[self._builds_logged:]
         self._builds_logged = len(self._builds.builds)
         if rows:
+            # beside the build that made the state: where its int8
+            # moments lie (ops/quantized_optim.py), if it has any
+            tiles = self.pipeline_stats.opt_q8_tiles_elems
+            blocks = self.pipeline_stats.opt_q8_blocks_elems
+            q8 = (
+                f"; int8 moments: {tiles} elements in tiles, "
+                f"{blocks} in blocks"
+                if tiles + blocks and any(b["what"] == "init" for b in rows)
+                else ""
+            )
             logger.info(
-                f"programs built {when}: {describe_builds(rows)}"
+                f"programs built {when}: {describe_builds(rows)}{q8}"
             )
 
     def _first_build(self, what: str):

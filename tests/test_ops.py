@@ -777,6 +777,162 @@ class TestQuantizedOptim:
         assert u["tiny"].shape == (8,)
         assert int(st2.count) == 1
 
+    @pytest.mark.parametrize(
+        "shape", [(16, 256), (4, 8, 384), (6288, 256)], ids=str
+    )
+    def test_tile_layout_is_the_block_layout_in_another_order(self, shape):
+        """A leaf of whole (8, 128) tiles keeps its moments in its own
+        tile order; its 128-element blocks are the ones the [nblocks,
+        128] layout has, so 20 steps give the same parameters, scales
+        and codes as the block-layout update called directly."""
+        from dlrover_tpu.ops.quantized_optim import (
+            BLOCKS,
+            TILES,
+            _from_blocks,
+            _from_tiles,
+        )
+
+        lr, b1, b2, eps, wd = 1e-2, 0.9, 0.95, 1e-8, 0.1
+        rng = np.random.default_rng(7)
+        p0 = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        target = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        weight = jnp.asarray(
+            np.exp(rng.normal(size=shape) * 2.0), jnp.float32
+        )
+        grad = jax.grad(lambda p: jnp.sum(weight * (p - target) ** 2))
+
+        tx = adamw_8bit(
+            lr, b1=b1, b2=b2, eps=eps, weight_decay=wd,
+            min_quantized_size=0, use_pallas=False,
+        )
+        st = tx.init({"w": p0})
+        assert st.mu["w"].layout == st.nu["w"].layout == TILES
+        R, C = shape[-2:]
+        assert st.mu["w"].codes.shape == (
+            *shape[:-2], R // 8, C // 128, 8, 128,
+        )
+        assert st.nu["w"].scales.shape == (*shape[:-2], R // 8, C // 128, 8)
+
+        @jax.jit
+        def step_tiles(p, st):
+            u, st = tx.update({"w": grad(p)}, st, {"w": p})
+            return optax.apply_updates({"w": p}, u)["w"], st
+
+        @jax.jit
+        def step_blocks(p, mq, vq, count):
+            count = count + 1
+            cf = count.astype(jnp.float32)
+            sc = jnp.stack([
+                jnp.float32(lr) / (1.0 - b1**cf),
+                1.0 / (1.0 - b2**cf),
+                jnp.float32(eps),
+            ])
+            mq, vq, delta = _adam8_update_jnp(
+                _to_blocks(grad(p)), mq, vq, sc, b1, b2
+            )
+            u = _from_blocks(delta, shape) - lr * wd * p
+            return p + u, mq, vq, count
+
+        zeros = jnp.zeros(shape, jnp.float32)
+        mq = quantize_8bit(zeros, True, BLOCKS)
+        vq = quantize_8bit(zeros, False, BLOCKS)
+        assert mq.layout == BLOCKS and mq.codes.shape[1:] == (128,)
+        pt = pb = p0
+        count = jnp.zeros((), jnp.int32)
+        for _ in range(20):
+            pt, st = step_tiles(pt, st)
+            pb, mq, vq, count = step_blocks(pb, mq, vq, count)
+        np.testing.assert_allclose(pt, pb, rtol=1e-6, atol=1e-7)
+        for tiled, blocked in ((st.mu["w"], mq), (st.nu["w"], vq)):
+            assert tiled.layout == TILES and tiled.shape == shape
+            np.testing.assert_array_equal(
+                np.asarray(tiled.scales).swapaxes(-2, -1).reshape(-1),
+                np.asarray(blocked.scales).reshape(-1),
+            )
+            # the same numbers but for a rounding tie where the backend
+            # contracts a multiply-add differently in the two programs
+            d = np.abs(
+                np.asarray(_from_tiles(tiled.codes, shape), np.int32)
+                - np.asarray(_from_blocks(blocked.codes, shape), np.int32)
+            )
+            assert d.max() <= 1 and (d > 0).mean() < 1e-4, (
+                d.max(), (d > 0).mean(),
+            )
+
+    @pytest.mark.parametrize("shape", [(4097,), (48, 100), (7, 1024)], ids=str)
+    def test_odd_leaves_keep_the_block_layout(self, shape):
+        """1-D leaves, widths that are not whole lane rows and row
+        counts that are not whole sublane groups stay on the padded
+        [nblocks, 128] path: same state shapes, same values."""
+        from dlrover_tpu.ops.quantized_optim import BLOCKS, _from_blocks
+
+        n = int(np.prod(shape))
+        nblocks = -(-n // 128)
+        rng = np.random.default_rng(8)
+        g = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        p = jnp.asarray(rng.normal(size=shape), jnp.float32)
+        tx = adamw_8bit(1e-2, min_quantized_size=0, use_pallas=False)
+        st = tx.init({"w": p})
+        for q in (st.mu["w"], st.nu["w"]):
+            assert q.layout == BLOCKS
+            assert q.codes.shape == (nblocks, 128)
+            assert q.scales.shape == (nblocks, 1)
+        u, st2 = tx.update({"w": g}, st, {"w": p})
+        cf = jnp.float32(1.0)  # the first step's bias corrections
+        sc = jnp.stack([
+            jnp.float32(1e-2) / (1.0 - 0.9**cf), 1.0 / (1.0 - 0.999**cf),
+            jnp.float32(1e-8),
+        ])
+        mq, vq, delta = _adam8_update_jnp(
+            _to_blocks(g), st.mu["w"], st.nu["w"], sc, 0.9, 0.999
+        )
+        np.testing.assert_array_equal(st2.mu["w"].codes, mq.codes)
+        np.testing.assert_array_equal(st2.nu["w"].codes, vq.codes)
+        np.testing.assert_array_equal(st2.nu["w"].scales, vq.scales)
+        np.testing.assert_allclose(
+            u["w"], _from_blocks(delta, shape), rtol=1e-6
+        )
+
+    @pytest.mark.parametrize(
+        "shape,layout",
+        [
+            ((16, 256), "tiles"), ((4, 8, 384), "tiles"),
+            ((4097,), "blocks"), ((48, 100), "blocks"),
+            ((16, 256), "forced-blocks"),
+        ],
+        ids=str,
+    )
+    def test_quant_roundtrip_returns_the_leafs_shape(self, shape, layout):
+        from dlrover_tpu.ops.quantized_optim import BLOCKS
+
+        x = jnp.asarray(
+            np.random.default_rng(9).normal(size=shape), jnp.float32
+        )
+        forced = layout == "forced-blocks"
+        q = quantize_8bit(x, True, BLOCKS if forced else None)
+        assert q.layout == (BLOCKS if forced else layout)
+        back = dequantize_8bit(q)
+        assert back.shape == shape and q.shape == shape
+        assert float(jnp.abs(back - x).max() / jnp.abs(x).max()) < 0.02
+        # the blocks are the same 128 elements in either layout
+        np.testing.assert_array_equal(
+            back, dequantize_8bit(quantize_8bit(x, True, BLOCKS))
+        )
+
+    @pytest.mark.parametrize("shape", [(16, 256), (4097,)], ids=str)
+    def test_tree_flatten_keeps_the_layout_tag(self, shape):
+        q = quantize_8bit(jnp.ones(shape, jnp.float32), False)
+        leaves, treedef = jax.tree.flatten({"m": q})
+        assert len(leaves) == 2
+        back = jax.tree.unflatten(treedef, leaves)["m"]
+        assert (back.layout, back.shape, back.signed) == (
+            q.layout, shape, False,
+        )
+        # through jit too: the tag is aux data and never traced
+        out = jax.jit(lambda t: t)(q)
+        assert out.layout == q.layout and out.codes.shape == q.codes.shape
+        assert q.layout in repr(q)
+
     def test_4bit_roundtrip_and_memory(self):
         from dlrover_tpu.ops.quantized_optim import (
             dequantize_4bit,

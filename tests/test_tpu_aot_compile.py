@@ -149,6 +149,75 @@ def test_adam8_kernel_compiles(leaf, variant, one_chip):
     _compile_for_chip(tx.update, params, state, params)
 
 
+def _entry_results(hlo_text):
+    """(op, element counts of its result's arrays) for every instruction
+    of the optimised HLO's entry computation."""
+    import math
+    import re
+
+    entry = hlo_text[hlo_text.index("\nENTRY"):]
+    out = []
+    for line in entry.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ([\w\-]+)\(", line
+        )
+        if m:
+            arrays = re.findall(r"\b([a-z]+\d+)\[([\d,]*)\]", m.group(1))
+            out.append((m.group(2), [
+                (dtype, math.prod(int(d) for d in dims.split(",") if d))
+                for dtype, dims in arrays
+            ]))
+    return out
+
+
+@pytest.mark.parametrize("shape", [(8, 2048, 1024), (2048, 2048)], ids=str)
+def test_adam8_tile_view_stays_a_bitcast(shape, one_chip):
+    """``adamw_8bit(use_pallas=False)`` on a leaf of whole (8, 128)
+    tiles: the moments' view of the gradient, and delta's way back, must
+    cost nothing on the chip. No ``reshape`` / ``copy`` / ``transpose``
+    of the leaf's size in the entry computation (the [nblocks, 128]
+    layout had two), no Pallas kernel, and one leaf-sized f32 result,
+    the new parameter: delta is fused into the apply."""
+    import math
+
+    import optax
+
+    from dlrover_tpu.ops.quantized_optim import TILES, adamw_8bit
+
+    tx = adamw_8bit(
+        3e-4, weight_decay=0.1, min_quantized_size=4096, use_pallas=False
+    )
+
+    def step(p, g, st):
+        u, st = tx.update(g, st, p)
+        return optax.apply_updates(p, u), st
+
+    params = {"w": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)}
+    state = jax.eval_shape(tx.init, params)
+    assert state.mu["w"].layout == state.nu["w"].layout == TILES
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        state,
+    )
+    lowered = jax.jit(step, donate_argnums=(0, 2)).lower(
+        params, params, state
+    )
+    assert "tpu_custom_call" not in lowered.as_text()
+    results = _entry_results(lowered.compile().as_text())
+    n = math.prod(shape)
+    moved = [
+        (op, arrays) for op, arrays in results
+        if op in ("reshape", "copy", "transpose")
+        and any(count == n for _, count in arrays)
+    ]
+    assert not moved, moved
+    leaf_f32 = [
+        op for op, arrays in results
+        if op == "fusion" and ("f32", n) in arrays
+    ]
+    assert len(leaf_f32) == 1, results
+
+
 @pytest.mark.parametrize("dim", [64, 128])
 @pytest.mark.parametrize("op", ["gather", "scatter"])
 def test_device_tier_kernel_compiles(op, dim, one_chip, monkeypatch):

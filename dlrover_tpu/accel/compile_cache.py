@@ -13,7 +13,7 @@ makes resize a *live reconfiguration*:
   ``common.jax_compat``) so a replacement worker warm-starts from a
   peer's serialized executable.  A generic ``get_or_build`` memo rides
   along for callables that cannot be serialized (lazily-jitted eval
-  steps — the per-mesh memoization ``ElasticTrainer._build_eval_step``
+  steps — the per-mesh memoization ``trainer.elastic.evaluation.Evaluator``
   uses).
 - ``SpeculativeCompiler``: a background thread that pre-lowers the
   train step for the *likely next* meshes (the master's

@@ -16,7 +16,7 @@ dry-runner) and the TF graph profile extractor. Two sources of truth:
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -52,6 +52,11 @@ def chip_peak_tflops(device) -> Optional[float]:
     )
 
 
+def _reported_to(places: int):
+    """A float counter that ``as_dict`` rounds to ``places`` decimals."""
+    return field(default=0.0, metadata={"places": places})
+
+
 @dataclass
 class PipelineStats:
     """Counters for the overlapped host↔device pipeline: the device
@@ -64,11 +69,11 @@ class PipelineStats:
     prefetch_hits: int = 0
     prefetch_misses: int = 0
     prefetch_reprimes: int = 0
-    prefetch_wait_s: float = 0.0  # time the consumer blocked on misses
+    prefetch_wait_s: float = _reported_to(4)  # time the consumer blocked on misses
     stage_chunks: int = 0
     stage_bytes: int = 0
     stage_backlog_bytes: int = 0  # bytes still to stage (last observed)
-    stage_block_s: float = 0.0  # critical-path seconds spent in advance()
+    stage_block_s: float = _reported_to(4)  # critical-path seconds spent in advance()
     stage_commits: int = 0
     donated_steps: int = 0
     safe_steps: int = 0  # steps run without donation (staging in flight)
@@ -84,7 +89,7 @@ class PipelineStats:
     reshard_bytes_device: int = 0  # state remapped without a host trip
     reshard_bytes_host: int = 0  # leaves that fell back to shm restore
     resize_count: int = 0
-    resize_downtime_ms: float = 0.0  # last resize's wall downtime
+    resize_downtime_ms: float = _reported_to(2)  # last resize's wall downtime
     # ranks left idle by the last resize's graceful degradation (a
     # non-divisible device count picks the largest valid mesh <= n
     # instead of failing; also dlrover_resize_idle_ranks gauge)
@@ -95,17 +100,13 @@ class PipelineStats:
     # when the current strategy is unpadded. resize_idle_ranks stays 0
     # on the rebalanced path (also dlrover_resize_mb_pad gauge).
     resize_mb_pad: int = 0
-    # capacity re-splits applied by the MoE rebalancer (trainer
-    # moe_rebalance_interval; each one is a step rebuild through the
-    # AOT cache)
-    moe_capacity_resplits: int = 0
     # routing of the steps reported at the log cadence (trainer
     # _log_step, from metrics of a step already waited for): how many
     # reports, their summed moe_drop_rate, and their summed largest
     # expert's share of the assignments x num_experts (1.0 = even)
     moe_reports: int = 0
-    moe_drop_rate_sum: float = 0.0
-    moe_max_load_sum: float = 0.0
+    moe_drop_rate_sum: float = _reported_to(6)
+    moe_max_load_sum: float = _reported_to(6)
     # elements of the optimizer's int8 moments (ops/quantized_optim.py
     # ``Quantized8``, both moments) by where their blocks lie: in the
     # leaf's own tile order, which the update reads as a bitcast, or in
@@ -122,12 +123,12 @@ class PipelineStats:
     grad_sync_path: str = ""
     # standalone wall time of one bucketed sync (its roofline: the
     # in-step cost is this minus whatever the scheduler overlaps)
-    grad_sync_ms: float = 0.0
+    grad_sync_ms: float = _reported_to(3)
     # per-link split of the standalone sync (grad_sync.measure_sync_
     # legs_ms): slice-local ICI legs vs the cross-slice DCN all-reduce;
     # flat (single-slice) plans are all-ICI by construction
-    grad_sync_ici_ms: float = 0.0
-    grad_sync_dcn_ms: float = 0.0
+    grad_sync_ici_ms: float = _reported_to(3)
+    grad_sync_dcn_ms: float = _reported_to(3)
     # fraction of sync wire time hidden behind backward compute; the
     # analytic model constant on backends where overlap cannot be
     # profiled (None until a grad-sync plan is active)
@@ -148,14 +149,15 @@ class PipelineStats:
     restore_bytes: int = 0
     # choosing the committed step: the storage tracker's newest step
     # that verifies (the repairing rank reads and checksums its files)
-    restore_storage_verify_s: float = 0.0
+    restore_storage_verify_s: float = _reported_to(4)
     # the fleet's agreement on the storage step and on the shm step
-    restore_agree_s: float = 0.0
-    restore_lock_wait_s: float = 0.0  # blocking acquire of the shard lock
-    restore_shm_verify_s: float = 0.0  # crc pass over the shm records
-    restore_storage_read_s: float = 0.0  # 0 on the shm path
+    restore_agree_s: float = _reported_to(4)
+    # blocking acquire of the shard lock; crc pass over the shm records
+    restore_lock_wait_s: float = _reported_to(4)
+    restore_shm_verify_s: float = _reported_to(4)
+    restore_storage_read_s: float = _reported_to(4)  # 0 on the shm path
     # records -> device, to block_until_ready of the restored state
-    restore_h2d_s: float = 0.0
+    restore_h2d_s: float = _reported_to(4)
 
     def set_restore(self, record: Optional[Dict[str, float]]):
         """Fold ``CheckpointEngine.last_restore`` in (None = no load)."""
@@ -184,61 +186,28 @@ class PipelineStats:
         return [self.grad_bytes_wire, self.grad_bytes_raw]
 
     def as_dict(self) -> Dict[str, Any]:
-        d = {
-            "prefetch_hits": self.prefetch_hits,
-            "prefetch_misses": self.prefetch_misses,
-            "prefetch_overlap_pct": self.prefetch_overlap_pct,
-            "prefetch_reprimes": self.prefetch_reprimes,
-            "prefetch_wait_s": round(self.prefetch_wait_s, 4),
-            "stage_chunks": self.stage_chunks,
-            "stage_bytes": self.stage_bytes,
-            "stage_backlog_bytes": self.stage_backlog_bytes,
-            "stage_block_s": round(self.stage_block_s, 4),
-            "stage_commits": self.stage_commits,
-            "donated_steps": self.donated_steps,
-            "safe_steps": self.safe_steps,
-            "steps_ahead": self.steps_ahead,
-            "donated_bytes": self.donated_bytes,
-            "compile_cache_hits": self.compile_cache_hits,
-            "compile_cache_misses": self.compile_cache_misses,
-            "compile_cache_hit_pct": self.compile_cache_hit_pct,
-            "reshard_bytes_device": self.reshard_bytes_device,
-            "reshard_bytes_host": self.reshard_bytes_host,
-            "reshard_bytes_device_vs_host": [
-                self.reshard_bytes_device,
-                self.reshard_bytes_host,
-            ],
-            "resize_count": self.resize_count,
-            "resize_downtime_ms": round(self.resize_downtime_ms, 2),
-            "resize_idle_ranks": self.resize_idle_ranks,
-            "resize_mb_pad": self.resize_mb_pad,
-            "moe_capacity_resplits": self.moe_capacity_resplits,
-            "moe_reports": self.moe_reports,
-            "moe_drop_rate_sum": round(self.moe_drop_rate_sum, 6),
-            "moe_max_load_sum": round(self.moe_max_load_sum, 6),
-            "opt_q8_tiles_elems": self.opt_q8_tiles_elems,
-            "opt_q8_blocks_elems": self.opt_q8_blocks_elems,
-            "grad_sync_path": self.grad_sync_path,
-            # numeric twin for the metrics registry (fold_pipeline_
-            # stats skips strings): 1 = explicit, 0 = gspmd fallback,
-            # None = no trainer resolved a plan yet
-            "grad_sync_explicit": (
-                None
-                if not self.grad_sync_path
-                else int(self.grad_sync_path == "explicit")
-            ),
-            "grad_sync_ms": round(self.grad_sync_ms, 3),
-            "grad_sync_ici_ms": round(self.grad_sync_ici_ms, 3),
-            "grad_sync_dcn_ms": round(self.grad_sync_dcn_ms, 3),
-            "comm_overlap_pct": self.comm_overlap_pct,
-            "overlap_pct_measured": self.overlap_pct_measured,
-            "grad_bytes_wire": self.grad_bytes_wire,
-            "grad_bytes_raw": self.grad_bytes_raw,
-            "grad_bytes_wire_vs_raw": self.grad_bytes_wire_vs_raw,
-        }
-        for key in RESTORE_FIELDS:
-            value = getattr(self, key)
-            d[key] = round(value, 4) if isinstance(value, float) else value
+        """Every field under its own name (a float to the places
+        written beside it), and the five derived keys."""
+        d = {}
+        for f in fields(self):
+            value = getattr(self, f.name)
+            places = f.metadata.get("places")
+            d[f.name] = value if places is None else round(value, places)
+        d["prefetch_overlap_pct"] = self.prefetch_overlap_pct
+        d["compile_cache_hit_pct"] = self.compile_cache_hit_pct
+        d["grad_bytes_wire_vs_raw"] = self.grad_bytes_wire_vs_raw
+        d["reshard_bytes_device_vs_host"] = [
+            self.reshard_bytes_device,
+            self.reshard_bytes_host,
+        ]
+        # numeric twin for the metrics registry (fold_pipeline_stats
+        # skips strings): 1 = explicit, 0 = gspmd fallback, None = no
+        # trainer resolved a plan yet
+        d["grad_sync_explicit"] = (
+            None
+            if not self.grad_sync_path
+            else int(self.grad_sync_path == "explicit")
+        )
         return d
 
     def summary(self) -> str:
@@ -295,10 +264,8 @@ class PipelineStats:
         )
 
 
-RESTORE_FIELDS = (
-    "restore_source", "restore_bytes", "restore_storage_verify_s",
-    "restore_agree_s", "restore_lock_wait_s", "restore_shm_verify_s",
-    "restore_storage_read_s", "restore_h2d_s",
+RESTORE_FIELDS = tuple(
+    f.name for f in fields(PipelineStats) if f.name.startswith("restore_")
 )
 
 

@@ -584,18 +584,34 @@ def _adam8_update_pallas_flat(
     )
 
 
-def layout_elems(opt_state) -> dict:
-    """Elements held by the state's ``Quantized8`` moments, by layout
-    tag (both moments counted; nothing for an fp32 optimizer): what
-    ``PipelineStats.opt_q8_tiles_elems`` / ``opt_q8_blocks_elems``
-    report, so a leaf that fell back to the relayout path is seen."""
+def int8_moments_on(opt_state, mesh) -> tuple:
+    """What a trainer asks of the state it built on ``mesh`` (a
+    ``MeshConfig``). ``(tiles, blocks)``: elements held by the state's
+    ``Quantized8`` moments, by layout tag (both moments counted; 0, 0
+    for an fp32 optimizer), which ``PipelineStats.opt_q8_tiles_elems`` /
+    ``opt_q8_blocks_elems`` report, so a leaf that fell back to the
+    relayout path is seen. And a ValueError for ``adamw_8bit_flat`` on a
+    model-sharded mesh."""
+    flats = jax.tree.leaves(
+        opt_state, is_leaf=lambda x: isinstance(x, Adam8FlatState)
+    )
+    has_flat = any(isinstance(x, Adam8FlatState) for x in flats)
+    if max(mesh.fsdp, mesh.tp, mesh.ep, mesh.sp, mesh.pp) > 1 and has_flat:
+        # the flat optimizer concatenates every big leaf per step:
+        # on a model-sharded mesh that forces cross-shard
+        # all-gathers and replicates the packed moment buffers,
+        # silently defeating ZeRO/TP sharding
+        raise ValueError(
+            "adamw_8bit_flat is for replicated/dp-only states; use "
+            "adamw_8bit (per-leaf) with fsdp/tp/ep/sp/pp sharding"
+        )
     elems = {TILES: 0, BLOCKS: 0}
     for q in jax.tree.leaves(
         opt_state, is_leaf=lambda x: isinstance(x, Quantized8)
     ):
         if isinstance(q, Quantized8):
             elems[q.layout] += math.prod(q.shape)
-    return elems
+    return elems[TILES], elems[BLOCKS]
 
 
 class Adam8State(NamedTuple):

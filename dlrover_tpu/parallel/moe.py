@@ -24,7 +24,6 @@ What moves the tokens depends on where the experts live:
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -384,6 +383,22 @@ def moe_layer(params: MoEParams, x, mesh, **kw):
     )(params, x)
 
 
+def fold_routing_report(metrics, stats) -> None:
+    """Fold a reported step's own ``moe_drop_rate`` / ``moe_expert_load``
+    into ``PipelineStats.moe_*``; nothing for a dense model. Copies to
+    the host of a step already waited for: an op on the device here
+    would queue behind the step in flight."""
+    if "moe_drop_rate" not in metrics:
+        return
+    import numpy as np
+
+    drop = np.asarray(metrics["moe_drop_rate"])
+    load = np.asarray(metrics["moe_expert_load"])
+    stats.moe_reports += 1
+    stats.moe_drop_rate_sum += float(drop)
+    stats.moe_max_load_sum += float(load.max()) * load.size
+
+
 # -- capacity rebalancing (ISSUE 13) ----------------------------------------
 
 
@@ -404,9 +419,8 @@ class CapacityRebalancer:
 
     Host-side and deliberately tiny: observe() is fed from the train
     metrics (``moe_expert_load``), splits() is consulted at a
-    recompile boundary (the trainer's ``moe_rebalance_interval``) —
-    capacities are STATIC shapes, so a re-split costs one step rebuild
-    through the AOT cache, amortized over the interval.
+    recompile boundary of the caller's choosing — capacities are STATIC
+    shapes, so a re-split costs one step rebuild.
     """
 
     def __init__(

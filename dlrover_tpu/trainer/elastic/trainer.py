@@ -21,10 +21,11 @@ facade owns the full elastic story so a user train script collapses to
 from __future__ import annotations
 
 import contextlib
+import json
 import os
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
 import numpy as np
@@ -32,7 +33,7 @@ import numpy as np
 from dlrover_tpu.accel.accelerate import AccelerateResult, auto_accelerate
 from dlrover_tpu.accel.strategy import Strategy
 from dlrover_tpu.agent.monitor import report_runtime_metrics
-from dlrover_tpu.common import faults, storage
+from dlrover_tpu.common import faults
 from dlrover_tpu.ckpt.checkpointer import FlashCheckpointer, StorageType
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.config import TransformerConfig
@@ -51,12 +52,25 @@ from dlrover_tpu.obs.goodput import GoodputLedger, install_default_ledger
 from dlrover_tpu.obs.metrics import default_registry, fold_pipeline_stats
 from dlrover_tpu.obs.trace import SpanHeartbeat, span
 from dlrover_tpu.parallel import transfer_sched
+from dlrover_tpu.parallel.moe import fold_routing_report
 from dlrover_tpu.trainer.elastic.dataloader import ElasticDataLoader
+from dlrover_tpu.trainer.elastic.evaluation import Evaluator
+from dlrover_tpu.trainer.elastic.optimizer import build_optimizer  # noqa: F401
 from dlrover_tpu.trainer.elastic.sampler import ElasticDistributedSampler
+from dlrover_tpu.trainer.elastic.sdc_fence import SdcFence
+from dlrover_tpu.trainer.elastic.step_programs import (
+    StepPrograms,
+    aot_supported,
+)
 
 
 # what ``_first_build`` hands back once a program is built
 _NO_BUILD = contextlib.nullcontext()
+# the eviction drain skips its DISK persist when less of the grace window
+# remains after the shm commit: the agent's shm handoff covers it
+_EVICTION_PERSIST_FLOOR_S = 5.0
+# wall-clock cap per candidate batch of the speculative compiler's thread
+_SPEC_COMPILE_BUDGET_S = 120.0
 
 
 @dataclass
@@ -95,7 +109,6 @@ class TrainerConfig:
     # in-memory flash saves stage device->shm in fixed-size chunks
     # interleaved between steps instead of one big drain (the commit
     # barrier is the only blocking point)
-    chunked_staging: bool = True
     stage_chunk_mb: int = 64
     # critical-path budget per step for draining stage chunks
     stage_budget_ms: float = 5.0
@@ -109,9 +122,6 @@ class TrainerConfig:
     # background thread, so the resize that lands finds its executable
     # already in the compile cache
     speculative_compile: bool = True
-    # wall-clock cap per candidate batch for that background thread
-    # (docs/elastic-resize.md: the speculative-compile budget knob)
-    spec_compile_budget_s: float = 120.0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # bucketed per-bucket collectives under shard_map (pure-dp RS+AG,
     # dp x fsdp ZeRO reduce-scatter into the shard layout, dp x tp/sp
@@ -141,21 +151,11 @@ class TrainerConfig:
     # REAL rows toward the faster slices). grad_accum>1 keeps the
     # idle-ranks behavior (pads would multiply across microbatches).
     mb_rebalance: bool = True
-    # >0: every this many steps, fold the measured per-expert routing
-    # load (moe_expert_load) into the CapacityRebalancer and — when
-    # the re-split changed — rebuild the step with the new
-    # cfg.capacity_splits (a recompile through the AOT cache,
-    # amortized over the interval). 0 = static capacity_factor.
-    moe_rebalance_interval: int = 0
     # -- eviction grace-window drain -----------------------------------
     # default grace window (seconds) for an eviction notice that does
     # not carry its own (SIGTERM, an `evict` command with arg=0);
     # DLROVER_TPU_EVICTION_DEADLINE_S overrides at construction
     eviction_grace_s: float = 30.0
-    # the emergency DISK persist is skipped when less than this remains
-    # of the grace window after the shm commit — the degraded-mode shm
-    # handoff (agent persists shm on restart) already covers it
-    eviction_persist_floor_s: float = 5.0
     # -- silent-data-corruption defense (parallel/sdc.py, ISSUE 20) ----
     # tier-1 fence: per-lane local grad norms ride the sync out-spec
     # and a robust median+MAD detector classifies each step (data
@@ -163,134 +163,9 @@ class TrainerConfig:
     # audit probe; conviction: verified rollback + quarantine halt).
     # DLROVER_TPU_SDC=1 enables without the knob; explicit dp-family
     # sync plans only (comm_overlap/grad_compress — the per-lane
-    # vector falls out of the bucket walk there)
+    # vector falls out of the bucket walk there); thresholds: parallel/
+    # sdc.SdcConfig; DLROVER_TPU_SDC_AUDIT_STEPS=N audits every N steps
     sdc_detect: bool = False
-    sdc_window: int = 32  # clean-step window behind the temporal test
-    sdc_min_history: int = 8  # observations before that test arms
-    sdc_spike_sigma: float = 6.0  # temporal (data-spike) threshold
-    sdc_suspect_sigma: float = 6.0  # cross-lane (device) threshold
-    # >0: also audit every N steps regardless of suspicion (a chip can
-    # be wrong in ways the norm fence misses);
-    # DLROVER_TPU_SDC_AUDIT_STEPS overrides
-    sdc_audit_steps: int = 0
-
-
-def build_optimizer(
-    name: str = "adamw",
-    lr: float = 3e-4,
-    schedule: str = "constant",
-    warmup_steps: int = 0,
-    total_steps: int = 10_000,
-    weight_decay: float = 0.0,
-    **kwargs,
-):
-    """Optimizer + LR schedule, retune-compatible (the AtorchTrainer
-    ``lr_scheduler_type`` surface, ref atorch_trainer.py:127).
-
-    The returned transform is built with ``optax.inject_hyperparams`` so
-    two knobs stay live in ``opt_state.hyperparams``:
-
-    - ``learning_rate`` — driven per-step by the chosen schedule
-      ("constant" | "cosine" | "linear"; warmup_steps prepends a linear
-      warmup);
-    - ``retune_scale`` — the master's batch-size linear-scaling factor
-      (ElasticTrainer._apply_lr_scale writes it), COMPOSED with the
-      schedule instead of being overwritten by it.
-    """
-    import optax
-
-    if schedule == "constant":
-        lr_fn = (
-            optax.linear_schedule(0.0, lr, warmup_steps)
-            if warmup_steps
-            else lr
-        )
-    elif schedule == "cosine":
-        # warmup_steps=0 means NO warmup: start at peak (forcing a
-        # 1-step warmup would make the first update a dead lr=0 step)
-        lr_fn = (
-            optax.warmup_cosine_decay_schedule(
-                init_value=0.0,
-                peak_value=lr,
-                warmup_steps=warmup_steps,
-                decay_steps=total_steps,
-            )
-            if warmup_steps
-            else optax.cosine_decay_schedule(lr, total_steps)
-        )
-    elif schedule == "linear":
-        decay = optax.linear_schedule(
-            lr, 0.0, max(total_steps - warmup_steps, 1)
-        )
-        lr_fn = (
-            optax.join_schedules(
-                [optax.linear_schedule(0.0, lr, warmup_steps), decay],
-                [warmup_steps],
-            )
-            if warmup_steps
-            else decay
-        )
-    else:
-        raise ValueError(f"unknown lr schedule {schedule!r}")
-
-    if name not in (
-        "adamw", "adam", "sgd", "agd", "adamw_8bit", "adamw_8bit_flat"
-    ):
-        raise ValueError(f"unknown optimizer {name!r}")
-
-    def make(learning_rate, retune_scale):
-        # weight_decay applies to EVERY optimizer: decoupled (after the
-        # adaptive direction) for adamw/adam/agd/8bit, classic
-        # L2-into-update for sgd. add_decayed_weights(0.0) is a no-op.
-        if name == "adamw":
-            opt = optax.adamw(
-                learning_rate, weight_decay=weight_decay, **kwargs
-            )
-        elif name == "adam":
-            opt = optax.chain(
-                optax.scale_by_adam(**kwargs),
-                optax.add_decayed_weights(weight_decay),
-                optax.scale_by_learning_rate(learning_rate),
-            )
-        elif name == "agd":
-            from dlrover_tpu.ops.optimizers import agd
-
-            opt = agd(
-                learning_rate, weight_decay=weight_decay, **kwargs
-            )
-        elif name == "adamw_8bit":
-            from dlrover_tpu.ops.quantized_optim import adamw_8bit
-
-            opt = adamw_8bit(
-                learning_rate, weight_decay=weight_decay, **kwargs
-            )
-        elif name == "adamw_8bit_flat":
-            from dlrover_tpu.ops.quantized_optim import adamw_8bit_flat
-
-            opt = adamw_8bit_flat(
-                learning_rate, weight_decay=weight_decay, **kwargs
-            )
-        else:
-            opt = optax.chain(
-                optax.add_decayed_weights(weight_decay),
-                optax.sgd(learning_rate, **kwargs),
-            )
-        return optax.chain(opt, optax.scale(retune_scale))
-
-    return optax.inject_hyperparams(make)(
-        learning_rate=lr_fn, retune_scale=1.0
-    )
-
-
-def _dense_eval_loss(params, x, y, cfg, mesh):
-    """PURE NLL — no MoE aux regularizers, so eval_loss/ppl are
-    comparable across parallelism modes and configs. One definition for
-    every mesh the trainer ever evaluates on (the pp path wraps the
-    pipeline's own loss instead)."""
-    from dlrover_tpu.models.transformer import forward, token_nll
-
-    logits, _ = forward(params, x, cfg, mesh)
-    return token_nll(logits, y)
 
 
 class ElasticTrainer:
@@ -388,31 +263,14 @@ class ElasticTrainer:
             )
         self.cfg = self.accel.cfg
         self.mesh = self.accel.mesh
-        self._step_fn = self.accel.step_fn
-        # donation-aware stepping: the donating twin runs whenever no
-        # async staging reads the state; flip back to the safe step for
-        # the staging window (a donated buffer mid-D2H is a crash)
-        self._donating_step_fn = (
-            self.accel.donating_step_fn
-            if self.tcfg.donation_aware
-            else None
-        )
-        from dlrover_tpu.accel.compile_cache import CompileCache
         from dlrover_tpu.accel.profiler import PipelineStats
 
         self.pipeline_stats = PipelineStats()
-        # AOT executables keyed by (mesh, shapes, donation, strategy):
-        # the first step on any mesh lands here, so a later resize back
-        # to that mesh skips the XLA compile entirely
-        self._compile_cache = CompileCache(stats=self.pipeline_stats)
+        # which program runs a step, and the compile cache behind them
+        self._programs = StepPrograms(
+            self.accel, self.pipeline_stats, self.tcfg.donation_aware
+        )
         self._spec_compiler = None
-        self._batch_avals = None  # ((shape, dtype), ...) of (x, y)
-        self._aot_primed = False
-        # the AOT executable + the exact batch shapes it was lowered
-        # for; other shapes (short final batch, master-retuned batch
-        # size) fall through to the retracing jit wrapper
-        self._aot_exec = None
-        self._aot_shapes = None
         self._last_candidates = None
         self._prefetcher = None
         self._stager = None
@@ -509,22 +367,6 @@ class ElasticTrainer:
         with self._builds.build("init"):
             self.state = self.accel.init_fn(jax.random.PRNGKey(0))
         self._grad_sync_plan = None
-        # MoE capacity rebalancer (ISSUE 13): folds the measured
-        # per-expert routing load into a periodic capacity re-split
-        # (cfg.capacity_splits) — each applied re-split is a step
-        # rebuild through the AOT cache
-        self._moe_rebalancer = None
-        if (
-            self._model_cfg.num_experts
-            and self.tcfg.moe_rebalance_interval > 0
-        ):
-            from dlrover_tpu.parallel.moe import CapacityRebalancer
-
-            self._moe_rebalancer = CapacityRebalancer(
-                self._model_cfg.num_experts,
-                capacity_factor=self._model_cfg.capacity_factor,
-                top_k=self._model_cfg.moe_top_k,
-            )
         # measured link-cost model (parallel/topology.py): probe once
         # per device fingerprint (warm restarts hit the JSON cache);
         # the dry-runner and the auto bucket sizer price wire time
@@ -534,44 +376,22 @@ class ElasticTrainer:
             self._setup_link_model()
         with self._builds.build("grad_sync"):
             self._setup_grad_sync()
-        self._setup_sdc()
-        self._audit_cal_loaded = False
-        self._setup_audit_budget()
-        self._state_nbytes = sum(
-            x.size * x.dtype.itemsize
-            for x in jax.tree_util.tree_leaves(self.state)
-            if hasattr(x, "dtype")
-        )
-        from dlrover_tpu.ops.quantized_optim import (
-            BLOCKS,
-            TILES,
-            Adam8FlatState,
-            layout_elems,
-        )
-
-        q8 = layout_elems(self.state.opt_state)
-        self.pipeline_stats.opt_q8_tiles_elems = q8[TILES]
-        self.pipeline_stats.opt_q8_blocks_elems = q8[BLOCKS]
-        m = self.accel.strategy.mesh
-        has_flat = any(
-            isinstance(x, Adam8FlatState)
-            for x in jax.tree_util.tree_leaves(
-                self.state.opt_state,
-                is_leaf=lambda x: isinstance(x, Adam8FlatState),
-            )
-        )
-        if max(m.fsdp, m.tp, m.ep, m.sp, m.pp) > 1 and has_flat:
-            # the flat optimizer concatenates every big leaf per step:
-            # on a model-sharded mesh that forces cross-shard
-            # all-gathers and replicates the packed moment buffers,
-            # silently defeating ZeRO/TP sharding
-            raise ValueError(
-                "adamw_8bit_flat is for replicated/dp-only states; use "
-                "adamw_8bit (per-leaf) with fsdp/tp/ep/sp/pp sharding"
-            )
-
         self.sampler = ElasticDistributedSampler(
             len(dataset), shuffle=True
+        )
+        # the SDC fence (parallel/sdc.py, ISSUE 20) on this world's lanes
+        self._sdc = SdcFence(
+            self._grad_sync_plan, self.mesh, self.sampler,
+            rollback=self._sdc_rollback, requested=self.tcfg.sdc_detect,
+        )
+        self._audit_cal_loaded = False
+        self._setup_audit_budget()
+        from dlrover_tpu.ops.quantized_optim import int8_moments_on
+
+        # where the state's int8 moments lie, if it has any
+        stats = self.pipeline_stats
+        stats.opt_q8_tiles_elems, stats.opt_q8_blocks_elems = (
+            int8_moments_on(self.state.opt_state, self.accel.strategy.mesh)
         )
         self.dataloader = ElasticDataLoader(
             dataset,
@@ -579,27 +399,19 @@ class ElasticTrainer:
             sampler=self.sampler,
             collate_fn=collate_fn,
         )
-        self._eval_dataset = eval_dataset
-        self._collate_fn = collate_fn
-        self._eval_step_fn = None  # built lazily on first evaluate()
         self._ckptr: Optional[FlashCheckpointer] = None
-        self._best_ckptr: Optional[FlashCheckpointer] = None
-        # the historical best survives restarts via a sidecar; a fresh
-        # run starts at +inf
-        self._best_eval_loss = float("inf")
-        self._last_best_save = 0.0
         if self.tcfg.ckpt_dir:
             self._ckptr = FlashCheckpointer(self.tcfg.ckpt_dir)
             # the restore's own programs (the packed put's unpack, the
             # residual's zeros) build here
             with self._builds.build("restore"):
                 self._maybe_restore()
-            if self.tcfg.save_best:
-                self._best_dir = os.path.join(
-                    self.tcfg.ckpt_dir, "best"
-                )
-                self._best_ckptr = FlashCheckpointer(self._best_dir)
-                self._best_eval_loss = self._load_best_sidecar()
+        # evaluation, best-eval checkpoint (made after the restore), early stop
+        self._eval = Evaluator(
+            self.tcfg, eval_dataset, collate_fn,
+            place=lambda batch: self._device_batch(batch, for_eval=True),
+            first_build=self._first_build, ckpt_state=self._ckpt_state,
+        )
         self._log_builds("at start")
 
     def _log_builds(self, when: str):
@@ -661,8 +473,6 @@ class ElasticTrainer:
             # measure (or reuse) the per-rail hidden fraction so the
             # dry-runner prices host traffic from observation instead
             # of the documented constant
-            from dlrover_tpu.parallel import transfer_sched
-
             transfer_sched.ensure_calibrated()
         except Exception as e:  # the probe must never kill training
             logger.warning(f"link-model probe failed: {e!r}")
@@ -797,7 +607,6 @@ class ElasticTrainer:
         adopts their warmup-mean observation as the budget instead."""
         import jax
 
-        from dlrover_tpu.parallel import transfer_sched
         from dlrover_tpu.parallel.grad_sync import (
             OVERLAP_HIDDEN_FRACTION,
             comm_time_legs_s,
@@ -868,166 +677,28 @@ class ElasticTrainer:
         )
 
     # -- silent-data-corruption defense (parallel/sdc.py, ISSUE 20) ----
-    def _setup_sdc(self):
-        """Build the tier-1 detector + tier-2 probe for the CURRENT
-        world (lane count = the sync plan's device total). Re-run after
-        a resize — the lane axis is per-world. Detection needs the
-        explicit dp-family sync path: that is where the per-lane norm
-        vector falls out of the bucket walk for free."""
-        from dlrover_tpu.parallel import sdc as sdc_mod
+    # what the fence found, under the names tools/chaos.py reads
+    @property
+    def sdc_convicted(self) -> tuple:
+        return self._sdc.convicted
 
-        self._sdc: Optional[sdc_mod.SdcDetector] = None
-        self._sdc_probe = None
-        # 1-step-delayed (step, loss_ref, norms_ref): the freshly
-        # dispatched step's outputs stay on device; the PREVIOUS
-        # step's are already materialized by dispatch depth, so the
-        # fetch adds no host sync to the critical path
-        self._sdc_pending = None
-        self._sdc_halt = False
-        self.sdc_convicted: tuple = ()
-        self.sdc_detect_step: Optional[int] = None
-        if not (self.tcfg.sdc_detect or sdc_mod.enabled()):
-            return
-        plan = self._grad_sync_plan
-        if (
-            plan is None
-            or getattr(plan, "three_d", False)
-            or getattr(plan, "kind", "") == "ep"
-        ):
-            logger.warning(
-                "sdc detection requested but this mesh has no per-lane"
-                " norm path (needs the explicit dp/ZeRO/tp sync plan —"
-                " comm_overlap or grad_compress); fences disabled"
-            )
-            return
-        cfg = sdc_mod.SdcConfig(
-            window=self.tcfg.sdc_window,
-            min_history=self.tcfg.sdc_min_history,
-            spike_sigma=self.tcfg.sdc_spike_sigma,
-            suspect_sigma=self.tcfg.sdc_suspect_sigma,
-            audit_steps=sdc_mod.audit_steps_from_env(
-                self.tcfg.sdc_audit_steps
-            ),
-        )
-        self._sdc = sdc_mod.SdcDetector(plan.total, cfg)
-        # lane i of the norm vector is device i of the mesh's stacked
-        # data axes — the probe must vote over the same ordering
-        self._sdc_probe = sdc_mod.AuditProbe(
-            devices=list(self.mesh.devices.flatten())
-        )
-        logger.info(
-            f"sdc defense armed: {plan.total} lanes, window "
-            f"{cfg.window}, suspect sigma {cfg.suspect_sigma}, audit "
-            f"cadence {cfg.audit_steps or 'on-suspicion'}"
-        )
+    @property
+    def sdc_detect_step(self) -> Optional[int]:
+        return self._sdc.detect_step
 
-    def _sdc_step(self, step: int, metrics: Dict, dev_norms):
-        """One detector observation per step (1-step delayed). Tier-1
-        verdicts route: data spike → count + log + black-box event
-        (never escalates — satellite 3's false-positive gate); device
-        suspect → tier-2 paired audit; audit conviction → tier-3
-        response (:meth:`_sdc_convict`)."""
-        # graftlint fault-site coverage + control-kind composability:
-        # device.sdc control kinds (delay — "the bad chip is also
-        # slow") fire here; the scale kind itself is a data kind baked
-        # into the step at trace time (models/train.py)
-        faults.fire("device.sdc")
-        pending, self._sdc_pending = self._sdc_pending, (
-            (step, metrics.get("loss"), dev_norms)
-            if dev_norms is not None
-            else None
-        )
-        if pending is None:
-            return
-        p_step, p_loss, p_norms = pending
-        try:
-            loss = float(p_loss)
-            norms = np.asarray(p_norms, dtype=np.float64).reshape(-1)
-        except Exception as e:
-            logger.warning(
-                f"sdc: fetching step {p_step} telemetry failed: {e!r}"
-            )
-            return
-        verdict = self._sdc.observe(p_step, loss, norms)
-        suspects: tuple = ()
-        if verdict.kind == "data_spike":
-            self._registry.counter(
-                "dlrover_sdc_data_spikes_total",
-                "steps classified as data spikes (skipped, not escalated)",
-            ).inc()
-            detail = (
-                f"step {p_step} (batch at sampler position "
-                f"{self.sampler.state_dict().get('completed_num', -1)})"
-                f": {verdict.detail}"
-            )
-            self._flight.note_event("sdc_data_spike", detail)
-            logger.warning(f"sdc data spike, skip-and-log: {detail}")
-        elif verdict.kind == "device_suspect":
-            self._registry.counter(
-                "dlrover_sdc_suspicions_total",
-                "tier-1 device-suspect verdicts (escalated to audit)",
-            ).inc()
-            if self.sdc_detect_step is None:
-                self.sdc_detect_step = p_step
-            logger.warning(
-                f"sdc device suspect at step {p_step}: lanes "
-                f"{list(verdict.suspects)} ({verdict.detail})"
-            )
-            suspects = verdict.suspects
-        cadence = self._sdc.cfg.audit_steps
-        if suspects or (cadence and p_step % cadence == 0):
-            self._registry.counter(
-                "dlrover_sdc_audits_run_total",
-                "tier-2 paired-device audit probes executed",
-            ).inc()
-            result = self._sdc_probe.run(p_step, suspects=suspects)
-            if result.convicted:
-                self._sdc_convict(p_step, result, verdict)
-            elif suspects and not result.inconclusive:
-                logger.info(
-                    f"sdc audit cleared lanes {list(suspects)} at step "
-                    f"{p_step} (bitwise agreement across rotated pairs)"
-                )
+    @property
+    def _sdc_halt(self) -> bool:
+        return self._sdc.halt
 
-    def _sdc_convict(self, step: int, result, verdict):
-        """Tier-3 response: evidence bundle (norm history + vote
-        matrix), ``sdc_conviction`` event to the master/Brain, verified
-        rollback with the downtime booked to ``restart_replay``, then
-        HALT this incarnation — the injected corruption is baked into
-        the compiled step (exactly like a real bad chip is baked into
-        the hardware), so the quarantine-drain model applies: the
-        master excludes the convicted host and the next world
-        re-assembles without it."""
-        import json as _json
-
+    def _sdc_rollback(self, step: int, evidence: Dict) -> int:
+        """What a conviction asks of the trainer: the ``sdc_conviction``
+        event to the master/Brain, then the verified rollback (downtime
+        booked to ``restart_replay``). Returns its step, -1 for none."""
         from dlrover_tpu.parallel.grad_sync import ensure_residual
 
-        self.sdc_convicted = tuple(result.convicted)
-        evidence = {
-            "step": step,
-            "convicted": list(result.convicted),
-            "votes": {
-                str(lane): [[p, bool(a)] for p, a in vv]
-                for lane, vv in result.votes.items()
-            },
-            "digests": list(result.digests),
-            "suspect_detail": verdict.detail if verdict else "",
-            "norm_history": self._sdc.history(),
-        }
-        self._registry.counter(
-            "dlrover_sdc_convictions_total",
-            "devices convicted by the paired audit vote",
-        ).inc(len(result.convicted))
-        self._flight.note_event(
-            "sdc_conviction",
-            f"lanes {list(result.convicted)} at step {step}",
-        )
-        self._flight.dump("sdc_conviction", extra=evidence, force=True)
         if self._event_reporter is not None:
             try:
-                self._event_reporter(
-                    "sdc_conviction", _json.dumps(evidence)
-                )
+                self._event_reporter("sdc_conviction", json.dumps(evidence))
             except Exception as e:
                 logger.warning(f"sdc conviction report failed: {e!r}")
         # PR-19 interop: the rollback stall and the replayed window are
@@ -1061,82 +732,9 @@ class ElasticTrainer:
                     )
             finally:
                 self._goodput.replay_end()
-        logger.error(
-            f"sdc conviction at step {step}: lanes "
-            f"{list(result.convicted)} convicted"
-            + (
-                f"; rolled back to verified step {rolled_to}"
-                if rolled_to >= 0
-                else ""
-            )
-            + "; halting for quarantine-drain"
-        )
-        # the detector's window described the corrupted trajectory and
-        # the auditor's recorded spans the pre-rollback incarnation
-        self._sdc.reset()
+        # the auditor's recorded spans are the pre-rollback incarnation's
         self._auditor.skip_to_now()
-        self._sdc_pending = None
-        self._sdc_halt = True
-
-    def _maybe_rebalance_experts(self, load) -> bool:
-        """Fold one measured per-expert routing-load vector into the
-        ``CapacityRebalancer``; when the re-split changed, rebuild the
-        train step with the new ``cfg.capacity_splits`` (static
-        shapes — one recompile through the AOT cache, amortized over
-        ``moe_rebalance_interval``). Returns True when a re-split was
-        applied."""
-        from dataclasses import replace as dc_replace
-
-        reb = self._moe_rebalancer
-        if reb is None:
-            return False
-        reb.observe(np.asarray(load))
-        m = self.accel.strategy.mesh
-        shards = max(m.dp * m.fsdp * m.sp, 1)
-        tokens = max(
-            1, self.tcfg.batch_size * self.tcfg.seq_len // shards
-        )
-        splits = reb.splits(tokens)
-        if tuple(splits) == tuple(self._model_cfg.capacity_splits):
-            return False
-        self._model_cfg = dc_replace(
-            self._model_cfg, capacity_splits=splits
-        )
-        logger.info(
-            f"moe capacity re-split #"
-            f"{self.pipeline_stats.moe_capacity_resplits + 1}: "
-            f"{splits} (load EMA "
-            f"{np.round(reb.load, 3).tolist()}); rebuilding the step"
-        )
-        devices = list(self.mesh.devices.flatten())
-        accel = auto_accelerate(
-            self._model_cfg,
-            self._tx,
-            batch=self.tcfg.batch_size,
-            seq=self.tcfg.seq_len,
-            devices=devices,
-            strategy=self.accel.strategy,
-            donate=False,
-            grad_accum=self.tcfg.grad_accum,
-        )
-        self.accel = accel
-        self.cfg = accel.cfg
-        self._step_fn = accel.step_fn
-        self._donating_step_fn = (
-            accel.donating_step_fn
-            if self.tcfg.donation_aware
-            else None
-        )
-        self._eval_step_fn = None
-        self._aot_exec = self._aot_shapes = None
-        self._aot_primed = False
-        self._built.clear()  # the new twins build at their first call
-        self.pipeline_stats.moe_capacity_resplits += 1
-        self._registry.gauge(
-            "dlrover_moe_capacity_resplits",
-            "applied MoE capacity re-splits",
-        ).set(float(self.pipeline_stats.moe_capacity_resplits))
-        return True
+        return rolled_to
 
     def measure_realized_overlap(self, iters: int = 3) -> Optional[float]:
         """A/B-measure how much of the sync's wire time the scheduler
@@ -1186,7 +784,7 @@ class ElasticTrainer:
             return float(np.median(times) * 1e3)
 
         with span("grad_sync_overlap_probe"):
-            with_ms = _time(self._step_fn, self.state)
+            with_ms = _time(self._programs.safe_step, self.state)
             gspmd_ms = _time(
                 base_step, strip_residual(self.state)
             )
@@ -1471,7 +1069,7 @@ class ElasticTrainer:
                     # storage — the shm/persist split only exists under an
                     # agent saver
                     persisted = True
-                elif committed and remaining > self.tcfg.eviction_persist_floor_s:
+                elif committed and remaining > _EVICTION_PERSIST_FLOOR_S:
                     try:
                         persisted = self.save(StorageType.DISK)
                     except Exception as e:
@@ -1577,167 +1175,11 @@ class ElasticTrainer:
         sharded = shard_batch({"x": bx, "y": by}, self.mesh)
         return sharded["x"], sharded["y"]
 
-    # -- eval ----------------------------------------------------------
-    def _build_eval_step(self):
-        """Eval loss step, memoized per mesh through the compile cache:
-        a resize invalidates the stale wrapper, but resizing back to a
-        previously-seen mesh reuses the jitted step instead of
-        re-tracing (the old behavior re-``jax.jit``-ed after every
-        mesh change)."""
-        import jax
-
-        from dlrover_tpu.accel.compile_cache import (
-            fingerprint,
-            mesh_signature,
-        )
-
-        cfg, mesh, strategy = self.cfg, self.mesh, self.accel.strategy
-        key = fingerprint(
-            "eval_step",
-            strategy.to_json(),
-            mesh_signature(mesh),
-            repr(cfg),
-        )
-
-        def build():
-            if strategy.mesh.pp > 1:
-                from dlrover_tpu.parallel.pipeline import (
-                    pipeline_loss_fn,
-                )
-
-                mb = strategy.num_microbatches
-                # the state layout is [pp, v, lc] iff the TRAINING
-                # schedule is interleaved — eval must read the same
-                # layout. The schedule may live in pp_schedule OR
-                # (pre-apply) only in opts; resolved_virtual() honors
-                # both sources
-                virtual = strategy.resolved_virtual()
-
-                def eval_loss(params, x, y):
-                    return pipeline_loss_fn(
-                        params, x, y, cfg, mesh, mb, virtual=virtual
-                    )
-
-            else:
-
-                def eval_loss(params, x, y):
-                    return _dense_eval_loss(params, x, y, cfg, mesh)
-
-            return jax.jit(eval_loss)
-
-        fn, _ = self._compile_cache.get_or_build(key, build)
-        return fn
-
-    def _eval_batches(self, max_batches: int):
-        """Sequential fixed-size batches over the eval set (no sampler
-        elasticity — eval restarts from the top every call)."""
-        bs = self.tcfg.batch_size
-        n = len(self._eval_dataset)
-        for start in range(0, min(max_batches * bs, n - bs + 1), bs):
-            rows = [self._eval_dataset[i] for i in range(start, start + bs)]
-            if self._collate_fn is not None:
-                yield self._collate_fn(rows)
-            elif isinstance(rows[0], dict):
-                yield {
-                    k: np.stack([r[k] for r in rows]) for k in rows[0]
-                }
-            else:
-                yield tuple(
-                    np.stack([r[j] for r in rows])
-                    for j in range(len(rows[0]))
-                )
-
     def evaluate(self, max_batches: Optional[int] = None) -> Dict[str, float]:
         """Run the eval set through a grad-free sharded loss step.
         Returns {"eval_loss": mean NLL, "eval_ppl": exp(mean NLL)}."""
-        if self._eval_dataset is None:
-            raise ValueError("ElasticTrainer built without eval_dataset")
-        if self._eval_step_fn is None:
-            self._eval_step_fn = self._build_eval_step()
-        max_batches = max_batches or self.tcfg.eval_steps
-        losses = []
-        for batch in self._eval_batches(max_batches):
-            x, y = self._device_batch(batch, for_eval=True)
-            with self._first_build("eval"):
-                losses.append(
-                    float(self._eval_step_fn(self.state.params, x, y))
-                )
-        if not losses:
-            # a silent NaN here would poison every later metrics report
-            raise ValueError(
-                f"eval dataset ({len(self._eval_dataset)} rows) yields "
-                f"zero batches of size {self.tcfg.batch_size}"
-            )
-        mean = float(np.mean(losses))
-        return {
-            "eval_loss": mean,
-            "eval_ppl": float(np.exp(min(mean, 20.0))),
-        }
-
-    def _best_sidecar_path(self) -> str:
-        return os.path.join(self._best_dir, "best_eval.json")
-
-    def _load_best_sidecar(self) -> float:
-        import json
-
-        try:
-            with open(self._best_sidecar_path()) as f:
-                return float(json.load(f)["eval_loss"])
-        except (OSError, ValueError, KeyError):
-            return float("inf")
-
-    def _after_eval(self, step: int) -> bool:
-        """save-best / early-stopping bookkeeping; True = stop now.
-
-        Two distinct "best" trackers on purpose:
-
-        - ``_run_best_eval_loss`` (reset every train() call) drives the
-          patience counter — a restarted run that is still improving
-          run-locally must not be stopped just because it hasn't yet
-          beaten the historical best it restarted below;
-        - ``_best_eval_loss`` is the best PERSISTED loss (sidecar) and
-          only advances when a checkpoint actually commits — a save
-          skipped by the rate limit stays beatable, so the next
-          improvement past the window persists instead of being lost.
-        """
-        import json
-
-        loss = self._last_eval.get("eval_loss", float("inf"))
-        if loss < self._run_best_eval_loss:
-            self._run_best_eval_loss = loss
-            self._evals_since_best = 0
-        else:
-            self._evals_since_best += 1
-        if (
-            self._best_ckptr is not None
-            and loss < self._best_eval_loss
-            and time.time() - self._last_best_save
-            >= self.tcfg.save_best_min_interval_s
-        ):
-            logger.info(
-                f"step {step}: new best eval_loss={loss:.4f}; "
-                f"persisting to {self._best_dir}"
-            )
-            if self._best_ckptr.save_checkpoint(
-                step, self._ckpt_state(), StorageType.DISK
-            ):
-                # the sidecar records the PERSISTED best — written only
-                # after the commit, so a crash mid-save cannot leave it
-                # claiming a checkpoint that isn't there; durable
-                # (fsync-before-rename) because its whole contract is
-                # being as durable as the checkpoint it describes
-                # (graftlint durable-rename)
-                storage.durable_replace(
-                    self._best_sidecar_path(),
-                    lambda f: json.dump(
-                        {"eval_loss": loss, "step": step}, f
-                    ),
-                )
-                self._best_eval_loss = loss
-                self._last_best_save = time.time()
-        return (
-            self.tcfg.early_stopping_patience > 0
-            and self._evals_since_best >= self.tcfg.early_stopping_patience
+        return self._eval.evaluate(
+            self.accel, self._programs.cache, self.state.params, max_batches
         )
 
     def current_lr(self) -> Optional[float]:
@@ -1795,156 +1237,19 @@ class ElasticTrainer:
             self._prefetcher.close()
             self._prefetcher = None
 
-    def _step_cache_key(self, strategy, mesh, state_like, batch_like):
-        """Compile-cache key of the SAFE train step for one world:
-        (strategy fingerprint, mesh shape + device assignment, abstract
-        state/batch shapes, donation signature). ``state_like`` and
-        ``batch_like`` may be concrete arrays or ShapeDtypeStructs —
-        both produce the same key (``tree_signature`` drops
-        weak_type), so a speculative pre-lower from specs collides
-        with the resize that consumes it. The job-name salt keeps two
-        jobs sharing one on-disk cache apart (a key assumes tx was
-        constructed identically, which holds within one SPMD job)."""
-        from dlrover_tpu.accel.compile_cache import (
-            fingerprint,
-            mesh_signature,
-            tree_signature,
-        )
-        from dlrover_tpu.common.constants import NodeEnv
-
-        return fingerprint(
-            "train_step",
-            strategy.to_json(),
-            mesh_signature(mesh),
-            tree_signature(state_like),
-            tree_signature(batch_like),
-            "donate=0",
-            os.getenv(NodeEnv.JOB_NAME, ""),
-        )
-
-    def _batch_specs(self, mesh, strategy=None):
-        """Abstract (x, y) for AOT lowering on ``mesh``, from the REAL
-        batch avals recorded at the first step — re-padded for the
-        target ``strategy``'s micro-batch rebalance (batch_pad differs
-        per world, so the same real batch lowers to different physical
-        shapes on different strategies)."""
-        import jax
-
-        from dlrover_tpu.parallel.mesh import batch_sharding
-
-        pad = int(getattr(strategy, "batch_pad", 0) or 0)
-        sh = batch_sharding(mesh)
-        return tuple(
-            jax.ShapeDtypeStruct(
-                (shape[0] + pad,) + tuple(shape[1:]),
-                np.dtype(dt),
-                sharding=sh,
-            )
-            for shape, dt in self._batch_avals
-        )
-
-    def _aot_supported(self, strategy) -> bool:
-        # the pipeline step takes host arrays (different signature) and
-        # the offload step's mixed host/device shardings defeat the
-        # spec-keyed cache — both keep their lazy jit path
-        return strategy.mesh.pp == 1 and not strategy.offload_opt
-
-    def _record_batch_avals(self, x, y):
-        """Shapes/dtypes of the live batch — speculative compiles for
-        other meshes lower against these. Recorded at the REAL row
-        count: a rebalanced strategy's zero-weight pad rows are its
-        own physical artifact (``_batch_specs`` re-pads per target
-        strategy)."""
-        pad = int(getattr(self.accel.strategy, "batch_pad", 0) or 0)
-        try:
-            self._batch_avals = tuple(
-                ((int(b.shape[0]) - pad,) + tuple(b.shape[1:]), str(b.dtype))
-                for b in (x, y)
-            )
-        except (AttributeError, TypeError, IndexError):
-            pass
-
-    def _prime_step_cache(self, x, y):
-        """First SAFE step on a mesh: route it through the AOT compile
-        cache. This replaces (not adds to) the lazy jit compile that
-        would happen at this exact moment, but the executable lands in
-        a cache that outlives the wrapper a resize throws away — the
-        entry is what makes resizing BACK to this mesh warm. Donating
-        steps never prime: their twin is a different program, and a
-        donation-only run pays no extra compile for a cache it may
-        never need (the resize itself populates it then)."""
-        self._aot_primed = True
-        strategy = self.accel.strategy
-        if not self._aot_supported(strategy):
-            return
-        step_fn, state = self._step_fn, self.state
-        key = self._step_cache_key(strategy, self.mesh, state, (x, y))
-        try:
-            with span("compile_prime"):
-                fn, _ = self._compile_cache.get_or_compile(
-                    key, lambda: step_fn.lower(state, x, y).compile()
-                )
-            self._install_aot(fn, (x.shape, y.shape))
-        except Exception as e:
-            # AOT is an optimization: a lowering quirk must not take
-            # down training — the lazy jit path still works
-            logger.warning(f"AOT step-cache priming failed: {e!r}")
-
-    def _install_aot(self, exec_fn, shapes):
-        self._aot_exec = exec_fn
-        self._aot_shapes = tuple(tuple(s) for s in shapes)
-
-    def _safe_step_for(self, x, y):
-        """The non-donating step for THIS batch: the AOT executable when
-        the shapes match what it was lowered for, else the jit wrapper —
-        a Compiled rejects differing avals where jit retraces, and both
-        the dataloader's short final batch and a master-retuned batch
-        size legitimately change the shape mid-run."""
-        if self._aot_exec is not None and self._aot_shapes == (
-            tuple(x.shape), tuple(y.shape)
-        ):
-            return self._aot_exec
-        return self._step_fn
-
     def _run_step(self, x, y):
         """One optimizer step, donation-aware: donate the state and the
         batch whenever no checkpoint staging is reading the buffers."""
-        if self._batch_avals is None:
-            self._record_batch_avals(x, y)
-        donate = (
-            self._donating_step_fn is not None
-            and self._stager is None
-            and (
-                self._ckptr is None
-                or not self._ckptr.staging_in_flight()
-            )
-            and (
-                self._best_ckptr is None
-                or not self._best_ckptr.staging_in_flight()
-            )
-        )
-        stats = self.pipeline_stats
-        if donate:
-            stats.donated_steps += 1
-            stats.donated_bytes += self._state_nbytes + sum(
-                getattr(b, "nbytes", 0) for b in (x, y)
-            )
-        else:
-            stats.safe_steps += 1
+        programs = self._programs
+        donate = programs.donates(staging=self._staging_active())
         # each twin is built by its first call (the safe one ahead of
         # time through the AOT cache, the donating one inside jit)
         with self._first_build("step_donating" if donate else "step_safe"):
-            if not donate and not self._aot_primed:
-                self._prime_step_cache(x, y)
-            fn = (
-                self._donating_step_fn
-                if donate
-                else self._safe_step_for(x, y)
-            )
+            step_fn = programs.step_for(self.state, x, y, donate)
             # the call of the step function, to its return: argument
             # handling on the host and the launch queued on the device
             with span("dispatch"):
-                self.state, metrics = fn(self.state, x, y)
+                self.state, metrics = step_fn(self.state, x, y)
         if self._builds_logged < len(self._builds.builds):
             self._log_builds("by a first step")
         return metrics
@@ -1983,21 +1288,21 @@ class ElasticTrainer:
             # the shard lock is free for the synchronous staging
             self._abort_stager()
             self.save(StorageType.DISK)
-        elif step % self.tcfg.save_memory_interval == 0:
-            if not self.tcfg.chunked_staging:
-                self.save(StorageType.MEMORY)
-            elif self._stager is None:
-                # a previous stage still draining keeps draining — skip
-                # this interval rather than stall on a forced commit
-                # (same skip-never-block contract as save_to_memory)
-                with span("ckpt_snapshot"):
-                    snapshot = self._ckpt_state()
-                # the engine names the legs of the begin (ckpt_begin_*)
-                self._stager = self._ckptr.begin_chunked_save(
-                    step,
-                    snapshot,
-                    chunk_bytes=self.tcfg.stage_chunk_mb << 20,
-                )
+        elif (
+            step % self.tcfg.save_memory_interval == 0
+            and self._stager is None
+        ):
+            # a previous stage still draining keeps draining — skip
+            # this interval rather than stall on a forced commit
+            # (same skip-never-block contract as save_to_memory)
+            with span("ckpt_snapshot"):
+                snapshot = self._ckpt_state()
+            # the engine names the legs of the begin (ckpt_begin_*)
+            self._stager = self._ckptr.begin_chunked_save(
+                step,
+                snapshot,
+                chunk_bytes=self.tcfg.stage_chunk_mb << 20,
+            )
 
     # -- elastic resize (fast path) ------------------------------------
     def _strategy_for_exact(self, n_devices: int) -> Optional[Strategy]:
@@ -2238,7 +1543,7 @@ class ElasticTrainer:
                 f"strategy mesh needs {strategy.mesh.num_devices} "
                 f"devices, resize got {len(devices)}"
             )
-        if not self._aot_supported(strategy):
+        if not aot_supported(strategy):
             raise ValueError(
                 "resize fast path supports pp=1, non-offload "
                 "strategies; restart for pipeline/offload changes"
@@ -2341,13 +1646,11 @@ class ElasticTrainer:
                 # as XlaRuntimeError), OSError the injected
                 # reshard.gather fault; ValueError (shape/struct
                 # mismatch = model change) still raises
-                import jax as _jax
-
                 logger.error(
                     f"resize: on-device reshard failed ({e!r}); "
                     f"falling back to a full checkpoint restore"
                 )
-                _leaves, _ = _jax.tree_util.tree_flatten_with_path(spec)
+                _leaves, _ = jax.tree_util.tree_flatten_with_path(spec)
                 report = reshard_mod.ReshardReport(
                     fallback_paths=[
                         reshard_mod._keystr(kp) for kp, _ in _leaves
@@ -2401,11 +1704,9 @@ class ElasticTrainer:
         self.cfg = accel.cfg
         self.mesh = accel.mesh
         self.state = new_state
-        self._donating_step_fn = (
-            accel.donating_step_fn if self.tcfg.donation_aware else None
-        )
-        self._step_fn = accel.step_fn
-        self._eval_step_fn = None  # per-mesh memo re-resolves lazily
+        # the new world's twins; the old world's executable is dropped
+        self._programs.rebuild(accel)
+        self._eval.step_fn = None  # per-mesh memo re-resolves lazily
         self._built.clear()  # the new twins build at their first call
         # link model: re-probe ONLY when the device fingerprint changed
         # (docs/elastic-resize.md) — a resize back onto the same
@@ -2418,7 +1719,7 @@ class ElasticTrainer:
         # the SDC lane axis is per-world: rebuild the detector and
         # probe for the new device total (history from the old world
         # describes different lanes)
-        self._setup_sdc()
+        self._sdc.arm(self._grad_sync_plan, self.mesh)
         # spans straddling the rebuild belong to neither world's
         # budget: drop everything buffered so far, then re-price the
         # per-component budget for the new mesh (tests/test_audit.py
@@ -2430,12 +1731,11 @@ class ElasticTrainer:
         # the next poll must re-evaluate them for this one
         self._last_candidates = None
         cache_hit = None
-        self._aot_exec = self._aot_shapes = None
-        if self._batch_avals is not None:
+        programs = self._programs
+        if programs.batch_avals is not None:
             with span("resize_compile") as compile_sp:
-                xy = self._batch_specs(accel.mesh, strategy)
-                key = self._step_cache_key(
-                    strategy, accel.mesh, new_state, xy
+                key, xy = programs.lowering_for(
+                    strategy, accel.mesh, new_state
                 )
                 if (
                     self._spec_compiler is not None
@@ -2446,16 +1746,11 @@ class ElasticTrainer:
                     # multi-minute compile into a cache hit
                     self._spec_compiler.wait_idle(600.0)
                 step_fn, state = accel.step_fn, new_state
-                fn, cache_hit = self._compile_cache.get_or_compile(
+                fn, cache_hit = programs.cache.get_or_compile(
                     key, lambda: step_fn.lower(state, *xy).compile()
                 )
                 compile_sp.set(cache_hit=bool(cache_hit))
-                self._install_aot(
-                    fn, tuple(shape for shape, _ in self._batch_avals)
-                )
-                self._aot_primed = True
-        else:
-            self._aot_primed = False
+                programs.install(fn)
         self._flight.clear_suppression()
         downtime_ms = (time.perf_counter() - t0) * 1e3
         self.pipeline_stats.resize_count += 1
@@ -2484,10 +1779,7 @@ class ElasticTrainer:
                 self._ckptr is not None
                 and self._ckptr.staging_in_flight()
             )
-            or (
-                self._best_ckptr is not None
-                and self._best_ckptr.staging_in_flight()
-            )
+            or self._eval.staging_in_flight()
         )
 
     def update_scale_candidates(self, device_counts) -> int:
@@ -2498,7 +1790,7 @@ class ElasticTrainer:
         world. Returns the number of candidates submitted."""
         if not self.tcfg.speculative_compile:
             return 0
-        if self._batch_avals is None or not self._aot_supported(
+        if self._programs.batch_avals is None or not aot_supported(
             self.accel.strategy
         ):
             return 0
@@ -2539,9 +1831,9 @@ class ElasticTrainer:
             )
 
             self._spec_compiler = SpeculativeCompiler(
-                self._compile_cache,
+                self._programs.cache,
                 pause_fn=self._staging_active,
-                budget_s=self.tcfg.spec_compile_budget_s,
+                budget_s=_SPEC_COMPILE_BUDGET_S,
             )
         self._spec_compiler.submit(tasks)
         logger.info(
@@ -2584,8 +1876,7 @@ class ElasticTrainer:
             # tree — the pre-lowered executable (and its cache key)
             # must see the same tree or the resize can never hit it
             spec = dc_replace(spec, grad_residual=residual_spec(plan, mesh))
-        xy = self._batch_specs(mesh, cand)
-        key = self._step_cache_key(cand, mesh, spec, xy)
+        key, xy = self._programs.lowering_for(cand, mesh, spec)
 
         def build():
             _, mesh2, step_fn, _, _, _ = _build(
@@ -2614,7 +1905,7 @@ class ElasticTrainer:
         ]
         if not counts or counts == self._last_candidates:
             return
-        if self._batch_avals is None:
+        if self._programs.batch_avals is None:
             # too early: the first step hasn't recorded the batch avals
             # the pre-lower needs — leave the candidates unconsumed so
             # the next poll picks them up
@@ -2641,12 +1932,7 @@ class ElasticTrainer:
         # producer parks in a read by design and must not masquerade as
         # the stuck frame)
         self._train_tid = threading.get_ident()
-        self._last_eval: Dict[str, float] = {}
-        # run-local best for the patience counter; the PERSISTED best
-        # (_best_eval_loss, sidecar-loaded) deliberately survives so a
-        # restarted run's first (worse) eval can't supersede it on disk
-        self._run_best_eval_loss = float("inf")
-        self._evals_since_best = 0
+        self._eval.begin_run()
         try:
             return self._train_loop(num_steps, t0, start_step)
         except BaseException as e:
@@ -2785,23 +2071,16 @@ class ElasticTrainer:
     def _log_step(self, due, t0, start_step):
         """The log-cadence report of one step, made once that step has
         been waited for: ``due`` is what the loop put aside at the step
-        itself (its number, its loss, copies of the learning-rate
-        scalars of its state, the eval scalars as they stood, and of a
-        MoE model its drop rate and per-expert load)."""
-        step, loss, lr_parts, evals, routing = due
+        itself (its number, its reportable metrics, copies of the
+        learning-rate scalars of its state, and the eval scalars as
+        they stood)."""
+        step, metrics, lr_parts, evals = due
         # materializing the loss is a host sync only when the report is
         # made at an exit of the loop; in the loop the step is done
         with span("host_sync"):
-            loss = float(loss)
-            if routing is not None:
-                # copies to the host of the step's own outputs: an op
-                # on the device here would queue behind the step in
-                # flight
-                drop, load = (np.asarray(a) for a in routing)
-                stats = self.pipeline_stats
-                stats.moe_reports += 1
-                stats.moe_drop_rate_sum += float(drop)
-                stats.moe_max_load_sum += float(load.max()) * load.size
+            loss = float(metrics["loss"])
+            # of a MoE model, its drop rate and per-expert load
+            fold_routing_report(metrics, self.pipeline_stats)
         with span("report"):
             scalars = {"loss": loss}
             lr = self._lr_value(lr_parts)
@@ -2928,7 +2207,7 @@ class ElasticTrainer:
                         with span("stage"):
                             self._advance_stager()
                     # what rides every step beside the program: the
-                    # SDC fence, the caller's hook, the MoE rebalance.
+                    # SDC fence and the caller's hook.
                     # `self.state` and `metrics` are THIS step's, still
                     # being computed: whoever reads them waits for them
                     with span("hooks"):
@@ -2937,19 +2216,10 @@ class ElasticTrainer:
                         # consumer that reports scalars sees it (same
                         # contract as moe_expert_load)
                         dev_norms = metrics.pop("sdc_device_norms", None)
-                        if self._sdc is not None:
-                            self._sdc_step(step, metrics, dev_norms)
+                        if self._sdc.detector is not None:
+                            self._sdc.after_step(step, metrics, dev_norms)
                         if self._metrics_hook is not None:
                             self._metrics_hook(step, metrics)
-                        if (
-                            self._moe_rebalancer is not None
-                            and step % self.tcfg.moe_rebalance_interval
-                            == 0
-                            and "moe_expert_load" in metrics
-                        ):
-                            self._maybe_rebalance_experts(
-                                metrics["moe_expert_load"]
-                            )
                     # a report step before this one has been waited
                     # for by now: its loss costs no sync
                     report_if_due()
@@ -2959,38 +2229,26 @@ class ElasticTrainer:
                         # one iteration on (or at the loop's exit)
                         report_due = (
                             step,
-                            metrics["loss"],
+                            dict(metrics),
                             held_lr(),
-                            dict(self._last_eval),
-                            (
-                                metrics["moe_drop_rate"],
-                                metrics["moe_expert_load"],
-                            )
-                            if "moe_drop_rate" in metrics
-                            else None,
+                            dict(self._eval.last),
                         )
-                    if (
-                        self._eval_dataset is not None
-                        and self.tcfg.eval_interval
-                        and step % self.tcfg.eval_interval == 0
-                    ):
+                    if self._eval.due(step):
                         with span("eval"):
-                            self._last_eval = self.evaluate()
+                            evals = self.evaluate()
                         logger.info(
                             f"step {step}: "
-                            f"eval_loss={self._last_eval['eval_loss']:.4f} "
-                            f"ppl={self._last_eval['eval_ppl']:.2f}"
+                            f"eval_loss={evals['eval_loss']:.4f} "
+                            f"ppl={evals['eval_ppl']:.2f}"
                         )
                         if self._metrics_hook is not None:
-                            self._metrics_hook(
-                                step, dict(self._last_eval)
-                            )
-                        if self._after_eval(step):
+                            self._metrics_hook(step, dict(evals))
+                        if self._eval.after_eval(step, evals):
                             logger.info(
                                 f"early stopping at step {step}: no "
                                 f"eval improvement in "
                                 f"{self.tcfg.early_stopping_patience} "
-                                f"evals (best {self._best_eval_loss:.4f})"
+                                f"evals (best {self._eval.best_loss:.4f})"
                             )
                             step_sp.end()
                             drain()
@@ -3098,5 +2356,4 @@ class ElasticTrainer:
             self._spec_compiler = None
         if self._ckptr is not None:
             self._ckptr.engine.close()
-        if self._best_ckptr is not None:
-            self._best_ckptr.engine.close()
+        self._eval.close()

@@ -6,10 +6,16 @@ import os
 os.environ.setdefault("DLROVER_TPU_SOCKET_DIR", "/tmp/dlrover_tpu_test/sockets")
 # the job name namespaces IPC sockets and shm segments: one per xdist
 # worker, or two test files that each run a checkpoint saver at the same
-# time share ``ckpt_meta_0.sock`` and the ``job_0_0`` segment
-os.environ.setdefault(
-    "DLROVER_TPU_JOB_NAME", "t" + os.getenv("PYTEST_XDIST_WORKER", "main")
-)
+# time share ``ckpt_lock_0.sock`` and the ``job_0_0`` segment (one's
+# teardown unlinks the other's socket, one's saver holds the other's
+# shard lock: "saver busy"). Assigned, not ``setdefault``: the xdist
+# controller imports this file first and its workers inherit its
+# environment, so a default set there would be every worker's name
+_worker = os.getenv("PYTEST_XDIST_WORKER")
+if _worker:
+    os.environ["DLROVER_TPU_JOB_NAME"] = "t" + _worker
+else:
+    os.environ.setdefault("DLROVER_TPU_JOB_NAME", "tmain")
 # trainers built inside the test process (and the workers tests launch)
 # must not write the persistent compile cache into the checkout; a test
 # that wants the cache places it with JAX_COMPILATION_CACHE_DIR

@@ -231,7 +231,7 @@ class TestTrainerPipeline:
                 )
 
             t = mk()
-            assert t._donating_step_fn is not None
+            assert t._programs.donating_step is not None
             t.train(num_steps=7)
             assert t.global_step == 7
             s = t.pipeline_stats

@@ -718,7 +718,7 @@ class TestTrainerResize:
             degraded = t._strategy_for(6)
             assert degraded.mesh.num_devices == 4
             m1 = t.evaluate(max_batches=1)
-            fn_a = t._eval_step_fn
+            fn_a = t._eval.step_fn
             assert fn_a is not None
             before = [
                 np.asarray(x).tobytes()
@@ -763,15 +763,15 @@ class TestTrainerResize:
                 for x in jax.tree_util.tree_leaves(t.state.params)
             ]
             assert before == after  # bitwise across the remap
-            assert t._eval_step_fn is None  # stale wrapper dropped
+            assert t._eval.step_fn is None  # stale wrapper dropped
             t.evaluate(max_batches=1)
-            fn_b = t._eval_step_fn
+            fn_b = t._eval.step_fn
             assert fn_b is not fn_a
             t.train(num_steps=4)
             warm = t.resize(4)  # primed by the first steps on dp4
             assert warm["compile_cache_hit"] is True
             m2 = t.evaluate(max_batches=1)
-            assert t._eval_step_fn is fn_a  # memo hit, no re-jit
+            assert t._eval.step_fn is fn_a  # memo hit, no re-jit
             assert np.isfinite(m1["eval_loss"])
             assert np.isfinite(m2["eval_loss"])
             t.train(num_steps=6)
@@ -793,7 +793,7 @@ class TestTrainerResize:
         try:
             t.train(num_steps=16)  # step 16 is the 4-row tail batch
             assert t.global_step == 16
-            assert t._aot_exec is not None  # priming did happen
+            assert t._programs.aot_exec is not None  # priming did happen
         finally:
             t.close()
 

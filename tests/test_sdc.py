@@ -249,24 +249,25 @@ class _NeverProbe:
 
 
 def _make_host(n_lanes=4):
-    """A bare stand-in exposing exactly what ``_sdc_step`` touches —
-    the routing logic is testable without compiling a trainer."""
-    from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer
+    """A bare stand-in exposing exactly what ``SdcFence.after_step``
+    touches — the routing logic is testable without compiling a
+    trainer."""
+    from dlrover_tpu.trainer.elastic.sdc_fence import SdcFence
 
     host = types.SimpleNamespace(
-        _sdc=sdc.SdcDetector(n_lanes),
-        _sdc_probe=_NeverProbe(),
-        _sdc_pending=None,
-        _sdc_halt=False,
-        sdc_convicted=(),
-        sdc_detect_step=None,
+        detector=sdc.SdcDetector(n_lanes),
+        probe=_NeverProbe(),
+        _pending=None,
+        halt=False,
+        convicted=(),
+        detect_step=None,
         _registry=_Registry(),
         _flight=_Flight(),
         sampler=types.SimpleNamespace(
             state_dict=lambda: {"completed_num": 123}
         ),
     )
-    host.step = lambda s, m, d: ElasticTrainer._sdc_step(host, s, m, d)
+    host.step = lambda s, m, d: SdcFence.after_step(host, s, m, d)
     return host
 
 
@@ -290,10 +291,10 @@ class TestTrainerRouting:
         assert reg["dlrover_sdc_data_spikes_total"].n == 1
         assert "dlrover_sdc_suspicions_total" not in reg
         assert "dlrover_sdc_audits_run_total" not in reg
-        assert host._sdc_probe.runs == 0
-        assert host.sdc_convicted == ()
+        assert host.probe.runs == 0
+        assert host.convicted == ()
         assert "sdc_data_spike" in host._flight.events
-        assert not host._sdc_halt
+        assert not host.halt
 
     def test_device_suspect_escalates_to_audit(self):
         host = _make_host()
@@ -303,15 +304,15 @@ class TestTrainerRouting:
         reg = host._registry.counters
         assert reg["dlrover_sdc_suspicions_total"].n == 1
         assert reg["dlrover_sdc_audits_run_total"].n == 1
-        assert host._sdc_probe.runs == 1
-        assert host.sdc_detect_step == 11
+        assert host.probe.runs == 1
+        assert host.detect_step == 11
 
     def test_observation_is_one_step_delayed(self):
         host = _make_host()
         host.step(1, {"loss": 2.0}, [1.0, 1.0, 1.0, 1.0])
-        assert host._sdc._steps_seen == 0  # first call only enqueues
+        assert host.detector._steps_seen == 0  # first call only enqueues
         host.step(2, {"loss": 2.0}, [1.0, 1.0, 1.0, 1.0])
-        assert host._sdc._steps_seen == 1
+        assert host.detector._steps_seen == 1
 
 
 # ---------------------------------------------------------------------------

@@ -109,7 +109,7 @@ def plain_loop():
                 state, losses, lrs = t.state, [], []
                 for b in itertools.islice(iter(t.dataloader), STEPS):
                     x, y = t._device_batch(b)
-                    state, m = t._step_fn(state, x, y)
+                    state, m = t._programs.safe_step(state, x, y)
                     losses.append(np.asarray(m["loss"]).tobytes())
                     hp = getattr(state.opt_state, "hyperparams", None)
                     lrs.append(float(hp["learning_rate"]) if hp else None)
@@ -316,14 +316,14 @@ def test_an_exception_leaves_no_span_open(where, tracer):
     t = _trainer(hook)
     if where == "step_function":
         calls = itertools.count(1)
-        real = t._donating_step_fn
+        real = t._programs.donating_step
 
         def failing(state, x, y):
             if next(calls) == 5:
                 raise _Boom("step")
             return real(state, x, y)
 
-        t._donating_step_fn = failing
+        t._programs.donating_step = failing
     try:
         with pytest.raises(_Boom):
             t.train(num_steps=STEPS)

@@ -381,7 +381,7 @@ def test_save_best_and_early_stopping(tmp_path):
         # must never supersede it) with the sidecar recording its loss
         import json, os
         best_dir = os.path.join(ckpt_dir, "best")
-        best_step = t._best_ckptr.engine.latest_step(best_dir)
+        best_step = t._eval.best_ckptr.engine.latest_step(best_dir)
         assert best_step >= 0
         side = json.load(open(os.path.join(best_dir, "best_eval.json")))
         assert side["step"] == best_step
@@ -404,7 +404,7 @@ def test_save_best_and_early_stopping(tmp_path):
             ),
             strategy=Strategy(mesh=MeshConfig(dp=8), dtype="float32"),
         )
-        assert t2._best_eval_loss == pytest.approx(recorded_best)
+        assert t2._eval.best_loss == pytest.approx(recorded_best)
         t2.close()
     finally:
         AsyncCheckpointSaver.reset()

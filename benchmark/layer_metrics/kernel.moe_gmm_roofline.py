@@ -1,7 +1,8 @@
 """The grouped expert matmuls' share of their roofline: the least time the
-chip could take for the expert projections of the traced steps (the larger
-of operations over the bf16 peak and bytes over HBM bandwidth, both from
-shapes by ``flops_moe.grouped_matmul_work``) over the summed device time
+chip could take for the expert projections of the traced stretch's whole
+steps (``xplane.step_stretch``), which is the larger of operations over the
+bf16 peak and bytes over HBM bandwidth, both from shapes by
+``flops_moe.grouped_matmul_work``, over the summed device time
 of the grouped-matmul events, forward, recomputed forward and backward.
 Which bound holds is printed on an earlier line, with the seconds of the
 other ``tpu_custom_call`` events (flash attention's) beside it.
@@ -40,16 +41,15 @@ def read(run):
         return None
     if not run.trace or not run.trace.get("devices") or not run.peak:
         return None
-    t = run.window["trace"]
-    steps = t["step_end"] - t["step_begin"]
     device = run.trace["devices"][0]
+    steps = device["steps"]
     rows = [r for r in device["ops"] if r["name"].startswith(PREFIX)]
     found = {
         "seconds": sum(r["total_s"] for r in rows),
         "count": sum(r["count"] for r in rows),
         "names": [r["name"] for r in rows],
     }
-    if not steps or not found["seconds"]:
+    if not found["seconds"]:
         return None
     work = flops_moe.grouped_matmul_work(
         m, run.cell["batch"] * run.cell["seq"]

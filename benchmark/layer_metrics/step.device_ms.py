@@ -1,6 +1,6 @@
-"""Milliseconds the device was busy per step: the union of the device
-plane's operation events over the traced stretch, divided by the steps
-traced."""
+"""Milliseconds the device was busy per step: the busy time of the traced
+stretch divided by the whole step executions it holds, both from the
+device trace (``xplane.step_stretch``), never from the host's hooks."""
 
 LAYER = "step program"
 UNIT = "ms"
@@ -14,6 +14,4 @@ def CELLS(cell):
 def read(run):
     if not run.trace or not run.trace.get("devices"):
         return None
-    t = run.window["trace"]
-    steps = t["step_end"] - t["step_begin"]
-    return 1e3 * run.trace["busy_s"] / steps if steps else None
+    return 1e3 * run.trace["busy_s"] / run.trace["steps"]

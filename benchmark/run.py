@@ -258,13 +258,17 @@ def reduce_trace(files: List[str], run_dir: str) -> Optional[Dict]:
     out = os.path.join(run_dir, "trace_reduced.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("BENCH_RUN", None)
-    rc = subprocess.run(
+    subprocess.run(
         [sys.executable, os.path.join(HERE, "xplane.py"), files[0], out],
         cwd=ROOT, env=env, timeout=600,
-    ).returncode
-    if rc != 0:
-        return None
-    return maybe_json(out)
+    )
+    reduced = maybe_json(out)
+    if reduced and "refused" in reduced:
+        raise Refused(
+            "the device trace holds no stretch of whole steps to measure: "
+            + str(reduced["refused"])
+        )
+    return reduced
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
@@ -450,11 +454,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if not trace_reduced or not trace_reduced.get("devices"):
             failure_report(run_dir)
             raise Refused("traced run left no device trace to read")
+        # both from the device trace's whole steps (xplane.step_stretch):
+        # no host clock in either, so busy_s cannot pass window_s
         device["busy_s"] = trace_reduced["busy_s"]
-        device["window_s"] = (
-            window["trace"]["t_end"] - window["trace"]["t_begin"]
-        )
+        device["window_s"] = trace_reduced["window_s"]
         d0 = trace_reduced["devices"][0]
+        note(stretch_beside_host_clock(d0, window["trace"], in_window))
         result["breakdown"] = {
             "device_ops": [
                 [r["name"], r["self_s"]] for r in d0["ops"][:10]
@@ -467,6 +472,94 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             ],
         }
     return result
+
+
+def stretch_beside_host_clock(d0: Dict, host: Dict,
+                              in_window: List[Dict]) -> Dict:
+    """The traced stretch of the first device beside what the whole trace
+    file sums to and beside the host's clock around the same capture
+    (``window_r0.json``'s ``trace``): three clocks to compare by eye. Only
+    the first feeds a metric; the host-span readers use the third."""
+    hooks = [
+        r["t"] for r in in_window
+        if host["step_begin"] <= r["step"] <= host["step_end"]
+    ]
+    periods = [b - a for a, b in zip(hooks, hooks[1:])]
+    return {
+        "traced_stretch": {
+            k: d0[k] for k in (
+                "steps", "window_s", "busy_s", "idle_s", "step_programs",
+                "step_executions", "other_executions",
+            )
+        },
+        "whole_trace_file": d0["whole_file"],
+        "host_clock": {
+            "t_end_less_t_begin_s": host["t_end"] - host["t_begin"],
+            "hooks": host["step_end"] - host["step_begin"],
+            # the same steps' period as the hooks saw it (the whole
+            # window's loop.step_p50_ms drifts from it by some 0.3 %)
+            "step_p50_ms_over_the_hooks": 1e3 * arith.percentile(
+                periods, 50) if periods else None,
+            "profiler_calls_s": [b - a for a, b in host["profiler_calls"]],
+        },
+    }
+
+
+def line_breaches(result: Dict, traced: bool) -> List[str]:
+    """Where ``result`` departs from what the driver reads in a run's
+    last line: ``correct``, ``attempted``, ``failed``, ``metrics`` (each a
+    value and a unit), ``device`` (``platform``, ``kind``, ``count``,
+    ``memory_peak_bytes`` and, traced, ``0 < busy_s <= window_s``) and,
+    where it is there, ``breakdown`` (two lists of at most 10 pairs)."""
+    def number(v):
+        return (
+            isinstance(v, (int, float)) and not isinstance(v, bool)
+            and math.isfinite(v)
+        )
+
+    def count(v):
+        return isinstance(v, int) and not isinstance(v, bool) and v >= 0
+
+    bad = []
+    if not isinstance(result.get("correct"), bool):
+        bad.append(f"correct is {result.get('correct')!r}, not a boolean")
+    for key in ("attempted", "failed"):
+        if not count(result.get(key)):
+            bad.append(f"{key} is {result.get(key)!r}, not a count")
+    metrics = result.get("metrics")
+    if not isinstance(metrics, dict) or not metrics:
+        bad.append(f"metrics is {metrics!r}, not a non-empty object")
+    else:
+        for name, m in metrics.items():
+            if not (
+                isinstance(m, dict) and number(m.get("value"))
+                and isinstance(m.get("unit"), str) and m["unit"]
+            ):
+                bad.append(f"metric {name} is {m!r}, not a value and a unit")
+    device = result.get("device")
+    if not isinstance(device, dict):
+        return bad + [f"device is {device!r}, not an object"]
+    for key in ("platform", "kind"):
+        if not (isinstance(device.get(key), str) and device[key]):
+            bad.append(f"device.{key} is {device.get(key)!r}")
+    for key in ("count", "memory_peak_bytes"):
+        if not (count(device.get(key)) and device[key] > 0):
+            bad.append(f"device.{key} is {device.get(key)!r}, not above 0")
+    if traced:
+        busy, win = device.get("busy_s"), device.get("window_s")
+        if not (number(busy) and number(win) and 0 < busy <= win):
+            bad.append(
+                f"device.busy_s {busy!r} is not above 0 and at most "
+                f"device.window_s {win!r}"
+            )
+    for key in ("device_ops", "idle_gaps"):
+        rows = (result.get("breakdown") or {}).get(key, [])
+        if not (isinstance(rows, list) and len(rows) <= 10 and all(
+            isinstance(r, list) and len(r) == 2
+            and isinstance(r[0], str) and number(r[1]) for r in rows
+        )):
+            bad.append(f"breakdown.{key} is not at most 10 [name, seconds]")
+    return bad
 
 
 def spans_without_profiler_steps(spans: List[List], calls: List[List]):
@@ -568,6 +661,11 @@ def main(argv=None) -> int:
         return 3
     if result["device"].get("platform") != "tpu":
         note({"refused": f"not a TPU run: {result['device']}"})
+        return 3
+    breaches = line_breaches(result, bool(args.trace))
+    if breaches:
+        note({"refused": "the result line would not meet its contract",
+              "breaches": breaches, "line": result})
         return 3
     print(json.dumps(result), flush=True)
     return 0

@@ -144,21 +144,28 @@ def test_profiler_steps_leave_with_their_children():
 
 # -- the trace reducer on a hand-built trace ---------------------------------
 def _plane():
-    ops = [
-        # a while loop holding two children, then a gap, then a kernel
-        ("while.1", 0.0, 100.0, {}),
-        ("fusion.1", 10.0, 30.0, {"hlo_category": "convolution fusion"}),
-        ("custom-call.7", 50.0, 40.0,
-         {"custom_call_target": "tpu_custom_call"}),
-        ("fusion.1", 150.0, 50.0, {"hlo_category": "convolution fusion"}),
-        ("custom-call.7", 200.0, 20.0,
-         {"custom_call_target": "tpu_custom_call"}),
-    ]
+    # five executions of one program, 250 ns apart: the first and the last
+    # may be cut by the capture, so the stretch is the three between them
+    ops, modules = [], []
+    for k in range(5):
+        t = 250.0 * k
+        modules.append(("jit_f(1)", t, 220.0, {}))
+        ops += [
+            # a while loop holding two children, then a gap, then a kernel
+            ("while.1", t, 100.0, {}),
+            ("fusion.1", t + 10.0, 30.0,
+             {"hlo_category": "convolution fusion"}),
+            ("custom-call.7", t + 50.0, 40.0,
+             {"custom_call_target": "tpu_custom_call"}),
+            ("fusion.1", t + 150.0, 50.0,
+             {"hlo_category": "convolution fusion"}),
+            ("custom-call.7", t + 200.0, 20.0,
+             {"custom_call_target": "tpu_custom_call"}),
+        ]
     return [
         {"name": "/host:CPU", "lines": [{"name": "python", "events": []}]},
         {"name": "/device:TPU:0", "lines": [
-            {"name": "XLA Modules",
-             "events": [("jit_f(1)", 0.0, 220.0, {})]},
+            {"name": "XLA Modules", "events": modules},
             {"name": "XLA Ops", "events": ops},
         ]},
     ]
@@ -168,18 +175,30 @@ def test_reducer_on_a_hand_built_trace():
     r = xplane.reduce_planes(_plane())
     assert [p["plane"] for p in r["listing"]] == ["/host:CPU", "/device:TPU:0"]
     d = r["devices"][0]
-    # busy: [0,100) + [150,220) = 170 ns; span 220 ns
-    assert abs(d["busy_s"] - 170e-9) < 1e-15
-    assert abs(d["span_s"] - 220e-9) < 1e-15
-    assert abs(r["busy_s"] - 170e-9) < 1e-15
+    # the stretch: [250, 1000) ns, three whole steps; in each, busy
+    # [0,100) + [150,220) = 170 ns of 250
+    assert d["steps"] == r["steps"] == 3
+    assert d["step_programs"] == ["jit_f(1)"] and d["step_executions"] == 5
+    assert abs(d["window_s"] - 750e-9) < 1e-15
+    assert abs(d["busy_s"] - 510e-9) < 1e-15
+    assert abs(r["busy_s"] - 510e-9) < 1e-15 and r["window_s"] == d["window_s"]
+    # the whole file, as it was summed before: five executions, edge to edge
+    assert abs(d["whole_file"]["busy_s"] - 850e-9) < 1e-15
+    assert abs(d["whole_file"]["span_s"] - 1220e-9) < 1e-15
     ops = {o["name"]: o for o in d["ops"]}
-    assert ops["while.1"]["self_s"] == pytest.approx(30e-9)
-    assert ops["fusion.1"]["total_s"] == pytest.approx(80e-9)
-    assert ops["fusion.1"]["count"] == 2
+    assert ops["while.1"]["self_s"] == pytest.approx(3 * 30e-9)
+    assert ops["fusion.1"]["total_s"] == pytest.approx(3 * 80e-9)
+    assert ops["fusion.1"]["count"] == 6
     k = xplane.kernel_seconds(d, ["tpu_custom_call"])
-    assert k["seconds"] == pytest.approx(60e-9) and k["count"] == 2
-    assert d["gaps"][0]["before"] == "fusion.1"
-    assert d["gaps"][0]["seconds"] == pytest.approx(50e-9)
+    assert k["seconds"] == pytest.approx(3 * 60e-9) and k["count"] == 6
+    # the gaps, by the operation that ended each: 50 ns before the second
+    # fusion.1, 30 ns before the next execution's while.1 (the last of
+    # them ended by the execution that closes the stretch)
+    assert [g["before"] for g in d["gaps"]] == ["fusion.1", "while.1"]
+    assert d["gaps"][0]["seconds"] == pytest.approx(150e-9)
+    assert d["gaps"][1]["seconds"] == pytest.approx(90e-9)
+    assert d["gaps"][1]["count"] == 3
+    assert d["modules"][0]["count"] == 3
     assert xplane.union_seconds([(0, 10), (5, 20), (30, 40)]) == \
         pytest.approx(30e-9)
 

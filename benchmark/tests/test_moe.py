@@ -80,7 +80,8 @@ def _run(pipeline_open, pipeline, model=None, trace=None):
         config={"model": model}, trace=trace,
         peak=peaks.peaks("TPU v5 lite"),
         window={"pipeline_open": pipeline_open, "pipeline": pipeline,
-                "trace": {"step_begin": 30, "step_end": 50}},
+                # the host's hooks: no reader of device time counts them
+                "trace": {"step_begin": 30, "step_end": 51}},
     )
 
 
@@ -118,16 +119,17 @@ def test_readers_on_hand_made_runs(capsys):
         {"name": "%fusion.1", "count": 40, "total_s": 1.0, "self_s": 1.0,
          "about": "hlo=bf16[8,8] fusion(bf16[8,8] %ragged-dot-none.3)"},
     ]
-    traced = _run(opened, closed, trace={"devices": [{"ops": ops}]})
+    # 20 whole steps in the traced stretch, whatever the hooks counted
+    trace = {"devices": [{"ops": ops, "steps": 20}]}
+    traced = _run(opened, closed, trace=trace)
     assert abs(gmm.read(traced) - 50.0) < 1e-9
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["roofline"]["bound"] == "flops"
     assert abs(line["other_custom_call_seconds"] - 0.5) < 1e-12
     assert gmm.read(_run(
-        opened, closed, model={"num_experts": 0},
-        trace={"devices": [{"ops": ops}]})) is None
-    assert gmm.read(_run(
-        opened, closed, trace={"devices": [{"ops": ops[2:]}]})) is None
+        opened, closed, model={"num_experts": 0}, trace=trace)) is None
+    trace = {"devices": [{"ops": ops[2:], "steps": 20}]}
+    assert gmm.read(_run(opened, closed, trace=trace)) is None
 
 
 def test_cpu_rehearsal_of_a_sparse_cell():

@@ -1,4 +1,14 @@
-"""Operations and bytes from shapes: the benchmark's own arithmetic.
+"""Operations and bytes from shapes: the benchmark's own arithmetic, and
+the family module of the models whose layers are all one dense block:
+attention over ``num_heads`` heads of ``model_dim / num_heads``, then one
+MLP (GPT-2's block, and what ``init_params`` builds without experts).
+
+A configuration names its family module with ``"flops": "<module>"``
+(absent: this one), and ``run.py`` and the trace readers ask that module's
+two functions, ``count`` and ``step_work``, and reckon no model's shape
+themselves. A family of another block brings its own ``flops_<family>.py``
+and builds on the per-layer pieces here (``attention_kernel_work``,
+``roofline_seconds``), which know nothing of any model's layout.
 
 ``model`` is the ``model`` group of a configuration file (the fields of
 ``TransformerConfig``). Nothing here imports the program or JAX.
@@ -42,11 +52,39 @@ def train_flops_per_token(model: dict, seq: int) -> float:
     return 6.0 * n_params(model) + attn
 
 
-def mfu_pct(tokens_per_s: float, model: dict, seq: int, peak_flops: float,
-            chips: int = 1) -> float:
-    return 100.0 * tokens_per_s * train_flops_per_token(model, seq) / (
-        peak_flops * chips
+def count(model: dict, seq: int) -> dict:
+    """The hook's first function: parameters held, parameters one token
+    passes through (a dense model: all of them), and the forward +
+    backward operations a token of a ``seq`` long row needs."""
+    n = n_params(model)
+    return {
+        "params": n,
+        "active_params": n,
+        "train_flops_per_token": train_flops_per_token(model, seq),
+    }
+
+
+def step_work(model: dict, batch: int, seq: int) -> dict:
+    """The hook's second function: the least one whole training step of
+    ``batch`` rows of ``seq`` tokens needs in each kind of kernel, every
+    layer that runs it added up, forward and backward, recomputation not
+    counted; ``None`` for a kind the model does not run. Here every layer
+    runs the one attention and none a grouped matmul."""
+    heads = model["num_heads"]
+    layer = attention_kernel_work(
+        batch, heads, seq, model["model_dim"] // heads
     )
+    return {
+        "attention": {
+            k: v * model["num_layers"] for k, v in layer.items()
+        },
+        "grouped_matmul": None,
+    }
+
+
+def mfu_pct(tokens_per_s: float, flops_per_token: float, peak_flops: float,
+            chips: int = 1) -> float:
+    return 100.0 * tokens_per_s * flops_per_token / (peak_flops * chips)
 
 
 def attention_kernel_work(batch: int, heads: int, seq: int, head_dim: int,

@@ -1,11 +1,19 @@
-"""Parameters, operations and bytes of a sparse-expert model from shapes:
-the benchmark's own arithmetic for configurations with ``num_experts``.
+"""The family module (``"flops": "flops_moe"`` in a configuration) of the
+models whose layers are all one sparse block: attention over ``num_heads``
+heads of ``model_dim / num_heads``, then ``num_experts`` experts, all held
+here, ``moe_top_k`` of them a token, gated where ``swiglu`` (OLMoE's
+block). ``count`` and ``step_work`` are what ``run.py`` and the trace
+readers ask; the rest are the per-layer pieces they are built on. A model
+whose layers are not all alike, or that holds a share of its experts,
+brings its own ``flops_<family>.py`` (see ``flops.py``).
 
 ``model`` is the ``model`` group of a configuration file (the fields of
-``TransformerConfig``). Nothing here imports the program, JAX or
-``flops.py`` (whose ``n_params`` counts a dense model: one ``mlp_dim``
-wide MLP a layer).
+``TransformerConfig``). Nothing here imports the program or JAX; of
+``flops.py`` only ``step_work``, for the attention, which is the dense
+block's (its ``n_params`` counts one ``mlp_dim`` wide MLP a layer).
 """
+
+from flops import step_work as dense_step_work
 
 
 def _layer_parts(model: dict) -> dict:
@@ -26,13 +34,17 @@ def _layer_parts(model: dict) -> dict:
     }
 
 
+def _every_layer_sparse(model: dict) -> None:
+    if model.get("moe_every", 2) != 1:
+        raise ValueError("flops_moe counts models whose every layer is sparse")
+
+
 def n_params(model: dict) -> dict:
     """``total`` parameters as ``init_params`` builds them with every
     layer sparse (``moe_every`` 1), and those ``active`` for one token:
     its ``moe_top_k`` experts of each layer and everything that is not an
     expert."""
-    if model.get("moe_every", 2) != 1:
-        raise ValueError("flops_moe counts models whose every layer is sparse")
+    _every_layer_sparse(model)
     d, v = model["model_dim"], model["vocab_size"]
     p = _layer_parts(model)
     outside = v * d + (d if model.get("rmsnorm") else 2 * d)
@@ -89,3 +101,28 @@ def grouped_matmul_work(model: dict, tokens: int, act_bytes: int = 2) -> dict:
         "flops": flops,
         "bytes": float(projections * 3 * moved * act_bytes),
     }
+
+
+def count(model: dict, seq: int) -> dict:
+    """The hook's first function (``flops.count``): every parameter held,
+    those one token passes through, and its forward + backward
+    operations in a ``seq`` long row."""
+    n = n_params(model)
+    return {
+        "params": n["total"],
+        "active_params": n["active"],
+        "train_flops_per_token": train_flops_per_token(model, seq),
+    }
+
+
+def step_work(model: dict, batch: int, seq: int) -> dict:
+    """The hook's second function (``flops.step_work``): every layer runs
+    the one attention and the grouped matmuls of all its experts."""
+    _every_layer_sparse(model)
+    layer = grouped_matmul_work(model, batch * seq)
+    return dict(
+        dense_step_work(model, batch, seq),
+        grouped_matmul={
+            k: v * model["num_layers"] for k, v in layer.items()
+        },
+    )

@@ -1,9 +1,12 @@
 """The Pallas attention kernels' share of their roofline: the least time
 the chip could take for the attention of the traced stretch's whole steps
 (``xplane.step_stretch``), which is the larger of operations over the bf16
-peak and bytes over HBM bandwidth, both from shapes by
-``flops.attention_kernel_work``, over the summed device time of the
-kernels' events, forward and backward. Which bound holds is printed on an
+peak and bytes over HBM bandwidth, over the summed device time of the
+kernels' events, forward and backward. The operations and bytes of one
+step are the ``attention`` of ``step_work`` in the configuration's family
+module (``run.hook``: which layers run attention, with how many heads of
+what width, is that module's knowledge and not this reader's); a model
+that runs none has nothing to read. Which bound holds is printed on an
 earlier line, with the seconds of the other ``tpu_custom_call`` events."""
 
 import json
@@ -21,6 +24,10 @@ MOVES = "tokens_per_s"
 TARGET = "tpu_custom_call"
 NAME = "flash_attn"
 
+# a share of a roofline cannot pass 100 %: above it the family module counts
+# work the program does not run, and run.py refuses the run with the numbers
+CEILING = 100.0
+
 
 def CELLS(cell):
     return True
@@ -32,6 +39,11 @@ def read(run):
 
     if not run.trace or not run.trace.get("devices") or not run.peak:
         return None
+    work = run.hook.step_work(
+        run.config["model"], run.cell["batch"], run.cell["seq"]
+    ).get("attention")
+    if work is None:
+        return None
     device = run.trace["devices"][0]
     steps = device["steps"]
     calls = xplane.kernel_seconds(device, (TARGET,))
@@ -39,13 +51,7 @@ def read(run):
     found = xplane.kernel_seconds({"ops": named}, (TARGET,))
     if not found["seconds"]:
         return None
-    m = run.config["model"]
-    work = flops.attention_kernel_work(
-        run.cell["batch"], m["num_heads"], run.cell["seq"],
-        m["model_dim"] // m["num_heads"],
-    )
-    layers = m["num_layers"] * steps
-    work = {k: v * layers for k, v in work.items()}
+    work = {k: v * steps for k, v in work.items()}
     roof = flops.roofline_seconds(work, run.peak)
     print(json.dumps({
         "attention_kernels": found, "roofline": roof,

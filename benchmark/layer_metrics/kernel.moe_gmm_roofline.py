@@ -1,9 +1,12 @@
 """The grouped expert matmuls' share of their roofline: the least time the
 chip could take for the expert projections of the traced stretch's whole
 steps (``xplane.step_stretch``), which is the larger of operations over the
-bf16 peak and bytes over HBM bandwidth, both from shapes by
-``flops_moe.grouped_matmul_work``, over the summed device time
+bf16 peak and bytes over HBM bandwidth, over the summed device time
 of the grouped-matmul events, forward, recomputed forward and backward.
+The operations and bytes of one step are the ``grouped_matmul`` of
+``step_work`` in the configuration's family module (``run.hook``: which
+layers are sparse, how many experts are held here and how many projections
+an expert has is that module's knowledge and not this reader's).
 Which bound holds is printed on an earlier line, with the seconds of the
 other ``tpu_custom_call`` events (flash attention's) beside it.
 
@@ -16,7 +19,7 @@ start ``%ragged-dot`` (``%ragged-dot-none.N`` the matmul,
 found by the start of that name, and not with ``xplane.kernel_seconds``,
 which also searches an event's HLO text: a fusion that takes a grouped
 matmul's result as an operand holds its name there. Nothing to read where
-the configuration has no experts or the trace holds no such event."""
+the model runs no grouped matmul or the trace holds no such event."""
 
 import json
 
@@ -26,6 +29,9 @@ MOVES = "tokens_per_s"
 
 PREFIX = "%ragged-dot"
 
+# as kernel.attn_roofline's: above it run.py refuses the run
+CEILING = 100.0
+
 
 def CELLS(cell):
     return bool(cell.get("moe"))
@@ -33,13 +39,14 @@ def CELLS(cell):
 
 def read(run):
     import flops
-    import flops_moe
     import xplane
 
-    m = run.config["model"]
-    if not m.get("num_experts"):
-        return None
     if not run.trace or not run.trace.get("devices") or not run.peak:
+        return None
+    work = run.hook.step_work(
+        run.config["model"], run.cell["batch"], run.cell["seq"]
+    ).get("grouped_matmul")
+    if work is None:
         return None
     device = run.trace["devices"][0]
     steps = device["steps"]
@@ -51,11 +58,7 @@ def read(run):
     }
     if not found["seconds"]:
         return None
-    work = flops_moe.grouped_matmul_work(
-        m, run.cell["batch"] * run.cell["seq"]
-    )
-    layers = m["num_layers"] * steps
-    work = {k: v * layers for k, v in work.items()}
+    work = {k: v * steps for k, v in work.items()}
     roof = flops.roofline_seconds(work, run.peak)
     every = xplane.kernel_seconds(device, ("tpu_custom_call",))
     print(json.dumps({

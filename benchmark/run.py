@@ -23,8 +23,10 @@ configuration ``configs/<name>.json``, a per-layer metric
 from __future__ import annotations
 
 import argparse
+import contextlib
 import glob
 import importlib.util
+import io
 import json
 import math
 import os
@@ -63,7 +65,12 @@ TAIL_LINES = 30
 
 
 class Refused(Exception):
-    """The run cannot start or did not reach its end: no result line."""
+    """The run cannot start or did not reach its end: no result line.
+    ``detail`` holds the numbers that go into the note beside the reason."""
+
+    def __init__(self, reason: str, detail: Optional[Dict] = None):
+        super().__init__(reason)
+        self.detail = detail or {}
 
 
 def note(obj: Dict) -> None:
@@ -91,6 +98,32 @@ def maybe_json(path: str) -> Optional[Dict]:
         return None
 
 
+def json_lines(text: str) -> List:
+    """Each line of ``text`` as the object it holds, or as it is."""
+    out = []
+    for line in text.splitlines():
+        try:
+            out.append(json.loads(line))
+        except ValueError:
+            out.append(line)
+    return out
+
+
+def is_number(v) -> bool:
+    return (
+        isinstance(v, (int, float)) and not isinstance(v, bool)
+        and math.isfinite(v)
+    )
+
+
+def load_module(name: str, path: str):
+    """The Python file at ``path`` as a module of its own."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def read_text(path: str) -> str:
     try:
         with open(path, errors="replace") as f:
@@ -103,6 +136,8 @@ CELL_KEYS = ("config", "traffic", "batch", "seq", "save_memory_interval",
              "kill", "warmup", "max_steps", "why")
 CONFIG_KEYS = ("source", "model", "optimizer", "strategy", "reduced",
                "reference", "reference_check")
+# what a configuration's family module (its ``flops`` key) has to define
+HOOK = ("count", "step_work")
 
 
 def load_cell(name: str, data_dir: str = HERE) -> Dict:
@@ -127,7 +162,68 @@ def load_config(name: str, data_dir: str = HERE) -> Dict:
     ref = os.path.join(HERE, "references", f"{config['reference']}.py")
     if not os.path.exists(ref):
         raise Refused(f"configuration {name}: no plain reference {ref}")
+    load_hook(name, config, data_dir)
     return config
+
+
+def load_hook(name: str, config: Dict, data_dir: str = HERE):
+    """The configuration's family module: ``<flops>.py`` (``flops`` where
+    the key is absent) beside ``configs/`` or, failing that, here. It
+    counts the model's parameters and a step's least work for ``run.py``
+    and the trace readers, which reckon no model's shape themselves."""
+    module = config.get("flops", "flops")
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", str(module)):
+        raise Refused(f"configuration {name}: flops {module!r} names no module")
+    paths = [os.path.join(d, f"{module}.py") for d in (data_dir, HERE)]
+    path = next((p for p in paths if os.path.exists(p)), None)
+    if path is None:
+        raise Refused(
+            f"configuration {name}: no family module {module}.py "
+            f"(looked for {' and '.join(dict.fromkeys(paths))})"
+        )
+    try:
+        hook = load_module("family_" + module, path)
+    except Exception as e:
+        raise Refused(f"configuration {name}: {path}: {e!r}") from None
+    missing = [f for f in HOOK if not callable(getattr(hook, f, None))]
+    if missing:
+        raise Refused(
+            f"configuration {name}: {path} lacks the function(s) {missing} "
+            f"of the hook {list(HOOK)}"
+        )
+    return hook
+
+
+def ask_hook(hook, name: str, model: Dict, batch: int, seq: int) -> Dict:
+    """What the family module's ``count`` says of this cell. Both functions
+    are asked before the run starts, so that a module that cannot count the
+    model, or answers in another shape than the hook's (``count``'s three
+    numbers; ``step_work``'s kinds, each ``None`` or operations and bytes),
+    costs no time on the chip."""
+    def number(v):
+        return is_number(v) and v > 0
+
+    try:
+        counted = hook.count(model, seq)
+        work = hook.step_work(model, batch, seq)
+        sound = all(
+            number(counted[k])
+            for k in ("params", "active_params", "train_flops_per_token")
+        ) and all(
+            w is None or (number(w["flops"]) and number(w["bytes"]))
+            for w in work.values()
+        )
+    except Exception as e:
+        raise Refused(
+            f"configuration {name}: {hook.__file__} cannot count the "
+            f"model: {e!r}"
+        ) from None
+    if not sound:
+        raise Refused(
+            f"configuration {name}: {hook.__file__} answers in another "
+            f"shape than the hook's", {"count": counted, "step_work": work},
+        )
+    return counted
 
 
 def load_layer_metrics() -> Dict[str, object]:
@@ -136,11 +232,9 @@ def load_layer_metrics() -> Dict[str, object]:
     for path in sorted(glob.glob(os.path.join(HERE, "layer_metrics", "*.py"))):
         name = os.path.basename(path)[:-3]
         try:
-            spec = importlib.util.spec_from_file_location(
+            mod = load_module(
                 "layer_metric_" + re.sub(r"\W", "_", name), path
             )
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
             for attr in ("LAYER", "UNIT", "MOVES", "CELLS", "read"):
                 getattr(mod, attr)
         except Exception as e:
@@ -282,6 +376,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     t0 = time.monotonic()
     cell = load_cell(workload, data_dir)
     config = load_config(cell["config"], data_dir)
+    hook = load_hook(cell["config"], config, data_dir)
+    counted = ask_hook(
+        hook, cell["config"], config["model"], int(cell["batch"]),
+        int(cell["seq"]),
+    )
     metrics_mods = load_layer_metrics()
     if not os.path.isdir(os.path.join(ROOT, "dlrover_tpu")):
         raise Refused("no program here: dlrover_tpu/ is not in the checkout")
@@ -374,7 +473,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     if dev["platform"] == "tpu":
         peak = peaks.peaks(dev["kind"])
         mfu = flops.mfu_pct(
-            summary["tokens_per_s"], config["model"], int(cell["seq"]),
+            summary["tokens_per_s"], counted["train_flops_per_token"],
             peak["bf16_flops"], chips,
         )
 
@@ -386,6 +485,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         cell=cell, config=config, seconds=seconds, reports=reports,
         steps=steps, window=window, in_window=in_window, summary=summary,
         spans=spans, recovery=recovery, trace=trace_reduced, peak=peak,
+        hook=hook,
     )
 
     end_to_end = {
@@ -397,9 +497,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     note({
         "window": {k: v for k, v in summary.items() if k != "step_ms"},
         "step_p95_samples": summary["samples"],
-        "mfu_pct": mfu, "flops_per_token": flops.train_flops_per_token(
-            config["model"], int(cell["seq"])),
-        "n_params": flops.n_params(config["model"]),
+        "mfu_pct": mfu,
+        "flops_per_token": counted["train_flops_per_token"],
+        "n_params": counted["params"],
+        "active_params": counted["active_params"],
         "state_bytes": reports[0].get("state_bytes"),
         "reference_check": ref,
         "compiles_in_window": compiles_in_window,
@@ -418,18 +519,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         "problems": problems,
     })
 
-    layer = {}
-    for name, mod in metrics_mods.items():
-        try:
-            if not mod.CELLS(cell):
-                continue
-            value = mod.read(run)
-        except Exception as e:
-            note({"per_layer_metric": name, "error": repr(e)})
-            continue
-        if value is None or not math.isfinite(value):
-            continue
-        layer[name] = (float(value), mod.UNIT)
+    layer = read_layer_metrics(metrics_mods, run)
     if trace:
         note({"per_layer": {k: v[0] for k, v in layer.items()}})
     else:
@@ -474,6 +564,47 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     return result
 
 
+def read_layer_metrics(mods: Dict[str, object], run) -> Dict[str, tuple]:
+    """``{metric: (value, unit)}`` of every reader whose ``CELLS`` rule takes
+    the cell and that finds something to read. A reader may declare a
+    ``CEILING`` (a share of a roofline or a peak: 100.0): a value above it
+    is never printed, clamped or left out, it is ``Refused`` with what the
+    reader printed on its earlier line (the seconds it found, the least
+    seconds it set against them) and the step's work the family module
+    counted, because that module then counts work the program does not
+    run, or the reader misses part of the kernels' time."""
+    layer = {}
+    for name, mod in mods.items():
+        said = io.StringIO()
+        try:
+            if not mod.CELLS(run.cell):
+                continue
+            with contextlib.redirect_stdout(said):
+                value = mod.read(run)
+        except Exception as e:
+            note({"per_layer_metric": name, "error": repr(e)})
+            continue
+        finally:
+            print(said.getvalue(), end="", flush=True)
+        if value is None or not math.isfinite(value):
+            continue
+        ceiling = getattr(mod, "CEILING", None)
+        if ceiling is not None and value > ceiling:
+            raise Refused(
+                f"per-layer metric {name} reads {value} {mod.UNIT}, above "
+                f"its ceiling of {ceiling}: more work is counted than the "
+                f"kernels found could have done in their seconds",
+                {"metric": name, "value": value, "ceiling": ceiling,
+                 "reader_printed": json_lines(said.getvalue()),
+                 "step_work_counted": run.hook.step_work(
+                     run.config["model"], run.cell["batch"],
+                     run.cell["seq"]),
+                 "steps_traced": (run.trace or {}).get("steps")},
+            )
+        layer[name] = (float(value), mod.UNIT)
+    return layer
+
+
 def stretch_beside_host_clock(d0: Dict, host: Dict,
                               in_window: List[Dict]) -> Dict:
     """The traced stretch of the first device beside what the whole trace
@@ -511,11 +642,7 @@ def line_breaches(result: Dict, traced: bool) -> List[str]:
     value and a unit), ``device`` (``platform``, ``kind``, ``count``,
     ``memory_peak_bytes`` and, traced, ``0 < busy_s <= window_s``) and,
     where it is there, ``breakdown`` (two lists of at most 10 pairs)."""
-    def number(v):
-        return (
-            isinstance(v, (int, float)) and not isinstance(v, bool)
-            and math.isfinite(v)
-        )
+    number = is_number
 
     def count(v):
         return isinstance(v, int) and not isinstance(v, bool) and v >= 0
@@ -657,7 +784,7 @@ def main(argv=None) -> int:
             args.workload, args.seed, args.seconds, bool(args.trace)
         )
     except Refused as e:
-        note({"refused": str(e)})
+        note({"refused": str(e), **e.detail})
         return 3
     if result["device"].get("platform") != "tpu":
         note({"refused": f"not a TPU run: {result['device']}"})

@@ -66,8 +66,10 @@ def test_mfu_and_roofline_by_hand():
     m = _config("gpt2-124m")["model"]
     p = peaks.peaks("TPU v5 lite")
     # 197e12 / 0.803e9 flops a token = 245k tokens/s at 100%
-    full = p["bf16_flops"] / flops.train_flops_per_token(m, 1024)
-    assert abs(flops.mfu_pct(full / 2, m, 1024, p["bf16_flops"]) - 50) < 1e-9
+    per_token = flops.train_flops_per_token(m, 1024)
+    full = p["bf16_flops"] / per_token
+    assert abs(flops.mfu_pct(full / 2, per_token, p["bf16_flops"]) - 50) \
+        < 1e-9
     # one head, one row, T=1024, D=64: 6 matmuls of 2*T*T*D/2
     w = flops.attention_kernel_work(1, 1, 1024, 64)
     assert w["flops"] == 6 * 1024 * 1024 * 64

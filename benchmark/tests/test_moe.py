@@ -73,11 +73,11 @@ def test_grouped_matmul_work_by_hand():
     assert w["flops"] / p["bf16_flops"] > w["bytes"] / p["hbm_bytes_per_s"]
 
 
-def _run(pipeline_open, pipeline, model=None, trace=None):
-    model = model or _config("olmoe-1b-7b-d2")["model"]
+def _run(pipeline_open, pipeline, config="olmoe-1b-7b-d2", trace=None):
+    config = _config(config)
     return SimpleNamespace(
         cell={"batch": 2, "seq": 4096, "moe": True},
-        config={"model": model}, trace=trace,
+        config=config, hook=harness.load_hook("x", config), trace=trace,
         peak=peaks.peaks("TPU v5 lite"),
         window={"pipeline_open": pipeline_open, "pipeline": pipeline,
                 # the host's hooks: no reader of device time counts them
@@ -126,13 +126,14 @@ def test_readers_on_hand_made_runs(capsys):
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["roofline"]["bound"] == "flops"
     assert abs(line["other_custom_call_seconds"] - 0.5) < 1e-12
+    # a family whose step runs no grouped matmul (the dense block's)
     assert gmm.read(_run(
-        opened, closed, model={"num_experts": 0}, trace=trace)) is None
+        opened, closed, config="gpt2-124m", trace=trace)) is None
     trace = {"devices": [{"ops": ops[2:], "steps": 20}]}
     assert gmm.read(_run(opened, closed, trace=trace)) is None
 
 
-def test_cpu_rehearsal_of_a_sparse_cell():
+def test_cpu_rehearsal_of_a_sparse_cell(capsys):
     res = harness.run_cell(
         "toy-olmoe.steady", seed=3000000019, seconds=2.0, trace=False,
         device_spec="cpu:1", expect_platform="cpu",
@@ -140,6 +141,16 @@ def test_cpu_rehearsal_of_a_sparse_cell():
     )
     assert res["correct"] is True and res["failed"] == 0
     assert res["device"]["platform"] == "cpu"  # never a device metric
+    # the notes count the sparse model (the toy's "flops": "flops_moe"):
+    # all 8 experts of a layer are held and a token passes through all 8
+    notes = next(n for n in harness.json_lines(capsys.readouterr().out)
+                 if isinstance(n, dict) and "n_params" in n)
+    layer = 4 * 64**2 + 64 * 8 + 8 * 3 * 64 * 32 + 4 * 64
+    assert notes["n_params"] == notes["active_params"] == (
+        2 * 256 * 64 + 64 + 2 * layer)
+    assert notes["flops_per_token"] == 6.0 * (
+        2 * (layer - 4 * 64) + 64 * 256) + 6.0 * 2 * 64 * 64
+    assert notes["mfu_pct"] is None  # no peak: the CPU
     run_dir = os.path.join(os.path.dirname(BENCH), ".benchmark_run",
                            "toy-olmoe.steady")
     with open(os.path.join(run_dir, "window_r0.json")) as f:

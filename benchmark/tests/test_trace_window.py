@@ -269,7 +269,8 @@ def _config(name):
 
 def _run_of(trace, config, batch, seq, hooks=20):
     return SimpleNamespace(
-        trace=trace, config=config, peak=peaks.peaks("TPU v5 lite"),
+        trace=trace, config=config, hook=harness.load_hook("x", config),
+        peak=peaks.peaks("TPU v5 lite"),
         cell={"batch": batch, "seq": seq, "moe": True},
         window={"trace": {"step_begin": 100, "step_end": 100 + hooks,
                           "t_begin": 0.0, "t_end": 1.0}},

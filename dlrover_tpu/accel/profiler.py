@@ -114,6 +114,17 @@ class PipelineStats:
     # Set once when the trainer has built its state; 0 / 0 for fp32
     opt_q8_tiles_elems: int = 0
     opt_q8_blocks_elems: int = 0
+    # fused short-sequence attention call sites (ops/flash_attention.py
+    # ``FusedTally``, forward and backward each counted) this process
+    # has lowered, by body: the triangle walk, which computes only the
+    # score tiles a causal query can see, or the whole square; and the
+    # tiles the triangle sites walk against the tiles of their squares.
+    # Set when the trainer logs the programs it built; 0 everywhere
+    # for a model that never enters the fused family
+    attn_tri_sites: int = 0
+    attn_square_sites: int = 0
+    attn_tiles_walked: int = 0
+    attn_tiles_square: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was

@@ -30,8 +30,20 @@ Design:
   per (batch element, head chunk) with a python-unrolled head loop,
   single-pass softmax, and ONE backward kernel computing s/p/dp once
   and emitting dq/dk/dv together (5 matmuls vs the streaming split's
-  7). Programs cover head CHUNKS sized so the unrolled per-head [T,T]
-  f32 temporaries stay within scoped VMEM (``_head_chunk``).
+  7). Two bodies, chosen by what is known when the program is traced:
+  **the triangle walk** when the mask is causal alone and the query
+  and key offsets are static and equal (a sequence attending to
+  itself): row tile *i* of ``bq`` queries meets keys ``[0, (i+1)*bq)``
+  only, masked on its diagonal tile, so a head computes (n+1)/2n of
+  its score square (62.5 % with four row tiles) and nothing above the
+  diagonal; **the whole square** otherwise (traced offsets: a ring
+  hop; unequal offsets, ``mask_fn``, ``causal=False``, a T the row
+  tile does not divide), masked afterwards, skipped whole when a ring
+  hop's keys all lie in the future. Programs cover head CHUNKS: the
+  square's unrolled per-head [T,T] f32 temporaries must stay within
+  scoped VMEM (``_head_chunk``); the walk loops over its heads and is
+  sized by its pipeline's blocks (``_walk_head_chunk``).
+  ``fused_tally()`` counts the call sites lowered by body.
 - ``layout="bhtd"`` lets callers hand over kernel-native [B, H, T, D]
   tensors (the model emits them straight from its QKV einsums), skipping
   the 25 MB-per-tensor relayout transposes on every call.
@@ -44,7 +56,7 @@ elementwise bool mask, e.g. ``lambda q, k: q >= k`` for causal.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -166,6 +178,7 @@ def _fwd_pallas(
     interpret,
     layout="bthd",
     allow_fused=True,
+    diagonal=False,
 ):
     # Kernel layout is [B, H, T, D]: TPU tiling needs the last two block
     # dims to be (seq_block, head_dim) — (8,128)-aligned or full-size.
@@ -187,7 +200,7 @@ def _fwd_pallas(
         ot, lse4 = _fused_fwd_call(
             qt, kt, vt, offsets,
             causal=causal, mask_fn=mask_fn, sm_scale=sm_scale,
-            interpret=interpret,
+            interpret=interpret, diagonal=diagonal,
         )
         if layout == "bhtd":
             return ot, lse4[..., 0]
@@ -247,14 +260,24 @@ def _fwd_pallas(
 
 
 # ---------------------------------------------------------------------------
-# fused short-sequence kernels (one program per batch element)
+# fused short-sequence kernels (one program per batch element and head
+# chunk): the whole-square body and the triangle walk
 # ---------------------------------------------------------------------------
 # Eligibility: the [T, T] f32 score tile must fit scoped VMEM (see
-# _head_chunk, which sizes head chunks against a 48 MB live-set budget
-# under the raised _FUSED_VMEM_LIMIT). At T=2048 a single head's
-# backward live set (~3.5 x 16 MB) no longer fits; the streaming
-# kernels take over there.
+# _head_chunk, which sizes the square body's head chunks against a
+# 48 MB live-set budget under the raised _FUSED_VMEM_LIMIT). At T=2048
+# a single head's backward live set (~3.5 x 16 MB) no longer fits; the
+# streaming kernels take over there. Measured at T=1024, D=64 (v5e,
+# bf16, PERF.md PR 36): the streaming kernels, which skip invisible
+# blocks too, take 2.2x (forward) and 1.8x (backward) the square's
+# time, so the cap stays.
 _FUSED_MAX_T = 1024
+# query rows a tile of the triangle walk. Measured at T=1024, D=64
+# (forward / backward ms a call of [16, 12, 1024, 64]; square 0.702 /
+# 1.833): 256 rows 0.542 / 1.428, 128 rows 0.620 / 1.424 (least work,
+# but the forward's softmax no longer hides under its fetches), 512
+# rows 0.541 / 1.590
+_TRI_ROW_TILE = 256
 
 
 def _fused_eligible(q_shape, k_shape, layout: str) -> bool:
@@ -265,6 +288,70 @@ def _fused_eligible(q_shape, k_shape, layout: str) -> bool:
         B, Tq, H, D = q_shape
         Tk, Hkv = k_shape[1], k_shape[2]
     return Tq == Tk and Tq <= _FUSED_MAX_T and H == Hkv
+
+
+def _row_tile(T: int) -> Optional[int]:
+    """Query rows a tile of the triangle walk, or None where a walk
+    would skip nothing or cannot tile T (T = 520 is fused-eligible)."""
+    bq = _TRI_ROW_TILE
+    return bq if T % bq == 0 and T // bq >= 2 else None
+
+
+def _mask_diagonal_tile(s, row_tile: int):
+    """Causal mask of a row tile's scores ``[bq, (i+1)*bq]`` when query
+    and key offsets are equal: the last ``bq`` columns are the tile on
+    the diagonal, every column before them is visible to every row."""
+    below = s.shape[1] - row_tile
+    r = lax.broadcasted_iota(jnp.int32, (row_tile, row_tile), 0)
+    c = lax.broadcasted_iota(jnp.int32, (row_tile, row_tile), 1)
+    diag = jnp.where(r >= c, s[:, below:], NEG_INF)
+    if not below:
+        return diag
+    return jnp.concatenate([s[:, :below], diag], axis=1)
+
+
+def _for_each_head(n_heads: int, head):
+    """The triangle walk's head loop: a real loop (the row tiles inside
+    ``head`` are unrolled), so a kernel compiles in a second whatever
+    the chunk, and only the pipeline's blocks grow with it."""
+
+    def body(h, carry):
+        head(h)
+        return carry
+
+    lax.fori_loop(0, n_heads, body, 0)
+
+
+class FusedTally(NamedTuple):
+    """Fused call sites lowered so far in this process, forward and
+    backward each counted, by the body they took, and the ``row_tile``
+    square score tiles the triangle sites walk against the tiles of
+    their whole squares. Counted when a program is traced, so it costs
+    a step nothing; a program that came out of a cache of executables
+    was not traced and adds nothing."""
+
+    tri_sites: int = 0
+    square_sites: int = 0
+    tiles_walked: int = 0
+    tiles_square: int = 0
+
+    def __sub__(self, other):
+        return FusedTally(*(a - b for a, b in zip(self, other)))
+
+
+_tally = FusedTally()
+
+
+def fused_tally() -> FusedTally:
+    return _tally
+
+
+def _tally_site(T: int, row_tile: Optional[int]):
+    global _tally
+    n = T // row_tile if row_tile else 0  # a square site walks no tiles
+    _tally = FusedTally(*(a + b for a, b in zip(
+        _tally, (n > 0, n == 0, n * (n + 1) // 2, n * n)
+    )))
 
 
 def _fused_fwd_kernel(
@@ -279,8 +366,36 @@ def _fused_fwd_kernel(
     mask_fn: Optional[MaskFn],
     sm_scale: float,
     n_heads: int,
+    row_tile: Optional[int] = None,
 ):
     T = q_ref.shape[2]
+    if row_tile is not None:
+        # the triangle walk: causal, no mask_fn, offsets known equal
+
+        def _head(h):
+            for i in range(T // row_tile):
+                rows = pl.ds(i * row_tile, row_tile)
+                seen = pl.ds(0, (i + 1) * row_tile)
+                s = jax.lax.dot_general(
+                    q_ref[0, h, rows, :], k_ref[0, h, seen, :],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                s = _mask_diagonal_tile(s * sm_scale, row_tile)
+                # every row sees itself: m is finite and l > 0
+                m = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                acc = jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[0, h, seen, :],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                o_ref[0, h, rows, :] = (acc / l).astype(o_ref.dtype)
+                lse_ref[0, h, rows, :] = m + jnp.log(l)
+
+        _for_each_head(n_heads, _head)
+        return
 
     def _compute():
         q_pos = off_ref[0] + lax.broadcasted_iota(jnp.int32, (T, 1), 0)
@@ -342,16 +457,69 @@ def _fused_bwd_kernel(
     dq_ref,  # out [1, H, T, D]
     dk_ref,
     dv_ref,
-    *,
+    *scratch,
     causal: bool,
     mask_fn: Optional[MaskFn],
     sm_scale: float,
     n_heads: int,
+    row_tile: Optional[int] = None,
 ):
     """One pass per head: s and p computed ONCE, then the three grad
     matmuls — the streaming FA2 split recomputes (s, p, dp) in both its
-    dq and dk/dv kernels (7 matmuls/head vs 5 here)."""
+    dq and dk/dv kernels (7 matmuls/head vs 5 here). The triangle walk
+    (``row_tile``) takes two more refs, ``dk_acc`` / ``dv_acc``: float32
+    scratch ``[T, D]`` the row tiles add their keys' gradients into."""
     T = q_ref.shape[2]
+    if row_tile is not None:
+        dk_acc, dv_acc = scratch
+        last = T // row_tile - 1
+
+        def _head(h):
+            # widest tile first: it writes every key's row, the
+            # narrower ones add into theirs, nothing is zeroed
+            for i in range(last, -1, -1):
+                rows = pl.ds(i * row_tile, row_tile)
+                seen = pl.ds(0, (i + 1) * row_tile)
+                q = q_ref[0, h, rows, :]
+                k = k_ref[0, h, seen, :]
+                do = do_ref[0, h, rows, :]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                s = _mask_diagonal_tile(s * sm_scale, row_tile)
+                # lse is finite (every row sees itself); a masked
+                # score gives exp(NEG_INF - lse) = 0
+                p = jnp.exp(s - lse_ref[0, h, rows, :])  # [bq, W]
+                dv = jax.lax.dot_general(
+                    p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dp = jax.lax.dot_general(
+                    do, v_ref[0, h, seen, :], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                ds = p * (dp - delta_ref[0, h, rows, :]) * sm_scale
+                ds_lo = ds.astype(q.dtype)
+                dq_ref[0, h, rows, :] = jax.lax.dot_general(
+                    ds_lo, k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ).astype(dq_ref.dtype)
+                dk = jax.lax.dot_general(
+                    ds_lo, q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                if i == last:
+                    dk_acc[...] = dk
+                    dv_acc[...] = dv
+                else:
+                    dk_acc[seen, :] += dk
+                    dv_acc[seen, :] += dv
+            dk_ref[0, h] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0, h] = dv_acc[...].astype(dv_ref.dtype)
+
+        _for_each_head(n_heads, _head)
+        return
 
     def _compute():
         q_pos = off_ref[0] + lax.broadcasted_iota(jnp.int32, (T, 1), 0)
@@ -414,20 +582,42 @@ def _fused_bwd_kernel(
         _compute()
 
 def _head_chunk(H: int, T: int, live_f32_per_head: float) -> int:
-    """Heads per program: the unrolled head loop's [T, T] f32 temporaries
-    occupy scoped VMEM stack; chunk so ``Hc * live set`` stays under a
-    conservative budget (the raised ``vmem_limit_bytes`` leaves slack for
-    the compiler's own scheduling)."""
+    """Heads per program of the WHOLE-SQUARE body: the unrolled head
+    loop's [T, T] f32 temporaries occupy scoped VMEM stack; chunk so
+    ``Hc * live set`` stays under a conservative budget (the raised
+    ``vmem_limit_bytes`` leaves slack for the compiler's own
+    scheduling)."""
     # measured on v5e (bf16, D=64/128): larger chunks amortize
     # per-program overhead — T=512 all-12-heads beats 9 by 27%, T=1024
     # Hc=4 beats Hc=2 by 28% — and Mosaic tolerates a live set past
     # physical VMEM by scheduling spills; the hard compile failure on
     # v5e lands near ~64 MB x live-factor, so 48 MB keeps margin
-    budget = 48 * 1024 * 1024
-    per_head = live_f32_per_head * T * T * 4
+    return _largest_chunk(H, live_f32_per_head * T * T * 4, 48 << 20)
+
+
+def _walk_head_chunk(H: int, T: int, D: int, itemsize: int,
+                     wide: int, narrow: int) -> int:
+    """Heads per program of the TRIANGLE walk. Its heads run in a real
+    loop, so one head's ``[bq, T]`` temporaries are all the compute
+    holds; what grows with the chunk is the pipeline: ``wide``
+    ``[T, D]`` blocks and ``narrow`` ``[T, 1]`` f32 blocks a head, each
+    double-buffered, the minor dimension padded to the 128 lanes."""
+    # measured on v5e (bf16, T=1024, D=64; PERF.md, PR 36): the walk is
+    # bound by the pipeline's traffic (forward) and the MXU (backward),
+    # so the chunk only has to hide the per-program cost without
+    # leaving a long first fetch exposed: forward 12 heads a program
+    # 0.557 ms, 4 heads 0.542, 1 head 0.568; 25 heads 0.608, 5 heads
+    # 0.564, 1 head 0.590; backward flat from 1 to 6. 16 MB of blocks
+    # gives 4 / 2 heads of 12 and 5 / 1 of 25
+    lanes = -(-D // _LANES) * _LANES
+    per_head = 2 * T * (wide * lanes * itemsize + narrow * _LANES * 4)
+    return _largest_chunk(H, per_head, 16 << 20)
+
+
+def _largest_chunk(H: int, per_head_bytes: float, budget: int) -> int:
     best = 1
     for d in range(1, H + 1):
-        if H % d == 0 and d * per_head <= budget:
+        if H % d == 0 and d * per_head_bytes <= budget:
             best = d
     return best
 
@@ -435,11 +625,30 @@ def _head_chunk(H: int, T: int, live_f32_per_head: float) -> int:
 _FUSED_VMEM_LIMIT = 100 * 1024 * 1024
 
 
+def _fused_plan(T, *, causal, mask_fn, diagonal, row_tile):
+    """The row tile of the triangle walk, or None for the whole-square
+    body: the walk needs the visible region known when the program is
+    traced, which is a causal mask alone with query and key offsets
+    static and equal (``diagonal``). ``row_tile`` overrides the tile
+    derived from T (tests and timing)."""
+    if not (causal and mask_fn is None and diagonal):
+        return None
+    return _row_tile(T) if row_tile is None else row_tile
+
+
 def _fused_fwd_call(qt, kt, vt, offsets, *, causal, mask_fn, sm_scale,
-                    interpret):
+                    interpret, diagonal=False, row_tile=None):
     """[B,H,T,D] in -> (o [B,H,T,D], lse4 [B,H,T,1])."""
     B, H, T, D = qt.shape
-    Hc = _head_chunk(H, T, live_f32_per_head=2.5)
+    row_tile = _fused_plan(
+        T, causal=causal, mask_fn=mask_fn, diagonal=diagonal,
+        row_tile=row_tile,
+    )
+    _tally_site(T, row_tile)
+    if row_tile:  # q, k, v, o and lse
+        Hc = _walk_head_chunk(H, T, D, qt.dtype.itemsize, wide=4, narrow=1)
+    else:
+        Hc = _head_chunk(H, T, live_f32_per_head=2.5)
     spec = pl.BlockSpec((1, Hc, T, D), lambda b, hc: (b, hc, 0, 0))
     row_spec = pl.BlockSpec((1, Hc, T, 1), lambda b, hc: (b, hc, 0, 0))
     return pl.pallas_call(
@@ -449,6 +658,7 @@ def _fused_fwd_call(qt, kt, vt, offsets, *, causal, mask_fn, sm_scale,
             mask_fn=mask_fn,
             sm_scale=sm_scale,
             n_heads=Hc,
+            row_tile=row_tile,
         ),
         name="flash_attn_fused_fwd",
         grid=(B, H // Hc),
@@ -467,10 +677,19 @@ def _fused_fwd_call(qt, kt, vt, offsets, *, causal, mask_fn, sm_scale,
 
 
 def _fused_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
-                    mask_fn, sm_scale, interpret):
+                    mask_fn, sm_scale, interpret, diagonal=False,
+                    row_tile=None):
     """[B,H,T,D] in -> (dq, dk, dv) each [B,H,T,D] (q dtype)."""
     B, H, T, D = qt.shape
-    Hc = _head_chunk(H, T, live_f32_per_head=3.5)
+    row_tile = _fused_plan(
+        T, causal=causal, mask_fn=mask_fn, diagonal=diagonal,
+        row_tile=row_tile,
+    )
+    _tally_site(T, row_tile)
+    if row_tile:  # q, k, v, do, dq, dk, dv and lse, delta
+        Hc = _walk_head_chunk(H, T, D, qt.dtype.itemsize, wide=7, narrow=2)
+    else:
+        Hc = _head_chunk(H, T, live_f32_per_head=3.5)
     spec = pl.BlockSpec((1, Hc, T, D), lambda b, hc: (b, hc, 0, 0))
     row_spec = pl.BlockSpec((1, Hc, T, 1), lambda b, hc: (b, hc, 0, 0))
     return pl.pallas_call(
@@ -480,6 +699,7 @@ def _fused_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
             mask_fn=mask_fn,
             sm_scale=sm_scale,
             n_heads=Hc,
+            row_tile=row_tile,
         ),
         name="flash_attn_fused_bwd",
         grid=(B, H // Hc),
@@ -493,6 +713,10 @@ def _fused_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
             jax.ShapeDtypeStruct((B, H, T, D), qt.dtype),
             jax.ShapeDtypeStruct((B, H, T, D), qt.dtype),
         ],
+        # the triangle walk's float32 dk / dv of one head
+        scratch_shapes=(
+            [pltpu.VMEM((T, D), jnp.float32)] * 2 if row_tile else []
+        ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_FUSED_VMEM_LIMIT,
@@ -685,6 +909,7 @@ def _bwd_pallas(
     interpret,
     layout="bthd",
     allow_fused=True,
+    diagonal=False,
 ):
     if layout == "bhtd":
         B, H, Tq, D = q.shape
@@ -717,7 +942,7 @@ def _bwd_pallas(
         dqt, dkt, dvt = _fused_bwd_call(
             qt, kt, vt, dot, lse4, delta4, offsets,
             causal=causal, mask_fn=mask_fn, sm_scale=sm_scale,
-            interpret=interpret,
+            interpret=interpret, diagonal=diagonal,
         )
         if layout == "bhtd":
             return dqt, dkt.astype(k.dtype), dvt.astype(v.dtype)
@@ -861,6 +1086,7 @@ def _flash_pallas(
         interpret=_interpret_default(),
         layout=layout,
         allow_fused=allow_fused,
+        diagonal=_on_diagonal(*offsets),
     )
     return o
 
@@ -882,6 +1108,7 @@ def _flash_fwd_rule(
         interpret=_interpret_default(),
         layout=layout,
         allow_fused=allow_fused,
+        diagonal=_on_diagonal(*offsets),
     )
     return o, (q, k, v, o, lse)
 
@@ -907,11 +1134,24 @@ def _flash_bwd_rule(
         interpret=_interpret_default(),
         layout=layout,
         allow_fused=allow_fused,
+        diagonal=_on_diagonal(*offsets),
     )
     return dq, dk, dv
 
 
 _flash_pallas.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _on_diagonal(q_offset, k_offset) -> bool:
+    """Query and key offsets known equal while the program is traced:
+    the sequence attends to itself (or a chunk on the diagonal of a
+    chunked sequence does), so a causal mask's visible region is the
+    lower triangle. A traced offset (a ring hop) is not known."""
+    return (
+        isinstance(q_offset, int)
+        and isinstance(k_offset, int)
+        and q_offset == k_offset
+    )
 
 
 def _interpret_default() -> bool:
@@ -957,6 +1197,7 @@ def flash_attention_fwd(
         interpret=_interpret_default() if interpret is None else interpret,
         layout=layout,
         allow_fused=allow_fused,
+        diagonal=_on_diagonal(q_offset, k_offset),
     )
 
 
@@ -1087,6 +1328,7 @@ def flash_attention_bwd(
         interpret=_interpret_default() if interpret is None else interpret,
         layout=layout,
         allow_fused=allow_fused,
+        diagonal=_on_diagonal(q_offset, k_offset),
     )
 
 

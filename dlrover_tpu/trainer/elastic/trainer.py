@@ -434,7 +434,27 @@ class ElasticTrainer:
             )
             logger.info(
                 f"programs built {when}: {describe_builds(rows)}{q8}"
+                f"{self._fold_attention_tally()}"
             )
+
+    def _fold_attention_tally(self) -> str:
+        """The fused attention sites lowered so far into the stats, and
+        what was lowered since the last such line, in words."""
+        from dlrover_tpu.ops.flash_attention import fused_tally
+
+        stats = self.pipeline_stats
+        tally = fused_tally()
+        # PipelineStats.attn_<field> is the tally as the last line left it
+        new = tally - [getattr(stats, f"attn_{f}") for f in tally._fields]
+        for f, n in zip(tally._fields, tally):
+            setattr(stats, f"attn_{f}", n)
+        if not new.tri_sites + new.square_sites:
+            return ""
+        return (
+            f"; fused attention: {new.tri_sites} sites as triangle "
+            f"({new.tiles_walked} of {new.tiles_square} tiles), "
+            f"{new.square_sites} as square"
+        )
 
     def _first_build(self, what: str):
         """``build:<what>`` around the FIRST call of a jitted program

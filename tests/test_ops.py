@@ -1,6 +1,8 @@
 """Ops layer numerics: Pallas flash attention (interpret mode), kernel
 ring attention, AGD/WSAM, 8-bit AdamW."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,6 +22,8 @@ from dlrover_tpu.ops.flash_attention import (
     flash_attention_fwd,
     flash_attention_reference,
 )
+# `dlrover_tpu.ops.flash_attention` the attribute is the function
+fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
 from dlrover_tpu.ops.optimizers import apply_wsam_sharpness
 from dlrover_tpu.ops.quantized_optim import (
     _adam8_update_jnp,
@@ -140,6 +144,169 @@ class TestFlashAttention:
         out = flash_attention(q, k, v)  # auto mode: should not raise
         ref = flash_attention_reference(q, k, v)
         np.testing.assert_allclose(out, ref, atol=2e-5)
+
+    # -- the fused family's triangle walk (causal, equal static offsets)
+    @pytest.mark.parametrize("row_tile", [8, 16, 32])  # 8, 4, 2 row tiles
+    @pytest.mark.parametrize(
+        "dtype,atol_o,atol_g",
+        [(jnp.float32, 2e-5, 2e-4), (jnp.bfloat16, 2e-2, 6e-2)],
+        ids=["f32", "bf16"],
+    )
+    def test_triangle_matches_reference(
+        self, dtype, atol_o, atol_g, row_tile, monkeypatch
+    ):
+        # the internal calls, so a small T can have several row tiles;
+        # 2 heads a program under H = 6 crosses the head chunking
+        monkeypatch.setattr(fa, "_walk_head_chunk", lambda *a, **kw: 2)
+        B, H, T, D = 2, 6, 64, 32
+        rng = np.random.default_rng(row_tile)
+        q, k, v, do = (
+            jnp.asarray(rng.normal(size=(B, H, T, D)), dtype)
+            for _ in range(4)
+        )
+        kw = dict(
+            causal=True, mask_fn=None, sm_scale=D**-0.5, interpret=True,
+            diagonal=True, row_tile=row_tile,
+        )
+        off = jnp.zeros(2, jnp.int32)
+        before = fa.fused_tally()
+        o, lse4 = fa._fused_fwd_call(q, k, v, off, **kw)
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        delta4 = (f32(do) * f32(o)).sum(-1, keepdims=True)
+        grads = fa._fused_bwd_call(q, k, v, do, lse4, delta4, off, **kw)
+        n = T // row_tile
+        assert fa.fused_tally() - before == (2, 0, n * (n + 1), 2 * n * n)
+
+        def ref(q, k, v):  # the reference speaks [B, T, H, D]
+            o, lse = flash_attention_reference(
+                *(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+                return_residuals=True,
+            )
+            return o.transpose(0, 2, 1, 3), lse
+
+        o_ref, lse_ref = ref(q, k, v)
+        np.testing.assert_allclose(f32(o), f32(o_ref), atol=atol_o)
+        np.testing.assert_allclose(lse4[..., 0], lse_ref, atol=2e-5)
+        want = jax.grad(
+            lambda q, k, v: (f32(ref(q, k, v)[0]) * f32(do)).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        for got, g_ref, name in zip(grads, want, "qkv"):
+            np.testing.assert_allclose(
+                f32(got), f32(g_ref), atol=atol_g, err_msg=f"d{name}"
+            )
+
+    # which body a fused call site takes is decided by what is known
+    # when the program is traced; either way it matches the reference
+    @pytest.mark.parametrize(
+        "case,kw,tally",
+        [
+            ("in_sequence", dict(), (2, 0, 20, 32)),
+            ("equal_offsets", dict(q_offset=96, k_offset=96), (2, 0, 20, 32)),
+            ("overlap", dict(q_offset=32, k_offset=0), (0, 2, 0, 0)),
+            ("all_visible", dict(q_offset=128, k_offset=0), (0, 2, 0, 0)),
+            ("all_future", dict(q_offset=0, k_offset=128), (0, 2, 0, 0)),
+            ("mask_fn", dict(
+                mask_fn=lambda qp, kp: (qp >= kp) & (qp - kp < 48)
+            ), (0, 2, 0, 0)),
+            ("not_causal", dict(causal=False), (0, 2, 0, 0)),
+            ("streaming", dict(allow_fused=False), (0, 0, 0, 0)),
+        ],
+    )
+    def test_body_taken_and_grads(self, case, kw, tally, monkeypatch):
+        monkeypatch.setattr(fa, "_TRI_ROW_TILE", 32)  # T = 128: 4 tiles
+        q, k, v = _qkv(T=128)
+
+        def lp(q, k, v):
+            return (
+                flash_attention(q, k, v, force="pallas", **kw) ** 2
+            ).sum()
+
+        kw_ref = {k_: v_ for k_, v_ in kw.items() if k_ != "allow_fused"}
+
+        def lr(q, k, v):
+            return (flash_attention_reference(q, k, v, **kw_ref) ** 2).sum()
+
+        before = fa.fused_tally()
+        gp = jax.grad(lp, argnums=(0, 1, 2))(q, k, v)
+        assert fa.fused_tally() - before == tally
+        gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gp, gr):
+            np.testing.assert_allclose(a, b, atol=5e-4)
+        np.testing.assert_allclose(
+            flash_attention(q, k, v, force="pallas", **kw),
+            flash_attention_reference(q, k, v, **kw_ref), atol=2e-5,
+        )
+
+    @pytest.mark.parametrize("offsets", [(0, 0), (128, 128), (128, 0)])
+    def test_traced_offsets_take_the_square(self, offsets, monkeypatch):
+        # a ring hop: the offsets are values of the program, so even
+        # equal ones are not known equal when it is traced
+        monkeypatch.setattr(fa, "_TRI_ROW_TILE", 32)
+        q, k, v = _qkv(T=128)
+
+        @jax.jit
+        def hop(q, k, v, q_off, k_off):
+            o, lse = flash_attention_fwd(
+                q, k, v, causal=True, q_offset=q_off, k_offset=k_off,
+                interpret=True,
+            )
+            grads = flash_attention_bwd(
+                q, k, v, o, lse, jnp.ones_like(o), causal=True,
+                q_offset=q_off, k_offset=k_off, interpret=True,
+            )
+            return o, lse, grads
+
+        before = fa.fused_tally()
+        o, lse, grads = hop(q, k, v, *(jnp.int32(n) for n in offsets))
+        assert fa.fused_tally() - before == (0, 2, 0, 0)
+        q_off, k_off = offsets
+        o_ref, lse_ref = flash_attention_reference(
+            q, k, v, q_offset=q_off, k_offset=k_off, return_residuals=True
+        )
+        np.testing.assert_allclose(o, o_ref, atol=2e-5)
+        np.testing.assert_allclose(lse, lse_ref, atol=2e-5)
+        want = jax.grad(
+            lambda q, k, v: flash_attention_reference(
+                q, k, v, q_offset=q_off, k_offset=k_off
+            ).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        for a, b in zip(grads, want):
+            np.testing.assert_allclose(a, b, atol=5e-4)
+        # the same offsets as Python ints are known: equal ones walk
+        before = fa.fused_tally()
+        o_static, _ = flash_attention_fwd(
+            q, k, v, causal=True, q_offset=q_off, k_offset=k_off,
+            interpret=True,
+        )
+        walked = (1, 0, 10, 16) if q_off == k_off else (0, 1, 0, 0)
+        assert fa.fused_tally() - before == walked
+        np.testing.assert_allclose(o_static, o_ref, atol=2e-5)
+
+    def test_t520_takes_the_square(self):
+        # fused-eligible, and no row tile divides it
+        q, k, v = _qkv(B=1, T=520, H=2, Hkv=2)
+        assert fa._row_tile(520) is None and fa._row_tile(1024)
+        before = fa.fused_tally()
+        # the call the public entry makes on the chip for such a T
+        # (no block size tiles it, and the fused family needs none)
+        gp = jax.grad(
+            lambda q, k, v: (
+                fa._flash_pallas(
+                    q, k, v, (0, 0), True, None, 32**-0.5, 8, 8, "bthd",
+                    True,
+                ) ** 2
+            ).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        assert fa.fused_tally() - before == (0, 2, 0, 0)
+        gr = jax.grad(
+            lambda q, k, v: (flash_attention_reference(q, k, v) ** 2).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        for a, b in zip(gp, gr):
+            np.testing.assert_allclose(a, b, atol=5e-4)
 
 
 class TestFusedShortSeq:

@@ -129,9 +129,11 @@ def test_rebuild_drops_the_executable(accel):
     assert stats.compile_cache_hits == 1
 
 
-# the parent's keys (PR 28), less the one counter PR 29 deleted with its code
+# the parent's keys (PR 28), less the one counter PR 29 deleted with its
+# code, and the fused attention tally's four (PR 36)
 AS_DICT_KEYS = [
-    "comm_overlap_pct", "compile_cache_hit_pct", "compile_cache_hits",
+    "attn_square_sites", "attn_tiles_square", "attn_tiles_walked",
+    "attn_tri_sites", "comm_overlap_pct", "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "donated_bytes", "donated_steps",
     "grad_bytes_raw", "grad_bytes_wire", "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",
@@ -188,3 +190,44 @@ def test_as_dict_has_the_parents_keys(key):
             "reshard_bytes_device_vs_host": [8, 2],
             "grad_sync_explicit": 0,
         }[key]
+
+
+def test_attention_tally_folds_into_the_stats_and_the_builds_line(
+    monkeypatch,
+):
+    """The fused attention tally (``ops/flash_attention.FusedTally``) is
+    cumulative in the process; the trainer's stats hold it as it stood
+    at the last ``programs built ...`` line, and the line says what was
+    lowered since the line before."""
+    import importlib
+    import types
+
+    from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer
+
+    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+    stats = PipelineStats()
+    trainer = types.SimpleNamespace(pipeline_stats=stats)
+    fold = lambda: ElasticTrainer._fold_attention_tally(trainer)  # noqa: E731
+
+    monkeypatch.setattr(fa, "_tally", fa.FusedTally())
+    assert fold() == ""  # nothing lowered: a model outside the family
+    # a step program of twelve layers at T = 1024, forward and backward
+    for _ in range(24):
+        fa._tally_site(1024, 256)
+    assert fold() == (
+        "; fused attention: 24 sites as triangle (240 of 384 tiles), "
+        "0 as square"
+    )
+    assert fold() == ""  # nothing new since that line
+    # its twin, and one ring hop (traced offsets: the square body)
+    for _ in range(24):
+        fa._tally_site(1024, 256)
+    fa._tally_site(1024, None)
+    assert fold() == (
+        "; fused attention: 24 sites as triangle (240 of 384 tiles), "
+        "1 as square"
+    )
+    assert (
+        stats.attn_tri_sites, stats.attn_square_sites,
+        stats.attn_tiles_walked, stats.attn_tiles_square,
+    ) == (48, 1, 480, 768) == fa.fused_tally()

@@ -75,6 +75,9 @@ ATTENTION_SHAPES = {
     "gpt2_124m": (8, 12, 1024, 64),
     # 25 heads against _head_chunk
     "gpt2_xl": (4, 25, 1024, 64),
+    # the benchmark's XL cell: the triangle walk, 5 heads a program
+    # forward and 1 backward (_walk_head_chunk)
+    "gpt2_xl_d12_cell": (8, 25, 1024, 64),
     # streaming path, 512-blocks
     "llama2_7b": (1, 32, 4096, 128),
 }
@@ -95,6 +98,7 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
             q, k, v, causal=True, force="pallas", layout="bhtd"
         )
 
+    before = fa.fused_tally()
     if direction == "fwd":
         compiled = _compile_for_chip(attend, *qkv)
     else:
@@ -122,6 +126,17 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
     text = compiled.as_text()
     for kernel in want:
         assert kernel in text, kernel
+    # causal, in sequence, T = 1024: every fused site is lowered as the
+    # triangle walk (4 row tiles: 10 of 16 score tiles a site), under
+    # _FUSED_VMEM_LIMIT since it compiled; the streaming shape has none
+    sites = len(want) if fused else 0
+    assert fa.fused_tally() - before == (sites, 0, 10 * sites, 16 * sites)
+    if fused:
+        H, T, D = shape[1:]
+        assert (
+            fa._walk_head_chunk(H, T, D, 2, wide=4, narrow=1),
+            fa._walk_head_chunk(H, T, D, 2, wide=7, narrow=2),
+        ) == {12: (4, 2), 25: (5, 1)}[H]
 
 
 # the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here

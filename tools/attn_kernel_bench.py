@@ -1,0 +1,110 @@
+"""Time the causal attention kernels alone on the chip.
+
+``LAYERS`` chained calls (a layer's output is the next one's query) in
+one jitted program, forward and forward + backward, host clock around
+``block_until_ready``: milliseconds a call. Variants of one shape:
+
+- ``fused``: what ``flash_attention`` picks (T <= 1024, H = H_kv: the
+  fused family; causal with equal static offsets: its triangle walk);
+- ``square``: the fused family's whole-square body (the walk switched
+  off), which is what every fused call ran before the walk;
+- ``streaming``: ``allow_fused=False``, the block-tiled kernels;
+- ``tri:<bq>[:<heads fwd>:<heads bwd>]``: the walk at another row tile
+  and other heads a program.
+
+    python tools/attn_kernel_bench.py 16x12x1024x64 fused square streaming tri:128
+"""
+import importlib
+import json
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+
+# the package re-exports the function under the module's name
+fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+
+# what the module picks, put back before each variant
+ROW_TILE = fa._TRI_ROW_TILE
+HEAD_CHUNK = fa._walk_head_chunk
+LAYERS = 12
+REPEATS = 10
+ROUNDS = 5
+
+
+def _steer(variant):
+    """Module constants of the variant; returns ``allow_fused``."""
+    fa._TRI_ROW_TILE = ROW_TILE
+    fa._walk_head_chunk = HEAD_CHUNK
+    kind, *rest = variant.split(":")
+    if kind == "square":
+        fa._TRI_ROW_TILE = 1 << 30  # divides no T: the square body
+    elif kind == "tri":
+        fa._TRI_ROW_TILE = int(rest[0])
+        if len(rest) == 3:  # by the wide blocks a head: 4 forward, 7 back
+            heads = {4: int(rest[1]), 7: int(rest[2])}
+            fa._walk_head_chunk = (
+                lambda H, T, D, itemsize, wide, narrow: heads[wide]
+            )
+    elif kind not in ("fused", "streaming"):
+        raise SystemExit(f"unknown variant {variant!r}")
+    return kind != "streaming"
+
+
+def _time(fn, *args):
+    """The least of ``ROUNDS`` rounds of ``REPEATS`` calls, a call."""
+    jax.block_until_ready(fn(*args))  # compiles
+    rounds = []
+    for _ in range(ROUNDS):
+        t0 = time.perf_counter()
+        for _ in range(REPEATS):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        rounds.append((time.perf_counter() - t0) / REPEATS / LAYERS * 1e3)
+    return min(rounds)
+
+
+def bench(shape, variant):
+    allow_fused = _steer(variant)
+
+    def chain(q, k, v):
+        for _ in range(LAYERS):
+            q = fa.flash_attention(
+                q, k, v, causal=True, layout="bhtd",
+                allow_fused=allow_fused,
+            )
+        return q
+
+    def loss(q, k, v):
+        return chain(q, k, v).astype(jnp.float32).sum()
+
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q, k, v = (jax.random.normal(key, shape, jnp.bfloat16) for key in keys)
+    before = fa.fused_tally()
+    t0 = time.perf_counter()
+    fwd = _time(jax.jit(chain), q, k, v)
+    both = _time(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, k, v)
+    return {
+        "shape": list(shape), "variant": variant,
+        "fwd_ms": round(fwd, 4), "fwd_bwd_ms": round(both, 4),
+        "tally": list(fa.fused_tally() - before),
+        "wall_s": round(time.perf_counter() - t0, 1),
+    }
+
+
+def main(argv):
+    assert jax.default_backend() == "tpu", "this timing needs the chip"
+    shape = tuple(int(n) for n in argv[0].split("x"))
+    for variant in argv[1:] or ["fused", "square", "streaming"]:
+        try:
+            print(json.dumps(bench(shape, variant)), flush=True)
+        except Exception as e:  # a variant the compiler refuses
+            print(json.dumps({
+                "shape": list(shape), "variant": variant,
+                "error": str(e)[:400],
+            }), flush=True)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])

@@ -107,6 +107,9 @@ class PipelineStats:
     moe_reports: int = 0
     moe_drop_rate_sum: float = _reported_to(6)
     moe_max_load_sum: float = _reported_to(6)
+    # ... and their summed share of the assignments that fell on the
+    # experts this chip holds (1.0 a report where it holds them all)
+    moe_held_share_sum: float = _reported_to(6)
     # elements of the optimizer's int8 moments (ops/quantized_optim.py
     # ``Quantized8``, both moments) by where their blocks lie: in the
     # leaf's own tile order, which the update reads as a bitcast, or in

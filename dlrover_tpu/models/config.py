@@ -10,7 +10,11 @@ optional MoE blocks).
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Optional
+from typing import Optional, Tuple
+
+# layer_pattern's alphabet (nemotron_h's) -> the key of the layer's one
+# mixer in the parameter tree: Mamba-2, attention, experts
+LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe"}
 
 
 @dataclass(frozen=True)
@@ -20,10 +24,22 @@ class TransformerConfig:
     model_dim: int = 768
     num_heads: int = 12
     num_kv_heads: Optional[int] = None  # None => MHA
+    # width of one attention head where the source states it;
+    # None => model_dim // num_heads
+    attn_head_dim: Optional[int] = None
     mlp_dim: Optional[int] = None  # None => 4*model_dim (gpt) / swiglu dim
     max_seq_len: int = 1024
+    # the kind of every layer, one character a layer, in the alphabet of
+    # the ``nemotron_h`` configs (``hybrid_override_pattern``): "M" a
+    # Mamba-2 layer, "*" an attention layer, "E" an expert layer; each
+    # layer is ONE mixer, ``x + mixer(norm(x))``. "" = every layer is the
+    # attention + FFN block (``moe_every`` places the experts).
+    layer_pattern: str = ""
     # architecture switches
     rope: bool = False  # False => learned positional embeddings
+    # "" => what ``rope`` says; "none" => no positions anywhere (the
+    # attention layers of a Mamba-2 hybrid: the scan carries the order)
+    positions: str = ""
     rope_theta: float = 10000.0
     rmsnorm: bool = False
     swiglu: bool = False
@@ -58,6 +74,40 @@ class TransformerConfig:
     # renormalise the k chosen experts' gate values to sum to one
     # (GShard); False keeps their softmax probabilities (OLMoE)
     norm_topk_prob: bool = True
+    # how the router scores: "softmax" over the logits, or "sigmoid" of
+    # each logit with a selection bias a expert (chosen by score + bias,
+    # weighted by the score alone; parallel/moe.route)
+    router: str = "softmax"
+    # the routed experts' summed output is multiplied by this
+    routed_scale: float = 1.0
+    # step of the auxiliary-loss-free balance rule that moves the
+    # selection bias after every train step; 0 leaves it where it is
+    router_bias_rate: float = 0.0
+    # weight of the load-balance loss; None => ``loss_fn``'s argument
+    router_balance_weight: Optional[float] = None
+    # width of the one shared expert every token passes beside its
+    # routed ones; 0 = none
+    shared_expert_dim: int = 0
+    # "" => SwiGLU where ``swiglu``, else the GELU pair; "relu2" =>
+    # the ungated pair with relu(x)^2 (expert and shared-expert FFNs)
+    mlp_activation: str = ""
+    # a chip's share of the experts: this many of ``num_experts``, from
+    # ``experts_offset`` on, are held (and computed) here; the router
+    # still scores all of them. 0 => all
+    experts_held: int = 0
+    experts_offset: int = 0
+    # Mamba-2 layers ("M"): heads x head width = the inner width; B and
+    # C are shared by the heads of one of ``ssm_groups`` groups
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # the step size's range at init (``time_step_min/max/floor``)
+    ssm_dt_min: float = 1e-3
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
     # sequence-parallel attention scheme when the mesh has sp > 1:
     # "ring" (P2P pipeline, any head count) or "ulysses" (two
     # all-to-alls; needs (heads/tp) % sp == 0) — parallel/{ring_
@@ -83,6 +133,40 @@ class TransformerConfig:
     int8_mlp: bool = False
 
     def __post_init__(self):
+        if self.layer_pattern:
+            if set(self.layer_pattern) - set(LAYER_KINDS):
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r}: kinds are "
+                    f"{sorted(LAYER_KINDS)}"
+                )
+            if len(self.layer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} names "
+                    f"{len(self.layer_pattern)} layers, num_layers is "
+                    f"{self.num_layers}"
+                )
+            if self.scan_layers:
+                raise ValueError(
+                    "scan_layers needs homogeneous blocks; a "
+                    "layer_pattern makes them differ"
+                )
+        if self.positions not in ("", "none"):
+            raise ValueError(f"unknown positions {self.positions!r}")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if self.mlp_activation not in ("", "relu2"):
+            raise ValueError(
+                f"unknown mlp_activation {self.mlp_activation!r}"
+            )
+        if self.experts_held and not (
+            0 <= self.experts_offset
+            and self.experts_offset + self.experts_held <= self.num_experts
+        ):
+            raise ValueError(
+                f"experts held [{self.experts_offset}, "
+                f"{self.experts_offset + self.experts_held}) are not "
+                f"among the {self.num_experts} routed over"
+            )
         if self.scan_layers and self.num_experts:
             raise ValueError(
                 "scan_layers needs homogeneous blocks; MoE interleave "
@@ -96,7 +180,21 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.model_dim // self.num_heads
+        return self.attn_head_dim or self.model_dim // self.num_heads
+
+    @property
+    def position_kind(self) -> str:
+        """"rope", "learned" or "none"."""
+        return self.positions or ("rope" if self.rope else "learned")
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(offset, count) of the experts this chip holds."""
+        return self.experts_offset, self.experts_held or self.num_experts
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
 
     @property
     def ffn_dim(self) -> int:
@@ -140,6 +238,8 @@ def is_moe_layer(cfg: TransformerConfig, i: int) -> bool:
     Every consumer (init/forward layout, metric normalization, the
     dry-runner's all-to-all pricing, the analytic profiler) routes
     through here so the rule cannot drift between them."""
+    if cfg.layer_pattern:
+        return cfg.layer_pattern[i] == "E"
     return bool(
         cfg.num_experts and i % cfg.moe_every == cfg.moe_every - 1
     )

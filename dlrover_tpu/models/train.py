@@ -796,6 +796,13 @@ def build_train_step(
             new_opt = offload_tree(new_opt, opt_sh)
         with jax.named_scope("scope/optimizer"):
             new_params = optax.apply_updates(state.params, updates)
+        if "layer_load" in aux and cfg.router == "sigmoid":
+            from dlrover_tpu.parallel.moe import move_router_bias
+
+            new_params = move_router_bias(
+                new_params, state.params, aux["layer_load"],
+                cfg.router_bias_rate,
+            )
         metrics = {"loss": loss, "grad_norm": gnorm}
         if dev_norms is not None:
             # SDC tier-1 fence input: each lane's LOCAL pre-sync grad

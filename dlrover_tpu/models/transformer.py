@@ -25,12 +25,23 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dlrover_tpu.models.config import TransformerConfig, is_moe_layer
+from dlrover_tpu.models.config import (
+    LAYER_KINDS,
+    TransformerConfig,
+    is_moe_layer,
+    num_moe_layers,
+)
+from dlrover_tpu.ops.mamba2 import (
+    init_mamba2_params,
+    mamba2_logical_axes,
+    mamba2_mixer,
+)
 from dlrover_tpu.parallel.moe import (
     MoEParams,
     init_moe_params,
     moe_layer,
     moe_layer_local,
+    relu2,
 )
 from dlrover_tpu.parallel.ring_attention import ring_self_attention
 
@@ -66,23 +77,49 @@ def init_params(key, cfg: TransformerConfig) -> Params:
     }
     if not cfg.rmsnorm:
         params["final_norm"]["bias"] = jnp.zeros((d,), pd)
-    if not cfg.rope:
+    if cfg.position_kind == "learned":
         params["embed"]["positions"] = dense(
             next(keys), (cfg.max_seq_len, d), d
         )
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(keys), (d, cfg.vocab_size), d)
 
-    for i in range(cfg.num_layers):
+    def attention():
+        return {
+            "wq": dense(next(keys), (d, h, hd), d),
+            "wk": dense(next(keys), (d, kvh, hd), d),
+            "wv": dense(next(keys), (d, kvh, hd), d),
+            "wo": dense(next(keys), (h, hd, d), h * hd),
+        }
+
+    def experts():
+        return init_moe_params(
+            next(keys), cfg.num_experts, d, f, dtype=pd,
+            gated=cfg.swiglu, held=cfg.experts_held,
+            selection_bias=cfg.router == "sigmoid",
+            shared_dim=cfg.shared_expert_dim,
+        )
+
+    mixers = {
+        "M": lambda: init_mamba2_params(next(keys), cfg, pd),
+        "*": attention,
+        "E": experts,
+    }
+    for kind in cfg.layer_pattern:
+        # one mixer a layer behind one norm
+        layer = {"norm": {"scale": jnp.ones((d,), pd)}}
+        if not cfg.rmsnorm:
+            layer["norm"]["bias"] = jnp.zeros((d,), pd)
+        layer[LAYER_KINDS[kind]] = mixers[kind]()
+        params["layers"].append(layer)
+
+    # without a pattern every layer is the attention + FFN block
+    blocks = 0 if cfg.layer_pattern else cfg.num_layers
+    for i in range(blocks):
         layer = {
             "attn_norm": {"scale": jnp.ones((d,), pd)},
             "mlp_norm": {"scale": jnp.ones((d,), pd)},
-            "attn": {
-                "wq": dense(next(keys), (d, h, hd), d),
-                "wk": dense(next(keys), (d, kvh, hd), d),
-                "wv": dense(next(keys), (d, kvh, hd), d),
-                "wo": dense(next(keys), (h, hd, d), h * hd),
-            },
+            "attn": attention(),
         }
         if not cfg.rmsnorm:
             layer["attn_norm"]["bias"] = jnp.zeros((d,), pd)
@@ -91,10 +128,7 @@ def init_params(key, cfg: TransformerConfig) -> Params:
             layer["q_norm"] = {"scale": jnp.ones((h, hd), pd)}
             layer["k_norm"] = {"scale": jnp.ones((kvh, hd), pd)}
         if is_moe_layer(cfg, i):
-            layer["moe"] = init_moe_params(
-                next(keys), cfg.num_experts, d, f, dtype=pd,
-                gated=cfg.swiglu,
-            )
+            layer["moe"] = experts()
         elif cfg.swiglu:
             layer["mlp"] = {
                 "w_gate": dense(next(keys), (d, f), d),
@@ -138,20 +172,46 @@ def logical_axes(cfg: TransformerConfig) -> Params:
     }
     if not cfg.rmsnorm:
         axes["final_norm"]["bias"] = ("norm",)
-    if not cfg.rope:
+    if cfg.position_kind == "learned":
         axes["embed"]["positions"] = (None, "embed")
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
-    for i in range(cfg.num_layers):
+
+    def attention():
+        return {
+            "wq": ("embed", "heads", "head_dim"),
+            "wk": ("embed", "kv_heads", "head_dim"),
+            "wv": ("embed", "kv_heads", "head_dim"),
+            "wo": ("heads", "head_dim", "embed"),
+        }
+
+    def experts():
+        up = ("experts", None, "expert_mlp")
+        shared = cfg.shared_expert_dim
+        return MoEParams(
+            gate=(None, None),
+            w_up=up,
+            w_down=("experts", "expert_mlp", None),
+            w_gate=up if cfg.swiglu else None,
+            bias=(None,) if cfg.router == "sigmoid" else None,
+            shared_up=("embed", "mlp") if shared else None,
+            shared_down=("mlp", "embed") if shared else None,
+        )
+
+    mixers = {"M": mamba2_logical_axes, "*": attention, "E": experts}
+    for kind in cfg.layer_pattern:
+        layer = {"norm": {"scale": ("norm",)}}
+        if not cfg.rmsnorm:
+            layer["norm"]["bias"] = ("norm",)
+        layer[LAYER_KINDS[kind]] = mixers[kind]()
+        axes["layers"].append(layer)
+
+    blocks = 0 if cfg.layer_pattern else cfg.num_layers
+    for i in range(blocks):
         layer = {
             "attn_norm": {"scale": ("norm",)},
             "mlp_norm": {"scale": ("norm",)},
-            "attn": {
-                "wq": ("embed", "heads", "head_dim"),
-                "wk": ("embed", "kv_heads", "head_dim"),
-                "wv": ("embed", "kv_heads", "head_dim"),
-                "wo": ("heads", "head_dim", "embed"),
-            },
+            "attn": attention(),
         }
         if not cfg.rmsnorm:
             layer["attn_norm"]["bias"] = ("norm",)
@@ -160,13 +220,7 @@ def logical_axes(cfg: TransformerConfig) -> Params:
             layer["q_norm"] = {"scale": ("heads", "head_dim")}
             layer["k_norm"] = {"scale": ("kv_heads", "head_dim")}
         if is_moe_layer(cfg, i):
-            up = ("experts", None, "expert_mlp")
-            layer["moe"] = MoEParams(
-                gate=(None, None),
-                w_up=up,
-                w_down=("experts", "expert_mlp", None),
-                w_gate=up if cfg.swiglu else None,
-            )
+            layer["moe"] = experts()
         elif cfg.swiglu:
             layer["mlp"] = {
                 "w_gate": ("embed", "mlp"),
@@ -320,8 +374,11 @@ def _causal_attention(q, k, v, mesh=None, layout: str = "bthd"):
 
 
 @jax.named_scope("scope/layer/attn")
-def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions):
-    h = _norm(x, layer["attn_norm"], cfg)
+def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
+                     norm: str = "attn_norm"):
+    """``x + attention(norm(x))``; ``norm`` names the layer's norm (a
+    one-mixer layer of a ``layer_pattern`` has the one, "norm")."""
+    h = _norm(x, layer[norm], cfg)
     sp = mesh is not None and mesh.shape.get("sp", 1) > 1
     # single-shard path: kernel-native [B,H,T,D] straight from the
     # projection einsums — no relayout transposes around the attention
@@ -334,7 +391,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions):
     if cfg.qk_norm:
         q = _qk_norm(q, layer["q_norm"], cfg, layout)
         k = _qk_norm(k, layer["k_norm"], cfg, layout)
-    if cfg.rope:
+    if cfg.position_kind == "rope":
         q = _rope(q, positions, cfg.rope_theta, layout)
         k = _rope(k, positions, cfg.rope_theta, layout)
     if cfg.mup_attn_scale is not None:
@@ -360,6 +417,12 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions):
     return x + jnp.einsum(out, o, layer["attn"]["wo"].astype(o.dtype))
 
 
+@jax.named_scope("scope/layer/ssm")
+def _ssm_block(x, layer, cfg: TransformerConfig):
+    h = _norm(x, layer["norm"], cfg)
+    return x + mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg))
+
+
 def _zero_aux(cfg: Optional[TransformerConfig] = None):
     """Aux-loss tree congruent with what MoE layers emit. With a MoE
     config the tree also carries the per-expert routing load vector
@@ -370,19 +433,31 @@ def _zero_aux(cfg: Optional[TransformerConfig] = None):
     if cfg is not None and cfg.num_experts:
         aux["load"] = jnp.zeros((cfg.num_experts,), jnp.float32)
         aux["drop"] = jnp.float32(0.0)
+        if cfg.layer_pattern:
+            # each sparse layer's own load, for the rule that moves its
+            # selection bias (parallel/moe.move_router_bias)
+            aux["layer_load"] = jnp.zeros(
+                (num_moe_layers(cfg), cfg.num_experts), jnp.float32
+            )
     return aux
 
 
 @jax.named_scope("scope/layer/mlp")
-def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None):
-    h = _norm(x, layer["mlp_norm"], cfg)
+def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None,
+               norm: str = "mlp_norm"):
+    h = _norm(x, layer[norm], cfg)
     if "moe" in layer:
         kw = dict(
             capacity_factor=cfg.capacity_factor,
             top_k=cfg.moe_top_k,
             expert_caps=cfg.capacity_splits or None,
             normalize=cfg.norm_topk_prob,
+            router=cfg.router,
+            routed_scale=cfg.routed_scale,
+            held=cfg.held_experts,
         )
+        if cfg.mlp_activation == "relu2":
+            kw["activation"] = relu2
         if mesh is not None and mesh.size > 1:
             out, aux = moe_layer(layer["moe"], h, mesh, **kw)
         else:
@@ -466,7 +541,7 @@ def embed_tokens(
     dt = _dtype(cfg)
     T = tokens.shape[-1]
     x = _embed_lookup(params["embed"]["tokens"].astype(dt), tokens, mesh)
-    if not cfg.rope:
+    if cfg.position_kind == "learned":
         x = x + params["embed"]["positions"].astype(dt)[:T][None]
     return x
 
@@ -545,9 +620,30 @@ def forward(
         x, aux = _mlp_block(x, layer, cfg, mesh, moe_axis=moe_axis)
         return x, aux
 
+    def mixer_layer(x, layer, kind):
+        """One layer of a ``layer_pattern``: ``x + mixer(norm(x))``."""
+        if kind == "M":
+            return _ssm_block(x, layer, cfg), None
+        if kind == "*":
+            x = _attention_block(x, layer, cfg, mesh, positions, "norm")
+            return x, None
+        return _mlp_block(x, layer, cfg, mesh, moe_axis, "norm")
+
     if cfg.remat:
         block = jax.checkpoint(block)
-    if cfg.scan_layers:
+        mixer_layer = jax.checkpoint(mixer_layer, static_argnums=(2,))
+    if cfg.layer_pattern:
+        loads = []
+        for kind, layer in zip(cfg.layer_pattern, params["layers"]):
+            x, aux = mixer_layer(x, layer, kind)
+            if aux is not None:
+                loads.append(aux["load"])
+                aux_total = dict(
+                    aux_total, **{k: aux_total[k] + aux[k] for k in aux}
+                )
+        if loads:
+            aux_total["layer_load"] = jnp.stack(loads)
+    elif cfg.scan_layers:
         # one scanned block: the traced/compiled graph is O(1) in depth
         # — 48-layer remat compiles where the unrolled graph cannot
         def sbody(carry, layer):
@@ -583,6 +679,8 @@ def loss_fn(
     ``moe_aux_weight``, router z at ``cfg.router_z_weight``).
     ``return_aux=True`` → (loss, aux dict) for metric surfacing."""
     logits, aux = forward(params, tokens, cfg, mesh, moe_axis=moe_axis)
+    if cfg.router_balance_weight is not None:
+        moe_aux_weight = cfg.router_balance_weight
     loss = (
         token_nll(logits, targets, row_weights=row_weights)
         + moe_aux_weight * aux["balance"]
@@ -599,6 +697,10 @@ def loss_fn(
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Per-layer K/V buffers [L, B, S, kv_heads, head_dim]. Static shape:
     the whole decode loop stays inside one compiled ``lax.scan``."""
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            "cached decoding knows the attention + FFN block only"
+        )
     dt = _dtype(cfg)
     shape = (cfg.num_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -623,7 +725,7 @@ def _cached_decode_layer(
     if cfg.qk_norm:
         q = _qk_norm(q, layer["q_norm"], cfg)
         k = _qk_norm(k, layer["k_norm"], cfg)
-    if cfg.rope:
+    if cfg.position_kind == "rope":
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
     if cfg.mup_attn_scale is not None:
@@ -700,7 +802,7 @@ def forward_step(
     x = params["embed"]["tokens"].astype(dt)[tokens]
     positions = cur_len + jnp.arange(t)[None, :]  # [1, t] broadcasts to B
     positions = jnp.broadcast_to(positions, (B, t))
-    if not cfg.rope:
+    if cfg.position_kind == "learned":
         pos_emb = lax.dynamic_slice_in_dim(
             params["embed"]["positions"].astype(dt), cur_len, t
         )
@@ -748,7 +850,7 @@ def forward_step_ragged(
 
     x = params["embed"]["tokens"].astype(dt)[tokens][:, None]  # [S,1,D]
     positions = cur_lens[:, None]  # [S, 1]
-    if not cfg.rope:
+    if cfg.position_kind == "learned":
         x = x + params["embed"]["positions"].astype(dt)[cur_lens][:, None]
 
     key_pos = jnp.arange(T)[None, None, :]  # [1, 1, T]

@@ -34,41 +34,78 @@ from jax import lax
 class MoEParams(NamedTuple):
     """Per-host expert weights: [E_local, ...]. Gate is replicated.
     ``w_gate`` is there for gated (SwiGLU) experts and None for the
-    activation pair ``w_up`` / ``w_down``."""
+    activation pair ``w_up`` / ``w_down``. ``bias`` is the sigmoid
+    router's selection bias, one an expert (no gradient: the train step
+    moves it, ``move_router_bias``); ``shared_up`` / ``shared_down`` the
+    one expert every token passes beside its routed ones. On a chip that
+    holds a share of the experts ``E_local`` is the number held."""
 
     gate: jnp.ndarray  # [model, E_global]
     w_up: jnp.ndarray  # [E_local, model, hidden]
     w_down: jnp.ndarray  # [E_local, hidden, model]
     w_gate: Optional[jnp.ndarray] = None  # [E_local, model, hidden]
+    bias: Optional[jnp.ndarray] = None  # [E_global]
+    shared_up: Optional[jnp.ndarray] = None  # [model, shared]
+    shared_down: Optional[jnp.ndarray] = None  # [shared, model]
 
 
 def init_moe_params(
     key, num_experts: int, model_dim: int, hidden_dim: int,
-    dtype=jnp.float32, gated: bool = False,
+    dtype=jnp.float32, gated: bool = False, held: int = 0,
+    selection_bias: bool = False, shared_dim: int = 0,
 ) -> MoEParams:
+    """``held`` (0 = all): how many of the ``num_experts`` the router
+    scores have their matrices here."""
     kg, ku, kd, kw = jax.random.split(key, 4)
     scale = model_dim**-0.5
+    e_local = held or num_experts
 
     def up(k):
         return jax.random.normal(
-            k, (num_experts, model_dim, hidden_dim), dtype
+            k, (e_local, model_dim, hidden_dim), dtype
         ) * scale
 
+    shared = {}
+    if shared_dim:
+        ks, kt = jax.random.split(jax.random.fold_in(key, 1))
+        shared = dict(
+            shared_up=jax.random.normal(
+                ks, (model_dim, shared_dim), dtype
+            ) * scale,
+            shared_down=jax.random.normal(
+                kt, (shared_dim, model_dim), dtype
+            ) * (shared_dim**-0.5),
+        )
     return MoEParams(
         gate=jax.random.normal(kg, (model_dim, num_experts), dtype) * scale,
         w_up=up(ku),
         w_down=jax.random.normal(
-            kd, (num_experts, hidden_dim, model_dim), dtype
+            kd, (e_local, hidden_dim, model_dim), dtype
         )
         * (hidden_dim**-0.5),
         w_gate=up(kw) if gated else None,
+        bias=jnp.zeros((num_experts,), dtype) if selection_bias else None,
+        **shared,
     )
 
 
-def route(logits: jnp.ndarray, k: int, normalize: bool):
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def route(logits: jnp.ndarray, k: int, normalize: bool, *,
+          kind: str = "softmax", bias=None, scale: float = 1.0):
     """THE routing decision, in float32: each token's ``k`` best
     experts by softmax probability, their gate values, and the two
     auxiliary losses. Shared by the one-device and the ``ep`` path.
+
+    ``kind="sigmoid"``: every expert is scored by the sigmoid of its own
+    logit; the ``k`` with the largest ``score + bias`` are chosen, and
+    their gate values are the scores WITHOUT the bias (so the bias
+    steers the load and never the output: it takes no gradient), over
+    their sum where ``normalize``. The balance loss is then over the
+    scores normalised to sum to one a token, and there is no z-loss.
+    ``scale`` multiplies the gate values of either kind.
 
     Returns ``(idx [T,k] int32, gates [T,k] f32, aux)``:
     - gates: the softmax probabilities of the chosen experts,
@@ -84,13 +121,29 @@ def route(logits: jnp.ndarray, k: int, normalize: bool):
     """
     T, num_experts = logits.shape
     logits = logits.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    vals, idx = lax.top_k(probs, k)
-    gates = (
-        vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-9)
-        if normalize and k > 1
-        else vals
-    )
+    if kind == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choose = scores
+        if bias is not None:
+            choose = scores + lax.stop_gradient(bias.astype(jnp.float32))
+        _, idx = lax.top_k(choose, k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+        gates = (
+            vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+            if normalize
+            else vals
+        )
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        vals, idx = lax.top_k(probs, k)
+        gates = (
+            vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-9)
+            if normalize and k > 1
+            else vals
+        )
+    if scale != 1.0:
+        gates = gates * scale
     counts = jnp.sum(
         idx[..., None] == jnp.arange(num_experts, dtype=idx.dtype),
         axis=(0, 1),
@@ -99,7 +152,9 @@ def route(logits: jnp.ndarray, k: int, normalize: bool):
     load = counts.astype(jnp.float32) / float(k * T)
     aux = {
         "balance": num_experts * jnp.sum(load * jnp.mean(probs, axis=0)),
-        "z": jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2),
+        "z": jnp.float32(0.0) if kind == "sigmoid" else jnp.mean(
+            jax.scipy.special.logsumexp(logits, axis=-1) ** 2
+        ),
         "load": load,
         "counts": counts,
     }
@@ -126,6 +181,7 @@ def topk_gating(
     normalize: bool = True,
     expert_caps: Optional[jnp.ndarray] = None,
     return_stats: bool = False,
+    routing: Optional[dict] = None,
 ):
     """``route`` packed into capacity buckets, for the ``ep`` path
     (parity: switch_gating.py:154's top-k path / GShard top-2): rank-0
@@ -145,7 +201,7 @@ def topk_gating(
     feeds on.
     """
     T = logits.shape[0]
-    idx, gates, aux = route(logits, k, normalize)
+    idx, gates, aux = route(logits, k, normalize, **(routing or {}))
     onehots = jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)  # [T,k,E]
 
     # capacity accounting rank-major: all rank-0 rows first, then rank-1
@@ -211,6 +267,16 @@ def _expert_ffn(params: MoEParams, matmul, x, activation):
     return matmul(h, params.w_down.astype(dt))
 
 
+def _add_shared_expert(out, params: MoEParams, x, activation):
+    """``out`` plus the shared expert's output for every token, where
+    the parameters have one."""
+    if params.shared_up is None:
+        return out
+    with jax.named_scope("scope/layer/moe/shared"):
+        h = activation(x @ params.shared_up.astype(x.dtype))
+        return out + h @ params.shared_down.astype(x.dtype)
+
+
 def _moe_dropless(params: MoEParams, x, idx, gates, counts, activation):
     """Every expert local: sort the k*T assignments by expert, gather
     the tokens in that order, run each projection as one grouped matmul
@@ -238,6 +304,122 @@ def _moe_dropless(params: MoEParams, x, idx, gates, counts, activation):
     return out.astype(x.dtype)
 
 
+def share_rows(assignments: int, count: int, num_experts: int) -> int:
+    """Rows of one round of ``_moe_share``: twice what the ``count`` held
+    experts get of ``assignments`` when the routing is balanced, in whole
+    512s, and at most all of them."""
+    rows = -(-2 * assignments * count // num_experts // 512) * 512
+    return min(rows, -(-assignments // 8) * 8)
+
+
+def _moe_share(params: MoEParams, x, idx, gates, counts, activation, held):
+    """A chip's share of the experts, ``held = (offset, count)``: the
+    router scored all ``E`` experts, the weights are those of ``count``
+    of them, and this computes their part of every token's output. What
+    the chips that hold the others would add is left out; however uneven
+    the routing, every assignment to a held expert is computed.
+
+    The k*T assignments are sorted with the held experts' first (key
+    ``(expert - offset) mod E``). Only those rows are ever gathered: they
+    are taken ``share_rows`` at a time, in as many rounds as they need
+    (one, unless the held experts draw more than twice their balanced
+    share), each round a gather of its tokens, the grouped matmuls over
+    the held groups as they fall into the round, and a scatter-add of
+    the gated rows onto their tokens. Before this, the whole k*T-row buffer was gathered,
+    masked and permuted home with 6 % of its rows real, a quarter of the
+    step (PERF.md, Findings PR 37).
+
+    Rows of a round past the last held row belong to no matmul, and what
+    ``lax.ragged_dot`` leaves there is undefined on the TPU (zeros at one
+    shape, NaN at another), in its result and in the cotangent it hands
+    back: every buffer of a round is zeroed there, which zeroes the
+    cotangents too, so nothing undefined reaches a token or a weight."""
+    offset, count = held
+    T, model = x.shape
+    k = idx.shape[1]
+    num_experts = counts.shape[0]
+    R = share_rows(T * k, count, num_experts)
+
+    def one_round(x, experts, flat_gates, order, starts, ends, lo):
+        """[T, model] float32: what the held rows lo .. lo + R of the
+        sorted assignments add to their tokens."""
+        n_held = ends[-1]
+        with jax.named_scope("scope/layer/moe/dispatch"):
+            mine = lax.dynamic_slice(order, (lo,), (R,))
+            real = (lo + jnp.arange(R) < n_held)[:, None]
+            token = mine // k
+            xs = jnp.where(real, x[token], 0)
+            sizes = jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R)
+        with jax.named_scope("scope/layer/moe/experts"):
+            ys = _expert_ffn(
+                MoEParams(None, *experts),
+                lambda a, w: jnp.where(real, lax.ragged_dot(a, w, sizes), 0),
+                xs,
+                activation,
+            )
+        with jax.named_scope("scope/layer/moe/combine"):
+            weight = jnp.where(real, flat_gates[mine][:, None], 0.0)
+            return jnp.zeros((T, model), jnp.float32).at[token].add(
+                ys.astype(jnp.float32) * weight
+            )
+
+    def over_rounds(round_fn, acc, ends):
+        """``acc`` after as many rounds as the held rows need."""
+        return lax.fori_loop(
+            0, (ends[-1] + R - 1) // R,
+            lambda i, acc: round_fn(acc, i * R), acc,
+        )
+
+    # Differentiated by hand: the rounds are a loop of as many trips as
+    # there are held rows to take (which ``jax.grad`` cannot reverse), a
+    # round is made again in the backward pass, and its cotangents are
+    # ADDED into one accumulator each. ``jax.grad`` of a scan over all
+    # possible rounds kept every round's residuals, the expert matrices
+    # among them, and added into the accumulators in skipped rounds too:
+    # 16.5 GiB of temporaries and 125 ms a step (PERF.md, PR 37).
+    @jax.custom_vjp
+    def rounds(x, experts, flat_gates, order, starts, ends):
+        return over_rounds(
+            lambda out, lo: out + one_round(
+                x, experts, flat_gates, order, starts, ends, lo
+            ),
+            jnp.zeros((T, model), jnp.float32), ends,
+        )
+
+    def rounds_fwd(x, experts, flat_gates, order, starts, ends):
+        res = (x, experts, flat_gates, order, starts, ends)
+        return rounds(*res), res
+
+    def rounds_bwd(res, d_out):
+        x, experts, flat_gates, order, starts, ends = res
+
+        def pull(acc, lo):
+            _, vjp = jax.vjp(
+                lambda *diff: one_round(*diff, order, starts, ends, lo),
+                x, experts, flat_gates,
+            )
+            return jax.tree_util.tree_map(jnp.add, acc, vjp(d_out))
+
+        zeros = jax.tree_util.tree_map(
+            jnp.zeros_like, (x, experts, flat_gates)
+        )
+        return (*over_rounds(pull, zeros, ends), None, None, None)
+
+    rounds.defvjp(rounds_fwd, rounds_bwd)
+
+    with jax.named_scope("scope/layer/moe/dispatch"):
+        key = ((idx - offset) % num_experts).reshape(T * k)
+        order = jnp.argsort(key, stable=True)  # assignment j is token j // k
+        order = jnp.pad(order, (0, -(-T * k // R) * R - T * k))
+        ends = jnp.cumsum(counts[offset:offset + count])
+        starts = ends - counts[offset:offset + count]
+    out = rounds(
+        x, (params.w_up, params.w_down, params.w_gate),
+        gates.reshape(T * k), order, starts, ends,
+    )
+    return out.astype(x.dtype)
+
+
 def moe_layer_local(
     params: MoEParams,
     x: jnp.ndarray,
@@ -248,6 +430,9 @@ def moe_layer_local(
     top_k: int = 1,
     expert_caps: Optional[Tuple[int, ...]] = None,
     normalize: bool = True,
+    router: str = "softmax",
+    routed_scale: float = 1.0,
+    held: Optional[Tuple[int, int]] = None,
 ):
     """Per-device MoE FFN body (call inside ``shard_map``).
 
@@ -262,11 +447,24 @@ def moe_layer_local(
     ``max(expert_caps)`` and expert e keeps only its first
     ``expert_caps[e]`` assignments (hot experts stop overflowing,
     cold ones ship padding in the all-to-all).
+
+    ``router`` and ``routed_scale`` are ``route``'s ``kind`` and
+    ``scale``. ``held = (offset, count)``, on one device only: the
+    weights are ``count`` of the experts the gate scores (``_moe_share``). The shared expert, where the parameters have
+    one, is added to every token's output.
     """
     ep = 1 if axis_name is None else lax.psum(1, axis_name)
     e_local = params.w_up.shape[0]
-    e_global = e_local * ep
+    e_global = params.gate.shape[1]
+    if held is not None and held[1] == e_global:
+        held = None
+    if held is not None and ep > 1:
+        raise ValueError(
+            "a chip's share of the experts is a one-device layout; over "
+            "an ep axis the axis is the share"
+        )
     T, model = x.shape
+    routing = dict(kind=router, bias=params.bias, scale=routed_scale)
 
     with jax.named_scope("scope/layer/moe/route"):
         logits = jnp.dot(
@@ -275,12 +473,17 @@ def moe_layer_local(
             precision=lax.Precision.HIGHEST,
         )  # [T, E_global]
         if ep == 1:
-            idx, gates, aux = route(logits, top_k, normalize)
+            idx, gates, aux = route(logits, top_k, normalize, **routing)
     if ep == 1:
         counts = aux.pop("counts")
-        out = _moe_dropless(params, x, idx, gates, counts, activation)
+        if held is None:
+            out = _moe_dropless(params, x, idx, gates, counts, activation)
+        else:
+            out = _moe_share(
+                params, x, idx, gates, counts, activation, held
+            )
         aux["drop"] = jnp.float32(0.0)
-        return out, aux
+        return _add_shared_expert(out, params, x, activation), aux
 
     # top-k routes k slots per token; capacity scales with k so the
     # same capacity_factor keeps the same drop rate
@@ -299,7 +502,7 @@ def moe_layer_local(
     with jax.named_scope("scope/layer/moe/route"):
         dispatch, combine, balance, z, stats = topk_gating(
             logits, e_global, capacity, k=top_k, normalize=normalize,
-            expert_caps=caps_arr, return_stats=True,
+            expert_caps=caps_arr, return_stats=True, routing=routing,
         )
     aux = {
         "balance": balance,
@@ -344,7 +547,7 @@ def moe_layer_local(
         out = jnp.einsum(
             "tec,ecm->tm", combine, expert_out.astype(jnp.float32)
         )
-    return out.astype(x.dtype), aux
+    return _add_shared_expert(out.astype(x.dtype), params, x, activation), aux
 
 
 def moe_layer(params: MoEParams, x, mesh, **kw):
@@ -355,9 +558,14 @@ def moe_layer(params: MoEParams, x, mesh, **kw):
 
     xspec = P(("dp", "fsdp"), "sp", None)
     expert = P("ep", None, None)
+    def whole(a):
+        return None if a is None else P(*([None] * a.ndim))
+
     pspec = MoEParams(
         gate=P(None, None), w_up=expert, w_down=expert,
         w_gate=None if params.w_gate is None else expert,
+        bias=whole(params.bias), shared_up=whole(params.shared_up),
+        shared_down=whole(params.shared_down),
     )
 
     def body(p, xb):
@@ -383,11 +591,14 @@ def moe_layer(params: MoEParams, x, mesh, **kw):
     )(params, x)
 
 
-def fold_routing_report(metrics, stats) -> None:
+def fold_routing_report(metrics, stats, held=None) -> None:
     """Fold a reported step's own ``moe_drop_rate`` / ``moe_expert_load``
-    into ``PipelineStats.moe_*``; nothing for a dense model. Copies to
-    the host of a step already waited for: an op on the device here
-    would queue behind the step in flight."""
+    into ``PipelineStats.moe_*``; nothing for a dense model. ``held =
+    (offset, count)`` names the experts this chip holds (None: all): the
+    share of the assignments that fell on them goes to
+    ``moe_held_share_sum``. Copies to the host of a step already waited
+    for: an op on the device here would queue behind the step in
+    flight."""
     if "moe_drop_rate" not in metrics:
         return
     import numpy as np
@@ -397,6 +608,33 @@ def fold_routing_report(metrics, stats) -> None:
     stats.moe_reports += 1
     stats.moe_drop_rate_sum += float(drop)
     stats.moe_max_load_sum += float(load.max()) * load.size
+    offset, count = held or (0, load.size)
+    stats.moe_held_share_sum += float(load[offset:offset + count].sum())
+
+
+def move_router_bias(params, before, layer_loads, rate: float):
+    """The auxiliary-loss-free balance rule, after a train step: every
+    expert layer's selection bias moves by ``rate`` towards the experts
+    that got less than the mean share of this step's assignments,
+    ``b_i += rate * sign(mean(load) - load_i)``, from its value
+    ``before`` the optimizer's update, which is thrown away (the bias
+    has no gradient and must take no weight decay). ``params`` and
+    ``before`` are the model's tree after and before the update,
+    ``layer_loads`` [sparse layers, E] in layer order. Layers without a
+    bias stay as they are."""
+    layers, i = [], 0
+    for layer, old in zip(params["layers"], before["layers"]):
+        if "moe" in layer:
+            if layer["moe"].bias is not None:
+                load = layer_loads[i]
+                step = rate * jnp.sign(jnp.mean(load) - load)
+                bias = old["moe"].bias
+                layer = dict(layer, moe=layer["moe"]._replace(
+                    bias=bias + step.astype(bias.dtype)
+                ))
+            i += 1
+        layers.append(layer)
+    return dict(params, layers=layers)
 
 
 # -- capacity rebalancing (ISSUE 13) ----------------------------------------

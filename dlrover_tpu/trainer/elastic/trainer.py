@@ -2100,7 +2100,9 @@ class ElasticTrainer:
         with span("host_sync"):
             loss = float(metrics["loss"])
             # of a MoE model, its drop rate and per-expert load
-            fold_routing_report(metrics, self.pipeline_stats)
+            fold_routing_report(
+                metrics, self.pipeline_stats, self.cfg.held_experts
+            )
         with span("report"):
             scalars = {"loss": loss}
             lr = self._lr_value(lr_parts)

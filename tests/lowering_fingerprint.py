@@ -1,0 +1,67 @@
+"""What a benchmark configuration's parameter tree and train step look
+like to the compiler, as two sha256: the tree's paths, shapes and dtypes,
+and the text the step lowers to (its own optimizer, one device, a batch of
+1 x 1024). ``tests/data/step_lowering.json`` holds what the commit before
+ISSUE 37 gave for the three configurations the benchmark had then, made by
+running this file there:
+
+    JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
+
+``test_nemotron_h.py`` holds the tree to it: a change to the model code
+that is meant to leave those configurations alone leaves both alone. A PR
+that means to change their step records the file anew and says so.
+"""
+
+import hashlib
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+NAMES = ("gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2")
+
+
+def fingerprint(name: str) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models.config import TransformerConfig
+    from dlrover_tpu.models.train import TrainState, build_train_step
+    from dlrover_tpu.models.transformer import init_params
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+    from dlrover_tpu.trainer.elastic.optimizer import build_optimizer
+
+    with open(
+        os.path.join(ROOT, "benchmark", "configs", f"{name}.json")
+    ) as f:
+        config = json.load(f)
+    cfg = TransformerConfig(**config["model"])
+    opt = dict(config["optimizer"])
+    tx = build_optimizer(opt.pop("name"), **opt)
+    mesh = build_mesh(MeshConfig(), jax.devices()[:1])
+
+    def state():
+        params = init_params(jax.random.PRNGKey(0), cfg)
+        return TrainState(
+            step=jnp.zeros((), jnp.int32), params=params,
+            opt_state=tx.init(params),
+        )
+
+    abstract = jax.eval_shape(state)
+    tree = "\n".join(
+        f"{jax.tree_util.keystr(path)} {leaf.shape} {leaf.dtype}"
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+            abstract.params
+        )
+    )
+    x = jax.ShapeDtypeStruct((1, 1024), jnp.int32)
+    text = build_train_step(cfg, mesh, tx).lower(abstract, x, x).as_text()
+    return {
+        "tree": hashlib.sha256(tree.encode()).hexdigest(),
+        "step": hashlib.sha256(text.encode()).hexdigest(),
+    }
+
+
+if __name__ == "__main__":
+    json.dump({n: fingerprint(n) for n in NAMES}, sys.stdout, indent=1)
+    print()

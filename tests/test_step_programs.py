@@ -130,7 +130,8 @@ def test_rebuild_drops_the_executable(accel):
 
 
 # the parent's keys (PR 28), less the one counter PR 29 deleted with its
-# code, and the fused attention tally's four (PR 36)
+# code, the fused attention tally's four (PR 36) and the held experts'
+# share of the routing (PR 37)
 AS_DICT_KEYS = [
     "attn_square_sites", "attn_tiles_square", "attn_tiles_walked",
     "attn_tri_sites", "comm_overlap_pct", "compile_cache_hit_pct", "compile_cache_hits",
@@ -138,7 +139,7 @@ AS_DICT_KEYS = [
     "grad_bytes_raw", "grad_bytes_wire", "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",
     "grad_sync_ms", "grad_sync_path", "moe_drop_rate_sum",
-    "moe_max_load_sum", "moe_reports", "opt_q8_blocks_elems",
+    "moe_held_share_sum", "moe_max_load_sum", "moe_reports", "opt_q8_blocks_elems",
     "opt_q8_tiles_elems", "overlap_pct_measured", "prefetch_hits",
     "prefetch_misses", "prefetch_overlap_pct", "prefetch_reprimes",
     "prefetch_wait_s", "reshard_bytes_device",
@@ -153,7 +154,8 @@ AS_DICT_KEYS = [
 # a float is reported to the places it had when each key was written out
 ROUNDED = {
     "prefetch_wait_s": 4, "stage_block_s": 4, "resize_downtime_ms": 2,
-    "moe_drop_rate_sum": 6, "moe_max_load_sum": 6, "grad_sync_ms": 3,
+    "moe_drop_rate_sum": 6, "moe_held_share_sum": 6,
+    "moe_max_load_sum": 6, "grad_sync_ms": 3,
     "grad_sync_ici_ms": 3, "grad_sync_dcn_ms": 3,
     "restore_storage_verify_s": 4, "restore_agree_s": 4,
     "restore_lock_wait_s": 4, "restore_shm_verify_s": 4,

@@ -128,6 +128,16 @@ class PipelineStats:
     attn_square_sites: int = 0
     attn_tiles_walked: int = 0
     attn_tiles_square: int = 0
+    # ... and the streaming kernels (``StreamTally``: longer T or GQA; a
+    # forward is one site, a backward one in one pass or two split), by
+    # grid: the triangle path, which takes a grid step, a fetch and a
+    # mask only where a causal query can see, or the whole rectangle;
+    # and the blocks a head of the triangle sites walks against the
+    # blocks of its rectangle
+    attn_stream_tri_sites: int = 0
+    attn_stream_rect_sites: int = 0
+    attn_stream_blocks_walked: int = 0
+    attn_stream_blocks_rect: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was

@@ -44,6 +44,19 @@ Design:
   scoped VMEM (``_head_chunk``); the walk loops over its heads and is
   sized by its pipeline's blocks (``_walk_head_chunk``).
   ``fused_tally()`` counts the call sites lowered by body.
+- **The streaming kernels' triangle path** (longer T, or GQA): a call
+  that is causal alone with static equal offsets, square blocks and one
+  sequence length knows its visible blocks when it is traced, so its
+  grid is ONE axis over the n(n+1)/2 blocks at or under the diagonal
+  (the step -> block tables ride as scalar prefetch: no grid step and
+  no fetch above the diagonal), only the block on the diagonal is
+  masked, the softmax scale rides in the exponent's argument and on the
+  float32 accumulators, and the backward runs in one pass (scores, p, dp
+  and ds once; a head's float32 dq resident in VMEM) while that fits,
+  split into the dq and the dk / dv kernel beyond. A caller that states
+  no block gets 1024 x 1024 there (measured), 512 on the rectangular
+  grid, which every other call keeps as it was. ``stream_tally()``
+  counts the kernels lowered by grid.
 - ``layout="bhtd"`` lets callers hand over kernel-native [B, H, T, D]
   tensors (the model emits them straight from its QKV einsums), skipping
   the 25 MB-per-tensor relayout transposes on every call.
@@ -60,6 +73,7 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -196,15 +210,28 @@ def _fwd_pallas(
     nq, nk = Tq // block_q, Tk // block_k
     group = H // Hkv
 
-    if allow_fused and _fused_eligible(qt.shape, kt.shape, "bhtd"):
-        ot, lse4 = _fused_fwd_call(
-            qt, kt, vt, offsets,
-            causal=causal, mask_fn=mask_fn, sm_scale=sm_scale,
-            interpret=interpret, diagonal=diagonal,
-        )
+    def in_layout(ot, lse4):
         if layout == "bhtd":
             return ot, lse4[..., 0]
         return ot.transpose(0, 2, 1, 3), lse4[..., 0]
+
+    if allow_fused and _fused_eligible(qt.shape, kt.shape, "bhtd"):
+        return in_layout(*_fused_fwd_call(
+            qt, kt, vt, offsets,
+            causal=causal, mask_fn=mask_fn, sm_scale=sm_scale,
+            interpret=interpret, diagonal=diagonal,
+        ))
+
+    n_tri = _stream_plan(
+        Tq, Tk, block_q, block_k,
+        causal=causal, mask_fn=mask_fn, diagonal=diagonal,
+    )
+    _tally_stream_site(n_tri)
+    if n_tri:
+        return in_layout(*_tri_fwd_call(
+            qt, kt, vt, sm_scale=sm_scale, block=block_q,
+            interpret=interpret,
+        ))
 
     kernel = functools.partial(
         _fwd_kernel,
@@ -254,9 +281,7 @@ def _fwd_pallas(
         ),
         interpret=interpret,
     )(offsets, qt, kt, vt)
-    if layout == "bhtd":
-        return ot, lse4[..., 0]
-    return ot.transpose(0, 2, 1, 3), lse4[..., 0]
+    return in_layout(ot, lse4)
 
 
 # ---------------------------------------------------------------------------
@@ -322,6 +347,17 @@ def _for_each_head(n_heads: int, head):
     lax.fori_loop(0, n_heads, body, 0)
 
 
+def _tally_minus(self, other):
+    return type(self)(*(a - b for a, b in zip(self, other)))
+
+
+def _site_counts(n: int):
+    """What one site of ``n`` tiles a side adds to a tally: a triangle
+    site and the tiles it walks of its whole, or with ``n`` 0 a site
+    that takes the whole and walks no triangle."""
+    return (n > 0, n == 0, n * (n + 1) // 2, n * n)
+
+
 class FusedTally(NamedTuple):
     """Fused call sites lowered so far in this process, forward and
     backward each counted, by the body they took, and the ``row_tile``
@@ -335,8 +371,7 @@ class FusedTally(NamedTuple):
     tiles_walked: int = 0
     tiles_square: int = 0
 
-    def __sub__(self, other):
-        return FusedTally(*(a - b for a, b in zip(self, other)))
+    __sub__ = _tally_minus
 
 
 _tally = FusedTally()
@@ -349,9 +384,7 @@ def fused_tally() -> FusedTally:
 def _tally_site(T: int, row_tile: Optional[int]):
     global _tally
     n = T // row_tile if row_tile else 0  # a square site walks no tiles
-    _tally = FusedTally(*(a + b for a, b in zip(
-        _tally, (n > 0, n == 0, n * (n + 1) // 2, n * n)
-    )))
+    _tally = FusedTally(*(a + b for a, b in zip(_tally, _site_counts(n))))
 
 
 def _fused_fwd_kernel(
@@ -631,7 +664,7 @@ def _fused_plan(T, *, causal, mask_fn, diagonal, row_tile):
     traced, which is a causal mask alone with query and key offsets
     static and equal (``diagonal``). ``row_tile`` overrides the tile
     derived from T (tests and timing)."""
-    if not (causal and mask_fn is None and diagonal):
+    if not _sees_triangle(causal, mask_fn, diagonal):
         return None
     return _row_tile(T) if row_tile is None else row_tile
 
@@ -892,6 +925,380 @@ def _bwd_dkv_kernel(
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+# ---------------------------------------------------------------------------
+# the streaming kernels' triangle path: a causal call with no mask_fn,
+# query and key offsets static and equal and block_q == block_k knows
+# its visible blocks when the program is traced
+# ---------------------------------------------------------------------------
+# The (query block, key block) axes of the rectangular grid become ONE
+# axis over the n(n+1)/2 blocks at or under the diagonal, with the step
+# -> (i, j) tables as scalar prefetch: no grid step and no fetch for a
+# block above the diagonal. Only the block on the diagonal is masked;
+# every row sees its own position, so no row is empty and the
+# rectangular bodies' guards against that (m_safe, row_valid) have
+# nothing to guard. The softmax scale rides in the exponent's argument
+# (the running maximum is of the raw scores: the scale is positive) and,
+# for ds, on the float32 accumulators at finalize; operands stay as they
+# come, p and ds are cast for the MXU where the rectangular bodies cast.
+#
+# blocks a side past which the two int32 tables (8,256 steps each)
+# would crowd SMEM, T = 131072 in blocks of 1024: the rectangular grid
+# takes over
+_TRI_MAX_BLOCKS = 128
+
+
+class StreamTally(NamedTuple):
+    """Streaming kernels lowered so far in this process (a forward is
+    one, a backward one in one pass and two split), by the grid they
+    took, and the blocks a head of the triangle kernels walks against
+    the blocks of its whole rectangle. Counted when a program is
+    traced, as ``FusedTally``."""
+
+    tri_sites: int = 0
+    rect_sites: int = 0
+    blocks_walked: int = 0
+    blocks_rect: int = 0
+
+    __sub__ = _tally_minus
+
+
+_stream_tally = StreamTally()
+
+
+def stream_tally() -> StreamTally:
+    return _stream_tally
+
+
+def _tally_stream_site(n: Optional[int], kernels: int = 1):
+    """``kernels`` kernels of ``n`` blocks a side on the triangle path,
+    or of the rectangular grid (``n`` None), which walks no triangle."""
+    global _stream_tally
+    _stream_tally = StreamTally(*(a + kernels * b for a, b in zip(
+        _stream_tally, _site_counts(n or 0)
+    )))
+
+
+def _stream_plan(Tq, Tk, block_q, block_k, *, causal, mask_fn, diagonal):
+    """Blocks a side of the triangle path, or None for the rectangular
+    grid: the same test ``_fused_plan`` makes for the fused family, and
+    square blocks over one sequence so that the diagonal is a block's."""
+    if not _sees_triangle(causal, mask_fn, diagonal):
+        return None
+    if block_q != block_k or Tq != Tk or Tq // block_q > _TRI_MAX_BLOCKS:
+        return None
+    return Tq // block_q
+
+
+def _triangle_steps(n: int, by_key: bool):
+    """step -> (query block i, key block j) over the blocks with
+    j <= i, in the order a kernel accumulates: a query block's keys
+    ``j = 0..i`` (forward, dq), or with ``by_key`` a key block's
+    queries ``i = j..n-1`` (dk / dv)."""
+    if by_key:
+        kj, qi = np.triu_indices(n)
+    else:
+        qi, kj = np.tril_indices(n)
+    return jnp.asarray(qi, jnp.int32), jnp.asarray(kj, jnp.int32)
+
+
+def _tri_scores(q_ref, k_ref, on_diagonal: bool):
+    """Raw scores ``q k^T`` of one block, float32; the block on the
+    diagonal masked ``row >= col``, any other block whole."""
+    s = jax.lax.dot_general(
+        q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return _mask_diagonal_tile(s, s.shape[0]) if on_diagonal else s
+
+
+def _tri_fwd_kernel(
+    qi_ref,  # SMEM [steps]: the step's query block
+    kj_ref,  # SMEM [steps]: the step's key block
+    q_ref,  # VMEM [1, 1, b, D]
+    k_ref,
+    v_ref,
+    o_ref,  # VMEM [1, 1, b, D]
+    lse_ref,  # VMEM [1, 1, b, 1]
+    acc_ref,  # scratch [b, D] f32
+    m_ref,  # scratch [b, _LANES] f32: running maximum of RAW scores
+    l_ref,  # scratch [b, _LANES] f32
+    *,
+    sm_scale: float,
+):
+    step = pl.program_id(2)
+    i, j = qi_ref[step], kj_ref[step]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def _block(on_diagonal):
+        s = _tri_scores(q_ref, k_ref, on_diagonal)
+        m_prev = m_ref[:, :1]  # [b, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # a row's first block: exp(scale * (NEG_INF - m_new)) = 0
+        alpha = jnp.exp((m_prev - m_new) * sm_scale)
+        p = jnp.exp((s - m_new) * sm_scale)
+        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    pl.when(j < i)(functools.partial(_block, False))
+
+    @pl.when(j == i)
+    def _last():  # the row's block on the diagonal
+        _block(True)
+        l = l_ref[:, :1]
+        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[:, :1] * sm_scale + jnp.log(l)
+
+
+def _tri_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
+              sm_scale, on_diagonal):
+    """(p, ds / sm_scale) of one block, float32: what both backward
+    kernels recompute. ds is scaled where it has been summed."""
+    s = _tri_scores(q_ref, k_ref, on_diagonal)
+    # lse is finite; a masked score gives exp(NEG_INF - lse) = 0
+    p = jnp.exp(s * sm_scale - lse_ref[0, 0, :, :1])
+    dp = jax.lax.dot_general(
+        do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta_ref[0, 0, :, :1])
+
+
+def _tri_bwd_dq_kernel(
+    qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dq_ref,  # out [1, 1, b, D]
+    dq_acc,  # scratch [b, D] f32
+    *,
+    sm_scale: float,
+):
+    step = pl.program_id(2)
+    i, j = qi_ref[step], kj_ref[step]
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def _block(on_diagonal):
+        _, ds = _tri_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            sm_scale=sm_scale, on_diagonal=on_diagonal,
+        )
+        k = k_ref[0, 0]
+        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    pl.when(j < i)(functools.partial(_block, False))
+
+    @pl.when(j == i)
+    def _last():
+        _block(True)
+        dq_ref[0, 0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+
+
+def _tri_bwd_kernel(
+    qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    *refs,
+    sm_scale: float,
+    n_blocks: int,
+):
+    """dk / dv over the triangle, key block by key block (outputs
+    ``dk, dv`` ``[1, 1, b, D]`` per q-head, summed over groups outside;
+    scratch ``dk_acc, dv_acc`` ``[b, D]`` f32). Led by a ``dq`` output
+    ``[1, 1, T, D]`` and a ``dq_acc`` scratch ``[T, D]`` f32 it is the
+    backward in ONE pass: scores, p, dp and ds computed once (five
+    matmuls and one exponential pass a block where the split kernels
+    run seven and two), dq summed in the float32 block that stays in
+    VMEM while the head is swept and written at its last step."""
+    if len(refs) == 6:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    else:
+        (dk_ref, dv_ref, dk_acc, dv_acc), dq_ref = refs, None
+    step = pl.program_id(2)
+    i, j = qi_ref[step], kj_ref[step]
+    block = q_ref.shape[2]
+
+    if dq_ref is not None:
+
+        @pl.when(step == 0)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def _block(on_diagonal):
+        p, ds = _tri_p_ds(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            sm_scale=sm_scale, on_diagonal=on_diagonal,
+        )
+        q, do = q_ref[0, 0], do_ref[0, 0]
+        ds_lo = ds.astype(q.dtype)
+        dv = jax.lax.dot_general(  # p^T @ do
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk = jax.lax.dot_general(  # ds^T @ q
+            ds_lo, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if dq_ref is not None:
+            rows = pl.ds(pl.multiple_of(i * block, block), block)
+            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+                ds_lo, k_ref[0, 0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        if on_diagonal:  # the key block's first step: nothing to add to
+            dv_acc[:] = dv
+            dk_acc[:] = dk
+        else:
+            dv_acc[:] = dv_acc[:] + dv
+            dk_acc[:] = dk_acc[:] + dk
+
+    pl.when(i == j)(functools.partial(_block, True))
+    pl.when(i > j)(functools.partial(_block, False))
+
+    @pl.when(i == n_blocks - 1)
+    def _finalize():
+        dk_ref[0, 0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+    if dq_ref is not None:
+
+        @pl.when(step == pl.num_programs(2) - 1)
+        def _finalize_head():
+            dq_ref[0, 0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+# the one-pass backward holds a head's float32 dq and both buffers of
+# its dq block in VMEM; past this many bytes of them the split kernels
+# run (T = 32768 at D = 128 in bf16 is the last that fits)
+_ONE_PASS_MAX_BYTES = 32 << 20
+
+
+def _one_pass_fits(T: int, D: int, itemsize: int) -> bool:
+    lanes = -(-D // _LANES) * _LANES
+    return T * lanes * (4 + 2 * itemsize) <= _ONE_PASS_MAX_BYTES
+
+
+def _tri_call(kernel, name, steps, ins, in_specs, out_specs, out_shape,
+              scratch, *, interpret, vmem_limit=None):
+    """One kernel over the triangle grid ``(B, H, steps)``, the two
+    step -> block tables as scalar prefetch."""
+    B, H = ins[0].shape[:2]
+    return pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H, steps[0].shape[0]),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit,
+        ),
+        interpret=interpret,
+    )(*steps, *ins)
+
+
+def _tri_specs(block: int, D: int, group: int):
+    """Block specs of the triangle grid: blocks of query rows and of
+    key rows, each by its table."""
+    rows = lambda b, h, s, qi, kj: (b, h, qi[s], 0)  # noqa: E731
+    return (
+        pl.BlockSpec((1, 1, block, D), rows),
+        pl.BlockSpec(
+            (1, 1, block, D),
+            lambda b, h, s, qi, kj: (b, h // group, kj[s], 0),
+        ),
+        # minor dim 1 == full array dim: a legal tile (see _fwd_pallas)
+        pl.BlockSpec((1, 1, block, 1), rows),
+        # dk / dv come out per QUERY head
+        pl.BlockSpec(
+            (1, 1, block, D), lambda b, h, s, qi, kj: (b, h, kj[s], 0)
+        ),
+    )
+
+
+def _tri_fwd_call(qt, kt, vt, *, sm_scale, block, interpret):
+    """[B,H,T,D] in -> (o [B,H,T,D], lse4 [B,H,T,1])."""
+    B, H, T, D = qt.shape
+    q_spec, kv_spec, row_spec, _ = _tri_specs(block, D, H // kt.shape[1])
+    return _tri_call(
+        functools.partial(_tri_fwd_kernel, sm_scale=sm_scale),
+        "flash_attn_fwd",
+        _triangle_steps(T // block, by_key=False),
+        (qt, kt, vt),
+        [q_spec, kv_spec, kv_spec],
+        [q_spec, row_spec],
+        [
+            jax.ShapeDtypeStruct((B, H, T, D), qt.dtype),
+            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
+        ],
+        [
+            pltpu.VMEM((block, D), jnp.float32),
+            pltpu.VMEM((block, _LANES), jnp.float32),
+            pltpu.VMEM((block, _LANES), jnp.float32),
+        ],
+        interpret=interpret,
+    )
+
+
+def _tri_bwd_call(qt, kt, vt, dot, lse4, delta4, *, sm_scale, block,
+                  interpret):
+    """[B,H,T,D] in -> (dq in q's dtype, dk, dv float32 per QUERY
+    head), as ``_rect_bwd_call``."""
+    B, H, T, D = qt.shape
+    n = T // block
+    q_spec, kv_spec, row_spec, kv_out_spec = _tri_specs(
+        block, D, H // kt.shape[1]
+    )
+    ins = (qt, kt, vt, dot, lse4, delta4)
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    dq_shape = jax.ShapeDtypeStruct((B, H, T, D), qt.dtype)
+    dkv_shape = jax.ShapeDtypeStruct((B, H, T, D), jnp.float32)
+    acc = pltpu.VMEM((block, D), jnp.float32)
+    by_key = _triangle_steps(n, by_key=True)
+    dkv_kernel = functools.partial(
+        _tri_bwd_kernel, sm_scale=sm_scale, n_blocks=n
+    )
+    if _one_pass_fits(T, D, qt.dtype.itemsize):
+        _tally_stream_site(n)
+        whole_head = pl.BlockSpec(
+            (1, 1, T, D), lambda b, h, s, qi, kj: (b, h, 0, 0)
+        )
+        return _tri_call(
+            dkv_kernel, "flash_attn_bwd", by_key, ins, in_specs,
+            [whole_head, kv_out_spec, kv_out_spec],
+            [dq_shape, dkv_shape, dkv_shape],
+            [pltpu.VMEM((T, D), jnp.float32), acc, acc],
+            interpret=interpret, vmem_limit=_FUSED_VMEM_LIMIT,
+        )
+    _tally_stream_site(n, kernels=2)
+    dqt = _tri_call(
+        functools.partial(_tri_bwd_dq_kernel, sm_scale=sm_scale),
+        "flash_attn_bwd_dq", _triangle_steps(n, by_key=False), ins,
+        in_specs, q_spec, dq_shape, [acc], interpret=interpret,
+    )
+    dk_full, dv_full = _tri_call(
+        dkv_kernel, "flash_attn_bwd_dkv", by_key, ins, in_specs,
+        [kv_out_spec, kv_out_spec], [dkv_shape, dkv_shape], [acc, acc],
+        interpret=interpret,
+    )
+    return dqt, dk_full, dv_full
+
+
 def _bwd_pallas(
     q,
     k,
@@ -933,7 +1340,6 @@ def _bwd_pallas(
             do.astype(jnp.float32),
             o.astype(jnp.float32),
         )
-    nq, nk = Tq // block_q, Tk // block_k
     group = H // Hkv
     delta4 = delta[..., None]  # [B,H,Tq,1]
     lse4 = lse[..., None]
@@ -952,6 +1358,48 @@ def _bwd_pallas(
             dvt.transpose(0, 2, 1, 3).astype(v.dtype),
         )
 
+    n_tri = _stream_plan(
+        Tq, Tk, block_q, block_k,
+        causal=causal, mask_fn=mask_fn, diagonal=diagonal,
+    )
+    if n_tri:
+        dqt, dk_full, dv_full = _tri_bwd_call(
+            qt, kt, vt, dot, lse4, delta4, sm_scale=sm_scale,
+            block=block_q, interpret=interpret,
+        )
+    else:
+        dqt, dk_full, dv_full = _rect_bwd_call(
+            qt, kt, vt, dot, lse4, delta4, offsets, causal=causal,
+            mask_fn=mask_fn, sm_scale=sm_scale, block_q=block_q,
+            block_k=block_k, interpret=interpret,
+        )
+    if layout == "bhtd":
+        if group > 1:
+            dk = dk_full.reshape(B, Hkv, group, Tk, D).sum(2)
+            dv = dv_full.reshape(B, Hkv, group, Tk, D).sum(2)
+        else:
+            dk, dv = dk_full, dv_full
+        return dqt, dk.astype(k.dtype), dv.astype(v.dtype)
+    dq = dqt.transpose(0, 2, 1, 3)
+    dk_t = dk_full.transpose(0, 2, 1, 3)  # [B,Tk,H,D]
+    dv_t = dv_full.transpose(0, 2, 1, 3)
+    if group > 1:
+        dk = dk_t.reshape(B, Tk, Hkv, group, D).sum(3)
+        dv = dv_t.reshape(B, Tk, Hkv, group, D).sum(3)
+    else:
+        dk, dv = dk_t, dv_t
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _rect_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
+                   mask_fn, sm_scale, block_q, block_k, interpret):
+    """The rectangular grid's two backward kernels over ``[B,H,T,D]``
+    -> (dq in q's dtype, dk, dv float32 per QUERY head)."""
+    B, H, Tq, D = qt.shape
+    Tk = kt.shape[2]
+    group = H // kt.shape[1]
+    nq, nk = Tq // block_q, Tk // block_k
+    _tally_stream_site(None, kernels=2)
     common = dict(
         causal=causal,
         mask_fn=mask_fn,
@@ -981,7 +1429,7 @@ def _bwd_pallas(
             row_spec,
         ],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), qt.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
@@ -1040,23 +1488,7 @@ def _bwd_pallas(
         ),
         interpret=interpret,
     )(offsets, qt, kt, vt, dot, lse4, delta4)
-
-    if layout == "bhtd":
-        if group > 1:
-            dk = dk_full.reshape(B, Hkv, group, Tk, D).sum(2)
-            dv = dv_full.reshape(B, Hkv, group, Tk, D).sum(2)
-        else:
-            dk, dv = dk_full, dv_full
-        return dqt, dk.astype(k.dtype), dv.astype(v.dtype)
-    dq = dqt.transpose(0, 2, 1, 3)
-    dk_t = dk_full.transpose(0, 2, 1, 3)  # [B,Tk,H,D]
-    dv_t = dv_full.transpose(0, 2, 1, 3)
-    if group > 1:
-        dk = dk_t.reshape(B, Tk, Hkv, group, D).sum(3)
-        dv = dv_t.reshape(B, Tk, Hkv, group, D).sum(3)
-    else:
-        dk, dv = dk_t, dv_t
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    return dqt, dk_full, dv_full
 
 
 # ---------------------------------------------------------------------------
@@ -1171,8 +1603,8 @@ def flash_attention_fwd(
     mask_fn=None,
     q_offset=0,
     k_offset=0,
-    block_q=512,
-    block_k=512,
+    block_q=None,
+    block_k=None,
     interpret=None,
     layout="bthd",
     allow_fused=True,
@@ -1183,7 +1615,9 @@ def flash_attention_fwd(
     when the fused short-seq form is eligible — for tests and A/B
     timing."""
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    bq, bk = _validate_blocks(q, k, block_q, block_k, layout)
+    bq, bk = _call_blocks(
+        q, k, block_q, block_k, layout, causal, mask_fn, q_offset, k_offset
+    )
     return _fwd_pallas(
         q,
         k,
@@ -1303,15 +1737,17 @@ def flash_attention_bwd(
     mask_fn=None,
     q_offset=0,
     k_offset=0,
-    block_q=512,
-    block_k=512,
+    block_q=None,
+    block_k=None,
     interpret=None,
     layout="bthd",
     allow_fused=True,
 ):
     """Backward kernels; returns ``(dq, dk, dv)`` given saved residuals."""
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    bq, bk = _validate_blocks(q, k, block_q, block_k, layout)
+    bq, bk = _call_blocks(
+        q, k, block_q, block_k, layout, causal, mask_fn, q_offset, k_offset
+    )
     return _bwd_pallas(
         q,
         k,
@@ -1332,9 +1768,37 @@ def flash_attention_bwd(
     )
 
 
-def _validate_blocks(q, k, block_q, block_k, layout="bthd"):
+# Blocks of the streaming kernels where the caller states none. Measured
+# on v5e (bf16, D = 128; ms a call forward / forward + backward, PERF.md
+# PR 38) on the triangle path at [2, 16/16, 4096, 128] and
+# [1, 32/2, 8192, 128]: 256 5.31 / 13.19 and 20.92 / 49.30, 512 2.59 /
+# 6.81 and 9.70 / 24.39, 1024 1.24 / 5.04 and 4.44 / 17.39: a step's
+# work on its [b, 1] running statistics and [b, D] accumulators costs as
+# much as a quarter of a [b, 512] score tile each, and a wider tile
+# halves it. The rectangular grid keeps the 512 it was measured at.
+_BLOCK = 512
+_TRI_BLOCK = 1024
+
+
+def _sees_triangle(causal, mask_fn, diagonal: bool) -> bool:
+    """The visible region is known to be the lower triangle when the
+    program is traced: a causal mask alone, with query and key offsets
+    static and equal (``_on_diagonal``). Both families test it."""
+    return bool(causal and mask_fn is None and diagonal)
+
+
+def _validate_blocks(q, k, block_q, block_k, layout="bthd", triangle=False):
     seq_axis = 2 if layout == "bhtd" else 1
     Tq, Tk = q.shape[seq_axis], k.shape[seq_axis]
+    if block_q is None and block_k is None:
+        # the caller states none: the triangle path's where it divides
+        # the one sequence and the head is no wider than measured
+        wide = (
+            triangle and Tq == Tk and Tq % _TRI_BLOCK == 0
+            and q.shape[-1] <= _LANES
+        )
+        block_q = block_k = _TRI_BLOCK if wide else _BLOCK
+    block_q, block_k = block_q or _BLOCK, block_k or _BLOCK
     bq, bk = min(block_q, Tq), min(block_k, Tk)
     if Tq % bq or Tk % bk or bq % 8 or bk % 8:
         # TPU sublane tiling wants 8-aligned seq blocks; the public entry
@@ -1344,6 +1808,18 @@ def _validate_blocks(q, k, block_q, block_k, layout="bthd"):
             f"blocks ({bq=}, {bk=}); pad inputs or pass other block sizes"
         )
     return bq, bk
+
+
+def _call_blocks(q, k, block_q, block_k, layout, causal, mask_fn,
+                 q_offset, k_offset):
+    """``_validate_blocks`` for an entry's call, which knows here
+    whether it will see the triangle."""
+    return _validate_blocks(
+        q, k, block_q, block_k, layout,
+        triangle=_sees_triangle(
+            causal, mask_fn, _on_diagonal(q_offset, k_offset)
+        ),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1410,8 +1886,8 @@ def flash_attention(
     mask_fn: Optional[MaskFn] = None,
     q_offset=0,
     k_offset=0,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     return_residuals: bool = False,
     force: Optional[str] = None,
     layout: str = "bthd",
@@ -1461,7 +1937,10 @@ def flash_attention(
 
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     try:
-        bq, bk = _validate_blocks(q, k, block_q, block_k, layout)
+        bq, bk = _call_blocks(
+            q, k, block_q, block_k, layout, causal, mask_fn, q_offset,
+            k_offset,
+        )
     except ValueError:
         if force is not None:
             raise

@@ -438,23 +438,40 @@ class ElasticTrainer:
             )
 
     def _fold_attention_tally(self) -> str:
-        """The fused attention sites lowered so far into the stats, and
-        what was lowered since the last such line, in words."""
-        from dlrover_tpu.ops.flash_attention import fused_tally
+        """The attention kernels lowered so far, fused and streaming,
+        into the stats, and what was lowered since the last such line,
+        in words."""
+        from dlrover_tpu.ops.flash_attention import fused_tally, stream_tally
 
         stats = self.pipeline_stats
-        tally = fused_tally()
-        # PipelineStats.attn_<field> is the tally as the last line left it
-        new = tally - [getattr(stats, f"attn_{f}") for f in tally._fields]
-        for f, n in zip(tally._fields, tally):
-            setattr(stats, f"attn_{f}", n)
-        if not new.tri_sites + new.square_sites:
-            return ""
-        return (
-            f"; fused attention: {new.tri_sites} sites as triangle "
-            f"({new.tiles_walked} of {new.tiles_square} tiles), "
-            f"{new.square_sites} as square"
-        )
+
+        def fold(tally, prefix):
+            # PipelineStats.<prefix><field> is the tally as the last
+            # line left it
+            new = tally - [
+                getattr(stats, prefix + f) for f in tally._fields
+            ]
+            for f, n in zip(tally._fields, tally):
+                setattr(stats, prefix + f, n)
+            return new
+
+        fused = fold(fused_tally(), "attn_")
+        stream = fold(stream_tally(), "attn_stream_")
+        said = ""
+        if fused.tri_sites + fused.square_sites:
+            said += (
+                f"; fused attention: {fused.tri_sites} sites as triangle "
+                f"({fused.tiles_walked} of {fused.tiles_square} tiles), "
+                f"{fused.square_sites} as square"
+            )
+        if stream.tri_sites + stream.rect_sites:
+            said += (
+                f"; streaming attention: {stream.tri_sites} sites as "
+                f"triangle ({stream.blocks_walked} of "
+                f"{stream.blocks_rect} blocks), {stream.rect_sites} as "
+                "rectangle"
+            )
+        return said
 
     def _first_build(self, what: str):
         """``build:<what>`` around the FIRST call of a jitted program

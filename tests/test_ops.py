@@ -309,6 +309,229 @@ class TestFlashAttention:
             np.testing.assert_allclose(a, b, atol=5e-4)
 
 
+    # -- the streaming family's triangle path (causal, equal static
+    # offsets, square blocks): a grid step, a fetch and a mask only
+    # where a query can see
+    @staticmethod
+    def _stream_case(dtype, n, H, Hkv, offset, seed=0):
+        """Forward and backward through the raw entries in blocks of 8
+        (so T = 8 n has n blocks a side), against the reference."""
+        B, D, block = 1, 32, 8
+        q, k, v = _qkv(B=B, T=n * block, H=H, Hkv=Hkv, D=D, seed=seed,
+                       dtype=dtype)
+        do = jnp.asarray(
+            np.random.default_rng(seed + 1).normal(size=q.shape), dtype
+        )
+        kw = dict(
+            causal=True, q_offset=offset, k_offset=offset, block_q=block,
+            block_k=block, interpret=True, allow_fused=False,
+        )
+        o, lse = flash_attention_fwd(q, k, v, **kw)
+        grads = flash_attention_bwd(q, k, v, o, lse, do, **kw)
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        o_ref, lse_ref = flash_attention_reference(
+            q, k, v, return_residuals=True
+        )
+        want = jax.grad(
+            lambda q, k, v: (
+                f32(flash_attention_reference(q, k, v)) * f32(do)
+            ).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        return (o, lse, grads), (o_ref, lse_ref, want)
+
+    @staticmethod
+    def _assert_close(got, want, atol_o, atol_g):
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        (o, lse, grads), (o_ref, lse_ref, g_ref) = got, want
+        np.testing.assert_allclose(f32(o), f32(o_ref), atol=atol_o)
+        np.testing.assert_allclose(lse, lse_ref, atol=2e-5)
+        for a, b, name in zip(grads, g_ref, "qkv"):
+            np.testing.assert_allclose(
+                f32(a), f32(b), atol=atol_g, err_msg=f"d{name}"
+            )
+
+    @pytest.mark.parametrize("offset", [0, 40], ids=["at0", "at40"])
+    @pytest.mark.parametrize(
+        "H,Hkv", [(4, 4), (8, 2), (16, 1)], ids=["mha", "gqa4", "gqa16"]
+    )
+    @pytest.mark.parametrize("n", [2, 4, 8])
+    @pytest.mark.parametrize(
+        "dtype,atol_o,atol_g",
+        [(jnp.float32, 2e-5, 2e-4), (jnp.bfloat16, 2e-2, 2.5e-1)],
+        ids=["f32", "bf16"],
+    )
+    def test_stream_triangle_matches_reference(
+        self, dtype, atol_o, atol_g, n, H, Hkv, offset
+    ):
+        before = fa.stream_tally()
+        got, want = self._stream_case(dtype, n, H, Hkv, offset, seed=n)
+        # a forward and a one-pass backward, n(n+1)/2 of n^2 blocks each
+        assert fa.stream_tally() - before == (
+            2, 0, n * (n + 1), 2 * n * n
+        )
+        self._assert_close(got, want, atol_o, atol_g)
+
+    @pytest.mark.parametrize("n", [2, 4, 8])
+    @pytest.mark.parametrize(
+        "dtype,atol_o,atol_g",
+        [(jnp.float32, 2e-5, 2e-4), (jnp.bfloat16, 2e-2, 2.5e-1)],
+        ids=["f32", "bf16"],
+    )
+    def test_stream_triangle_split_backward(
+        self, dtype, atol_o, atol_g, n, monkeypatch
+    ):
+        # a head whose float32 dq no longer fits VMEM beside its dq
+        # block: the dq kernel and the dk / dv kernel over the triangle
+        assert fa._one_pass_fits(8192, 128, 2)
+        assert not fa._one_pass_fits(65536, 128, 2)
+        monkeypatch.setattr(fa, "_ONE_PASS_MAX_BYTES", 0)
+        before = fa.stream_tally()
+        got, want = self._stream_case(dtype, n, 8, 2, 0, seed=n)
+        assert fa.stream_tally() - before == (
+            3, 0, 3 * n * (n + 1) // 2, 3 * n * n
+        )
+        self._assert_close(got, want, atol_o, atol_g)
+
+    # which grid a streaming site takes is decided by what is known
+    # when the program is traced; either way it matches the reference.
+    # T = 128 in blocks of 32: 10 of 16 blocks a kernel, a forward and
+    # a one-pass backward; the rectangle's backward is two kernels
+    @pytest.mark.parametrize(
+        "case,kw,tally",
+        [
+            ("in_sequence", dict(), (2, 0, 20, 32)),
+            ("equal_offsets", dict(q_offset=96, k_offset=96), (2, 0, 20, 32)),
+            ("overlap", dict(q_offset=32, k_offset=0), (0, 3, 0, 0)),
+            ("all_visible", dict(q_offset=128, k_offset=0), (0, 3, 0, 0)),
+            ("all_future", dict(q_offset=0, k_offset=128), (0, 3, 0, 0)),
+            ("mask_fn", dict(
+                mask_fn=lambda qp, kp: (qp >= kp) & (qp - kp < 48)
+            ), (0, 3, 0, 0)),
+            ("not_causal", dict(causal=False), (0, 3, 0, 0)),
+            ("oblong_blocks", dict(block_k=64), (0, 3, 0, 0)),
+            ("too_many_blocks", dict(), (0, 3, 0, 0)),
+        ],
+    )
+    def test_stream_grid_taken_and_grads(self, case, kw, tally, monkeypatch):
+        if case == "too_many_blocks":  # the tables would crowd SMEM
+            monkeypatch.setattr(fa, "_TRI_MAX_BLOCKS", 2)
+        q, k, v = _qkv(T=128, H=4, Hkv=2)
+        kw = dict(dict(block_q=32, block_k=32), **kw)
+
+        def lp(q, k, v):
+            return (flash_attention(q, k, v, force="pallas", **kw) ** 2).sum()
+
+        kw_ref = {
+            k_: v_ for k_, v_ in kw.items() if not k_.startswith("block")
+        }
+
+        def lr(q, k, v):
+            return (flash_attention_reference(q, k, v, **kw_ref) ** 2).sum()
+
+        before = fa.stream_tally(), fa.fused_tally()
+        gp = jax.grad(lp, argnums=(0, 1, 2))(q, k, v)
+        assert fa.stream_tally() - before[0] == tally
+        assert fa.fused_tally() == before[1]  # GQA: never the fused family
+        gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gp, gr):
+            np.testing.assert_allclose(a, b, atol=5e-4)
+        np.testing.assert_allclose(
+            flash_attention(q, k, v, force="pallas", **kw),
+            flash_attention_reference(q, k, v, **kw_ref), atol=2e-5,
+        )
+
+    @pytest.mark.parametrize("offsets", [(0, 0), (128, 128), (128, 0)])
+    def test_stream_traced_offsets_take_the_rectangle(self, offsets):
+        # a ring hop: the offsets are values of the program, so even
+        # equal ones are not known equal when it is traced
+        q, k, v = _qkv(T=128, H=4, Hkv=2)
+        kw = dict(causal=True, block_q=32, block_k=32, interpret=True)
+
+        @jax.jit
+        def hop(q, k, v, q_off, k_off):
+            o, lse = flash_attention_fwd(
+                q, k, v, q_offset=q_off, k_offset=k_off, **kw
+            )
+            grads = flash_attention_bwd(
+                q, k, v, o, lse, jnp.ones_like(o), q_offset=q_off,
+                k_offset=k_off, **kw,
+            )
+            return o, lse, grads
+
+        before = fa.stream_tally()
+        o, lse, grads = hop(q, k, v, *(jnp.int32(n) for n in offsets))
+        assert fa.stream_tally() - before == (0, 3, 0, 0)
+        q_off, k_off = offsets
+        o_ref, lse_ref = flash_attention_reference(
+            q, k, v, q_offset=q_off, k_offset=k_off, return_residuals=True
+        )
+        np.testing.assert_allclose(o, o_ref, atol=2e-5)
+        np.testing.assert_allclose(lse, lse_ref, atol=2e-5)
+        want = jax.grad(
+            lambda q, k, v: flash_attention_reference(
+                q, k, v, q_offset=q_off, k_offset=k_off
+            ).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        for a, b in zip(grads, want):
+            np.testing.assert_allclose(a, b, atol=5e-4)
+        # the same offsets as Python ints are known: equal ones walk
+        before = fa.stream_tally()
+        o_static, _ = flash_attention_fwd(
+            q, k, v, q_offset=q_off, k_offset=k_off, **kw
+        )
+        walked = (1, 0, 10, 16) if q_off == k_off else (0, 1, 0, 0)
+        assert fa.stream_tally() - before == walked
+        np.testing.assert_allclose(o_static, o_ref, atol=2e-5)
+
+    def test_fused_eligible_call_is_no_streaming_site(self):
+        q, k, v = _qkv(T=128)  # H = H_kv, T <= 1024: the fused family
+        before = fa.stream_tally(), fa.fused_tally()
+        jax.grad(
+            lambda q, k, v: flash_attention(q, k, v, force="pallas").sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+        assert fa.stream_tally() == before[0]
+        assert fa.fused_tally() - before[1] == (0, 2, 0, 0)
+
+    # the blocks of a call that states none: 1024 where it will take the
+    # triangle path and 1024 divides its one sequence, else the 512 the
+    # rectangular grid was measured at; a stated block is the caller's
+    @pytest.mark.parametrize(
+        "T,Tk,D,stated,triangle,want",
+        [
+            (4096, 4096, 128, (None, None), True, (1024, 1024)),
+            (8192, 8192, 64, (None, None), True, (1024, 1024)),
+            (1024, 1024, 128, (None, None), True, (1024, 1024)),
+            (4096, 4096, 128, (None, None), False, (512, 512)),
+            (1536, 1536, 128, (None, None), True, (512, 512)),
+            (4096, 4096, 256, (None, None), True, (512, 512)),
+            (1024, 4096, 128, (None, None), True, (512, 512)),
+            (4096, 4096, 128, (256, None), True, (256, 512)),
+            (4096, 4096, 128, (512, 512), True, (512, 512)),
+            (256, 256, 128, (None, None), True, (256, 256)),
+        ],
+    )
+    def test_blocks_of_a_call_that_states_none(
+        self, T, Tk, D, stated, triangle, want
+    ):
+        q = jax.ShapeDtypeStruct((1, 2, T, D), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, 2, Tk, D), jnp.bfloat16)
+        assert fa._validate_blocks(
+            q, k, *stated, "bhtd", triangle=triangle
+        ) == want
+
+    def test_the_triangle_is_seen_only_when_traced_facts_show_it(self):
+        assert fa._sees_triangle(True, None, fa._on_diagonal(7, 7))
+        assert not fa._sees_triangle(True, None, fa._on_diagonal(7, 0))
+        assert not fa._sees_triangle(
+            True, None, fa._on_diagonal(jnp.int32(7), jnp.int32(7))
+        )
+        assert not fa._sees_triangle(False, None, True)
+        assert not fa._sees_triangle(True, lambda q, k: q >= k, True)
+
+
 class TestFusedShortSeq:
     """The fused single-program kernels (T <= 1024, H == Hkv) vs the
     streaming block-tiled kernels and the jnp reference."""

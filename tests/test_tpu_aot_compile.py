@@ -78,9 +78,16 @@ ATTENTION_SHAPES = {
     # the benchmark's XL cell: the triangle walk, 5 heads a program
     # forward and 1 backward (_walk_head_chunk)
     "gpt2_xl_d12_cell": (8, 25, 1024, 64),
-    # streaming path, 512-blocks
+    # streaming path: the triangle in blocks of 1024, one-pass backward
     "llama2_7b": (1, 32, 4096, 128),
+    # the benchmark's OLMoE cell (two such layers) ...
+    "olmoe_cell": (2, 16, 4096, 128),
+    # ... and its Nemotron cell: 32 query heads on 2 key-value heads
+    "nemotron_cell": (1, 32, 8192, 128),
+    # a head's float32 dq no longer fits VMEM: the split backward
+    "long_context": (1, 4, 65536, 128),
 }
+KV_HEADS = {"nemotron_cell": 2}
 
 
 @pytest.mark.parametrize("name", list(ATTENTION_SHAPES))
@@ -88,17 +95,21 @@ ATTENTION_SHAPES = {
 def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
     shape = ATTENTION_SHAPES[name]
-    assert (shape[2] <= fa._FUSED_MAX_T) == (name != "llama2_7b")
+    B, H, T, D = shape
+    fused = T <= fa._FUSED_MAX_T
+    assert fused == name.startswith("gpt2")
+    kv_shape = (B, KV_HEADS.get(name, H), T, D)
     qkv = [
-        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
-    ] * 3
+        jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+        for s in (shape, kv_shape, kv_shape)
+    ]
 
     def attend(q, k, v):
         return fa.flash_attention(
             q, k, v, causal=True, force="pallas", layout="bhtd"
         )
 
-    before = fa.fused_tally()
+    before = fa.fused_tally(), fa.stream_tally()
     if direction == "fwd":
         compiled = _compile_for_chip(attend, *qkv)
     else:
@@ -114,25 +125,43 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
     # the kernel's name= reaches the HLO instruction's name (and so a
     # device trace's event): the benchmark's reducer finds the kernels
     # by it, whatever the compiler numbers them
-    fused = name != "llama2_7b"
     want = {
         ("fwd", True): ["flash_attn_fused_fwd"],
         ("bwd", True): ["flash_attn_fused_fwd", "flash_attn_fused_bwd"],
         ("fwd", False): ["flash_attn_fwd"],
-        ("bwd", False): [
-            "flash_attn_fwd", "flash_attn_bwd_dq", "flash_attn_bwd_dkv",
-        ],
+        # the backward in one pass (no dq and no dk / dv kernel) while
+        # a head's dq fits, split in those two beyond
+        ("bwd", False): (
+            ["flash_attn_fwd", "flash_attn_bwd"]
+            if fa._one_pass_fits(T, D, 2)
+            else ["flash_attn_fwd", "flash_attn_bwd_dq", "flash_attn_bwd_dkv"]
+        ),
     }[direction, fused]
+    assert fa._one_pass_fits(T, D, 2) == (name != "long_context")
     text = compiled.as_text()
     for kernel in want:
         assert kernel in text, kernel
+    assert ("flash_attn_bwd_d" in text) == (len(want) == 3)
     # causal, in sequence, T = 1024: every fused site is lowered as the
     # triangle walk (4 row tiles: 10 of 16 score tiles a site), under
-    # _FUSED_VMEM_LIMIT since it compiled; the streaming shape has none
-    sites = len(want) if fused else 0
-    assert fa.fused_tally() - before == (sites, 0, 10 * sites, 16 * sites)
+    # _FUSED_VMEM_LIMIT since it compiled; a streaming shape has none
+    # and is lowered on the triangle path in blocks of 1024 (10 of 16
+    # blocks a kernel at T = 4096, 36 of 64 at T = 8192), its one-pass
+    # backward with a head's float32 dq resident under the same limit
+    sites = len(want)
+    n = T // fa._TRI_BLOCK
+    walked = (sites, 0, n * (n + 1) // 2 * sites, n * n * sites)
+    assert (walked[2], walked[3]) == {
+        1024: (sites, sites), 4096: (10 * sites, 16 * sites),
+        8192: (36 * sites, 64 * sites), 65536: (2080 * sites, 4096 * sites),
+    }[T]
+    assert fa.fused_tally() - before[0] == (
+        (sites, 0, 10 * sites, 16 * sites) if fused else (0, 0, 0, 0)
+    )
+    assert fa.stream_tally() - before[1] == (
+        (0, 0, 0, 0) if fused else walked
+    )
     if fused:
-        H, T, D = shape[1:]
         assert (
             fa._walk_head_chunk(H, T, D, 2, wide=4, narrow=1),
             fa._walk_head_chunk(H, T, D, 2, wide=7, narrow=2),

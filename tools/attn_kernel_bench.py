@@ -8,11 +8,25 @@ one jitted program, forward and forward + backward, host clock around
   fused family; causal with equal static offsets: its triangle walk);
 - ``square``: the fused family's whole-square body (the walk switched
   off), which is what every fused call ran before the walk;
-- ``streaming``: ``allow_fused=False``, the block-tiled kernels;
-- ``tri:<bq>[:<heads fwd>:<heads bwd>]``: the walk at another row tile
-  and other heads a program.
+- ``streaming`` = ``stream-tri``: ``allow_fused=False``, the block-tiled
+  kernels as ``flash_attention`` lowers them (causal with equal static
+  offsets and square blocks: their triangle path);
+- ``stream-rect[:<n>]``: the same with the triangle path switched off,
+  the rectangular grid every streaming call ran before it (at blocks of
+  512, or of ``n``);
+- ``stream-split``: the triangle path with the one-pass backward
+  switched off (its dq and dk / dv kernels);
+- ``block:<n>``: ``stream-tri`` at blocks of ``n`` x ``n``;
+- ``stream-floor``: the triangle grid with no compute in its steps
+  (fetches, steps and write-backs only);
+- ``tri:<bq>[:<heads fwd>:<heads bwd>]``: the fused walk at another row
+  tile and other heads a program.
+
+A shape is ``BxHxTxD`` or, with its own key-value head count,
+``BxHxHkvxTxD``.
 
     python tools/attn_kernel_bench.py 16x12x1024x64 fused square streaming tri:128
+    python tools/attn_kernel_bench.py 1x32x2x8192x128 stream-tri stream-rect block:256
 """
 import importlib
 import json
@@ -28,16 +42,51 @@ fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
 # what the module picks, put back before each variant
 ROW_TILE = fa._TRI_ROW_TILE
 HEAD_CHUNK = fa._walk_head_chunk
+STREAM = {
+    name: getattr(fa, name)
+    for name in (
+        "_stream_plan", "_ONE_PASS_MAX_BYTES", "_tri_fwd_kernel",
+        "_tri_bwd_kernel",
+    )
+}
 LAYERS = 12
 REPEATS = 10
 ROUNDS = 5
 
 
+def _no_compute(n_in: int, n_out: int):
+    """A triangle kernel's refs (two tables, ``n_in`` inputs, ``n_out``
+    outputs, scratch) with every step writing zeros and no more."""
+
+    def kernel(*refs, **_):
+        for out in refs[2 + n_in:2 + n_in + n_out]:
+            out[...] = jnp.zeros_like(out)
+
+    return kernel
+
+
 def _steer(variant):
-    """Module constants of the variant; returns ``allow_fused``."""
+    """Module constants of the variant; returns ``allow_fused`` and the
+    entry's block arguments."""
     fa._TRI_ROW_TILE = ROW_TILE
     fa._walk_head_chunk = HEAD_CHUNK
+    for name, was in STREAM.items():
+        setattr(fa, name, was)
     kind, *rest = variant.split(":")
+    if kind in ("streaming", "block") or kind.startswith("stream-"):
+        if kind == "stream-rect":
+            fa._stream_plan = lambda *a, **kw: None
+        elif kind == "stream-split":
+            fa._ONE_PASS_MAX_BYTES = 0
+        elif kind == "stream-floor":
+            fa._tri_fwd_kernel = _no_compute(3, 2)
+            fa._tri_bwd_kernel = _no_compute(6, 3)
+        elif kind not in ("streaming", "stream-tri", "block"):
+            raise SystemExit(f"unknown variant {variant!r}")
+        n = int(rest[0]) if rest else None
+        if kind == "stream-rect":
+            n = n or fa._BLOCK  # what the rectangular grid is called at
+        return False, {"block_q": n, "block_k": n} if n else {}
     if kind == "square":
         fa._TRI_ROW_TILE = 1 << 30  # divides no T: the square body
     elif kind == "tri":
@@ -47,9 +96,9 @@ def _steer(variant):
             fa._walk_head_chunk = (
                 lambda H, T, D, itemsize, wide, narrow: heads[wide]
             )
-    elif kind not in ("fused", "streaming"):
+    elif kind != "fused":
         raise SystemExit(f"unknown variant {variant!r}")
-    return kind != "streaming"
+    return True, {}
 
 
 def _time(fn, *args):
@@ -66,13 +115,14 @@ def _time(fn, *args):
 
 
 def bench(shape, variant):
-    allow_fused = _steer(variant)
+    allow_fused, blocks = _steer(variant)
+    B, H, Hkv, T, D = shape
 
     def chain(q, k, v):
         for _ in range(LAYERS):
             q = fa.flash_attention(
                 q, k, v, causal=True, layout="bhtd",
-                allow_fused=allow_fused,
+                allow_fused=allow_fused, **blocks,
             )
         return q
 
@@ -80,15 +130,19 @@ def bench(shape, variant):
         return chain(q, k, v).astype(jnp.float32).sum()
 
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    q, k, v = (jax.random.normal(key, shape, jnp.bfloat16) for key in keys)
-    before = fa.fused_tally()
+    q, k, v = (
+        jax.random.normal(key, (B, heads, T, D), jnp.bfloat16)
+        for key, heads in zip(keys, (H, Hkv, Hkv))
+    )
+    before = fa.fused_tally(), fa.stream_tally()
     t0 = time.perf_counter()
     fwd = _time(jax.jit(chain), q, k, v)
     both = _time(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, k, v)
     return {
         "shape": list(shape), "variant": variant,
         "fwd_ms": round(fwd, 4), "fwd_bwd_ms": round(both, 4),
-        "tally": list(fa.fused_tally() - before),
+        "tally": list(fa.fused_tally() - before[0]),
+        "stream_tally": list(fa.stream_tally() - before[1]),
         "wall_s": round(time.perf_counter() - t0, 1),
     }
 
@@ -96,6 +150,8 @@ def bench(shape, variant):
 def main(argv):
     assert jax.default_backend() == "tpu", "this timing needs the chip"
     shape = tuple(int(n) for n in argv[0].split("x"))
+    if len(shape) == 4:  # no key-value head count of its own
+        shape = shape[:2] + shape[1:]
     for variant in argv[1:] or ["fused", "square", "streaming"]:
         try:
             print(json.dumps(bench(shape, variant)), flush=True)

@@ -182,12 +182,43 @@ class PipelineStats:
     restore_storage_read_s: float = _reported_to(4)  # 0 on the shm path
     # records -> device, to block_until_ready of the restored state
     restore_h2d_s: float = _reported_to(4)
+    # -- this incarnation's way from the agent's Popen to its first
+    # step (trainer/elastic/distributed.py ``init_elastic`` times the
+    # first two where they happen; the trainer folds the record in and
+    # adds the rest from the ``build:<what>`` rows at its first step) --
+    # Popen -> init_elastic(): the interpreter's start and every import
+    # up to there; 0 where no agent handed the instant over
+    startup_import_s: float = _reported_to(4)
+    # the ``backend_up`` span: device spec, compile cache,
+    # jax.distributed.initialize, the backend's start and the chip
+    startup_backend_s: float = _reported_to(4)
+    # the first ``build:step_donating`` / ``step_safe`` row's seconds:
+    # compile or cache load, plus one step
+    startup_first_step_s: float = _reported_to(4)
+    # XLA's compile + cache-retrieval seconds, and the persistent
+    # cache's misses, over every build row up to and including that one
+    startup_compile_s: float = _reported_to(4)
+    startup_cache_misses: int = 0
+    # -- the restart that made this incarnation, as the agent timed it
+    # and handed it over (agent/training_agent.py ``_restart_workers``;
+    # 0 on a first start): the monitor tick in which the death was
+    # found, persist-before-restart, and stop + lock reset + rendezvous
+    recover_detect_tick_s: float = _reported_to(4)
+    recover_persist_s: float = _reported_to(4)
+    recover_respawn_s: float = _reported_to(4)
+
+    def _fold(self, record: Optional[Dict[str, float]], names):
+        for key, value in (record or {}).items():
+            if key in names:
+                setattr(self, key, value)
 
     def set_restore(self, record: Optional[Dict[str, float]]):
         """Fold ``CheckpointEngine.last_restore`` in (None = no load)."""
-        for key, value in (record or {}).items():
-            if key in RESTORE_FIELDS:
-                setattr(self, key, value)
+        self._fold(record, RESTORE_FIELDS)
+
+    def set_startup(self, record: Optional[Dict[str, float]]):
+        """Fold ``distributed.startup_record()`` in."""
+        self._fold(record, STARTUP_FIELDS)
 
     @property
     def prefetch_overlap_pct(self) -> Optional[float]:
@@ -276,6 +307,23 @@ class PipelineStats:
             if self.restore_source
             else ""
         )
+        recovered = (
+            f" after a restart (tick {self.recover_detect_tick_s:.2f} s, "
+            f"persist {self.recover_persist_s:.2f} s, respawn "
+            f"{self.recover_respawn_s:.2f} s)"
+            if self.recover_detect_tick_s + self.recover_persist_s
+            + self.recover_respawn_s
+            else ""
+        )
+        startup = (
+            f", up{recovered} in {self.startup_import_s:.2f} s of imports + "
+            f"{self.startup_backend_s:.2f} s of backend, first step "
+            f"{self.startup_first_step_s:.2f} s (compile or load "
+            f"{self.startup_compile_s:.2f} s, "
+            f"{self.startup_cache_misses} misses)"
+            if self.startup_backend_s
+            else ""
+        )
         return (
             f"prefetch {self.prefetch_hits}h/{self.prefetch_misses}m"
             f" ({'-' if ov is None else ov}% overlap), "
@@ -284,12 +332,16 @@ class PipelineStats:
             f"path, {self.stage_commits} commits), donated "
             f"{self.donated_bytes >> 20} MiB over {self.donated_steps} "
             f"steps ({self.safe_steps} safe, {self.steps_ahead} dispatched "
-            f"ahead of the device){resize}{gsync}{restore}"
+            f"ahead of the device){resize}{gsync}{restore}{startup}"
         )
 
 
 RESTORE_FIELDS = tuple(
     f.name for f in fields(PipelineStats) if f.name.startswith("restore_")
+)
+STARTUP_FIELDS = tuple(
+    f.name for f in fields(PipelineStats)
+    if f.name.startswith(("startup_", "recover_"))
 )
 
 

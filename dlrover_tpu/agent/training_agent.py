@@ -116,6 +116,14 @@ class RunResult:
     message: str = ""
 
 
+def _rounded(timeline: Dict[str, object]) -> Dict[str, object]:
+    """A recovery's timeline as it is shown: seconds to the millisecond."""
+    return {
+        k: round(v, 3) if isinstance(v, float) else v
+        for k, v in timeline.items()
+    }
+
+
 def _host_ip() -> str:
     try:
         s = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
@@ -188,7 +196,16 @@ class ElasticTrainingAgent:
             f"{self._node_rank}"
         )
 
-    def _worker_env(self, local_rank: int, world: comm.CommWorld) -> Dict[str, str]:
+    def _worker_env(
+        self,
+        local_rank: int,
+        world: comm.CommWorld,
+        timeline: Optional[Dict[str, object]] = None,
+    ) -> Dict[str, str]:
+        """The environment of one training process. ``timeline`` is the
+        restart's record as it stands (``_restart_workers``); it rides
+        to the worker with the instant of its start, so that the
+        worker's own record says where the time since the death went."""
         ranks = sorted(world.world)
         base = sum(world.world[r] for r in ranks if r < self._node_rank)
         num_processes = sum(world.world.values())
@@ -214,12 +231,20 @@ class ElasticTrainingAgent:
         if self._spec.device_spec:
             env["DLROVER_TPU_DEVICE_SPEC"] = self._spec.device_spec
         ensure_framework_on_pythonpath(env)
+        # last, so that the instant is the one just before Popen
+        env[NodeEnv.SPAWN_TIMELINE] = json.dumps(
+            {**_rounded(timeline or {}), "t_spawn": time.monotonic()}
+        )
         return env
 
     # ------------------------------------------------------------------
     # worker process management
     # ------------------------------------------------------------------
-    def _start_workers(self, world: comm.CommWorld):
+    def _start_workers(
+        self,
+        world: comm.CommWorld,
+        timeline: Optional[Dict[str, object]] = None,
+    ):
         self._close_log_files()
         self._workers = []
         log_dir = self._spec.log_dir
@@ -240,7 +265,7 @@ class ElasticTrainingAgent:
                 stdout = stderr = None
             proc = subprocess.Popen(
                 cmd,
-                env=self._worker_env(local_rank, world),
+                env=self._worker_env(local_rank, world, timeline),
                 stdout=stdout,
                 stderr=stderr,
                 preexec_fn=die_with_parent_hook(),
@@ -390,7 +415,9 @@ class ElasticTrainingAgent:
         The restart is one ``recover`` span with a child per leg, and
         the legs' seconds go to the log as ONE line (``recovery
         timeline: {...}``) once the new workers are started: where the
-        time between a death and the new worker's start went.
+        time between a death and the new worker's start went. The new
+        workers are handed the same record as it stands at their start
+        (``_worker_env``), and carry it on in theirs.
         ``tick_s`` is the monitor tick in which the failure was found
         (the detection's own share, spent before this call)."""
         timeline: Dict[str, object] = {
@@ -441,13 +468,9 @@ class ElasticTrainingAgent:
             with leg("rendezvous"):
                 world = self._rendezvous()
             with leg("start_workers"):
-                self._start_workers(world)
+                self._start_workers(world, timeline)
         timeline["total_s"] = time.monotonic() - t0
-        rounded = {
-            k: round(v, 3) if isinstance(v, float) else v
-            for k, v in timeline.items()
-        }
-        logger.info(f"recovery timeline: {json.dumps(rounded)}")
+        logger.info(f"recovery timeline: {json.dumps(_rounded(timeline))}")
 
     def stop(self):
         self._stop_event.set()

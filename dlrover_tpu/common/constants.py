@@ -154,6 +154,15 @@ class NodeEnv:
     PROCESS_ID = "DLROVER_TPU_PROCESS_ID"
     NUM_PROCESSES = "DLROVER_TPU_NUM_PROCESSES"
     RESTART_COUNT = "DLROVER_TPU_RESTART_COUNT"
+    # the agent's to set and the worker's to read, nobody else's: one
+    # JSON object with ``t_spawn``, the agent's ``time.monotonic()`` just
+    # before it started this process (one clock for a host), and, after
+    # a restart, the record ``_restart_workers`` had kept by then
+    # (``reason``, ``restart``, ``detect_tick_s``,
+    # ``persist_before_restart_s``, ``stop_workers_s``,
+    # ``shm_lock_reset_s``, ``rendezvous_s``).
+    # ``init_elastic()`` folds it into the worker's start-up record
+    SPAWN_TIMELINE = "DLROVER_TPU_SPAWN_TIMELINE"
     GRAFT_PLATFORM = "JAX_PLATFORMS"
 
 

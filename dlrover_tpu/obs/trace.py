@@ -226,23 +226,6 @@ class SpanTracer:
         sp._done = True
         self._unwind(self._stacks.get(sp._tid), sp)
 
-    def traced(self, name: Optional[str] = None) -> Callable:
-        """Decorator form: ``@tracer.traced("load_config")``."""
-
-        def wrap(fn):
-            import functools
-
-            label = name or fn.__name__
-
-            @functools.wraps(fn)
-            def inner(*args, **kwargs):
-                with self.span(label):
-                    return fn(*args, **kwargs)
-
-            return inner
-
-        return wrap
-
     # -- introspection -------------------------------------------------
     def __len__(self) -> int:
         return len(self._buf)
@@ -462,10 +445,6 @@ def span(name: str, **attrs):
     """Span on the process default tracer (the instrumentation points
     in trainer/prefetch/ckpt/grad_sync all use this)."""
     return _default.span(name, **attrs)
-
-
-def traced(name: Optional[str] = None) -> Callable:
-    return _default.traced(name)
 
 
 def enable(on: bool = True):

@@ -11,11 +11,15 @@ assignment and restart, which is the elasticity seam JAX itself lacks
 
 from __future__ import annotations
 
+import json
 import os
+import time
 from dataclasses import dataclass
+from typing import Dict
 
 from dlrover_tpu.common.constants import NodeEnv
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.obs.trace import TimedSpan
 from dlrover_tpu.utils.device import check_devices, configure_devices
 from dlrover_tpu.utils.env import framework_root
 
@@ -58,6 +62,39 @@ def elastic_context() -> ElasticContext:
 
 
 _initialized = False
+# this process's way up, under the names ``PipelineStats`` gives it
+# (``startup_import_s``, ``startup_backend_s``, ``recover_*_s``): written
+# by the first ``init_elastic()``, folded in by every trainer built after
+_startup: Dict[str, float] = {}
+
+
+def startup_record() -> Dict[str, float]:
+    return dict(_startup)
+
+
+def _handed_over(now: float) -> Dict[str, float]:
+    """What the agent handed this process at its start
+    (``NodeEnv.SPAWN_TIMELINE``): the seconds from its ``Popen`` to
+    ``now`` (the interpreter's start and every import so far) and,
+    after a restart, the legs the agent had timed by then. Empty where
+    no agent handed anything (a script run by hand) or what it handed
+    does not parse."""
+    try:
+        handed = json.loads(os.getenv(NodeEnv.SPAWN_TIMELINE, ""))
+
+        def seconds(*legs):
+            return sum(float(handed.get(leg, 0.0)) for leg in legs)
+
+        return {
+            "startup_import_s": now - float(handed["t_spawn"]),
+            "recover_detect_tick_s": seconds("detect_tick_s"),
+            "recover_persist_s": seconds("persist_before_restart_s"),
+            "recover_respawn_s": seconds(
+                "stop_workers_s", "shm_lock_reset_s", "rendezvous_s"
+            ),
+        }
+    except (ValueError, TypeError, KeyError):
+        return {}
 
 
 def enable_compile_cache() -> str:
@@ -106,29 +143,39 @@ def init_elastic(timeout_secs: int = 300) -> ElasticContext:
     ``process_id``/``coordinator_addr`` for the new world; the
     persistent compilation cache turns the post-restart recompile into
     a disk read.
+
+    It also times this process's way up, once: the seconds since the
+    agent started it (``startup_import_s``; 0 where no agent did) and
+    the ``backend_up`` span around what brings the backend up
+    (``startup_backend_s``), beside the restart's legs the agent handed
+    over. ``startup_record()`` keeps them for the trainer's
+    ``PipelineStats``.
     """
     global _initialized
     ctx = elastic_context()
     if _initialized:
         return ctx
-    # configuration first, nothing here may bring the backend up:
-    # jax.distributed.initialize refuses to run after it
-    configure_devices()  # honors DLROVER_TPU_DEVICE_SPEC
-    enable_compile_cache()
-    if ctx.is_distributed:
-        import jax
+    _startup.clear()
+    _startup.update(_handed_over(time.monotonic()))
+    with TimedSpan(_startup, "startup_backend_s", name="backend_up"):
+        # configuration first, nothing here may bring the backend up:
+        # jax.distributed.initialize refuses to run after it
+        configure_devices()  # honors DLROVER_TPU_DEVICE_SPEC
+        enable_compile_cache()
+        if ctx.is_distributed:
+            import jax
 
-        logger.info(
-            f"jax.distributed.initialize(coordinator="
-            f"{ctx.coordinator_addr}, n={ctx.num_processes}, "
-            f"id={ctx.process_id})"
-        )
-        jax.distributed.initialize(
-            coordinator_address=ctx.coordinator_addr,
-            num_processes=ctx.num_processes,
-            process_id=ctx.process_id,
-            initialization_timeout=timeout_secs,
-        )
-    check_devices()  # the backend may come up now
+            logger.info(
+                f"jax.distributed.initialize(coordinator="
+                f"{ctx.coordinator_addr}, n={ctx.num_processes}, "
+                f"id={ctx.process_id})"
+            )
+            jax.distributed.initialize(
+                coordinator_address=ctx.coordinator_addr,
+                num_processes=ctx.num_processes,
+                process_id=ctx.process_id,
+                initialization_timeout=timeout_secs,
+            )
+        check_devices()  # the backend may come up now
     _initialized = True
     return ctx

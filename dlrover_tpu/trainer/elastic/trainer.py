@@ -199,7 +199,10 @@ class ElasticTrainer:
     ):
         import jax
 
-        from dlrover_tpu.trainer.elastic.distributed import init_elastic
+        from dlrover_tpu.trainer.elastic.distributed import (
+            init_elastic,
+            startup_record,
+        )
 
         # before anything touches a device: the agent's device spec
         # (asking for the chip and not getting it is an error), the
@@ -222,7 +225,7 @@ class ElasticTrainer:
         # misses across each; the list is logged at the end of __init__
         # (the steps' own programs build at their first call in train())
         self._builds = compile_meter()
-        self._builds_logged = len(self._builds.builds)
+        self._builds_first = self._builds_logged = len(self._builds.builds)
         self._built: set = set()
         self.tcfg = trainer_cfg or TrainerConfig()
         self._metrics_hook = metrics_hook
@@ -266,6 +269,9 @@ class ElasticTrainer:
         from dlrover_tpu.accel.profiler import PipelineStats
 
         self.pipeline_stats = PipelineStats()
+        # this process's way up as init_elastic() timed it, and the
+        # restart's legs the agent handed it
+        self.pipeline_stats.set_startup(startup_record())
         # which program runs a step, and the compile cache behind them
         self._programs = StepPrograms(
             self.accel, self.pipeline_stats, self.tcfg.donation_aware
@@ -422,6 +428,7 @@ class ElasticTrainer:
         rows = self._builds.builds[self._builds_logged:]
         self._builds_logged = len(self._builds.builds)
         if rows:
+            self._fold_first_step()
             # beside the build that made the state: where its int8
             # moments lie (ops/quantized_optim.py), if it has any
             tiles = self.pipeline_stats.opt_q8_tiles_elems
@@ -436,6 +443,26 @@ class ElasticTrainer:
                 f"programs built {when}: {describe_builds(rows)}{q8}"
                 f"{self._fold_attention_tally()}"
             )
+
+    def _fold_first_step(self):
+        """This trainer's build rows up to and including its first
+        step's into the stats (``startup_first_step_s``,
+        ``startup_compile_s``, ``startup_cache_misses``): what the way
+        to the first step compiled, and what the cache gave it. Nothing
+        before a step has built; the same numbers ever after."""
+        upto = []
+        for row in self._builds.builds[self._builds_first:]:
+            upto.append(row)
+            if row["what"] in ("step_donating", "step_safe"):
+                break
+        else:
+            return
+        stats = self.pipeline_stats
+        stats.startup_first_step_s = upto[-1]["seconds"]
+        stats.startup_compile_s = sum(
+            b["compile_s"] + b["retrieval_s"] for b in upto
+        )
+        stats.startup_cache_misses = int(sum(b["cache_misses"] for b in upto))
 
     def _fold_attention_tally(self) -> str:
         """The attention kernels lowered so far, fused and streaming,

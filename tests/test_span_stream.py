@@ -13,6 +13,7 @@ program carries ``scope/<name>`` scopes as metadata only, and the agent
 logs a recovery as one timeline.
 """
 
+import importlib.util
 import json
 import logging
 import os
@@ -20,6 +21,7 @@ import subprocess
 import sys
 import threading
 import time
+import types
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +31,7 @@ import pytest
 
 from dlrover_tpu.accel.profiler import (
     RESTORE_FIELDS,
+    STARTUP_FIELDS,
     PipelineStats,
     compile_meter,
 )
@@ -409,17 +412,18 @@ def test_scopes_are_metadata_only(lowered_step, monkeypatch):
 
 
 # -- 6. the agent's recovery as one timeline --------------------------------
-def test_agent_logs_a_recovery_as_one_timeline(tracer):
+def _restart_once():
+    """An agent whose worker fails once: the run's result, what it
+    logged, and the environment it gave each worker it started."""
     from dlrover_tpu.agent.master_client import MasterClient
     from dlrover_tpu.agent.training_agent import (
         ElasticTrainingAgent,
         WorkerSpec,
-        WorkerState,
     )
     from dlrover_tpu.common.log import default_logger
     from dlrover_tpu.master.local_master import start_local_master
 
-    lines = []
+    lines, envs = [], []
 
     class _Capture(logging.Handler):
         def emit(self, record):
@@ -440,14 +444,32 @@ def test_agent_logs_a_recovery_as_one_timeline(tracer):
             client=MasterClient(master.addr, node_id=0),
         )
         agent.set_checkpoint_hook(lambda: time.sleep(0.05))
+        worker_env = agent._worker_env
+
+        def recording_env(*args):
+            envs.append(worker_env(*args))
+            return envs[-1]
+
+        agent._worker_env = recording_env
         result = agent.run()
     finally:
         default_logger.removeHandler(handler)
         master.stop()
-    assert result.state == WorkerState.SUCCEEDED and result.restarts == 1
+    return result, lines, envs
+
+
+def _timeline(lines):
     found = [m for m in lines if m.startswith("recovery timeline: ")]
     assert len(found) == 1
-    timeline = json.loads(found[0].split(": ", 1)[1])
+    return json.loads(found[0].split(": ", 1)[1])
+
+
+def test_agent_logs_a_recovery_as_one_timeline(tracer):
+    from dlrover_tpu.agent.training_agent import WorkerState
+
+    result, lines, _envs = _restart_once()
+    assert result.state == WorkerState.SUCCEEDED and result.restarts == 1
+    timeline = _timeline(lines)
     legs = [
         "persist_before_restart_s", "stop_workers_s", "shm_lock_reset_s",
         "rendezvous_s", "start_workers_s",
@@ -463,6 +485,215 @@ def test_agent_logs_a_recovery_as_one_timeline(tracer):
     assert recover[0][4]["detect_tick_s"] == timeline["detect_tick_s"]
     inside = [r[0] for r in recs if r[0] != "recover" and _inside(r, recover[0])]
     assert inside == [k[:-2] for k in legs]
+
+
+# -- 6b. start-up and recovery on one timeline (ISSUE 40) ---------------------
+HANDED_LEGS = (
+    "reason", "detect_tick_s", "persist_before_restart_s", "stop_workers_s",
+    "shm_lock_reset_s", "rendezvous_s",
+)
+
+
+def test_agent_hands_its_legs_to_the_worker_it_starts(tracer):
+    from dlrover_tpu.common.constants import NodeEnv
+
+    before = time.monotonic()
+    _result, lines, envs = _restart_once()
+    first, second = (json.loads(e[NodeEnv.SPAWN_TIMELINE]) for e in envs)
+    # a first start hands the instant and no legs
+    assert set(first) == {"t_spawn"}
+    assert before <= first["t_spawn"] <= second["t_spawn"] <= time.monotonic()
+    # a restart hands the legs timed by then, as the log line says them
+    timeline = _timeline(lines)
+    assert {k: second[k] for k in HANDED_LEGS} == {
+        k: timeline[k] for k in HANDED_LEGS
+    }
+    assert "start_workers_s" not in second and "total_s" not in second
+    # ... and the instant just before Popen: inside the start_workers leg
+    (leg,) = [r for r in _records(tracer) if r[0] == "start_workers"]
+    assert leg[1] <= second["t_spawn"] * 1e9 <= leg[2]
+    assert len(envs[1][NodeEnv.SPAWN_TIMELINE]) < 512
+
+
+@pytest.fixture
+def fresh_process(monkeypatch):
+    """``init_elastic()`` as a new process finds it: not yet run, its
+    record empty (and this process's own left as it was afterwards)."""
+    from dlrover_tpu.common.constants import NodeEnv
+    from dlrover_tpu.trainer.elastic import distributed
+
+    monkeypatch.setattr(distributed, "_initialized", False)
+    monkeypatch.setattr(distributed, "_startup", {})
+    monkeypatch.delenv(NodeEnv.SPAWN_TIMELINE, raising=False)
+    return distributed
+
+
+@pytest.mark.parametrize("handed", ["restart", "first_start", "by_hand", "garbled"])
+def test_init_elastic_times_its_way_up(handed, fresh_process, monkeypatch, tracer):
+    from dlrover_tpu.common.constants import NodeEnv
+
+    legs = {
+        "reason": "worker_failure", "restart": 0, "detect_tick_s": 3.0,
+        "persist_before_restart_s": 8.5, "stop_workers_s": 0.25,
+        "shm_lock_reset_s": 0.5, "rendezvous_s": 1.0,
+    }
+    value = {
+        "restart": json.dumps({**legs, "t_spawn": time.monotonic() - 2.0}),
+        "first_start": json.dumps({"t_spawn": time.monotonic() - 2.0}),
+        "by_hand": None,
+        "garbled": "{'t_spawn': yesterday",
+    }[handed]
+    if value is not None:
+        monkeypatch.setenv(NodeEnv.SPAWN_TIMELINE, value)
+    fresh_process.init_elastic()
+    rec = fresh_process.startup_record()
+    assert rec["startup_backend_s"] > 0
+    if handed in ("restart", "first_start"):
+        assert 2.0 <= rec["startup_import_s"] < 2.0 + 5.0
+    else:
+        assert "startup_import_s" not in rec
+    if handed == "restart":
+        assert rec["recover_detect_tick_s"] == 3.0
+        assert rec["recover_persist_s"] == 8.5
+        assert rec["recover_respawn_s"] == 1.75
+    else:
+        assert not any(v for k, v in rec.items() if k.startswith("recover_"))
+    # a later call returns early and changes nothing
+    fresh_process.init_elastic()
+    assert fresh_process.startup_record() == rec
+    assert [r[0] for r in _records(tracer)].count("backend_up") == 1
+    # what a trainer folds in: the fields, zeros where nothing was handed
+    stats = PipelineStats()
+    stats.set_startup(rec)
+    assert set(rec) <= set(STARTUP_FIELDS)
+    assert stats.startup_backend_s == rec["startup_backend_s"]
+    assert stats.startup_import_s == rec.get("startup_import_s", 0.0)
+    assert ("after a restart" in stats.summary()) == (handed == "restart")
+
+
+def test_trainer_carries_the_way_up_in_its_stats(tmp_path, tracer, monkeypatch):
+    from dlrover_tpu.trainer.elastic import distributed
+
+    handed = {
+        "startup_import_s": 6.5, "startup_backend_s": 4.25,
+        "recover_detect_tick_s": 3.0, "recover_persist_s": 8.5,
+        "recover_respawn_s": 1.75,
+    }
+    monkeypatch.setattr(distributed, "_initialized", True)
+    monkeypatch.setattr(distributed, "_startup", dict(handed))
+    before = len(compile_meter().builds)
+    trainer, stats = _train(tmp_path, 3)
+    assert {k: stats[k] for k in handed} == handed
+    # the first step's row, and every row up to it
+    rows = compile_meter().builds[before:]
+    first = [r["what"] for r in rows].index("step_donating")
+    upto = rows[: first + 1]
+    assert stats["startup_first_step_s"] == rows[first]["seconds"] > 0
+    assert stats["startup_cache_misses"] == sum(
+        r["cache_misses"] for r in upto
+    )
+    assert stats["startup_compile_s"] == pytest.approx(
+        sum(r["compile_s"] + r["retrieval_s"] for r in upto), abs=1e-4
+    )
+    assert 0 < stats["startup_compile_s"] <= sum(r["seconds"] for r in upto)
+    assert "up after a restart (tick 3.00 s" in trainer.pipeline_stats.summary()
+    registry = MetricsRegistry()
+    fold_pipeline_stats(trainer.pipeline_stats, registry)
+    gauges = registry.scalars()
+    for field in STARTUP_FIELDS:
+        assert gauges["dlrover_pipeline_" + field] == pytest.approx(stats[field])
+    assert len(STARTUP_FIELDS) == 8
+
+
+def _reader(metric):
+    path = os.path.join(REPO, "benchmark", "layer_metrics", metric + ".py")
+    spec = importlib.util.spec_from_file_location("reader_under_test", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# a first incarnation's record at the window's close, a second one's final
+# report, and what each reader makes of them
+_FIRST = {
+    "startup_import_s": 6.5, "startup_backend_s": 4.25,
+    "startup_first_step_s": 2.5, "startup_compile_s": 1.5,
+    "startup_cache_misses": 0, "recover_detect_tick_s": 0.0,
+    "recover_persist_s": 0.0, "recover_respawn_s": 0.0,
+}
+_SECOND = {
+    "startup_import_s": 7.0, "startup_backend_s": 5.0,
+    "startup_first_step_s": 8.0, "startup_compile_s": 7.5,
+    "startup_cache_misses": 1, "recover_detect_tick_s": 3.0,
+    "recover_persist_s": 9.0, "recover_respawn_s": 1.5,
+}
+READERS = {
+    # metric: (its reading, whether it belongs to the kill cell alone)
+    "startup.import_s": (6.5, False),
+    "startup.backend_s": (4.25, False),
+    "startup.first_step_s": (2.5, False),
+    "startup.cache_misses": (0, False),
+    "agent.detect_tick_s": (3.0, True),
+    "agent.persist_before_restart_s": (9.0, True),
+    "agent.respawn_s": (1.5, True),
+    "restart.import_s": (7.0, True),
+    "restart.backend_s": (5.0, True),
+    "restart.first_step_s": (8.0, True),
+    "restart.cache_misses": (1, True),
+    # 26.0 from the last hook to `up`, less tick, persist, respawn, imports
+    # and backend: 26.0 - 25.5
+    "agent.respawn_unattributed_s": (0.5, True),
+}
+
+
+def _made_up_run(first, second, recovery):
+    return types.SimpleNamespace(
+        window={"pipeline": first, "pipeline_open": {}},
+        reports={0: {}, 1: {"stage": "done", "pipeline": second}},
+        recovery=recovery,
+    )
+
+
+@pytest.mark.parametrize("metric", sorted(READERS))
+def test_reader_finds_its_field_and_nothing_on_the_parent(metric):
+    reading, kill_only = READERS[metric]
+    mod = _reader(metric)
+    recovery = {"detect_respawn_s": 26.0, "recover_s": 50.0}
+    got = mod.read(_made_up_run(_FIRST, _SECOND, recovery))
+    assert got == pytest.approx(reading) and got is not None
+    # the parent's program: its records hold no such key
+    parent = {"restore_source": 1, "restore_shm_verify_s": 2.0}
+    assert mod.read(_made_up_run(parent, parent, recovery)) is None
+    assert mod.read(_made_up_run({}, {}, recovery)) is None
+    # a run that did not come back from its kill has no recovery to read;
+    # a steady cell has none by design and still came up
+    lone = mod.read(_made_up_run(_FIRST, _SECOND, None))
+    assert lone is None if kill_only else lone == pytest.approx(reading)
+    # which cells it is read in
+    assert mod.CELLS({"kill": True}) is True
+    assert mod.CELLS({"kill": False}) is (not kill_only)
+
+
+def test_new_per_layer_entries_match_their_files():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cells = {}
+    for w in bench["workloads"]:
+        path = os.path.join(REPO, "benchmark", "cells", w["name"] + ".json")
+        with open(path) as f:
+            cells[w["name"]] = json.load(f)
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    # the twelve are the last twelve, in no other entry's place
+    assert {m["name"] for m in bench["per_layer"][-12:]} == set(READERS)
+    for metric in READERS:
+        entry, mod = entries[metric], _reader(metric)
+        assert (mod.LAYER, mod.UNIT, mod.MOVES) == (
+            entry["layer"], entry["unit"], entry["moves"]
+        )
+        assert entry["source"] == "program_counter"
+        assert entry["better"] == "lower" and entry["moves"] == "setup_s"
+        taken = [name for name, cell in cells.items() if mod.CELLS(cell)]
+        assert taken == entry.get("workloads", list(cells))
 
 
 # -- 7. the telemetry writer's race -----------------------------------------

@@ -132,7 +132,7 @@ def test_rebuild_drops_the_executable(accel):
 # the parent's keys (PR 28), less the one counter PR 29 deleted with its
 # code, the fused attention tally's four (PR 36) and the held experts'
 # share of the routing (PR 37), the streaming attention tally's four
-# (PR 38)
+# (PR 38), an incarnation's way up and the restart behind it (PR 40)
 AS_DICT_KEYS = [
     "attn_square_sites", "attn_stream_blocks_rect",
     "attn_stream_blocks_walked", "attn_stream_rect_sites",
@@ -145,14 +145,17 @@ AS_DICT_KEYS = [
     "moe_held_share_sum", "moe_max_load_sum", "moe_reports", "opt_q8_blocks_elems",
     "opt_q8_tiles_elems", "overlap_pct_measured", "prefetch_hits",
     "prefetch_misses", "prefetch_overlap_pct", "prefetch_reprimes",
-    "prefetch_wait_s", "reshard_bytes_device",
+    "prefetch_wait_s", "recover_detect_tick_s", "recover_persist_s",
+    "recover_respawn_s", "reshard_bytes_device",
     "reshard_bytes_device_vs_host", "reshard_bytes_host", "resize_count",
     "resize_downtime_ms", "resize_idle_ranks", "resize_mb_pad",
     "restore_agree_s", "restore_bytes", "restore_h2d_s",
     "restore_lock_wait_s", "restore_shm_verify_s", "restore_source",
     "restore_storage_read_s", "restore_storage_verify_s", "safe_steps",
     "stage_backlog_bytes", "stage_block_s", "stage_bytes", "stage_chunks",
-    "stage_commits", "steps_ahead",
+    "stage_commits", "startup_backend_s", "startup_cache_misses",
+    "startup_compile_s", "startup_first_step_s", "startup_import_s",
+    "steps_ahead",
 ]
 # a float is reported to the places it had when each key was written out
 ROUNDED = {
@@ -163,6 +166,10 @@ ROUNDED = {
     "restore_storage_verify_s": 4, "restore_agree_s": 4,
     "restore_lock_wait_s": 4, "restore_shm_verify_s": 4,
     "restore_storage_read_s": 4, "restore_h2d_s": 4,
+    "startup_import_s": 4, "startup_backend_s": 4,
+    "startup_first_step_s": 4, "startup_compile_s": 4,
+    "recover_detect_tick_s": 4, "recover_persist_s": 4,
+    "recover_respawn_s": 4,
 }
 
 

@@ -25,8 +25,8 @@ Rules (per function):
   are exempt — only a begin that can strand its own function's end is
   a leak.
 
-``with span(...):`` and ``@traced`` need no analysis — the context
-manager closes on unwind by construction.
+``with span(...):`` needs no analysis — the context manager closes on
+unwind by construction.
 """
 
 from __future__ import annotations

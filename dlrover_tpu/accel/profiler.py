@@ -182,6 +182,15 @@ class PipelineStats:
     restore_storage_read_s: float = _reported_to(4)  # 0 on the shm path
     # records -> device, to block_until_ready of the restored state
     restore_h2d_s: float = _reported_to(4)
+    # -- the shard lock's side of the memory saves that fell due
+    # (ckpt/engine.py ``_take_shard_lock``; the trainer folds the record
+    # in after every save it asks for): saves skipped because the
+    # agent's saver still held the lock, the seconds inside the
+    # ``ckpt_begin_lock`` span of every due save, and the skips that
+    # the lock's mirror answered with no request to the agent --
+    save_skips: int = 0
+    begin_lock_s: float = _reported_to(4)
+    lock_local_answers: int = 0
     # -- this incarnation's way from the agent's Popen to its first
     # step (trainer/elastic/distributed.py ``init_elastic`` times the
     # first two where they happen; the trainer folds the record in and
@@ -215,6 +224,10 @@ class PipelineStats:
     def set_restore(self, record: Optional[Dict[str, float]]):
         """Fold ``CheckpointEngine.last_restore`` in (None = no load)."""
         self._fold(record, RESTORE_FIELDS)
+
+    def set_save_begin(self, record: Optional[Dict[str, float]]):
+        """Fold ``CheckpointEngine.save_begin`` in."""
+        self._fold(record, SAVE_BEGIN_FIELDS)
 
     def set_startup(self, record: Optional[Dict[str, float]]):
         """Fold ``distributed.startup_record()`` in."""
@@ -324,12 +337,19 @@ class PipelineStats:
             if self.startup_backend_s
             else ""
         )
+        skips = (
+            f", {self.save_skips} saves skipped on a busy shard lock "
+            f"({self.lock_local_answers} answered by its mirror, "
+            f"{self.begin_lock_s * 1e3:.1f} ms asking over all due saves)"
+            if self.save_skips
+            else ""
+        )
         return (
             f"prefetch {self.prefetch_hits}h/{self.prefetch_misses}m"
             f" ({'-' if ov is None else ov}% overlap), "
             f"staged {self.stage_bytes >> 20} MiB in {self.stage_chunks} "
             f"chunks ({self.stage_block_s * 1e3:.1f} ms on critical "
-            f"path, {self.stage_commits} commits), donated "
+            f"path, {self.stage_commits} commits{skips}), donated "
             f"{self.donated_bytes >> 20} MiB over {self.donated_steps} "
             f"steps ({self.safe_steps} safe, {self.steps_ahead} dispatched "
             f"ahead of the device){resize}{gsync}{restore}{startup}"
@@ -339,6 +359,7 @@ class PipelineStats:
 RESTORE_FIELDS = tuple(
     f.name for f in fields(PipelineStats) if f.name.startswith("restore_")
 )
+SAVE_BEGIN_FIELDS = ("save_skips", "begin_lock_s", "lock_local_answers")
 STARTUP_FIELDS = tuple(
     f.name for f in fields(PipelineStats)
     if f.name.startswith(("startup_", "recover_"))

@@ -568,6 +568,13 @@ class CheckpointEngine:
         self._active_stager = None
         # phases of the last ``load`` (None until one ran)
         self.last_restore: Optional[Dict[str, float]] = None
+        # the shard lock's side of every memory save that fell due
+        # (``_take_shard_lock``): seconds asking for it, the saves
+        # skipped because the saver held it, and how many of those the
+        # lock's mirror answered without a request to the agent
+        self.save_begin: Dict[str, float] = {
+            "begin_lock_s": 0.0, "save_skips": 0, "lock_local_answers": 0,
+        }
         if self._agent_mode:
             self._shm = ShmHandler(self.local_rank, create=False)
             self._queue = SharedQueue(saver_mod.CKPT_EVENT_QUEUE)
@@ -604,7 +611,7 @@ class CheckpointEngine:
         # persisting, so shm can never be overwritten before it is safe on
         # storage — a save issued while the saver is busy is skipped, never
         # blocked on.
-        if not self._lock.acquire(blocking=False):
+        if not self._take_shard_lock():
             logger.warning(
                 f"step {step}: saver busy persisting a previous checkpoint; "
                 f"skipping this save"
@@ -649,9 +656,7 @@ class CheckpointEngine:
         (default ``transfer_sched.DEFAULT_STRIPE_MIN_BYTES``)."""
         if self._agent_mode:
             assert self._lock and self._shm and self._queue
-            with span("ckpt_begin_lock"):
-                got = self._lock.acquire(blocking=False)
-            if not got:
+            if not self._take_shard_lock():
                 logger.warning(
                     f"step {step}: saver busy persisting a previous "
                     f"checkpoint; skipping this chunked save"
@@ -672,6 +677,19 @@ class CheckpointEngine:
             )
         self._active_stager = stager
         return stager
+
+    def _take_shard_lock(self) -> bool:
+        """The non-blocking acquire a memory save begins with, whole under
+        the ``ckpt_begin_lock`` span. While the saver holds the lock the
+        answer comes from the lock's mirror (``SharedLock``), not from
+        the agent, whose interpreter is busy persisting just then."""
+        rec = self.save_begin
+        with TimedSpan(rec, "begin_lock_s", name="ckpt_begin_lock"):
+            got = self._lock.acquire(blocking=False)
+        if not got:
+            rec["save_skips"] += 1
+            rec["lock_local_answers"] = self._lock.local_answers
+        return got
 
     def staging_in_flight(self) -> bool:
         """True while ANY staging still reads state buffers — a

@@ -12,10 +12,20 @@ Design: every named primitive is hosted by the process that creates it with
 ``create=True`` (a daemon thread serves requests on a unix socket); any
 process on the host attaches with ``create=False``. Requests are
 length-prefixed pickled tuples ``(method, args)``.
+
+One answer needs no request. The process that hosts a ``SharedLock`` also
+writes the lock's state (free / held, and its own pid) into a few bytes of
+named shared memory, the lock's *mirror*, in the same place as every
+transition of the real lock. A client's non-blocking acquire reads the
+mirror first and returns False at once where it reads *held*; everything
+else, taking the lock included, is still a request. The flash save that
+falls due while the agent's saver persists the last one is skipped that
+way, without waiting for a turn of the busy agent's interpreter.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import pickle
 import queue
@@ -181,39 +191,129 @@ class LocalSocketComm:
         )
 
 
+# the mirror of a SharedLock: one state byte and the hosting process's pid
+_MIRROR = struct.Struct("<B3xI")
+_FREE, _HELD = 0, 1
+
+
 class SharedLock(LocalSocketComm):
-    """Named lock usable across processes (parity: multi_process.py:234)."""
+    """Named lock usable across processes (parity: multi_process.py:234).
+
+    The real lock is a ``threading.Lock`` in the hosting process, and
+    every transition of it runs there (``_do_acquire``, ``_do_release``,
+    ``_do_force_release``). The host mirrors the state into a named shm
+    segment of ``_MIRROR.size`` bytes beside the socket (made with the
+    server, unlinked in ``close()``): *held* is written after the lock
+    was taken, *free* before it is let go, both under ``_transition``,
+    so the mirror never reads *held* over a free lock. Only the host
+    writes it. A client's ``acquire(blocking=False)`` reads it first:
+    *held* by a host that is alive is the answer the request would have
+    brought, a failed non-blocking acquire having no side effect;
+    *free*, or a mirror that is missing, short or unreadable, means ask.
+    Taking the lock is always a request. ``local_answers`` counts the
+    acquires this client answered from the mirror."""
 
     def __init__(self, name: str, create: bool = False):
         self._lock = threading.Lock() if create else None
         self._owner: Optional[str] = None
+        # serialises a release with the mirror's write and with other
+        # releases (host only)
+        self._transition = threading.Lock()
+        self._mirror: Optional[SharedMemory] = None
+        self.local_answers = 0
+        # named from the socket's path: whoever finds this server's
+        # socket finds its mirror, and no other job's or node's
+        digest = hashlib.sha1(_socket_path(name).encode()).hexdigest()[:12]
+        self._mirror_name = f"dlrover_tpu_lock_{name}_{digest}"
+        if create:
+            # a segment left by a killed host is taken over, and reads
+            # free from here on: the lock it mirrored died with it
+            self._mirror = create_shared_memory(
+                self._mirror_name, _MIRROR.size
+            )
+            self._publish(_FREE)
         super().__init__(name, create)
+
+    # -- host side: the transitions, each with the mirror's write ------
+    def _publish(self, state: int):
+        if self._mirror is not None:
+            _MIRROR.pack_into(self._mirror.buf, 0, state, os.getpid())
 
     def _do_acquire(self, blocking: bool, owner: str) -> bool:
         got = self._lock.acquire(blocking=blocking, timeout=30 if blocking else -1)
         if got:
-            self._owner = owner
+            with self._transition:
+                self._owner = owner
+                # what the lock is now, not what this thread did: a
+                # force_release may have come between
+                self._publish(_HELD if self._lock.locked() else _FREE)
         return got
 
-    def _do_release(self, owner: str) -> bool:
-        if self._owner == owner and self._lock.locked():
+    def _let_go(self) -> bool:
+        """Free the lock if it is held; the mirror goes first. Under
+        ``_transition``."""
+        held = self._lock.locked()
+        self._publish(_FREE)
+        if held:
             self._owner = None
             self._lock.release()
-            return True
-        return False
+        return held
+
+    def _do_release(self, owner: str) -> bool:
+        with self._transition:
+            return self._owner == owner and self._let_go()
 
     def _do_locked(self) -> bool:
         return self._lock.locked()
 
     def _do_force_release(self) -> bool:
-        if self._lock.locked():
-            self._owner = None
-            self._lock.release()
-            return True
-        return False
+        with self._transition:
+            return self._let_go()
+
+    def close(self):
+        super().close()
+        if self._create:
+            # a client that still maps the segment must not read a lock
+            # that is gone as held
+            self._publish(_FREE)
+        mirror, self._mirror = self._mirror, None
+        if mirror is not None:
+            if self._create:
+                mirror.unlink()
+            mirror.close()
+
+    # -- client side ---------------------------------------------------
+    def _mirror_reads_held(self) -> bool:
+        """True only where the mirror says *held* and its host is alive."""
+        if self._mirror is None:
+            self._mirror = attach_shared_memory(self._mirror_name)
+            if self._mirror is None:
+                return False
+        try:
+            state, pid = _MIRROR.unpack_from(self._mirror.buf, 0)
+            if state != _HELD:
+                return False
+            os.kill(pid, 0)
+        except (struct.error, OSError):
+            # too short to hold a state, or the host that wrote *held*
+            # is gone (or not ours to see): ask
+            return False
+        return True
 
     def acquire(self, blocking: bool = True) -> bool:
-        return self._call("acquire", blocking, self._owner_id())
+        if blocking or self._create:
+            return self._call("acquire", blocking, self._owner_id())
+        if self._mirror_reads_held():
+            self.local_answers += 1
+            return False
+        got = self._call("acquire", False, self._owner_id())
+        if not got and self._mirror is not None:
+            # the mirror did not say what the host said: a transition in
+            # between, or the segment of a host that has closed since.
+            # Look it up again next time
+            self._mirror.close()
+            self._mirror = None
+        return got
 
     def release(self) -> bool:
         return self._call("release", self._owner_id())

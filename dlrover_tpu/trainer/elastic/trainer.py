@@ -951,9 +951,16 @@ class ElasticTrainer:
     def save(self, storage: StorageType = StorageType.MEMORY) -> bool:
         if self._ckptr is None:
             return False
-        return self._ckptr.save_checkpoint(
+        ok = self._ckptr.save_checkpoint(
             self.global_step, self._ckpt_state(), storage
         )
+        self._fold_save_begin()
+        return ok
+
+    def _fold_save_begin(self):
+        """The shard lock's side of the save just asked for, as the
+        engine counted it, into ``pipeline_stats``."""
+        self.pipeline_stats.set_save_begin(self._ckptr.engine.save_begin)
 
     # -- eviction grace-window drain -----------------------------------
     def set_event_reporter(self, reporter: Callable[[str, str], None]):
@@ -1093,6 +1100,7 @@ class ElasticTrainer:
                         chunk_bytes=self.tcfg.stage_chunk_mb << 20,
                         priority=transfer_sched.Priority.EMERGENCY,
                     )
+                    self._fold_save_begin()
                     if stager is not None:
                         # leave a commit-sized margin before the deadline
                         while (
@@ -1367,6 +1375,7 @@ class ElasticTrainer:
                 snapshot,
                 chunk_bytes=self.tcfg.stage_chunk_mb << 20,
             )
+            self._fold_save_begin()
 
     # -- elastic resize (fast path) ------------------------------------
     def _strategy_for_exact(self, n_devices: int) -> Optional[Strategy]:

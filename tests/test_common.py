@@ -137,6 +137,204 @@ class TestIPC:
         assert not lock.force_release()  # idempotent on unlocked
         lock.close()
 
+    # -- the lock's mirror: a "no" that needs no request -----------------
+    @staticmethod
+    def _calls(monkeypatch, client):
+        """Count the requests ``client`` makes from here on."""
+        made = []
+        real = client._call
+
+        def counted(method, *args, **kw):
+            made.append(method)
+            return real(method, *args, **kw)
+
+        monkeypatch.setattr(client, "_call", counted)
+        return made
+
+    @staticmethod
+    def _mirror(lock):
+        """``(state, pid)`` as a client that attaches now reads them."""
+        from dlrover_tpu.common.multi_process import _MIRROR
+
+        shm = attach_shared_memory(lock._mirror_name)
+        if shm is None:
+            return None
+        try:
+            return _MIRROR.unpack_from(shm.buf, 0)
+        finally:
+            shm.close()
+
+    def test_held_lock_says_no_without_a_request(self, monkeypatch):
+        lock = SharedLock("test-m-held", create=True)
+        holder = SharedLock("test-m-held", create=False)
+        client = SharedLock("test-m-held", create=False)
+        assert holder.acquire(blocking=False)
+        made = self._calls(monkeypatch, client)
+        # no connection either: a request would hang on a dead socket
+        monkeypatch.setattr(client, "_path", client._path + ".nowhere")
+        t0 = time.perf_counter()
+        assert client.acquire(blocking=False) is False
+        assert client.acquire(blocking=False) is False
+        assert time.perf_counter() - t0 < 0.05
+        assert made == [] and client.local_answers == 2
+        assert lock.locked()  # a failed try has no side effect
+        lock.close()
+
+    def test_free_lock_is_taken_through_the_request(self, monkeypatch):
+        lock = SharedLock("test-m-free", create=True)
+        client = SharedLock("test-m-free", create=False)
+        made = self._calls(monkeypatch, client)
+        assert client.acquire(blocking=False) is True
+        assert made == ["acquire"] and client.local_answers == 0
+        assert lock.locked() and lock._owner == client._owner_id()
+        assert client.release() and not lock.locked()
+        lock.close()
+
+    def test_blocking_acquire_always_asks(self, monkeypatch):
+        import threading
+
+        lock = SharedLock("test-m-block", create=True)
+        client = SharedLock("test-m-block", create=False)
+        assert lock.acquire(blocking=False)
+        made = self._calls(monkeypatch, client)
+        t = threading.Timer(0.2, lock.force_release)
+        t.start()
+        assert client.acquire(blocking=True) is True  # waited it out
+        t.join(timeout=5)
+        assert made == ["acquire"] and client.local_answers == 0
+        lock.close()
+
+    @pytest.mark.parametrize(
+        "after",
+        ["created", "acquire", "release", "force_release", "recreated",
+         "recreated_after_kill", "close"],
+    )
+    def test_mirror_follows_the_lock(self, after):
+        from dlrover_tpu.common.multi_process import _FREE, _HELD
+
+        name = "test-m-" + after
+        lock = SharedLock(name, create=True)
+        client = SharedLock(name, create=False)
+        me = os.getpid()
+        if after == "created":
+            assert self._mirror(lock) == (_FREE, me)
+        elif after == "acquire":
+            assert client.acquire(blocking=False)
+            assert self._mirror(lock) == (_HELD, me)
+        elif after == "release":
+            assert client.acquire(blocking=False) and client.release()
+            assert self._mirror(lock) == (_FREE, me)
+            assert not lock.release()  # not held: still free
+            assert self._mirror(lock) == (_FREE, me)
+        elif after == "force_release":
+            assert client.acquire(blocking=False)
+            assert client.force_release()
+            assert self._mirror(lock) == (_FREE, me)
+            # a mirror gone wrong is repaired by the next force_release
+            lock._publish(_HELD)
+            assert not lock.force_release()
+            assert self._mirror(lock) == (_FREE, me)
+        elif after == "recreated":
+            # the host closes holding the lock; a new one takes the name
+            assert client.acquire(blocking=False)
+            assert client.acquire(blocking=False) is False  # maps the mirror
+            lock.close()
+            lock = SharedLock(name, create=True)
+            assert self._mirror(lock) == (_FREE, me)
+            # the client still maps the segment the old host unlinked: it
+            # reads free there, asks, and takes the new host's lock
+            assert client.acquire(blocking=False) and lock.locked()
+            assert self._mirror(lock) == (_HELD, me)
+        elif after == "recreated_after_kill":
+            # a killed host unlinks nothing: its segment says held
+            assert client.acquire(blocking=False)
+            lock._mirror = None  # what close() would have unlinked
+            lock.close()
+            lock = SharedLock(name, create=True)
+            assert self._mirror(lock) == (_FREE, me)
+            assert client.acquire(blocking=False) and lock.locked()
+        elif after == "close":
+            assert client.acquire(blocking=False)
+            assert client.acquire(blocking=False) is False
+            lock.close()
+            # unlinked, and free for whoever still maps it
+            assert self._mirror(lock) is None
+            assert not client._mirror_reads_held()
+        client.close()
+        lock.close()
+
+    @pytest.mark.parametrize("mirror", ["missing", "truncated", "dead_host"])
+    def test_unreadable_mirror_means_ask(self, mirror, monkeypatch):
+        from dlrover_tpu.common.multi_process import _HELD, _MIRROR
+
+        name = "test-m-" + mirror
+        lock = SharedLock(name, create=True)
+        client = SharedLock(name, create=False)
+        assert lock.acquire(blocking=False)
+        if mirror == "dead_host":
+            child = mp.get_context("spawn").Process(target=int)
+            child.start()
+            child.join(timeout=30)
+            assert child.exitcode == 0
+            _MIRROR.pack_into(lock._mirror.buf, 0, _HELD, child.pid)
+        else:
+            lock._mirror.unlink()
+            if mirror == "truncated":
+                short = SharedMemory(lock._mirror_name, create=True, size=1)
+                short.buf[0] = _HELD
+        made = self._calls(monkeypatch, client)
+        assert client.acquire(blocking=False) is False  # the host's answer
+        assert made == ["acquire"] and client.local_answers == 0
+        assert lock.force_release()
+        assert client.acquire(blocking=False) is True
+        assert made == ["acquire", "acquire"]
+        if mirror == "truncated":
+            short.close()
+        client.close()
+        lock.close()
+
+    def test_mirror_never_reads_held_over_a_free_lock(self):
+        """Acquires and releases from more threads than cores: whenever
+        the lock is at rest the mirror says what it is."""
+        import sys
+        import threading
+
+        from dlrover_tpu.common.multi_process import _FREE, _HELD
+
+        lock = SharedLock("test-m-stress", create=True)
+        stop = threading.Event()
+
+        def taker(owner):
+            while not stop.is_set():
+                if lock._do_acquire(False, owner):
+                    lock._do_release(owner)
+
+        def breaker():
+            while not stop.is_set():
+                lock._do_force_release()
+
+        n = 2 * (os.cpu_count() or 4)
+        threads = [
+            threading.Thread(target=taker, args=(f"o{i}",)) for i in range(n)
+        ] + [threading.Thread(target=breaker) for _ in range(4)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            time.sleep(1.0)
+            stop.set()
+            for t in threads:
+                t.join(timeout=30)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert not lock.locked()
+        assert self._mirror(lock) == (_FREE, os.getpid())
+        assert lock._do_acquire(False, "last")
+        assert self._mirror(lock) == (_HELD, os.getpid())
+        lock.close()
+
     def test_server_exists_probes_liveness(self):
         from dlrover_tpu.common.multi_process import (
             _socket_path,

@@ -24,6 +24,12 @@ from dlrover_tpu.ckpt.sharding import (
     restore_state,
 )
 from dlrover_tpu.ckpt.shm_handler import ShmHandler
+from dlrover_tpu.common.multi_process import (
+    _FREE,
+    _HELD,
+    _MIRROR,
+    attach_shared_memory,
+)
 
 
 @pytest.fixture
@@ -43,6 +49,82 @@ def _sharded_state(mesh_axis="x"):
     w = jax.device_put(jnp.arange(16.0).reshape(16), sharding)
     b = jnp.ones((3,))  # replicated
     return {"w": w, "b": b, "step": 7}
+
+
+def _mirror_state(lock):
+    """What a client that attaches now reads of ``lock``'s state."""
+    shm = attach_shared_memory(lock._mirror_name)
+    try:
+        return _MIRROR.unpack_from(shm.buf, 0)[0]
+    finally:
+        shm.close()
+
+
+def _begin(eng, how, step, ckpt_dir):
+    """Ask ``eng`` for a memory save of ``step``; True when it began."""
+    state = {"w": np.arange(8.0)}
+    if how == "save_to_memory":
+        return eng.save_to_memory(step, state, ckpt_dir)
+    return eng.begin_chunked_save(step, state, ckpt_dir) is not None
+
+
+class TestSkippedSaveAsksNobody:
+    """A memory save that falls due while the saver holds the shard lock
+    is skipped from the lock's mirror, whatever the agent is busy with."""
+
+    @pytest.mark.parametrize("how", ["begin_chunked_save", "save_to_memory"])
+    def test_skip_does_not_wait_for_a_stalled_agent(
+        self, how, saver, tmp_path, monkeypatch
+    ):
+        lock = saver._shard_locks[0]
+        answer = lock._do_acquire
+
+        def stalled(blocking, owner):
+            time.sleep(1.0)  # the agent's interpreter, mid-persist
+            return answer(blocking, owner)
+
+        monkeypatch.setattr(lock, "_do_acquire", stalled)
+        eng = CheckpointEngine()
+        # the saver holds the lock over a persist (the handoff's far end)
+        assert answer(False, "saver")
+        for skipped in (1, 2):
+            t0 = time.perf_counter()
+            assert _begin(eng, how, 10 * skipped, str(tmp_path)) is False
+            assert time.perf_counter() - t0 < 0.05
+            assert eng.save_begin["save_skips"] == skipped
+            assert eng.save_begin["lock_local_answers"] == skipped
+        assert lock.locked() and lock._owner == "saver"  # untouched
+        # the persist ends: the next due save asks, waits its turn, begins
+        assert lock.force_release()
+        t0 = time.perf_counter()
+        assert _begin(eng, how, 30, str(tmp_path)) is True
+        assert time.perf_counter() - t0 >= 1.0
+        assert lock.locked()
+        assert eng.save_begin["save_skips"] == 2
+        assert eng.save_begin["lock_local_answers"] == 2
+        assert eng.save_begin["begin_lock_s"] >= 1.0
+        eng.wait_staging()
+
+    @pytest.mark.parametrize("how", ["begin_chunked_save", "save_to_memory"])
+    def test_begin_lock_span_holds_the_whole_decision(
+        self, how, saver, tmp_path
+    ):
+        from dlrover_tpu.obs.trace import get_tracer
+
+        tracer = get_tracer()
+        tracer.reset()
+        eng = CheckpointEngine()
+        assert _begin(eng, how, 1, str(tmp_path)) is True  # asked
+        eng.wait_staging()
+        assert _begin(eng, how, 2, str(tmp_path)) is False  # mirror
+        spans = [r for r in tracer.drain(0)[0] if r[0] == "ckpt_begin_lock"]
+        tracer.reset()
+        assert len(spans) == 2
+        seconds = sum(r[3] for r in spans) / 1e9
+        assert eng.save_begin["begin_lock_s"] == pytest.approx(
+            seconds, abs=2e-3
+        )
+        assert eng.save_begin["save_skips"] == 1
 
 
 class TestShardRecords:
@@ -286,6 +368,27 @@ class TestAdviceFixes:
         saver.reset_shared_memory()
         assert eng._lock.acquire(blocking=False)
         eng._lock.force_release()
+
+    def test_reset_after_a_dead_worker_leaves_a_free_mirror(
+        self, saver, tmp_path
+    ):
+        # a worker killed between its begin and its commit: lock held,
+        # mirror held, nothing to persist
+        dead = CheckpointEngine()
+        state = {"w": jnp.arange(8.0)}
+        assert dead.begin_chunked_save(3, state, str(tmp_path)) is not None
+        lock = saver._shard_locks[0]
+        assert lock.locked() and _mirror_state(lock) == _HELD
+        saver.reset_shared_memory()
+        assert not lock.locked() and _mirror_state(lock) == _FREE
+        # the next incarnation attaches to the same names, and its save
+        # begins: asked for, not answered from a stale mirror
+        eng = CheckpointEngine()
+        stager = eng.begin_chunked_save(4, state, str(tmp_path))
+        assert stager is not None and _mirror_state(lock) == _HELD
+        assert eng.save_begin["save_skips"] == 0
+        stager.abort()
+        assert _mirror_state(lock) == _FREE
 
     def test_step_agreement_single_process(self, saver):
         eng = CheckpointEngine()

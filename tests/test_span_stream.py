@@ -31,6 +31,7 @@ import pytest
 
 from dlrover_tpu.accel.profiler import (
     RESTORE_FIELDS,
+    SAVE_BEGIN_FIELDS,
     STARTUP_FIELDS,
     PipelineStats,
     compile_meter,
@@ -217,6 +218,47 @@ def test_chunked_save_names_the_phases_of_a_chunk(saver, tmp_path, tracer):
     named = sum(p[2] - p[1] for p in phases) / 1e9
     assert named <= stats.stage_block_s
     assert named >= 0.95 * stats.stage_block_s, (named, stats.stage_block_s)
+
+
+@pytest.mark.parametrize("lock", ["held", "free"])
+def test_skipped_saves_reach_the_stats_and_the_gauges(
+    lock, saver, tmp_path, tracer
+):
+    """Saves fall due at steps 4, 8 and 12. While somebody holds the shard
+    lock (the saver, over a persist) each is skipped, counted, and answered
+    by the lock's mirror; with the lock free none is."""
+    if lock == "held":
+        assert saver._shard_locks[0]._do_acquire(False, "saver")
+    trainer, stats = _train(
+        tmp_path, 14, ckpt_dir=str(tmp_path / "ckpt"),
+        save_memory_interval=4, save_storage_interval=10**9,
+        stage_chunk_mb=1, stage_budget_ms=0.0,
+    )
+    skips = stats["save_skips"]
+    if lock == "held":
+        assert skips == stats["lock_local_answers"] == 3
+    else:
+        # the saver's own persist of a save may still skip the next one
+        assert stats["lock_local_answers"] <= skips < 3
+    assert stats["stage_commits"] == 3 - skips
+    recs = _records(tracer, tid=threading.get_ident())
+    asked = [r for r in recs if r[0] == "ckpt_begin_lock"]
+    saves = [r for r in recs if r[0] == "ckpt_save"]
+    assert len(asked) == 3
+    assert all(any(_inside(a, s) for s in saves) for a in asked)
+    assert stats["begin_lock_s"] == pytest.approx(
+        sum(a[2] - a[1] for a in asked) / 1e9, abs=1e-3
+    )
+    assert ("saves skipped on a busy shard lock"
+            in trainer.pipeline_stats.summary()) == bool(skips)
+    registry = MetricsRegistry()
+    fold_pipeline_stats(trainer.pipeline_stats, registry)
+    gauges = registry.scalars()
+    for field in SAVE_BEGIN_FIELDS:
+        assert gauges["dlrover_pipeline_" + field] == pytest.approx(stats[field])
+    assert SAVE_BEGIN_FIELDS == (
+        "save_skips", "begin_lock_s", "lock_local_answers"
+    )
 
 
 # -- 3. the mirror ----------------------------------------------------------
@@ -683,8 +725,10 @@ def test_new_per_layer_entries_match_their_files():
         with open(path) as f:
             cells[w["name"]] = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"]}
-    # the twelve are the last twelve, in no other entry's place
-    assert {m["name"] for m in bench["per_layer"][-12:]} == set(READERS)
+    # the twelve stand together, in no other entry's place
+    names = [m["name"] for m in bench["per_layer"]]
+    first = min(names.index(metric) for metric in READERS)
+    assert set(names[first:first + 12]) == set(READERS)
     for metric in READERS:
         entry, mod = entries[metric], _reader(metric)
         assert (mod.LAYER, mod.UNIT, mod.MOVES) == (
@@ -694,6 +738,38 @@ def test_new_per_layer_entries_match_their_files():
         assert entry["better"] == "lower" and entry["moves"] == "setup_s"
         taken = [name for name, cell in cells.items() if mod.CELLS(cell)]
         assert taken == entry.get("workloads", list(cells))
+
+
+def test_begin_lock_reader_takes_the_mean_of_the_due_saves():
+    """``ckpt.begin_lock_ms_per_due_save`` (ISSUE 42): every
+    ``ckpt_begin_lock`` span of the window, begun or skipped, and nothing
+    where the stream holds none."""
+    mod = _reader("ckpt.begin_lock_ms_per_due_save")
+    spans = [
+        ["step", 0, 100_000_000, 0, 1],
+        ["ckpt_begin_lock", 10, 1_200_000_000, 2, 1],  # a slow "no"
+        ["ckpt_begin_plan", 20, 5_000_000, 2, 1],
+        ["ckpt_begin_lock", 30, 1_000_000, 2, 1],
+        ["ckpt_begin_lock", 40, 2_000_000, 2, 1],
+    ]
+    run = types.SimpleNamespace(spans=spans)
+    assert mod.read(run) == pytest.approx(401.0)
+    assert mod.read(types.SimpleNamespace(spans=spans[:1])) is None
+    assert mod.read(types.SimpleNamespace(spans=[])) is None
+    assert mod.CELLS({"save_memory_interval": 50, "max_steps": 800}) is True
+    assert mod.CELLS({"save_memory_interval": 10**9, "max_steps": 800}) is False
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert bench["per_layer"][-1] == {
+        "name": "ckpt.begin_lock_ms_per_due_save", "unit": mod.UNIT,
+        "better": "lower", "source": "program_span", "layer": mod.LAYER,
+        "moves": mod.MOVES, "workloads": ["gpt2-124m.save-kill-resume"],
+    }
+    for w in bench["workloads"]:
+        path = os.path.join(REPO, "benchmark", "cells", w["name"] + ".json")
+        with open(path) as f:
+            taken = mod.CELLS(json.load(f))
+        assert taken == (w["name"] == "gpt2-124m.save-kill-resume")
 
 
 # -- 7. the telemetry writer's race -----------------------------------------

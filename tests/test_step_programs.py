@@ -132,16 +132,19 @@ def test_rebuild_drops_the_executable(accel):
 # the parent's keys (PR 28), less the one counter PR 29 deleted with its
 # code, the fused attention tally's four (PR 36) and the held experts'
 # share of the routing (PR 37), the streaming attention tally's four
-# (PR 38), an incarnation's way up and the restart behind it (PR 40)
+# (PR 38), an incarnation's way up and the restart behind it (PR 40), the
+# shard lock's side of the due saves (PR 42)
 AS_DICT_KEYS = [
     "attn_square_sites", "attn_stream_blocks_rect",
     "attn_stream_blocks_walked", "attn_stream_rect_sites",
     "attn_stream_tri_sites", "attn_tiles_square", "attn_tiles_walked",
-    "attn_tri_sites", "comm_overlap_pct", "compile_cache_hit_pct", "compile_cache_hits",
+    "attn_tri_sites", "begin_lock_s", "comm_overlap_pct",
+    "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "donated_bytes", "donated_steps",
     "grad_bytes_raw", "grad_bytes_wire", "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",
-    "grad_sync_ms", "grad_sync_path", "moe_drop_rate_sum",
+    "grad_sync_ms", "grad_sync_path", "lock_local_answers",
+    "moe_drop_rate_sum",
     "moe_held_share_sum", "moe_max_load_sum", "moe_reports", "opt_q8_blocks_elems",
     "opt_q8_tiles_elems", "overlap_pct_measured", "prefetch_hits",
     "prefetch_misses", "prefetch_overlap_pct", "prefetch_reprimes",
@@ -152,6 +155,7 @@ AS_DICT_KEYS = [
     "restore_agree_s", "restore_bytes", "restore_h2d_s",
     "restore_lock_wait_s", "restore_shm_verify_s", "restore_source",
     "restore_storage_read_s", "restore_storage_verify_s", "safe_steps",
+    "save_skips",
     "stage_backlog_bytes", "stage_block_s", "stage_bytes", "stage_chunks",
     "stage_commits", "startup_backend_s", "startup_cache_misses",
     "startup_compile_s", "startup_first_step_s", "startup_import_s",
@@ -169,7 +173,7 @@ ROUNDED = {
     "startup_import_s": 4, "startup_backend_s": 4,
     "startup_first_step_s": 4, "startup_compile_s": 4,
     "recover_detect_tick_s": 4, "recover_persist_s": 4,
-    "recover_respawn_s": 4,
+    "recover_respawn_s": 4, "begin_lock_s": 4,
 }
 
 

@@ -138,6 +138,13 @@ class PipelineStats:
     attn_stream_rect_sites: int = 0
     attn_stream_blocks_walked: int = 0
     attn_stream_blocks_rect: int = 0
+    # Gated DeltaNet mixers (ops/gated_delta.py ``GdnTally``) in the
+    # train step program this process traced last, and the sequential
+    # chunk-state steps one training step runs through them, forward and
+    # backward: the step's serial depth in that layer kind. Set when the
+    # trainer logs the step it built; 0 / 0 for a model without the kind
+    gdn_sites: int = 0
+    gdn_chunk_steps: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was

@@ -12,9 +12,10 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Optional, Tuple
 
-# layer_pattern's alphabet (nemotron_h's) -> the key of the layer's one
-# mixer in the parameter tree: Mamba-2, attention, experts
-LAYER_KINDS = {"M": "ssm", "*": "attn", "E": "moe"}
+# layer_pattern's alphabet (nemotron_h's, and "G") -> the key of the
+# layer's one mixer in the parameter tree: Mamba-2, Gated DeltaNet,
+# attention, experts
+LAYER_KINDS = {"M": "ssm", "G": "gdn", "*": "attn", "E": "moe"}
 
 
 @dataclass(frozen=True)
@@ -31,8 +32,10 @@ class TransformerConfig:
     max_seq_len: int = 1024
     # the kind of every layer, one character a layer, in the alphabet of
     # the ``nemotron_h`` configs (``hybrid_override_pattern``): "M" a
-    # Mamba-2 layer, "*" an attention layer, "E" an expert layer; each
-    # layer is ONE mixer, ``x + mixer(norm(x))``. "" = every layer is the
+    # Mamba-2 layer, "*" an attention layer, "E" an expert layer, and
+    # "G" a Gated DeltaNet layer; each layer is ONE mixer, ``x +
+    # mixer(norm(x))``, so a block of mixer then experts is two entries
+    # ("GEGEGE*E": one period of ``qwen3_next``). "" = every layer is the
     # attention + FFN block (``moe_every`` places the experts).
     layer_pattern: str = ""
     # architecture switches
@@ -41,14 +44,28 @@ class TransformerConfig:
     # attention layers of a Mamba-2 hybrid: the scan carries the order)
     positions: str = ""
     rope_theta: float = 10000.0
+    # the leading dims of each head that are rotated (pairs ``(i, i +
+    # rope_dim / 2)``), the rest passing untouched; 0 => the whole head
+    rope_dim: int = 0
     rmsnorm: bool = False
+    # how an RMSNorm's weight enters (the residual stream's norms, the
+    # final norm and a head's q / k norm): "" => ``x * w``, w from 1;
+    # "one_plus" => ``x * (1 + w)``, w from 0 (zero-centred: weight
+    # decay pulls the scale to 1 and not to 0)
+    norm_weight: str = ""
     swiglu: bool = False
     tie_embeddings: bool = True
     # eps of every norm; None => 1e-6 under rmsnorm, 1e-5 under LayerNorm
     norm_eps: Optional[float] = None
-    # RMSNorm over each token's whole query and key projections, all
-    # heads together, before RoPE (OLMoE's QK-norm)
+    # RMSNorm of the query and key projections before RoPE, and what one
+    # mean square spans: "token" => a token's whole projection, all heads
+    # together, a weight a head and dim (OLMoE's QK-norm); "head" =>
+    # each head's own width, one weight vector for all heads
     qk_norm: bool = False
+    qk_norm_span: str = "token"
+    # "sigmoid" => the query projection is twice as wide, a head's
+    # second half a gate: ``attention * sigmoid(gate)`` before ``wo``
+    attn_gate: str = ""
     # MoE: every `moe_every`-th block uses an expert FFN (SwiGLU experts
     # where `swiglu`, the GELU pair where not)
     num_experts: int = 0
@@ -86,8 +103,10 @@ class TransformerConfig:
     # weight of the load-balance loss; None => ``loss_fn``'s argument
     router_balance_weight: Optional[float] = None
     # width of the one shared expert every token passes beside its
-    # routed ones; 0 = none
+    # routed ones (SwiGLU where ``swiglu``, as they are); 0 = none
     shared_expert_dim: int = 0
+    # "sigmoid" => its output times ``sigmoid(x . w)``, a scalar a token
+    shared_expert_gate: str = ""
     # "" => SwiGLU where ``swiglu``, else the GELU pair; "relu2" =>
     # the ungated pair with relu(x)^2 (expert and shared-expert FFNs)
     mlp_activation: str = ""
@@ -108,6 +127,16 @@ class TransformerConfig:
     ssm_dt_min: float = 1e-3
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
+    # Gated DeltaNet layers ("G"): value heads of ``gdn_value_dim``, each
+    # ``gdn_value_heads / gdn_key_heads`` of them reading one key head of
+    # ``gdn_key_dim``; the causal convolution's taps and the chunk of the
+    # chunked delta rule (ops/gated_delta.py)
+    gdn_value_heads: int = 0
+    gdn_key_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    gdn_chunk: int = 64
     # sequence-parallel attention scheme when the mesh has sp > 1:
     # "ring" (P2P pipeline, any head count) or "ulysses" (two
     # all-to-alls; needs (heads/tp) % sp == 0) — parallel/{ring_
@@ -154,6 +183,33 @@ class TransformerConfig:
             raise ValueError(f"unknown positions {self.positions!r}")
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
+        for name, kinds in (
+            ("norm_weight", ("", "one_plus")),
+            ("qk_norm_span", ("token", "head")),
+            ("attn_gate", ("", "sigmoid")),
+            ("shared_expert_gate", ("", "sigmoid")),
+        ):
+            if getattr(self, name) not in kinds:
+                raise ValueError(
+                    f"unknown {name} {getattr(self, name)!r}"
+                )
+        if self.norm_weight and not self.rmsnorm:
+            raise ValueError("norm_weight is of an RMSNorm: rmsnorm is off")
+        if self.rope_dim % 2 or not 0 <= self.rope_dim <= self.head_dim:
+            raise ValueError(
+                f"rope_dim {self.rope_dim} is not an even share of a head "
+                f"of {self.head_dim}"
+            )
+        if "G" in self.layer_pattern and (
+            min(self.gdn_key_heads, self.gdn_key_dim, self.gdn_value_dim) < 1
+            or self.gdn_value_heads % max(self.gdn_key_heads, 1)
+            or self.gdn_value_heads < 1
+        ):
+            raise ValueError(
+                f"Gated DeltaNet layers need gdn_value_heads "
+                f"({self.gdn_value_heads}) a multiple of gdn_key_heads "
+                f"({self.gdn_key_heads}) and both head widths"
+            )
         if self.mlp_activation not in ("", "relu2"):
             raise ValueError(
                 f"unknown mlp_activation {self.mlp_activation!r}"

@@ -31,6 +31,11 @@ from dlrover_tpu.models.config import (
     is_moe_layer,
     num_moe_layers,
 )
+from dlrover_tpu.ops.gated_delta import (
+    gated_delta_logical_axes,
+    gated_delta_mixer,
+    init_gated_delta_params,
+)
 from dlrover_tpu.ops.mamba2 import (
     init_mamba2_params,
     mamba2_logical_axes,
@@ -67,12 +72,26 @@ def init_params(key, cfg: TransformerConfig) -> Params:
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape) * fan_in**-0.5).astype(pd)
 
+    def norm_scale(shape):
+        # a zero-centred norm's weight starts at 0: its scale is 1 + w
+        if cfg.norm_weight == "one_plus":
+            return jnp.zeros(shape, pd)
+        return jnp.ones(shape, pd)
+
+    def qk_norms():
+        # a weight a head and dim, or one vector for all heads
+        a_head = cfg.qk_norm_span == "token"
+        return {
+            "q_norm": {"scale": norm_scale((h, hd) if a_head else (hd,))},
+            "k_norm": {"scale": norm_scale((kvh, hd) if a_head else (hd,))},
+        }
+
     keys = iter(jax.random.split(key, 8 + cfg.num_layers * 16))
     params: Params = {
         "embed": {
             "tokens": dense(next(keys), (cfg.vocab_size, d), d),
         },
-        "final_norm": {"scale": jnp.ones((d,), pd)},
+        "final_norm": {"scale": norm_scale((d,))},
         "layers": [],
     }
     if not cfg.rmsnorm:
@@ -85,8 +104,10 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         params["lm_head"] = dense(next(keys), (d, cfg.vocab_size), d)
 
     def attention():
+        # with an output gate a head's projection is [query | gate]
+        q_width = 2 * hd if cfg.attn_gate else hd
         return {
-            "wq": dense(next(keys), (d, h, hd), d),
+            "wq": dense(next(keys), (d, h, q_width), d),
             "wk": dense(next(keys), (d, kvh, hd), d),
             "wv": dense(next(keys), (d, kvh, hd), d),
             "wo": dense(next(keys), (h, hd, d), h * hd),
@@ -98,35 +119,38 @@ def init_params(key, cfg: TransformerConfig) -> Params:
             gated=cfg.swiglu, held=cfg.experts_held,
             selection_bias=cfg.router == "sigmoid",
             shared_dim=cfg.shared_expert_dim,
+            shared_out_gate=bool(cfg.shared_expert_gate),
         )
 
     mixers = {
         "M": lambda: init_mamba2_params(next(keys), cfg, pd),
+        "G": lambda: init_gated_delta_params(next(keys), cfg, pd),
         "*": attention,
         "E": experts,
     }
     for kind in cfg.layer_pattern:
         # one mixer a layer behind one norm
-        layer = {"norm": {"scale": jnp.ones((d,), pd)}}
+        layer = {"norm": {"scale": norm_scale((d,))}}
         if not cfg.rmsnorm:
             layer["norm"]["bias"] = jnp.zeros((d,), pd)
         layer[LAYER_KINDS[kind]] = mixers[kind]()
+        if kind == "*" and cfg.qk_norm:
+            layer.update(qk_norms())
         params["layers"].append(layer)
 
     # without a pattern every layer is the attention + FFN block
     blocks = 0 if cfg.layer_pattern else cfg.num_layers
     for i in range(blocks):
         layer = {
-            "attn_norm": {"scale": jnp.ones((d,), pd)},
-            "mlp_norm": {"scale": jnp.ones((d,), pd)},
+            "attn_norm": {"scale": norm_scale((d,))},
+            "mlp_norm": {"scale": norm_scale((d,))},
             "attn": attention(),
         }
         if not cfg.rmsnorm:
             layer["attn_norm"]["bias"] = jnp.zeros((d,), pd)
             layer["mlp_norm"]["bias"] = jnp.zeros((d,), pd)
         if cfg.qk_norm:
-            layer["q_norm"] = {"scale": jnp.ones((h, hd), pd)}
-            layer["k_norm"] = {"scale": jnp.ones((kvh, hd), pd)}
+            layer.update(qk_norms())
         if is_moe_layer(cfg, i):
             layer["moe"] = experts()
         elif cfg.swiglu:
@@ -196,14 +220,36 @@ def logical_axes(cfg: TransformerConfig) -> Params:
             bias=(None,) if cfg.router == "sigmoid" else None,
             shared_up=("embed", "mlp") if shared else None,
             shared_down=("mlp", "embed") if shared else None,
+            shared_gate=(
+                ("embed", "mlp") if shared and cfg.swiglu else None
+            ),
+            shared_out_gate=(
+                ("embed",) if shared and cfg.shared_expert_gate else None
+            ),
         )
 
-    mixers = {"M": mamba2_logical_axes, "*": attention, "E": experts}
+    def qk_norms():
+        if cfg.qk_norm_span == "head":
+            return {
+                "q_norm": {"scale": ("head_dim",)},
+                "k_norm": {"scale": ("head_dim",)},
+            }
+        return {
+            "q_norm": {"scale": ("heads", "head_dim")},
+            "k_norm": {"scale": ("kv_heads", "head_dim")},
+        }
+
+    mixers = {
+        "M": mamba2_logical_axes, "G": gated_delta_logical_axes,
+        "*": attention, "E": experts,
+    }
     for kind in cfg.layer_pattern:
         layer = {"norm": {"scale": ("norm",)}}
         if not cfg.rmsnorm:
             layer["norm"]["bias"] = ("norm",)
         layer[LAYER_KINDS[kind]] = mixers[kind]()
+        if kind == "*" and cfg.qk_norm:
+            layer.update(qk_norms())
         axes["layers"].append(layer)
 
     blocks = 0 if cfg.layer_pattern else cfg.num_layers
@@ -217,8 +263,7 @@ def logical_axes(cfg: TransformerConfig) -> Params:
             layer["attn_norm"]["bias"] = ("norm",)
             layer["mlp_norm"]["bias"] = ("norm",)
         if cfg.qk_norm:
-            layer["q_norm"] = {"scale": ("heads", "head_dim")}
-            layer["k_norm"] = {"scale": ("kv_heads", "head_dim")}
+            layer.update(qk_norms())
         if is_moe_layer(cfg, i):
             layer["moe"] = experts()
         elif cfg.swiglu:
@@ -255,12 +300,19 @@ def _norm_eps(cfg: TransformerConfig) -> float:
     return 1e-6 if cfg.rmsnorm else 1e-5
 
 
+def _rms_scale(p, cfg: TransformerConfig):
+    """An RMSNorm's scale in float32 from its weight: the weight itself,
+    or ``1 + w`` where the norms are zero-centred."""
+    scale = p["scale"].astype(jnp.float32)
+    return 1.0 + scale if cfg.norm_weight == "one_plus" else scale
+
+
 def _norm(x, p, cfg: TransformerConfig):
     xf = x.astype(jnp.float32)
     eps = _norm_eps(cfg)
     if cfg.rmsnorm:
         y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-        return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
+        return (y * _rms_scale(p, cfg)).astype(x.dtype)
     mu = jnp.mean(xf, -1, keepdims=True)
     var = jnp.var(xf, -1, keepdims=True)
     y = (xf - mu) * jax.lax.rsqrt(var + eps)
@@ -269,21 +321,29 @@ def _norm(x, p, cfg: TransformerConfig):
 
 
 def _qk_norm(x, p, cfg: TransformerConfig, layout: str = "bthd"):
-    """RMSNorm over a token's WHOLE query (or key) projection, every
-    head together, before RoPE (OLMoE's QK-norm). x: [B,T,H,D] or
-    [B,H,T,D] per layout; the scale is [H,D]."""
-    heads = 1 if layout == "bhtd" else 2
+    """RMSNorm of the query (or key) projection before RoPE. x:
+    [B,T,H,D] or [B,H,T,D] per layout. ``qk_norm_span`` "token": over a
+    token's WHOLE projection, every head together, the scale [H,D]
+    (OLMoE's QK-norm); "head": over each head's own D, the scale [D]."""
     xf = x.astype(jnp.float32)
+    if cfg.qk_norm_span == "head":
+        ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        y = xf * jax.lax.rsqrt(ms + _norm_eps(cfg)) * _rms_scale(p, cfg)
+        return y.astype(x.dtype)
+    heads = 1 if layout == "bhtd" else 2
     ms = jnp.mean(xf * xf, axis=(heads, 3), keepdims=True)
-    scale = p["scale"].astype(jnp.float32)
+    scale = _rms_scale(p, cfg)
     if layout == "bhtd":
         scale = scale[:, None, :]
     return (xf * jax.lax.rsqrt(ms + _norm_eps(cfg)) * scale).astype(x.dtype)
 
 
-def _rope(x, positions, theta: float, layout: str = "bthd"):
-    """Rotate pairs (d, d+D/2). x: [B,T,H,D] or [B,H,T,D] per layout."""
-    half = x.shape[-1] // 2
+def _rope(x, positions, theta: float, layout: str = "bthd", dims: int = 0):
+    """Rotate pairs (d, d+D/2). x: [B,T,H,D] or [B,H,T,D] per layout.
+    ``dims`` (0 = D): only the leading ``dims`` of a head are rotated, in
+    pairs (d, d+dims/2); the rest pass as they are."""
+    rotated = dims or x.shape[-1]
+    half = rotated // 2
     freqs = 1.0 / (
         theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
     )
@@ -294,10 +354,11 @@ def _rope(x, positions, theta: float, layout: str = "bthd"):
     else:
         cos = jnp.cos(ang)[:, :, None, :]
         sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:rotated]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if rotated < x.shape[-1]:
+        parts.append(x[..., rotated:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def _causal_attention(q, k, v, mesh=None, layout: str = "bthd"):
@@ -388,12 +449,14 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
     q = jnp.einsum(proj, h, layer["attn"]["wq"].astype(h.dtype))
     k = jnp.einsum(proj, h, layer["attn"]["wk"].astype(h.dtype))
     v = jnp.einsum(proj, h, layer["attn"]["wv"].astype(h.dtype))
+    if cfg.attn_gate:
+        q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
     if cfg.qk_norm:
         q = _qk_norm(q, layer["q_norm"], cfg, layout)
         k = _qk_norm(k, layer["k_norm"], cfg, layout)
     if cfg.position_kind == "rope":
-        q = _rope(q, positions, cfg.rope_theta, layout)
-        k = _rope(k, positions, cfg.rope_theta, layout)
+        q = _rope(q, positions, cfg.rope_theta, layout, cfg.rope_dim)
+        k = _rope(k, positions, cfg.rope_theta, layout, cfg.rope_dim)
     if cfg.mup_attn_scale is not None:
         # muP 1/d attention: fold the deviation from the kernels' builtin
         # 1/sqrt(d) into q, so flash and ring paths need no new plumbing
@@ -413,6 +476,12 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
             f"unknown sp_scheme {cfg.sp_scheme!r} "
             "(expected 'ring' or 'ulysses')"
         )
+    if cfg.attn_gate:
+        with jax.named_scope("scope/layer/attn/gate"):
+            o = (
+                o.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))
+            ).astype(o.dtype)
     out = "bthk,hkd->btd" if sp else "bhtk,hkd->btd"
     return x + jnp.einsum(out, o, layer["attn"]["wo"].astype(o.dtype))
 
@@ -421,6 +490,12 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
 def _ssm_block(x, layer, cfg: TransformerConfig):
     h = _norm(x, layer["norm"], cfg)
     return x + mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg))
+
+
+@jax.named_scope("scope/layer/gdn")
+def _gdn_block(x, layer, cfg: TransformerConfig):
+    h = _norm(x, layer["norm"], cfg)
+    return x + gated_delta_mixer(h, layer["gdn"], cfg, _norm_eps(cfg))
 
 
 def _zero_aux(cfg: Optional[TransformerConfig] = None):
@@ -624,6 +699,8 @@ def forward(
         """One layer of a ``layer_pattern``: ``x + mixer(norm(x))``."""
         if kind == "M":
             return _ssm_block(x, layer, cfg), None
+        if kind == "G":
+            return _gdn_block(x, layer, cfg), None
         if kind == "*":
             x = _attention_block(x, layer, cfg, mesh, positions, "norm")
             return x, None
@@ -697,9 +774,13 @@ def loss_fn(
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Per-layer K/V buffers [L, B, S, kv_heads, head_dim]. Static shape:
     the whole decode loop stays inside one compiled ``lax.scan``."""
-    if cfg.layer_pattern:
+    if (
+        cfg.layer_pattern or cfg.attn_gate or cfg.rope_dim
+        or cfg.qk_norm_span != "token"
+    ):
         raise NotImplementedError(
-            "cached decoding knows the attention + FFN block only"
+            "cached decoding knows the attention + FFN block only, "
+            "ungated, wholly rotated, its QK-norm over the token"
         )
     dt = _dtype(cfg)
     shape = (cfg.num_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)

@@ -1,0 +1,401 @@
+"""The Gated DeltaNet layer (arXiv:2412.06464, as ``qwen3_next`` runs it)
+in plain ``jax.numpy``: in-projections, causal depthwise convolution, the
+gated delta rule computed in chunks, gated per-head RMSNorm, out-projection.
+
+The recurrence, for one value head with state ``S [d_k, d_v]`` from 0, decay
+``alpha_t = exp(g_t)``, ``g_t = -exp(A_log) * softplus(a_t + dt_bias)``,
+write strength ``beta_t = sigmoid(b_t)``, unit-length ``k_t`` and ``q_t``
+(``q`` over ``sqrt(d_k)`` besides):
+
+    S <- alpha_t S
+    S <- S + k_t (outer) (beta_t (v_t - S^T k_t))
+    o_t = S^T q_t
+
+so the transition ``alpha_t (I - beta_t k_t k_t^T)`` is a matrix, and the
+pass over chunk states has no closed form in scalar decays as Mamba-2's
+has (``ops/mamba2.py``). ``gated_delta_chunked`` computes it in chunks of
+``chunk`` steps (the WY / UT transform). With ``gamma_i`` the running sum
+of ``g`` inside a chunk:
+
+    A  = -tril_{-1}((K_beta K^T) * exp(gamma_i - gamma_j))
+    T  = (I - A)^{-1}                      unit lower triangular
+    U  = T V_beta        W = T (K_beta * exp(gamma))
+
+then over the chunks IN ORDER, from ``S = 0`` (``chunk_state_pass``):
+
+    V' = U - W S
+    S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
+
+and every position reads the state that entered its chunk and the chunk's
+own ``V'``: ``O = (Q * exp(gamma)) S + tril((Q K^T) * exp(gamma_i -
+gamma_j)) V'``. Decays, their sums, ``T`` and the carried state are
+float32; matmul operands are the activation dtype, accumulated in float32.
+No decay is ever divided by: every exponent above is <= 0.
+
+The pass is the layer's serial depth: ``T / chunk`` steps forward and as
+many backward, each two small matmuls a head; everything that does not
+depend on the carried state (``U``, ``W``, the read-out, and in the
+backward pass the cotangents of ``W``, ``K`` and the decays) is batched
+over all chunks outside it. It is differentiated by hand for that reason
+(``jax.grad`` of the forward loop carries all of a step's matmuls through
+the reversed loop and keeps the float32 state of every chunk).
+``gdn_tally()`` counts the passes traced and their steps.
+
+The spans of a layer: ``scope/layer/gdn/{in_proj,conv,scan,gate,out_proj}``.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from dlrover_tpu.ops.mamba2 import causal_conv1d, gated_group_rmsnorm
+
+L2_EPS = 1e-6  # of the unit-length q and k (the source's ``l2norm``)
+
+
+def init_gated_delta_params(key, cfg, dtype):
+    """One layer's parameters. The source's ``in_proj_qkvz`` is stored as
+    its column blocks ``[q | k | v]`` (``w_qkv``: the channels the
+    convolution runs over) and ``z`` (``w_z``), ``in_proj_ba`` as ``[b |
+    a]`` (``w_ba``); ``A_log = log U(0, 16]``, ``dt_bias = 1``, the gated
+    norm's weight 1, the convolution without bias."""
+    d, Hv = cfg.model_dim, cfg.gdn_value_heads
+    key_w = cfg.gdn_key_heads * cfg.gdn_key_dim
+    val_w = Hv * cfg.gdn_value_dim
+    kq, kz, kb, kc, ka, ko = jax.random.split(key, 6)
+
+    def dense(k, shape, fan_in):
+        return (jax.random.normal(k, shape) * fan_in**-0.5).astype(dtype)
+
+    return {
+        "w_qkv": dense(kq, (d, 2 * key_w + val_w), d),
+        "w_z": dense(kz, (d, val_w), d),
+        "w_ba": dense(kb, (d, 2 * Hv), d),
+        "conv_w": dense(kc, (cfg.gdn_conv, 2 * key_w + val_w), cfg.gdn_conv),
+        "dt_bias": jnp.ones((Hv,), dtype),
+        "A_log": jnp.log(
+            16.0 * (1.0 - jax.random.uniform(ka, (Hv,)))
+        ).astype(dtype),
+        "norm": jnp.ones((cfg.gdn_value_dim,), dtype),
+        "w_out": dense(ko, (val_w, d), val_w),
+    }
+
+
+def gated_delta_logical_axes():
+    return {
+        "w_qkv": ("embed", None),
+        "w_z": ("embed", None),
+        "w_ba": ("embed", None),
+        "conv_w": (None, None),
+        "dt_bias": (None,),
+        "A_log": (None,),
+        "norm": (None,),
+        "w_out": (None, "embed"),
+    }
+
+
+class GdnTally(NamedTuple):
+    """Chunk-state passes traced so far in this process (one a Gated
+    DeltaNet mixer of a program) and the sequential steps they run, a
+    backward pass counted with its forward. Counted when a program is
+    traced, as the attention tallies (``ops/flash_attention.py``)."""
+
+    sites: int = 0
+    chunk_steps: int = 0
+
+    def __sub__(self, other):
+        return GdnTally(*(a - b for a, b in zip(self, other)))
+
+
+_tally = GdnTally()
+
+
+def gdn_tally() -> GdnTally:
+    return _tally
+
+
+def _tally_pass(sites: int, steps: int):
+    global _tally
+    _tally = GdnTally(_tally.sites + sites, _tally.chunk_steps + steps)
+
+
+def l2norm(x):
+    """``x / |x|`` over the last axis, float32 (``rsqrt(sum x^2 + eps)``)."""
+    xf = x.astype(jnp.float32)
+    return xf * lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + L2_EPS)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(A):
+    """``(I - A)^{-1}`` for strictly lower triangular ``A [..., C, C]``
+    (float32): ``A`` is nilpotent, so the inverse is the finite product
+    ``(I + A)(I + A^2)(I + A^4) ...`` up to ``A^(C-1)``, ``ceil(log2 C)``
+    factors, all matmuls. Its cotangent is ``T^T dT T^T``."""
+    C = A.shape[-1]
+    hi = lax.Precision.HIGHEST
+    T = A + jnp.eye(C, dtype=A.dtype)
+    power, reach = A, 2  # T holds the powers below ``reach``
+    while reach < C:
+        power = jnp.matmul(power, power, precision=hi)
+        T = T + jnp.matmul(T, power, precision=hi)
+        reach *= 2
+    return T
+
+
+def _unit_lower_inverse_fwd(A):
+    T = unit_lower_inverse(A)
+    return T, T
+
+
+def _unit_lower_inverse_bwd(T, dT):
+    hi = lax.Precision.HIGHEST
+    Tt = jnp.swapaxes(T, -1, -2)
+    return (jnp.matmul(jnp.matmul(Tt, dT, precision=hi), Tt, precision=hi),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _pass_forward(U, W, K, delta, a):
+    f32, act = jnp.float32, W.dtype
+    _tally_pass(1, U.shape[0])
+
+    def step(S, x):
+        U, W, K, delta, a = x
+        Sb = S.astype(act)
+        Vn = U - jnp.einsum(
+            "bgrid,bgrdv->bgriv", W, Sb, preferred_element_type=f32
+        )
+        S = a[..., None, None] * S + jnp.einsum(
+            "bgjd,bgrjv->bgrdv", K, (delta[..., None] * Vn).astype(act),
+            preferred_element_type=f32,
+        )
+        return S, (Vn.astype(act), Sb)
+
+    _, b, g, r, _, dv = U.shape
+    S0 = jnp.zeros((b, g, r, K.shape[-1], dv), f32)
+    _, out = lax.scan(step, S0, (U, W, K, delta, a))
+    return out
+
+
+@jax.custom_vjp
+def chunk_state_pass(U, W, K, delta, a):
+    """The serial pass over the chunk states, chunk axis first: ``U``
+    [n, b, g, r, C, d_v] float32, ``W`` [n, b, g, r, C, d_k] and ``K``
+    [n, b, g, C, d_k] in the activation dtype (a key head ``g`` serves its
+    ``r`` value heads), ``delta = exp(gamma_C - gamma)`` [n, b, g, r, C]
+    and ``a = exp(gamma_C)`` [n, b, g, r] float32. Returns ``V' = U - W
+    S`` [n, b, g, r, C, d_v] and the state that ENTERED each chunk
+    [n, b, g, r, d_k, d_v], both in the activation dtype (what the
+    read-out's matmuls take)."""
+    return _pass_forward(U, W, K, delta, a)
+
+
+def _chunk_state_pass_fwd(U, W, K, delta, a):
+    Vn, S_in = _pass_forward(U, W, K, delta, a)
+    return (Vn, S_in), (W, K, delta, a, Vn, S_in)
+
+
+def _chunk_state_pass_bwd(res, cts):
+    """The reversed pass carries the cotangent of the state alone: a step
+    is ``dV'`` of the state's update (one matmul) and the state's own
+    cotangent (one more). What the steps leave behind (the cotangent of
+    every chunk's leaving state, of its decayed ``V'``) gives the
+    cotangents of ``W``, ``K`` and the decays in matmuls over all chunks
+    at once, after the loop."""
+    W, K, delta, a, Vn, S_in = res
+    dVn, dS_in = cts
+    f32, act = jnp.float32, W.dtype
+    _tally_pass(0, W.shape[0])
+
+    def step(dS, x):  # dS: of the state that LEFT this chunk
+        W, K, delta, a, dVn, dS_in = x
+        dSb = dS.astype(act)
+        dVd = jnp.einsum(
+            "bgjd,bgrdv->bgrjv", K, dSb, preferred_element_type=f32
+        )
+        dV = dVn.astype(f32) + delta[..., None] * dVd
+        dS = (
+            a[..., None, None] * dS + dS_in.astype(f32)
+            - jnp.einsum(
+                "bgrid,bgriv->bgrdv", W, dV.astype(act),
+                preferred_element_type=f32,
+            )
+        )
+        return dS, (dVd, dSb)
+
+    _, (dVd, dS_out) = lax.scan(
+        step, jnp.zeros(S_in.shape[1:], f32),
+        (W, K, delta, a, dVn, dS_in), reverse=True,
+    )
+    Vf = Vn.astype(f32)
+    dU = dVn.astype(f32) + delta[..., None] * dVd
+    dW = -jnp.einsum(
+        "nbgriv,nbgrdv->nbgrid", dU.astype(act), S_in,
+        preferred_element_type=f32,
+    ).astype(act)
+    dK = jnp.einsum(
+        "nbgrjv,nbgrdv->nbgjd", (delta[..., None] * Vf).astype(act), dS_out,
+        preferred_element_type=f32,
+    ).astype(act)
+    ddelta = jnp.sum(dVd * Vf, axis=-1)
+    da = jnp.einsum(
+        "nbgrdv,nbgrdv->nbgr", dS_out, S_in, preferred_element_type=f32
+    )
+    return dU, dW, dK, ddelta, da
+
+
+chunk_state_pass.defvjp(_chunk_state_pass_fwd, _chunk_state_pass_bwd)
+
+
+def _chunks(x, nc, C):
+    """[B, T, heads, w] -> [nc, B, heads, C, w]: chunk axis first, heads
+    before the positions of a chunk."""
+    B, _, H, w = x.shape
+    return jnp.transpose(x.reshape(B, nc, C, H, w), (1, 0, 3, 2, 4))
+
+
+def _sum_over(g, picks):
+    """``sum_j g[..., j] * picks[j, i]``, float32 at full precision."""
+    return jnp.einsum(
+        "nbgrj,ji->nbgri", g, picks, precision=lax.Precision.HIGHEST
+    )
+
+
+def _decays(g, diagonal: bool):
+    """``gamma``, the running sum of ``g`` [..., C] inside its chunk, and
+    the [..., C, C] square ``exp(gamma_i - gamma_j)`` below the diagonal
+    (with it where ``diagonal``), 0 elsewhere; float32. The diagonal's
+    ``exp(0)`` is a constant 1 and not ``exp`` of a difference: as a
+    difference its cotangent reaches ``g`` as two reductions that must
+    cancel to the last bit, and a head that decays fast multiplies what
+    is left by its ``|g|`` (PERF.md, Findings PR 43)."""
+    C = g.shape[-1]
+    gamma = _sum_over(g, jnp.triu(jnp.ones((C, C), jnp.float32)))
+    below = jnp.tril(jnp.ones((C, C), bool), -1)
+    decay = jnp.exp(jnp.where(
+        below, gamma[..., :, None] - gamma[..., None, :], -jnp.inf
+    ))
+    if diagonal:
+        decay = decay + jnp.eye(C, dtype=jnp.float32)
+    return gamma, decay
+
+
+def _wy(k, v, beta, g):
+    """What a chunk computes before the pass, for all chunks at once: from
+    k [n, b, g, C, d_k], v [n, b, g, r, C, d_v] and beta, g
+    [n, b, g, r, C] the pass's ``U, W, delta, a``."""
+    f32, act = jnp.float32, k.dtype
+    gamma, decay = _decays(g, diagonal=False)
+    kk = jnp.einsum("nbgid,nbgjd->nbgij", k, k, preferred_element_type=f32)
+    T = unit_lower_inverse(
+        -(beta[..., :, None] * kk[:, :, :, None] * decay)
+    )
+    U = jnp.einsum(
+        "nbgrij,nbgrjv->nbgriv", T.astype(act),
+        (v.astype(f32) * beta[..., None]).astype(act),
+        preferred_element_type=f32,
+    )
+    W = jnp.einsum(
+        "nbgrij,nbgjd->nbgrid",
+        (T * (beta * jnp.exp(gamma))[..., None, :]).astype(act), k,
+        preferred_element_type=f32,
+    ).astype(act)
+    # what is left of the chunk after each position, summed as such and
+    # not as ``gamma_C - gamma`` (the last position's is 0 by construction)
+    C = g.shape[-1]
+    left = _sum_over(g, jnp.tril(jnp.ones((C, C), jnp.float32), -1))
+    return U, W, jnp.exp(left), jnp.exp(gamma[..., -1])
+
+
+def _read_out(q, k, g, Vn, S_in):
+    """What every position reads: the state that entered its chunk and
+    the chunk's own ``V'`` up to itself. -> [n, b, g, r, C, d_v] float32."""
+    f32, act = jnp.float32, k.dtype
+    gamma, decay = _decays(g, diagonal=True)
+    qk = jnp.einsum("nbgid,nbgjd->nbgij", q, k, preferred_element_type=f32)
+    own = jnp.einsum(
+        "nbgrij,nbgrjv->nbgriv", (qk[:, :, :, None] * decay).astype(act),
+        Vn, preferred_element_type=f32,
+    )
+    entered = jnp.einsum(
+        "nbgid,nbgrdv->nbgriv", q, S_in, preferred_element_type=f32
+    )
+    return own + entered * jnp.exp(gamma)[..., None]
+
+
+def gated_delta_chunked(q, k, v, beta, g, chunk: int):
+    """The gated delta rule in chunks of ``chunk`` steps: q, k
+    [B, T, H_k, d_k] (unit length, q over sqrt(d_k) besides) and v
+    [B, T, H_v, d_v] in the activation dtype, beta and g [B, T, H_v]
+    float32 (g <= 0) -> o [B, T, H_v, d_v] float32. Value head ``h`` reads
+    key head ``h // (H_v // H_k)``. T must be whole chunks.
+
+    The two stretches around the pass are made again in the backward pass
+    and not kept: their [C, C] squares a value head a chunk (decays, ``T``,
+    masked scores) would be the layer's largest residuals."""
+    B, T, Hk, dk = q.shape
+    Hv, dv = v.shape[2], v.shape[3]
+    if T % chunk:
+        raise ValueError(f"sequence {T} is not whole chunks of {chunk}")
+    if Hv % Hk:
+        raise ValueError(f"{Hv} value heads do not share {Hk} key heads")
+    nc, r = T // chunk, Hv // Hk
+    qc, kc = _chunks(q, nc, chunk), _chunks(k, nc, chunk)
+    vc = _chunks(v, nc, chunk).reshape(nc, B, Hk, r, chunk, dv)
+
+    def per_head(x):  # [B, T, H_v] -> [nc, B, H_k, r, C]
+        return jnp.transpose(
+            x.reshape(B, nc, chunk, Hk, r), (1, 0, 3, 4, 2)
+        )
+
+    beta, g = per_head(beta), per_head(g)
+    U, W, delta, a = jax.checkpoint(_wy)(kc, vc, beta, g)
+    Vn, S_in = chunk_state_pass(U, W, kc, delta, a)
+    o = jax.checkpoint(_read_out)(qc, kc, g, Vn, S_in)
+    # [n, b, g, r, C, d_v] -> [b, (n, C), (g, r), d_v]
+    return jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(B, T, Hv, dv)
+
+
+def gated_delta_mixer(u, p, cfg, eps: float):
+    """u [B, T, d] (already normed) -> [B, T, d]."""
+    Bsz, T, _ = u.shape
+    Hv, Hk = cfg.gdn_value_heads, cfg.gdn_key_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    key_w = Hk * dk
+    act = u.dtype
+    with jax.named_scope("scope/layer/gdn/in_proj"):
+        qkv = u @ p["w_qkv"].astype(act)
+        z = u @ p["w_z"].astype(act)
+        ba = jnp.dot(
+            u, p["w_ba"].astype(act), preferred_element_type=jnp.float32
+        )
+    # the elementwise stretches compute in float32 and are made again in
+    # the backward pass, as the Mamba-2 layer's (``ops/mamba2.py``)
+    with jax.named_scope("scope/layer/gdn/conv"):
+        qkv = jax.checkpoint(
+            lambda x, w: jax.nn.silu(causal_conv1d(x, w)).astype(act)
+        )(qkv, p["conv_w"])
+    with jax.named_scope("scope/layer/gdn/scan"):
+        beta = jax.nn.sigmoid(ba[..., :Hv])
+        g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            ba[..., Hv:] + p["dt_bias"].astype(jnp.float32)
+        )
+        q = qkv[..., :key_w].reshape(Bsz, T, Hk, dk)
+        k = qkv[..., key_w:2 * key_w].reshape(Bsz, T, Hk, dk)
+        v = qkv[..., 2 * key_w:].reshape(Bsz, T, Hv, dv)
+        q, k = jax.checkpoint(lambda q, k: (
+            (l2norm(q) * dk**-0.5).astype(act), l2norm(k).astype(act)
+        ))(q, k)
+        o = gated_delta_chunked(q, k, v, beta, g, min(cfg.gdn_chunk, T))
+        o = o.astype(act).reshape(Bsz, T, Hv * dv)
+    with jax.named_scope("scope/layer/gdn/gate"):
+        o = jax.checkpoint(lambda o, z, w: gated_group_rmsnorm(
+            o, z, jnp.tile(w, Hv), Hv, eps, norm_before_gate=True
+        ).astype(act))(o, z, p["norm"])
+    with jax.named_scope("scope/layer/gdn/out_proj"):
+        return o @ p["w_out"].astype(act)

@@ -87,15 +87,15 @@ def mamba2_logical_axes():
     }
 
 
-def causal_conv1d(x, w, b):
+def causal_conv1d(x, w, b=None):
     """Depthwise causal convolution over time: ``y_t = b + sum_k w[k] *
     x_{t - (K-1) + k}`` with zeros before the row's start. x: [B, T, C],
-    w: [K, C], b: [C]; float32 inside."""
+    w: [K, C], b: [C] or None for no bias; float32 inside."""
     K, T = w.shape[0], x.shape[1]
     xf = x.astype(jnp.float32)
     wf = w.astype(jnp.float32)
     padded = jnp.pad(xf, ((0, 0), (K - 1, 0), (0, 0)))
-    y = b.astype(jnp.float32)
+    y = 0.0 if b is None else b.astype(jnp.float32)
     for k in range(K):
         y = y + padded[:, k:k + T, :] * wf[k]
     return y
@@ -172,14 +172,21 @@ def ssd_chunked(x, dt, a, Bm, Cm, chunk: int):
     return jnp.moveaxis(y, 4, 2).reshape(Bsz, T, H, P)
 
 
-def gated_group_rmsnorm(y, z, weight, groups: int, eps: float):
+def gated_group_rmsnorm(y, z, weight, groups: int, eps: float,
+                        norm_before_gate: bool = False):
     """``RMSNorm_group(y * silu(z)) * weight``: the mean square is taken
-    over each of ``groups`` equal slices of the last axis. float32."""
-    yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    over each of ``groups`` equal slices of the last axis. float32.
+    ``norm_before_gate``: ``RMSNorm_group(y) * weight * silu(z)``, the
+    gate outside the norm (the Gated DeltaNet layer's order)."""
+    yf = y.astype(jnp.float32)
+    gate = jax.nn.silu(z.astype(jnp.float32))
+    if not norm_before_gate:
+        yf = yf * gate
     shape = yf.shape
     yg = yf.reshape(*shape[:-1], groups, shape[-1] // groups)
     yg = yg * lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + eps)
-    return yg.reshape(shape) * weight.astype(jnp.float32)
+    out = yg.reshape(shape) * weight.astype(jnp.float32)
+    return out * gate if norm_before_gate else out
 
 
 def mamba2_mixer(u, p, cfg, eps: float):

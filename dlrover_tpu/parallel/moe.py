@@ -37,8 +37,10 @@ class MoEParams(NamedTuple):
     activation pair ``w_up`` / ``w_down``. ``bias`` is the sigmoid
     router's selection bias, one an expert (no gradient: the train step
     moves it, ``move_router_bias``); ``shared_up`` / ``shared_down`` the
-    one expert every token passes beside its routed ones. On a chip that
-    holds a share of the experts ``E_local`` is the number held."""
+    one expert every token passes beside its routed ones, SwiGLU with
+    ``shared_gate`` where the experts are gated, its output times
+    ``sigmoid(x . shared_out_gate)`` where it has that vector. On a chip
+    that holds a share of the experts ``E_local`` is the number held."""
 
     gate: jnp.ndarray  # [model, E_global]
     w_up: jnp.ndarray  # [E_local, model, hidden]
@@ -47,15 +49,19 @@ class MoEParams(NamedTuple):
     bias: Optional[jnp.ndarray] = None  # [E_global]
     shared_up: Optional[jnp.ndarray] = None  # [model, shared]
     shared_down: Optional[jnp.ndarray] = None  # [shared, model]
+    shared_gate: Optional[jnp.ndarray] = None  # [model, shared]
+    shared_out_gate: Optional[jnp.ndarray] = None  # [model]
 
 
 def init_moe_params(
     key, num_experts: int, model_dim: int, hidden_dim: int,
     dtype=jnp.float32, gated: bool = False, held: int = 0,
     selection_bias: bool = False, shared_dim: int = 0,
+    shared_out_gate: bool = False,
 ) -> MoEParams:
     """``held`` (0 = all): how many of the ``num_experts`` the router
-    scores have their matrices here."""
+    scores have their matrices here. The shared expert is gated (SwiGLU)
+    where the routed ones are."""
     kg, ku, kd, kw = jax.random.split(key, 4)
     scale = model_dim**-0.5
     e_local = held or num_experts
@@ -76,6 +82,15 @@ def init_moe_params(
                 kt, (shared_dim, model_dim), dtype
             ) * (shared_dim**-0.5),
         )
+        kg2, ko = jax.random.split(jax.random.fold_in(key, 2))
+        if gated:
+            shared["shared_gate"] = jax.random.normal(
+                kg2, (model_dim, shared_dim), dtype
+            ) * scale
+        if shared_out_gate:
+            shared["shared_out_gate"] = jax.random.normal(
+                ko, (model_dim,), dtype
+            ) * scale
     return MoEParams(
         gate=jax.random.normal(kg, (model_dim, num_experts), dtype) * scale,
         w_up=up(ku),
@@ -269,12 +284,25 @@ def _expert_ffn(params: MoEParams, matmul, x, activation):
 
 def _add_shared_expert(out, params: MoEParams, x, activation):
     """``out`` plus the shared expert's output for every token, where
-    the parameters have one."""
+    the parameters have one: SwiGLU where they have its gate projection,
+    the activation pair where not; times ``sigmoid(x . shared_out_gate)``,
+    a scalar a token, where they have that vector."""
     if params.shared_up is None:
         return out
     with jax.named_scope("scope/layer/moe/shared"):
-        h = activation(x @ params.shared_up.astype(x.dtype))
-        return out + h @ params.shared_down.astype(x.dtype)
+        h = x @ params.shared_up.astype(x.dtype)
+        if params.shared_gate is not None:
+            h = jax.nn.silu(x @ params.shared_gate.astype(x.dtype)) * h
+        else:
+            h = activation(h)
+        y = h @ params.shared_down.astype(x.dtype)
+        if params.shared_out_gate is not None:
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, params.shared_out_gate.astype(x.dtype),
+                preferred_element_type=jnp.float32,
+            ))
+            y = (y.astype(jnp.float32) * gate[:, None]).astype(x.dtype)
+        return out + y
 
 
 def _moe_dropless(params: MoEParams, x, idx, gates, counts, activation):
@@ -566,6 +594,8 @@ def moe_layer(params: MoEParams, x, mesh, **kw):
         w_gate=None if params.w_gate is None else expert,
         bias=whole(params.bias), shared_up=whole(params.shared_up),
         shared_down=whole(params.shared_down),
+        shared_gate=whole(params.shared_gate),
+        shared_out_gate=whole(params.shared_out_gate),
     )
 
     def body(p, xb):

@@ -227,6 +227,8 @@ class ElasticTrainer:
         self._builds = compile_meter()
         self._builds_first = self._builds_logged = len(self._builds.builds)
         self._built: set = set()
+        # ops.gated_delta's tally as a train step's build began
+        self._gdn_before_step = None
         self.tcfg = trainer_cfg or TrainerConfig()
         self._metrics_hook = metrics_hook
         # kept for the resize path: a new mesh rebuilds the accel
@@ -441,7 +443,7 @@ class ElasticTrainer:
             )
             logger.info(
                 f"programs built {when}: {describe_builds(rows)}{q8}"
-                f"{self._fold_attention_tally()}"
+                f"{self._fold_attention_tally()}{self._fold_gdn_tally()}"
             )
 
     def _fold_first_step(self):
@@ -500,6 +502,26 @@ class ElasticTrainer:
             )
         return said
 
+    def _fold_gdn_tally(self) -> str:
+        """The Gated DeltaNet passes traced since a train step's build
+        began (``_first_build``) into the stats, and in words; nothing
+        where no step was built since the last such line, or the step
+        came whole out of a cache of executables (not traced)."""
+        from dlrover_tpu.ops.gated_delta import gdn_tally
+
+        before, self._gdn_before_step = self._gdn_before_step, None
+        if before is None:
+            return ""
+        step = gdn_tally() - before
+        if not step.sites:
+            return ""
+        stats = self.pipeline_stats
+        stats.gdn_sites, stats.gdn_chunk_steps = step
+        return (
+            f"; gated delta rule: {step.sites} sites, "
+            f"{step.chunk_steps} serial chunk steps a train step"
+        )
+
     def _first_build(self, what: str):
         """``build:<what>`` around the FIRST call of a jitted program
         (jit compiles, or loads from the cache, inside that call);
@@ -507,6 +529,10 @@ class ElasticTrainer:
         if what in self._built:
             return _NO_BUILD
         self._built.add(what)
+        if what.startswith("step_"):
+            from dlrover_tpu.ops.gated_delta import gdn_tally
+
+            self._gdn_before_step = gdn_tally()
         return self._builds.build(what)
 
     # -- measured link-cost model (parallel/topology.py) ----------------

@@ -2,8 +2,10 @@
 like to the compiler, as two sha256: the tree's paths, shapes and dtypes,
 and the text the step lowers to (its own optimizer, one device, a batch of
 1 x 1024). ``tests/data/step_lowering.json`` holds what the commit before
-ISSUE 37 gave for the three configurations the benchmark had then, made by
-running this file there:
+ISSUE 37 gave for the three configurations the benchmark had then, what the
+commit before ISSUE 43 gave for the Nemotron configuration, and what ISSUE
+43 itself gave for its own (``qwen3-next-80b-a3b-d4``), made by running this
+file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
 
@@ -18,7 +20,10 @@ import os
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-NAMES = ("gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2")
+NAMES = (
+    "gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2",
+    "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
+)
 
 
 def fingerprint(name: str) -> dict:

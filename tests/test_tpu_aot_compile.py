@@ -168,6 +168,51 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
         ) == {12: (4, 2), 25: (5, 1)}[H]
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_streaming_attention_compiles_at_heads_of_256(
+    direction, one_chip, monkeypatch
+):
+    """The Qwen3-Next cell's attention layer, [1, 16 / 2, 8192, 256]: a
+    head twice as wide as any the streaming kernels had run. A head wider
+    than the lanes keeps blocks of 512 (``_validate_blocks``: the
+    1024-blocks triangle is for heads no wider than measured), so the
+    triangle path walks 136 of 256 blocks a kernel."""
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    B, H, Hkv, T, D = 1, 16, 2, 8192, 256
+    qkv = [
+        jax.ShapeDtypeStruct((B, h, T, D), jnp.bfloat16, sharding=one_chip)
+        for h in (H, Hkv, Hkv)
+    ]
+
+    def attend(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, force="pallas", layout="bhtd"
+        )
+
+    before = fa.stream_tally()
+    if direction == "fwd":
+        text = _compile_for_chip(attend, *qkv).as_text()
+        want = ["flash_attn_fwd"]
+    else:
+        text = _compile_for_chip(
+            jax.grad(
+                lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            ),
+            *qkv,
+        ).as_text()
+        want = ["flash_attn_fwd"] + (
+            ["flash_attn_bwd"] if fa._one_pass_fits(T, D, 2)
+            else ["flash_attn_bwd_dq", "flash_attn_bwd_dkv"]
+        )
+    for kernel in want:
+        assert kernel in text, kernel
+    assert "flash_attn_fused" not in text
+    sites = len(want)
+    assert T // fa._BLOCK == 16
+    assert fa.stream_tally() - before == (sites, 0, 136 * sites, 256 * sites)
+
+
 # the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here
 ADAM_LEAVES = {
     "gpt2_wte": (50257, 768),

@@ -142,9 +142,13 @@ class PipelineStats:
     # train step program this process traced last, and the sequential
     # chunk-state steps one training step runs through them, forward and
     # backward: the step's serial depth in that layer kind. Set when the
-    # trainer logs the step it built; 0 / 0 for a model without the kind
+    # trainer logs the step it built; 0 / 0 for a model without the kind.
+    # ``gdn_kernel_sites``: the mixers among them whose chunk-local work
+    # (the [C, C] squares around the pass) was traced into the
+    # ``gdn_chunk_*`` kernels (``ops/gated_delta_kernels.fits``)
     gdn_sites: int = 0
     gdn_chunk_steps: int = 0
+    gdn_kernel_sites: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was

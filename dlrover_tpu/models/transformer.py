@@ -493,9 +493,9 @@ def _ssm_block(x, layer, cfg: TransformerConfig):
 
 
 @jax.named_scope("scope/layer/gdn")
-def _gdn_block(x, layer, cfg: TransformerConfig):
+def _gdn_block(x, layer, cfg: TransformerConfig, mesh):
     h = _norm(x, layer["norm"], cfg)
-    return x + gated_delta_mixer(h, layer["gdn"], cfg, _norm_eps(cfg))
+    return x + gated_delta_mixer(h, layer["gdn"], cfg, _norm_eps(cfg), mesh)
 
 
 def _zero_aux(cfg: Optional[TransformerConfig] = None):
@@ -700,7 +700,7 @@ def forward(
         if kind == "M":
             return _ssm_block(x, layer, cfg), None
         if kind == "G":
-            return _gdn_block(x, layer, cfg), None
+            return _gdn_block(x, layer, cfg, mesh), None
         if kind == "*":
             x = _attention_block(x, layer, cfg, mesh, positions, "norm")
             return x, None

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.mamba2 import causal_conv1d, gated_group_rmsnorm
 
 L2_EPS = 1e-6  # of the unit-length q and k (the source's ``l2norm``)
@@ -100,12 +101,15 @@ def gated_delta_logical_axes():
 
 class GdnTally(NamedTuple):
     """Chunk-state passes traced so far in this process (one a Gated
-    DeltaNet mixer of a program) and the sequential steps they run, a
-    backward pass counted with its forward. Counted when a program is
-    traced, as the attention tallies (``ops/flash_attention.py``)."""
+    DeltaNet mixer of a program), the sequential steps they run, a
+    backward pass counted with its forward, and the sites whose
+    chunk-local work went into the kernels
+    (``gated_delta_kernels.fits``). Counted when a program is traced, as
+    the attention tallies (``ops/flash_attention.py``)."""
 
     sites: int = 0
     chunk_steps: int = 0
+    kernel_sites: int = 0
 
     def __sub__(self, other):
         return GdnTally(*(a - b for a, b in zip(self, other)))
@@ -118,9 +122,11 @@ def gdn_tally() -> GdnTally:
     return _tally
 
 
-def _tally_pass(sites: int, steps: int):
+def _tally_pass(sites: int, steps: int, kernel_sites: int = 0):
     global _tally
-    _tally = GdnTally(_tally.sites + sites, _tally.chunk_steps + steps)
+    _tally = GdnTally(*(
+        a + b for a, b in zip(_tally, (sites, steps, kernel_sites))
+    ))
 
 
 def l2norm(x):
@@ -332,12 +338,17 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     """The gated delta rule in chunks of ``chunk`` steps: q, k
     [B, T, H_k, d_k] (unit length, q over sqrt(d_k) besides) and v
     [B, T, H_v, d_v] in the activation dtype, beta and g [B, T, H_v]
-    float32 (g <= 0) -> o [B, T, H_v, d_v] float32. Value head ``h`` reads
-    key head ``h // (H_v // H_k)``. T must be whole chunks.
+    float32 (g <= 0) -> o [B, T, H_v, d_v], accumulated in float32 and
+    rounded once to the activation dtype. Value head ``h`` reads key head
+    ``h // (H_v // H_k)``. T must be whole chunks.
 
     The two stretches around the pass are made again in the backward pass
     and not kept: their [C, C] squares a value head a chunk (decays, ``T``,
-    masked scores) would be the layer's largest residuals."""
+    masked scores) would be the layer's largest residuals. Where the
+    shapes allow (``gated_delta_kernels.fits``) both stretches, forward
+    and backward, are kernels that keep those squares in VMEM and read
+    and write the layouts around them; ``_wy`` and ``_read_out`` below are
+    the statement they are held to, and what runs everywhere else."""
     B, T, Hk, dk = q.shape
     Hv, dv = v.shape[2], v.shape[3]
     if T % chunk:
@@ -345,8 +356,6 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     if Hv % Hk:
         raise ValueError(f"{Hv} value heads do not share {Hk} key heads")
     nc, r = T // chunk, Hv // Hk
-    qc, kc = _chunks(q, nc, chunk), _chunks(k, nc, chunk)
-    vc = _chunks(v, nc, chunk).reshape(nc, B, Hk, r, chunk, dv)
 
     def per_head(x):  # [B, T, H_v] -> [nc, B, H_k, r, C]
         return jnp.transpose(
@@ -354,15 +363,81 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
         )
 
     beta, g = per_head(beta), per_head(g)
+    if kernels.fits(dk, dv, chunk, T, k.dtype):
+        _tally_pass(0, 0, kernel_sites=1)
+        q, k = q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk)
+        rows = (nc, B, Hk, 1, r * chunk)
+        g = g.reshape(rows)
+        U, W, kc, delta, a = kernels.wy(
+            k, v.reshape(B, T, Hv * dv), beta.reshape(rows), g, Hk, r, chunk
+        )
+        Vn, S_in = chunk_state_pass(U, W, kc, delta, a)
+        return kernels.read_out(q, k, g, Vn, S_in).reshape(B, T, Hv, dv)
+    qc, kc = _chunks(q, nc, chunk), _chunks(k, nc, chunk)
+    vc = _chunks(v, nc, chunk).reshape(nc, B, Hk, r, chunk, dv)
     U, W, delta, a = jax.checkpoint(_wy)(kc, vc, beta, g)
     Vn, S_in = chunk_state_pass(U, W, kc, delta, a)
     o = jax.checkpoint(_read_out)(qc, kc, g, Vn, S_in)
     # [n, b, g, r, C, d_v] -> [b, (n, C), (g, r), d_v]
-    return jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(B, T, Hv, dv)
+    o = jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(B, T, Hv, dv)
+    return o.astype(k.dtype)
 
 
-def gated_delta_mixer(u, p, cfg, eps: float):
-    """u [B, T, d] (already normed) -> [B, T, d]."""
+def _delta_rule(q, k, v, beta, g, chunk: int, mesh):
+    """``gated_delta_chunked`` as the mixer calls it. Where the chunk-local
+    work goes into kernels and GSPMD still owns a mesh axis, the call runs
+    under ``shard_map``, batch over the data axes and heads over tp: for
+    ``models/transformer._causal_attention``'s reason and by its rules
+    (the rule is independent per example and key head; GSPMD refuses to
+    partition a Mosaic kernel on its own; a batch or a head count that does
+    not divide is an error on the TPU and stays with GSPMD elsewhere). The
+    plain statement is left to GSPMD as it was."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    def rule(*a):
+        return gated_delta_chunked(*a, chunk)
+
+    args = (q, k, v, beta, g)
+    if not kernels.fits(q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype):
+        return rule(*args)
+
+    def specs(batch, heads):
+        wide, narrow = P(batch, None, heads, None), P(batch, None, heads)
+        return dict(
+            in_specs=(wide, wide, wide, narrow, narrow), out_specs=wide,
+            check_vma=False,
+        )
+
+    if mesh is None:
+        auto = jax.sharding.get_abstract_mesh().auto_axes
+        if not auto or jax.typeof(q).vma:
+            return rule(*args)
+        rest = tuple(a for a in auto if a != "tp") or None
+        return shard_map(
+            rule, axis_names=frozenset(auto),
+            **specs(rest, "tp" if "tp" in auto else None),
+        )(*args)
+    if mesh.size == 1:
+        return rule(*args)
+    data, tp = mesh.shape["dp"] * mesh.shape["fsdp"], mesh.shape["tp"]
+    if q.shape[0] % data or q.shape[2] % tp:
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                f"gated delta rule batch {q.shape[0]} and key heads "
+                f"{q.shape[2]} do not divide dp*fsdp={data} and tp={tp} of "
+                f"mesh {dict(mesh.shape)}: its Pallas kernels cannot be "
+                "partitioned unevenly; pick a (micro)batch and a tp that "
+                "divide"
+            )
+        return rule(*args)
+    return shard_map(rule, mesh=mesh, **specs(("dp", "fsdp"), "tp"))(*args)
+
+
+def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
+    """u [B, T, d] (already normed) -> [B, T, d]. ``mesh``: the mesh the
+    step is sharded over, or None inside a region that names its own axes
+    (``_delta_rule``)."""
     Bsz, T, _ = u.shape
     Hv, Hk = cfg.gdn_value_heads, cfg.gdn_key_heads
     dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
@@ -391,8 +466,8 @@ def gated_delta_mixer(u, p, cfg, eps: float):
         q, k = jax.checkpoint(lambda q, k: (
             (l2norm(q) * dk**-0.5).astype(act), l2norm(k).astype(act)
         ))(q, k)
-        o = gated_delta_chunked(q, k, v, beta, g, min(cfg.gdn_chunk, T))
-        o = o.astype(act).reshape(Bsz, T, Hv * dv)
+        o = _delta_rule(q, k, v, beta, g, min(cfg.gdn_chunk, T), mesh)
+        o = o.reshape(Bsz, T, Hv * dv)
     with jax.named_scope("scope/layer/gdn/gate"):
         o = jax.checkpoint(lambda o, z, w: gated_group_rmsnorm(
             o, z, jnp.tile(w, Hv), Hv, eps, norm_before_gate=True

@@ -516,9 +516,10 @@ class ElasticTrainer:
         if not step.sites:
             return ""
         stats = self.pipeline_stats
-        stats.gdn_sites, stats.gdn_chunk_steps = step
+        stats.gdn_sites, stats.gdn_chunk_steps, stats.gdn_kernel_sites = step
         return (
-            f"; gated delta rule: {step.sites} sites, "
+            f"; gated delta rule: {step.sites} sites "
+            f"({step.kernel_sites} in the kernel), "
             f"{step.chunk_steps} serial chunk steps a train step"
         )
 

@@ -4,8 +4,11 @@ and the text the step lowers to (its own optimizer, one device, a batch of
 1 x 1024). ``tests/data/step_lowering.json`` holds what the commit before
 ISSUE 37 gave for the three configurations the benchmark had then, what the
 commit before ISSUE 43 gave for the Nemotron configuration, and what ISSUE
-43 itself gave for its own (``qwen3-next-80b-a3b-d4``), made by running this
-file there:
+44 gave for ``qwen3-next-80b-a3b-d4`` (its tree is ISSUE 43's; its step was
+recorded anew when the delta rule's output took the activation dtype inside
+``gated_delta_chunked``: at this batch of 1 x 1024 with heads of 128 the
+chunk-local work lowers to the ``gdn_chunk_*`` kernels, interpreted on the
+CPU), made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
 

@@ -44,6 +44,7 @@ from dlrover_tpu.models.transformer import (
     loss_fn,
 )
 from dlrover_tpu.ops import gated_delta
+from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.gated_delta import (
     gated_delta_chunked,
     gdn_tally,
@@ -330,6 +331,221 @@ def test_unit_lower_inverse_is_the_inverse(C):
     plain = jax.grad(lambda a: jnp.sum(jnp.sin(jnp.linalg.inv(eye - a))))(A)
     ours = jax.grad(lambda a: jnp.sum(jnp.sin(unit_lower_inverse(a))))(A)
     assert _rel(jnp.tril(ours, -1), jnp.tril(plain, -1)) <= GRAD_RTOL
+
+
+# -- the chunk-local work as kernels (ISSUE 44) ----------------------------
+
+# the layer's own decay rates, ``g = -A softplus(.)``: heads that forget
+# inside a chunk (A >= 10), heads that remember for thousands of steps
+# (A down to 1e-3), and the layer's start (A = U(0, 16])
+KERNEL_REGIMES = {
+    "fast_decay": (10.0, 16.0),
+    "long_memory": (1e-3, 1e-2),
+    "as_initialised": (1e-3, 16.0),
+}
+# float32: the kernels and the statement differ in the order of their sums
+# (a cotangent sums more terms: ``RTOL``); bfloat16: two roundings of the
+# largest value to 8 bits of mantissa
+KERNEL_TOL = {"float32": (1e-5, RTOL), "bfloat16": (2e-2, 2e-2)}
+KERNEL_SHAPE = dict(B=1, T=256, Hk=2, Hv=4, d=128, C=64)
+
+
+def _kernel_inputs(regime, dtype, seed=0, B=1, T=256, Hk=2, Hv=4, d=128,
+                   C=64):
+    lo, hi = KERNEL_REGIMES[regime]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = l2norm(jax.random.normal(ks[0], (B, T, Hk, d))) * d**-0.5
+    k = l2norm(jax.random.normal(ks[1], (B, T, Hk, d)))
+    v = jax.random.normal(ks[2], (B, T, Hv, d))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, Hv)))
+    A = jnp.exp(jax.random.uniform(
+        ks[4], (Hv,), minval=np.log(lo), maxval=np.log(hi)
+    ))
+    g = -A * jax.nn.softplus(jax.random.normal(ks[5], (B, T, Hv)) + 1.0)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), beta, g
+
+
+def _both_ways(B, T, Hk, Hv, d, C):
+    """``(wy, read_out)`` as the plain statement and as the kernels, both
+    from token-major arguments to the same results."""
+    nc, r = T // C, Hv // Hk
+    rows = (nc, B, Hk, 1, r * C)
+
+    def per_head(x):
+        return jnp.transpose(x.reshape(B, nc, C, Hk, r), (1, 0, 3, 4, 2))
+
+    def chunks(x):
+        return gated_delta._chunks(x, nc, C)
+
+    def plain_wy(k, v, beta, g):
+        kc = chunks(k)
+        vc = chunks(v).reshape(nc, B, Hk, r, C, d)
+        return (*gated_delta._wy(kc, vc, per_head(beta), per_head(g)), kc)
+
+    def kernel_wy(k, v, beta, g):
+        U, W, kc, delta, a = kernels.wy(
+            k.reshape(B, T, Hk * d), v.reshape(B, T, Hv * d),
+            per_head(beta).reshape(rows), per_head(g).reshape(rows),
+            Hk, r, C,
+        )
+        return U, W, delta, a, kc
+
+    def plain_read(q, k, g, Vn, S_in):
+        o = gated_delta._read_out(
+            chunks(q), chunks(k), per_head(g), Vn, S_in
+        )
+        o = jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(B, T, Hv, d)
+        return o.astype(k.dtype)
+
+    def kernel_read(q, k, g, Vn, S_in):
+        return kernels.read_out(
+            q.reshape(B, T, Hk * d), k.reshape(B, T, Hk * d),
+            per_head(g).reshape(rows), Vn, S_in,
+        ).reshape(B, T, Hv, d)
+
+    return (plain_wy, kernel_wy), (plain_read, kernel_read)
+
+
+def _within(got, want, tol):
+    """``_rel`` for results that may be all zeros (a fast head's ``a``)."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+
+
+def _cotangents(outs, seed):
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(outs))
+    return tuple(
+        jax.random.normal(key, o.shape).astype(o.dtype)
+        for key, o in zip(keys, outs)
+    )
+
+
+@pytest.mark.parametrize("regime", sorted(KERNEL_REGIMES))
+@pytest.mark.parametrize("dtype", sorted(KERNEL_TOL))
+@pytest.mark.parametrize("stretch", ["wy", "read_out"])
+def test_chunk_kernels_are_the_plain_statement(stretch, dtype, regime):
+    """Heads of 128, chunks of 64, two key heads of two value heads, four
+    chunks, under ``interpret=True``: what the kernels write (``U, W,
+    delta, a`` and the chunk-major ``K``; ``o``) and every cotangent they
+    return against ``_wy`` / ``_read_out`` and ``jax.vjp`` of those."""
+    tol, grad_tol = KERNEL_TOL[dtype]
+    q, k, v, beta, g = _kernel_inputs(regime, dtype)
+    A_eff = -np.asarray(g).mean((0, 1))
+    if regime == "fast_decay":
+        assert A_eff.min() >= 10.0
+    if regime == "long_memory":
+        assert A_eff.max() <= 2e-2
+    wy, read = _both_ways(**KERNEL_SHAPE)
+    if stretch == "wy":
+        (plain, kernel), args = wy, (k, v, beta, g)
+    else:
+        U, W, delta, a, kc = wy[0](k, v, beta, g)
+        Vn, S_in = gated_delta.chunk_state_pass(U, W, kc, delta, a)
+        (plain, kernel), args = read, (q, k, g, Vn, S_in)
+    want, vjp_want = jax.vjp(plain, *args)
+    got, vjp_got = jax.vjp(kernel, *args)
+    one = not isinstance(want, tuple)
+    for a, b in zip(*(((x,) if one else x) for x in (got, want))):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert _within(a, b, tol)
+    cts = _cotangents((want,) if one else want, seed=7)
+    cts = cts[0] if one else cts
+    for a, b in zip(vjp_got(cts), vjp_want(cts)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert _within(a, b, grad_tol)
+
+
+@pytest.mark.parametrize("regime", sorted(KERNEL_REGIMES))
+def test_chunked_rule_through_the_kernels_is_the_recurrence(
+    regime, monkeypatch
+):
+    """``gated_delta_chunked`` at shapes the kernels take, forward and in
+    every gradient, against one step at a time; the tally says which way
+    the site went."""
+    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    args = _kernel_inputs(regime, jnp.float32)
+
+    def chunked(*a):
+        return gated_delta_chunked(*a, 64)
+
+    want = jax.jit(delta_rule_sequential)(*args)
+    got = jax.jit(chunked)(*args)
+    assert gdn_tally() == (1, 4, 1)
+    assert got.shape == want.shape == (1, 256, 4, 128)
+    assert _rel(got, want) <= RTOL
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=range(5)
+        ))(*args)
+
+    for a, b in zip(grads(chunked), grads(delta_rule_sequential)):
+        assert _rel(a, b) <= GRAD_RTOL
+    assert gdn_tally() == (2, 12, 2)
+
+
+@pytest.mark.parametrize("d_k,d_v,chunk,T,dtype,kernel", [
+    (128, 128, 64, 8192, "bfloat16", True),  # the cell
+    (128, 256, 16, 64, "bfloat16", True),
+    (128, 128, 8, 64, "float32", True),
+    (128, 128, 8, 64, "bfloat16", False),  # half a bfloat16 sublane tile
+    (64, 128, 64, 8192, "bfloat16", False),  # a key head of half a tile
+    (128, 192, 64, 8192, "bfloat16", False),
+    (128, 128, 64, 8192 + 32, "bfloat16", False),  # a ragged sequence
+    (128, 128, 40, 40, "float32", True),  # one short chunk, whole tiles
+    (128, 128, 20, 20, "float32", False),
+])
+def test_the_shapes_decide_which_way_a_chunk_is_computed(
+    d_k, d_v, chunk, T, dtype, kernel
+):
+    assert kernels.fits(d_k, d_v, chunk, T, dtype) is kernel
+
+
+@pytest.mark.parametrize("d,T", [(64, 128), (128, 20)])
+def test_a_site_the_kernels_cannot_take_is_plain_and_says_so(
+    d, T, monkeypatch
+):
+    """A head of 64, or a sequence that is one chunk of no whole tiles:
+    the plain statement runs, to the recurrence's result, and the tally
+    counts a site and none in the kernel."""
+    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    args = _kernel_inputs("as_initialised", jnp.float32, T=T, d=d)
+    got = jax.jit(lambda *a: gated_delta_chunked(*a, min(64, T)))(*args)
+    assert gdn_tally() == (1, max(T // 64, 1), 0)
+    assert _rel(got, jax.jit(delta_rule_sequential)(*args)) <= RTOL
+
+
+@pytest.mark.parametrize("shape", ["fsdp2_tp2", "fsdp4", "tp2"])
+def test_a_mesh_of_several_devices_runs_the_kernels_under_shard_map(shape):
+    """A mixer whose heads fit the kernels, on a mesh GSPMD owns: the rule
+    runs under ``shard_map`` (batch over the data axes, heads over tp), to
+    the one-device layer's output and gradients."""
+    from dlrover_tpu.ops.gated_delta import (
+        gated_delta_mixer,
+        init_gated_delta_params,
+    )
+
+    over = {"fsdp2_tp2": dict(fsdp=2, tp=2), "fsdp4": dict(fsdp=4),
+            "tp2": dict(tp=2)}[shape]
+    mesh = build_mesh(
+        MeshConfig(**over), jax.devices()[:int(np.prod(list(over.values())))]
+    )
+    cfg = _cfg(gdn_key_dim=128, gdn_value_dim=128, gdn_chunk=16)
+    p = init_gated_delta_params(jax.random.PRNGKey(0), cfg, jnp.float32)
+    u = jax.random.normal(jax.random.PRNGKey(1), (4, 32, cfg.model_dim))
+
+    def layer(mesh):
+        def loss(u, p):
+            return jnp.sum(jnp.sin(gated_delta_mixer(u, p, cfg, 1e-6, mesh)))
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
+
+    text = layer(mesh).lower(u, p).as_text()
+    assert "sdy.manual_computation" in text
+    want, g_want = layer(None)(u, p)
+    got, g_got = layer(mesh)(u, p)
+    assert abs(float(got) - float(want)) <= RTOL * abs(float(want))
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        assert _rel(a, b) <= GRAD_RTOL
 
 
 def test_the_gate_stands_outside_the_norm():
@@ -647,7 +863,7 @@ def test_the_tally_counts_sites_and_chunk_steps_of_a_built_step(monkeypatch):
     x, y = _batch(cfg)
     # the worker's reference check: a forward pass before any step
     jax.jit(lambda p: loss_fn(p, x, y, cfg, None)).lower(params)
-    assert gdn_tally() == (2, 8)
+    assert gdn_tally() == (2, 8, 0)
 
     stats = PipelineStats()
     trainer = types.SimpleNamespace(
@@ -658,23 +874,49 @@ def test_the_tally_counts_sites_and_chunk_steps_of_a_built_step(monkeypatch):
     assert ElasticTrainer._first_build(trainer, "eval") == "eval"
     assert trainer._gdn_before_step is None
     ElasticTrainer._first_build(trainer, "step_donating")
-    assert trainer._gdn_before_step == (2, 8)
+    assert trainer._gdn_before_step == (2, 8, 0)
     state = TrainState(
         step=jnp.zeros((), jnp.int32), params=params,
         opt_state=tx.init(params),
     )
     build_train_step(cfg, mesh, tx, donate=False).lower(state, x, y)
-    assert gdn_tally() == (4, 24)
+    assert gdn_tally() == (4, 24, 0)
+    # heads of 12 / 8 are no lane tiles: both sites took the plain way
     assert ElasticTrainer._fold_gdn_tally(trainer) == (
-        "; gated delta rule: 2 sites, 16 serial chunk steps a train step"
+        "; gated delta rule: 2 sites (0 in the kernel), "
+        "16 serial chunk steps a train step"
     )
     assert (stats.gdn_sites, stats.gdn_chunk_steps) == (2, 16)
+    assert stats.gdn_kernel_sites == 0
     assert ElasticTrainer._fold_gdn_tally(trainer) == ""  # said once
     # a twin that came whole out of a cache of executables traced nothing
     ElasticTrainer._first_build(trainer, "step_safe")
     assert ElasticTrainer._fold_gdn_tally(trainer) == ""
     assert (stats.gdn_sites, stats.gdn_chunk_steps) == (2, 16)
-    assert {"gdn_sites", "gdn_chunk_steps"} <= set(stats.as_dict())
+    assert {
+        "gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites"
+    } <= set(stats.as_dict())
+    # a step whose mixers have heads of whole lane tiles is built from the
+    # kernels, and the line and the stats say so
+    wide = _cfg(
+        num_layers=2, layer_pattern="GE", gdn_key_dim=128,
+        gdn_value_dim=128, gdn_chunk=16,
+    )
+    wide_params = _weights(wide)
+    ElasticTrainer._first_build(trainer, "step_donating_wide")
+    build_train_step(wide, mesh, tx, donate=False).lower(
+        TrainState(
+            step=jnp.zeros((), jnp.int32), params=wide_params,
+            opt_state=tx.init(wide_params),
+        ), x, y,
+    )
+    assert ElasticTrainer._fold_gdn_tally(trainer) == (
+        "; gated delta rule: 1 sites (1 in the kernel), "
+        "8 serial chunk steps a train step"
+    )
+    assert (
+        stats.gdn_sites, stats.gdn_chunk_steps, stats.gdn_kernel_sites
+    ) == (1, 8, 1)
     # a model without the kind never moves it
     dense = tiny()
     before = gdn_tally()

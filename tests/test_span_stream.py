@@ -776,6 +776,55 @@ def test_begin_lock_reader_takes_the_mean_of_the_due_saves():
         assert taken == (w["name"] == "gpt2-124m.save-kill-resume")
 
 
+KERNEL_SHARE_RUNS = {
+    "every_site_in_the_kernel": ("GEGE", {"gdn_sites": 3, "gdn_kernel_sites": 3}, 100.0),
+    "one_site_of_three_plain": ("GEGE", {"gdn_sites": 3, "gdn_kernel_sites": 2}, 200 / 3),
+    "no_site_in_the_kernel": ("GEGE", {"gdn_sites": 3, "gdn_kernel_sites": 0}, 0.0),
+    "a_program_without_the_counter": ("GEGE", {"gdn_sites": 3, "gdn_chunk_steps": 768}, None),
+    "no_step_was_traced": ("GEGE", {"gdn_sites": 0, "gdn_kernel_sites": 0}, None),
+    "a_window_without_the_record": ("GEGE", None, None),
+    "a_configuration_without_the_kind": ("MEM*", {"gdn_sites": 0, "gdn_kernel_sites": 0}, None),
+    "the_old_blocks": ("", {"gdn_sites": 3, "gdn_kernel_sites": 3}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_SHARE_RUNS))
+def test_kernel_sites_share_reads_the_two_counters(case):
+    """``gdn.kernel_sites_share`` (ISSUE 44): 100 x ``gdn_kernel_sites``
+    over ``gdn_sites`` of the window's ``pipeline`` record; nothing where
+    the program keeps no such counter (the parent's traced run prints what
+    it printed) or the configuration no Gated DeltaNet layer."""
+    pattern, pipeline, want = KERNEL_SHARE_RUNS[case]
+    mod = _reader("gdn.kernel_sites_share")
+    run = types.SimpleNamespace(
+        config={"model": {"layer_pattern": pattern}},
+        window={} if pipeline is None else {"pipeline": pipeline},
+    )
+    got = mod.read(run)
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+def test_kernel_sites_share_is_declared_as_its_reader_says():
+    mod = _reader("gdn.kernel_sites_share")
+    steps = _reader("gdn.serial_chunk_steps")
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert bench["per_layer"][-1] == {
+        "name": "gdn.kernel_sites_share", "unit": mod.UNIT,
+        "better": "higher", "source": "program_counter",
+        "layer": mod.LAYER, "moves": mod.MOVES,
+        "workloads": ["qwen3-next-80b-a3b-d4.steady"],
+    }
+    assert (mod.UNIT, mod.LAYER, mod.MOVES) == ("%", "kernels", "tokens_per_s")
+    for w in bench["workloads"]:
+        path = os.path.join(REPO, "benchmark", "cells", w["name"] + ".json")
+        with open(path) as f:
+            cell = json.load(f)
+        assert mod.CELLS(cell) == steps.CELLS(cell) == (
+            w["name"] == "qwen3-next-80b-a3b-d4.steady"
+        )
+
+
 # -- 7. the telemetry writer's race -----------------------------------------
 def test_two_threads_publish_one_file(tmp_path):
     """The span heartbeat and the loop's report write one file from two

@@ -134,7 +134,7 @@ def test_rebuild_drops_the_executable(accel):
 # share of the routing (PR 37), the streaming attention tally's four
 # (PR 38), an incarnation's way up and the restart behind it (PR 40), the
 # shard lock's side of the due saves (PR 42), the Gated DeltaNet tally's
-# two (PR 43)
+# two (PR 43) and its sites in the kernels (PR 44)
 AS_DICT_KEYS = [
     "attn_square_sites", "attn_stream_blocks_rect",
     "attn_stream_blocks_walked", "attn_stream_rect_sites",
@@ -142,7 +142,7 @@ AS_DICT_KEYS = [
     "attn_tri_sites", "begin_lock_s", "comm_overlap_pct",
     "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "donated_bytes", "donated_steps",
-    "gdn_chunk_steps", "gdn_sites", "grad_bytes_raw", "grad_bytes_wire",
+    "gdn_chunk_steps", "gdn_kernel_sites", "gdn_sites", "grad_bytes_raw", "grad_bytes_wire",
     "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",
     "grad_sync_ms", "grad_sync_path", "lock_local_answers",

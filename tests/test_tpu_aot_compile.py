@@ -213,6 +213,50 @@ def test_streaming_attention_compiles_at_heads_of_256(
     assert fa.stream_tally() - before == (sites, 0, 136 * sites, 256 * sites)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_delta_rule_chunk_kernels_compile_at_the_cell(
+    direction, one_chip, monkeypatch
+):
+    """The Qwen3-Next cell's Gated DeltaNet layer, 1 x 8192 at 16 key / 32
+    value heads of 128 in chunks of 64, bfloat16: the two kernels around
+    the serial pass forward, all four under ``grad``; no [64, 64] square
+    of a value head and chunk is left in the program around them."""
+    from dlrover_tpu.ops import gated_delta
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    B, T, Hk, Hv, d, C = 1, 8192, 16, 32, 128, 64
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [
+        sds((B, T, Hk, d)), sds((B, T, Hk, d)), sds((B, T, Hv, d)),
+        sds((B, T, Hv), jnp.float32), sds((B, T, Hv), jnp.float32),
+    ]
+
+    def rule(*a):
+        return gated_delta.gated_delta_chunked(*a, C)
+
+    before = gated_delta.gdn_tally()
+    if direction == "fwd":
+        text = _compile_for_chip(rule, *args).as_text()
+        want, steps = ["gdn_chunk_wy_fwd", "gdn_chunk_read_fwd"], T // C
+    else:
+        text = _compile_for_chip(
+            jax.grad(lambda *a: jnp.sum(rule(*a) ** 2), argnums=range(5)),
+            *args,
+        ).as_text()
+        want = [
+            "gdn_chunk_wy_fwd", "gdn_chunk_read_fwd",
+            "gdn_chunk_wy_bwd", "gdn_chunk_read_bwd",
+        ]
+        steps = 2 * T // C
+    for kernel in want:
+        assert kernel in text, kernel
+    assert f"f32[{T // C},{B},{Hk},{Hv // Hk},{C},{C}]" not in text
+    assert gated_delta.gdn_tally() - before == (1, steps, 1)
+
+
 # the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here
 ADAM_LEAVES = {
     "gpt2_wte": (50257, 768),
@@ -360,6 +404,50 @@ def test_sharded_attention_compiles_for_four_chips(topo, monkeypatch):
         lambda q, k, v: _causal_attention(q, k, v, mesh, layout="bhtd"),
         *qkv,
     )
+
+
+def test_sharded_delta_rule_compiles_for_four_chips(topo, monkeypatch):
+    """The Gated DeltaNet mixer at the Qwen3-Next widths on a mesh of four
+    chips: alone its kernels cannot be partitioned; the mixer runs them
+    under shard_map, batch over fsdp and heads over tp."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dlrover_tpu.ops import gated_delta
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), devices=topo.devices[:4])
+    B, T, Hk, Hv, d, C = 2, 2048, 16, 32, 128, 64
+
+    def sds(shape, dtype=jnp.bfloat16):
+        spec = P(("dp", "fsdp"), None, "tp", None)[:len(shape)]
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec))
+        )
+
+    args = [
+        sds((B, T, Hk, d)), sds((B, T, Hk, d)), sds((B, T, Hv, d)),
+        sds((B, T, Hv), jnp.float32), sds((B, T, Hv), jnp.float32),
+    ]
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        jax.jit(
+            lambda *a: gated_delta.gated_delta_chunked(*a, C)
+        ).lower(*args)
+    text = _compile_for_chip(
+        jax.grad(
+            lambda *a: jnp.sum(gated_delta._delta_rule(*a, C, mesh) ** 2),
+            argnums=range(5),
+        ),
+        *args,
+    ).as_text()
+    for kernel in ("gdn_chunk_wy_bwd", "gdn_chunk_read_bwd"):
+        assert kernel in text, kernel
+    with pytest.raises(ValueError, match="do not divide dp\\*fsdp=2"):
+        gated_delta._delta_rule(
+            *(jnp.zeros(a.shape[:0] + (3,) + a.shape[1:], a.dtype)
+              for a in args), C, mesh,
+        )
 
 
 def test_explicit_sync_step_over_dp_x_tp_compiles_for_four_chips(

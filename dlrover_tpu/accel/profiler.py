@@ -149,6 +149,14 @@ class PipelineStats:
     gdn_sites: int = 0
     gdn_chunk_steps: int = 0
     gdn_kernel_sites: int = 0
+    # the width of a head's query and key summed over the attention sites
+    # of the train step program this process traced last
+    # (models/transformer.py ``ScoreLanes``): what the attention call was
+    # given, and what the model states. They differ where the call pads
+    # (a latent attention's 192 through kernels of whole lane tiles). Set
+    # when the trainer logs the step it built; 0 / 0 without attention
+    attn_score_lanes: int = 0
+    attn_score_lanes_used: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was

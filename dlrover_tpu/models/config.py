@@ -13,9 +13,10 @@ from dataclasses import dataclass, replace
 from typing import Optional, Tuple
 
 # layer_pattern's alphabet (nemotron_h's, and "G") -> the key of the
-# layer's one mixer in the parameter tree: Mamba-2, Gated DeltaNet,
-# attention, experts
-LAYER_KINDS = {"M": "ssm", "G": "gdn", "*": "attn", "E": "moe"}
+# layer's one mixer in the parameter tree: Mamba-2, the gated delta rule
+# (Gated DeltaNet's, or with ``gdn_decay`` "channel" Kimi Delta
+# Attention's), attention, experts, the dense feed-forward
+LAYER_KINDS = {"M": "ssm", "G": "gdn", "*": "attn", "E": "moe", "-": "mlp"}
 
 
 @dataclass(frozen=True)
@@ -33,7 +34,8 @@ class TransformerConfig:
     # the kind of every layer, one character a layer, in the alphabet of
     # the ``nemotron_h`` configs (``hybrid_override_pattern``): "M" a
     # Mamba-2 layer, "*" an attention layer, "E" an expert layer, and
-    # "G" a Gated DeltaNet layer; each layer is ONE mixer, ``x +
+    # "-" a dense feed-forward layer of ``dense_mlp_dim``, and "G" a
+    # Gated DeltaNet layer; each layer is ONE mixer, ``x +
     # mixer(norm(x))``, so a block of mixer then experts is two entries
     # ("GEGEGE*E": one period of ``qwen3_next``). "" = every layer is the
     # attention + FFN block (``moe_every`` places the experts).
@@ -66,6 +68,20 @@ class TransformerConfig:
     # "sigmoid" => the query projection is twice as wide, a head's
     # second half a gate: ``attention * sigmoid(gate)`` before ``wo``
     attn_gate: str = ""
+    # "latent" => keys and values come from one ``kv_latent_dim`` wide
+    # latent a token (down-projection, RMSNorm, up-projection) beside one
+    # rotated key of ``qk_rope_dim`` that every head shares: a head's
+    # query and key are ``qk_nope_dim`` unrotated dims then
+    # ``qk_rope_dim`` rotated ones, its value ``v_head_dim`` (MLA,
+    # DeepSeek-V2's, the query projected whole); as many key/value heads
+    # as query heads, ``attn_head_dim`` and ``rope_dim`` unused
+    attn_kind: str = ""
+    kv_latent_dim: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # width of a dense feed-forward layer ("-"); 0 => ``ffn_dim``
+    dense_mlp_dim: int = 0
     # MoE: every `moe_every`-th block uses an expert FFN (SwiGLU experts
     # where `swiglu`, the GELU pair where not)
     num_experts: int = 0
@@ -100,6 +116,12 @@ class TransformerConfig:
     # step of the auxiliary-loss-free balance rule that moves the
     # selection bias after every train step; 0 leaves it where it is
     router_bias_rate: float = 0.0
+    # group-limited selection: the experts lie in ``router_groups`` equal
+    # groups by index, a group scores the sum of its two best selection
+    # scores, and a token chooses among the experts of its
+    # ``router_groups_kept`` best groups only (DeepSeek-V3's). 1 => none
+    router_groups: int = 1
+    router_groups_kept: int = 1
     # weight of the load-balance loss; None => ``loss_fn``'s argument
     router_balance_weight: Optional[float] = None
     # width of the one shared expert every token passes beside its
@@ -137,6 +159,18 @@ class TransformerConfig:
     gdn_value_dim: int = 0
     gdn_conv: int = 4
     gdn_chunk: int = 64
+    # what decays the state: "head" => one scalar a value head and step,
+    # ``-exp(A_log) * softplus(a + dt_bias)`` (Gated DeltaNet); "channel"
+    # => a vector over the key's channels a head and step, the log-decay
+    # ``gdn_decay_bound * sigmoid(exp(A_log) * (f + dt_bias))`` in
+    # ``(gdn_decay_bound, 0)`` (Kimi Delta Attention; as many key as
+    # value heads). The chunked rule divides by no more than 16 steps'
+    # decay, so the bound must keep that in float32: at least -5
+    gdn_decay: str = "head"
+    gdn_decay_bound: float = 0.0
+    # the output gate: "silu" => ``silu`` of a projection a channel;
+    # "head_sigmoid" => ``sigmoid`` of one projection a head
+    gdn_gate: str = "silu"
     # sequence-parallel attention scheme when the mesh has sp > 1:
     # "ring" (P2P pipeline, any head count) or "ulysses" (two
     # all-to-alls; needs (heads/tp) % sp == 0) — parallel/{ring_
@@ -187,7 +221,10 @@ class TransformerConfig:
             ("norm_weight", ("", "one_plus")),
             ("qk_norm_span", ("token", "head")),
             ("attn_gate", ("", "sigmoid")),
+            ("attn_kind", ("", "latent")),
             ("shared_expert_gate", ("", "sigmoid")),
+            ("gdn_decay", ("head", "channel")),
+            ("gdn_gate", ("silu", "head_sigmoid")),
         ):
             if getattr(self, name) not in kinds:
                 raise ValueError(
@@ -209,6 +246,43 @@ class TransformerConfig:
                 f"Gated DeltaNet layers need gdn_value_heads "
                 f"({self.gdn_value_heads}) a multiple of gdn_key_heads "
                 f"({self.gdn_key_heads}) and both head widths"
+            )
+        if self.gdn_decay == "channel" and "G" in self.layer_pattern and (
+            self.gdn_value_heads != self.gdn_key_heads
+            or not -5.0 <= self.gdn_decay_bound < 0.0
+        ):
+            raise ValueError(
+                f"a decay a key channel needs as many key heads "
+                f"({self.gdn_key_heads}) as value heads "
+                f"({self.gdn_value_heads}) and gdn_decay_bound "
+                f"({self.gdn_decay_bound}) in [-5, 0)"
+            )
+        if self.attn_kind == "latent" and (
+            min(self.kv_latent_dim, self.qk_nope_dim, self.v_head_dim) < 1
+            or self.qk_rope_dim < 2 or self.qk_rope_dim % 2
+            or self.num_kv_heads not in (None, self.num_heads)
+            or self.attn_gate or self.rope_dim
+            or (self.qk_norm and self.qk_norm_span != "head")
+        ):
+            raise ValueError(
+                "latent attention needs kv_latent_dim, qk_nope_dim, an even "
+                "qk_rope_dim and v_head_dim, as many key/value heads as "
+                "query heads, no output gate, no rope_dim and a q / k norm "
+                "a head"
+            )
+        if self.router_groups < 1 or not (
+            1 <= self.router_groups_kept <= self.router_groups
+        ) or (self.router_groups > 1 and (
+            self.num_experts % self.router_groups
+            or self.num_experts // self.router_groups < 2
+            or self.moe_top_k
+            > self.router_groups_kept * self.num_experts // self.router_groups
+        )):
+            raise ValueError(
+                f"router_groups {self.router_groups} (kept "
+                f"{self.router_groups_kept}) do not split {self.num_experts} "
+                f"experts into groups of two or more that hold "
+                f"{self.moe_top_k} a token"
             )
         if self.mlp_activation not in ("", "relu2"):
             raise ValueError(
@@ -237,6 +311,13 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.attn_head_dim or self.model_dim // self.num_heads
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of a head's query and key: what the scores contract."""
+        if self.attn_kind == "latent":
+            return self.qk_nope_dim + self.qk_rope_dim
+        return self.head_dim
 
     @property
     def position_kind(self) -> str:

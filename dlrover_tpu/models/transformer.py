@@ -18,7 +18,7 @@ TPU-first design notes:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,9 +81,10 @@ def init_params(key, cfg: TransformerConfig) -> Params:
     def qk_norms():
         # a weight a head and dim, or one vector for all heads
         a_head = cfg.qk_norm_span == "token"
+        qk = cfg.qk_head_dim
         return {
-            "q_norm": {"scale": norm_scale((h, hd) if a_head else (hd,))},
-            "k_norm": {"scale": norm_scale((kvh, hd) if a_head else (hd,))},
+            "q_norm": {"scale": norm_scale((h, qk) if a_head else (qk,))},
+            "k_norm": {"scale": norm_scale((kvh, qk) if a_head else (qk,))},
         }
 
     keys = iter(jax.random.split(key, 8 + cfg.num_layers * 16))
@@ -104,6 +105,18 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         params["lm_head"] = dense(next(keys), (d, cfg.vocab_size), d)
 
     def attention():
+        if cfg.attn_kind == "latent":
+            latent, nope = cfg.kv_latent_dim, cfg.qk_nope_dim
+            rope, vd = cfg.qk_rope_dim, cfg.v_head_dim
+            return {
+                "wq": dense(next(keys), (d, h, nope + rope), d),
+                # [latent | the one rotated key every head shares]
+                "w_kva": dense(next(keys), (d, latent + rope), d),
+                "kv_norm": {"scale": norm_scale((latent,))},
+                # a head's [unrotated key | value] from the latent
+                "w_kvb": dense(next(keys), (latent, h, nope + vd), latent),
+                "wo": dense(next(keys), (h, vd, d), h * vd),
+            }
         # with an output gate a head's projection is [query | gate]
         q_width = 2 * hd if cfg.attn_gate else hd
         return {
@@ -111,6 +124,20 @@ def init_params(key, cfg: TransformerConfig) -> Params:
             "wk": dense(next(keys), (d, kvh, hd), d),
             "wv": dense(next(keys), (d, kvh, hd), d),
             "wo": dense(next(keys), (h, hd, d), h * hd),
+        }
+
+    def dense_mlp(width):
+        if cfg.swiglu:
+            return {
+                "w_gate": dense(next(keys), (d, width), d),
+                "w_up": dense(next(keys), (d, width), d),
+                "w_down": dense(next(keys), (width, d), width),
+            }
+        return {
+            "w_up": dense(next(keys), (d, width), d),
+            "b_up": jnp.zeros((width,), pd),
+            "w_down": dense(next(keys), (width, d), width),
+            "b_down": jnp.zeros((d,), pd),
         }
 
     def experts():
@@ -127,6 +154,7 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         "G": lambda: init_gated_delta_params(next(keys), cfg, pd),
         "*": attention,
         "E": experts,
+        "-": lambda: dense_mlp(cfg.dense_mlp_dim or f),
     }
     for kind in cfg.layer_pattern:
         # one mixer a layer behind one norm
@@ -153,19 +181,8 @@ def init_params(key, cfg: TransformerConfig) -> Params:
             layer.update(qk_norms())
         if is_moe_layer(cfg, i):
             layer["moe"] = experts()
-        elif cfg.swiglu:
-            layer["mlp"] = {
-                "w_gate": dense(next(keys), (d, f), d),
-                "w_up": dense(next(keys), (d, f), d),
-                "w_down": dense(next(keys), (f, d), f),
-            }
         else:
-            layer["mlp"] = {
-                "w_up": dense(next(keys), (d, f), d),
-                "b_up": jnp.zeros((f,), pd),
-                "w_down": dense(next(keys), (f, d), f),
-                "b_down": jnp.zeros((d,), pd),
-            }
+            layer["mlp"] = dense_mlp(f)
         params["layers"].append(layer)
     if cfg.scan_layers:
         params["layers"] = stack_layer_params(params["layers"])
@@ -202,11 +219,33 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         axes["lm_head"] = ("embed", "vocab")
 
     def attention():
+        if cfg.attn_kind == "latent":
+            return {
+                "wq": ("embed", "heads", "head_dim"),
+                "w_kva": ("embed", None),
+                "kv_norm": {"scale": (None,)},
+                "w_kvb": (None, "heads", "head_dim"),
+                "wo": ("heads", "head_dim", "embed"),
+            }
         return {
             "wq": ("embed", "heads", "head_dim"),
             "wk": ("embed", "kv_heads", "head_dim"),
             "wv": ("embed", "kv_heads", "head_dim"),
             "wo": ("heads", "head_dim", "embed"),
+        }
+
+    def dense_mlp():
+        if cfg.swiglu:
+            return {
+                "w_gate": ("embed", "mlp"),
+                "w_up": ("embed", "mlp"),
+                "w_down": ("mlp", "embed"),
+            }
+        return {
+            "w_up": ("embed", "mlp"),
+            "b_up": ("mlp",),
+            "w_down": ("mlp", "embed"),
+            "b_down": ("norm",),
         }
 
     def experts():
@@ -240,8 +279,9 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         }
 
     mixers = {
-        "M": mamba2_logical_axes, "G": gated_delta_logical_axes,
-        "*": attention, "E": experts,
+        "M": mamba2_logical_axes,
+        "G": lambda: gated_delta_logical_axes(cfg),
+        "*": attention, "E": experts, "-": dense_mlp,
     }
     for kind in cfg.layer_pattern:
         layer = {"norm": {"scale": ("norm",)}}
@@ -266,19 +306,8 @@ def logical_axes(cfg: TransformerConfig) -> Params:
             layer.update(qk_norms())
         if is_moe_layer(cfg, i):
             layer["moe"] = experts()
-        elif cfg.swiglu:
-            layer["mlp"] = {
-                "w_gate": ("embed", "mlp"),
-                "w_up": ("embed", "mlp"),
-                "w_down": ("mlp", "embed"),
-            }
         else:
-            layer["mlp"] = {
-                "w_up": ("embed", "mlp"),
-                "b_up": ("mlp",),
-                "w_down": ("mlp", "embed"),
-                "b_down": ("norm",),
-            }
+            layer["mlp"] = dense_mlp()
         axes["layers"].append(layer)
     if cfg.scan_layers:
         layer0 = axes["layers"][0]
@@ -361,7 +390,38 @@ def _rope(x, positions, theta: float, layout: str = "bthd", dims: int = 0):
     return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
-def _causal_attention(q, k, v, mesh=None, layout: str = "bthd"):
+class ScoreLanes(NamedTuple):
+    """Attention sites traced so far in this process, by the width of a
+    head's query and key: what the attention call was given (``called``)
+    and what the model states (``used``), each summed over the sites.
+    They differ where a call pads: a latent attention's 192-wide scores
+    run through kernels that take one width of whole lane tiles for q, k
+    and v. Counted when a program is traced, as the kernels' tallies
+    (``ops/flash_attention.py``)."""
+
+    called: int = 0
+    used: int = 0
+
+    def __sub__(self, other):
+        return ScoreLanes(*(a - b for a, b in zip(self, other)))
+
+
+_score_lanes = ScoreLanes()
+
+
+def score_lanes_tally() -> ScoreLanes:
+    return _score_lanes
+
+
+def _tally_score_lanes(called: int, used: int):
+    global _score_lanes
+    _score_lanes = ScoreLanes(
+        _score_lanes.called + called, _score_lanes.used + used
+    )
+
+
+def _causal_attention(q, k, v, mesh=None, layout: str = "bthd",
+                      sm_scale: Optional[float] = None):
     """Single-shard causal attention, [B,T,H,D] or [B,H,T,D].
 
     Dispatches to the Pallas flash-attention kernel on TPU (fused
@@ -389,7 +449,9 @@ def _causal_attention(q, k, v, mesh=None, layout: str = "bthd"):
     from dlrover_tpu.ops.flash_attention import flash_attention
 
     def attend(q, k, v):
-        return flash_attention(q, k, v, causal=True, layout=layout)
+        return flash_attention(
+            q, k, v, causal=True, layout=layout, sm_scale=sm_scale
+        )
 
     def spec(batch, heads):
         if layout == "bhtd":
@@ -439,8 +501,11 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
                      norm: str = "attn_norm"):
     """``x + attention(norm(x))``; ``norm`` names the layer's norm (a
     one-mixer layer of a ``layer_pattern`` has the one, "norm")."""
+    if cfg.attn_kind == "latent":
+        return _latent_attention(x, layer, cfg, mesh, positions, norm)
     h = _norm(x, layer[norm], cfg)
     sp = mesh is not None and mesh.shape.get("sp", 1) > 1
+    _tally_score_lanes(cfg.head_dim, cfg.head_dim)
     # single-shard path: kernel-native [B,H,T,D] straight from the
     # projection einsums — no relayout transposes around the attention
     # kernel. SP schemes shard/permute the seq dim and keep [B,T,H,D].
@@ -484,6 +549,68 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
             ).astype(o.dtype)
     out = "bthk,hkd->btd" if sp else "bhtk,hkd->btd"
     return x + jnp.einsum(out, o, layer["attn"]["wo"].astype(o.dtype))
+
+
+_LANES = 128
+
+
+def _attention_of_two_widths(q, k, v, mesh):
+    """Causal attention [B, H, T, .] whose scores contract another width
+    than its values have (q, k 192 and v 128 wide, say), scaled by the
+    stated score width. The attention kernels take ONE width of whole
+    lane tiles for q, k and v, so the call pads: zeros on q and k leave
+    every score as it is, v is padded and the output sliced. ``ScoreLanes``
+    counts the width called beside the width stated."""
+    qk, vd = q.shape[-1], v.shape[-1]
+    width = -(-max(qk, vd) // _LANES) * _LANES
+    _tally_score_lanes(width, qk)
+
+    def pad(t):
+        return jnp.pad(t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
+
+    return _causal_attention(
+        pad(q), pad(k), pad(v), mesh, layout="bhtd", sm_scale=qk**-0.5
+    )[..., :vd]
+
+
+def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
+                      norm: str):
+    """``x + attention(norm(x))`` with keys and values from a latent
+    (``cfg.attn_kind`` "latent"): ``[c | k_rope] = h W_kva``, ``[k_nope |
+    v] = RMSNorm(c) W_kvb`` a head, the one ``k_rope`` shared by every
+    head; a head's q and k are ``[nope | rope]``, normed whole where
+    ``qk_norm`` and then rotated on the rope dims alone; causal softmax
+    over ``qk_nope_dim + qk_rope_dim`` wide scores, values ``v_head_dim``
+    wide (``_attention_of_two_widths``)."""
+    if mesh is not None and mesh.shape.get("sp", 1) > 1:
+        raise NotImplementedError(
+            "latent attention knows no sequence-parallel scheme"
+        )
+    a = layer["attn"]
+    nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    latent = cfg.kv_latent_dim
+    h = _norm(x, layer[norm], cfg)
+    q = jnp.einsum("btd,dhk->bhtk", h, a["wq"].astype(h.dtype))
+    with jax.named_scope("scope/layer/attn/kv_down"):
+        down = h @ a["w_kva"].astype(h.dtype)
+    c = _norm(down[..., :latent], a["kv_norm"], cfg)
+    with jax.named_scope("scope/layer/attn/kv_up"):
+        kv = jnp.einsum("btc,chk->bhtk", c, a["w_kvb"].astype(h.dtype))
+    k_rope = jnp.broadcast_to(
+        down[:, None, :, latent:], (*kv.shape[:3], rope)
+    )
+    k = jnp.concatenate([kv[..., :nope], k_rope], axis=-1)
+    v = kv[..., nope:]
+    if cfg.qk_norm:
+        q = _qk_norm(q, layer["q_norm"], cfg, "bhtd")
+        k = _qk_norm(k, layer["k_norm"], cfg, "bhtd")
+    if cfg.position_kind == "rope":
+        q, k = (jnp.concatenate([
+            t[..., :nope],
+            _rope(t[..., nope:], positions, cfg.rope_theta, "bhtd"),
+        ], axis=-1) for t in (q, k))
+    o = _attention_of_two_widths(q, k, v, mesh)
+    return x + jnp.einsum("bhtk,hkd->btd", o, a["wo"].astype(o.dtype))
 
 
 @jax.named_scope("scope/layer/ssm")
@@ -531,6 +658,8 @@ def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None,
             routed_scale=cfg.routed_scale,
             held=cfg.held_experts,
         )
+        if cfg.router_groups > 1:
+            kw["groups"] = (cfg.router_groups, cfg.router_groups_kept)
         if cfg.mlp_activation == "relu2":
             kw["activation"] = relu2
         if mesh is not None and mesh.size > 1:
@@ -704,15 +833,23 @@ def forward(
         if kind == "*":
             x = _attention_block(x, layer, cfg, mesh, positions, "norm")
             return x, None
-        return _mlp_block(x, layer, cfg, mesh, moe_axis, "norm")
+        x, aux = _mlp_block(x, layer, cfg, mesh, moe_axis, "norm")
+        return x, aux if kind == "E" else None
 
     if cfg.remat:
         block = jax.checkpoint(block)
-        mixer_layer = jax.checkpoint(mixer_layer, static_argnums=(2,))
     if cfg.layer_pattern:
         loads = []
         for kind, layer in zip(cfg.layer_pattern, params["layers"]):
-            x, aux = mixer_layer(x, layer, kind)
+            one_layer = functools.partial(mixer_layer, kind=kind)
+            if cfg.remat:
+                # a wrapper a layer: ``jax.checkpoint`` keeps the trace of
+                # a function it has seen at these shapes, and a layer that
+                # came out of that cache is not traced, so the tallies a
+                # trace keeps (``gdn_tally``, ``ScoreLanes``) would count
+                # one layer of each kind
+                one_layer = jax.checkpoint(one_layer)
+            x, aux = one_layer(x, layer)
             if aux is not None:
                 loads.append(aux["load"])
                 aux_total = dict(
@@ -776,11 +913,12 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
     the whole decode loop stays inside one compiled ``lax.scan``."""
     if (
         cfg.layer_pattern or cfg.attn_gate or cfg.rope_dim
-        or cfg.qk_norm_span != "token"
+        or cfg.qk_norm_span != "token" or cfg.attn_kind
     ):
         raise NotImplementedError(
             "cached decoding knows the attention + FFN block only, "
-            "ungated, wholly rotated, its QK-norm over the token"
+            "ungated, wholly rotated, its QK-norm over the token, its "
+            "keys and values projected and not from a latent"
         )
     dt = _dtype(cfg)
     shape = (cfg.num_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)

@@ -41,6 +41,27 @@ over all chunks outside it. It is differentiated by hand for that reason
 the reversed loop and keeps the float32 state of every chunk).
 ``gdn_tally()`` counts the passes traced and their steps.
 
+``cfg.gdn_decay`` "channel" is Kimi Delta Attention's rule (arXiv:
+2510.26692): the decay is a vector over the key's channels, the transition
+``(I - beta_t k_t k_t^T) Diag(alpha_t)``, and ``gamma`` a row ``[C, d_k]``
+a chunk. The decay then no longer factors out of the products over the
+key's channels, ``A_ij = sum_c kbeta_ic k_jc exp(gamma_ic - gamma_jc)``:
+``_decayed_scores`` forms such a masked square in row blocks of
+``SUB_BLOCK`` steps, each a matmul of two factors taken at the block's first
+row, ``exp(gamma_i - gamma_b)`` <= 1 on the rows and ``exp(gamma_b -
+gamma_j)`` on the columns: <= 1 before the block and, on the block's own
+diagonal square, a division by at most ``SUB_BLOCK - 1`` steps' decay. A
+step's log-decay is bounded below (``cfg.gdn_decay_bound`` >= -5), so that
+quotient stays under ``exp(75)`` in float32. The pass carries ``a`` as a
+row over the key's channels and takes the keys already decayed to the
+chunk's end; everything else (the pass and its hand-written reversal, the
+triangle's inverse's cotangent, the tally) is the one code for both kinds.
+The ``gdn_chunk_*`` kernels build their squares from scalar decays and take
+no such site (``gated_delta_kernels.fits``). Such a layer's decays start
+slow, and where a chunk's keys are nearly parallel and hardly decay
+``unit_lower_inverse``'s product form loses the inverse to cancellation:
+this kind inverts by halves (``unit_lower_inverse_blocked``).
+
 The spans of a layer: ``scope/layer/gdn/{in_proj,conv,scan,gate,out_proj}``.
 """
 
@@ -56,6 +77,8 @@ from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.mamba2 import causal_conv1d, gated_group_rmsnorm
 
 L2_EPS = 1e-6  # of the unit-length q and k (the source's ``l2norm``)
+SUB_BLOCK = 16  # steps whose decay a vector-decay chunk may divide by
+DT_SHARE = (0.002, 0.2)  # of ``sigmoid(dt_bias)`` at init, vector decay
 
 
 def init_gated_delta_params(key, cfg, dtype):
@@ -67,14 +90,16 @@ def init_gated_delta_params(key, cfg, dtype):
     d, Hv = cfg.model_dim, cfg.gdn_value_heads
     key_w = cfg.gdn_key_heads * cfg.gdn_key_dim
     val_w = Hv * cfg.gdn_value_dim
+    # the output gate's projection: a channel, or one a head
+    gate_w = Hv if cfg.gdn_gate == "head_sigmoid" else val_w
     kq, kz, kb, kc, ka, ko = jax.random.split(key, 6)
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape) * fan_in**-0.5).astype(dtype)
 
-    return {
+    p = {
         "w_qkv": dense(kq, (d, 2 * key_w + val_w), d),
-        "w_z": dense(kz, (d, val_w), d),
+        "w_z": dense(kz, (d, gate_w), d),
         "w_ba": dense(kb, (d, 2 * Hv), d),
         "conv_w": dense(kc, (cfg.gdn_conv, 2 * key_w + val_w), cfg.gdn_conv),
         "dt_bias": jnp.ones((Hv,), dtype),
@@ -84,10 +109,26 @@ def init_gated_delta_params(key, cfg, dtype):
         "norm": jnp.ones((cfg.gdn_value_dim,), dtype),
         "w_out": dense(ko, (val_w, d), val_w),
     }
+    if cfg.gdn_decay == "channel":
+        # the write strength's projection alone (``w_b``), the decay's at
+        # full rank (``w_f``) with its bias a channel, ``A_log`` from 0
+        del p["w_ba"]
+        kb, kf = jax.random.split(kb)
+        p["w_b"] = dense(kb, (d, Hv), d)
+        p["w_f"] = dense(kf, (d, key_w), d)
+        p["A_log"] = jnp.zeros((Hv,), dtype)
+        # sigmoid(dt_bias), the share of the bound a step decays by where
+        # the projection reads 0, log-uniform over DT_SHARE
+        lo, hi = DT_SHARE
+        share = jnp.exp(
+            jax.random.uniform(ka, (key_w,)) * jnp.log(hi / lo) + jnp.log(lo)
+        )
+        p["dt_bias"] = jnp.log(share / (1.0 - share)).astype(dtype)
+    return p
 
 
-def gated_delta_logical_axes():
-    return {
+def gated_delta_logical_axes(cfg):
+    axes = {
         "w_qkv": ("embed", None),
         "w_z": ("embed", None),
         "w_ba": ("embed", None),
@@ -97,6 +138,10 @@ def gated_delta_logical_axes():
         "norm": (None,),
         "w_out": (None, "embed"),
     }
+    if cfg.gdn_decay == "channel":
+        del axes["w_ba"]
+        axes.update(w_b=("embed", None), w_f=("embed", None))
+    return axes
 
 
 class GdnTally(NamedTuple):
@@ -135,6 +180,17 @@ def l2norm(x):
     return xf * lax.rsqrt(jnp.sum(xf * xf, -1, keepdims=True) + L2_EPS)
 
 
+def head_gated_rmsnorm(o, z, weight, eps: float):
+    """``weight * RMSNorm(o) * sigmoid(z)`` a head: o [B, T, H * d_v], one
+    gate a head z [B, T, H], the norm over each head's own d_v with the
+    one weight [d_v]. float32."""
+    B, T, H = z.shape
+    of = o.astype(jnp.float32).reshape(B, T, H, -1)
+    of = of * lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
+    gate = jax.nn.sigmoid(z.astype(jnp.float32))[..., None]
+    return (of * weight.astype(jnp.float32) * gate).reshape(o.shape)
+
+
 @jax.custom_vjp
 def unit_lower_inverse(A):
     """``(I - A)^{-1}`` for strictly lower triangular ``A [..., C, C]``
@@ -166,6 +222,75 @@ def _unit_lower_inverse_bwd(T, dT):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
+_INVERSE_BASE = 8  # diagonal blocks the product form is still good for
+
+
+def _blocked_inverse(A):
+    C = A.shape[-1]
+    lead = A.shape[:-2]
+    hi = lax.Precision.HIGHEST
+    size = 1 << max(C - 1, 0).bit_length()  # whole halvings
+    if size != C:
+        A = jnp.pad(A, [(0, 0)] * len(lead) + [(0, size - C)] * 2)
+    s = min(_INVERSE_BASE, size)
+    n = size // s
+    T = unit_lower_inverse(
+        jnp.einsum("...iaib->...iab", A.reshape(*lead, n, s, n, s))
+    )  # [..., n, s, s]: the diagonal blocks' inverses
+    while s < size:
+        n = size // (2 * s)
+        low = A.reshape(*lead, n, 2, s, n, 2, s)[..., :, 1, :, :, 0, :]
+        A21 = jnp.einsum("...iaib->...iab", low)
+        T11, T22 = T[..., 0::2, :, :], T[..., 1::2, :, :]
+        T21 = jnp.matmul(
+            jnp.matmul(T22, A21, precision=hi), T11, precision=hi
+        )
+        T = jnp.concatenate([
+            jnp.concatenate([T11, jnp.zeros_like(T11)], axis=-1),
+            jnp.concatenate([T21, T22], axis=-1),
+        ], axis=-2)
+        s *= 2
+    return T[..., 0, :C, :C]
+
+
+@jax.custom_vjp
+def unit_lower_inverse_blocked(A):
+    """``unit_lower_inverse`` by halves: with ``I - A = [[L11, 0], [-A21,
+    L22]]`` the inverse is ``[[T11, 0], [T22 A21 T11, T22]]``, from
+    diagonal blocks of ``_INVERSE_BASE`` up, a level two matmuls over all
+    its blocks at once. Every intermediate is a block of the inverse
+    itself, so it is as well conditioned as the inverse is. The product
+    form's powers ``A^2, A^4, ... A^32`` of a 64-step chunk reach 1e17
+    where the chunk's keys are nearly parallel and hardly decay, and
+    cancel to a result of order 1 that float32 cannot hold: the first cell
+    whose decays start slow read NaN from step 42 on (PERF.md, Findings PR
+    45). Inside a block of 8 the powers stay under 35 and the product form
+    serves."""
+    return _blocked_inverse(A)
+
+
+def _unit_lower_inverse_blocked_fwd(A):
+    T = _blocked_inverse(A)
+    return T, T
+
+
+unit_lower_inverse_blocked.defvjp(
+    _unit_lower_inverse_blocked_fwd, _unit_lower_inverse_bwd
+)
+
+
+def _decay_state(a, S):
+    """``a`` times the state [..., d_k, d_v]: a scalar a head, or a row over
+    the key's channels (one axis more)."""
+    return (a[..., None] if a.ndim == S.ndim - 1 else a[..., None, None]) * S
+
+
+def _decay_rows(delta, V):
+    """``delta`` [..., C] times the rows of ``V`` [..., C, d_v]; ``V`` as
+    it is where the keys carry the decay (``delta`` None)."""
+    return V if delta is None else delta[..., None] * V
+
+
 def _pass_forward(U, W, K, delta, a):
     f32, act = jnp.float32, W.dtype
     _tally_pass(1, U.shape[0])
@@ -176,8 +301,8 @@ def _pass_forward(U, W, K, delta, a):
         Vn = U - jnp.einsum(
             "bgrid,bgrdv->bgriv", W, Sb, preferred_element_type=f32
         )
-        S = a[..., None, None] * S + jnp.einsum(
-            "bgjd,bgrjv->bgrdv", K, (delta[..., None] * Vn).astype(act),
+        S = _decay_state(a, S) + jnp.einsum(
+            "bgjd,bgrjv->bgrdv", K, _decay_rows(delta, Vn).astype(act),
             preferred_element_type=f32,
         )
         return S, (Vn.astype(act), Sb)
@@ -194,10 +319,12 @@ def chunk_state_pass(U, W, K, delta, a):
     [n, b, g, r, C, d_v] float32, ``W`` [n, b, g, r, C, d_k] and ``K``
     [n, b, g, C, d_k] in the activation dtype (a key head ``g`` serves its
     ``r`` value heads), ``delta = exp(gamma_C - gamma)`` [n, b, g, r, C]
-    and ``a = exp(gamma_C)`` [n, b, g, r] float32. Returns ``V' = U - W
-    S`` [n, b, g, r, C, d_v] and the state that ENTERED each chunk
-    [n, b, g, r, d_k, d_v], both in the activation dtype (what the
-    read-out's matmuls take)."""
+    and ``a = exp(gamma_C)`` [n, b, g, r] float32. Where the decay is a
+    vector over the key's channels, ``a`` is [n, b, g, r, d_k], ``K``
+    comes already times ``exp(gamma_C - gamma)`` and ``delta`` is None.
+    Returns ``V' = U - W S`` [n, b, g, r, C, d_v] and the state that
+    ENTERED each chunk [n, b, g, r, d_k, d_v], both in the activation
+    dtype (what the read-out's matmuls take)."""
     return _pass_forward(U, W, K, delta, a)
 
 
@@ -224,9 +351,9 @@ def _chunk_state_pass_bwd(res, cts):
         dVd = jnp.einsum(
             "bgjd,bgrdv->bgrjv", K, dSb, preferred_element_type=f32
         )
-        dV = dVn.astype(f32) + delta[..., None] * dVd
+        dV = dVn.astype(f32) + _decay_rows(delta, dVd)
         dS = (
-            a[..., None, None] * dS + dS_in.astype(f32)
+            _decay_state(a, dS) + dS_in.astype(f32)
             - jnp.einsum(
                 "bgrid,bgriv->bgrdv", W, dV.astype(act),
                 preferred_element_type=f32,
@@ -239,18 +366,19 @@ def _chunk_state_pass_bwd(res, cts):
         (W, K, delta, a, dVn, dS_in), reverse=True,
     )
     Vf = Vn.astype(f32)
-    dU = dVn.astype(f32) + delta[..., None] * dVd
+    dU = dVn.astype(f32) + _decay_rows(delta, dVd)
     dW = -jnp.einsum(
         "nbgriv,nbgrdv->nbgrid", dU.astype(act), S_in,
         preferred_element_type=f32,
     ).astype(act)
     dK = jnp.einsum(
-        "nbgrjv,nbgrdv->nbgjd", (delta[..., None] * Vf).astype(act), dS_out,
+        "nbgrjv,nbgrdv->nbgjd", _decay_rows(delta, Vf).astype(act), dS_out,
         preferred_element_type=f32,
     ).astype(act)
-    ddelta = jnp.sum(dVd * Vf, axis=-1)
+    ddelta = None if delta is None else jnp.sum(dVd * Vf, axis=-1)
     da = jnp.einsum(
-        "nbgrdv,nbgrdv->nbgr", dS_out, S_in, preferred_element_type=f32
+        "nbgrdv,nbgrdv->nbgrd" if a.ndim == 5 else "nbgrdv,nbgrdv->nbgr",
+        dS_out, S_in, preferred_element_type=f32,
     )
     return dU, dW, dK, ddelta, da
 
@@ -334,13 +462,123 @@ def _read_out(q, k, g, Vn, S_in):
     return own + entered * jnp.exp(gamma)[..., None]
 
 
+def _sum_rows(g, picks):
+    """``sum_j g[..., j, :] * picks[j, i]`` for rows of channels
+    [..., C, d_k], float32 at full precision."""
+    return jnp.einsum(
+        "...jd,ji->...id", g, picks, precision=lax.Precision.HIGHEST
+    )
+
+
+def _decayed_scores(x, k, gamma):
+    """``sum_c x_ic k_jc exp(gamma_ic - gamma_jc)`` for j < i and 0
+    elsewhere, [..., C, C] float32, from x, k and gamma [..., C, d_k]: the
+    masked square of a decay that is a vector over the key's channels.
+    Row block ``a`` of ``SUB_BLOCK`` steps is one matmul of ``x *
+    exp(gamma_i - gamma_a)`` with ``k * exp(gamma_a - gamma_j)``,
+    ``gamma_a`` the block's first row: every exponent is <= 0 but on the
+    block's own columns, where it is at most ``SUB_BLOCK - 1`` steps'
+    log-decay (the module's docstring); columns past the block are 0. The
+    result does not depend on ``gamma_a``, so it takes no cotangent: what
+    it would get is two sums that cancel."""
+    f32, act = jnp.float32, k.dtype
+    *lead, C, dk = gamma.shape
+    sub = SUB_BLOCK if C % SUB_BLOCK == 0 else C
+    nb = C // sub
+    first = lax.stop_gradient(gamma[..., ::sub, :])[..., None, :]
+    rows = jnp.exp(gamma.reshape(*lead, nb, sub, dk) - first)
+    upto = jnp.arange(C) < (jnp.arange(nb)[:, None] + 1) * sub  # [nb, C]
+    cols = jnp.exp(jnp.where(
+        upto[..., None], first - gamma[..., None, :, :], -jnp.inf
+    ))
+    xr = (x.astype(f32).reshape(*lead, nb, sub, dk) * rows).astype(act)
+    kc = (k.astype(f32)[..., None, :, :] * cols).astype(act)
+    s = jnp.einsum(
+        "...aid,...ajd->...aij", xr, kc, preferred_element_type=f32
+    ).reshape(*lead, C, C)
+    return jnp.where(jnp.tril(jnp.ones((C, C), bool), -1), s, 0.0)
+
+
+def _wy_channel(k, v, beta, g):
+    """``_wy`` where the decay is a vector over the key's channels: from k
+    [n, b, h, C, d_k], v [n, b, h, C, d_v], beta [n, b, h, C] and g
+    [n, b, h, C, d_k] the pass's ``U, W``, the keys decayed to the chunk's
+    end ``K * exp(gamma_C - gamma)`` and ``a = exp(gamma_C)`` [n, b, h,
+    d_k]."""
+    f32, act = jnp.float32, k.dtype
+    C = g.shape[-2]
+    gamma = _sum_rows(g, jnp.triu(jnp.ones((C, C), f32)))
+    kb = k.astype(f32) * beta[..., None]
+    T = unit_lower_inverse_blocked(
+        -_decayed_scores(kb, k, gamma)
+    ).astype(act)
+    U = jnp.einsum(
+        "nbhij,nbhjv->nbhiv", T,
+        (v.astype(f32) * beta[..., None]).astype(act),
+        preferred_element_type=f32,
+    )
+    W = jnp.einsum(
+        "nbhij,nbhjd->nbhid", T, (kb * jnp.exp(gamma)).astype(act),
+        preferred_element_type=f32,
+    ).astype(act)
+    # what is left of the chunk after each position, summed as such
+    left = _sum_rows(g, jnp.tril(jnp.ones((C, C), f32), -1))
+    K_left = (k.astype(f32) * jnp.exp(left)).astype(act)
+    return U, W, K_left, jnp.exp(gamma[..., -1, :])
+
+
+def _read_out_channel(q, k, g, Vn, S_in):
+    """``_read_out`` for a vector decay -> [n, b, h, C, d_v] float32. A
+    position's own step enters undecayed, as a constant 1 (``_decays``)."""
+    f32, act = jnp.float32, k.dtype
+    C = g.shape[-2]
+    gamma = _sum_rows(g, jnp.triu(jnp.ones((C, C), f32)))
+    own_step = jnp.sum(q.astype(f32) * k.astype(f32), -1)
+    scores = _decayed_scores(q, k, gamma) + (
+        own_step[..., None] * jnp.eye(C, dtype=f32)
+    )
+    own = jnp.einsum(
+        "nbhij,nbhjv->nbhiv", scores.astype(act), Vn,
+        preferred_element_type=f32,
+    )
+    entered = jnp.einsum(
+        "nbhid,nbhdv->nbhiv", (q.astype(f32) * jnp.exp(gamma)).astype(act),
+        S_in, preferred_element_type=f32,
+    )
+    return own + entered
+
+
+def _chunked_channel(q, k, v, beta, g, chunk: int):
+    """``gated_delta_chunked`` for g [B, T, H, d_k]: the plain statement,
+    its two stretches made again in the backward pass as the scalar
+    decay's are."""
+    B, T, H, dk = q.shape
+    nc = T // chunk
+    qc, kc, vc = (_chunks(x, nc, chunk) for x in (q, k, v))
+    beta = jnp.transpose(beta.reshape(B, nc, chunk, H), (1, 0, 3, 2))
+    g = _chunks(g, nc, chunk)
+    U, W, K_left, a = jax.checkpoint(_wy_channel)(kc, vc, beta, g)
+    # the pass's layout: every key head serves its one value head
+    Vn, S_in = chunk_state_pass(
+        U[:, :, :, None], W[:, :, :, None], K_left, None, a[:, :, :, None]
+    )
+    o = jax.checkpoint(_read_out_channel)(
+        qc, kc, g, Vn[:, :, :, 0], S_in[:, :, :, 0]
+    )
+    # [n, b, h, C, d_v] -> [b, (n, C), h, d_v]
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(B, T, H, v.shape[3])
+    return o.astype(k.dtype)
+
+
 def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     """The gated delta rule in chunks of ``chunk`` steps: q, k
     [B, T, H_k, d_k] (unit length, q over sqrt(d_k) besides) and v
     [B, T, H_v, d_v] in the activation dtype, beta and g [B, T, H_v]
     float32 (g <= 0) -> o [B, T, H_v, d_v], accumulated in float32 and
     rounded once to the activation dtype. Value head ``h`` reads key head
-    ``h // (H_v // H_k)``. T must be whole chunks.
+    ``h // (H_v // H_k)``. T must be whole chunks. A decay that is a
+    vector over the key's channels comes as g [B, T, H_v, d_k], bounded
+    below as the module's docstring says, with ``H_v == H_k``.
 
     The two stretches around the pass are made again in the backward pass
     and not kept: their [C, C] squares a value head a chunk (decays, ``T``,
@@ -356,6 +594,13 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     if Hv % Hk:
         raise ValueError(f"{Hv} value heads do not share {Hk} key heads")
     nc, r = T // chunk, Hv // Hk
+    if g.ndim == 4:  # no site of the kernels (``kernels.fits``)
+        if r != 1:
+            raise ValueError(
+                f"a decay a key channel needs as many key heads ({Hk}) as "
+                f"value heads ({Hv})"
+            )
+        return _chunked_channel(q, k, v, beta, g, chunk)
 
     def per_head(x):  # [B, T, H_v] -> [nc, B, H_k, r, C]
         return jnp.transpose(
@@ -399,7 +644,10 @@ def _delta_rule(q, k, v, beta, g, chunk: int, mesh):
         return gated_delta_chunked(*a, chunk)
 
     args = (q, k, v, beta, g)
-    if not kernels.fits(q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype):
+    if not kernels.fits(
+        q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype,
+        vector_decay=g.ndim == 4,
+    ):
         return rule(*args)
 
     def specs(batch, heads):
@@ -443,12 +691,18 @@ def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
     dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
     key_w = Hk * dk
     act = u.dtype
+    f32 = jnp.float32
+    channel = cfg.gdn_decay == "channel"
     with jax.named_scope("scope/layer/gdn/in_proj"):
         qkv = u @ p["w_qkv"].astype(act)
         z = u @ p["w_z"].astype(act)
-        ba = jnp.dot(
-            u, p["w_ba"].astype(act), preferred_element_type=jnp.float32
-        )
+        if channel:
+            b = jnp.dot(u, p["w_b"].astype(act), preferred_element_type=f32)
+            f = jnp.dot(u, p["w_f"].astype(act), preferred_element_type=f32)
+        else:
+            ba = jnp.dot(
+                u, p["w_ba"].astype(act), preferred_element_type=jnp.float32
+            )
     # the elementwise stretches compute in float32 and are made again in
     # the backward pass, as the Mamba-2 layer's (``ops/mamba2.py``)
     with jax.named_scope("scope/layer/gdn/conv"):
@@ -456,10 +710,18 @@ def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
             lambda x, w: jax.nn.silu(causal_conv1d(x, w)).astype(act)
         )(qkv, p["conv_w"])
     with jax.named_scope("scope/layer/gdn/scan"):
-        beta = jax.nn.sigmoid(ba[..., :Hv])
-        g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
-            ba[..., Hv:] + p["dt_bias"].astype(jnp.float32)
-        )
+        if channel:
+            beta = jax.nn.sigmoid(b)
+            g = cfg.gdn_decay_bound * jax.nn.sigmoid(
+                jnp.exp(p["A_log"].astype(f32))[:, None] * (
+                    f + p["dt_bias"].astype(f32)
+                ).reshape(Bsz, T, Hk, dk)
+            )
+        else:
+            beta = jax.nn.sigmoid(ba[..., :Hv])
+            g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+                ba[..., Hv:] + p["dt_bias"].astype(jnp.float32)
+            )
         q = qkv[..., :key_w].reshape(Bsz, T, Hk, dk)
         k = qkv[..., key_w:2 * key_w].reshape(Bsz, T, Hk, dk)
         v = qkv[..., 2 * key_w:].reshape(Bsz, T, Hv, dv)
@@ -469,8 +731,13 @@ def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
         o = _delta_rule(q, k, v, beta, g, min(cfg.gdn_chunk, T), mesh)
         o = o.reshape(Bsz, T, Hv * dv)
     with jax.named_scope("scope/layer/gdn/gate"):
-        o = jax.checkpoint(lambda o, z, w: gated_group_rmsnorm(
-            o, z, jnp.tile(w, Hv), Hv, eps, norm_before_gate=True
-        ).astype(act))(o, z, p["norm"])
+        if cfg.gdn_gate == "head_sigmoid":
+            o = jax.checkpoint(lambda o, z, w: head_gated_rmsnorm(
+                o, z, w, eps
+            ).astype(act))(o, z, p["norm"])
+        else:
+            o = jax.checkpoint(lambda o, z, w: gated_group_rmsnorm(
+                o, z, jnp.tile(w, Hv), Hv, eps, norm_before_gate=True
+            ).astype(act))(o, z, p["norm"])
     with jax.named_scope("scope/layer/gdn/out_proj"):
         return o @ p["w_out"].astype(act)

@@ -52,15 +52,20 @@ _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 _CHUNKS_A_PROGRAM = (8, 4, 2, 1)
 
 
-def fits(d_k: int, d_v: int, chunk: int, T: int, dtype) -> bool:
+def fits(d_k: int, d_v: int, chunk: int, T: int, dtype,
+         vector_decay: bool = False) -> bool:
     """THE rule for which way the chunk-local work is executed, read from
     the shapes alone: the kernels where a key and a value head are whole
     128-lane tiles, a chunk is whole sublane tiles of the activation dtype
-    (8 rows of float32, 16 of bfloat16) and the sequence is whole chunks;
-    the plain ``jax.numpy`` statement everywhere else."""
+    (8 rows of float32, 16 of bfloat16), the sequence is whole chunks and
+    the decay is one scalar a head and step (the kernels build their
+    squares from ``exp(gamma_i - gamma_j)`` of scalars; a decay that is a
+    vector over the key's channels, ``g [B, T, H, d_k]``, they do not
+    take); the plain ``jax.numpy`` statement everywhere else."""
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
     return (
-        d_k % _LANES == 0 and d_v % _LANES == 0
+        not vector_decay
+        and d_k % _LANES == 0 and d_v % _LANES == 0
         and chunk % sublanes == 0 and T % chunk == 0
     )
 

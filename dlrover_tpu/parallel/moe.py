@@ -108,8 +108,27 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def keep_best_groups(choose, groups: int, kept: int):
+    """Group-limited selection (DeepSeek-V3's): ``choose`` [T, E] with the
+    experts in ``groups`` equal groups by index; a group scores the sum of
+    its two largest entries, and outside a token's ``kept`` best groups
+    every entry becomes -inf, so that no top-k takes it."""
+    T, num_experts = choose.shape
+    with jax.named_scope("scope/layer/moe/route/groups"):
+        grouped = choose.reshape(T, groups, num_experts // groups)
+        score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)  # [T, groups]
+        _, best = lax.top_k(score, kept)
+        keep = jnp.any(
+            best[..., None] == jnp.arange(groups, dtype=best.dtype), axis=1
+        )  # [T, groups]
+        return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+            T, num_experts
+        )
+
+
 def route(logits: jnp.ndarray, k: int, normalize: bool, *,
-          kind: str = "softmax", bias=None, scale: float = 1.0):
+          kind: str = "softmax", bias=None, scale: float = 1.0,
+          groups: Tuple[int, int] = (1, 1)):
     """THE routing decision, in float32: each token's ``k`` best
     experts by softmax probability, their gate values, and the two
     auxiliary losses. Shared by the one-device and the ``ep`` path.
@@ -120,7 +139,10 @@ def route(logits: jnp.ndarray, k: int, normalize: bool, *,
     steers the load and never the output: it takes no gradient), over
     their sum where ``normalize``. The balance loss is then over the
     scores normalised to sum to one a token, and there is no z-loss.
-    ``scale`` multiplies the gate values of either kind.
+    ``scale`` multiplies the gate values of either kind. ``groups =
+    (n, kept)`` with ``n`` > 1 limits the choice to the experts of each
+    token's ``kept`` best of ``n`` groups (``keep_best_groups``, on the
+    scores the choice is made by: with the bias).
 
     Returns ``(idx [T,k] int32, gates [T,k] f32, aux)``:
     - gates: the softmax probabilities of the chosen experts,
@@ -141,6 +163,8 @@ def route(logits: jnp.ndarray, k: int, normalize: bool, *,
         choose = scores
         if bias is not None:
             choose = scores + lax.stop_gradient(bias.astype(jnp.float32))
+        if groups[0] > 1:
+            choose = keep_best_groups(lax.stop_gradient(choose), *groups)
         _, idx = lax.top_k(choose, k)
         vals = jnp.take_along_axis(scores, idx, axis=-1)
         gates = (
@@ -151,7 +175,13 @@ def route(logits: jnp.ndarray, k: int, normalize: bool, *,
         probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-        vals, idx = lax.top_k(probs, k)
+        if groups[0] > 1:
+            _, idx = lax.top_k(
+                keep_best_groups(lax.stop_gradient(probs), *groups), k
+            )
+            vals = jnp.take_along_axis(probs, idx, axis=-1)
+        else:
+            vals, idx = lax.top_k(probs, k)
         gates = (
             vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-9)
             if normalize and k > 1
@@ -461,6 +491,7 @@ def moe_layer_local(
     router: str = "softmax",
     routed_scale: float = 1.0,
     held: Optional[Tuple[int, int]] = None,
+    groups: Tuple[int, int] = (1, 1),
 ):
     """Per-device MoE FFN body (call inside ``shard_map``).
 
@@ -476,8 +507,9 @@ def moe_layer_local(
     ``expert_caps[e]`` assignments (hot experts stop overflowing,
     cold ones ship padding in the all-to-all).
 
-    ``router`` and ``routed_scale`` are ``route``'s ``kind`` and
-    ``scale``. ``held = (offset, count)``, on one device only: the
+    ``router``, ``routed_scale`` and ``groups`` are ``route``'s ``kind``,
+    ``scale`` and ``groups``. ``held = (offset, count)``, on one device
+    only: the
     weights are ``count`` of the experts the gate scores (``_moe_share``). The shared expert, where the parameters have
     one, is added to every token's output.
     """
@@ -492,7 +524,9 @@ def moe_layer_local(
             "an ep axis the axis is the share"
         )
     T, model = x.shape
-    routing = dict(kind=router, bias=params.bias, scale=routed_scale)
+    routing = dict(
+        kind=router, bias=params.bias, scale=routed_scale, groups=groups
+    )
 
     with jax.named_scope("scope/layer/moe/route"):
         logits = jnp.dot(

@@ -227,8 +227,10 @@ class ElasticTrainer:
         self._builds = compile_meter()
         self._builds_first = self._builds_logged = len(self._builds.builds)
         self._built: set = set()
-        # ops.gated_delta's tally as a train step's build began
+        # ops.gated_delta's tally as a train step's build began, and
+        # models.transformer's of the attention sites' score widths
         self._gdn_before_step = None
+        self._lanes_before_step = None
         self.tcfg = trainer_cfg or TrainerConfig()
         self._metrics_hook = metrics_hook
         # kept for the resize path: a new mesh rebuilds the accel
@@ -444,6 +446,7 @@ class ElasticTrainer:
             logger.info(
                 f"programs built {when}: {describe_builds(rows)}{q8}"
                 f"{self._fold_attention_tally()}{self._fold_gdn_tally()}"
+                f"{self._fold_score_lanes()}"
             )
 
     def _fold_first_step(self):
@@ -523,6 +526,27 @@ class ElasticTrainer:
             f"{step.chunk_steps} serial chunk steps a train step"
         )
 
+    def _fold_score_lanes(self) -> str:
+        """The score widths of the attention sites traced since a train
+        step's build began into the stats, and in words where a call
+        was wider than the model states; by ``_fold_gdn_tally``'s rules."""
+        from dlrover_tpu.models.transformer import score_lanes_tally
+
+        before, self._lanes_before_step = self._lanes_before_step, None
+        if before is None:
+            return ""
+        step = score_lanes_tally() - before
+        if not step.called:
+            return ""
+        stats = self.pipeline_stats
+        stats.attn_score_lanes, stats.attn_score_lanes_used = step
+        if step.called == step.used:
+            return ""
+        return (
+            f"; attention scores: {step.used} of the {step.called} lanes "
+            "the kernels were called with"
+        )
+
     def _first_build(self, what: str):
         """``build:<what>`` around the FIRST call of a jitted program
         (jit compiles, or loads from the cache, inside that call);
@@ -531,9 +555,11 @@ class ElasticTrainer:
             return _NO_BUILD
         self._built.add(what)
         if what.startswith("step_"):
+            from dlrover_tpu.models.transformer import score_lanes_tally
             from dlrover_tpu.ops.gated_delta import gdn_tally
 
             self._gdn_before_step = gdn_tally()
+            self._lanes_before_step = score_lanes_tally()
         return self._builds.build(what)
 
     # -- measured link-cost model (parallel/topology.py) ----------------

@@ -8,7 +8,9 @@ commit before ISSUE 43 gave for the Nemotron configuration, and what ISSUE
 recorded anew when the delta rule's output took the activation dtype inside
 ``gated_delta_chunked``: at this batch of 1 x 1024 with heads of 128 the
 chunk-local work lowers to the ``gdn_chunk_*`` kernels, interpreted on the
-CPU), made by running this file there:
+CPU), and what ISSUE 45 gave for ``ling-3.0-flash-d7``, the family it
+brought (a later PR that means to leave it alone leaves both alone), made
+by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
 
@@ -26,6 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAMES = (
     "gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2",
     "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
+    "ling-3.0-flash-d7",
 )
 
 

@@ -136,7 +136,8 @@ def test_rebuild_drops_the_executable(accel):
 # shard lock's side of the due saves (PR 42), the Gated DeltaNet tally's
 # two (PR 43) and its sites in the kernels (PR 44)
 AS_DICT_KEYS = [
-    "attn_square_sites", "attn_stream_blocks_rect",
+    "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
+    "attn_stream_blocks_rect",
     "attn_stream_blocks_walked", "attn_stream_rect_sites",
     "attn_stream_tri_sites", "attn_tiles_square", "attn_tiles_walked",
     "attn_tri_sites", "begin_lock_s", "comm_overlap_pct",

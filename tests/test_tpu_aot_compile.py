@@ -257,6 +257,60 @@ def test_delta_rule_chunk_kernels_compile_at_the_cell(
     assert gated_delta.gdn_tally() - before == (1, steps, 1)
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_attention_of_192_and_128_compiles_as_the_program_calls_it(
+    direction, one_chip, monkeypatch
+):
+    """The Ling-3.0-flash cell's latent attention, [1, 32, 8192, 192 |
+    128]: the program pads q, k and v to the kernels' one width of 256,
+    scales by 1 / sqrt(192) and slices the output
+    (``models/transformer._attention_of_two_widths``). 32 key/value heads
+    of 256 in blocks of 512: the triangle walks 136 of 256 blocks a
+    kernel, and the call is counted as 256 lanes for 192 stated."""
+    from dlrover_tpu.models import transformer
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(transformer, "_score_lanes", transformer.ScoreLanes())
+    B, H, T = 1, 32, 8192
+    qkv = [
+        jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
+        for D in (192, 192, 128)
+    ]
+
+    def attend(q, k, v):
+        return transformer._attention_of_two_widths(q, k, v, None)
+
+    before = fa.stream_tally()
+    if direction == "fwd":
+        compiled = _compile_for_chip(attend, *qkv)
+        want = ["flash_attn_fwd"]
+        assert compiled.output_shardings is not None
+        (out,) = jax.tree_util.tree_leaves(
+            jax.eval_shape(attend, *qkv)
+        )
+        assert out.shape == (B, H, T, 128)
+    else:
+        compiled = _compile_for_chip(
+            jax.grad(
+                lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            ),
+            *qkv,
+        )
+        want = ["flash_attn_fwd"] + (
+            ["flash_attn_bwd"] if fa._one_pass_fits(T, 256, 2)
+            else ["flash_attn_bwd_dq", "flash_attn_bwd_dkv"]
+        )
+    text = compiled.as_text()
+    for kernel in want:
+        assert kernel in text, kernel
+    assert "flash_attn_fused" not in text
+    sites = len(want)
+    assert fa.stream_tally() - before == (sites, 0, 136 * sites, 256 * sites)
+    assert transformer.score_lanes_tally()[:2] in ((256, 192), (512, 384))
+
+
 # the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here
 ADAM_LEAVES = {
     "gpt2_wte": (50257, 768),

@@ -56,11 +56,14 @@ quotient stays under ``exp(75)`` in float32. The pass carries ``a`` as a
 row over the key's channels and takes the keys already decayed to the
 chunk's end; everything else (the pass and its hand-written reversal, the
 triangle's inverse's cotangent, the tally) is the one code for both kinds.
-The ``gdn_chunk_*`` kernels build their squares from scalar decays and take
-no such site (``gated_delta_kernels.fits``). Such a layer's decays start
-slow, and where a chunk's keys are nearly parallel and hardly decay
-``unit_lower_inverse``'s product form loses the inverse to cancellation:
-this kind inverts by halves (``unit_lower_inverse_blocked``).
+Where the shapes allow (``gated_delta_kernels.fits``, the one rule for both
+kinds) the kind's chunk-local work runs in kernels of its own, the
+``gdn_channel_*`` beside the scalar kind's ``gdn_chunk_*``; ``_wy_channel``
+and ``_read_out_channel`` are the statement those are held to and what runs
+at every other shape. Such a layer's decays start slow, and where a chunk's
+keys are nearly parallel and hardly decay ``unit_lower_inverse``'s product
+form loses the inverse to cancellation: this kind inverts by halves
+(``unit_lower_inverse_blocked``), in the kernels too.
 
 The spans of a layer: ``scope/layer/gdn/{in_proj,conv,scan,gate,out_proj}``.
 """
@@ -77,7 +80,7 @@ from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.mamba2 import causal_conv1d, gated_group_rmsnorm
 
 L2_EPS = 1e-6  # of the unit-length q and k (the source's ``l2norm``)
-SUB_BLOCK = 16  # steps whose decay a vector-decay chunk may divide by
+SUB_BLOCK = kernels.SUB_BLOCK  # the one bound of both ways to execute
 DT_SHARE = (0.002, 0.2)  # of ``sigmoid(dt_bias)`` at init, vector decay
 
 
@@ -174,6 +177,9 @@ def _tally_pass(sites: int, steps: int, kernel_sites: int = 0):
     ))
 
 
+kernels.forward_traced = lambda: _tally_pass(0, 0, kernel_sites=1)
+
+
 def l2norm(x):
     """``x / |x|`` over the last axis, float32 (``rsqrt(sum x^2 + eps)``)."""
     xf = x.astype(jnp.float32)
@@ -222,7 +228,7 @@ def _unit_lower_inverse_bwd(T, dT):
 unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-_INVERSE_BASE = 8  # diagonal blocks the product form is still good for
+_INVERSE_BASE = kernels.INVERSE_BASE
 
 
 def _blocked_inverse(A):
@@ -585,8 +591,10 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     masked scores) would be the layer's largest residuals. Where the
     shapes allow (``gated_delta_kernels.fits``) both stretches, forward
     and backward, are kernels that keep those squares in VMEM and read
-    and write the layouts around them; ``_wy`` and ``_read_out`` below are
-    the statement they are held to, and what runs everywhere else."""
+    and write the layouts around them, for either kind of decay; ``_wy``
+    and ``_read_out`` (``_wy_channel`` and ``_read_out_channel`` for a
+    vector decay) are the statement they are held to, and what runs
+    everywhere else."""
     B, T, Hk, dk = q.shape
     Hv, dv = v.shape[2], v.shape[3]
     if T % chunk:
@@ -594,13 +602,22 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     if Hv % Hk:
         raise ValueError(f"{Hv} value heads do not share {Hk} key heads")
     nc, r = T // chunk, Hv // Hk
-    if g.ndim == 4:  # no site of the kernels (``kernels.fits``)
-        if r != 1:
-            raise ValueError(
-                f"a decay a key channel needs as many key heads ({Hk}) as "
-                f"value heads ({Hv})"
-            )
-        return _chunked_channel(q, k, v, beta, g, chunk)
+    if g.ndim == 4 and r != 1:
+        raise ValueError(
+            f"a decay a key channel needs as many key heads ({Hk}) as "
+            f"value heads ({Hv})"
+        )
+    in_kernels = kernels.fits(dk, dv, chunk, T, k.dtype)
+    if g.ndim == 4:  # a site of the kernels is counted by ``wy_channel``
+        if not in_kernels:
+            return _chunked_channel(q, k, v, beta, g, chunk)
+        q, k, g = (x.reshape(B, T, Hk * dk) for x in (q, k, g))
+        U, W, K_left, a = kernels.wy_channel(
+            k, v.reshape(B, T, Hv * dv), beta, g, Hk, chunk
+        )
+        Vn, S_in = chunk_state_pass(U, W, K_left, None, a)
+        o = kernels.read_out_channel(q, k, g, Vn, S_in)
+        return o.reshape(B, T, Hv, dv)
 
     def per_head(x):  # [B, T, H_v] -> [nc, B, H_k, r, C]
         return jnp.transpose(
@@ -608,7 +625,7 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
         )
 
     beta, g = per_head(beta), per_head(g)
-    if kernels.fits(dk, dv, chunk, T, k.dtype):
+    if in_kernels:
         _tally_pass(0, 0, kernel_sites=1)
         q, k = q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk)
         rows = (nc, B, Hk, 1, r * chunk)
@@ -644,17 +661,16 @@ def _delta_rule(q, k, v, beta, g, chunk: int, mesh):
         return gated_delta_chunked(*a, chunk)
 
     args = (q, k, v, beta, g)
-    if not kernels.fits(
-        q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype,
-        vector_decay=g.ndim == 4,
-    ):
+    if not kernels.fits(q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype):
         return rule(*args)
 
     def specs(batch, heads):
         wide, narrow = P(batch, None, heads, None), P(batch, None, heads)
         return dict(
-            in_specs=(wide, wide, wide, narrow, narrow), out_specs=wide,
-            check_vma=False,
+            in_specs=(
+                wide, wide, wide, narrow, wide if g.ndim == 4 else narrow
+            ),
+            out_specs=wide, check_vma=False,
         )
 
     if mesh is None:

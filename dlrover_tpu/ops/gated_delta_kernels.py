@@ -17,6 +17,14 @@ Layouts are the neighbours': ``q, k, v`` are read token-major
 them, ``o`` token-major as the gate does. ``beta`` and ``g`` (and their
 cotangents) travel as rows ``[n, B, H_k, 1, r C]``.
 
+A decay that is a vector over the key's channels (Kimi Delta Attention,
+``g [B, T, H d_k]``) has the same two stretches as kernels of its own,
+``wy_channel`` and ``read_out_channel`` (``gdn_channel_*``, the second half
+of this file): there every key head serves its one value head, the square
+stacks two consecutive chunks of a head, the decayed operands of a masked
+square are made in VMEM in row blocks of 16 steps, and the inverse is the
+one by halves.
+
 The precision is the plain statement's: decays, their sums and the inverse
 float32, the inverse's products at ``Precision.HIGHEST``; every other
 matmul takes the activation dtype and accumulates in float32 (a float32
@@ -52,20 +60,19 @@ _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 _CHUNKS_A_PROGRAM = (8, 4, 2, 1)
 
 
-def fits(d_k: int, d_v: int, chunk: int, T: int, dtype,
-         vector_decay: bool = False) -> bool:
+def fits(d_k: int, d_v: int, chunk: int, T: int, dtype) -> bool:
     """THE rule for which way the chunk-local work is executed, read from
     the shapes alone: the kernels where a key and a value head are whole
     128-lane tiles, a chunk is whole sublane tiles of the activation dtype
-    (8 rows of float32, 16 of bfloat16), the sequence is whole chunks and
-    the decay is one scalar a head and step (the kernels build their
-    squares from ``exp(gamma_i - gamma_j)`` of scalars; a decay that is a
-    vector over the key's channels, ``g [B, T, H, d_k]``, they do not
-    take); the plain ``jax.numpy`` statement everywhere else."""
+    (8 rows of float32, 16 of bfloat16) and the sequence is whole chunks;
+    the plain ``jax.numpy`` statement everywhere else. The same shapes
+    serve both kinds of decay, each with kernels of its own: one scalar a
+    head and step (``wy``, ``read_out``: squares from ``exp(gamma_i -
+    gamma_j)`` of scalars) or a vector over the key's channels, ``g [B, T,
+    H, d_k]`` (``wy_channel``, ``read_out_channel``)."""
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
     return (
-        not vector_decay
-        and d_k % _LANES == 0 and d_v % _LANES == 0
+        d_k % _LANES == 0 and d_v % _LANES == 0
         and chunk % sublanes == 0 and T % chunk == 0
     )
 
@@ -508,3 +515,518 @@ def _read_bwd(res, do):
 
 
 read_out.defvjp(_read_fwd, _read_bwd)
+
+
+# -- a decay that is a vector over the key's channels ------------------------
+#
+# ``gated_delta._wy_channel`` and ``_read_out_channel`` as kernels. Every
+# key head serves its one value head, so a chunk's square is ``[C, C]``: a
+# program is one ``(batch, head, run of chunks)`` and stacks ``s`` (two)
+# CONSECUTIVE chunks of its head along the rows, which is how they already
+# lie in the token-major block, into one block-diagonal ``[s C, s C]``
+# square (``_geometry(C, s)``, a chunk where the scalar kind has a value
+# head). ``g [B, T, H d_k]`` float32 travels token-major like ``k`` and its
+# cotangent leaves the same way; ``beta`` as rows ``[n / s, B, H, 1, s C]``.
+# The decay does not factor out of the sums over the key's channels: a
+# masked square is made in row blocks of ``SUB_BLOCK`` steps from operands
+# decayed in float32 before they are rounded, as ``_decayed_scores`` states
+# it (and with its bound on the one exponent above 0), and the triangle's
+# inverse is the one by halves (``unit_lower_inverse_blocked``): the product
+# form inside diagonal blocks of 8, then ``T <- T + T A_level T`` with ``A``
+# masked to the level's off-diagonal blocks, ten ``highest`` products on
+# the whole square at a chunk of 64.
+
+SUB_BLOCK = 16  # steps whose decay a vector-decay chunk may divide by
+INVERSE_BASE = 8  # diagonal blocks the product form is still good for
+
+
+class _ChannelGeometry(NamedTuple):
+    """``_geometry(C, s)`` of the ``s`` stacked chunks, every row's
+    position in its chunk, and the masks of the inverse by halves."""
+
+    geo: _Geometry
+    pos: jax.Array  # int32 [N, 1]
+    base: jax.Array  # [N, N]: i and j in one diagonal block of the base
+    levels: tuple  # of [N, N]: the off-diagonal blocks the level fills
+
+
+def _channel_geometry(C: int, s: int) -> _ChannelGeometry:
+    N = s * C
+    geo = _geometry(C, s)
+
+    def position(shape, axis):
+        i = lax.broadcasted_iota(jnp.int32, shape, axis)
+        return i - C * sum((i >= j * C).astype(jnp.int32) for j in range(1, s))
+
+    prow, pcol = position((N, N), 0), position((N, N), 1)
+
+    def block(p, size):  # size a power of two
+        return lax.shift_right_logical(p, size.bit_length() - 1)
+
+    base = min(INVERSE_BASE, C)
+    levels, size = [], base
+    while size < C:
+        levels.append(
+            geo.same & (block(prow, 2 * size) == block(pcol, 2 * size))
+            & (block(prow, size) & 1 == 1) & (block(pcol, size) & 1 == 0)
+        )
+        size *= 2
+    return _ChannelGeometry(
+        geo, position((N, 1), 0),
+        geo.below & (block(prow, base) == block(pcol, base)), tuple(levels),
+    )
+
+
+def _sum_inside(x, cg: _ChannelGeometry, C: int, after: bool = False,
+                own: bool = True):
+    """The running sum of the rows of ``x`` [N, d] inside their chunk, up
+    to each position (from it on where ``after``) and with it (without
+    where not ``own``): float32 adds of rows rolled by 1, 2, 4 ... and
+    masked at the chunk's ends, no matmul. ``gamma`` is the sum up to and
+    with, what is left of the chunk the sum after and without (summed as
+    such: the last position's is an empty sum), and each is the other's
+    cotangent's way back."""
+    N = x.shape[0]
+
+    def shifted(x, by):  # row i takes row i - by (i + by where ``after``)
+        seen = cg.pos < C - by if after else cg.pos >= by
+        return jnp.where(seen, pltpu.roll(x, (N - by) if after else by, 0), 0.0)
+
+    if not own:
+        x = shifted(x, 1)
+    by = 1
+    while by < C:
+        x = x + shifted(x, by)
+        by *= 2
+    return x
+
+
+def _inverses_by_halves(As, cg: _ChannelGeometry, C: int):
+    """``(I - A)^{-1}`` of each ``A`` as ``unit_lower_inverse_blocked``
+    forms it, the squares of a program side by side: inside the diagonal
+    blocks of the base the product form, then level by level ``T21 = T22
+    A21 T11`` for all of a level's blocks in two products of the whole
+    square, ``T`` being block-diagonal at the level's size."""
+    eye = cg.geo.eye.astype(_F32)
+    powers = [jnp.where(cg.base, A, 0.0) for A in As]
+    Ts, reach = [p + eye for p in powers], 2
+    while reach < min(INVERSE_BASE, C):
+        powers = [_dot(p, p, precision=_HI) for p in powers]
+        Ts = [T + _dot(T, p, precision=_HI) for T, p in zip(Ts, powers)]
+        reach *= 2
+    for level in cg.levels:
+        halves = [
+            _dot(T, jnp.where(level, A, 0.0), precision=_HI)
+            for T, A in zip(Ts, As)
+        ]
+        Ts = [T + _dot(h, T, precision=_HI) for T, h in zip(Ts, halves)]
+    return Ts
+
+
+def _sub_block(C: int) -> int:
+    return SUB_BLOCK if C % SUB_BLOCK == 0 else C
+
+
+def _block_rows(x, a: int, C: int, s: int):
+    """Row block ``a`` of each of the ``s`` stacked chunks -> [s sub, ...]."""
+    sub = _sub_block(C)
+    return jnp.concatenate(
+        [x[j * C + a * sub:j * C + (a + 1) * sub] for j in range(s)], axis=0
+    )
+
+
+def _unblock_rows(blocks, C: int, s: int):
+    """``_block_rows`` back: the row blocks ``a`` -> [s C, ...]."""
+    sub = _sub_block(C)
+    return jnp.concatenate([
+        blocks[a][j * sub:(j + 1) * sub]
+        for j in range(s) for a in range(C // sub)
+    ], axis=0)
+
+
+class _Decayed(NamedTuple):
+    """The operands of a masked square ``sum_c x_ic k_jc exp(gamma_ic -
+    gamma_jc)``: ``er = exp(gamma_i - gamma_a)`` with ``gamma_a`` the first
+    row of ``i``'s own row block, ``xr = x * er`` rounded, and a block
+    ``a``: ``ecs[a] = exp(gamma_a - gamma_j)`` up to the block's end and 0
+    past it, ``kcs[a] = k * ecs[a]`` rounded."""
+
+    er: jax.Array
+    xr: jax.Array
+    ecs: list
+    kcs: list
+
+
+def _decayed(xf, kf, gamma, cg, C: int, s: int, act) -> _Decayed:
+    sub = _sub_block(C)
+    nb, dk = C // sub, gamma.shape[-1]
+
+    def first(j, a, rows):  # gamma at the first row of chunk j's block a
+        at = j * C + a * sub
+        return jnp.broadcast_to(gamma[at:at + 1], (rows, dk))
+
+    own = jnp.concatenate(
+        [first(j, a, sub) for j in range(s) for a in range(nb)], axis=0
+    )
+    er = jnp.exp(gamma - own)
+    ecs = []
+    for a in range(nb):
+        ref = jnp.concatenate([first(j, a, C) for j in range(s)], axis=0)
+        ecs.append(jnp.exp(
+            jnp.where(cg.pos < (a + 1) * sub, ref - gamma, -jnp.inf)
+        ))
+    return _Decayed(
+        er, (xf * er).astype(act), ecs, [(kf * e).astype(act) for e in ecs]
+    )
+
+
+def _scores(d: _Decayed, C: int, s: int):
+    """The square, before its mask: row block ``a`` of every chunk is one
+    matmul against ``kcs[a]``."""
+    return _unblock_rows([
+        _dot(_block_rows(d.xr, a, C, s), kc, _NT)
+        for a, kc in enumerate(d.kcs)
+    ], C, s)
+
+
+def _scores_bwd(dS, d: _Decayed, C: int, s: int):
+    """The square's cotangent (float32, masked) -> those of ``xr`` and of
+    each ``kcs[a]``."""
+    dSb = dS.astype(d.xr.dtype)
+    dxs, dkcs = [], []
+    for a, kc in enumerate(d.kcs):
+        dR = _block_rows(dSb, a, C, s)
+        dxs.append(_dot(dR, kc))
+        dkcs.append(_dot(dR, _block_rows(d.xr, a, C, s), _TN))
+    return _unblock_rows(dxs, C, s), dkcs
+
+
+def _chunk_rows(x, j: int, C: int):
+    return x[j * C:(j + 1) * C]
+
+
+def _last_rows(gamma, cg, C: int, s: int):
+    """``gamma`` at each chunk's last position, [1, d_k] a chunk."""
+    last = jnp.where(cg.pos == C - 1, gamma, 0.0)
+    return [
+        jnp.sum(_chunk_rows(last, j, C), axis=0, keepdims=True)
+        for j in range(s)
+    ]
+
+
+class _ChannelWy(NamedTuple):
+    kf: jax.Array  # [N, d_k] float32
+    vf: jax.Array
+    beta_col: jax.Array
+    kb: jax.Array  # k * beta, float32
+    gamma: jax.Array
+    left: jax.Array
+    d: _Decayed
+    T: jax.Array
+
+
+def _channel_wy_squares(k_ref, v_ref, beta_ref, g_ref, cg, C, s, m):
+    """What ``wy`` makes of each of a program's ``m / s`` squares."""
+    act, N = k_ref.dtype, s * C
+    made, As = [], []
+    for t in range(m // s):
+        rows = slice(t * N, (t + 1) * N)
+        kf, g2 = k_ref[rows, :].astype(_F32), g_ref[rows, :]
+        beta_col = _as_col(beta_ref[t], cg.geo)
+        kb = kf * beta_col
+        gamma = _sum_inside(g2, cg, C)
+        left = _sum_inside(g2, cg, C, after=True, own=False)
+        d = _decayed(kb, kf, gamma, cg, C, s, act)
+        As.append(-jnp.where(cg.geo.below, _scores(d, C, s), 0.0))
+        made.append(_ChannelWy(
+            kf, v_ref[rows, :].astype(_F32), beta_col, kb, gamma, left, d,
+            None,
+        ))
+    Ts = _inverses_by_halves(As, cg, C)
+    return [x._replace(T=T) for x, T in zip(made, Ts)]
+
+
+def _channel_wy_fwd_kernel(k_ref, v_ref, beta_ref, g_ref,
+                           u_ref, w_ref, kl_ref, a_ref, *, C, r, m):
+    act, s = k_ref.dtype, r
+    cg = _channel_geometry(C, s)
+    squares = _channel_wy_squares(k_ref, v_ref, beta_ref, g_ref, cg, C, s, m)
+    for t, x in enumerate(squares):
+        Tb = x.T.astype(act)
+        U = _dot(Tb, (x.vf * x.beta_col).astype(act))
+        W = _dot(Tb, (x.kb * jnp.exp(x.gamma)).astype(act)).astype(act)
+        Kl = (x.kf * jnp.exp(x.left)).astype(act)
+        lasts = _last_rows(x.gamma, cg, C, s)
+        for j in range(s):
+            c = t * s + j
+            u_ref[c, 0] = _chunk_rows(U, j, C)
+            w_ref[c, 0] = _chunk_rows(W, j, C)
+            kl_ref[c] = _chunk_rows(Kl, j, C)
+            a_ref[c] = jnp.exp(lasts[j])
+
+
+def _channel_wy_bwd_kernel(k_ref, v_ref, beta_ref, g_ref,
+                           du_ref, dw_ref, dkl_ref, da_ref,
+                           dk_ref, dv_ref, dbeta_ref, dg_ref, *, C, r, m):
+    act, s = k_ref.dtype, r
+    N = s * C
+    cg = _channel_geometry(C, s)
+    squares = _channel_wy_squares(k_ref, v_ref, beta_ref, g_ref, cg, C, s, m)
+    for t, x in enumerate(squares):
+        rows = slice(t * N, (t + 1) * N)
+        chunks = range(t * s, (t + 1) * s)
+        dU = jnp.concatenate([du_ref[c, 0] for c in chunks], 0).astype(act)
+        dW = jnp.concatenate([dw_ref[c, 0] for c in chunks], 0)
+        dKl = jnp.concatenate([dkl_ref[c] for c in chunks], 0).astype(_F32)
+        e_gamma, e_left = jnp.exp(x.gamma), jnp.exp(x.left)
+        Tb = x.T.astype(act)
+        vb = (x.vf * x.beta_col).astype(act)
+        kg = x.kb * e_gamma
+        # U = T vb and W = T kg
+        dvb = _dot(Tb, dU, _TN)
+        dkg = _dot(Tb, dW, _TN)
+        dT = _dot(dU, vb, _NT) + _dot(dW, kg.astype(act), _NT)
+        # T = (I - A)^{-1}: dA = T^T dT T^T; A = -S below the diagonal
+        dA = _dot(x.T, _dot(dT, x.T, _NT, _HI), _TN, _HI)
+        dxr, dkcs = _scores_bwd(
+            jnp.where(cg.geo.below, -dA, 0.0), x.d, C, s
+        )
+        # xr = kb er, kcs[a] = k ecs[a], kg = kb exp(gamma), Kl = k exp(left)
+        dkb = dkg * e_gamma + dxr * x.d.er
+        dkc = sum(dk_a * e for dk_a, e in zip(dkcs, x.d.ecs))
+        dkf = dkb * x.beta_col + dkc + dKl * e_left
+        dgamma = dkg * kg + (dxr * x.d.er) * x.kb - dkc * x.kf
+        # a = exp(gamma) at a chunk's last position
+        lasts = _last_rows(x.gamma, cg, C, s)
+        da = jnp.concatenate([
+            jnp.broadcast_to(da_ref[c] * jnp.exp(last), (C, dgamma.shape[1]))
+            for c, last in zip(chunks, lasts)
+        ], axis=0)
+        dgamma = dgamma + jnp.where(cg.pos == C - 1, da, 0.0)
+        dleft = (dKl * e_left) * x.kf
+        dbeta_col = (
+            jnp.sum(dkb * x.kf, axis=1, keepdims=True)
+            + jnp.sum(dvb * x.vf, axis=1, keepdims=True)
+        )
+        dk_ref[rows, :] = dkf.astype(act)
+        dv_ref[rows, :] = (dvb * x.beta_col).astype(act)
+        dbeta_ref[t] = _as_row(dbeta_col, cg.geo)
+        dg_ref[rows, :] = _sum_inside(dgamma, cg, C, after=True) + (
+            _sum_inside(dleft, cg, C, own=False)
+        )
+
+
+class _ChannelRead(NamedTuple):
+    qf: jax.Array
+    kf: jax.Array
+    e_gamma: jax.Array
+    d: _Decayed
+    Pb: jax.Array  # the masked scores with the own step, rounded
+    qe: jax.Array  # q * exp(gamma), rounded
+    Vn: jax.Array
+
+
+def _channel_read(q_ref, k_ref, g_ref, vn_ref, t, cg, C, s) -> _ChannelRead:
+    act, N = k_ref.dtype, s * C
+    rows = slice(t * N, (t + 1) * N)
+    qf, kf = q_ref[rows, :].astype(_F32), k_ref[rows, :].astype(_F32)
+    gamma = _sum_inside(g_ref[rows, :], cg, C)
+    e_gamma = jnp.exp(gamma)
+    d = _decayed(qf, kf, gamma, cg, C, s, act)
+    # a position's own step enters undecayed: a constant 1, not exp(0)
+    own_step = jnp.sum(qf * kf, axis=1, keepdims=True)
+    P = jnp.where(cg.geo.below, _scores(d, C, s), 0.0) + jnp.where(
+        cg.geo.eye, own_step, 0.0
+    )
+    Vn = jnp.concatenate(
+        [vn_ref[c, 0] for c in range(t * s, (t + 1) * s)], axis=0
+    )
+    return _ChannelRead(
+        qf, kf, e_gamma, d, P.astype(act), (qf * e_gamma).astype(act), Vn
+    )
+
+
+def _channel_read_fwd_kernel(q_ref, k_ref, g_ref, vn_ref, s_ref, o_ref,
+                             *, C, r, m):
+    s = r
+    cg = _channel_geometry(C, s)
+    made = [
+        _channel_read(q_ref, k_ref, g_ref, vn_ref, t, cg, C, s)
+        for t in range(m // s)
+    ]
+    for t, x in enumerate(made):
+        entered = jnp.concatenate([
+            _dot(_chunk_rows(x.qe, j, C), s_ref[t * s + j, 0])
+            for j in range(s)
+        ], axis=0)
+        o = _dot(x.Pb, x.Vn) + entered
+        o_ref[t * s * C:(t + 1) * s * C, :] = o.astype(o_ref.dtype)
+
+
+def _channel_read_bwd_kernel(q_ref, k_ref, g_ref, vn_ref, s_ref, do_ref,
+                             dq_ref, dk_ref, dg_ref, dvn_ref, ds_ref,
+                             *, C, r, m):
+    act, s = k_ref.dtype, r
+    N = s * C
+    cg = _channel_geometry(C, s)
+    made = [
+        _channel_read(q_ref, k_ref, g_ref, vn_ref, t, cg, C, s)
+        for t in range(m // s)
+    ]
+    for t, x in enumerate(made):
+        rows = slice(t * N, (t + 1) * N)
+        do = do_ref[rows, :]
+        # own = P V', P = the masked scores + the own step on the diagonal
+        dvn = _dot(x.Pb, do, _TN).astype(act)
+        dP = _dot(do, x.Vn, _NT)
+        dxr, dkcs = _scores_bwd(jnp.where(cg.geo.below, dP, 0.0), x.d, C, s)
+        down = jnp.sum(jnp.where(cg.geo.eye, dP, 0.0), axis=1, keepdims=True)
+        # entered = qe S, qe = q exp(gamma)
+        dqe = []
+        for j in range(s):
+            c, do_j = t * s + j, _chunk_rows(do, j, C)
+            dqe.append(_dot(do_j, s_ref[c, 0], _NT))
+            ds_ref[c, 0] = _dot(_chunk_rows(x.qe, j, C), do_j, _TN).astype(act)
+            dvn_ref[c, 0] = _chunk_rows(dvn, j, C)
+        dqe = jnp.concatenate(dqe, axis=0)
+        dkc = sum(dk_a * e for dk_a, e in zip(dkcs, x.d.ecs))
+        dx = dxr * x.d.er + dqe * x.e_gamma
+        dgamma = dx * x.qf - dkc * x.kf
+        dq_ref[rows, :] = (dx + down * x.kf).astype(act)
+        dk_ref[rows, :] = (dkc + down * x.qf).astype(act)
+        dg_ref[rows, :] = _sum_inside(dgamma, cg, C, after=True)
+
+
+def _channel_shape(k, H: int, C: int, d_v: int) -> _Shape:
+    """``r`` is the chunks a square stacks: two where the chunks pair up."""
+    B, T, key_lanes = k.shape
+    n = T // C
+    s = 2 if n % 2 == 0 else 1
+    m = next(m for m in _CHUNKS_A_PROGRAM if n % m == 0 and m % s == 0)
+    return _Shape(B, n, H, s, C, key_lanes // H, d_v, m)
+
+
+def _beta_rows(sh: _Shape):
+    """``beta`` [n / s, B, H, 1, s C]: a program's squares, whole."""
+    return pl.BlockSpec(
+        (sh.m // sh.r, None, None, 1, sh.r * sh.C),
+        lambda b, h, i: (i, b, h, 0, 0),
+    )
+
+
+def _channel_wy_specs(sh: _Shape):
+    C = sh.C
+    keys = _tokens(sh, sh.d_k)
+    return (
+        [keys, _tokens(sh, sh.d_v), _beta_rows(sh), keys],
+        [
+            _chunk_major(sh, 1, C, sh.d_v), _chunk_major(sh, 1, C, sh.d_k),
+            _chunk_major(sh, C, sh.d_k), _chunk_major(sh, 1, sh.d_k),
+        ],
+    )
+
+
+def forward_traced():
+    """Called once a trace of ``wy_channel``'s forward, the primal and the
+    ``custom_vjp`` rule alike: where ``gated_delta._pass_forward`` counts a
+    site, so that the two counts are of the same traces (a layer under
+    ``jax.checkpoint`` is traced as the primal once and through the rule
+    once more). ``ops/gated_delta.py`` puts its tally here."""
+
+
+def _channel_wy_call(k, v, beta, g, H, C):
+    forward_traced()
+    sh = _channel_shape(k, H, C, v.shape[-1] // H)
+    ins, outs = _channel_wy_specs(sh)
+    lead = (sh.n, sh.B, H)
+    return _call(
+        _channel_wy_fwd_kernel, "gdn_channel_wy_fwd", sh, ins, outs,
+        [
+            jax.ShapeDtypeStruct(lead + (1, C, sh.d_v), _F32),
+            jax.ShapeDtypeStruct(lead + (1, C, sh.d_k), k.dtype),
+            jax.ShapeDtypeStruct(lead + (C, sh.d_k), k.dtype),
+            jax.ShapeDtypeStruct(lead + (1, sh.d_k), _F32),
+        ],
+        k, v, beta, g,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _channel_wy(k, v, beta, g, H: int, C: int):
+    return _channel_wy_call(k, v, beta, g, H, C)
+
+
+def _channel_wy_fwd(k, v, beta, g, H, C):
+    return _channel_wy_call(k, v, beta, g, H, C), (k, v, beta, g)
+
+
+def _channel_wy_bwd(H, C, res, cts):
+    k, v, beta, g = res
+    sh = _channel_shape(k, H, C, v.shape[-1] // H)
+    ins, outs = _channel_wy_specs(sh)
+    return _call(
+        _channel_wy_bwd_kernel, "gdn_channel_wy_bwd", sh, ins + outs, ins,
+        _like(*res), *res, *cts,
+    )
+
+
+_channel_wy.defvjp(_channel_wy_fwd, _channel_wy_bwd)
+
+
+def wy_channel(k, v, beta, g, H: int, C: int):
+    """``wy`` for a decay that is a vector over the key's channels: ``k``
+    and ``g`` [B, T, H d_k] (``g`` float32), ``v`` [B, T, H d_v], ``beta``
+    [B, T, H] float32 -> ``chunk_state_pass``'s arguments for the kind,
+    chunk axis first and every key head its one value head: ``U``
+    [n, B, H, 1, C, d_v] float32, ``W`` [n, B, H, 1, C, d_k], the keys
+    decayed to the chunk's end [n, B, H, C, d_k] and ``a`` [n, B, H, 1,
+    d_k] float32."""
+    B, T, _ = k.shape
+    sh = _channel_shape(k, H, C, v.shape[-1] // H)
+    rows = sh.r * C
+    beta = jnp.transpose(beta.reshape(B, T // rows, rows, H), (1, 0, 3, 2))
+    return _channel_wy(k, v, beta[:, :, :, None], g, H, C)
+
+
+def _channel_read_specs(q, k, g, Vn, S_in):
+    _, _, H, _, C, d_v = Vn.shape
+    sh = _channel_shape(k, H, C, d_v)
+    keys = _tokens(sh, sh.d_k)
+    return sh, [
+        keys, keys, keys,
+        _chunk_major(sh, 1, C, d_v), _chunk_major(sh, 1, sh.d_k, d_v),
+    ]
+
+
+def _channel_read_call(q, k, g, Vn, S_in):
+    sh, ins = _channel_read_specs(q, k, g, Vn, S_in)
+    return _call(
+        _channel_read_fwd_kernel, "gdn_channel_read_fwd", sh, ins,
+        _tokens(sh, sh.d_v),
+        jax.ShapeDtypeStruct((sh.B, sh.n * sh.C, sh.Hk * sh.d_v), k.dtype),
+        q, k, g, Vn, S_in,
+    )
+
+
+@jax.custom_vjp
+def read_out_channel(q, k, g, Vn, S_in):
+    """``read_out`` for the vector kind: ``q, k, g`` [B, T, H d_k], ``V'``
+    [n, B, H, 1, C, d_v] and the entered states [n, B, H, 1, d_k, d_v] as
+    the pass returns them -> ``o`` [B, T, H d_v] in the activation
+    dtype."""
+    return _channel_read_call(q, k, g, Vn, S_in)
+
+
+def _channel_read_fwd(q, k, g, Vn, S_in):
+    return _channel_read_call(q, k, g, Vn, S_in), (q, k, g, Vn, S_in)
+
+
+def _channel_read_bwd(res, do):
+    sh, ins = _channel_read_specs(*res)
+    return _call(
+        _channel_read_bwd_kernel, "gdn_channel_read_bwd", sh,
+        ins + [_tokens(sh, sh.d_v)], ins, _like(*res), *res, do,
+    )
+
+
+read_out_channel.defvjp(_channel_read_fwd, _channel_read_bwd)

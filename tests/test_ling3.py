@@ -11,7 +11,9 @@ score + bias, times 2.5, beside an ungated SwiGLU shared one of 40; plain
 norms, 64 tokens a row) in float32 on the CPU, seeded weights: the
 program's ``loss_fn`` and every gradient leaf against
 ``benchmark/references/ling3.py`` (loaded by path), the chunked vector-decay
-rule against the recurrence one step at a time, the latent attention
+rule against the recurrence one step at a time, its kernels (``gdn_channel_*``,
+ISSUE 46) under ``interpret=True`` against the plain statement and the
+recurrence at heads of 128, the latent attention
 against the written-out full matrix, group-limited routing against a
 brute-force mask, a chip's share of the experts adding up to the whole
 layer, and the tallies of a built step.
@@ -414,34 +416,178 @@ def test_the_vector_rule_refuses_what_it_cannot_chunk():
         )
 
 
-@pytest.mark.parametrize("d_k,d_v,chunk,T,dtype,vector,kernel", [
-    (128, 128, 64, 8192, "bfloat16", False, True),
-    (128, 128, 64, 8192, "bfloat16", True, False),
-    (128, 128, 64, 1024, "float32", True, False),
+@pytest.mark.parametrize("d_k,d_v,chunk,T,dtype,kernel", [
+    (128, 128, 64, 8192, "bfloat16", True),  # the cell
+    (128, 128, 64, 1024, "float32", True),
+    (128, 128, 64, 192, "float32", True),  # chunks that do not pair up
+    (64, 128, 64, 8192, "bfloat16", False),  # a key head of half a tile
+    (128, 192, 64, 8192, "bfloat16", False),
+    (128, 128, 8, 64, "bfloat16", False),  # half a bfloat16 sublane tile
+    (128, 128, 20, 20, "float32", False),
+    (128, 128, 64, 8192 + 32, "bfloat16", False),  # a ragged sequence
 ])
-def test_the_kernels_take_no_site_with_a_vector_decay(
-    d_k, d_v, chunk, T, dtype, vector, kernel
+def test_the_kernels_take_a_vector_decay_where_the_shapes_allow(
+    d_k, d_v, chunk, T, dtype, kernel, monkeypatch
 ):
-    assert kernels.fits(
-        d_k, d_v, chunk, T, dtype, vector_decay=vector
-    ) is kernel
+    """``fits`` reads the shapes alone, and a site with a vector decay goes
+    the way it says: traced at the shapes, the tally counts the site as
+    the kernels' or not."""
+    assert kernels.fits(d_k, d_v, chunk, T, dtype) is kernel
+    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    shape = lambda *s: jax.ShapeDtypeStruct(s, dtype)  # noqa: E731
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
+    args = (
+        shape(1, T, 2, d_k), shape(1, T, 2, d_k), shape(1, T, 2, d_v),
+        f32(1, T, 2), f32(1, T, 2, d_k),
+    )
+    if T % chunk:  # neither way takes it
+        with pytest.raises(ValueError, match="whole chunks"):
+            jax.eval_shape(lambda *a: gated_delta_chunked(*a, chunk), *args)
+        return
+    out = jax.eval_shape(lambda *a: gated_delta_chunked(*a, chunk), *args)
+    assert out.shape == (1, T, 2, d_v) and out.dtype == dtype
+    assert gdn_tally() == (1, T // chunk, int(kernel))
 
 
-def test_a_vector_decay_site_of_kernel_shapes_is_plain_and_says_so(
+def test_a_vector_decay_site_of_kernel_shapes_is_the_kernels_and_says_so(
     monkeypatch
 ):
-    """Heads of whole lane tiles, which the kernels would take with a
-    scalar decay: the site is counted, and not as the kernels'."""
+    """Heads of whole lane tiles: the site is counted as the kernels' with
+    a vector decay as with a scalar one, and lowers to the kind's own."""
     monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
     q, k, v, beta, g = _rule_inputs("mid", B=1, T=64, dk=128, dv=128)
-    jax.jit(lambda *a: gated_delta._delta_rule(*a, 16, None)).lower(
+    text = jax.jit(lambda *a: gated_delta._delta_rule(*a, 16, None)).lower(
         q, k, v, beta, g
-    )
-    assert gdn_tally() == (1, 4, 0)
+    ).as_text()
+    assert gdn_tally() == (1, 4, 1)
+    assert "f32[4,1,2,16,16]" not in text  # no chunk's square around them
     jax.jit(lambda *a: gated_delta._delta_rule(*a, 16, None)).lower(
         q, k, v, beta, g[..., 0]
     )
-    assert gdn_tally() == (2, 8, 1)
+    assert gdn_tally() == (2, 8, 2)
+
+
+# the vector-decay kernels (``gdn_channel_*``) under ``interpret=True``:
+# heads of 128, chunks of 64, two heads, four chunks
+KERNEL_TOL = {"float32": (1e-5, GRAD_RTOL), "bfloat16": (2e-2, 2e-2)}
+
+
+def _kernel_inputs(regime, dtype="float32", **shape):
+    shape = dict(dict(B=1, T=256, H=2, dk=128, dv=128), **shape)
+    q, k, v, beta, g = _rule_inputs(regime, **shape)
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), beta, g
+
+
+def _value_and_cotangents(rule, args, seed=9):
+    o = jax.jit(rule)(*args)
+    w = jax.random.normal(jax.random.PRNGKey(seed), o.shape)
+    grads = jax.jit(jax.grad(
+        lambda *a: jnp.sum(rule(*a).astype(jnp.float32) * w),
+        argnums=range(5),
+    ))(*args)
+    return [np.asarray(x, np.float32) for x in (o, *grads)]
+
+
+@pytest.mark.parametrize("regime", sorted(REGIMES))
+@pytest.mark.parametrize("dtype", sorted(KERNEL_TOL))
+def test_vector_decay_kernels_are_the_plain_statement(dtype, regime):
+    """``o`` and every cotangent (``dq, dk, dv, dbeta, dg``) of the rule
+    through the kernels against ``_chunked_channel``, in the regimes the
+    plain statement is held to the recurrence in."""
+    tol, grad_tol = KERNEL_TOL[dtype]
+    args = _kernel_inputs(regime, dtype)
+    got = _value_and_cotangents(lambda *a: gated_delta_chunked(*a, 64), args)
+    want = _value_and_cotangents(
+        lambda *a: gated_delta._chunked_channel(*a, 64), args
+    )
+    for name, a, b, t in zip(
+        ["o", "dq", "dk", "dv", "dbeta", "dg"], got, want,
+        [tol] + [grad_tol] * 5,
+    ):
+        assert a.shape == b.shape and np.all(np.isfinite(a)), name
+        assert np.max(np.abs(a - b)) <= t * np.max(np.abs(b)), name
+
+
+@pytest.mark.parametrize("regime", sorted(REGIMES))
+def test_vector_decay_rule_through_the_kernels_is_the_recurrence(
+    regime, monkeypatch
+):
+    """Forward and in every gradient against one step at a time; the tally
+    says which way the site went (a site, its steps, in the kernels; the
+    backward pass its steps again)."""
+    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    args = _kernel_inputs(regime)
+    want = jax.jit(delta_rule_sequential)(*args)
+    got = jax.jit(lambda *a: gated_delta_chunked(*a, 64))(*args)
+    assert gdn_tally() == (1, 4, 1)
+    assert got.shape == want.shape == (1, 256, 2, 128)
+    assert _rel(got, want) <= RTOL
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=range(5)
+        ))(*args)
+
+    for a, b in zip(
+        grads(lambda *a: gated_delta_chunked(*a, 64)),
+        grads(delta_rule_sequential),
+    ):
+        assert _rel(a, b) <= GRAD_RTOL
+    assert gdn_tally() == (2, 12, 2)
+
+
+def test_under_recomputation_every_counted_site_is_counted_in_the_kernels(
+    monkeypatch
+):
+    """A layer under ``jax.checkpoint`` is traced as the primal once and
+    through the ``custom_vjp`` rules once more: the pass counts a site each
+    time, and so does the kernels' forward, or the share of sites in the
+    kernels would read 50 where every site is theirs (the Ling cell's
+    first traced run did)."""
+    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    args = _kernel_inputs("mid")
+    layer = jax.checkpoint(lambda *a: gated_delta_chunked(*a, 64))
+    jax.jit(jax.grad(
+        lambda *a: jnp.sum(layer(*a) ** 2), argnums=range(5)
+    )).lower(*args)
+    sites, steps, in_kernels = gdn_tally()
+    assert (sites, in_kernels) == (2, 2)
+    assert steps == 3 * 4  # forward, the forward again, backward
+
+
+@pytest.mark.parametrize("T,chunk", [(192, 64), (64, 8), (80, 40)])
+def test_the_kernels_square_whatever_chunks_there_are(T, chunk):
+    """Three chunks (a square holds one, not two), chunks of 8 (no
+    sub-blocks, no level of halves) and of 40 (not a power of two: the
+    halves' masks go by position) are the recurrence too."""
+    args = _kernel_inputs("mid", T=T)
+    want = jax.jit(delta_rule_sequential)(*args)
+    got = jax.jit(lambda *a: gated_delta_chunked(*a, chunk))(*args)
+    assert _rel(got, want) <= RTOL
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_kernels_inverse_is_the_one_by_halves(dtype, monkeypatch):
+    """``test_nearly_parallel_keys_that_hardly_decay_stay_the_recurrence``'s
+    case at heads of 128, through the kernels: with the product form in
+    their place the first chunk reads 1e8 and the third NaN."""
+    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    B, T, H, d = 1, 2048, 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    base = l2norm(jax.random.normal(ks[0], (1, 1, H, d)))
+    noise = jax.random.normal(ks[1], (B, T, H, d)) / np.sqrt(d)
+    k = l2norm(0.99 * base + np.sqrt(1 - 0.99**2) * noise)
+    q = l2norm(jax.random.normal(ks[2], (B, T, H, d))) * d**-0.5
+    v = jax.random.normal(ks[3], (B, T, H, d))
+    beta = jnp.full((B, T, H), 0.99)
+    g = jnp.full((B, T, H, d), -1e-3)
+    want = jax.jit(delta_rule_sequential)(q, k, v, beta, g)
+    got = jax.jit(lambda *a: gated_delta_chunked(*a, 64))(
+        q.astype(dtype), k.astype(dtype), v.astype(dtype), beta, g
+    ).astype(jnp.float32)
+    assert gdn_tally() == (1, 32, 1)
+    assert np.all(np.isfinite(got))
+    assert _rel(got, want) <= (2e-4 if dtype == "float32" else 5e-2)
 
 
 def test_the_decay_stays_inside_its_bound_and_the_gate_is_a_head():

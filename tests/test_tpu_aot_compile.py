@@ -257,6 +257,57 @@ def test_delta_rule_chunk_kernels_compile_at_the_cell(
     assert gated_delta.gdn_tally() - before == (1, steps, 1)
 
 
+CHANNEL_KERNELS = [
+    "gdn_channel_wy_fwd", "gdn_channel_read_fwd",
+    "gdn_channel_wy_bwd", "gdn_channel_read_bwd",
+]
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_vector_decay_chunk_kernels_compile_at_the_cell(
+    direction, one_chip, monkeypatch
+):
+    """The Ling cell's Kimi-Delta-Attention layer, 1 x 8192 at 32 heads of
+    128 / 128 in chunks of 64, bfloat16, the decay a vector over the key's
+    channels: the two kernels around the serial pass forward, all four
+    under ``grad``; no [64, 64] square of a head and chunk and no decayed
+    copy of a chunk's keys a row block is left in the program around
+    them."""
+    from dlrover_tpu.ops import gated_delta
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    B, T, H, d, C = 1, 8192, 32, 128, 64
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [
+        sds((B, T, H, d)), sds((B, T, H, d)), sds((B, T, H, d)),
+        sds((B, T, H), jnp.float32), sds((B, T, H, d), jnp.float32),
+    ]
+
+    def rule(*a):
+        return gated_delta.gated_delta_chunked(*a, C)
+
+    before = gated_delta.gdn_tally()
+    if direction == "fwd":
+        text = _compile_for_chip(rule, *args).as_text()
+        want, steps = CHANNEL_KERNELS[:2], T // C
+    else:
+        text = _compile_for_chip(
+            jax.grad(lambda *a: jnp.sum(rule(*a) ** 2), argnums=range(5)),
+            *args,
+        ).as_text()
+        want, steps = CHANNEL_KERNELS, 2 * T // C
+    for kernel in want:
+        assert kernel in text, kernel
+    assert "gdn_chunk_" not in text  # the scalar kind's are not this site's
+    n = T // C
+    assert f"f32[{n},{B},{H},{C},{C}]" not in text
+    assert f"bf16[{n},{B},{H},{C // 16},{C},{d}]" not in text
+    assert gated_delta.gdn_tally() - before == (1, steps, 1)
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_attention_of_192_and_128_compiles_as_the_program_calls_it(
     direction, one_chip, monkeypatch
@@ -502,6 +553,48 @@ def test_sharded_delta_rule_compiles_for_four_chips(topo, monkeypatch):
             *(jnp.zeros(a.shape[:0] + (3,) + a.shape[1:], a.dtype)
               for a in args), C, mesh,
         )
+
+
+def test_sharded_vector_decay_rule_compiles_for_four_chips(topo, monkeypatch):
+    """The same for a decay that is a vector over the key's channels, at
+    the Ling widths: ``g`` has four axes and is sharded as ``q`` is, batch
+    over fsdp and heads over tp; all four of the kind's kernels are in the
+    step."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dlrover_tpu.ops import gated_delta
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), devices=topo.devices[:4])
+    B, T, H, d, C = 2, 2048, 32, 128, 64
+
+    def sds(shape, dtype=jnp.bfloat16):
+        spec = P(("dp", "fsdp"), None, "tp", None)[:len(shape)]
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec))
+        )
+
+    args = [
+        sds((B, T, H, d)), sds((B, T, H, d)), sds((B, T, H, d)),
+        sds((B, T, H), jnp.float32), sds((B, T, H, d), jnp.float32),
+    ]
+    with pytest.raises(NotImplementedError, match="automatically partitioned"):
+        jax.jit(
+            lambda *a: gated_delta.gated_delta_chunked(*a, C)
+        ).lower(*args)
+    text = _compile_for_chip(
+        jax.grad(
+            lambda *a: jnp.sum(gated_delta._delta_rule(*a, C, mesh) ** 2),
+            argnums=range(5),
+        ),
+        *args,
+    ).as_text()
+    for kernel in CHANNEL_KERNELS:
+        assert kernel in text, kernel
+    # a device's share: one batch element, 16 heads
+    assert f"f32[1,{T},{16 * d}]" in text
 
 
 def test_explicit_sync_step_over_dp_x_tp_compiles_for_four_chips(

@@ -18,7 +18,12 @@ Variants of one shape ``BxHkxHvxTxDxC``:
   rule, only what its full-precision products cost;
 - ``parts``: each of the four kernels and the serial pass, forward and
   backward, alone (one call a program; a backward call is its kernel or
-  loop without the forward, which nothing reads).
+  loop without the forward, which nothing reads);
+- ``vector``: not a variant of its own: the decay of every variant named
+  with it is a vector over the key's channels (Kimi Delta Attention: ``g``
+  [B, T, H, D] in (-5, 0) as the layer draws it at its start, ``Hk == Hv``),
+  so ``kernel`` is the ``gdn_channel_*`` kernels and ``plain`` is
+  ``_chunked_channel``.
 
 With both ``kernel`` and ``plain`` among the variants, the output and
 every cotangent of one call are compared too (largest difference over
@@ -26,6 +31,7 @@ the largest plain value), which no CPU run can do for the compiled
 kernels.
 
     python tools/gdn_kernel_bench.py 1x16x32x8192x128x64 kernel plain chunks:1
+    python tools/gdn_kernel_bench.py 1x32x32x8192x128x64 vector kernel plain parts
 """
 import json
 import sys
@@ -46,7 +52,7 @@ REPEATS = 5
 ROUNDS = 5
 
 
-def _inputs(B, Hk, Hv, T, D, seed=0, dtype=jnp.bfloat16):
+def _inputs(B, Hk, Hv, T, D, vector, seed=0, dtype=jnp.bfloat16):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     q = gated_delta.l2norm(jax.random.normal(ks[0], (B, T, Hk, D)))
     k = gated_delta.l2norm(jax.random.normal(ks[1], (B, T, Hk, D)))
@@ -55,6 +61,18 @@ def _inputs(B, Hk, Hv, T, D, seed=0, dtype=jnp.bfloat16):
     # decay rates as the layer's at its start: A = U(0, 16], softplus ~ 1
     A = 16.0 * (1.0 - jax.random.uniform(ks[4], (Hv,)))
     g = -A * jax.nn.softplus(jax.random.normal(ks[5], (B, T, Hv)) + 1.0)
+    if vector:
+        # the share of the bound a step decays by, log-uniform a channel
+        # over ``DT_SHARE`` where the projection reads 0, as the layer's
+        lo, hi = gated_delta.DT_SHARE
+        share = jnp.exp(
+            jax.random.uniform(ks[4], (Hk * D,)) * jnp.log(hi / lo)
+            + jnp.log(lo)
+        )
+        g = -5.0 * jax.nn.sigmoid(
+            jax.random.normal(ks[5], (B, T, Hk * D))
+            + jnp.log(share / (1.0 - share))
+        ).reshape(B, T, Hk, D)
     return (
         (q * D**-0.5).astype(dtype), k.astype(dtype), v.astype(dtype),
         beta, g,
@@ -112,10 +130,18 @@ def _parts(C: int, args):
         ).reshape(rows)
 
     q, k = q.reshape(B, T, Hk * D), k.reshape(B, T, Hk * D)
-    v, beta, g = v.reshape(B, T, Hv * D), per_head(beta), per_head(g)
+    v = v.reshape(B, T, Hv * D)
+    if g.ndim == 4:
+        g, read = g.reshape(B, T, Hk * D), kernels.read_out_channel
 
-    def wy(k, v, beta, g):
-        return kernels.wy(k, v, beta, g, Hk, r, C)
+        def wy(k, v, beta, g):
+            U, W, Kl, a = kernels.wy_channel(k, v, beta, g, Hk, C)
+            return U, W, Kl, None, a
+    else:
+        beta, g, read = per_head(beta), per_head(g), kernels.read_out
+
+        def wy(k, v, beta, g):
+            return kernels.wy(k, v, beta, g, Hk, r, C)
 
     def cotangents(outs):
         return jax.tree.map(lambda x: jnp.ones(x.shape, x.dtype), outs)
@@ -130,7 +156,7 @@ def _parts(C: int, args):
     stretches = {
         "wy": (wy, (k, v, beta, g)),
         "pass": (gated_delta.chunk_state_pass, made),
-        "read": (kernels.read_out, (q, k, g, *passed)),
+        "read": (read, (q, k, g, *passed)),
     }
     out = {}
     for name, (fn, a) in stretches.items():
@@ -155,12 +181,14 @@ def _rel(got, want):
 
 def main(argv):
     B, Hk, Hv, T, D, C = (int(x) for x in argv[0].split("x"))
-    variants = argv[1:] or ["kernel", "plain"]
+    variants = [v for v in argv[1:] if v != "vector"] or ["kernel", "plain"]
+    vector = "vector" in argv[1:]
     dev = jax.devices()[0]
-    args = _inputs(B, Hk, Hv, T, D)
+    args = _inputs(B, Hk, Hv, T, D, vector)
     out = {
         "device": {"platform": dev.platform, "kind": dev.device_kind},
-        "shape": argv[0], "layers": LAYERS, "variants": {},
+        "shape": argv[0], "decay": "channel" if vector else "head",
+        "layers": LAYERS, "variants": {},
     }
     held = {}
     for variant in variants:

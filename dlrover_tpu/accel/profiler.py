@@ -149,6 +149,15 @@ class PipelineStats:
     gdn_sites: int = 0
     gdn_chunk_steps: int = 0
     gdn_kernel_sites: int = 0
+    # convolution stretches before a scan (``ops/mamba2.conv_silu``
+    # ``ConvTally``: one a Mamba-2 or Gated DeltaNet mixer) in the train
+    # step program this process traced last, and those among them that
+    # were traced into the ``conv_silu_*`` kernels
+    # (``ops/conv_kernels.fits``). Both counted at one place, so a layer
+    # traced twice under ``jax.checkpoint`` counts twice in both. Set when
+    # the trainer logs the step it built; 0 / 0 for a model without them
+    conv_sites: int = 0
+    conv_kernel_sites: int = 0
     # the width of a head's query and key summed over the attention sites
     # of the train step program this process traced last
     # (models/transformer.py ``ScoreLanes``): what the attention call was

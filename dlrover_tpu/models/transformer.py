@@ -614,9 +614,9 @@ def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
 
 
 @jax.named_scope("scope/layer/ssm")
-def _ssm_block(x, layer, cfg: TransformerConfig):
+def _ssm_block(x, layer, cfg: TransformerConfig, mesh):
     h = _norm(x, layer["norm"], cfg)
-    return x + mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg))
+    return x + mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg), mesh)
 
 
 @jax.named_scope("scope/layer/gdn")
@@ -827,7 +827,7 @@ def forward(
     def mixer_layer(x, layer, kind):
         """One layer of a ``layer_pattern``: ``x + mixer(norm(x))``."""
         if kind == "M":
-            return _ssm_block(x, layer, cfg), None
+            return _ssm_block(x, layer, cfg, mesh), None
         if kind == "G":
             return _gdn_block(x, layer, cfg, mesh), None
         if kind == "*":

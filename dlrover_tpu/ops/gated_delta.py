@@ -77,7 +77,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.ops import gated_delta_kernels as kernels
-from dlrover_tpu.ops.mamba2 import causal_conv1d, gated_group_rmsnorm
+from dlrover_tpu.ops.mamba2 import conv_silu, gated_group_rmsnorm
 
 L2_EPS = 1e-6  # of the unit-length q and k (the source's ``l2norm``)
 SUB_BLOCK = kernels.SUB_BLOCK  # the one bound of both ways to execute
@@ -722,9 +722,7 @@ def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
     # the elementwise stretches compute in float32 and are made again in
     # the backward pass, as the Mamba-2 layer's (``ops/mamba2.py``)
     with jax.named_scope("scope/layer/gdn/conv"):
-        qkv = jax.checkpoint(
-            lambda x, w: jax.nn.silu(causal_conv1d(x, w)).astype(act)
-        )(qkv, p["conv_w"])
+        qkv = conv_silu(qkv, p["conv_w"], mesh=mesh)
     with jax.named_scope("scope/layer/gdn/scan"):
         if channel:
             beta = jax.nn.sigmoid(b)

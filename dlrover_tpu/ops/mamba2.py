@@ -21,16 +21,24 @@ chunk states are float32; the matmul operands are
 the activation dtype, accumulated in float32. The tests hold it to the
 recurrence itself, one step at a time.
 
+The convolution and its SiLU are ``conv_silu``, which this layer and the
+Gated DeltaNet layer (``ops/gated_delta.py``) both call: the plain statement
+here, or the ``conv_silu_*`` kernels of ``ops/conv_kernels.py`` where their
+rule takes the input.
+
 The spans of a layer: ``scope/layer/ssm/{in_proj,conv,scan,gate,out_proj}``.
 """
 
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from dlrover_tpu.ops import conv_kernels
 
 
 def init_mamba2_params(key, cfg, dtype):
@@ -99,6 +107,48 @@ def causal_conv1d(x, w, b=None):
     for k in range(K):
         y = y + padded[:, k:k + T, :] * wf[k]
     return y
+
+
+class ConvTally(NamedTuple):
+    """Convolution stretches traced so far in this process (one a Mamba-2
+    or Gated DeltaNet mixer of a program) and those among them that went
+    into the kernels (``conv_kernels.fits``). Counted when a program is
+    traced, both in ``conv_silu``, so a layer traced twice under
+    ``jax.checkpoint`` counts twice in both."""
+
+    sites: int = 0
+    kernel_sites: int = 0
+
+    def __sub__(self, other):
+        return ConvTally(*(a - b for a, b in zip(self, other)))
+
+
+_conv_tally = ConvTally()
+
+
+def conv_tally() -> ConvTally:
+    return _conv_tally
+
+
+def conv_silu(x, w, b=None, mesh=None):
+    """``silu(causal_conv1d(x, w, b))`` rounded once to ``x``'s dtype, the
+    stretch before a mixer's scan. Where ``conv_kernels.fits`` takes the
+    input, forward and backward are the ``conv_silu_*`` kernels; everywhere
+    else the plain statement, float32 inside and made again in the backward
+    pass: what either keeps is its inputs in the activation dtype, and not
+    a float32 copy of every channel of every token. ``mesh``: the mesh the
+    step is sharded over, or None inside a region that names its own
+    axes."""
+    global _conv_tally
+    in_kernels = conv_kernels.fits(x, w, mesh)
+    _conv_tally = ConvTally(
+        _conv_tally.sites + 1, _conv_tally.kernel_sites + in_kernels
+    )
+    if in_kernels:
+        return conv_kernels.conv_silu(x, w, b)
+    return jax.checkpoint(
+        lambda x, w, b: jax.nn.silu(causal_conv1d(x, w, b)).astype(x.dtype)
+    )(x, w, b)
 
 
 def ssd_chunked(x, dt, a, Bm, Cm, chunk: int):
@@ -189,8 +239,10 @@ def gated_group_rmsnorm(y, z, weight, groups: int, eps: float,
     return out * gate if norm_before_gate else out
 
 
-def mamba2_mixer(u, p, cfg, eps: float):
-    """u [B, T, d] (already normed) -> [B, T, d]."""
+def mamba2_mixer(u, p, cfg, eps: float, mesh=None):
+    """u [B, T, d] (already normed) -> [B, T, d]. ``mesh``: the mesh the
+    step is sharded over, or None inside a region that names its own axes
+    (``conv_silu``)."""
     Bsz, T, _ = u.shape
     H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     d_in = H * P
@@ -207,9 +259,7 @@ def mamba2_mixer(u, p, cfg, eps: float):
     # what they keep is their inputs in the activation dtype, and not a
     # float32 copy of every channel of every token
     with jax.named_scope("scope/layer/ssm/conv"):
-        xbc = jax.checkpoint(
-            lambda v, w, b: jax.nn.silu(causal_conv1d(v, w, b)).astype(dt_act)
-        )(xbc, p["conv_w"], p["conv_b"])
+        xbc = conv_silu(xbc, p["conv_w"], p["conv_b"], mesh)
     x = xbc[..., :d_in].reshape(Bsz, T, H, P)
     Bm = xbc[..., d_in:d_in + G * N].reshape(Bsz, T, G, N)
     Cm = xbc[..., d_in + G * N:].reshape(Bsz, T, G, N)

@@ -227,9 +227,11 @@ class ElasticTrainer:
         self._builds = compile_meter()
         self._builds_first = self._builds_logged = len(self._builds.builds)
         self._built: set = set()
-        # ops.gated_delta's tally as a train step's build began, and
+        # ops.gated_delta's tally as a train step's build began,
+        # ops.mamba2's of the convolution stretches, and
         # models.transformer's of the attention sites' score widths
         self._gdn_before_step = None
+        self._conv_before_step = None
         self._lanes_before_step = None
         self.tcfg = trainer_cfg or TrainerConfig()
         self._metrics_hook = metrics_hook
@@ -446,7 +448,7 @@ class ElasticTrainer:
             logger.info(
                 f"programs built {when}: {describe_builds(rows)}{q8}"
                 f"{self._fold_attention_tally()}{self._fold_gdn_tally()}"
-                f"{self._fold_score_lanes()}"
+                f"{self._fold_score_lanes()}{self._fold_conv_tally()}"
             )
 
     def _fold_first_step(self):
@@ -547,6 +549,25 @@ class ElasticTrainer:
             "the kernels were called with"
         )
 
+    def _fold_conv_tally(self) -> str:
+        """The convolution stretches traced since a train step's build
+        began into the stats, and in words; by ``_fold_gdn_tally``'s
+        rules."""
+        from dlrover_tpu.ops.mamba2 import conv_tally
+
+        before, self._conv_before_step = self._conv_before_step, None
+        if before is None:
+            return ""
+        step = conv_tally() - before
+        if not step.sites:
+            return ""
+        stats = self.pipeline_stats
+        stats.conv_sites, stats.conv_kernel_sites = step
+        return (
+            f"; convolution: {step.sites} sites "
+            f"({step.kernel_sites} in the kernel)"
+        )
+
     def _first_build(self, what: str):
         """``build:<what>`` around the FIRST call of a jitted program
         (jit compiles, or loads from the cache, inside that call);
@@ -557,8 +578,10 @@ class ElasticTrainer:
         if what.startswith("step_"):
             from dlrover_tpu.models.transformer import score_lanes_tally
             from dlrover_tpu.ops.gated_delta import gdn_tally
+            from dlrover_tpu.ops.mamba2 import conv_tally
 
             self._gdn_before_step = gdn_tally()
+            self._conv_before_step = conv_tally()
             self._lanes_before_step = score_lanes_tally()
         return self._builds.build(what)
 

@@ -9,8 +9,11 @@ recorded anew when the delta rule's output took the activation dtype inside
 ``gated_delta_chunked``: at this batch of 1 x 1024 with heads of 128 the
 chunk-local work lowers to the ``gdn_chunk_*`` kernels, interpreted on the
 CPU), and what ISSUE 45 gave for ``ling-3.0-flash-d7``, the family it
-brought (a later PR that means to leave it alone leaves both alone), made
-by running this file there:
+brought (a later PR that means to leave it alone leaves both alone). The
+three hybrid configurations' steps were recorded anew at ISSUE 47, whose
+convolution kernels their mixers take at these widths (``conv_silu_*``,
+interpreted on the CPU); their trees and the other three entries are as
+they were. Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
 

@@ -858,3 +858,58 @@ def test_two_threads_publish_one_file(tmp_path):
     with open(path) as f:
         assert json.load(f)["i"] == 1499
     assert os.listdir(tmp_path) == ["runtime_metrics.json"]
+
+
+CONV_SHARE_RUNS = {
+    "every_site_in_the_kernel": ("MEM*", {"conv_sites": 4, "conv_kernel_sites": 4}, 100.0),
+    "every_site_of_a_recomputed_step": ("G-GEGE*E", {"conv_sites": 12, "conv_kernel_sites": 12}, 100.0),
+    "one_site_of_three_plain": ("GEGE", {"conv_sites": 3, "conv_kernel_sites": 2}, 200 / 3),
+    "no_site_in_the_kernel": ("GEGE", {"conv_sites": 3, "conv_kernel_sites": 0}, 0.0),
+    "a_program_without_the_counter": ("GEGE", {"gdn_sites": 3, "gdn_kernel_sites": 3}, None),
+    "a_program_with_half_the_counter": ("MEM*", {"conv_sites": 4}, None),
+    "no_step_was_traced": ("MEM*", {"conv_sites": 0, "conv_kernel_sites": 0}, None),
+    "a_window_without_the_record": ("GEGE", None, None),
+    "a_configuration_without_the_kind": ("E*E*", {"conv_sites": 2, "conv_kernel_sites": 2}, None),
+    "the_old_blocks": ("", {"conv_sites": 3, "conv_kernel_sites": 3}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CONV_SHARE_RUNS))
+def test_conv_kernel_sites_share_reads_the_two_counters(case):
+    """``conv.kernel_sites_share`` (ISSUE 47): 100 x ``conv_kernel_sites``
+    over ``conv_sites`` of the window's ``pipeline`` record; nothing where
+    the program keeps no such counter (the parent's traced run prints what
+    it printed) or the configuration no layer with a convolution."""
+    pattern, pipeline, want = CONV_SHARE_RUNS[case]
+    mod = _reader("conv.kernel_sites_share")
+    run = types.SimpleNamespace(
+        config={"model": {"layer_pattern": pattern}},
+        window={} if pipeline is None else {"pipeline": pipeline},
+    )
+    got = mod.read(run)
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+def test_conv_kernel_sites_share_is_declared_as_its_reader_says():
+    mod = _reader("conv.kernel_sites_share")
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    # the cells whose configuration has a Mamba-2 or a Gated DeltaNet /
+    # Kimi Delta Attention layer
+    cells = [
+        "nemotron3-nano-30b-a3b-d9.steady", "qwen3-next-80b-a3b-d4.steady",
+        "ling-3.0-flash-d7.steady",
+    ]
+    assert bench["per_layer"][-1] == {
+        "name": "conv.kernel_sites_share", "unit": mod.UNIT,
+        "better": "higher", "source": "program_counter",
+        "layer": mod.LAYER, "moves": mod.MOVES, "workloads": cells,
+    }
+    assert (mod.UNIT, mod.LAYER, mod.MOVES) == ("%", "kernels", "tokens_per_s")
+    for w in bench["workloads"]:
+        path = os.path.join(REPO, "benchmark", "cells", w["name"] + ".json")
+        with open(path) as f:
+            cell = json.load(f)
+        assert mod.CELLS(cell) == (w["name"] in cells)
+    assert mod.CELLS({"config": "no-such-configuration"}) is True
+

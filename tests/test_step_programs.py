@@ -134,7 +134,8 @@ def test_rebuild_drops_the_executable(accel):
 # share of the routing (PR 37), the streaming attention tally's four
 # (PR 38), an incarnation's way up and the restart behind it (PR 40), the
 # shard lock's side of the due saves (PR 42), the Gated DeltaNet tally's
-# two (PR 43) and its sites in the kernels (PR 44)
+# two (PR 43), its sites in the kernels (PR 44) and the convolution
+# tally's two (PR 47)
 AS_DICT_KEYS = [
     "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
     "attn_stream_blocks_rect",
@@ -142,7 +143,8 @@ AS_DICT_KEYS = [
     "attn_stream_tri_sites", "attn_tiles_square", "attn_tiles_walked",
     "attn_tri_sites", "begin_lock_s", "comm_overlap_pct",
     "compile_cache_hit_pct", "compile_cache_hits",
-    "compile_cache_misses", "donated_bytes", "donated_steps",
+    "compile_cache_misses", "conv_kernel_sites", "conv_sites",
+    "donated_bytes", "donated_steps",
     "gdn_chunk_steps", "gdn_kernel_sites", "gdn_sites", "grad_bytes_raw", "grad_bytes_wire",
     "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",

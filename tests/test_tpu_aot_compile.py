@@ -257,6 +257,58 @@ def test_delta_rule_chunk_kernels_compile_at_the_cell(
     assert gated_delta.gdn_tally() - before == (1, steps, 1)
 
 
+CONV_SHAPES = {
+    # [B, T, C], with a bias or without: the convolution before the scan
+    "nemotron3_nano_mamba2": ((1, 8192, 6144), True),
+    "qwen3_next_deltanet": ((1, 8192, 8192), False),
+    "ling3_kda": ((1, 8192, 12288), False),
+}
+
+
+@pytest.mark.parametrize("name", list(CONV_SHAPES))
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_convolution_kernels_compile_at_the_cells(
+    name, direction, one_chip, monkeypatch
+):
+    """``silu(causal_conv1d(...))`` at the three hybrid cells' widths,
+    1 x 8192 in bfloat16 with float32 weights of four taps: the forward
+    kernel, and under ``grad`` the backward kernel with the taps'
+    weight-gradient sums inside it; no padded float32 copy of the tokens
+    is left in the program around them."""
+    from dlrover_tpu.ops import conv_kernels, mamba2
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    shape, bias = CONV_SHAPES[name]
+    C = shape[2]
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds(shape, jnp.bfloat16), sds((4, C))] + [sds((C,))] * bias
+    assert conv_kernels.fits(*args[:2])
+    before = mamba2.conv_tally()
+    if direction == "fwd":
+        text = _compile_for_chip(mamba2.conv_silu, *args).as_text()
+        want = ["conv_silu_fwd"]
+    else:
+        text = _compile_for_chip(
+            jax.grad(
+                lambda *a: jnp.sum(
+                    mamba2.conv_silu(*a).astype(jnp.float32) ** 2
+                ),
+                argnums=range(len(args)),
+            ),
+            *args,
+        ).as_text()
+        want = ["conv_silu_fwd", "conv_silu_bwd"]
+    for kernel in want:
+        assert kernel in text, kernel
+    if direction == "fwd":  # (the test's own loss is a float32 fusion)
+        assert f"f32[{shape[0]},{shape[1]},{C}]" not in text
+    assert f"f32[{shape[0]},{shape[1] + 3},{C}]" not in text
+    assert mamba2.conv_tally() - before == (1, 1)
+
+
 CHANNEL_KERNELS = [
     "gdn_channel_wy_fwd", "gdn_channel_read_fwd",
     "gdn_channel_wy_bwd", "gdn_channel_read_bwd",

@@ -117,59 +117,59 @@ class PipelineStats:
     # Set once when the trainer has built its state; 0 / 0 for fp32
     opt_q8_tiles_elems: int = 0
     opt_q8_blocks_elems: int = 0
-    # fused short-sequence attention call sites (ops/flash_attention.py
-    # ``FusedTally``, forward and backward each counted) this process
-    # has lowered, by body: the triangle walk, which computes only the
-    # score tiles a causal query can see, or the whole square; and the
-    # tiles the triangle sites walk against the tiles of their squares.
-    # Set when the trainer logs the programs it built; 0 everywhere
-    # for a model that never enters the fused family
+    # -- counted while programs were traced (common/trace_counts.py: a
+    # counter's name is its field here; the trainer folds them when it
+    # logs the programs it built) ---------------------------------------
+    # fused short-sequence attention call sites (ops/flash_attention.py,
+    # forward and backward each counted) this process has lowered, by
+    # body: the triangle walk, which computes only the score tiles a
+    # causal query can see, or the whole square; and the tiles the
+    # triangle sites walk against the tiles of their squares. 0
+    # everywhere for a model that never enters the fused family
     attn_tri_sites: int = 0
     attn_square_sites: int = 0
     attn_tiles_walked: int = 0
     attn_tiles_square: int = 0
-    # ... and the streaming kernels (``StreamTally``: longer T or GQA; a
-    # forward is one site, a backward one in one pass or two split), by
-    # grid: the triangle path, which takes a grid step, a fetch and a
-    # mask only where a causal query can see, or the whole rectangle;
-    # and the blocks a head of the triangle sites walks against the
-    # blocks of its rectangle
+    # ... and the streaming kernels (longer T or GQA; a forward is one
+    # site, a backward one in one pass or two split), by grid: the
+    # triangle path, which takes a grid step, a fetch and a mask only
+    # where a causal query can see, or the whole rectangle; and the
+    # blocks a head of the triangle sites walks against the blocks of
+    # its rectangle
     attn_stream_tri_sites: int = 0
     attn_stream_rect_sites: int = 0
     attn_stream_blocks_walked: int = 0
     attn_stream_blocks_rect: int = 0
-    # Gated DeltaNet mixers (ops/gated_delta.py ``GdnTally``) in the
-    # train step program this process traced last, and the sequential
-    # chunk-state steps one training step runs through them, forward and
-    # backward: the step's serial depth in that layer kind. Set when the
-    # trainer logs the step it built; 0 / 0 for a model without the kind.
-    # ``gdn_kernel_sites``: the mixers among them whose chunk-local work
-    # (the [C, C] squares around the pass) was traced into the
-    # ``gdn_chunk_*`` kernels (``ops/gated_delta_kernels.fits``)
+    # Gated DeltaNet mixers (ops/gated_delta.py) in the train step
+    # program this process traced last, and the sequential chunk-state
+    # steps one training step runs through them, forward and backward:
+    # the step's serial depth in that layer kind; 0 / 0 for a model
+    # without the kind. ``gdn_kernel_sites``: the mixers among them whose
+    # chunk-local work (the [C, C] squares around the pass) was traced
+    # into the ``gdn_chunk_*`` kernels (``ops/gated_delta_kernels.fits``)
     gdn_sites: int = 0
     gdn_chunk_steps: int = 0
     gdn_kernel_sites: int = 0
-    # convolution stretches before a scan (``ops/mamba2.conv_silu``
-    # ``ConvTally``: one a Mamba-2 or Gated DeltaNet mixer) in the train
-    # step program this process traced last, and those among them that
-    # were traced into the ``conv_silu_*`` kernels
-    # (``ops/conv_kernels.fits``). Both counted at one place, so a layer
-    # traced twice under ``jax.checkpoint`` counts twice in both. Set when
-    # the trainer logs the step it built; 0 / 0 for a model without them
+    # convolution stretches before a scan (``ops/mamba2.conv_silu``: one
+    # a Mamba-2 or Gated DeltaNet mixer) in the train step program this
+    # process traced last, and those among them that were traced into the
+    # ``conv_silu_*`` kernels (``ops/conv_kernels.fits``). Both counted at
+    # one place, so a layer traced twice under ``jax.checkpoint`` counts
+    # twice in both; 0 / 0 for a model without them
     conv_sites: int = 0
     conv_kernel_sites: int = 0
     # the width of a head's query and key summed over the attention sites
     # of the train step program this process traced last
-    # (models/transformer.py ``ScoreLanes``): what the attention call was
-    # given, and what the model states. They differ where the call pads
-    # (a latent attention's 192 through kernels of whole lane tiles). Set
-    # when the trainer logs the step it built; 0 / 0 without attention
+    # (models/transformer.py): what the attention call was given, and
+    # what the model states. They differ where the call pads (a latent
+    # attention's 192 through kernels of whole lane tiles); 0 / 0 without
+    # attention
     attn_score_lanes: int = 0
     attn_score_lanes_used: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was
-    # silent-by-design before ISSUE 8; now visible in bench output and
+    # silent-by-design before ISSUE 8; now visible in the log and in
     # the metrics registry via the numeric grad_sync_explicit twin).
     # "" until a trainer resolves the plan.
     grad_sync_path: str = ""
@@ -187,8 +187,8 @@ class PipelineStats:
     comm_overlap_pct: Optional[float] = None
     # the A/B-measured twin of comm_overlap_pct (grad_sync.measured_
     # overlap_pct: step time with the sync vs without, normalized by
-    # the standalone roofline); None until someone ran the A/B —
-    # ElasticTrainer.measure_realized_overlap or the topology bench
+    # the standalone roofline); None where that A/B was not run, and
+    # nothing in the tree runs it
     overlap_pct_measured: Optional[float] = None
     # wire bytes one sync moves vs what the uncompressed monolithic
     # sync would move (per optimizer step, per device ring traffic

@@ -277,7 +277,7 @@ class ClusterScheduler(PollingDaemon):
     the datastore protocol (``BrainServicer``): ``job_metrics`` /
     ``node_events`` / ``record_node_event`` / ``active_jobs`` and the
     ``cluster_plans`` table methods. Start it with ``.start()`` for
-    the daemon loop or call ``run_pass()`` directly (tests, bench)."""
+    the daemon loop or call ``run_pass()`` directly (tests)."""
 
     def __init__(
         self,

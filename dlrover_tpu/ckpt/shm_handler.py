@@ -370,7 +370,8 @@ class ShmSubscriber:
         Fault point ``serve.stale_read``: sits between the zero-copy
         map and the seqlock re-check — an armed delay widens exactly
         the window a concurrent commit must hit to tear the frame,
-        which is how the bench provokes the race deterministically."""
+        which is how ``tests/test_serving.py`` provokes the race
+        deterministically."""
         faults.fire("serve.subscribe")
         meta = self.handler.metadata()
         gen = meta.get("gen")

@@ -4,7 +4,7 @@ The only failure testing the repo had was the random-SIGKILL chaos soak —
 process death, nothing else, and nothing reproducible. This module gives
 the storage/RPC failure scenarios a deterministic harness: production
 code declares *fault points* (named sites like ``ckpt.shard_write``),
-and a test/bench/operator arms them with spec strings::
+and a test, a harness or an operator arms them with spec strings::
 
     site:kind:prob[:seed]
     site:kind:@N[:seed]

@@ -18,13 +18,14 @@ TPU-first design notes:
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.models.config import (
     LAYER_KINDS,
     TransformerConfig,
@@ -390,34 +391,15 @@ def _rope(x, positions, theta: float, layout: str = "bthd", dims: int = 0):
     return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
-class ScoreLanes(NamedTuple):
-    """Attention sites traced so far in this process, by the width of a
-    head's query and key: what the attention call was given (``called``)
-    and what the model states (``used``), each summed over the sites.
-    They differ where a call pads: a latent attention's 192-wide scores
-    run through kernels that take one width of whole lane tiles for q, k
-    and v. Counted when a program is traced, as the kernels' tallies
-    (``ops/flash_attention.py``)."""
-
-    called: int = 0
-    used: int = 0
-
-    def __sub__(self, other):
-        return ScoreLanes(*(a - b for a, b in zip(self, other)))
-
-
-_score_lanes = ScoreLanes()
-
-
-def score_lanes_tally() -> ScoreLanes:
-    return _score_lanes
-
-
-def _tally_score_lanes(called: int, used: int):
-    global _score_lanes
-    _score_lanes = ScoreLanes(
-        _score_lanes.called + called, _score_lanes.used + used
-    )
+def _count_score_lanes(called: int, used: int):
+    """One attention site by the width of a head's query and key, into
+    ``common/trace_counts``: what the attention call is given
+    (``attn_score_lanes``) and what the model states
+    (``attn_score_lanes_used``), each summed over the sites. They differ
+    where a call pads: a latent attention's 192-wide scores run through
+    kernels that take one width of whole lane tiles for q, k and v."""
+    trace_counts.count("attn_score_lanes", called)
+    trace_counts.count("attn_score_lanes_used", used)
 
 
 def _causal_attention(q, k, v, mesh=None, layout: str = "bthd",
@@ -505,7 +487,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
         return _latent_attention(x, layer, cfg, mesh, positions, norm)
     h = _norm(x, layer[norm], cfg)
     sp = mesh is not None and mesh.shape.get("sp", 1) > 1
-    _tally_score_lanes(cfg.head_dim, cfg.head_dim)
+    _count_score_lanes(cfg.head_dim, cfg.head_dim)
     # single-shard path: kernel-native [B,H,T,D] straight from the
     # projection einsums — no relayout transposes around the attention
     # kernel. SP schemes shard/permute the seq dim and keep [B,T,H,D].
@@ -559,11 +541,11 @@ def _attention_of_two_widths(q, k, v, mesh):
     than its values have (q, k 192 and v 128 wide, say), scaled by the
     stated score width. The attention kernels take ONE width of whole
     lane tiles for q, k and v, so the call pads: zeros on q and k leave
-    every score as it is, v is padded and the output sliced. ``ScoreLanes``
-    counts the width called beside the width stated."""
+    every score as it is, v is padded and the output sliced; the width
+    called is counted beside the width stated."""
     qk, vd = q.shape[-1], v.shape[-1]
     width = -(-max(qk, vd) // _LANES) * _LANES
-    _tally_score_lanes(width, qk)
+    _count_score_lanes(width, qk)
 
     def pad(t):
         return jnp.pad(t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
@@ -782,8 +764,8 @@ def token_nll(
     Written as ``logsumexp(logits) - logits[target]`` (identical math
     and gradient — softmax minus one-hot) instead of gathering from
     ``log_softmax``: the log_softmax form materializes a second
-    [B, T, vocab] fp32 tensor for the backward, measured +5.6 ms/step
-    on the 124M bench (3.3 GB of avoidable HBM traffic at bs32)."""
+    [B, T, vocab] fp32 tensor for the backward (3.3 GB of avoidable HBM
+    traffic a step of the 124M model at batch 32)."""
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     nll = lse - tgt
@@ -845,9 +827,9 @@ def forward(
             if cfg.remat:
                 # a wrapper a layer: ``jax.checkpoint`` keeps the trace of
                 # a function it has seen at these shapes, and a layer that
-                # came out of that cache is not traced, so the tallies a
-                # trace keeps (``gdn_tally``, ``ScoreLanes``) would count
-                # one layer of each kind
+                # came out of that cache is not traced, so what a trace
+                # counts (``common/trace_counts``) would be of one layer
+                # of each kind
                 one_layer = jax.checkpoint(one_layer)
             x, aux = one_layer(x, layer)
             if aux is not None:

@@ -17,8 +17,8 @@ Three layers, one spine (docs/observability.md):
   goodput rollup;
 - ``obs.goodput`` — the accounting layer: a ``GoodputLedger`` that
   attributes every second of trainer wall time to a closed taxonomy
-  derived from the span stream, with a closure invariant gated by
-  ``bench.py --smoke``;
+  derived from the span stream, with a closure invariant
+  (``tests/test_goodput.py``; a running trainer's: ``tests/test_obs.py``);
 - ``obs.flight_recorder`` — the forensics layer: an always-on black
   box that dumps a self-contained bundle (trace, metrics, stacks,
   events, manifest) on crash/hang/degraded-entry or master request,

@@ -100,7 +100,7 @@ class FlightRecorder:
 
         # "" = resolve flight_dir() per dump, so redirecting the env
         # var works even after the process-default recorder exists
-        # (bench legs and tests point it at a scratch dir)
+        # (tests point it at a scratch dir)
         self._base_dir = base_dir
         self._tracer = tracer if tracer is not None else get_tracer()
         self._registry = (

@@ -1,12 +1,12 @@
 """Goodput ledger: attribute EVERY second of job wall time to one bucket.
 
-Before this module "goodput" existed only as ad-hoc arithmetic inside
-bench legs (``goodput_pct_preempt_flashckpt_gpt2`` and friends) — a
-number you could quote but not decompose, and nothing continuous a
-resource optimizer could plan against. The ledger turns the PR-4 span
+Before this module "goodput" existed only as ad-hoc arithmetic over
+whole runs — a number you could quote but not decompose, and nothing
+continuous a resource optimizer could plan against. The ledger turns the
+PR-4 span
 stream into a closed accounting: wall time since the ledger started is
 partitioned into the taxonomy below, the categories sum back to wall
-time (the **closure invariant**, gated at ±1% by ``bench.py --smoke``),
+time (the **closure invariant**, held to ±1% in ``tests/test_obs.py``),
 and the resulting goodput fraction is exported as ``dlrover_goodput_*``
 Prometheus gauges, aggregated per-worker/fleet by the master's
 ``TelemetryAggregator``, and ingested by the Brain as the
@@ -96,7 +96,7 @@ SPAN_CATEGORY = {
 }
 
 # the closure gate: |sum(categories) - wall| / wall must stay under
-# this (bench --smoke exits nonzero past it)
+# this (tests/test_obs.py holds a running trainer's ledger to it)
 CLOSURE_GATE_PCT = 1.0
 
 METRIC_PREFIX = "dlrover_goodput_"
@@ -143,9 +143,10 @@ def _total_s(ivs: List[Tuple[int, int]]) -> float:
 
 
 def compute_goodput_pct(productive_s: float, wall_s: float) -> float:
-    """The one shared goodput formula (bench legs that measure across
-    processes — where no single tracer sees the whole window — still
-    divide through here, so the definition cannot drift)."""
+    """The one shared goodput formula (the worker's ledger, the master's
+    fleet view and whoever measures across processes, where no single
+    tracer sees the whole window, divide through here, so the definition
+    cannot drift)."""
     if wall_s <= 0:
         return 0.0
     return 100.0 * max(0.0, productive_s) / wall_s
@@ -286,7 +287,7 @@ class GoodputLedger:
                 self._serving_since = None
 
     def mark_interval(self, category: str, start_ns: int, end_ns: int):
-        """Attribute an explicit monotonic-ns interval (bench probes
+        """Attribute an explicit monotonic-ns interval (probes
         that measure a restore with ``time.perf_counter`` bracket it
         here instead of re-inventing the categories; a serving plane
         running in another process reports its busy windows the same

@@ -1,7 +1,7 @@
 """Metrics registry: counters / gauges / histograms with one export path.
 
 Before this module every fast-path subsystem invented its own counter
-surface (``PipelineStats`` fields, bench result keys, ad-hoc scalars in
+surface (``PipelineStats`` fields, result keys of a harness, ad-hoc scalars in
 the runtime-metrics file). The registry gives them one home with two
 read sides:
 

@@ -5,9 +5,7 @@ A process-wide, thread-safe tracer built for the train loop's cadence:
 - **low overhead** — an enabled span costs two ``time.monotonic_ns``
   calls, one small object and one GIL-atomic deque append (no lock on
   the hot path); a disabled tracer hands back a shared no-op context
-  manager. The bench gates the measured overhead (``bench.py --smoke``,
-  docs/observability.md) at ≤ ``TRACER_OVERHEAD_GATE_PCT`` of step
-  time.
+  manager (what the spans cost a step on the chip: PERF.md §3).
 - **bounded memory** — spans land in a ring buffer (``capacity``
   events, oldest dropped); a multi-day job can leave tracing on.
 - **hang attribution** — every thread's currently-open span stack is
@@ -138,7 +136,7 @@ class SpanTracer:
         # thread preempted between next(seq) and append would let a
         # HIGHER seq land first, and a drain cursor advancing past it
         # would silently drop the straggler record forever (~100ns
-        # acquire vs the ~µs span cost the bench gate bounds)
+        # acquire beside a ~µs span)
         self._seq = itertools.count()
         self._end_lock = threading.Lock()
         # tid -> stack of live _OpenSpan (each thread mutates only its

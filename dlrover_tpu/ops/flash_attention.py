@@ -43,7 +43,8 @@ Design:
   square's unrolled per-head [T,T] f32 temporaries must stay within
   scoped VMEM (``_head_chunk``); the walk loops over its heads and is
   sized by its pipeline's blocks (``_walk_head_chunk``).
-  ``fused_tally()`` counts the call sites lowered by body.
+  ``common/trace_counts`` holds the call sites lowered, by body
+  (``attn_tri_sites`` ...).
 - **The streaming kernels' triangle path** (longer T, or GQA): a call
   that is causal alone with static equal offsets, square blocks and one
   sequence length knows its visible blocks when it is traced, so its
@@ -55,8 +56,8 @@ Design:
   and ds once; a head's float32 dq resident in VMEM) while that fits,
   split into the dq and the dk / dv kernel beyond. A caller that states
   no block gets 1024 x 1024 there (measured), 512 on the rectangular
-  grid, which every other call keeps as it was. ``stream_tally()``
-  counts the kernels lowered by grid.
+  grid, which every other call keeps as it was. ``common/trace_counts``
+  holds the kernels lowered, by grid (``attn_stream_tri_sites`` ...).
 - ``layout="bhtd"`` lets callers hand over kernel-native [B, H, T, D]
   tensors (the model emits them straight from its QKV einsums), skipping
   the 25 MB-per-tensor relayout transposes on every call.
@@ -69,7 +70,7 @@ elementwise bool mask, e.g. ``lambda q, k: q >= k`` for causal.
 from __future__ import annotations
 
 import functools
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +78,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from dlrover_tpu.common import trace_counts
 
 MaskFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
@@ -226,7 +229,7 @@ def _fwd_pallas(
         Tq, Tk, block_q, block_k,
         causal=causal, mask_fn=mask_fn, diagonal=diagonal,
     )
-    _tally_stream_site(n_tri)
+    _count_site(_STREAM, n_tri or 0)
     if n_tri:
         return in_layout(*_tri_fwd_call(
             qt, kt, vt, sm_scale=sm_scale, block=block_q,
@@ -347,44 +350,31 @@ def _for_each_head(n_heads: int, head):
     lax.fori_loop(0, n_heads, body, 0)
 
 
-def _tally_minus(self, other):
-    return type(self)(*(a - b for a, b in zip(self, other)))
+_FUSED = (
+    "attn_tri_sites", "attn_square_sites",
+    "attn_tiles_walked", "attn_tiles_square",
+)
+_STREAM = (
+    "attn_stream_tri_sites", "attn_stream_rect_sites",
+    "attn_stream_blocks_walked", "attn_stream_blocks_rect",
+)
 
 
-def _site_counts(n: int):
-    """What one site of ``n`` tiles a side adds to a tally: a triangle
-    site and the tiles it walks of its whole, or with ``n`` 0 a site
-    that takes the whole and walks no triangle."""
-    return (n > 0, n == 0, n * (n + 1) // 2, n * n)
-
-
-class FusedTally(NamedTuple):
-    """Fused call sites lowered so far in this process, forward and
-    backward each counted, by the body they took, and the ``row_tile``
-    square score tiles the triangle sites walk against the tiles of
-    their whole squares. Counted when a program is traced, so it costs
-    a step nothing; a program that came out of a cache of executables
-    was not traced and adds nothing."""
-
-    tri_sites: int = 0
-    square_sites: int = 0
-    tiles_walked: int = 0
-    tiles_square: int = 0
-
-    __sub__ = _tally_minus
-
-
-_tally = FusedTally()
-
-
-def fused_tally() -> FusedTally:
-    return _tally
-
-
-def _tally_site(T: int, row_tile: Optional[int]):
-    global _tally
-    n = T // row_tile if row_tile else 0  # a square site walks no tiles
-    _tally = FusedTally(*(a + b for a, b in zip(_tally, _site_counts(n))))
+def _count_site(names, n: int, kernels: int = 1):
+    """``kernels`` kernels of one call site into ``common/trace_counts``
+    under ``names`` (the fused family's or the streaming kernels'): a
+    triangle site of ``n`` tiles a side and the tiles it walks of its
+    whole, or with ``n`` 0 a site that takes the whole and walks no
+    triangle. The fused family counts call sites, forward and backward
+    each, by the body they took, in ``row_tile`` square score tiles; the
+    streaming family counts kernels (a forward is one, a backward one in
+    one pass and two split) by the grid they took, in the blocks a head
+    walks. Counted when a program is traced, so it costs a step nothing; a
+    program that came out of a cache of executables was not traced and
+    adds nothing."""
+    added = (n > 0, n == 0, n * (n + 1) // 2, n * n)
+    for name, k in zip(names, added):
+        trace_counts.count(name, kernels * k)
 
 
 def _fused_fwd_kernel(
@@ -677,7 +667,7 @@ def _fused_fwd_call(qt, kt, vt, offsets, *, causal, mask_fn, sm_scale,
         T, causal=causal, mask_fn=mask_fn, diagonal=diagonal,
         row_tile=row_tile,
     )
-    _tally_site(T, row_tile)
+    _count_site(_FUSED, T // row_tile if row_tile else 0)
     if row_tile:  # q, k, v, o and lse
         Hc = _walk_head_chunk(H, T, D, qt.dtype.itemsize, wide=4, narrow=1)
     else:
@@ -718,7 +708,7 @@ def _fused_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
         T, causal=causal, mask_fn=mask_fn, diagonal=diagonal,
         row_tile=row_tile,
     )
-    _tally_site(T, row_tile)
+    _count_site(_FUSED, T // row_tile if row_tile else 0)
     if row_tile:  # q, k, v, do, dq, dk, dv and lse, delta
         Hc = _walk_head_chunk(H, T, D, qt.dtype.itemsize, wide=7, narrow=2)
     else:
@@ -945,37 +935,6 @@ def _bwd_dkv_kernel(
 # would crowd SMEM, T = 131072 in blocks of 1024: the rectangular grid
 # takes over
 _TRI_MAX_BLOCKS = 128
-
-
-class StreamTally(NamedTuple):
-    """Streaming kernels lowered so far in this process (a forward is
-    one, a backward one in one pass and two split), by the grid they
-    took, and the blocks a head of the triangle kernels walks against
-    the blocks of its whole rectangle. Counted when a program is
-    traced, as ``FusedTally``."""
-
-    tri_sites: int = 0
-    rect_sites: int = 0
-    blocks_walked: int = 0
-    blocks_rect: int = 0
-
-    __sub__ = _tally_minus
-
-
-_stream_tally = StreamTally()
-
-
-def stream_tally() -> StreamTally:
-    return _stream_tally
-
-
-def _tally_stream_site(n: Optional[int], kernels: int = 1):
-    """``kernels`` kernels of ``n`` blocks a side on the triangle path,
-    or of the rectangular grid (``n`` None), which walks no triangle."""
-    global _stream_tally
-    _stream_tally = StreamTally(*(a + kernels * b for a, b in zip(
-        _stream_tally, _site_counts(n or 0)
-    )))
 
 
 def _stream_plan(Tq, Tk, block_q, block_k, *, causal, mask_fn, diagonal):
@@ -1274,7 +1233,7 @@ def _tri_bwd_call(qt, kt, vt, dot, lse4, delta4, *, sm_scale, block,
         _tri_bwd_kernel, sm_scale=sm_scale, n_blocks=n
     )
     if _one_pass_fits(T, D, qt.dtype.itemsize):
-        _tally_stream_site(n)
+        _count_site(_STREAM, n)
         whole_head = pl.BlockSpec(
             (1, 1, T, D), lambda b, h, s, qi, kj: (b, h, 0, 0)
         )
@@ -1285,7 +1244,7 @@ def _tri_bwd_call(qt, kt, vt, dot, lse4, delta4, *, sm_scale, block,
             [pltpu.VMEM((T, D), jnp.float32), acc, acc],
             interpret=interpret, vmem_limit=_FUSED_VMEM_LIMIT,
         )
-    _tally_stream_site(n, kernels=2)
+    _count_site(_STREAM, n, kernels=2)
     dqt = _tri_call(
         functools.partial(_tri_bwd_dq_kernel, sm_scale=sm_scale),
         "flash_attn_bwd_dq", _triangle_steps(n, by_key=False), ins,
@@ -1399,7 +1358,7 @@ def _rect_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
     Tk = kt.shape[2]
     group = H // kt.shape[1]
     nq, nk = Tq // block_q, Tk // block_k
-    _tally_stream_site(None, kernels=2)
+    _count_site(_STREAM, 0, kernels=2)
     common = dict(
         causal=causal,
         mask_fn=mask_fn,

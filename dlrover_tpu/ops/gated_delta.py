@@ -39,7 +39,10 @@ backward pass the cotangents of ``W``, ``K`` and the decays) is batched
 over all chunks outside it. It is differentiated by hand for that reason
 (``jax.grad`` of the forward loop carries all of a step's matmuls through
 the reversed loop and keeps the float32 state of every chunk).
-``gdn_tally()`` counts the passes traced and their steps.
+``common/trace_counts`` holds the passes traced (``gdn_sites``, one a
+mixer of a program), their sequential steps, a backward pass counted with
+its forward (``gdn_chunk_steps``), and the sites whose chunk-local work
+went into the kernels (``gdn_kernel_sites``).
 
 ``cfg.gdn_decay`` "channel" is Kimi Delta Attention's rule (arXiv:
 2510.26692): the decay is a vector over the key's channels, the transition
@@ -55,7 +58,7 @@ step's log-decay is bounded below (``cfg.gdn_decay_bound`` >= -5), so that
 quotient stays under ``exp(75)`` in float32. The pass carries ``a`` as a
 row over the key's channels and takes the keys already decayed to the
 chunk's end; everything else (the pass and its hand-written reversal, the
-triangle's inverse's cotangent, the tally) is the one code for both kinds.
+triangle's inverse's cotangent, the counts) is the one code for both kinds.
 Where the shapes allow (``gated_delta_kernels.fits``, the one rule for both
 kinds) the kind's chunk-local work runs in kernels of its own, the
 ``gdn_channel_*`` beside the scalar kind's ``gdn_chunk_*``; ``_wy_channel``
@@ -70,12 +73,11 @@ The spans of a layer: ``scope/layer/gdn/{in_proj,conv,scan,gate,out_proj}``.
 
 from __future__ import annotations
 
-from typing import NamedTuple
-
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.mamba2 import conv_silu, gated_group_rmsnorm
 
@@ -145,39 +147,6 @@ def gated_delta_logical_axes(cfg):
         del axes["w_ba"]
         axes.update(w_b=("embed", None), w_f=("embed", None))
     return axes
-
-
-class GdnTally(NamedTuple):
-    """Chunk-state passes traced so far in this process (one a Gated
-    DeltaNet mixer of a program), the sequential steps they run, a
-    backward pass counted with its forward, and the sites whose
-    chunk-local work went into the kernels
-    (``gated_delta_kernels.fits``). Counted when a program is traced, as
-    the attention tallies (``ops/flash_attention.py``)."""
-
-    sites: int = 0
-    chunk_steps: int = 0
-    kernel_sites: int = 0
-
-    def __sub__(self, other):
-        return GdnTally(*(a - b for a, b in zip(self, other)))
-
-
-_tally = GdnTally()
-
-
-def gdn_tally() -> GdnTally:
-    return _tally
-
-
-def _tally_pass(sites: int, steps: int, kernel_sites: int = 0):
-    global _tally
-    _tally = GdnTally(*(
-        a + b for a, b in zip(_tally, (sites, steps, kernel_sites))
-    ))
-
-
-kernels.forward_traced = lambda: _tally_pass(0, 0, kernel_sites=1)
 
 
 def l2norm(x):
@@ -299,7 +268,8 @@ def _decay_rows(delta, V):
 
 def _pass_forward(U, W, K, delta, a):
     f32, act = jnp.float32, W.dtype
-    _tally_pass(1, U.shape[0])
+    trace_counts.count("gdn_sites")
+    trace_counts.count("gdn_chunk_steps", U.shape[0])
 
     def step(S, x):
         U, W, K, delta, a = x
@@ -349,7 +319,7 @@ def _chunk_state_pass_bwd(res, cts):
     W, K, delta, a, Vn, S_in = res
     dVn, dS_in = cts
     f32, act = jnp.float32, W.dtype
-    _tally_pass(0, W.shape[0])
+    trace_counts.count("gdn_chunk_steps", W.shape[0])
 
     def step(dS, x):  # dS: of the state that LEFT this chunk
         W, K, delta, a, dVn, dS_in = x
@@ -626,7 +596,7 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
 
     beta, g = per_head(beta), per_head(g)
     if in_kernels:
-        _tally_pass(0, 0, kernel_sites=1)
+        trace_counts.count("gdn_kernel_sites")
         q, k = q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk)
         rows = (nc, B, Hk, 1, r * chunk)
         g = g.reshape(rows)

@@ -47,6 +47,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.common import trace_counts
+
 # ``dlrover_tpu.ops.flash_attention`` the attribute is the function
 _flash = importlib.import_module("dlrover_tpu.ops.flash_attention")
 
@@ -926,16 +928,13 @@ def _channel_wy_specs(sh: _Shape):
     )
 
 
-def forward_traced():
-    """Called once a trace of ``wy_channel``'s forward, the primal and the
-    ``custom_vjp`` rule alike: where ``gated_delta._pass_forward`` counts a
-    site, so that the two counts are of the same traces (a layer under
-    ``jax.checkpoint`` is traced as the primal once and through the rule
-    once more). ``ops/gated_delta.py`` puts its tally here."""
-
-
 def _channel_wy_call(k, v, beta, g, H, C):
-    forward_traced()
+    # once a trace of ``wy_channel``'s forward, the primal and the
+    # ``custom_vjp`` rule alike: where ``gated_delta._pass_forward`` counts
+    # a site, so that the two counts are of the same traces (a layer under
+    # ``jax.checkpoint`` is traced as the primal once and through the rule
+    # once more)
+    trace_counts.count("gdn_kernel_sites")
     sh = _channel_shape(k, H, C, v.shape[-1] // H)
     ins, outs = _channel_wy_specs(sh)
     lead = (sh.n, sh.B, H)

@@ -32,12 +32,12 @@ The spans of a layer: ``scope/layer/ssm/{in_proj,conv,scan,gate,out_proj}``.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.ops import conv_kernels
 
 
@@ -109,27 +109,6 @@ def causal_conv1d(x, w, b=None):
     return y
 
 
-class ConvTally(NamedTuple):
-    """Convolution stretches traced so far in this process (one a Mamba-2
-    or Gated DeltaNet mixer of a program) and those among them that went
-    into the kernels (``conv_kernels.fits``). Counted when a program is
-    traced, both in ``conv_silu``, so a layer traced twice under
-    ``jax.checkpoint`` counts twice in both."""
-
-    sites: int = 0
-    kernel_sites: int = 0
-
-    def __sub__(self, other):
-        return ConvTally(*(a - b for a, b in zip(self, other)))
-
-
-_conv_tally = ConvTally()
-
-
-def conv_tally() -> ConvTally:
-    return _conv_tally
-
-
 def conv_silu(x, w, b=None, mesh=None):
     """``silu(causal_conv1d(x, w, b))`` rounded once to ``x``'s dtype, the
     stretch before a mixer's scan. Where ``conv_kernels.fits`` takes the
@@ -138,12 +117,12 @@ def conv_silu(x, w, b=None, mesh=None):
     pass: what either keeps is its inputs in the activation dtype, and not
     a float32 copy of every channel of every token. ``mesh``: the mesh the
     step is sharded over, or None inside a region that names its own
-    axes."""
-    global _conv_tally
+    axes. A call is a site of ``common/trace_counts`` (``conv_sites``, and
+    ``conv_kernel_sites`` where the kernels take it), both counted here, so
+    a layer traced twice under ``jax.checkpoint`` counts twice in both."""
     in_kernels = conv_kernels.fits(x, w, mesh)
-    _conv_tally = ConvTally(
-        _conv_tally.sites + 1, _conv_tally.kernel_sites + in_kernels
-    )
+    trace_counts.count("conv_sites")
+    trace_counts.count("conv_kernel_sites", in_kernels)
     if in_kernels:
         return conv_kernels.conv_silu(x, w, b)
     return jax.checkpoint(

@@ -32,8 +32,8 @@ flattened leaf in every layout below). What runs today:
   tree kernel, one ``pallas_call`` a leaf over ``BLOCKS`` rows (g, codes,
   scales in; codes', scales', delta out), between the same two relayouts.
 - ``adamw_8bit_flat``: big leaves packed into a few flat buffers, one
-  aliased Pallas pass a group with dense ("wide") scales; the path
-  ``bench.py`` runs. ``bits=4``: jnp only, over ``BLOCKS`` rows.
+  aliased Pallas pass a group with dense ("wide") scales (no benchmark
+  configuration names it). ``bits=4``: jnp only, over ``BLOCKS`` rows.
 
 Block size 128 = one lane row, so a block's maximum is a reduction along
 lanes; whether that is cheap depends on the layout above, not on the
@@ -874,8 +874,7 @@ def adamw_8bit_flat(
     size``) keep fp32 moments, packed into one flat f32 vector pair —
     one fused elementwise update instead of ~100 tiny kernels.
 
-    Intended for replicated / single-device training states (the 1.5B
-    single-chip bench). Sharded states keep the tree form: a flat
+    Intended for replicated / single-device training states. Sharded states keep the tree form: a flat
     buffer would force cross-shard concats of every leaf.
 
     ``eps``/``eps_root`` follow ``adamw_8bit``: classic outside-sqrt
@@ -939,7 +938,7 @@ def adamw_8bit_flat(
 
         mq_groups, vq_groups = [], []
         for gi, group in enumerate(layout.groups):
-            # grads stay in their own dtype (bf16 on the big bench) —
+            # grads stay in their own dtype (bf16, say) —
             # the kernel upcasts per block in VMEM; a f32 flat buffer
             # would double the transient HBM
             gflat = _pack_group(leaves, group, leaves[group.idx[0]].dtype)

@@ -25,8 +25,8 @@ module replaces it with an explicit, schedulable sync layer:
   (``TrainState.grad_residual``) added back before the next step's
   quantization — the 1-bit-Adam/FlexLink error-feedback construction
   under which compression noise cancels across steps instead of
-  biasing the trajectory. Convergence parity is gated in tests and
-  ``bench.py --smoke``.
+  biasing the trajectory. Convergence parity is held in
+  ``tests/test_grad_sync.py``.
 
 - **Two-level sync for multi-slice meshes** (``BucketPlan.slices >
   1``): when the dp axis spans DCN-connected slices (``MeshConfig
@@ -136,7 +136,7 @@ import numpy as np
 # assumed fraction of sync wire time hidden behind backward compute
 # once the sync is bucketed (used by the dry-runner's comm-cost term
 # and reported as the analytic ``comm_overlap_pct`` on backends where
-# real overlap cannot be measured, e.g. the CPU smoke bench). 0.7 is
+# real overlap cannot be measured, the CPU among them). 0.7 is
 # the TorchTitan-reported neighborhood for bucketed DP overlap; the
 # timed finalists settle real rankings.
 OVERLAP_HIDDEN_FRACTION = 0.7
@@ -1155,7 +1155,7 @@ class PPSyncPlan:
     both compose with the flat and two-level schedules
     (``BucketPlan.slices``).
 
-    Quacks like a ``BucketPlan`` for the trainer/bench surfaces
+    Quacks like a ``BucketPlan`` for the trainer's surfaces
     (``raw_bytes``/``wire_bytes``/``describe``/``compress``); the
     in-step walk runs inside the pipeline step's manual region via
     ``sync_local_tree`` (parallel/pipeline.py wires it)."""
@@ -1290,7 +1290,7 @@ class EPSyncPlan:
     ``expert_leaf_dims`` mark which flatten-order param leaves are
     expert-sharded (and on which dim) so the step builder can build
     the region's in/out specs. Quacks like a BucketPlan for the
-    trainer/bench surfaces."""
+    trainer's surfaces."""
 
     expert_plan: BucketPlan
     dense_plan: BucketPlan
@@ -2263,8 +2263,7 @@ def comm_time_legs_s(
 
 def estimate_overlap_pct(strategy) -> Optional[float]:
     """Analytic hidden-fraction of sync wire time (documented model
-    constant — ``measured_overlap_pct`` is the A/B-measured twin; the
-    CPU smoke bench emits both, labeled)."""
+    constant — ``measured_overlap_pct`` is the A/B-measured twin)."""
     if not strategy.resolved_comm_overlap():
         return None
     return round(100.0 * OVERLAP_HIDDEN_FRACTION, 2)

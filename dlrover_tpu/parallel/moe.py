@@ -716,8 +716,8 @@ class CapacityRebalancer:
     (``TransformerConfig.capacity_splits``) the gating enforces via
     its per-expert cutoffs. The bucket dim becomes ``max(caps)`` —
     cold experts ship padding in the all-to-all — so wire/compute cost
-    rises by at most ``boost``x while overflow drops fall (the bench's
-    ``mesh_matrix_ep_drop_*`` gate).
+    rises by at most ``boost``x while overflow drops fall
+    (``tests/test_mesh_matrix.py::TestCapacityRebalance``).
 
     Host-side and deliberately tiny: observe() is fed from the train
     metrics (``moe_expert_load``), splits() is consulted at a

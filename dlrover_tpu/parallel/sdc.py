@@ -108,8 +108,8 @@ class InjectionPlan:
 def injection_plan(n_lanes: int) -> Optional[InjectionPlan]:
     """Resolve the armed ``device.sdc`` scale spec into a concrete
     plan, or None. Fully derived from the spec fields (no RNG stream),
-    so the step builder, the audit probe and the bench all replay the
-    SAME corruption: ``seed % n_lanes`` is the lane, ``@N`` is the
+    so the step builder, the audit probe and the chaos harness all
+    replay the SAME corruption: ``seed % n_lanes`` is the lane, ``@N`` is the
     onset step (default 1 = corrupt from the first step)."""
     from dlrover_tpu.common import faults
 
@@ -191,8 +191,8 @@ def _robust_scale(
 class SdcDetector:
     """The tier-1 fence: feed it one (loss, per-lane local grad norm)
     observation per step; it answers with a verdict. Host-side Python
-    on a handful of floats — the steady-state cost is microseconds (the
-    bench gates it under the tracer-overhead budget)."""
+    on a handful of floats (not measured on the chip: no benchmark cell
+    turns it on)."""
 
     def __init__(self, n_lanes: int, cfg: Optional[SdcConfig] = None):
         self.cfg = cfg or SdcConfig()

@@ -4,9 +4,9 @@ Parity: the reference hardcodes its scheme per model config
 (atorch distributed_transformer/distributed_attention.py — ring-style
 DistributedAttention); here the choice reads a MEASURED table.
 
-The table comes from ``bench.py run_sp_compare`` with the kernel
-strategy held constant per row (fused 1024x1024 tiles + online merges
-vs block-tiled streaming, both schemes, both strategies timed — r4's
+The table was measured once on a v5e, with the kernel strategy held
+constant per row (fused 1024x1024 tiles + online merges vs block-tiled
+streaming, both schemes, both strategies timed — r4's
 2x "ring wins" verdict turned out to be a kernel-strategy artifact,
 not a scheme property). v5e, sp=4, H=16, D=128, bf16, best kernel per
 scheme, per-device attention ms:
@@ -14,7 +14,7 @@ scheme, per-device attention ms:
     seq 4096:  ring 3.83   ulysses 6.29
     seq 8192:  ring 6.91   ulysses 6.86   (a tie)
 
-(A second full-bench run measured ring 4.09 / ulysses 4.05 at 4096 —
+(A second run measured ring 4.09 / ulysses 4.05 at 4096 —
 run-to-run variance swamps sub-10% differences, which is what the tie
 margin below exists to absorb.)
 
@@ -30,7 +30,11 @@ from __future__ import annotations
 from typing import Dict, Tuple
 
 # (seq -> scheme -> per-device attention ms), measured as described
-# above; refresh by running bench.py on new hardware and updating here
+# above. A row is refreshed, or added, by timing both schemes' attention
+# on the chip at that sequence length (sp=4, so a four-chip call; the
+# kernels alone: tools/attn_kernel_bench.py) and writing the two numbers
+# here with their origin; no benchmark cell runs sp yet (ROADMAP Queue 2
+# B8)
 MEASURED_MS: Dict[int, Dict[str, float]] = {
     4096: {"ring": 3.83, "ulysses": 6.29},
     8192: {"ring": 6.91, "ulysses": 6.86},

@@ -26,8 +26,8 @@ those constants with ONE measured subsystem:
 Downstream consumers: ``accel/dry_runner._comm_estimate`` (est_step_s
 priced from the probed model whenever a cache exists),
 ``grad_sync`` per-link bucket sizing (``bucket_bytes_for``) and the
-two-level sync, the trainer's startup/resize probe, ``bench.py
-run_topology_bench``, and the heterogeneous per-slice throughput
+two-level sync, the trainer's startup/resize probe, and the
+heterogeneous per-slice throughput
 weighting (``slice_throughput_weights``) that feeds the elastic data
 layer's unequal shard sizing.
 """
@@ -50,7 +50,7 @@ from dlrover_tpu.common.log import default_logger as logger
 # (v5p-class ~90 GB/s effective per chip); DCN is the per-host
 # data-center NIC class (~100 Gbit/s => 12.5 GB/s); host is a PCIe-gen3
 # D2H staging link. The *ordering* (ici >= dcn >= host) is the invariant
-# the bench gates — a model violating it would invert every scheduling
+# ``tests/test_topology.py`` holds — a model violating it would invert every scheduling
 # decision built on top.
 FALLBACK_ICI_GBPS = 90.0
 FALLBACK_DCN_GBPS = 12.5
@@ -344,7 +344,7 @@ def save_rail_rates(
 
 def set_rail_rates(rates: Optional[ObservedRailRates]) -> None:
     """Install an observed-rates snapshot as the process-current one
-    (tests/bench; ``observe_rail_rate`` maintains it in production)."""
+    (tests; ``observe_rail_rate`` maintains it in production)."""
     global _OBSERVED
     _OBSERVED = rates
 
@@ -400,7 +400,7 @@ def observe_rail_rate(
     transfer of at least ``RAIL_RATE_MIN_BYTES``) into the per-rail
     EWMA, persist the snapshot, and export the gauge. ``rail`` is a
     direction key from ``_RAIL_RATE_FIELDS``; anything else (a custom
-    bench rail with no LinkModel leg) is ignored."""
+    rail with no LinkModel leg) is ignored."""
     global _OBSERVED
     if rail not in _RAIL_RATE_FIELDS or not gbps > 0.0:
         return _OBSERVED
@@ -652,7 +652,7 @@ def get_link_model(
     process most recently probed/installed (a subset probe from a
     resize beats stale disk files from other runs), else a persisted
     probe cache for the fingerprint, else the documented fallback
-    constants. NEVER probes — probing is an explicit startup/bench
+    constants. NEVER probes — probing is an explicit startup
     action (``probe_link_model``); estimation paths must stay cheap
     and deterministic.
 
@@ -680,7 +680,7 @@ def get_link_model(
 
 
 def set_link_model(model: LinkModel, devices=None) -> None:
-    """Install a model as the process-current one (tests/bench, and
+    """Install a model as the process-current one (tests, and
     any consumer asking without an exact fingerprint match)."""
     global _CURRENT
     fp = model.fingerprint or device_fingerprint(devices)
@@ -694,7 +694,7 @@ def reset_link_model() -> None:
     _CURRENT = None
     _FALLBACK_WARNED = False
     # observed rail rates overlay whatever get_link_model returns, so a
-    # full model reset (tests/bench teardown) must drop them too or the
+    # full model reset (a test's teardown) must drop them too or the
     # "pristine" fallback would come back pre-overlaid
     reset_rail_rates()
 

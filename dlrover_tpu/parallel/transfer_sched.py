@@ -164,7 +164,7 @@ class Rail:
     ``"d2h"`` / ``"h2d"`` / ``"peer"`` (the DCN path carries payloads
     of either direction). ``admit`` limits which priority classes may
     stripe onto it (None = all); ``gbps`` overrides the LinkModel
-    price (bench/emulation)."""
+    price (emulation)."""
 
     __slots__ = ("name", "direction", "gbps", "admit", "holder",
                  "grants", "bytes_total", "busy_s", "yields",
@@ -600,7 +600,7 @@ class TransferArbiter:
                 if (
                     r is None
                     # an explicit gbps override marks an emulated/
-                    # repriced rail (tests, bench) — its realized rate
+                    # repriced rail (tests) — its realized rate
                     # measures the emulation, not a physical link
                     or r.gbps is not None
                     or secs <= 0.0
@@ -1115,7 +1115,7 @@ def save_calibration(
 
 
 def set_calibration(cal: Optional[ArbiterCalibration]) -> None:
-    """Install a calibration as the process-current one (tests/bench;
+    """Install a calibration as the process-current one (tests;
     ``calibrate_hidden_fraction`` calls this with what it measured)."""
     global _cal_current
     _cal_current = cal

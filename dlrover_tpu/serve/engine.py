@@ -319,7 +319,7 @@ class ServingEngine:
         return rows
 
     def stats(self) -> Dict[str, float]:
-        """Engine-side counters for bench legs and tests."""
+        """Engine-side counters for tests and harnesses."""
         return {
             "weight_step": self.weight_step,
             "swaps": self.swaps,

@@ -33,7 +33,7 @@ import numpy as np
 from dlrover_tpu.accel.accelerate import AccelerateResult, auto_accelerate
 from dlrover_tpu.accel.strategy import Strategy
 from dlrover_tpu.agent.monitor import report_runtime_metrics
-from dlrover_tpu.common import faults
+from dlrover_tpu.common import faults, trace_counts
 from dlrover_tpu.ckpt.checkpointer import FlashCheckpointer, StorageType
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.config import TransformerConfig
@@ -227,12 +227,8 @@ class ElasticTrainer:
         self._builds = compile_meter()
         self._builds_first = self._builds_logged = len(self._builds.builds)
         self._built: set = set()
-        # ops.gated_delta's tally as a train step's build began,
-        # ops.mamba2's of the convolution stretches, and
-        # models.transformer's of the attention sites' score widths
-        self._gdn_before_step = None
-        self._conv_before_step = None
-        self._lanes_before_step = None
+        # common/trace_counts as a train step's build began
+        self._counts_before_step = None
         self.tcfg = trainer_cfg or TrainerConfig()
         self._metrics_hook = metrics_hook
         # kept for the resize path: a new mesh rebuilds the accel
@@ -369,7 +365,7 @@ class ElasticTrainer:
         self.eviction_drain_ms = 0.0
         # event-reporter seam (the PR-5 saver pattern): in the agent
         # architecture the monitor file carries the notice; in-process
-        # callers (bench, chaos harness, tests) wire this to
+        # callers (chaos harness, tests) wire this to
         # MasterClient.report_failure / report_eviction_notice directly
         self._event_reporter: Optional[Callable[[str, str], None]] = None
         if env_grace:
@@ -447,8 +443,7 @@ class ElasticTrainer:
             )
             logger.info(
                 f"programs built {when}: {describe_builds(rows)}{q8}"
-                f"{self._fold_attention_tally()}{self._fold_gdn_tally()}"
-                f"{self._fold_score_lanes()}{self._fold_conv_tally()}"
+                f"{self._fold_trace_counts()}"
             )
 
     def _fold_first_step(self):
@@ -471,102 +466,34 @@ class ElasticTrainer:
         )
         stats.startup_cache_misses = int(sum(b["cache_misses"] for b in upto))
 
-    def _fold_attention_tally(self) -> str:
-        """The attention kernels lowered so far, fused and streaming,
-        into the stats, and what was lowered since the last such line,
-        in words."""
-        from dlrover_tpu.ops.flash_attention import fused_tally, stream_tally
-
+    def _fold_trace_counts(self) -> str:
+        """What the modules counted while programs were traced
+        (``common/trace_counts``: a counter's name is the stats field it
+        lands in, its two scopes are stated there) into the stats, and
+        what this line adds, in words: ``+n`` a running total's growth
+        since the last such line, ``=n`` a count of the train step built
+        since. Of the latter nothing where no step was built since the
+        last line, or the step came whole out of a cache of executables
+        (not traced)."""
         stats = self.pipeline_stats
-
-        def fold(tally, prefix):
-            # PipelineStats.<prefix><field> is the tally as the last
-            # line left it
-            new = tally - [
-                getattr(stats, prefix + f) for f in tally._fields
-            ]
-            for f, n in zip(tally._fields, tally):
-                setattr(stats, prefix + f, n)
-            return new
-
-        fused = fold(fused_tally(), "attn_")
-        stream = fold(stream_tally(), "attn_stream_")
-        said = ""
-        if fused.tri_sites + fused.square_sites:
-            said += (
-                f"; fused attention: {fused.tri_sites} sites as triangle "
-                f"({fused.tiles_walked} of {fused.tiles_square} tiles), "
-                f"{fused.square_sites} as square"
-            )
-        if stream.tri_sites + stream.rect_sites:
-            said += (
-                f"; streaming attention: {stream.tri_sites} sites as "
-                f"triangle ({stream.blocks_walked} of "
-                f"{stream.blocks_rect} blocks), {stream.rect_sites} as "
-                "rectangle"
-            )
-        return said
-
-    def _fold_gdn_tally(self) -> str:
-        """The Gated DeltaNet passes traced since a train step's build
-        began (``_first_build``) into the stats, and in words; nothing
-        where no step was built since the last such line, or the step
-        came whole out of a cache of executables (not traced)."""
-        from dlrover_tpu.ops.gated_delta import gdn_tally
-
-        before, self._gdn_before_step = self._gdn_before_step, None
-        if before is None:
-            return ""
-        step = gdn_tally() - before
-        if not step.sites:
-            return ""
-        stats = self.pipeline_stats
-        stats.gdn_sites, stats.gdn_chunk_steps, stats.gdn_kernel_sites = step
-        return (
-            f"; gated delta rule: {step.sites} sites "
-            f"({step.kernel_sites} in the kernel), "
-            f"{step.chunk_steps} serial chunk steps a train step"
-        )
-
-    def _fold_score_lanes(self) -> str:
-        """The score widths of the attention sites traced since a train
-        step's build began into the stats, and in words where a call
-        was wider than the model states; by ``_fold_gdn_tally``'s rules."""
-        from dlrover_tpu.models.transformer import score_lanes_tally
-
-        before, self._lanes_before_step = self._lanes_before_step, None
-        if before is None:
-            return ""
-        step = score_lanes_tally() - before
-        if not step.called:
-            return ""
-        stats = self.pipeline_stats
-        stats.attn_score_lanes, stats.attn_score_lanes_used = step
-        if step.called == step.used:
-            return ""
-        return (
-            f"; attention scores: {step.used} of the {step.called} lanes "
-            "the kernels were called with"
-        )
-
-    def _fold_conv_tally(self) -> str:
-        """The convolution stretches traced since a train step's build
-        began into the stats, and in words; by ``_fold_gdn_tally``'s
-        rules."""
-        from dlrover_tpu.ops.mamba2 import conv_tally
-
-        before, self._conv_before_step = self._conv_before_step, None
-        if before is None:
-            return ""
-        step = conv_tally() - before
-        if not step.sites:
-            return ""
-        stats = self.pipeline_stats
-        stats.conv_sites, stats.conv_kernel_sites = step
-        return (
-            f"; convolution: {step.sites} sites "
-            f"({step.kernel_sites} in the kernel)"
-        )
+        now = trace_counts.snapshot()
+        said = []
+        for name in trace_counts.RUNNING_TOTALS:
+            # the field is the total as the last line left it
+            if now[name] != getattr(stats, name):
+                said.append(f"{name} +{now[name] - getattr(stats, name)}")
+                setattr(stats, name, now[name])
+        before, self._counts_before_step = self._counts_before_step, None
+        if before is not None:
+            step = {
+                name: n for name, n in trace_counts.since(before).items()
+                if name not in trace_counts.RUNNING_TOTALS
+            }
+            if any(step.values()):
+                for name, n in step.items():
+                    setattr(stats, name, n)
+                said += [f"{name} ={n}" for name, n in step.items() if n]
+        return "; traced: " + ", ".join(said) if said else ""
 
     def _first_build(self, what: str):
         """``build:<what>`` around the FIRST call of a jitted program
@@ -576,13 +503,7 @@ class ElasticTrainer:
             return _NO_BUILD
         self._built.add(what)
         if what.startswith("step_"):
-            from dlrover_tpu.models.transformer import score_lanes_tally
-            from dlrover_tpu.ops.gated_delta import gdn_tally
-            from dlrover_tpu.ops.mamba2 import conv_tally
-
-            self._gdn_before_step = gdn_tally()
-            self._conv_before_step = conv_tally()
-            self._lanes_before_step = score_lanes_tally()
+            self._counts_before_step = trace_counts.snapshot()
         return self._builds.build(what)
 
     # -- measured link-cost model (parallel/topology.py) ----------------
@@ -692,8 +613,8 @@ class ElasticTrainer:
         self._grad_sync_plan = plan
         stats = self.pipeline_stats
         # the chosen path is visible state, not an HLO-only fact: the
-        # bench and the metrics registry (grad_sync_explicit gauge via
-        # fold_pipeline_stats) can now see a mesh losing the fast path
+        # metrics registry (grad_sync_explicit gauge via
+        # fold_pipeline_stats) sees a mesh losing the fast path
         stats.grad_sync_path = "explicit" if plan is not None else "gspmd"
         # mode/density gauges cover the plan-None case too (mode 0 =
         # uncompressed GSPMD), so a downgrade is visible as a gauge
@@ -875,72 +796,6 @@ class ElasticTrainer:
         # the auditor's recorded spans are the pre-rollback incarnation's
         self._auditor.skip_to_now()
         return rolled_to
-
-    def measure_realized_overlap(self, iters: int = 3) -> Optional[float]:
-        """A/B-measure how much of the sync's wire time the scheduler
-        actually hides. The baseline twin uses GSPMD's monolithic
-        schedule, which serializes its sync after the last backward op
-        (the PR-3 premise this whole module exists to fix) — so the
-        *sync-free* step time is approximately ``baseline -
-        standalone_roofline``, and the explicit step's exposed sync is
-        what it runs above that. Writes ``PipelineStats.overlap_pct_
-        measured`` (the measured twin of the analytic
-        ``comm_overlap_pct``) and returns it. Opt-in — it costs one
-        extra step compile, so it is a diagnostic call / bench hook,
-        not startup work."""
-        import jax
-
-        from dlrover_tpu.models.train import build_train_step
-        from dlrover_tpu.parallel.grad_sync import (
-            measured_overlap_pct,
-            strip_residual,
-        )
-
-        plan = self._grad_sync_plan
-        stats = self.pipeline_stats
-        if plan is None or not stats.grad_sync_ms:
-            return None
-        s = self.accel.strategy
-        base_step = build_train_step(
-            self.cfg, self.mesh, self._tx, donate=False,
-            grad_accum=s.grad_accum, batch_pad=s.batch_pad,
-        )
-        rng = np.random.default_rng(0)
-        x = rng.integers(
-            0, self.cfg.vocab_size,
-            (self.tcfg.batch_size + s.batch_pad, self.tcfg.seq_len),
-        ).astype(np.int32)
-        b = shard_batch({"x": x, "y": x}, self.mesh)
-
-        def _time(fn, state):
-            st, _ = fn(state, b["x"], b["y"])  # compile + warmup
-            jax.block_until_ready(st.params)
-            times = []
-            for _ in range(iters):
-                t0 = time.perf_counter()
-                st, _ = fn(state, b["x"], b["y"])
-                jax.block_until_ready(st.params)
-                times.append(time.perf_counter() - t0)
-            return float(np.median(times) * 1e3)
-
-        with span("grad_sync_overlap_probe"):
-            with_ms = _time(self._programs.safe_step, self.state)
-            gspmd_ms = _time(
-                base_step, strip_residual(self.state)
-            )
-        # the GSPMD baseline carries its own monolithic sync fully
-        # serialized; subtracting the standalone roofline approximates
-        # the sync-free step the pure function normalizes against
-        stats.overlap_pct_measured = measured_overlap_pct(
-            stats.grad_sync_ms, with_ms,
-            gspmd_ms - stats.grad_sync_ms,
-        )
-        logger.info(
-            f"grad sync realized overlap: {stats.overlap_pct_measured}%"
-            f" (step {with_ms:.2f} ms explicit vs {gspmd_ms:.2f} ms "
-            f"gspmd, standalone {stats.grad_sync_ms:.2f} ms)"
-        )
-        return stats.overlap_pct_measured
 
     # -- checkpoint ----------------------------------------------------
     def _rewound_sampler_state(self, samp: Dict, buffered: int) -> Dict:
@@ -1662,8 +1517,8 @@ class ElasticTrainer:
         Single-process scope: the sampler's replica split is
         per-process and unchanged here; multi-process resizes
         re-rendezvous through the agent and land in ``__init__``'s
-        restore path instead. Returns a dict of timings/counters (the
-        bench's ``resize_downtime_*`` keys)."""
+        restore path instead. Returns a dict of timings/counters
+        (``downtime_ms``, ``compile_cache_hit``, ``reshard_bytes_*``)."""
         import jax
 
         t0 = time.perf_counter()

@@ -454,6 +454,37 @@ class TestExportAndIngestion:
         assert rows and rows[0].event == "straggler"
         assert "dcn_sync" in rows[0].detail
 
+    def test_an_alarm_leaves_a_bundle_that_names_the_component(
+        self, tmp_path
+    ):
+        """The trainer's side of an alarm (``_on_audit_alarm``, the
+        auditor's ``on_alarm``): the recorder's event log carries the
+        attribution and a bundle is captured at the moment it fires."""
+        import types
+
+        from dlrover_tpu.obs.flight_recorder import FlightRecorder
+        from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer
+
+        rec = FlightRecorder(
+            base_dir=str(tmp_path), tracer=SpanTracer(enabled=True),
+            registry=MetricsRegistry(),
+        )
+        ElasticTrainer._on_audit_alarm(
+            types.SimpleNamespace(_flight=rec), "data_wait", 3.14159,
+            "data_wait is 3.1x its budget",
+        )
+        assert [e["kind"] for e in rec.events()] == ["audit_regression"]
+        (bundle,) = [
+            d for d in os.listdir(tmp_path) if "audit_regression" in d
+        ]
+        with open(tmp_path / bundle / "manifest.json") as f:
+            manifest = json.load(f)
+        assert manifest["reason"] == "audit_regression"
+        assert manifest["extra"] == {
+            "component": "data_wait", "ratio": 3.142,
+            "detail": "data_wait is 3.1x its budget",
+        }
+
     def test_merge_timeline_names_alarm_component(self):
         import sys
 

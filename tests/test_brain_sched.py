@@ -767,20 +767,89 @@ class TestBrainCtl:
         assert main([str(tmp_path / "nope.db"), "jobs"]) == 1
 
 
-@pytest.mark.slow
-def test_brain_bench_leg_gates():
-    """The bench leg end to end: convergence beats the equal split,
-    latency reported, accounting closed."""
-    import bench
+def test_the_closed_loop_beats_the_equal_split_and_drops_no_plan():
+    """Three simulated jobs with unequal scaling curves (near-linear,
+    knee, flat) under one Brain with the ClusterScheduler over real gRPC,
+    each job's ``PlanExecutor`` driving a real ``JobAutoScaler``: the
+    loop's allocation of the same 12 chips runs faster in aggregate than
+    four each, the decision -> resized latency is recorded, and every
+    emitted plan slice ends acked or expired, never silently dropped (one
+    job goes dark for the first rounds, one dies before ever polling)."""
+    from dlrover_tpu.brain.plan_exec import PlanExecutor
+    from dlrover_tpu.brain.service import BrainClient, start_brain_service
+    from dlrover_tpu.master.job_auto_scaler import JobAutoScaler
+    from dlrover_tpu.master.job_manager import JobManager
+    from dlrover_tpu.master.scaler import CallbackScaler
 
-    results = {}
-    bench.run_brain_bench(None, results, smoke=True)
-    assert (
-        results["brain_agg_goodput_closed"]
-        > results["brain_agg_goodput_equal_split"]
+    total_chips, start_n = 12, 4
+    curves = {"sim-lin": 0.95, "sim-knee": 0.55, "sim-flat": 0.20}
+
+    def true_speed(job, n):
+        return 10.0 * max(0, n) ** curves[job]
+
+    server, servicer, addr = start_brain_service(
+        scheduler=True, total_chips=total_chips
     )
-    assert results["brain_decision_to_resized_ms"] is not None
-    assert results["brain_plans_unresolved"] == 0
-    assert results["brain_plans_acked"] > 0
-    assert results["brain_plans_expired"] > 0
-    assert results["brain_outcome_rows"] > 0
+    sched = servicer.scheduler
+    sched.stop()  # passes are driven by hand: deterministic rounds
+    sched.min_dwell_s = 0.0
+    sched.hysteresis_frac = 0.01
+    jobs = {}
+    try:
+        for job in curves:
+            jm = JobManager()
+            jm.create_initial_nodes(start_n)
+            auto = JobAutoScaler(
+                jm, scaler=CallbackScaler(lambda plan: None),
+                target_nodes=start_n,
+            )
+            cli = BrainClient(addr, job)
+            jobs[job] = (auto, cli, PlanExecutor(cli, auto))
+        for rnd in range(8):
+            for job, (auto, cli, _ex) in jobs.items():
+                cli.persist_metrics(comm.JobMetricsSample(
+                    timestamp=time.time(), alive_nodes=auto.target,
+                    steps_per_sec=true_speed(job, auto.target),
+                    goodput_pct=99.0,
+                ))
+            sched.run_pass()
+            for job, (_auto, _cli, ex) in jobs.items():
+                if job == "sim-flat" and rnd < 2:
+                    continue  # dark: its slices must expire, visibly
+                ex.poll_once()
+        servicer.record_cluster_plan(
+            servicer.next_plan_version(),
+            [{
+                "job": "sim-zombie", "worker_count": 2, "prev_count": 4,
+                "reason": "master died before ack",
+            }],
+            time.time(),
+        )
+        with servicer._lock:
+            servicer._conn.execute(
+                "UPDATE cluster_plans SET ts = ts - ? "
+                "WHERE status='pending'",
+                (sched.plan_ttl_s + 1,),
+            )
+            servicer._conn.commit()
+        servicer.expire_stale_plans(time.time() - sched.plan_ttl_s)
+
+        alloc = {job: auto.target for job, (auto, _c, _e) in jobs.items()}
+        assert sum(alloc.values()) <= total_chips
+        assert sum(true_speed(j, n) for j, n in alloc.items()) > sum(
+            true_speed(j, start_n) for j in curves
+        )
+        assert any(ex.executed for _a, _c, ex in jobs.values())
+        counts = servicer.plan_status_counts()
+        assert counts.get("pending", 0) == 0
+        assert counts.get("acked", 0) > 0
+        assert counts.get("expired", 0) > 0
+        assert any(
+            r["decision_to_resized_ms"] is not None
+            for r in servicer.plan_history()
+        )
+    finally:
+        for _auto, cli, _ex in jobs.values():
+            cli.close()
+        server.stop(grace=1)
+        servicer.close()

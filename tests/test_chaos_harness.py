@@ -4,8 +4,7 @@ evict / outage scenarios gated on the survival contract.
 Tier-1 runs the fast control-plane scenarios (master restart with a
 pending cluster-plan slice — the PR-9 robustness gap — and a Brain
 outage mid-plan) plus the CLI surface; the trainer-bearing scenarios
-(eviction drain, subprocess SIGKILL) are the bench --smoke gate and the
-``slow`` matrix here.
+(eviction drain, subprocess SIGKILL) are the ``slow`` matrix here.
 """
 
 import json
@@ -102,8 +101,7 @@ class TestCli:
 
 @pytest.mark.slow
 class TestTrainerScenarios:
-    """The full matrix (also gated every CI run by bench --smoke's
-    chaos leg — these are the replay-under-pytest form)."""
+    """The full matrix, replayed under pytest."""
 
     def test_eviction_during_save(self, tmp_path):
         res = chaos.run_scenario(

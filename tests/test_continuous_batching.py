@@ -32,7 +32,7 @@ def _prompt_queue(n, p_max, vocab, seed=0):
 
 
 class TestGreedyEquivalence:
-    @pytest.mark.slow  # ~9s; bench --smoke gates the same bitwise claim
+    @pytest.mark.slow  # ~9s
     def test_matches_single_prompt_generate(self, model):
         cfg, params = model
         N, P_max, new = 5, 10, 6

@@ -4,7 +4,6 @@ statement ``silu(causal_conv1d(x, w, b))`` and to ``jax.grad`` of it; the
 rule that chooses between them (``fits``), and the counters the mixers
 keep of which way each site went (``ops/mamba2.conv_silu``)."""
 
-import types
 from dataclasses import replace
 
 import jax
@@ -12,15 +11,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.accel.profiler import PipelineStats
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.models.config import TransformerConfig
 from dlrover_tpu.models.train import TrainState, build_train_step
 from dlrover_tpu.models.transformer import init_params, loss_fn
 from dlrover_tpu.ops import conv_kernels, mamba2
 from dlrover_tpu.ops.gated_delta import gated_delta_mixer
-from dlrover_tpu.ops.mamba2 import causal_conv1d, conv_silu, conv_tally
+from dlrover_tpu.ops.mamba2 import causal_conv1d, conv_silu
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
-from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
+from dlrover_tpu.trainer.elastic.trainer import build_optimizer
+from trace_counted import CONV, added
 
 F32 = jnp.float32
 # three time blocks of 512 (a halo crosses two boundaries) and one
@@ -191,10 +191,10 @@ def test_fits_refuses(case):
     shape, taps = REFUSED[case]
     x, w = jnp.zeros(shape, jnp.bfloat16), jnp.zeros((taps, shape[2]))
     assert not conv_kernels.fits(x, w)
-    before = conv_tally()
+    before = trace_counts.snapshot()
     text = str(jax.make_jaxpr(conv_silu)(x, w))
     assert "pallas_call" not in text
-    assert conv_tally() - before == (1, 0)
+    assert added(before, CONV) == (1, 0)
 
 
 @pytest.mark.parametrize("dtype", ["float16", "int8", "float64"])
@@ -272,9 +272,9 @@ def test_the_mixers_take_the_kernels_where_the_widths_allow():
     params = init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     x = rng.integers(0, 64, (2, 64)).astype(np.int32)
-    before = conv_tally()
+    before = trace_counts.snapshot()
     text = str(jax.make_jaxpr(lambda p: loss_fn(p, x, x, cfg, None))(params))
-    assert conv_tally() - before == (2, 2)
+    assert added(before, CONV) == (2, 2)
     assert "conv_silu_fwd" in text
     # against the same model with the rule switched off
     loss = jax.value_and_grad(lambda p: loss_fn(p, x, x, cfg, None))
@@ -299,66 +299,43 @@ def test_the_mixers_take_the_kernels_where_the_widths_allow():
 
 def test_the_mixers_lower_to_the_plain_statement_at_toy_widths():
     narrow = _hybrid(ssm_head_dim=8, gdn_key_dim=8)
-    before = conv_tally()
+    before = trace_counts.snapshot()
     text = _step(narrow, build_mesh(MeshConfig(), jax.devices()[:1])).as_text()
-    assert conv_tally() - before == (2, 0)
+    assert added(before, CONV) == (2, 0)
     assert "conv_silu" not in text
 
 
 def test_the_mixers_lower_to_the_plain_statement_on_a_mesh():
     cfg = _hybrid()
-    before = conv_tally()
+    before = trace_counts.snapshot()
     _step(cfg, build_mesh(MeshConfig(dp=2), jax.devices()[:2]))
-    step = conv_tally() - before
-    assert step.sites >= 2 and step.kernel_sites == 0
+    sites, in_kernels = added(before, CONV)
+    assert sites >= 2 and in_kernels == 0
 
 
 def test_under_checkpoint_both_counts_see_the_same_traces():
     """A layer under ``jax.checkpoint`` is traced once as the primal and
     once more for the backward pass: both counters are kept at the one
-    place that sees both, so the share reads N of N, and the trainer's
-    line and stats say so."""
+    place that sees both, so the share reads N of N (how the trainer
+    folds what a step's build traced: ``test_trace_counts.py``)."""
     cfg = _hybrid(remat=True)
     mesh = build_mesh(MeshConfig(), jax.devices()[:1])
-    stats = PipelineStats()
-    trainer = types.SimpleNamespace(
-        pipeline_stats=stats, _conv_before_step=None, _built=set(),
-        _builds=types.SimpleNamespace(build=lambda what: what),
-    )
-    assert ElasticTrainer._fold_conv_tally(trainer) == ""  # no step built
-    # the worker's reference check: a forward pass before any step
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    xs = jnp.zeros((2, 64), jnp.int32)
-    jax.jit(lambda p: loss_fn(p, xs, xs, cfg, None)).lower(params)
-    assert ElasticTrainer._first_build(trainer, "eval") == "eval"
-    assert trainer._conv_before_step is None
-    ElasticTrainer._first_build(trainer, "step_donating")
-    assert trainer._conv_before_step == conv_tally()
-    before = conv_tally()
+    before = trace_counts.snapshot()
     _step(cfg, mesh)
-    step = conv_tally() - before
-    assert step.sites == step.kernel_sites >= 2
-    assert ElasticTrainer._fold_conv_tally(trainer) == (
-        f"; convolution: {step.sites} sites ({step.sites} in the kernel)"
-    )
-    assert (stats.conv_sites, stats.conv_kernel_sites) == step
-    assert ElasticTrainer._fold_conv_tally(trainer) == ""  # said once
-    assert {"conv_sites", "conv_kernel_sites"} <= set(stats.as_dict())
+    sites, in_kernels = added(before, CONV)
+    assert sites == in_kernels >= 2
     # without recomputation every mixer is one site
-    ElasticTrainer._first_build(trainer, "step_donating_plain")
+    before = trace_counts.snapshot()
     _step(replace(cfg, remat=False), mesh)
-    assert ElasticTrainer._fold_conv_tally(trainer) == (
-        "; convolution: 2 sites (2 in the kernel)"
-    )
+    assert added(before, CONV) == (2, 2)
     # a model without such a layer never moves it
     dense = TransformerConfig(
         vocab_size=64, num_layers=1, model_dim=32, num_heads=2, mlp_dim=32,
         max_seq_len=64,
     )
-    ElasticTrainer._first_build(trainer, "step_donating_dense")
+    before = trace_counts.snapshot()
     _step(dense, mesh)
-    assert ElasticTrainer._fold_conv_tally(trainer) == ""
-    assert (stats.conv_sites, stats.conv_kernel_sites) == (2, 2)
+    assert added(before, CONV) == (0, 0)
 
 
 def test_the_delta_mixer_hands_its_mesh_to_the_rule():
@@ -369,10 +346,10 @@ def test_the_delta_mixer_hands_its_mesh_to_the_rule():
     p = init_params(jax.random.PRNGKey(0), cfg)["layers"][1]["gdn"]
     u = jnp.zeros((2, 64, 32))
     for mesh, want in ((None, (1, 1)), (many, (1, 0))):
-        before = conv_tally()
+        before = trace_counts.snapshot()
         jax.make_jaxpr(lambda u: gated_delta_mixer(u, p, cfg, 1e-5, mesh))(u)
-        assert conv_tally() - before == want
-    before = conv_tally()
+        assert added(before, CONV) == want
+    before = trace_counts.snapshot()
     ssm = init_params(jax.random.PRNGKey(0), cfg)["layers"][0]["ssm"]
     jax.make_jaxpr(lambda u: mamba2.mamba2_mixer(u, ssm, cfg, 1e-5, many))(u)
-    assert conv_tally() - before == (1, 0)
+    assert added(before, CONV) == (1, 0)

@@ -615,6 +615,42 @@ class TestChunkedStaging:
             set_arbiter(None)
             engine.close()
 
+    def test_a_budgeted_advance_stages_one_chunk_at_most(
+        self, saver, tmp_path
+    ):
+        """What a train step pays for a save in flight is a share of one
+        synchronous drain of the same state: a budgeted ``advance()``
+        touches no group it has just issued, and overshoots its budget by
+        at most one write group of ``chunk_bytes``."""
+        engine = CheckpointEngine()
+        try:
+            state = {"big": jnp.arange(1 << 16, dtype=jnp.float32)}
+            chunk = 1 << 14
+            stager = engine.begin_chunked_save(
+                1, state, str(tmp_path / "ck"), chunk_bytes=chunk
+            )
+            assert stager is not None
+            total = stager.total_bytes
+            assert total == 16 * chunk
+            # the first call only issues copies: they ride behind the
+            # step in flight
+            assert stager.advance(budget_s=0.0) == 0
+            per_step = []
+            while not stager.done:
+                per_step.append(stager.advance(budget_s=0.0))
+                assert len(per_step) <= 64
+            assert max(per_step) <= chunk < total
+            assert sum(per_step) == total and len(per_step) >= 16
+            assert stager.backlog_bytes == 0
+            assert stager.commit()
+            _, recs, _ = engine._shm.load_records(copy=True)
+            (rec,) = recs
+            np.testing.assert_array_equal(
+                rec.data.reshape(-1), np.asarray(state["big"])
+            )
+        finally:
+            engine.close()
+
     def test_lock_busy_skips(self, saver, tmp_path):
         """Starting a chunked save while the saver owns the lock is a
         skip, never a block (the save_to_memory contract)."""
@@ -672,32 +708,3 @@ class TestChunkedStaging:
             )
         finally:
             engine.close()
-
-
-class TestBenchSmoke:
-    def test_bench_smoke_emits_pipeline_keys(self):
-        """CI wiring for the overlap keys: the --smoke path must emit
-        prefetch + chunked-staging measurements on a plain CPU."""
-        import importlib.util
-        import os as _os
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_smoke_mod",
-            _os.path.join(
-                _os.path.dirname(_os.path.dirname(__file__)), "bench.py"
-            ),
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        results = {}
-        bench.run_pipeline_bench(jax, results, smoke=True)
-        assert results["prefetch_overlap_pct"] is not None
-        assert results["feed_MBps_prefetch_on"] > 0
-        assert results["feed_MBps_prefetch_off"] > 0
-        assert results["stage_amortized_block_ms"] is not None
-        # the whole point: amortized per-step blocking far below the
-        # single synchronous drain of the same state
-        assert (
-            results["stage_amortized_block_ms"]
-            < results["stage_sync_block_ms"]
-        )

@@ -3,8 +3,8 @@ the cross-worker timeline merge (ISSUE 7).
 
 Acceptance anchors:
 - the ledger partitions wall time into the closed taxonomy with zero
-  closure error on synthetic and live-span inputs (the ±1% smoke gate
-  is the bench twin of these tests);
+  closure error on synthetic and live-span inputs (a running trainer's
+  ledger against the ±1% gate: ``test_obs.py``);
 - the fleet goodput number flows worker scalars → TelemetryAggregator
   → JobMetricCollector sample → Brain datastore (including schema
   migration of pre-goodput stores);

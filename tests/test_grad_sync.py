@@ -296,8 +296,8 @@ class TestTrainStepSync:
         _assert_same_adam_step(s1, s4, atol=2e-5)
 
     def test_int8_error_feedback_convergence_parity(self):
-        """The bench gate in test form: int8+EF training tracks the
-        fp32 baseline's loss on the same data/init."""
+        """int8+EF training tracks the fp32 baseline's loss on the same
+        data/init."""
         cfg = _fp32_tiny()
         mesh = _mesh(2)
         tx = optax.adamw(1e-2)
@@ -889,40 +889,3 @@ class TestKnobPlumbing:
         assert out.comm_overlap is True
         assert out.grad_compress == "int8"
         assert out.grad_bucket_mb == 2
-
-
-# -- bench leg (slow: three full train-step compiles + 72 steps) ------------
-@pytest.mark.slow
-class TestBenchGradSync:
-    def test_bench_leg_emits_keys_and_passes_gates(self):
-        """The --smoke gate in test form: the bench's three-way
-        comparison (fp32 / bucketed / int8+EF) must emit every
-        acceptance key and land inside its documented gates."""
-        import importlib.util
-        import os as _os
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_grad_sync_mod",
-            _os.path.join(
-                _os.path.dirname(_os.path.dirname(__file__)), "bench.py"
-            ),
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        results = {}
-        bench.run_grad_sync_bench(jax, results, smoke=True)
-        assert results["grad_sync_ms"] > 0
-        assert results["comm_overlap_pct"] is not None
-        wire, raw = results["grad_bytes_wire_vs_raw"]
-        assert wire <= bench.GRAD_SYNC_WIRE_GATE * raw
-        # same schedule, same math: bucketed fp32 == GSPMD baseline
-        assert (
-            abs(
-                results["grad_sync_loss_overlap"]
-                - results["grad_sync_loss_fp32"]
-            )
-            < 1e-4
-        )
-        assert (
-            results["grad_sync_loss_gap"] <= bench.GRAD_SYNC_LOSS_GATE
-        )

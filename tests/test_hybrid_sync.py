@@ -286,8 +286,8 @@ class TestHybridTrainStep:
         assert abs(g0 - g1) < 1e-4
 
     # slow tier (budget): tier-1 keeps the tp path covered by the
-    # unit-level sync test + the lower-only HLO structure check; the
-    # full parity A/B also gates in bench --smoke
+    # unit-level sync test (test_tp_mode_sync_is_exact_mean) + the
+    # lower-only HLO structure check
     @pytest.mark.slow
     def test_tp_explicit_matches_gspmd(self):
         """dp x tp: the sync itself is the same psum in the same
@@ -310,8 +310,7 @@ class TestHybridTrainStep:
             )
 
     # slow tier (budget): int8-on-zero-plans stays tier-1-covered by
-    # TestZeroSyncGrads (quantization error bound + residual shapes);
-    # this 12-step convergence A/B also gates in bench --smoke
+    # TestZeroSyncGrads (quantization error bound + residual shapes)
     @pytest.mark.slow
     def test_fsdp_int8_error_feedback_convergence(self):
         mc = MeshConfig(dp=2, fsdp=2)
@@ -487,40 +486,52 @@ class TestHybridCommCost:
         assert compressed == plain
 
 
-# -- bench leg (slow: many full train-step compiles) ------------------------
+# -- a trainer on a dp x tp mesh (slow: a trainer and two more worlds) ------
 @pytest.mark.slow
-class TestBenchHybridSync:
-    def test_bench_leg_emits_keys_and_passes_gates(self):
-        """The --smoke gate in test form: run_hybrid_sync_bench must
-        emit every acceptance key and land inside its gates."""
-        import importlib.util
-        import os as _os
+class TestHybridTrainer:
+    def test_trainer_takes_the_explicit_path_and_resizes_warm(self):
+        """A trainer on dp2 x tp2 resolves the explicit schedule (no
+        silent GSPMD fallback), grows to dp4 x tp2 and comes back through
+        the compile cache."""
+        from dlrover_tpu.trainer.elastic.trainer import (
+            ElasticTrainer,
+            TrainerConfig,
+        )
 
-        spec = importlib.util.spec_from_file_location(
-            "bench_hybrid_sync_mod",
-            _os.path.join(
-                _os.path.dirname(_os.path.dirname(__file__)), "bench.py"
+        class _Tokens:
+            data = np.random.default_rng(0).integers(
+                0, 256, (128, 33), dtype=np.int32
+            )
+
+            def __len__(self):
+                return len(self.data)
+
+            def __getitem__(self, i):
+                return {"x": self.data[i][:-1], "y": self.data[i][1:]}
+
+        trainer = ElasticTrainer(
+            model_cfg=tiny(num_layers=1),
+            tx=optax.adamw(1e-2),
+            dataset=_Tokens(),
+            trainer_cfg=TrainerConfig(
+                batch_size=8, seq_len=32, report_metrics=False,
+                log_interval=1000, prefetch=2, donation_aware=False,
+                speculative_compile=False, comm_overlap=True,
             ),
+            strategy=Strategy(mesh=MeshConfig(dp=2, tp=2), dtype="float32"),
+            devices=jax.devices()[:4],
         )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        results = {}
-        bench.run_hybrid_sync_bench(jax, results, smoke=True)
-        assert "hybrid_sync_error" not in results, results
-        assert results["hybrid_sync_path_fsdp"] == "explicit"
-        assert results["hybrid_sync_path_tp"] == "explicit"
-        assert results["hybrid_sync_path_trainer"] == "explicit"
-        assert results["hybrid_sync_no_fallback_log"] is True
-        assert results["hybrid_sync_parity_fsdp"] is True
-        assert results["hybrid_sync_parity_tp"] is True
-        assert results["hybrid_sync_fsdp_wire_bytes"] < (
-            results["hybrid_sync_gspmd_wire_bytes"]
-        )
-        assert results["hybrid_sync_int8_loss_gap"] <= (
-            bench.GRAD_SYNC_LOSS_GATE
-        )
-        assert results["resize_downtime_warm_tp_ms"] is not None
-        assert results["hybrid_resize_cache_hit"] is True
+        try:
+            assert trainer.pipeline_stats.grad_sync_path == "explicit"
+            trainer.train(num_steps=2)
+            trainer.resize(8)  # dp4 x tp2: never compiled
+            trainer.train(num_steps=4)
+            warm = trainer.resize(4)  # back to dp2 x tp2
+            trainer.train(num_steps=6)
+            assert warm["compile_cache_hit"] is True
+            assert trainer.pipeline_stats.grad_sync_path == "explicit"
+        finally:
+            trainer.close()
 
 
 # -- fallback visibility ----------------------------------------------------

@@ -16,7 +16,7 @@ ISSUE 46) under ``interpret=True`` against the plain statement and the
 recurrence at heads of 128, the latent attention
 against the written-out full matrix, group-limited routing against a
 brute-force mask, a chip's share of the experts adding up to the whole
-layer, and the tallies of a built step.
+layer, and the counts of a built step.
 
 The tolerance is 2e-5 relative (2e-4 for a gradient leaf), as
 ``test_qwen3_next.py``'s and for its reason.
@@ -26,7 +26,6 @@ import functools
 import hashlib
 import importlib.util
 import os
-import types
 from dataclasses import replace
 
 import jax
@@ -34,8 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.accel.profiler import PipelineStats
-from dlrover_tpu.models import transformer
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.models.config import TransformerConfig, tiny
 from dlrover_tpu.models.train import TrainState, build_train_step
 from dlrover_tpu.models.transformer import (
@@ -44,14 +42,12 @@ from dlrover_tpu.models.transformer import (
     init_params,
     logical_axes,
     loss_fn,
-    score_lanes_tally,
 )
 from dlrover_tpu.ops import gated_delta
 from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.gated_delta import (
     gated_delta_chunked,
     gated_delta_mixer,
-    gdn_tally,
     head_gated_rmsnorm,
     l2norm,
     unit_lower_inverse,
@@ -64,10 +60,8 @@ from dlrover_tpu.parallel.moe import (
     moe_layer_local,
     route,
 )
-from dlrover_tpu.trainer.elastic.trainer import (
-    ElasticTrainer,
-    build_optimizer,
-)
+from dlrover_tpu.trainer.elastic.trainer import build_optimizer
+from trace_counted import GDN, LANES, added
 
 RTOL = 2e-5
 GRAD_RTOL = 2e-4  # a gradient sums more terms in another order
@@ -427,13 +421,13 @@ def test_the_vector_rule_refuses_what_it_cannot_chunk():
     (128, 128, 64, 8192 + 32, "bfloat16", False),  # a ragged sequence
 ])
 def test_the_kernels_take_a_vector_decay_where_the_shapes_allow(
-    d_k, d_v, chunk, T, dtype, kernel, monkeypatch
+    d_k, d_v, chunk, T, dtype, kernel
 ):
     """``fits`` reads the shapes alone, and a site with a vector decay goes
-    the way it says: traced at the shapes, the tally counts the site as
-    the kernels' or not."""
+    the way it says: traced at the shapes, the site is counted as the
+    kernels' or not."""
     assert kernels.fits(d_k, d_v, chunk, T, dtype) is kernel
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    before = trace_counts.snapshot()
     shape = lambda *s: jax.ShapeDtypeStruct(s, dtype)  # noqa: E731
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731
     args = (
@@ -446,25 +440,23 @@ def test_the_kernels_take_a_vector_decay_where_the_shapes_allow(
         return
     out = jax.eval_shape(lambda *a: gated_delta_chunked(*a, chunk), *args)
     assert out.shape == (1, T, 2, d_v) and out.dtype == dtype
-    assert gdn_tally() == (1, T // chunk, int(kernel))
+    assert added(before, GDN) == (1, T // chunk, int(kernel))
 
 
-def test_a_vector_decay_site_of_kernel_shapes_is_the_kernels_and_says_so(
-    monkeypatch
-):
+def test_a_vector_decay_site_of_kernel_shapes_is_the_kernels_and_says_so():
     """Heads of whole lane tiles: the site is counted as the kernels' with
     a vector decay as with a scalar one, and lowers to the kind's own."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    before = trace_counts.snapshot()
     q, k, v, beta, g = _rule_inputs("mid", B=1, T=64, dk=128, dv=128)
     text = jax.jit(lambda *a: gated_delta._delta_rule(*a, 16, None)).lower(
         q, k, v, beta, g
     ).as_text()
-    assert gdn_tally() == (1, 4, 1)
+    assert added(before, GDN) == (1, 4, 1)
     assert "f32[4,1,2,16,16]" not in text  # no chunk's square around them
     jax.jit(lambda *a: gated_delta._delta_rule(*a, 16, None)).lower(
         q, k, v, beta, g[..., 0]
     )
-    assert gdn_tally() == (2, 8, 2)
+    assert added(before, GDN) == (2, 8, 2)
 
 
 # the vector-decay kernels (``gdn_channel_*``) under ``interpret=True``:
@@ -509,17 +501,15 @@ def test_vector_decay_kernels_are_the_plain_statement(dtype, regime):
 
 
 @pytest.mark.parametrize("regime", sorted(REGIMES))
-def test_vector_decay_rule_through_the_kernels_is_the_recurrence(
-    regime, monkeypatch
-):
-    """Forward and in every gradient against one step at a time; the tally
-    says which way the site went (a site, its steps, in the kernels; the
+def test_vector_decay_rule_through_the_kernels_is_the_recurrence(regime):
+    """Forward and in every gradient against one step at a time; the counts
+    say which way the site went (a site, its steps, in the kernels; the
     backward pass its steps again)."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    before = trace_counts.snapshot()
     args = _kernel_inputs(regime)
     want = jax.jit(delta_rule_sequential)(*args)
     got = jax.jit(lambda *a: gated_delta_chunked(*a, 64))(*args)
-    assert gdn_tally() == (1, 4, 1)
+    assert added(before, GDN) == (1, 4, 1)
     assert got.shape == want.shape == (1, 256, 2, 128)
     assert _rel(got, want) <= RTOL
 
@@ -533,24 +523,22 @@ def test_vector_decay_rule_through_the_kernels_is_the_recurrence(
         grads(delta_rule_sequential),
     ):
         assert _rel(a, b) <= GRAD_RTOL
-    assert gdn_tally() == (2, 12, 2)
+    assert added(before, GDN) == (2, 12, 2)
 
 
-def test_under_recomputation_every_counted_site_is_counted_in_the_kernels(
-    monkeypatch
-):
+def test_under_recomputation_every_counted_site_is_counted_in_the_kernels():
     """A layer under ``jax.checkpoint`` is traced as the primal once and
     through the ``custom_vjp`` rules once more: the pass counts a site each
     time, and so does the kernels' forward, or the share of sites in the
     kernels would read 50 where every site is theirs (the Ling cell's
     first traced run did)."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    before = trace_counts.snapshot()
     args = _kernel_inputs("mid")
     layer = jax.checkpoint(lambda *a: gated_delta_chunked(*a, 64))
     jax.jit(jax.grad(
         lambda *a: jnp.sum(layer(*a) ** 2), argnums=range(5)
     )).lower(*args)
-    sites, steps, in_kernels = gdn_tally()
+    sites, steps, in_kernels = added(before, GDN)
     assert (sites, in_kernels) == (2, 2)
     assert steps == 3 * 4  # forward, the forward again, backward
 
@@ -567,11 +555,11 @@ def test_the_kernels_square_whatever_chunks_there_are(T, chunk):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_the_kernels_inverse_is_the_one_by_halves(dtype, monkeypatch):
+def test_the_kernels_inverse_is_the_one_by_halves(dtype):
     """``test_nearly_parallel_keys_that_hardly_decay_stay_the_recurrence``'s
     case at heads of 128, through the kernels: with the product form in
     their place the first chunk reads 1e8 and the third NaN."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    before = trace_counts.snapshot()
     B, T, H, d = 1, 2048, 2, 128
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     base = l2norm(jax.random.normal(ks[0], (1, 1, H, d)))
@@ -585,7 +573,7 @@ def test_the_kernels_inverse_is_the_one_by_halves(dtype, monkeypatch):
     got = jax.jit(lambda *a: gated_delta_chunked(*a, 64))(
         q.astype(dtype), k.astype(dtype), v.astype(dtype), beta, g
     ).astype(jnp.float32)
-    assert gdn_tally() == (1, 32, 1)
+    assert added(before, GDN) == (1, 32, 1)
     assert np.all(np.isfinite(got))
     assert _rel(got, want) <= (2e-4 if dtype == "float32" else 5e-2)
 
@@ -946,17 +934,16 @@ def test_a_configuration_that_cannot_be_is_refused(bad, match):
         _cfg(**bad)
 
 
-# -- the tallies -------------------------------------------------------------
+# -- the counts --------------------------------------------------------------
 
 
-def test_the_tallies_count_sites_chunk_steps_and_score_lanes(monkeypatch):
+def test_the_counts_are_sites_chunk_steps_and_score_lanes():
     """Three KDA mixers over 64 tokens in chunks of 16 and one latent
     attention whose 24-wide scores are called 128 wide: a traced train
     step is 3 sites, 3 x 4 steps forward and as many backward, none in
-    the kernels, and 24 of 128 score lanes. The trainer folds what a
-    step's build traced, and nothing else, into the stats and its line."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
-    monkeypatch.setattr(transformer, "_score_lanes", transformer.ScoreLanes())
+    the kernels, and 24 of 128 score lanes (how the trainer folds what a
+    step's build traced: ``test_trace_counts.py``)."""
+    before = trace_counts.snapshot()
     cfg = _cfg()
     tx = build_optimizer("adamw", lr=1e-3)
     mesh = build_mesh(MeshConfig(), jax.devices()[:1])
@@ -964,65 +951,34 @@ def test_the_tallies_count_sites_chunk_steps_and_score_lanes(monkeypatch):
     x, y = _batch(cfg)
     # the worker's reference check: a forward pass before any step
     jax.jit(lambda p: loss_fn(p, x, y, cfg, None)).lower(params)
-    assert gdn_tally() == (3, 12, 0)
-    assert score_lanes_tally() == (128, 24)
-
-    stats = PipelineStats()
-    trainer = types.SimpleNamespace(
-        pipeline_stats=stats, _gdn_before_step=None, _lanes_before_step=None,
-        _built=set(), _builds=types.SimpleNamespace(build=lambda what: what),
-    )
-    assert ElasticTrainer._fold_score_lanes(trainer) == ""  # no step built
-    assert ElasticTrainer._first_build(trainer, "eval") == "eval"
-    assert trainer._lanes_before_step is None
-    ElasticTrainer._first_build(trainer, "step_donating")
-    assert trainer._lanes_before_step == (128, 24)
+    assert added(before, GDN) == (3, 12, 0)
+    assert added(before, LANES) == (128, 24)
     state = TrainState(
         step=jnp.zeros((), jnp.int32), params=params,
         opt_state=tx.init(params),
     )
+    before = trace_counts.snapshot()
     build_train_step(cfg, mesh, tx, donate=False).lower(state, x, y)
-    assert ElasticTrainer._fold_gdn_tally(trainer) == (
-        "; gated delta rule: 3 sites (0 in the kernel), "
-        "24 serial chunk steps a train step"
-    )
-    assert ElasticTrainer._fold_score_lanes(trainer) == (
-        "; attention scores: 24 of the 128 lanes the kernels were called with"
-    )
-    assert (stats.gdn_sites, stats.gdn_chunk_steps) == (3, 24)
-    assert stats.gdn_kernel_sites == 0
-    assert (stats.attn_score_lanes, stats.attn_score_lanes_used) == (128, 24)
-    assert ElasticTrainer._fold_score_lanes(trainer) == ""  # said once
-    assert {"attn_score_lanes", "attn_score_lanes_used"} <= set(
-        stats.as_dict()
-    )
+    assert added(before, GDN) == (3, 24, 0)
+    assert added(before, LANES) == (128, 24)
     # under ``remat`` every layer is traced on its own (a wrapper a layer:
     # ``jax.checkpoint`` would hand layers two and three the trace of the
     # first) and a mixer's forward pass is traced, and run, twice: the
     # step's serial depth is 3 x 3 x 4, and the latent site still one
-    ElasticTrainer._first_build(trainer, "step_donating_remat")
+    before = trace_counts.snapshot()
     build_train_step(
         replace(cfg, remat=True), mesh, tx, donate=False
     ).lower(state, x, y)
-    assert ElasticTrainer._fold_gdn_tally(trainer) == (
-        "; gated delta rule: 6 sites (0 in the kernel), "
-        "36 serial chunk steps a train step"
-    )
-    ElasticTrainer._fold_score_lanes(trainer)
-    assert (stats.attn_score_lanes, stats.attn_score_lanes_used) == (128, 24)
-    # an attention that states one width for all three is called with it:
-    # counted, and nothing to say
+    assert added(before, GDN) == (6, 36, 0)
+    assert added(before, LANES) == (128, 24)
+    # an attention that states one width for all three is called with it
     dense = tiny()
     p = init_params(jax.random.PRNGKey(0), dense)
     xs = jnp.zeros((1, 16), jnp.int32)
-    ElasticTrainer._first_build(trainer, "step_donating_dense")
-    before = gdn_tally()
+    before = trace_counts.snapshot()
     jax.jit(lambda p: loss_fn(p, xs, xs, dense, None)).lower(p)
-    assert gdn_tally() == before
-    assert ElasticTrainer._fold_score_lanes(trainer) == ""
-    assert (stats.attn_score_lanes, stats.attn_score_lanes_used) == (16, 16)
-
-
+    assert added(before, GDN) == (0, 0, 0)
+    assert added(before, LANES) == (16, 16)
 def test_one_train_step_moves_every_leaf_and_reports_the_routing():
     cfg = _cfg(experts_held=4, experts_offset=4, router_bias_rate=1e-3)
     tx = build_optimizer("adamw", lr=1e-2)

@@ -4,8 +4,7 @@ pp x dp (bubble-scheduled per-stage), dp x ep (manual all-to-all region
 micro-batch rebalance alternative to idling surplus ranks.
 
 Tier-1 keeps the unit-sync + HLO-structure + pricing tests; the full
-parity A/Bs (which also gate in ``bench.py --smoke``) ride the slow
-tier per the PR-8 budget convention.
+parity A/Bs ride the slow tier per the PR-8 budget convention.
 """
 
 import re
@@ -187,7 +186,7 @@ class Test3DSync:
             MeshConfig(dp=2, fsdp=2, tp=2), 8
         ) == rs_per_bucket(MeshConfig(dp=2, fsdp=2), 4)
 
-    # the full train-step parity A/B also gates in bench --smoke
+    # tier-1 twin: test_unit_sync_is_exact_mean
     @pytest.mark.slow
     def test_train_step_parity_with_gspmd(self):
         cfg = _fp32_tiny()
@@ -275,8 +274,8 @@ class TestPPSync:
             for g in re.findall(r"\{([0-9, ]+)\}", groups):
                 assert len(g.split(",")) == 4, groups
 
-    # parity A/Bs for all three schedules gate in bench --smoke; the
-    # tier-1 twin keeps one cheap schedule compiled+stepped
+    # tier-1 keeps the plan's structure and the lowered groups
+    # (test_hlo_per_stage_rs_with_stage_local_groups)
     @pytest.mark.slow
     @pytest.mark.parametrize("sched", ["gpipe", "1f1b", "interleaved"])
     def test_parity_with_plain_dp_reference(self, sched):
@@ -394,7 +393,7 @@ class TestEPSync:
             cfg, dc_replace(s, grad_accum=1)
         ) is not None
 
-    # the 4-step parity A/B also gates in bench --smoke
+    # tier-1 twin: test_hlo_two_alltoalls_per_layer_each_way
     @pytest.mark.slow
     def test_train_step_parity_with_gspmd(self):
         cfg = _fp32_tiny(num_experts=2)

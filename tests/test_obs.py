@@ -21,6 +21,8 @@ import time
 import numpy as np
 import pytest
 
+from dlrover_tpu.common import faults
+from dlrover_tpu.obs import flight_recorder as obs_flight
 from dlrover_tpu.obs import trace as obs_trace
 from dlrover_tpu.obs.aggregate import TelemetryAggregator
 from dlrover_tpu.obs.metrics import (
@@ -724,6 +726,7 @@ def traced_smoke_run(tmp_path_factory):
     metrics_path = str(tmp / "runtime_metrics.json")
     old_env = os.environ.get("DLROVER_TPU_RUNTIME_METRICS_PATH")
     os.environ["DLROVER_TPU_RUNTIME_METRICS_PATH"] = metrics_path
+    old_flight = os.environ.get(obs_flight.ENV_FLIGHT_DIR)
 
     class _Tokens:
         def __init__(self, n=256, seq=32, vocab=256):
@@ -766,12 +769,30 @@ def traced_smoke_run(tmp_path_factory):
         trainer.train(num_steps=14)
         trace_path = str(tmp / "trace.json")
         tracer.dump(trace_path)
+        goodput = trainer._goodput.snapshot()
+        # then a crash: the feed fails under the loop, which leaves its
+        # black box behind before the exception reaches the caller
+        os.environ[obs_flight.ENV_FLIGHT_DIR] = str(tmp / "flight")
+        faults.configure("prefetch.pull:io_error:1.0")
+        try:
+            trainer.train(num_steps=trainer.global_step + 8)
+            crashed = None
+        except OSError as e:
+            crashed = e
         yield {
             "trace_path": trace_path,
             "metrics_path": metrics_path,
             "stats": trainer.pipeline_stats,
+            "goodput": goodput,
+            "crashed": crashed,
+            "flight_dir": str(tmp / "flight"),
         }
     finally:
+        faults.reset()
+        if old_flight is None:
+            os.environ.pop(obs_flight.ENV_FLIGHT_DIR, None)
+        else:
+            os.environ[obs_flight.ENV_FLIGHT_DIR] = old_flight
         trainer.close()
         tracer.enabled = was_enabled
         if old_env is None:
@@ -808,6 +829,31 @@ class TestTrainerTraceArtifact:
             "prefetch_pull", "h2d",
         ):
             assert expected in names, f"missing span {expected}"
+
+    def test_the_live_ledger_closes_on_wall_time(self, traced_smoke_run):
+        """The goodput categories of a running trainer sum back to its
+        wall time within the gate, and spans did flow into them."""
+        from dlrover_tpu.obs.goodput import CLOSURE_GATE_PCT
+
+        report = traced_smoke_run["goodput"]
+        assert report.closure_error_pct <= CLOSURE_GATE_PCT
+        assert report.goodput_pct > 0
+        assert report.seconds.get("productive_compute", 0.0) > 0
+
+    def test_a_crash_leaves_a_bundle_with_a_valid_trace(
+        self, traced_smoke_run
+    ):
+        assert isinstance(traced_smoke_run["crashed"], OSError)
+        root = traced_smoke_run["flight_dir"]
+        bundles = sorted(
+            d for d in os.listdir(root) if d.split("_")[1:2] == ["crash"]
+        )
+        assert bundles
+        with open(os.path.join(root, bundles[-1], "trace.json")) as f:
+            ok, reason = validate_chrome_trace(json.load(f))
+        assert ok, reason
+        with open(os.path.join(root, bundles[-1], "manifest.json")) as f:
+            assert json.load(f)["exception"]["type"] == "OSError"
 
     def test_registry_scalars_reach_metrics_file(self, traced_smoke_run):
         payload = json.load(open(traced_smoke_run["metrics_path"]))

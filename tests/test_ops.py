@@ -9,6 +9,7 @@ import numpy as np
 import optax
 import pytest
 
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.ops import (
     adamw_8bit,
     agd,
@@ -30,6 +31,7 @@ from dlrover_tpu.ops.quantized_optim import (
     _adam8_update_pallas,
     _to_blocks,
 )
+from trace_counted import FUSED, STREAM, added
 
 
 def _qkv(B=2, T=128, H=4, Hkv=4, D=32, seed=0, dtype=jnp.float32):
@@ -169,13 +171,13 @@ class TestFlashAttention:
             diagonal=True, row_tile=row_tile,
         )
         off = jnp.zeros(2, jnp.int32)
-        before = fa.fused_tally()
+        before = trace_counts.snapshot()
         o, lse4 = fa._fused_fwd_call(q, k, v, off, **kw)
         f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
         delta4 = (f32(do) * f32(o)).sum(-1, keepdims=True)
         grads = fa._fused_bwd_call(q, k, v, do, lse4, delta4, off, **kw)
         n = T // row_tile
-        assert fa.fused_tally() - before == (2, 0, n * (n + 1), 2 * n * n)
+        assert added(before, FUSED) == (2, 0, n * (n + 1), 2 * n * n)
 
         def ref(q, k, v):  # the reference speaks [B, T, H, D]
             o, lse = flash_attention_reference(
@@ -199,7 +201,7 @@ class TestFlashAttention:
     # which body a fused call site takes is decided by what is known
     # when the program is traced; either way it matches the reference
     @pytest.mark.parametrize(
-        "case,kw,tally",
+        "case,kw,counts",
         [
             ("in_sequence", dict(), (2, 0, 20, 32)),
             ("equal_offsets", dict(q_offset=96, k_offset=96), (2, 0, 20, 32)),
@@ -213,7 +215,7 @@ class TestFlashAttention:
             ("streaming", dict(allow_fused=False), (0, 0, 0, 0)),
         ],
     )
-    def test_body_taken_and_grads(self, case, kw, tally, monkeypatch):
+    def test_body_taken_and_grads(self, case, kw, counts, monkeypatch):
         monkeypatch.setattr(fa, "_TRI_ROW_TILE", 32)  # T = 128: 4 tiles
         q, k, v = _qkv(T=128)
 
@@ -227,9 +229,9 @@ class TestFlashAttention:
         def lr(q, k, v):
             return (flash_attention_reference(q, k, v, **kw_ref) ** 2).sum()
 
-        before = fa.fused_tally()
+        before = trace_counts.snapshot()
         gp = jax.grad(lp, argnums=(0, 1, 2))(q, k, v)
-        assert fa.fused_tally() - before == tally
+        assert added(before, FUSED) == counts
         gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gp, gr):
             np.testing.assert_allclose(a, b, atol=5e-4)
@@ -257,9 +259,9 @@ class TestFlashAttention:
             )
             return o, lse, grads
 
-        before = fa.fused_tally()
+        before = trace_counts.snapshot()
         o, lse, grads = hop(q, k, v, *(jnp.int32(n) for n in offsets))
-        assert fa.fused_tally() - before == (0, 2, 0, 0)
+        assert added(before, FUSED) == (0, 2, 0, 0)
         q_off, k_off = offsets
         o_ref, lse_ref = flash_attention_reference(
             q, k, v, q_offset=q_off, k_offset=k_off, return_residuals=True
@@ -275,20 +277,20 @@ class TestFlashAttention:
         for a, b in zip(grads, want):
             np.testing.assert_allclose(a, b, atol=5e-4)
         # the same offsets as Python ints are known: equal ones walk
-        before = fa.fused_tally()
+        before = trace_counts.snapshot()
         o_static, _ = flash_attention_fwd(
             q, k, v, causal=True, q_offset=q_off, k_offset=k_off,
             interpret=True,
         )
         walked = (1, 0, 10, 16) if q_off == k_off else (0, 1, 0, 0)
-        assert fa.fused_tally() - before == walked
+        assert added(before, FUSED) == walked
         np.testing.assert_allclose(o_static, o_ref, atol=2e-5)
 
     def test_t520_takes_the_square(self):
         # fused-eligible, and no row tile divides it
         q, k, v = _qkv(B=1, T=520, H=2, Hkv=2)
         assert fa._row_tile(520) is None and fa._row_tile(1024)
-        before = fa.fused_tally()
+        before = trace_counts.snapshot()
         # the call the public entry makes on the chip for such a T
         # (no block size tiles it, and the fused family needs none)
         gp = jax.grad(
@@ -300,7 +302,7 @@ class TestFlashAttention:
             ).sum(),
             argnums=(0, 1, 2),
         )(q, k, v)
-        assert fa.fused_tally() - before == (0, 2, 0, 0)
+        assert added(before, FUSED) == (0, 2, 0, 0)
         gr = jax.grad(
             lambda q, k, v: (flash_attention_reference(q, k, v) ** 2).sum(),
             argnums=(0, 1, 2),
@@ -364,10 +366,10 @@ class TestFlashAttention:
     def test_stream_triangle_matches_reference(
         self, dtype, atol_o, atol_g, n, H, Hkv, offset
     ):
-        before = fa.stream_tally()
+        before = trace_counts.snapshot()
         got, want = self._stream_case(dtype, n, H, Hkv, offset, seed=n)
         # a forward and a one-pass backward, n(n+1)/2 of n^2 blocks each
-        assert fa.stream_tally() - before == (
+        assert added(before, STREAM) == (
             2, 0, n * (n + 1), 2 * n * n
         )
         self._assert_close(got, want, atol_o, atol_g)
@@ -386,9 +388,9 @@ class TestFlashAttention:
         assert fa._one_pass_fits(8192, 128, 2)
         assert not fa._one_pass_fits(65536, 128, 2)
         monkeypatch.setattr(fa, "_ONE_PASS_MAX_BYTES", 0)
-        before = fa.stream_tally()
+        before = trace_counts.snapshot()
         got, want = self._stream_case(dtype, n, 8, 2, 0, seed=n)
-        assert fa.stream_tally() - before == (
+        assert added(before, STREAM) == (
             3, 0, 3 * n * (n + 1) // 2, 3 * n * n
         )
         self._assert_close(got, want, atol_o, atol_g)
@@ -398,7 +400,7 @@ class TestFlashAttention:
     # T = 128 in blocks of 32: 10 of 16 blocks a kernel, a forward and
     # a one-pass backward; the rectangle's backward is two kernels
     @pytest.mark.parametrize(
-        "case,kw,tally",
+        "case,kw,counts",
         [
             ("in_sequence", dict(), (2, 0, 20, 32)),
             ("equal_offsets", dict(q_offset=96, k_offset=96), (2, 0, 20, 32)),
@@ -413,7 +415,7 @@ class TestFlashAttention:
             ("too_many_blocks", dict(), (0, 3, 0, 0)),
         ],
     )
-    def test_stream_grid_taken_and_grads(self, case, kw, tally, monkeypatch):
+    def test_stream_grid_taken_and_grads(self, case, kw, counts, monkeypatch):
         if case == "too_many_blocks":  # the tables would crowd SMEM
             monkeypatch.setattr(fa, "_TRI_MAX_BLOCKS", 2)
         q, k, v = _qkv(T=128, H=4, Hkv=2)
@@ -429,10 +431,11 @@ class TestFlashAttention:
         def lr(q, k, v):
             return (flash_attention_reference(q, k, v, **kw_ref) ** 2).sum()
 
-        before = fa.stream_tally(), fa.fused_tally()
+        before = trace_counts.snapshot()
         gp = jax.grad(lp, argnums=(0, 1, 2))(q, k, v)
-        assert fa.stream_tally() - before[0] == tally
-        assert fa.fused_tally() == before[1]  # GQA: never the fused family
+        assert added(before, STREAM) == counts
+        # GQA: never the fused family
+        assert added(before, FUSED) == (0, 0, 0, 0)
         gr = jax.grad(lr, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gp, gr):
             np.testing.assert_allclose(a, b, atol=5e-4)
@@ -459,9 +462,9 @@ class TestFlashAttention:
             )
             return o, lse, grads
 
-        before = fa.stream_tally()
+        before = trace_counts.snapshot()
         o, lse, grads = hop(q, k, v, *(jnp.int32(n) for n in offsets))
-        assert fa.stream_tally() - before == (0, 3, 0, 0)
+        assert added(before, STREAM) == (0, 3, 0, 0)
         q_off, k_off = offsets
         o_ref, lse_ref = flash_attention_reference(
             q, k, v, q_offset=q_off, k_offset=k_off, return_residuals=True
@@ -477,23 +480,23 @@ class TestFlashAttention:
         for a, b in zip(grads, want):
             np.testing.assert_allclose(a, b, atol=5e-4)
         # the same offsets as Python ints are known: equal ones walk
-        before = fa.stream_tally()
+        before = trace_counts.snapshot()
         o_static, _ = flash_attention_fwd(
             q, k, v, q_offset=q_off, k_offset=k_off, **kw
         )
         walked = (1, 0, 10, 16) if q_off == k_off else (0, 1, 0, 0)
-        assert fa.stream_tally() - before == walked
+        assert added(before, STREAM) == walked
         np.testing.assert_allclose(o_static, o_ref, atol=2e-5)
 
     def test_fused_eligible_call_is_no_streaming_site(self):
         q, k, v = _qkv(T=128)  # H = H_kv, T <= 1024: the fused family
-        before = fa.stream_tally(), fa.fused_tally()
+        before = trace_counts.snapshot()
         jax.grad(
             lambda q, k, v: flash_attention(q, k, v, force="pallas").sum(),
             argnums=(0, 1, 2),
         )(q, k, v)
-        assert fa.stream_tally() == before[0]
-        assert fa.fused_tally() - before[1] == (0, 2, 0, 0)
+        assert added(before, STREAM) == (0, 0, 0, 0)
+        assert added(before, FUSED) == (0, 2, 0, 0)
 
     # the blocks of a call that states none: 1024 where it will take the
     # triangle path and 1024 divides its one sequence, else the 512 the

@@ -10,7 +10,7 @@ in float32 on the CPU, seeded weights: the program's ``loss_fn`` and every
 gradient leaf against ``benchmark/references/qwen3_next.py`` (loaded by
 path), the chunked delta rule against the recurrence one step at a time,
 each new kind of the attention layer against what it replaces, a chip's
-share of the experts adding up to the whole layer, and the tally of a built
+share of the experts adding up to the whole layer, and the counts of a built
 step.
 
 The tolerance is 2e-5 relative (2e-4 for a gradient leaf): program and
@@ -23,7 +23,6 @@ attention's jnp path against a plain softmax).
 import functools
 import importlib.util
 import os
-import types
 from dataclasses import replace
 
 import jax
@@ -31,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.accel.profiler import PipelineStats
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.models.config import TransformerConfig, tiny
 from dlrover_tpu.models.train import TrainState, build_train_step
 from dlrover_tpu.models.transformer import (
@@ -47,17 +46,14 @@ from dlrover_tpu.ops import gated_delta
 from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.gated_delta import (
     gated_delta_chunked,
-    gdn_tally,
     l2norm,
     unit_lower_inverse,
 )
 from dlrover_tpu.ops.mamba2 import gated_group_rmsnorm
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.moe import init_moe_params, moe_layer_local, route
-from dlrover_tpu.trainer.elastic.trainer import (
-    ElasticTrainer,
-    build_optimizer,
-)
+from dlrover_tpu.trainer.elastic.trainer import build_optimizer
+from trace_counted import GDN, added
 
 RTOL = 2e-5
 GRAD_RTOL = 2e-4  # a gradient sums more terms in another order
@@ -456,13 +452,11 @@ def test_chunk_kernels_are_the_plain_statement(stretch, dtype, regime):
 
 
 @pytest.mark.parametrize("regime", sorted(KERNEL_REGIMES))
-def test_chunked_rule_through_the_kernels_is_the_recurrence(
-    regime, monkeypatch
-):
+def test_chunked_rule_through_the_kernels_is_the_recurrence(regime):
     """``gated_delta_chunked`` at shapes the kernels take, forward and in
-    every gradient, against one step at a time; the tally says which way
+    every gradient, against one step at a time; the counts say which way
     the site went."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    before = trace_counts.snapshot()
     args = _kernel_inputs(regime, jnp.float32)
 
     def chunked(*a):
@@ -470,7 +464,7 @@ def test_chunked_rule_through_the_kernels_is_the_recurrence(
 
     want = jax.jit(delta_rule_sequential)(*args)
     got = jax.jit(chunked)(*args)
-    assert gdn_tally() == (1, 4, 1)
+    assert added(before, GDN) == (1, 4, 1)
     assert got.shape == want.shape == (1, 256, 4, 128)
     assert _rel(got, want) <= RTOL
 
@@ -481,7 +475,7 @@ def test_chunked_rule_through_the_kernels_is_the_recurrence(
 
     for a, b in zip(grads(chunked), grads(delta_rule_sequential)):
         assert _rel(a, b) <= GRAD_RTOL
-    assert gdn_tally() == (2, 12, 2)
+    assert added(before, GDN) == (2, 12, 2)
 
 
 @pytest.mark.parametrize("d_k,d_v,chunk,T,dtype,kernel", [
@@ -502,16 +496,14 @@ def test_the_shapes_decide_which_way_a_chunk_is_computed(
 
 
 @pytest.mark.parametrize("d,T", [(64, 128), (128, 20)])
-def test_a_site_the_kernels_cannot_take_is_plain_and_says_so(
-    d, T, monkeypatch
-):
+def test_a_site_the_kernels_cannot_take_is_plain_and_says_so(d, T):
     """A head of 64, or a sequence that is one chunk of no whole tiles:
-    the plain statement runs, to the recurrence's result, and the tally
-    counts a site and none in the kernel."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    the plain statement runs, to the recurrence's result, and the counts
+    are a site and none in the kernel."""
+    before = trace_counts.snapshot()
     args = _kernel_inputs("as_initialised", jnp.float32, T=T, d=d)
     got = jax.jit(lambda *a: gated_delta_chunked(*a, min(64, T)))(*args)
-    assert gdn_tally() == (1, max(T // 64, 1), 0)
+    assert added(before, GDN) == (1, max(T // 64, 1), 0)
     assert _rel(got, jax.jit(delta_rule_sequential)(*args)) <= RTOL
 
 
@@ -847,15 +839,15 @@ def test_a_configuration_that_cannot_be_is_refused(bad, match):
         _cfg(**bad)
 
 
-# -- the tally ----------------------------------------------------------------
+# -- the counts ---------------------------------------------------------------
 
 
-def test_the_tally_counts_sites_and_chunk_steps_of_a_built_step(monkeypatch):
+def test_the_counts_are_sites_and_chunk_steps_of_a_built_step():
     """Two DeltaNet mixers over 64 tokens in chunks of 16: a traced train
     step is 2 sites and 2 x 4 steps forward and as many backward; a
-    forward alone is half the steps. The trainer folds what a step's
-    build traced, and nothing else, into the stats and its line."""
-    monkeypatch.setattr(gated_delta, "_tally", gated_delta.GdnTally())
+    forward alone is half the steps (how the trainer folds what a step's
+    build traced: ``test_trace_counts.py``)."""
+    before = trace_counts.snapshot()
     cfg = _cfg()
     tx = build_optimizer("adamw", lr=1e-3)
     mesh = build_mesh(MeshConfig(), jax.devices()[:1])
@@ -863,67 +855,36 @@ def test_the_tally_counts_sites_and_chunk_steps_of_a_built_step(monkeypatch):
     x, y = _batch(cfg)
     # the worker's reference check: a forward pass before any step
     jax.jit(lambda p: loss_fn(p, x, y, cfg, None)).lower(params)
-    assert gdn_tally() == (2, 8, 0)
-
-    stats = PipelineStats()
-    trainer = types.SimpleNamespace(
-        pipeline_stats=stats, _gdn_before_step=None, _built=set(),
-        _builds=types.SimpleNamespace(build=lambda what: what),
-    )
-    assert ElasticTrainer._fold_gdn_tally(trainer) == ""  # no step built
-    assert ElasticTrainer._first_build(trainer, "eval") == "eval"
-    assert trainer._gdn_before_step is None
-    ElasticTrainer._first_build(trainer, "step_donating")
-    assert trainer._gdn_before_step == (2, 8, 0)
+    assert added(before, GDN) == (2, 8, 0)
     state = TrainState(
         step=jnp.zeros((), jnp.int32), params=params,
         opt_state=tx.init(params),
     )
     build_train_step(cfg, mesh, tx, donate=False).lower(state, x, y)
-    assert gdn_tally() == (4, 24, 0)
     # heads of 12 / 8 are no lane tiles: both sites took the plain way
-    assert ElasticTrainer._fold_gdn_tally(trainer) == (
-        "; gated delta rule: 2 sites (0 in the kernel), "
-        "16 serial chunk steps a train step"
-    )
-    assert (stats.gdn_sites, stats.gdn_chunk_steps) == (2, 16)
-    assert stats.gdn_kernel_sites == 0
-    assert ElasticTrainer._fold_gdn_tally(trainer) == ""  # said once
-    # a twin that came whole out of a cache of executables traced nothing
-    ElasticTrainer._first_build(trainer, "step_safe")
-    assert ElasticTrainer._fold_gdn_tally(trainer) == ""
-    assert (stats.gdn_sites, stats.gdn_chunk_steps) == (2, 16)
-    assert {
-        "gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites"
-    } <= set(stats.as_dict())
+    assert added(before, GDN) == (4, 24, 0)
     # a step whose mixers have heads of whole lane tiles is built from the
-    # kernels, and the line and the stats say so
+    # kernels
     wide = _cfg(
         num_layers=2, layer_pattern="GE", gdn_key_dim=128,
         gdn_value_dim=128, gdn_chunk=16,
     )
     wide_params = _weights(wide)
-    ElasticTrainer._first_build(trainer, "step_donating_wide")
+    before = trace_counts.snapshot()
     build_train_step(wide, mesh, tx, donate=False).lower(
         TrainState(
             step=jnp.zeros((), jnp.int32), params=wide_params,
             opt_state=tx.init(wide_params),
         ), x, y,
     )
-    assert ElasticTrainer._fold_gdn_tally(trainer) == (
-        "; gated delta rule: 1 sites (1 in the kernel), "
-        "8 serial chunk steps a train step"
-    )
-    assert (
-        stats.gdn_sites, stats.gdn_chunk_steps, stats.gdn_kernel_sites
-    ) == (1, 8, 1)
+    assert added(before, GDN) == (1, 8, 1)
     # a model without the kind never moves it
     dense = tiny()
-    before = gdn_tally()
+    before = trace_counts.snapshot()
     p = init_params(jax.random.PRNGKey(0), dense)
     xs = jnp.zeros((1, 16), jnp.int32)
     jax.jit(lambda p: loss_fn(p, xs, xs, dense, None)).lower(p)
-    assert gdn_tally() == before
+    assert added(before, GDN) == (0, 0, 0)
 
 
 def test_one_train_step_moves_every_leaf_and_reports_the_routing():

@@ -9,8 +9,7 @@ quarantine surviving a relaunch — same rank after a relaunch means the
 same convicted chip), the Brain's single-event condemnation, and ONE
 full in-process detect->convict->rollback->halt trainer chain. The
 multi-seed soak (full quarantine scenario + extra convict-only seeds)
-is ``slow``; ``bench.py --smoke`` re-runs the full scenario as a
-nonzero-exit gate.
+is ``slow``.
 """
 
 import importlib.util

@@ -119,7 +119,7 @@ class TestSparsePlanAccounting:
         assert sparse.compressed and sparse.compress == "int8_topk"
         ratio = sparse.dcn_bytes_twolevel() / dense.dcn_bytes_twolevel()
         # density 0.25 of int8 blocks + 4B/block indices: well under
-        # half the dense int8 DCN payload (the bench gate, in-unit)
+        # half the dense int8 DCN payload
         assert ratio <= 0.5, ratio
         assert 0.0 < sparse.dcn_density <= 0.3
 
@@ -543,8 +543,8 @@ class TestStripeFoldsObservedRates:
         from dlrover_tpu.parallel.transfer_sched import TransferArbiter
 
         a = TransferArbiter()
-        # an explicit gbps override marks an emulated rail (tests,
-        # bench) — its realized rate measures the emulation, not a
+        # an explicit gbps override marks an emulated rail (tests)
+        # — its realized rate measures the emulation, not a
         # physical link, and must never reprice the model
         a.register_rail("railA", direction="d2h", gbps=2.0)
         a.register_rail("railB", direction="peer", gbps=1.0)

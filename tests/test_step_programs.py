@@ -130,12 +130,13 @@ def test_rebuild_drops_the_executable(accel):
 
 
 # the parent's keys (PR 28), less the one counter PR 29 deleted with its
-# code, the fused attention tally's four (PR 36) and the held experts'
-# share of the routing (PR 37), the streaming attention tally's four
-# (PR 38), an incarnation's way up and the restart behind it (PR 40), the
-# shard lock's side of the due saves (PR 42), the Gated DeltaNet tally's
-# two (PR 43), its sites in the kernels (PR 44) and the convolution
-# tally's two (PR 47)
+# code, the fused attention kernels' four counts (PR 36) and the held
+# experts' share of the routing (PR 37), the streaming attention kernels'
+# four (PR 38), an incarnation's way up and the restart behind it (PR 40),
+# the shard lock's side of the due saves (PR 42), the Gated DeltaNet
+# mixers' two (PR 43), their sites in the kernels (PR 44) and the
+# convolutions' two (PR 47); how the counted ones are folded:
+# ``test_trace_counts.py``
 AS_DICT_KEYS = [
     "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
     "attn_stream_blocks_rect",
@@ -213,108 +214,3 @@ def test_as_dict_has_the_parents_keys(key):
         }[key]
 
 
-def test_attention_tally_folds_into_the_stats_and_the_builds_line(
-    monkeypatch,
-):
-    """The fused attention tally (``ops/flash_attention.FusedTally``) is
-    cumulative in the process; the trainer's stats hold it as it stood
-    at the last ``programs built ...`` line, and the line says what was
-    lowered since the line before."""
-    import importlib
-    import types
-
-    from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer
-
-    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
-    stats = PipelineStats()
-    trainer = types.SimpleNamespace(pipeline_stats=stats)
-    fold = lambda: ElasticTrainer._fold_attention_tally(trainer)  # noqa: E731
-
-    monkeypatch.setattr(fa, "_tally", fa.FusedTally())
-    assert fold() == ""  # nothing lowered: a model outside the family
-    # a step program of twelve layers at T = 1024, forward and backward
-    for _ in range(24):
-        fa._tally_site(1024, 256)
-    assert fold() == (
-        "; fused attention: 24 sites as triangle (240 of 384 tiles), "
-        "0 as square"
-    )
-    assert fold() == ""  # nothing new since that line
-    # its twin, and one ring hop (traced offsets: the square body)
-    for _ in range(24):
-        fa._tally_site(1024, 256)
-    fa._tally_site(1024, None)
-    assert fold() == (
-        "; fused attention: 24 sites as triangle (240 of 384 tiles), "
-        "1 as square"
-    )
-    assert (
-        stats.attn_tri_sites, stats.attn_square_sites,
-        stats.attn_tiles_walked, stats.attn_tiles_square,
-    ) == (48, 1, 480, 768) == fa.fused_tally()
-
-
-@pytest.mark.parametrize(
-    "sites,clause,folded",
-    [
-        # two layers at T = 4096 in blocks of 1024, a forward and a
-        # one-pass backward each: ten of sixteen blocks a kernel
-        (
-            [(4, 1)] * 4,
-            "; streaming attention: 4 sites as triangle (40 of 64 "
-            "blocks), 0 as rectangle",
-            (4, 0, 40, 64),
-        ),
-        # one layer at T = 8192, its backward split in two kernels
-        (
-            [(8, 1), (8, 2)],
-            "; streaming attention: 3 sites as triangle (108 of 192 "
-            "blocks), 0 as rectangle",
-            (3, 0, 108, 192),
-        ),
-        # a ring hop: traced offsets, the rectangular grid, three kernels
-        (
-            [(None, 1), (None, 2)],
-            "; streaming attention: 0 sites as triangle (0 of 0 blocks), "
-            "3 as rectangle",
-            (0, 3, 0, 0),
-        ),
-    ],
-    ids=["one_pass", "split", "rectangle"],
-)
-def test_streaming_tally_folds_beside_the_fused_one(
-    sites, clause, folded, monkeypatch
-):
-    """``StreamTally`` folds into ``PipelineStats.attn_stream_*`` as
-    ``FusedTally`` into ``attn_*``; each family has its clause on the
-    ``programs built ...`` line, and only when it lowered something."""
-    import importlib
-    import types
-
-    from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer
-
-    fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
-    stats = PipelineStats()
-    trainer = types.SimpleNamespace(pipeline_stats=stats)
-    fold = lambda: ElasticTrainer._fold_attention_tally(trainer)  # noqa: E731
-
-    monkeypatch.setattr(fa, "_tally", fa.FusedTally())
-    monkeypatch.setattr(fa, "_stream_tally", fa.StreamTally())
-    assert fold() == ""
-    for n, kernels in sites:
-        fa._tally_stream_site(n, kernels=kernels)
-    assert fold() == clause  # and no fused clause: none was lowered
-    assert fold() == ""  # nothing new since that line
-    assert (
-        stats.attn_stream_tri_sites, stats.attn_stream_rect_sites,
-        stats.attn_stream_blocks_walked, stats.attn_stream_blocks_rect,
-    ) == folded == fa.stream_tally()
-    # a fused site lowered later has its own clause, before this one's
-    fa._tally_site(1024, 256)
-    fa._tally_stream_site(2)
-    assert fold() == (
-        "; fused attention: 1 sites as triangle (10 of 16 tiles), "
-        "0 as square; streaming attention: 1 sites as triangle (3 of 4 "
-        "blocks), 0 as rectangle"
-    )
-    assert stats.attn_tri_sites == 1

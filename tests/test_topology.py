@@ -594,10 +594,8 @@ class TestTwoLevelSync:
         assert res1 is not None
         assert float(np.abs(np.asarray(res1[0])).max()) > 0
 
-    @pytest.mark.slow  # two full train-step compiles (~4.5s); the
-    # same parity is gated every CI run by bench --smoke's
-    # grad_sync_2level_parity key, and sync-level parity stays tier-1
-    # (test_fp32_two_level_is_exact_mean)
+    @pytest.mark.slow  # two full train-step compiles (~4.5s);
+    # sync-level parity stays tier-1 (test_fp32_two_level_is_exact_mean)
     def test_two_level_train_step_matches_gspmd_bitwise(self):
         """The acceptance check: on an emulated 2-slice mesh the
         two-level fp32 schedule is the same math as GSPMD's monolithic
@@ -890,28 +888,3 @@ class TestSamplerWeighting:
         # mismatched slice count resets to equal round-robin
         ElasticTrainer.apply_slice_throughput(fake, [1.0, 2.0, 3.0])
         assert sampler._weights is None
-
-
-# -- bench leg (slow: probe + three train-step compiles) ---------------------
-@pytest.mark.slow
-class TestBenchTopology:
-    def test_bench_leg_emits_keys_and_passes_gates(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_topology_mod",
-            os.path.join(
-                os.path.dirname(os.path.dirname(__file__)), "bench.py"
-            ),
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        results = {}
-        bench.run_topology_bench(jax, results, smoke=True)
-        assert "topology_error" not in results
-        assert results["link_ici_GBps"] >= results["link_dcn_GBps"]
-        assert results["link_ordering_ok"] is True
-        assert results["topology_probe_cache_hit"] is True
-        assert results["grad_sync_2level_wire_vs_flat"] < 1.0
-        assert results["grad_sync_2level_parity"] is True
-        assert results["dry_run_priced_from_link_model"] is True

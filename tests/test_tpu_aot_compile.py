@@ -21,6 +21,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from dlrover_tpu.common import trace_counts
+from trace_counted import CONV, FUSED, GDN, LANES, STREAM, added
+
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
 # (ops/__init__ re-exports it); the module has to be asked for by name
 fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
@@ -109,7 +112,7 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
             q, k, v, causal=True, force="pallas", layout="bhtd"
         )
 
-    before = fa.fused_tally(), fa.stream_tally()
+    before = trace_counts.snapshot()
     if direction == "fwd":
         compiled = _compile_for_chip(attend, *qkv)
     else:
@@ -155,10 +158,10 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
         1024: (sites, sites), 4096: (10 * sites, 16 * sites),
         8192: (36 * sites, 64 * sites), 65536: (2080 * sites, 4096 * sites),
     }[T]
-    assert fa.fused_tally() - before[0] == (
+    assert added(before, FUSED) == (
         (sites, 0, 10 * sites, 16 * sites) if fused else (0, 0, 0, 0)
     )
-    assert fa.stream_tally() - before[1] == (
+    assert added(before, STREAM) == (
         (0, 0, 0, 0) if fused else walked
     )
     if fused:
@@ -189,7 +192,7 @@ def test_streaming_attention_compiles_at_heads_of_256(
             q, k, v, causal=True, force="pallas", layout="bhtd"
         )
 
-    before = fa.stream_tally()
+    before = trace_counts.snapshot()
     if direction == "fwd":
         text = _compile_for_chip(attend, *qkv).as_text()
         want = ["flash_attn_fwd"]
@@ -210,7 +213,7 @@ def test_streaming_attention_compiles_at_heads_of_256(
     assert "flash_attn_fused" not in text
     sites = len(want)
     assert T // fa._BLOCK == 16
-    assert fa.stream_tally() - before == (sites, 0, 136 * sites, 256 * sites)
+    assert added(before, STREAM) == (sites, 0, 136 * sites, 256 * sites)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -237,7 +240,7 @@ def test_delta_rule_chunk_kernels_compile_at_the_cell(
     def rule(*a):
         return gated_delta.gated_delta_chunked(*a, C)
 
-    before = gated_delta.gdn_tally()
+    before = trace_counts.snapshot()
     if direction == "fwd":
         text = _compile_for_chip(rule, *args).as_text()
         want, steps = ["gdn_chunk_wy_fwd", "gdn_chunk_read_fwd"], T // C
@@ -254,7 +257,7 @@ def test_delta_rule_chunk_kernels_compile_at_the_cell(
     for kernel in want:
         assert kernel in text, kernel
     assert f"f32[{T // C},{B},{Hk},{Hv // Hk},{C},{C}]" not in text
-    assert gated_delta.gdn_tally() - before == (1, steps, 1)
+    assert added(before, GDN) == (1, steps, 1)
 
 
 CONV_SHAPES = {
@@ -286,7 +289,7 @@ def test_convolution_kernels_compile_at_the_cells(
 
     args = [sds(shape, jnp.bfloat16), sds((4, C))] + [sds((C,))] * bias
     assert conv_kernels.fits(*args[:2])
-    before = mamba2.conv_tally()
+    before = trace_counts.snapshot()
     if direction == "fwd":
         text = _compile_for_chip(mamba2.conv_silu, *args).as_text()
         want = ["conv_silu_fwd"]
@@ -306,7 +309,7 @@ def test_convolution_kernels_compile_at_the_cells(
     if direction == "fwd":  # (the test's own loss is a float32 fusion)
         assert f"f32[{shape[0]},{shape[1]},{C}]" not in text
     assert f"f32[{shape[0]},{shape[1] + 3},{C}]" not in text
-    assert mamba2.conv_tally() - before == (1, 1)
+    assert added(before, CONV) == (1, 1)
 
 
 CHANNEL_KERNELS = [
@@ -341,7 +344,7 @@ def test_vector_decay_chunk_kernels_compile_at_the_cell(
     def rule(*a):
         return gated_delta.gated_delta_chunked(*a, C)
 
-    before = gated_delta.gdn_tally()
+    before = trace_counts.snapshot()
     if direction == "fwd":
         text = _compile_for_chip(rule, *args).as_text()
         want, steps = CHANNEL_KERNELS[:2], T // C
@@ -357,7 +360,7 @@ def test_vector_decay_chunk_kernels_compile_at_the_cell(
     n = T // C
     assert f"f32[{n},{B},{H},{C},{C}]" not in text
     assert f"bf16[{n},{B},{H},{C // 16},{C},{d}]" not in text
-    assert gated_delta.gdn_tally() - before == (1, steps, 1)
+    assert added(before, GDN) == (1, steps, 1)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -374,7 +377,6 @@ def test_attention_of_192_and_128_compiles_as_the_program_calls_it(
 
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(transformer, "_score_lanes", transformer.ScoreLanes())
     B, H, T = 1, 32, 8192
     qkv = [
         jax.ShapeDtypeStruct((B, H, T, D), jnp.bfloat16, sharding=one_chip)
@@ -384,7 +386,7 @@ def test_attention_of_192_and_128_compiles_as_the_program_calls_it(
     def attend(q, k, v):
         return transformer._attention_of_two_widths(q, k, v, None)
 
-    before = fa.stream_tally()
+    before = trace_counts.snapshot()
     if direction == "fwd":
         compiled = _compile_for_chip(attend, *qkv)
         want = ["flash_attn_fwd"]
@@ -410,8 +412,8 @@ def test_attention_of_192_and_128_compiles_as_the_program_calls_it(
         assert kernel in text, kernel
     assert "flash_attn_fused" not in text
     sites = len(want)
-    assert fa.stream_tally() - before == (sites, 0, 136 * sites, 256 * sites)
-    assert transformer.score_lanes_tally()[:2] in ((256, 192), (512, 384))
+    assert added(before, STREAM) == (sites, 0, 136 * sites, 256 * sites)
+    assert added(before, LANES) in ((256, 192), (512, 384))
 
 
 # the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here

@@ -1,0 +1,394 @@
+"""``common/trace_counts``: the registry the modules count into while a
+program is traced, the trainer's one fold of it into ``PipelineStats``
+(``ElasticTrainer._first_build`` takes the snapshot, ``_fold_trace_counts``
+folds), and the two rules that keep the loop from knowing the kernels: every
+name counted is a stats field, and ``trainer.py`` imports no module that
+counts."""
+
+import ast
+import dataclasses
+import functools
+import importlib
+import os
+import sys
+import threading
+import types
+from collections import Counter
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dlrover_tpu.accel.profiler import PipelineStats
+from dlrover_tpu.common import trace_counts
+from dlrover_tpu.models.config import TransformerConfig
+from dlrover_tpu.models.train import TrainState, build_train_step
+from dlrover_tpu.models.transformer import init_params
+from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
+from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
+from trace_counted import CONV, FUSED, GDN, LANES, STREAM
+
+# `dlrover_tpu.ops.flash_attention` the attribute is the function
+fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FIELDS = {f.name for f in dataclasses.fields(PipelineStats)}
+
+
+@pytest.fixture
+def fresh(monkeypatch):
+    """The registry as a process finds it: nothing counted."""
+    monkeypatch.setattr(trace_counts, "_counts", Counter())
+
+
+# -- the registry -------------------------------------------------------------
+
+
+def test_a_name_never_counted_reads_zero(fresh):
+    assert trace_counts.snapshot() == {}
+    assert trace_counts.snapshot()["gdn_sites"] == 0
+    assert trace_counts.since(trace_counts.snapshot())["gdn_sites"] == 0
+
+
+def test_counts_add_up_and_a_snapshot_is_a_copy(fresh):
+    trace_counts.count("gdn_sites")
+    before = trace_counts.snapshot()
+    trace_counts.count("gdn_sites")
+    trace_counts.count("gdn_chunk_steps", 8)
+    trace_counts.count("conv_kernel_sites", True)  # a rule's answer
+    trace_counts.count("conv_kernel_sites", False)
+    assert before == {"gdn_sites": 1}
+    assert trace_counts.snapshot() == {
+        "gdn_sites": 2, "gdn_chunk_steps": 8, "conv_kernel_sites": 1,
+    }
+
+
+def test_since_names_everything_seen_and_what_each_added(fresh):
+    trace_counts.count("conv_sites", 2)
+    before = trace_counts.snapshot()
+    trace_counts.count("gdn_sites", 3)
+    # a name seen and not added to is there, as 0: a step that traced
+    # none of it resets the field
+    assert trace_counts.since(before) == {"conv_sites": 0, "gdn_sites": 3}
+    assert dict(trace_counts.since(trace_counts.snapshot())) == {
+        "conv_sites": 0, "gdn_sites": 0,
+    }
+
+
+def test_counting_from_many_threads_loses_nothing(fresh):
+    """A speculative compile traces on a thread of its own beside the
+    loop's: more threads than cores, switching every few bytecodes."""
+    threads, each = 4 * (os.cpu_count() or 2), 500
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        workers = [
+            threading.Thread(target=lambda: [
+                trace_counts.count("gdn_chunk_steps", 2) for _ in range(each)
+            ])
+            for _ in range(threads)
+        ]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(w.is_alive() for w in workers)
+    assert trace_counts.snapshot()["gdn_chunk_steps"] == 2 * each * threads
+
+
+def test_the_running_totals_are_stats_fields():
+    assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
+    assert set(GDN + CONV + LANES) <= FIELDS - set(trace_counts.RUNNING_TOTALS)
+
+
+# -- the trainer's fold -------------------------------------------------------
+
+
+def _trainer():
+    return types.SimpleNamespace(
+        pipeline_stats=PipelineStats(), _counts_before_step=None,
+        _built=set(), _builds=types.SimpleNamespace(build=lambda what: what),
+    )
+
+
+def _site(names, n, kernels=1):
+    """Such a site of the attention kernels, counted as they count it."""
+    return functools.partial(fa._count_site, names, n, kernels)
+
+
+# a case is a run of moves on one trainer: {name: n} is counted (a trace),
+# as is a site of the attention kernels,
+# a string is ``_first_build`` of that program, a pair is the fold: the
+# line's clause and the fields it leaves
+FOLDS = {
+    # the fused attention kernels' counts are the process's running
+    # total; the line says what was lowered since the line before
+    "fused_running_total": [
+        ("", {}),  # nothing lowered: a model outside the family
+        # a step program of twelve layers at T = 1024, fwd and bwd
+        *[_site(FUSED, 4)] * 24,
+        (
+            "; traced: attn_tri_sites +24, attn_tiles_walked +240, "
+            "attn_tiles_square +384",
+            dict(zip(FUSED, (24, 0, 240, 384))),
+        ),
+        ("", {}),  # nothing new since that line
+        # its twin, and one ring hop (traced offsets: the square body)
+        *[_site(FUSED, 4)] * 24,
+        _site(FUSED, 0),
+        (
+            "; traced: attn_tri_sites +24, attn_square_sites +1, "
+            "attn_tiles_walked +240, attn_tiles_square +384",
+            dict(zip(FUSED, (48, 1, 480, 768))),
+        ),
+    ],
+    # two layers at T = 4096 in blocks of 1024, a forward and a one-pass
+    # backward each: ten of sixteen blocks a kernel
+    "stream_one_pass": [
+        *[_site(STREAM, 4)] * 4,
+        (
+            "; traced: attn_stream_tri_sites +4, "
+            "attn_stream_blocks_walked +40, attn_stream_blocks_rect +64",
+            dict(zip(STREAM + FUSED, (4, 0, 40, 64, 0, 0, 0, 0))),
+        ),
+        ("", {}),
+        # a fused site lowered later is said before the streaming one
+        _site(FUSED, 4), _site(STREAM, 2),
+        (
+            "; traced: attn_tri_sites +1, attn_tiles_walked +10, "
+            "attn_tiles_square +16, attn_stream_tri_sites +1, "
+            "attn_stream_blocks_walked +3, attn_stream_blocks_rect +4",
+            {"attn_tri_sites": 1, "attn_stream_tri_sites": 5},
+        ),
+    ],
+    # one layer at T = 8192, its backward split in two kernels
+    "stream_split": [
+        _site(STREAM, 8), _site(STREAM, 8, kernels=2),
+        (
+            "; traced: attn_stream_tri_sites +3, "
+            "attn_stream_blocks_walked +108, attn_stream_blocks_rect +192",
+            dict(zip(STREAM, (3, 0, 108, 192))),
+        ),
+    ],
+    # a ring hop: traced offsets, the rectangular grid, three kernels
+    "stream_rectangle": [
+        _site(STREAM, 0), _site(STREAM, 0, kernels=2),
+        (
+            "; traced: attn_stream_rect_sites +3",
+            dict(zip(STREAM, (0, 3, 0, 0))),
+        ),
+    ],
+    # every other count is of the train step built since: the worker's
+    # reference check (a forward pass before any step) is not in it, a
+    # twin that came whole out of a cache of executables traced nothing
+    # and moves nothing, and a step is said once
+    "delta_rule_plain": [
+        dict(zip(GDN, (2, 8, 0))),
+        ("", {"gdn_sites": 0}),  # no step built
+        "eval",
+        ("", {"gdn_sites": 0}),  # and an evaluation is no step
+        "step_donating",
+        dict(zip(GDN, (2, 16, 0))),
+        (
+            "; traced: gdn_sites =2, gdn_chunk_steps =16",
+            dict(zip(GDN, (2, 16, 0))),
+        ),
+        ("", {}),  # said once
+        "step_safe",
+        ("", dict(zip(GDN, (2, 16, 0)))),
+    ],
+    # a step whose mixers have heads of whole lane tiles
+    "delta_rule_in_the_kernels": [
+        "step_donating",
+        dict(zip(GDN, (1, 8, 1))),
+        (
+            "; traced: gdn_sites =1, gdn_chunk_steps =8, gdn_kernel_sites =1",
+            dict(zip(GDN, (1, 8, 1))),
+        ),
+    ],
+    # a layer traced twice under ``jax.checkpoint`` counts twice in both,
+    # and without recomputation every mixer is one site
+    "convolution_under_checkpoint": [
+        dict(zip(CONV, (2, 2))),  # the reference check
+        "step_donating",
+        dict(zip(CONV, (4, 4))),
+        ("; traced: conv_sites =4, conv_kernel_sites =4",
+         dict(zip(CONV, (4, 4)))),
+        "step_donating",
+        dict(zip(CONV, (2, 2))),
+        ("; traced: conv_sites =2, conv_kernel_sites =2",
+         dict(zip(CONV, (2, 2)))),
+    ],
+    # a latent attention's 24-wide scores called 128 wide beside three
+    # KDA mixers, then the same under ``remat``: the fields hold the step
+    # traced last
+    "score_lanes_beside_the_delta_rule": [
+        dict(zip(GDN + LANES, (3, 12, 0, 128, 24))),
+        "step_donating",
+        dict(zip(GDN + LANES, (3, 24, 0, 128, 24))),
+        (
+            "; traced: gdn_sites =3, gdn_chunk_steps =24, "
+            "attn_score_lanes =128, attn_score_lanes_used =24",
+            dict(zip(GDN + LANES, (3, 24, 0, 128, 24))),
+        ),
+        "step_donating",
+        dict(zip(GDN + LANES, (6, 36, 0, 128, 24))),
+        (
+            "; traced: gdn_sites =6, gdn_chunk_steps =36, "
+            "attn_score_lanes =128, attn_score_lanes_used =24",
+            dict(zip(GDN + LANES, (6, 36, 0, 128, 24))),
+        ),
+    ],
+    # a resize onto a mesh the kernels refuse: the step built there is
+    # traced anew, and what it did not count reads 0, not the old step's
+    "a_rebuilt_step_resets_what_it_did_not_count": [
+        "step_donating",
+        dict(zip(CONV, (2, 2))),
+        ("; traced: conv_sites =2, conv_kernel_sites =2",
+         dict(zip(CONV, (2, 2)))),
+        "step_donating",
+        {"conv_sites": 2},
+        ("; traced: conv_sites =2", dict(zip(CONV, (2, 0)))),
+    ],
+    # both scopes on one line: the kernels' totals first
+    "both_scopes_on_one_line": [
+        "step_safe",
+        _site(STREAM, 8),
+        {"attn_score_lanes": 128, "attn_score_lanes_used": 128},
+        (
+            "; traced: attn_stream_tri_sites +1, "
+            "attn_stream_blocks_walked +36, attn_stream_blocks_rect +64, "
+            "attn_score_lanes =128, attn_score_lanes_used =128",
+            {"attn_stream_tri_sites": 1, "attn_score_lanes": 128},
+        ),
+    ],
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLDS))
+def test_the_trainer_folds_the_registry_into_its_stats_and_its_line(
+    case, fresh
+):
+    trainer = _trainer()
+    stats = trainer.pipeline_stats
+    for move in FOLDS[case]:
+        if callable(move):
+            move()
+        elif isinstance(move, dict):
+            for name, n in move.items():
+                trace_counts.count(name, n)
+        elif isinstance(move, str):
+            assert ElasticTrainer._first_build(trainer, move) == move
+            assert (trainer._counts_before_step is None) == (
+                not move.startswith("step_")
+            )
+            trainer._built.clear()  # the next build of it is a first too
+        else:
+            said, fields = move
+            assert ElasticTrainer._fold_trace_counts(trainer) == said
+            assert {k: getattr(stats, k) for k in fields} == fields
+            assert trainer._counts_before_step is None
+    assert set(trace_counts.snapshot()) <= set(stats.as_dict())
+
+
+# -- every name counted is a field, and the loop knows no kernel ------------
+
+_SMALL = dict(
+    vocab_size=64, model_dim=32, num_heads=2, mlp_dim=32, max_seq_len=64,
+    dtype="float32", param_dtype="float32",
+)
+_MIXERS = dict(
+    ssm_heads=4, ssm_head_dim=16, ssm_state=16, ssm_groups=2, ssm_chunk=16,
+    gdn_key_heads=2, gdn_value_heads=2, gdn_key_dim=16, gdn_value_dim=16,
+    gdn_chunk=16, positions="none", rmsnorm=True, tie_embeddings=False,
+    dense_mlp_dim=32,
+)
+# one toy a family of the benchmark's configurations, and the families of
+# names a traced train step of it counts under
+TOYS = {
+    "dense": (TransformerConfig(num_layers=2, **_SMALL), (FUSED, LANES)),
+    "dense_remat": (
+        TransformerConfig(num_layers=2, remat=True, **_SMALL), (FUSED, LANES)
+    ),
+    "grouped_queries": (
+        TransformerConfig(num_layers=1, num_kv_heads=1, **_SMALL),
+        (STREAM, LANES),
+    ),
+    "mamba2_and_delta_rule": (
+        TransformerConfig(
+            num_layers=3, layer_pattern="MG*", **_SMALL, **_MIXERS
+        ),
+        (GDN, CONV, FUSED, LANES),
+    ),
+    "vector_decay_and_latent_attention": (
+        TransformerConfig(
+            num_layers=2, layer_pattern="G*", gdn_decay="channel",
+            gdn_decay_bound=-5.0,
+            gdn_gate="head_sigmoid", attn_kind="latent", kv_latent_dim=16,
+            qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope=True,
+            **_SMALL, **dict(_MIXERS, positions=""),
+        ),
+        (GDN, CONV, FUSED, LANES),
+    ),
+}
+
+
+@pytest.mark.parametrize("toy", sorted(TOYS))
+def test_every_name_a_traced_step_counts_is_a_stats_field(toy, monkeypatch):
+    """Whatever a module counts lands in ``PipelineStats`` by its name
+    alone: a name that is no field would fail the trainer's ``setattr``
+    silently into an attribute nothing reads."""
+    # the attention kernels' own path, interpreted: where their sites count
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret_default", lambda: True)
+    cfg, families = TOYS[toy]
+    tx = build_optimizer("adamw", lr=1e-3)
+    mesh = build_mesh(MeshConfig(), jax.devices()[:1])
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(lambda p: TrainState(
+        step=jnp.zeros((), jnp.int32), params=p, opt_state=tx.init(p),
+    ), params)
+    x = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    trainer = _trainer()
+    before = trace_counts.snapshot()
+    ElasticTrainer._first_build(trainer, "step_donating")
+    build_train_step(cfg, mesh, tx, donate=False).lower(state, x, x)
+    step = trace_counts.since(before)
+    assert set(trace_counts.snapshot()) <= FIELDS
+    assert {name for name, n in step.items() if n} <= set(sum(families, ()))
+    for family in families:
+        assert any(step[name] for name in family), family
+    said = ElasticTrainer._fold_trace_counts(trainer)
+    stats = trainer.pipeline_stats
+    for name, n in step.items():
+        if name not in trace_counts.RUNNING_TOTALS:
+            assert getattr(stats, name) == n, name
+            assert (f"{name} ={n}" in said) == bool(n)
+
+
+KERNEL_MODULES = {
+    "dlrover_tpu.ops.flash_attention", "dlrover_tpu.ops.gated_delta",
+    "dlrover_tpu.ops.gated_delta_kernels", "dlrover_tpu.ops.mamba2",
+    "dlrover_tpu.ops.conv_kernels", "dlrover_tpu.models.transformer",
+}
+
+
+def test_the_loop_imports_no_module_that_counts():
+    """``trainer/elastic/trainer.py`` names no kernel module and nothing
+    of ``models.transformer``, at the top or inside a function: a new
+    count is one ``count(...)`` and one field, never an edit there."""
+    path = os.path.join(
+        ROOT, "dlrover_tpu", "trainer", "elastic", "trainer.py"
+    )
+    with open(path) as f:
+        tree = ast.parse(f.read(), filename=path)
+    named = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            named |= {alias.name for alias in node.names}
+        elif isinstance(node, ast.ImportFrom) and node.module:
+            named.add(node.module)
+            named |= {f"{node.module}.{a.name}" for a in node.names}
+    assert "dlrover_tpu.common.trace_counts" in named  # the walk sees them
+    assert not named & KERNEL_MODULES

@@ -21,7 +21,7 @@ from dlrover_tpu.parallel.transfer_sched import (
 @pytest.fixture(autouse=True)
 def _isolated_calibration(monkeypatch, tmp_path):
     """Pricing must not depend on whatever arbiter calibration an
-    earlier test (or a bench run on this machine) left in the real
+    earlier test (or another run on this machine) left in the real
     topology cache: point the cache at a fresh dir and drop any
     in-process calibration for every test in this file."""
     from dlrover_tpu.parallel import transfer_sched

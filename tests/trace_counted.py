@@ -1,0 +1,18 @@
+"""What the tests read of ``common/trace_counts``: the counts added since
+a snapshot, as a tuple in a family's order."""
+
+import importlib
+
+from dlrover_tpu.common import trace_counts
+
+# `dlrover_tpu.ops.flash_attention` the attribute is the function
+_fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+FUSED, STREAM = _fa._FUSED, _fa._STREAM
+GDN = ("gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites")
+CONV = ("conv_sites", "conv_kernel_sites")
+LANES = ("attn_score_lanes", "attn_score_lanes_used")
+
+
+def added(before, names):
+    now = trace_counts.since(before)
+    return tuple(now[name] for name in names)

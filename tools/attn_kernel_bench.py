@@ -36,6 +36,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from dlrover_tpu.common import trace_counts
+
 # the package re-exports the function under the module's name
 fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
 
@@ -134,15 +136,14 @@ def bench(shape, variant):
         jax.random.normal(key, (B, heads, T, D), jnp.bfloat16)
         for key, heads in zip(keys, (H, Hkv, Hkv))
     )
-    before = fa.fused_tally(), fa.stream_tally()
+    before = trace_counts.snapshot()
     t0 = time.perf_counter()
     fwd = _time(jax.jit(chain), q, k, v)
     both = _time(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, k, v)
     return {
         "shape": list(shape), "variant": variant,
         "fwd_ms": round(fwd, 4), "fwd_bwd_ms": round(both, 4),
-        "tally": list(fa.fused_tally() - before[0]),
-        "stream_tally": list(fa.stream_tally() - before[1]),
+        "counts": dict(+trace_counts.since(before)),
         "wall_s": round(time.perf_counter() - t0, 1),
     }
 

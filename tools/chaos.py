@@ -51,10 +51,9 @@ Usage:
 
 Exit codes: 0 = every gate passed; 1 = a gate failed; 2 = usage.
 
-``bench.py --smoke`` runs ``eviction_during_save`` + ``sigkill_mid_step``
-through :func:`run_scenario` as a nonzero-exit CI gate; the full matrix
-lives in ``tests/test_chaos_harness.py`` (tier-1 runs the fast
-scenarios, the subprocess legs are ``slow``).
+The full matrix lives in ``tests/test_chaos_harness.py`` (tier-1 runs
+the fast scenarios through :func:`run_scenario`, the trainer-bearing and
+subprocess legs are ``slow``).
 """
 
 from __future__ import annotations
@@ -89,7 +88,7 @@ KILL_STEP = 7  # node.preempt evaluations are step boundaries (1-based)
 
 
 # ---------------------------------------------------------------------------
-# shared tiny-trainer scaffolding (the bench's forensics-leg pattern)
+# shared tiny-trainer scaffolding
 # ---------------------------------------------------------------------------
 class _Tokens:
     def __init__(self, n=2048, seq=32, vocab=256, seed=11):
@@ -653,8 +652,8 @@ def _sdc_cleanup():
 def sdc_convict_only(seed: int, workdir: str) -> Dict:
     """Light leg (no golden / no resume): arm ``device.sdc`` against
     lane ``seed % 4`` and gate that the audit convicts EXACTLY that
-    lane. The bench runs this across extra seeds as the
-    innocent-conviction sweep."""
+    lane. ``tests/test_sdc.py::TestSdcSoak`` runs this across extra seeds
+    as the innocent-conviction sweep."""
     from dlrover_tpu.common import faults
 
     faults.reset()

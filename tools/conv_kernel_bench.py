@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.ops import conv_kernels as kernels
 from dlrover_tpu.ops import mamba2
 
@@ -121,12 +122,12 @@ def main(argv):
     held = {}
     for variant in variants:
         _select(variant)
-        before = mamba2.conv_tally()
+        before = trace_counts.snapshot()
         fwd, both = _programs(bias)
         t0 = time.perf_counter()
         out["variants"][variant] = {
             "fwd": _time(fwd, *args), "fwd_bwd": _time(both, *args),
-            "tally": list(mamba2.conv_tally() - before),
+            "counts": dict(+trace_counts.since(before)),
             "wall_s": round(time.perf_counter() - t0, 1),
         }
         if variant in ("kernel", "plain"):

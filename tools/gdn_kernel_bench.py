@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dlrover_tpu.common import trace_counts
 from dlrover_tpu.ops import gated_delta
 from dlrover_tpu.ops import gated_delta_kernels as kernels
 
@@ -198,12 +199,12 @@ def main(argv):
             print(json.dumps({"parts_ms": out["parts_ms"]}), flush=True)
             continue
         _select(variant)
-        before = gated_delta.gdn_tally()
+        before = trace_counts.snapshot()
         fwd, both = _programs(C)
         t0 = time.perf_counter()
         out["variants"][variant] = {
             "fwd": _time(fwd, *args), "fwd_bwd": _time(both, *args),
-            "tally": list(gated_delta.gdn_tally() - before),
+            "counts": dict(+trace_counts.since(before)),
             "wall_s": round(time.perf_counter() - t0, 1),
         }
         if variant in ("kernel", "plain"):

@@ -7,8 +7,7 @@ paths (PR 4), non-idempotent RPCs silently retried, the hand-synced
 nobody exercises, and rename-without-fsync "durable" commits (PR 11) —
 are mechanized here as repo-specific static checks. Pure `ast`, no
 third-party deps, sub-second over the whole tree, so the suite runs as
-a tier-1 test, a pre-PR CLI (`python -m tools.graftlint`) and a
-`bench.py --smoke` gate.
+a tier-1 test and a pre-PR CLI (`python -m tools.graftlint`).
 
 Deliberate violations are suppressed in place, and a suppression
 REQUIRES a reason::

@@ -3,7 +3,7 @@
 beside ``tools/tier1_budget.py``::
 
     python -m tools.graftlint                 # whole tree, text output
-    python -m tools.graftlint --json          # machine-readable (bench gate)
+    python -m tools.graftlint --json          # machine-readable
     python -m tools.graftlint --changed-only  # pre-commit: git-diff filter
     python -m tools.graftlint --select lock-discipline,span-leak
     python -m tools.graftlint dlrover_tpu/ckpt   # a subtree

@@ -36,8 +36,8 @@ CLI::
     python tools/rpc_load.py --nodes 1000 --gate-rpcs 1.25 \
         --gate-p99-ms 200 --gate-delta-ratio 0.4          # CI gate
 
-Exit status is nonzero when any ``--gate-*`` bound is violated (the
-``bench.py --smoke`` control-plane leg drives exactly this).
+Exit status is nonzero when any ``--gate-*`` bound is violated
+(``tests/test_control_plane.py`` drives ``run_load`` at small fleets).
 """
 
 from __future__ import annotations

@@ -140,6 +140,15 @@ class PipelineStats:
     attn_stream_rect_sites: int = 0
     attn_stream_blocks_walked: int = 0
     attn_stream_blocks_rect: int = 0
+    # the blocks a head walks at the attention kernels that were given a
+    # window (``flash_attention(..., window=W)``; counted as the streaming
+    # family counts: a forward is one kernel, a backward one or two) in
+    # the train step program this process traced last, and the blocks at
+    # or under the diagonal at them: walked is the band's count where the
+    # band ``i - wb <= j <= i`` is walked, and equals the other where the
+    # window was only a mask; 0 / 0 for a model without a window
+    attn_window_blocks_walked: int = 0
+    attn_window_blocks_causal: int = 0
     # Gated DeltaNet mixers (ops/gated_delta.py) in the train step
     # program this process traced last, and the sequential chunk-state
     # steps one training step runs through them, forward and backward:

@@ -15,8 +15,13 @@ from typing import Optional, Tuple
 # layer_pattern's alphabet (nemotron_h's, and "G") -> the key of the
 # layer's one mixer in the parameter tree: Mamba-2, the gated delta rule
 # (Gated DeltaNet's, or with ``gdn_decay`` "channel" Kimi Delta
-# Attention's), attention, experts, the dense feed-forward
-LAYER_KINDS = {"M": "ssm", "G": "gdn", "*": "attn", "E": "moe", "-": "mlp"}
+# Attention's), attention, experts, the dense feed-forward, and "W":
+# attention through a window of ``attn_window`` keys (the parameters of
+# "*", so its key)
+LAYER_KINDS = {
+    "M": "ssm", "G": "gdn", "*": "attn", "E": "moe", "-": "mlp",
+    "W": "attn",
+}
 
 
 @dataclass(frozen=True)
@@ -35,15 +40,30 @@ class TransformerConfig:
     # the ``nemotron_h`` configs (``hybrid_override_pattern``): "M" a
     # Mamba-2 layer, "*" an attention layer, "E" an expert layer, and
     # "-" a dense feed-forward layer of ``dense_mlp_dim``, and "G" a
-    # Gated DeltaNet layer; each layer is ONE mixer, ``x +
-    # mixer(norm(x))``, so a block of mixer then experts is two entries
-    # ("GEGEGE*E": one period of ``qwen3_next``). "" = every layer is the
-    # attention + FFN block (``moe_every`` places the experts).
+    # Gated DeltaNet layer, "W" an attention layer whose queries see
+    # themselves and the ``attn_window - 1`` keys before them; each layer
+    # is ONE mixer, ``x + mixer(norm(x))``, so a block of mixer then
+    # experts is two entries ("GEGEGE*E": one period of ``qwen3_next``;
+    # "WEWE*EWE": one of ``afmoe``). "" = every layer is the attention +
+    # FFN block (``moe_every`` places the experts).
     layer_pattern: str = ""
+    # keys a query of a "W" layer sees, itself among them (a "*" layer
+    # sees every key before it); 0 = the pattern has no "W"
+    attn_window: int = 0
+    # a norm on every mixer's output before the residual add, ``x +
+    # norm_out(mixer(norm(x)))`` (``layer_pattern`` models; "out_norm" in
+    # a layer's parameters)
+    mixer_out_norm: bool = False
+    # the token embedding is multiplied by ``sqrt(model_dim)`` as it
+    # enters the residual stream (and nothing else is: an untied head
+    # reads its own table)
+    embed_scale: bool = False
     # architecture switches
     rope: bool = False  # False => learned positional embeddings
     # "" => what ``rope`` says; "none" => no positions anywhere (the
-    # attention layers of a Mamba-2 hybrid: the scan carries the order)
+    # attention layers of a Mamba-2 hybrid: the scan carries the order);
+    # "window" => by the kind of layer: rotary in the "W" layers, none in
+    # the "*" layers, whose keys' order the window layers below carry
     positions: str = ""
     rope_theta: float = 10000.0
     # the leading dims of each head that are rotated (pairs ``(i, i +
@@ -213,8 +233,36 @@ class TransformerConfig:
                     "scan_layers needs homogeneous blocks; a "
                     "layer_pattern makes them differ"
                 )
-        if self.positions not in ("", "none"):
+        if self.positions not in ("", "none", "window"):
             raise ValueError(f"unknown positions {self.positions!r}")
+        windowed = "W" in self.layer_pattern
+        if isinstance(self.attn_window, bool) or (
+            self.attn_window < 1 if windowed else self.attn_window
+        ):
+            raise ValueError(
+                f"attn_window {self.attn_window!r} is the window of the "
+                f"\"W\" layers of layer_pattern {self.layer_pattern!r}: "
+                "1 or more keys where there are such layers, else 0"
+            )
+        if windowed and self.attn_kind:
+            raise ValueError(
+                "a window is of the projected attention: attn_kind "
+                f"{self.attn_kind!r} knows none"
+            )
+        if self.positions == "window" and not windowed:
+            raise ValueError(
+                "positions \"window\" are the window layers' rotary "
+                "positions: layer_pattern has no \"W\""
+            )
+        if not isinstance(self.embed_scale, bool):
+            raise ValueError(
+                f"embed_scale is on or off, not {self.embed_scale!r}"
+            )
+        if self.mixer_out_norm and not self.layer_pattern:
+            raise ValueError(
+                "mixer_out_norm is of the one-mixer layers of a "
+                "layer_pattern"
+            )
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
         for name, kinds in (
@@ -321,8 +369,16 @@ class TransformerConfig:
 
     @property
     def position_kind(self) -> str:
-        """"rope", "learned" or "none"."""
+        """"rope", "learned", "none", or "window": by the kind of layer
+        (``layer_positions``)."""
         return self.positions or ("rope" if self.rope else "learned")
+
+    def layer_positions(self, kind: str) -> str:
+        """``position_kind`` of an attention layer of ``kind`` ("*" or
+        "W"; "" the attention of the attention + FFN block)."""
+        if self.positions == "window":
+            return "rope" if kind == "W" else "none"
+        return self.position_kind
 
     @property
     def held_experts(self) -> Tuple[int, int]:

@@ -21,6 +21,7 @@ import optax
 
 from dlrover_tpu.models.config import TransformerConfig
 from dlrover_tpu.models.transformer import (
+    check_window_mesh,
     forward,
     init_params,
     logical_axes,
@@ -318,6 +319,7 @@ def build_train_step(
     once-per-mesh log naming the axes. ``batch_pad`` is the
     micro-batch rebalance (zero-weight pad rows; see
     ``pad_row_weights``)."""
+    check_window_mesh(cfg, mesh)
     # the state leaves the step in the layout it is initialized and
     # restored in. Left to GSPMD, the outputs of a sharded mesh drift
     # (replicated 1-D params and their moments came back sharded over

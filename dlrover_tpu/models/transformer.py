@@ -17,6 +17,7 @@ TPU-first design notes:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Dict, Optional, Tuple
 
@@ -154,16 +155,25 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         "M": lambda: init_mamba2_params(next(keys), cfg, pd),
         "G": lambda: init_gated_delta_params(next(keys), cfg, pd),
         "*": attention,
+        "W": attention,
         "E": experts,
         "-": lambda: dense_mlp(cfg.dense_mlp_dim or f),
     }
-    for kind in cfg.layer_pattern:
-        # one mixer a layer behind one norm
-        layer = {"norm": {"scale": norm_scale((d,))}}
+
+    def layer_norm():
+        norm = {"scale": norm_scale((d,))}
         if not cfg.rmsnorm:
-            layer["norm"]["bias"] = jnp.zeros((d,), pd)
+            norm["bias"] = jnp.zeros((d,), pd)
+        return norm
+
+    for kind in cfg.layer_pattern:
+        # one mixer a layer behind one norm (and before one, where
+        # ``mixer_out_norm``)
+        layer = {"norm": layer_norm()}
+        if cfg.mixer_out_norm:
+            layer["out_norm"] = layer_norm()
         layer[LAYER_KINDS[kind]] = mixers[kind]()
-        if kind == "*" and cfg.qk_norm:
+        if kind in "*W" and cfg.qk_norm:
             layer.update(qk_norms())
         params["layers"].append(layer)
 
@@ -282,14 +292,21 @@ def logical_axes(cfg: TransformerConfig) -> Params:
     mixers = {
         "M": mamba2_logical_axes,
         "G": lambda: gated_delta_logical_axes(cfg),
-        "*": attention, "E": experts, "-": dense_mlp,
+        "*": attention, "W": attention, "E": experts, "-": dense_mlp,
     }
-    for kind in cfg.layer_pattern:
-        layer = {"norm": {"scale": ("norm",)}}
+
+    def layer_norm():
+        norm = {"scale": ("norm",)}
         if not cfg.rmsnorm:
-            layer["norm"]["bias"] = ("norm",)
+            norm["bias"] = ("norm",)
+        return norm
+
+    for kind in cfg.layer_pattern:
+        layer = {"norm": layer_norm()}
+        if cfg.mixer_out_norm:
+            layer["out_norm"] = layer_norm()
         layer[LAYER_KINDS[kind]] = mixers[kind]()
-        if kind == "*" and cfg.qk_norm:
+        if kind in "*W" and cfg.qk_norm:
             layer.update(qk_norms())
         axes["layers"].append(layer)
 
@@ -403,8 +420,10 @@ def _count_score_lanes(called: int, used: int):
 
 
 def _causal_attention(q, k, v, mesh=None, layout: str = "bthd",
-                      sm_scale: Optional[float] = None):
-    """Single-shard causal attention, [B,T,H,D] or [B,H,T,D].
+                      sm_scale: Optional[float] = None,
+                      window: Optional[int] = None):
+    """Single-shard causal attention, [B,T,H,D] or [B,H,T,D]; with a
+    ``window`` a query sees itself and the ``window - 1`` keys before it.
 
     Dispatches to the Pallas flash-attention kernel on TPU (fused
     single-program kernels at short seq, block-tiled streaming beyond)
@@ -431,9 +450,15 @@ def _causal_attention(q, k, v, mesh=None, layout: str = "bthd",
     from dlrover_tpu.ops.flash_attention import flash_attention
 
     def attend(q, k, v):
-        return flash_attention(
-            q, k, v, causal=True, layout=layout, sm_scale=sm_scale
+        scope = (
+            jax.named_scope("scope/layer/attn/window") if window
+            else contextlib.nullcontext()
         )
+        with scope:
+            return flash_attention(
+                q, k, v, causal=True, layout=layout, sm_scale=sm_scale,
+                window=window,
+            )
 
     def spec(batch, heads):
         if layout == "bhtd":
@@ -478,15 +503,42 @@ def _causal_attention(q, k, v, mesh=None, layout: str = "bthd",
     )(q, k, v)
 
 
+def check_window_mesh(cfg: TransformerConfig, mesh):
+    """Refuse a model with window layers on a mesh that splits the
+    sequence: ring and Ulysses attention know no window and would run
+    those layers as full attention (``build_train_step`` asks when a step
+    is built, a window layer when it is traced)."""
+    if cfg.attn_window and mesh is not None and mesh.shape.get("sp", 1) > 1:
+        raise NotImplementedError(
+            f"the window layers (attn_window {cfg.attn_window}) know no "
+            f"sequence-parallel scheme: under sp = {mesh.shape['sp']} "
+            f"{cfg.sp_scheme} attention would run them as full attention"
+        )
+
+
+def _residual(x, out, layer, cfg: TransformerConfig):
+    """``x + out``, the mixer's output through the layer's output norm
+    first where it has one (``cfg.mixer_out_norm``)."""
+    if "out_norm" in layer:
+        with jax.named_scope("scope/layer/out_norm"):
+            out = _norm(out, layer["out_norm"], cfg)
+    return x + out
+
+
 @jax.named_scope("scope/layer/attn")
 def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
-                     norm: str = "attn_norm"):
+                     norm: str = "attn_norm", kind: str = ""):
     """``x + attention(norm(x))``; ``norm`` names the layer's norm (a
-    one-mixer layer of a ``layer_pattern`` has the one, "norm")."""
+    one-mixer layer of a ``layer_pattern`` has the one, "norm") and
+    ``kind`` its letter there: a "W" layer attends through
+    ``cfg.attn_window``, and ``cfg.layer_positions`` may differ by it."""
     if cfg.attn_kind == "latent":
         return _latent_attention(x, layer, cfg, mesh, positions, norm)
     h = _norm(x, layer[norm], cfg)
     sp = mesh is not None and mesh.shape.get("sp", 1) > 1
+    window = cfg.attn_window if kind == "W" else None
+    if window:
+        check_window_mesh(cfg, mesh)
     _count_score_lanes(cfg.head_dim, cfg.head_dim)
     # single-shard path: kernel-native [B,H,T,D] straight from the
     # projection einsums — no relayout transposes around the attention
@@ -501,7 +553,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
     if cfg.qk_norm:
         q = _qk_norm(q, layer["q_norm"], cfg, layout)
         k = _qk_norm(k, layer["k_norm"], cfg, layout)
-    if cfg.position_kind == "rope":
+    if cfg.layer_positions(kind) == "rope":
         q = _rope(q, positions, cfg.rope_theta, layout, cfg.rope_dim)
         k = _rope(k, positions, cfg.rope_theta, layout, cfg.rope_dim)
     if cfg.mup_attn_scale is not None:
@@ -509,7 +561,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
         # 1/sqrt(d) into q, so flash and ring paths need no new plumbing
         q = q * (cfg.mup_attn_scale * cfg.head_dim**0.5)
     if not sp:
-        o = _causal_attention(q, k, v, mesh, layout="bhtd")
+        o = _causal_attention(q, k, v, mesh, layout="bhtd", window=window)
     elif cfg.sp_scheme == "ulysses":
         from dlrover_tpu.parallel.ulysses import ulysses_self_attention
 
@@ -530,7 +582,10 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
                 * jax.nn.sigmoid(gate.astype(jnp.float32))
             ).astype(o.dtype)
     out = "bthk,hkd->btd" if sp else "bhtk,hkd->btd"
-    return x + jnp.einsum(out, o, layer["attn"]["wo"].astype(o.dtype))
+    return _residual(
+        x, jnp.einsum(out, o, layer["attn"]["wo"].astype(o.dtype)), layer,
+        cfg,
+    )
 
 
 _LANES = 128
@@ -592,19 +647,28 @@ def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
             _rope(t[..., nope:], positions, cfg.rope_theta, "bhtd"),
         ], axis=-1) for t in (q, k))
     o = _attention_of_two_widths(q, k, v, mesh)
-    return x + jnp.einsum("bhtk,hkd->btd", o, a["wo"].astype(o.dtype))
+    return _residual(
+        x, jnp.einsum("bhtk,hkd->btd", o, a["wo"].astype(o.dtype)), layer,
+        cfg,
+    )
 
 
 @jax.named_scope("scope/layer/ssm")
 def _ssm_block(x, layer, cfg: TransformerConfig, mesh):
     h = _norm(x, layer["norm"], cfg)
-    return x + mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg), mesh)
+    return _residual(
+        x, mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg), mesh), layer,
+        cfg,
+    )
 
 
 @jax.named_scope("scope/layer/gdn")
 def _gdn_block(x, layer, cfg: TransformerConfig, mesh):
     h = _norm(x, layer["norm"], cfg)
-    return x + gated_delta_mixer(h, layer["gdn"], cfg, _norm_eps(cfg), mesh)
+    return _residual(
+        x, gated_delta_mixer(h, layer["gdn"], cfg, _norm_eps(cfg), mesh),
+        layer, cfg,
+    )
 
 
 def _zero_aux(cfg: Optional[TransformerConfig] = None):
@@ -658,7 +722,7 @@ def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None,
                 **kw,
             )
             out = out.reshape(B, T, d)
-        return x + out, aux
+        return _residual(x, out, layer, cfg), aux
     mlp = layer["mlp"]
     if cfg.int8_mlp:
         from dlrover_tpu.ops.int8_matmul import int8_einsum_btd_df as mm
@@ -676,7 +740,7 @@ def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None,
     out = mm(z, mlp["w_down"])
     if not cfg.swiglu:
         out = out + mlp["b_down"].astype(h.dtype)
-    return x + out, _zero_aux(cfg)
+    return _residual(x, out, layer, cfg), _zero_aux(cfg)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -727,6 +791,8 @@ def embed_tokens(
     dt = _dtype(cfg)
     T = tokens.shape[-1]
     x = _embed_lookup(params["embed"]["tokens"].astype(dt), tokens, mesh)
+    if cfg.embed_scale:
+        x = (x.astype(jnp.float32) * cfg.model_dim**0.5).astype(dt)
     if cfg.position_kind == "learned":
         x = x + params["embed"]["positions"].astype(dt)[:T][None]
     return x
@@ -812,8 +878,10 @@ def forward(
             return _ssm_block(x, layer, cfg, mesh), None
         if kind == "G":
             return _gdn_block(x, layer, cfg, mesh), None
-        if kind == "*":
-            x = _attention_block(x, layer, cfg, mesh, positions, "norm")
+        if kind in "*W":
+            x = _attention_block(
+                x, layer, cfg, mesh, positions, "norm", kind
+            )
             return x, None
         x, aux = _mlp_block(x, layer, cfg, mesh, moe_axis, "norm")
         return x, aux if kind == "E" else None
@@ -893,14 +961,22 @@ def loss_fn(
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Per-layer K/V buffers [L, B, S, kv_heads, head_dim]. Static shape:
     the whole decode loop stays inside one compiled ``lax.scan``."""
+    if cfg.attn_window:
+        raise NotImplementedError(
+            f"cached decoding knows no window: the \"W\" layers' "
+            f"attn_window of {cfg.attn_window} keys would be decoded as "
+            "full attention (a window layer's cache is its last "
+            "attn_window keys, which no cache here holds)"
+        )
     if (
         cfg.layer_pattern or cfg.attn_gate or cfg.rope_dim
-        or cfg.qk_norm_span != "token" or cfg.attn_kind
+        or cfg.qk_norm_span != "token" or cfg.attn_kind or cfg.embed_scale
     ):
         raise NotImplementedError(
             "cached decoding knows the attention + FFN block only, "
             "ungated, wholly rotated, its QK-norm over the token, its "
-            "keys and values projected and not from a latent"
+            "keys and values projected and not from a latent, its "
+            "embedding unscaled"
         )
     dt = _dtype(cfg)
     shape = (cfg.num_layers, batch, max_len, cfg.kv_heads, cfg.head_dim)

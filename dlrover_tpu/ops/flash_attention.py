@@ -58,6 +58,22 @@ Design:
   no block gets 1024 x 1024 there (measured), 512 on the rectangular
   grid, which every other call keeps as it was. ``common/trace_counts``
   holds the kernels lowered, by grid (``attn_stream_tri_sites`` ...).
+- **A window** (``window=W``, a static argument of its own and not a
+  ``mask_fn``): a query sees itself and the ``W - 1`` keys before it. On
+  the streaming triangle path the step tables list only the band of
+  blocks a window can see, ``max(0, i - wb) <= j <= i`` with ``wb =
+  ceil((W - 1) / block)`` (45 of the triangle's 136 blocks at T = 16384,
+  W = 2048 in blocks of 1024), in the forward, the one-pass backward and
+  the split one: a query block walks DOWN from its diagonal block (where
+  every row sees itself, so the running maximum is finite before any
+  block whose rows may see nothing of it), a key block's queries end at
+  ``min(n - 1, j + wb)``; the diagonal block keeps its mask and the
+  blocks the window's far edge crosses get their own, the rest none. The
+  kernels are the triangle's with a static ``window`` (absent, they trace
+  as they did) under names of their own (``flash_attn_window_*``).
+  Everywhere else (the fused family, the rectangular grid, the jnp path)
+  the window is an exact mask with no skip, and ``common/trace_counts``
+  says which (``attn_window_blocks_walked`` against ``_causal``).
 - ``layout="bhtd"`` lets callers hand over kernel-native [B, H, T, D]
   tensors (the model emits them straight from its QKV einsums), skipping
   the 25 MB-per-tensor relayout transposes on every call.
@@ -85,6 +101,20 @@ MaskFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 NEG_INF = -1e30  # finite stand-in for -inf: keeps exp()=0 without NaN risk
 _LANES = 128  # f32 VMEM tile lane count; scratch vectors are padded to it
+
+
+@functools.lru_cache(maxsize=None)
+def _window_mask(window: int) -> MaskFn:
+    """A causal window as a ``mask_fn``: a query sees itself and the
+    ``window - 1`` keys before it. What a window is wherever no band is
+    walked (the fused square, the rectangular grid, the jnp path); one
+    function a length, so that it can ride as a static argument."""
+
+    def mask(q_pos, k_pos):
+        ahead = q_pos - k_pos
+        return (ahead >= 0) & (ahead < window)
+
+    return mask
 
 
 def _mask_for_block(q_pos, k_pos, causal, mask_fn):
@@ -196,6 +226,7 @@ def _fwd_pallas(
     layout="bthd",
     allow_fused=True,
     diagonal=False,
+    window=None,
 ):
     # Kernel layout is [B, H, T, D]: TPU tiling needs the last two block
     # dims to be (seq_block, head_dim) — (8,128)-aligned or full-size.
@@ -218,22 +249,26 @@ def _fwd_pallas(
             return ot, lse4[..., 0]
         return ot.transpose(0, 2, 1, 3), lse4[..., 0]
 
-    if allow_fused and _fused_eligible(qt.shape, kt.shape, "bhtd"):
+    fused, n_tri, mask_fn = _call_plan(
+        qt, kt, block_q, block_k, allow_fused=allow_fused, causal=causal,
+        mask_fn=mask_fn, diagonal=diagonal, window=window,
+    )
+    if window is not None:
+        _count_window_site(nq, block_q, window if n_tri else None)
+    if fused:
         return in_layout(*_fused_fwd_call(
             qt, kt, vt, offsets,
             causal=causal, mask_fn=mask_fn, sm_scale=sm_scale,
             interpret=interpret, diagonal=diagonal,
         ))
 
-    n_tri = _stream_plan(
-        Tq, Tk, block_q, block_k,
-        causal=causal, mask_fn=mask_fn, diagonal=diagonal,
+    _count_site(
+        _STREAM, n_tri or 0, walked=_band_steps(n_tri, block_q, window)
     )
-    _count_site(_STREAM, n_tri or 0)
     if n_tri:
         return in_layout(*_tri_fwd_call(
             qt, kt, vt, sm_scale=sm_scale, block=block_q,
-            interpret=interpret,
+            interpret=interpret, window=window,
         ))
 
     kernel = functools.partial(
@@ -358,9 +393,10 @@ _STREAM = (
     "attn_stream_tri_sites", "attn_stream_rect_sites",
     "attn_stream_blocks_walked", "attn_stream_blocks_rect",
 )
+_WINDOW = ("attn_window_blocks_walked", "attn_window_blocks_causal")
 
 
-def _count_site(names, n: int, kernels: int = 1):
+def _count_site(names, n: int, kernels: int = 1, walked=None):
     """``kernels`` kernels of one call site into ``common/trace_counts``
     under ``names`` (the fused family's or the streaming kernels'): a
     triangle site of ``n`` tiles a side and the tiles it walks of its
@@ -369,11 +405,29 @@ def _count_site(names, n: int, kernels: int = 1):
     each, by the body they took, in ``row_tile`` square score tiles; the
     streaming family counts kernels (a forward is one, a backward one in
     one pass and two split) by the grid they took, in the blocks a head
-    walks. Counted when a program is traced, so it costs a step nothing; a
+    walks (``walked`` where a window's band is less than the triangle).
+    Counted when a program is traced, so it costs a step nothing; a
     program that came out of a cache of executables was not traced and
     adds nothing."""
-    added = (n > 0, n == 0, n * (n + 1) // 2, n * n)
+    if walked is None:
+        walked = n * (n + 1) // 2
+    added = (n > 0, n == 0, walked, n * n)
     for name, k in zip(names, added):
+        trace_counts.count(name, kernels * k)
+
+
+def _count_window_site(n: int, block: int, band, kernels: int = 1):
+    """``kernels`` kernels of one call site that was given a window, into
+    ``common/trace_counts`` under ``_WINDOW``: the blocks a head walks
+    there against the ``n (n + 1) / 2`` at or under its diagonal, ``n``
+    blocks of ``block`` a side. ``band`` is the window's length where its
+    band is walked; where it is None the window is a mask over whatever
+    the call walks, and the site counts as having walked all of them.
+    Called where a call's plan is made (``_fwd_pallas``, ``_bwd_pallas``)
+    and nowhere else."""
+    under = n * (n + 1) // 2
+    walked = under if band is None else _band_steps(n, block, band)
+    for name, k in zip(_WINDOW, (walked, under)):
         trace_counts.count(name, kernels * k)
 
 
@@ -948,26 +1002,117 @@ def _stream_plan(Tq, Tk, block_q, block_k, *, causal, mask_fn, diagonal):
     return Tq // block_q
 
 
-def _triangle_steps(n: int, by_key: bool):
+def _band_blocks(window, block: int):
+    """Key blocks before its own that a query block can see through a
+    window of ``window`` keys (a query sees itself and the ``window - 1``
+    before it): the band is ``i - wb <= j <= i``. None without one."""
+    if window is None:
+        return None
+    return -(-(window - 1) // block)
+
+
+def _band_steps(n, block: int, window):
+    """Blocks a head walks on the band of ``n`` blocks a side, or None
+    where there is no band (no window, or no triangle path)."""
+    if not n or window is None:
+        return None
+    wb = _band_blocks(window, block)
+    return sum(min(i, wb) + 1 for i in range(n))
+
+
+def _call_plan(qt, kt, block_q, block_k, *, allow_fused, causal, mask_fn,
+               diagonal, window):
+    """``(fused, n_tri, mask_fn)`` of a call over ``[B, H, T, D]``: the
+    fused family or not, the blocks a side of the triangle path (None for
+    the rectangular grid), and the mask the other bodies apply: a window
+    is walked as a band on the triangle path alone, and is a mask
+    everywhere else."""
+    fused = allow_fused and _fused_eligible(qt.shape, kt.shape, "bhtd")
+    n_tri = None if fused else _stream_plan(
+        qt.shape[2], kt.shape[2], block_q, block_k,
+        causal=causal, mask_fn=mask_fn, diagonal=diagonal,
+    )
+    if window is not None and not n_tri:
+        mask_fn = _window_mask(window)
+    return fused, n_tri, mask_fn
+
+
+def _triangle_steps(n: int, by_key: bool, wb=None):
     """step -> (query block i, key block j) over the blocks with
     j <= i, in the order a kernel accumulates: a query block's keys
     ``j = 0..i`` (forward, dq), or with ``by_key`` a key block's
-    queries ``i = j..n-1`` (dk / dv)."""
-    if by_key:
-        kj, qi = np.triu_indices(n)
+    queries ``i = j..n-1`` (dk / dv). With ``wb`` only the band ``i - wb
+    <= j <= i`` a window can see: a key block's queries end at ``min(n -
+    1, j + wb)``, and a query block's keys run DOWN from its own block to
+    ``max(0, i - wb)``: the block on the diagonal, where every row sees
+    itself, is a row's first, so the running maximum is finite before a
+    block on the far edge whose rows may see nothing of it."""
+    if wb is None:
+        if by_key:
+            kj, qi = np.triu_indices(n)
+        else:
+            qi, kj = np.tril_indices(n)
+    elif by_key:
+        qi, kj = zip(*(
+            (i, j) for j in range(n) for i in range(j, min(n, j + wb + 1))
+        ))
     else:
-        qi, kj = np.tril_indices(n)
+        qi, kj = zip(*(
+            (i, j) for i in range(n)
+            for j in range(i, max(0, i - wb) - 1, -1)
+        ))
     return jnp.asarray(qi, jnp.int32), jnp.asarray(kj, jnp.int32)
 
 
-def _tri_scores(q_ref, k_ref, on_diagonal: bool):
+def _tri_scores(q_ref, k_ref, on_diagonal: bool, window=None, far=None):
     """Raw scores ``q k^T`` of one block, float32; the block on the
-    diagonal masked ``row >= col``, any other block whole."""
+    diagonal masked ``row >= col``, any other block whole. With a
+    ``window``, a block the window's far edge may cross is given ``far``,
+    how many blocks it lies before the query block (a scalar of the
+    step), and keeps ``row - col < window - far * block``; the block on
+    the diagonal is such a block too where the window is shorter than
+    it."""
     s = jax.lax.dot_general(
         q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    return _mask_diagonal_tile(s, s.shape[0]) if on_diagonal else s
+    block = s.shape[0]
+    if on_diagonal and (window is None or window >= block):
+        return _mask_diagonal_tile(s, block)
+    if not on_diagonal and far is None:
+        return s
+    ahead = (
+        lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        - lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    )
+    if on_diagonal:
+        seen = (ahead >= 0) & (ahead < window)
+    else:
+        seen = ahead < window - far * block
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _band_below(i, j, block: int, window: int, body):
+    """``body(False[, far])`` on a band step under the diagonal: with no
+    mask where the window covers the whole block, and with ``far`` where
+    its far edge may cross it."""
+    whole = window // block - 1  # blocks back that are covered whole
+    far = i - j
+    if whole >= 1:
+        pl.when((far >= 1) & (far <= whole))(
+            functools.partial(body, False)
+        )
+    pl.when(far > max(whole, 0))(functools.partial(body, False, far))
+
+
+def _band_by_query(i, j, block: int, window: int, body, write):
+    """A query block's walk over its band, a step of it: ``body`` on the
+    block on the diagonal (the row's first) and on those under it,
+    ``write`` at the first block the window reaches (its last)."""
+    pl.when(j == i)(functools.partial(body, True))
+    _band_below(i, j, block, window, body)
+    last = jnp.maximum(i - _band_blocks(window, block), 0)
+    pl.when(j == last)(write)
 
 
 def _tri_fwd_kernel(
@@ -983,18 +1128,23 @@ def _tri_fwd_kernel(
     l_ref,  # scratch [b, _LANES] f32
     *,
     sm_scale: float,
+    window=None,
 ):
+    """One step of a query block's walk over its keys. With a ``window``
+    the walk is the band's: from the block on the diagonal down to the
+    first block the window reaches (``_triangle_steps``)."""
     step = pl.program_id(2)
     i, j = qi_ref[step], kj_ref[step]
+    block = q_ref.shape[2]
 
-    @pl.when(j == 0)
+    @pl.when(j == (0 if window is None else i))
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def _block(on_diagonal):
-        s = _tri_scores(q_ref, k_ref, on_diagonal)
+    def _block(on_diagonal, far=None):
+        s = _tri_scores(q_ref, k_ref, on_diagonal, window, far)
         m_prev = m_ref[:, :1]  # [b, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a row's first block: exp(scale * (NEG_INF - m_new)) = 0
@@ -1008,21 +1158,28 @@ def _tri_fwd_kernel(
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
+    def _write():
+        l = l_ref[:, :1]
+        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[:, :1] * sm_scale + jnp.log(l)
+
+    if window is not None:
+        _band_by_query(i, j, block, window, _block, _write)
+        return
+
     pl.when(j < i)(functools.partial(_block, False))
 
     @pl.when(j == i)
     def _last():  # the row's block on the diagonal
         _block(True)
-        l = l_ref[:, :1]
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[:, :1] * sm_scale + jnp.log(l)
+        _write()
 
 
 def _tri_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
-              sm_scale, on_diagonal):
+              sm_scale, on_diagonal, window=None, far=None):
     """(p, ds / sm_scale) of one block, float32: what both backward
     kernels recompute. ds is scaled where it has been summed."""
-    s = _tri_scores(q_ref, k_ref, on_diagonal)
+    s = _tri_scores(q_ref, k_ref, on_diagonal, window, far)
     # lse is finite; a masked score gives exp(NEG_INF - lse) = 0
     p = jnp.exp(s * sm_scale - lse_ref[0, 0, :, :1])
     dp = jax.lax.dot_general(
@@ -1038,18 +1195,21 @@ def _tri_bwd_dq_kernel(
     dq_acc,  # scratch [b, D] f32
     *,
     sm_scale: float,
+    window=None,
 ):
     step = pl.program_id(2)
     i, j = qi_ref[step], kj_ref[step]
+    block = q_ref.shape[2]
 
-    @pl.when(j == 0)
+    @pl.when(j == (0 if window is None else i))
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def _block(on_diagonal):
+    def _block(on_diagonal, far=None):
         _, ds = _tri_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             sm_scale=sm_scale, on_diagonal=on_diagonal,
+            window=window, far=far,
         )
         k = k_ref[0, 0]
         dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
@@ -1057,12 +1217,19 @@ def _tri_bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
+    def _write():
+        dq_ref[0, 0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+
+    if window is not None:  # the band's walk, as the forward's
+        _band_by_query(i, j, block, window, _block, _write)
+        return
+
     pl.when(j < i)(functools.partial(_block, False))
 
     @pl.when(j == i)
     def _last():
         _block(True)
-        dq_ref[0, 0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+        _write()
 
 
 def _tri_bwd_kernel(
@@ -1070,6 +1237,7 @@ def _tri_bwd_kernel(
     *refs,
     sm_scale: float,
     n_blocks: int,
+    window=None,
 ):
     """dk / dv over the triangle, key block by key block (outputs
     ``dk, dv`` ``[1, 1, b, D]`` per q-head, summed over groups outside;
@@ -1078,7 +1246,8 @@ def _tri_bwd_kernel(
     backward in ONE pass: scores, p, dp and ds computed once (five
     matmuls and one exponential pass a block where the split kernels
     run seven and two), dq summed in the float32 block that stays in
-    VMEM while the head is swept and written at its last step."""
+    VMEM while the head is swept and written at its last step. With a
+    ``window`` a key block's queries end where the window leaves it."""
     if len(refs) == 6:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
     else:
@@ -1093,10 +1262,11 @@ def _tri_bwd_kernel(
         def _init():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _block(on_diagonal):
+    def _block(on_diagonal, far=None):
         p, ds = _tri_p_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             sm_scale=sm_scale, on_diagonal=on_diagonal,
+            window=window, far=far,
         )
         q, do = q_ref[0, 0], do_ref[0, 0]
         ds_lo = ds.astype(q.dtype)
@@ -1122,9 +1292,14 @@ def _tri_bwd_kernel(
             dk_acc[:] = dk_acc[:] + dk
 
     pl.when(i == j)(functools.partial(_block, True))
-    pl.when(i > j)(functools.partial(_block, False))
+    if window is None:
+        pl.when(i > j)(functools.partial(_block, False))
+        last = n_blocks - 1
+    else:
+        _band_below(i, j, block, window, _block)
+        last = jnp.minimum(j + _band_blocks(window, block), n_blocks - 1)
 
-    @pl.when(i == n_blocks - 1)
+    @pl.when(i == last)
     def _finalize():
         dk_ref[0, 0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
@@ -1190,14 +1365,22 @@ def _tri_specs(block: int, D: int, group: int):
     )
 
 
-def _tri_fwd_call(qt, kt, vt, *, sm_scale, block, interpret):
+def _tri_names(window):
+    """A kernel's name on the triangle path, and with a window on its
+    band: names of their own that a trace can tell apart."""
+    stem = "flash_attn" if window is None else "flash_attn_window"
+    return tuple(f"{stem}_{k}" for k in ("fwd", "bwd", "bwd_dq", "bwd_dkv"))
+
+
+def _tri_fwd_call(qt, kt, vt, *, sm_scale, block, interpret, window=None):
     """[B,H,T,D] in -> (o [B,H,T,D], lse4 [B,H,T,1])."""
     B, H, T, D = qt.shape
     q_spec, kv_spec, row_spec, _ = _tri_specs(block, D, H // kt.shape[1])
+    wb = _band_blocks(window, block)
     return _tri_call(
-        functools.partial(_tri_fwd_kernel, sm_scale=sm_scale),
-        "flash_attn_fwd",
-        _triangle_steps(T // block, by_key=False),
+        functools.partial(_tri_fwd_kernel, sm_scale=sm_scale, window=window),
+        _tri_names(window)[0],
+        _triangle_steps(T // block, by_key=False, wb=wb),
         (qt, kt, vt),
         [q_spec, kv_spec, kv_spec],
         [q_spec, row_spec],
@@ -1215,11 +1398,14 @@ def _tri_fwd_call(qt, kt, vt, *, sm_scale, block, interpret):
 
 
 def _tri_bwd_call(qt, kt, vt, dot, lse4, delta4, *, sm_scale, block,
-                  interpret):
+                  interpret, window=None):
     """[B,H,T,D] in -> (dq in q's dtype, dk, dv float32 per QUERY
     head), as ``_rect_bwd_call``."""
     B, H, T, D = qt.shape
     n = T // block
+    wb = _band_blocks(window, block)
+    _, one_pass, dq_name, dkv_name = _tri_names(window)
+    walked = _band_steps(n, block, window)
     q_spec, kv_spec, row_spec, kv_out_spec = _tri_specs(
         block, D, H // kt.shape[1]
     )
@@ -1228,30 +1414,32 @@ def _tri_bwd_call(qt, kt, vt, dot, lse4, delta4, *, sm_scale, block,
     dq_shape = jax.ShapeDtypeStruct((B, H, T, D), qt.dtype)
     dkv_shape = jax.ShapeDtypeStruct((B, H, T, D), jnp.float32)
     acc = pltpu.VMEM((block, D), jnp.float32)
-    by_key = _triangle_steps(n, by_key=True)
+    by_key = _triangle_steps(n, by_key=True, wb=wb)
     dkv_kernel = functools.partial(
-        _tri_bwd_kernel, sm_scale=sm_scale, n_blocks=n
+        _tri_bwd_kernel, sm_scale=sm_scale, n_blocks=n, window=window
     )
-    if _one_pass_fits(T, D, qt.dtype.itemsize):
-        _count_site(_STREAM, n)
+    kernels = 1 if _one_pass_fits(T, D, qt.dtype.itemsize) else 2
+    _count_site(_STREAM, n, kernels=kernels, walked=walked)
+    if kernels == 1:
         whole_head = pl.BlockSpec(
             (1, 1, T, D), lambda b, h, s, qi, kj: (b, h, 0, 0)
         )
         return _tri_call(
-            dkv_kernel, "flash_attn_bwd", by_key, ins, in_specs,
+            dkv_kernel, one_pass, by_key, ins, in_specs,
             [whole_head, kv_out_spec, kv_out_spec],
             [dq_shape, dkv_shape, dkv_shape],
             [pltpu.VMEM((T, D), jnp.float32), acc, acc],
             interpret=interpret, vmem_limit=_FUSED_VMEM_LIMIT,
         )
-    _count_site(_STREAM, n, kernels=2)
     dqt = _tri_call(
-        functools.partial(_tri_bwd_dq_kernel, sm_scale=sm_scale),
-        "flash_attn_bwd_dq", _triangle_steps(n, by_key=False), ins,
+        functools.partial(
+            _tri_bwd_dq_kernel, sm_scale=sm_scale, window=window
+        ),
+        dq_name, _triangle_steps(n, by_key=False, wb=wb), ins,
         in_specs, q_spec, dq_shape, [acc], interpret=interpret,
     )
     dk_full, dv_full = _tri_call(
-        dkv_kernel, "flash_attn_bwd_dkv", by_key, ins, in_specs,
+        dkv_kernel, dkv_name, by_key, ins, in_specs,
         [kv_out_spec, kv_out_spec], [dkv_shape, dkv_shape], [acc, acc],
         interpret=interpret,
     )
@@ -1276,6 +1464,7 @@ def _bwd_pallas(
     layout="bthd",
     allow_fused=True,
     diagonal=False,
+    window=None,
 ):
     if layout == "bhtd":
         B, H, Tq, D = q.shape
@@ -1303,7 +1492,19 @@ def _bwd_pallas(
     delta4 = delta[..., None]  # [B,H,Tq,1]
     lse4 = lse[..., None]
 
-    if allow_fused and _fused_eligible(qt.shape, kt.shape, "bhtd"):
+    fused, n_tri, mask_fn = _call_plan(
+        qt, kt, block_q, block_k, allow_fused=allow_fused, causal=causal,
+        mask_fn=mask_fn, diagonal=diagonal, window=window,
+    )
+    if window is not None:
+        # a backward is one kernel in the fused family and in one pass,
+        # two split and on the rectangular grid
+        one = fused or (n_tri and _one_pass_fits(Tq, D, qt.dtype.itemsize))
+        _count_window_site(
+            Tq // block_q, block_q, window if n_tri else None,
+            kernels=1 if one else 2,
+        )
+    if fused:
         dqt, dkt, dvt = _fused_bwd_call(
             qt, kt, vt, dot, lse4, delta4, offsets,
             causal=causal, mask_fn=mask_fn, sm_scale=sm_scale,
@@ -1317,14 +1518,10 @@ def _bwd_pallas(
             dvt.transpose(0, 2, 1, 3).astype(v.dtype),
         )
 
-    n_tri = _stream_plan(
-        Tq, Tk, block_q, block_k,
-        causal=causal, mask_fn=mask_fn, diagonal=diagonal,
-    )
     if n_tri:
         dqt, dk_full, dv_full = _tri_bwd_call(
             qt, kt, vt, dot, lse4, delta4, sm_scale=sm_scale,
-            block=block_q, interpret=interpret,
+            block=block_q, interpret=interpret, window=window,
         )
     else:
         dqt, dk_full, dv_full = _rect_bwd_call(
@@ -1458,11 +1655,11 @@ def _rect_bwd_call(qt, kt, vt, dot, lse4, delta4, offsets, *, causal,
 # ``flash_attention_fwd``/``flash_attention_bwd`` pair and define their own
 # VJP at the ring level, where the lse residual's gradient is handled.
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11)
 )
 def _flash_pallas(
     q, k, v, offsets, causal, mask_fn, sm_scale, block_q, block_k, layout,
-    allow_fused,
+    allow_fused, window=None,
 ):
     o, _ = _fwd_pallas(
         q,
@@ -1478,13 +1675,14 @@ def _flash_pallas(
         layout=layout,
         allow_fused=allow_fused,
         diagonal=_on_diagonal(*offsets),
+        window=window,
     )
     return o
 
 
 def _flash_fwd_rule(
     q, k, v, offsets, causal, mask_fn, sm_scale, block_q, block_k, layout,
-    allow_fused,
+    allow_fused, window,
 ):
     o, lse = _fwd_pallas(
         q,
@@ -1500,13 +1698,14 @@ def _flash_fwd_rule(
         layout=layout,
         allow_fused=allow_fused,
         diagonal=_on_diagonal(*offsets),
+        window=window,
     )
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd_rule(
     offsets, causal, mask_fn, sm_scale, block_q, block_k, layout,
-    allow_fused, res, do,
+    allow_fused, window, res, do,
 ):
     q, k, v, o, lse = res
     dq, dk, dv = _bwd_pallas(
@@ -1526,6 +1725,7 @@ def _flash_bwd_rule(
         layout=layout,
         allow_fused=allow_fused,
         diagonal=_on_diagonal(*offsets),
+        window=window,
     )
     return dq, dk, dv
 
@@ -1567,12 +1767,16 @@ def flash_attention_fwd(
     interpret=None,
     layout="bthd",
     allow_fused=True,
+    window=None,
 ):
     """Forward kernel; returns ``(o, lse)`` with lse ``[B,H,Tq]`` f32.
 
     ``allow_fused=False`` pins the streaming (block-tiled) kernels even
     when the fused short-seq form is eligible — for tests and A/B
     timing."""
+    window = _checked_window(
+        q, layout, causal, mask_fn, q_offset, k_offset, window
+    )
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     bq, bk = _call_blocks(
         q, k, block_q, block_k, layout, causal, mask_fn, q_offset, k_offset
@@ -1591,6 +1795,7 @@ def flash_attention_fwd(
         layout=layout,
         allow_fused=allow_fused,
         diagonal=_on_diagonal(q_offset, k_offset),
+        window=window,
     )
 
 
@@ -1701,8 +1906,12 @@ def flash_attention_bwd(
     interpret=None,
     layout="bthd",
     allow_fused=True,
+    window=None,
 ):
     """Backward kernels; returns ``(dq, dk, dv)`` given saved residuals."""
+    window = _checked_window(
+        q, layout, causal, mask_fn, q_offset, k_offset, window
+    )
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     bq, bk = _call_blocks(
         q, k, block_q, block_k, layout, causal, mask_fn, q_offset, k_offset
@@ -1724,6 +1933,7 @@ def flash_attention_bwd(
         layout=layout,
         allow_fused=allow_fused,
         diagonal=_on_diagonal(q_offset, k_offset),
+        window=window,
     )
 
 
@@ -1744,6 +1954,27 @@ def _sees_triangle(causal, mask_fn, diagonal: bool) -> bool:
     program is traced: a causal mask alone, with query and key offsets
     static and equal (``_on_diagonal``). Both families test it."""
     return bool(causal and mask_fn is None and diagonal)
+
+
+def _checked_window(q, layout, causal, mask_fn, q_offset, k_offset, window):
+    """A call's ``window`` as the kernels take it: None where there is
+    none, or where the offsets are static and no query is ``window`` or
+    more keys past the first key (the call is then the plain causal one,
+    and is traced as that)."""
+    if window is None:
+        return None
+    if isinstance(window, bool) or not isinstance(window, int) or window < 1:
+        raise ValueError(f"window {window!r}: a whole number of keys, 1 up")
+    if not causal or mask_fn is not None:
+        raise ValueError(
+            "a window is of a causal call without a mask_fn (a query "
+            "sees itself and the window - 1 keys before it)"
+        )
+    if isinstance(q_offset, int) and isinstance(k_offset, int):
+        Tq = q.shape[2 if layout == "bhtd" else 1]
+        if q_offset + Tq - 1 - k_offset < window:
+            return None
+    return window
 
 
 def _validate_blocks(q, k, block_q, block_k, layout="bthd", triangle=False):
@@ -1795,8 +2026,13 @@ def flash_attention_reference(
     q_offset=0,
     k_offset=0,
     return_residuals: bool = False,
+    window: Optional[int] = None,
 ):
     """Same semantics as the kernel, materialized scores. Differentiable."""
+    if _checked_window(
+        q, "bthd", causal, mask_fn, q_offset, k_offset, window
+    ) is not None:
+        mask_fn = _window_mask(window)
     D = q.shape[-1]
     H, Hkv = q.shape[2], k.shape[2]
     if Hkv != H:
@@ -1851,6 +2087,7 @@ def flash_attention(
     force: Optional[str] = None,
     layout: str = "bthd",
     allow_fused: bool = True,
+    window: Optional[int] = None,
 ):
     """Flash attention over ``q:[B,Tq,H,D] k,v:[B,Tk,Hkv,D]`` (or the
     kernel-native ``[B,H,T,D]`` with ``layout="bhtd"`` — no relayout
@@ -1862,6 +2099,16 @@ def flash_attention(
     logsumexp ``[B,H,Tq]``, letting callers merge partial attention
     results across devices (online-softmax merge in ring attention).
 
+    ``window`` (with ``causal``, without ``mask_fn``): a query sees
+    itself and the ``window - 1`` keys before it, exactly, for any
+    length, window and block. Where the streaming kernels' triangle path
+    holds (static equal offsets, square blocks over one sequence) the
+    forward and both backwards walk only the band of blocks the window
+    can see, ``i - wb <= j <= i`` with ``wb = ceil((window - 1) /
+    block)``, masking the block on the diagonal and the blocks its far
+    edge crosses; everywhere else the window is a mask over what the
+    call walks. A window no query can see past is no window.
+
     ``force``: ``None`` auto-picks (pallas on TPU, jnp elsewhere),
     ``"pallas"``/``"reference"`` override.
 
@@ -1870,6 +2117,9 @@ def flash_attention(
     ``flash_attention_fwd``/``flash_attention_bwd`` directly (see ring
     attention).
     """
+    window = _checked_window(
+        q, layout, causal, mask_fn, q_offset, k_offset, window
+    )
     mode = force
     if mode is None:
         mode = "pallas" if jax.default_backend() == "tpu" else "reference"
@@ -1887,6 +2137,7 @@ def flash_attention(
             q_offset=q_offset,
             k_offset=k_offset,
             return_residuals=return_residuals,
+            window=window,
         )
         if layout != "bhtd":
             return r
@@ -1939,6 +2190,7 @@ def flash_attention(
                 return_residuals=return_residuals,
                 force="reference",
                 layout=layout,
+                window=window,
             )
     if return_residuals:
         # raw forward — callers own the VJP (e.g. the ring merge)
@@ -1955,6 +2207,7 @@ def flash_attention(
             block_k=bk,
             layout=layout,
             allow_fused=allow_fused,
+            window=window,
         )
     if not isinstance(q_offset, int) or not isinstance(k_offset, int):
         raise ValueError(
@@ -1963,5 +2216,5 @@ def flash_attention(
         )
     return _flash_pallas(
         q, k, v, (q_offset, k_offset), causal, mask_fn, scale, bq, bk,
-        layout, allow_fused
+        layout, allow_fused, window
     )

@@ -6,8 +6,10 @@ quantization, linear + nonlinear qmaps) backed by the CUDA kernels in
 atorch/atorch/ops/csrc/{quantize.cu,dequantize.cu,quantization_optimizer.cu}.
 
 TPU-native design: optimizer moments are stored as int8 codes + one f32
-scale per 128-element block (the same 128 consecutive elements of the
-flattened leaf in every layout below). What runs today:
+scale per 128-element block: 128 consecutive elements of one row of the
+leaf, the same in every layout below (a row whose width is no multiple
+of 128 is padded to whole blocks, ``_to_blocks``: a block never holds
+the end of one row and the start of the next). What runs today:
 
 - ``adamw_8bit`` with ``use_pallas=False`` (the benchmark's OLMoE cell,
   and every backend but the TPU by default): plain jnp that XLA fuses. A
@@ -87,10 +89,10 @@ class Quantized8:
     """Blockwise quantized tensor: ``x ~ sqrt-map(codes) * scales``.
 
     ``codes``/``scales`` are pytree children; ``shape``/``signed``/
-    ``layout`` are static aux data so jit never traces them. Block ``b``
-    holds elements ``[128 b, 128 b + 128)`` of the flattened leaf in
-    either layout; ``TILES`` stores them in the order the leaf's own
-    (8, 128) tiles lie in HBM (``_to_tiles``).
+    ``layout`` are static aux data so jit never traces them. A block
+    holds 128 consecutive elements of one row of the leaf in either
+    layout (``_to_blocks``); ``TILES`` stores them in the order the
+    leaf's own (8, 128) tiles lie in HBM (``_to_tiles``).
     """
 
     def __init__(self, codes, scales, shape, signed, layout=BLOCKS):
@@ -117,6 +119,17 @@ class Quantized8:
 
 
 def _to_blocks(x):
+    """``[nblocks, BLOCK]`` rows in which no block crosses a row of the
+    leaf: a leaf ``[..., C]`` with ``C`` no multiple of ``BLOCK`` has
+    each of its rows padded to whole blocks first (zeros, which move no
+    block's maximum), any other leaf is flattened as it lies. Flattened
+    unpadded, ``[2048, 25024]`` put the last 64 columns of one row and
+    the first 64 of the next under one scale: an untied head's rarest and
+    most frequent ids, whose second moments lie seven orders of magnitude
+    apart (PERF.md, Findings PR 50)."""
+    if x.ndim > 1 and x.shape[-1] % BLOCK:
+        pad = (-x.shape[-1]) % BLOCK
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
     flat = x.reshape(-1)
     pad = (-flat.size) % BLOCK
     if pad:
@@ -125,10 +138,18 @@ def _to_blocks(x):
 
 
 def _from_blocks(blocks, shape):
-    n = 1
-    for d in shape:
-        n *= d
-    return blocks.reshape(-1)[:n].reshape(shape)
+    if len(shape) > 1 and shape[-1] % BLOCK:
+        width = -(-shape[-1] // BLOCK) * BLOCK
+        rows = blocks.reshape(*shape[:-1], width)
+        return rows[..., : shape[-1]]
+    return blocks.reshape(-1)[: math.prod(shape)].reshape(shape)
+
+
+def _blocks_size(shape) -> int:
+    """Elements of ``_to_blocks``' rows for a leaf of ``shape``."""
+    if len(shape) > 1:
+        shape = (*shape[:-1], -(-shape[-1] // BLOCK) * BLOCK)
+    return -(-math.prod(shape) // BLOCK) * BLOCK
 
 
 def _layout_for(shape) -> str:
@@ -787,9 +808,10 @@ def _flat_layout(
     bound the transient HBM of the update (one group's grad concat +
     delta live at a time) — a single 1.5B-param flat buffer measured
     +6 GB of transients and OOMed next to bf16 params+grads, while
-    per-group transients are ~2×group_elems bytes. Each leaf is padded
-    to a BLOCK boundary so quantization blocks never straddle leaves
-    (numerics identical to the per-leaf tree form)."""
+    per-group transients are ~2×group_elems bytes. Each leaf lies in
+    its group as ``_to_blocks`` rows, so quantization blocks straddle
+    neither leaves nor a leaf's rows (numerics identical to the per-leaf
+    tree form)."""
     chunk = BLOCK * _FLAT_ROWS
     groups, g_idx, g_off, off = [], [], [], 0
     g_dtype = None
@@ -817,7 +839,7 @@ def _flat_layout(
             g_idx.append(i)
             g_off.append(off)
             g_dtype = leaf.dtype
-            off += -(-leaf.size // BLOCK) * BLOCK
+            off += _blocks_size(leaf.shape)
         else:
             small_idx.append(i)
             small_off.append(soff)
@@ -829,18 +851,13 @@ def _flat_layout(
 
 
 def _pack_group(leaves, group: _FlatGroup, dtype):
-    """Concatenate one group's leaves (each zero-padded to its
-    BLOCK-aligned slot) into a flat [group.total] buffer — one fused
-    concat pass per group."""
-    segs = []
-    for i in group.idx:
-        n = leaves[i].size
-        pad = -(-n // BLOCK) * BLOCK - n
-        seg = leaves[i].reshape(-1).astype(dtype)
-        if pad:
-            seg = jnp.pad(seg, (0, pad))
-        segs.append(seg)
-    used = group.offsets[-1] + -(-leaves[group.idx[-1]].size // BLOCK) * BLOCK
+    """Concatenate one group's leaves (each as its ``_to_blocks`` rows)
+    into a flat [group.total] buffer — one fused concat pass per
+    group."""
+    segs = [
+        _to_blocks(leaves[i].astype(dtype)).reshape(-1) for i in group.idx
+    ]
+    used = group.offsets[-1] + _blocks_size(leaves[group.idx[-1]].shape)
     if group.total - used:
         segs.append(jnp.zeros((group.total - used,), dtype))
     return jnp.concatenate(segs)
@@ -868,9 +885,9 @@ def adamw_8bit_flat(
     delta at a time) — a single 1.5B flat buffer OOMed next to bf16
     params+grads.
 
-    Numerics are IDENTICAL to ``adamw_8bit``: each leaf is padded to a
-    BLOCK boundary inside its group, so quantization blocks (and their
-    scales) never straddle leaves. Small leaves (< ``min_quantized_
+    Numerics are IDENTICAL to ``adamw_8bit``: each leaf lies in its
+    group as ``_to_blocks`` rows, so quantization blocks (and their
+    scales) never straddle leaves or a leaf's rows. Small leaves (< ``min_quantized_
     size``) keep fp32 moments, packed into one flat f32 vector pair —
     one fused elementwise update instead of ~100 tiny kernels.
 
@@ -957,13 +974,12 @@ def adamw_8bit_flat(
             vq_groups.append(vq)
             delta_flat = delta.reshape(-1)
             for k, i in enumerate(group.idx):
-                n = leaves[i].size
                 off = group.offsets[k]
-                out[i] = (
-                    lax.slice(delta_flat, (off,), (off + n,))
-                    .reshape(leaves[i].shape)
-                    .astype(leaves[i].dtype)
-                )
+                n = _blocks_size(leaves[i].shape)
+                out[i] = _from_blocks(
+                    lax.slice(delta_flat, (off,), (off + n,)),
+                    leaves[i].shape,
+                ).astype(leaves[i].dtype)
 
         if layout.small_idx:
             gs = jnp.concatenate(

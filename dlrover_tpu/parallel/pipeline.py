@@ -110,6 +110,13 @@ def _microbatch_axes(mesh, mb: int) -> Tuple[str, ...]:
 def _check_pipeline_cfg(
     cfg: TransformerConfig, pp: int, virtual: int = 1
 ) -> None:
+    if cfg.attn_window:
+        raise ValueError(
+            f"pipeline parallelism stacks all-alike attention + FFN "
+            f"blocks: the window layers of layer_pattern "
+            f"{cfg.layer_pattern!r} (attn_window {cfg.attn_window}) "
+            "would run as full attention"
+        )
     if cfg.num_experts:
         raise ValueError(
             "pipeline parallelism requires homogeneous blocks (MoE layers "

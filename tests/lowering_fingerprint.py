@@ -13,7 +13,15 @@ brought (a later PR that means to leave it alone leaves both alone). The
 three hybrid configurations' steps were recorded anew at ISSUE 47, whose
 convolution kernels their mixers take at these widths (``conv_silu_*``,
 interpreted on the CPU); their trees and the other three entries are as
-they were. Made by running this file there:
+they were. ``trinity-mini-d5`` is as ISSUE 50 brought it (window and
+global attention layers, each layer between two norms; on the CPU its
+attention lowers to the jnp path with the window as a mask), and ISSUE 50
+recorded the Nemotron and Ling steps anew, their trees as they were: each
+holds a quantised leaf whose rows are no whole blocks of 128 (the experts'
+``w_up`` ``[8, 2688, 1856]``, the latent layer's ``wq`` ``[2560, 32,
+192]``), and ``ops/quantized_optim._to_blocks`` now pads such a row to
+whole blocks so that no block of int8 moments crosses a row. Made by
+running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
 
@@ -31,7 +39,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAMES = (
     "gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2",
     "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
-    "ling-3.0-flash-d7",
+    "ling-3.0-flash-d7", "trinity-mini-d5",
 )
 
 

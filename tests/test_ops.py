@@ -1256,11 +1256,11 @@ class TestQuantizedOptim:
     def test_odd_leaves_keep_the_block_layout(self, shape):
         """1-D leaves, widths that are not whole lane rows and row
         counts that are not whole sublane groups stay on the padded
-        [nblocks, 128] path: same state shapes, same values."""
+        [nblocks, 128] path, a row of a width that is not whole lane
+        rows padded to whole blocks: same state shapes, same values."""
         from dlrover_tpu.ops.quantized_optim import BLOCKS, _from_blocks
 
-        n = int(np.prod(shape))
-        nblocks = -(-n // 128)
+        nblocks = {(4097,): 33, (48, 100): 48, (7, 1024): 56}[shape]
         rng = np.random.default_rng(8)
         g = jnp.asarray(rng.normal(size=shape), jnp.float32)
         p = jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -1311,6 +1311,64 @@ class TestQuantizedOptim:
         np.testing.assert_array_equal(
             back, dequantize_8bit(quantize_8bit(x, True, BLOCKS))
         )
+
+    @pytest.mark.parametrize("make", ["tree", "pallas", "flat", "4bit"])
+    def test_no_block_crosses_a_row(self, make):
+        """A head ``[d, vocab]`` whose vocabulary is one and a half lane
+        tiles wide, its first ids frequent and its last ones rare: were
+        the leaf flattened before it is cut into blocks, every second
+        block would hold a row's rare columns beside the next row's
+        frequent ones, the rare columns' second moments would round to
+        zero under the block's scale while their first moments did not,
+        and their update would be lr * m / eps (the Trinity-Mini cell at
+        25,024 rows lost its loss so: PERF.md, Findings PR 50)."""
+        from dlrover_tpu.ops.quantized_optim import (
+            _adam8_update_pallas,
+            _from_blocks,
+            adamw_4bit,
+            adamw_8bit_flat,
+        )
+
+        shape, lr = (64, 192), 1e-3
+        rng = np.random.default_rng(11)
+        col = np.where(np.arange(192) < 128, 1.0, 1e-4)
+        grads = [
+            jnp.asarray(rng.normal(size=shape) * col, jnp.float32)
+            for _ in range(4)
+        ]
+        np.testing.assert_array_equal(
+            _from_blocks(_to_blocks(grads[0]), shape), grads[0]
+        )
+        blocks = np.asarray(_to_blocks(grads[0])).reshape(64, 2, 128)
+        assert (np.abs(blocks[:, 1, :64]).max(-1) < 1e-3).all()
+        assert (blocks[:, 1, 64:] == 0).all()
+        if make == "pallas":
+            q0 = quantize_8bit(jnp.zeros(shape), True)
+            mq, vq = q0, quantize_8bit(jnp.zeros(shape), False)
+            for i, g in enumerate(grads, 1):
+                sc = jnp.asarray(
+                    [lr / (1 - 0.9**i), 1 / (1 - 0.999**i), 1e-8],
+                    jnp.float32,
+                )
+                mq, vq, delta = _adam8_update_pallas(
+                    _to_blocks(g), mq, vq, sc, 0.9, 0.999, interpret=True
+                )
+            u = _from_blocks(delta, shape)
+        else:
+            tx = {
+                "tree": adamw_8bit, "flat": adamw_8bit_flat,
+                "4bit": adamw_4bit,
+            }[make](
+                learning_rate=lr, min_quantized_size=4096, use_pallas=False
+            )
+            p = {"head": jnp.zeros(shape, jnp.float32)}
+            st = tx.init(p)
+            for g in grads:
+                u, st = tx.update({"head": g}, st, p)
+            u = u["head"]
+        assert u.shape == shape
+        # Adam moves no entry much further than lr a step; m / eps is 1e4 lr
+        assert float(jnp.abs(u).max()) < 20 * lr
 
     @pytest.mark.parametrize("shape", [(16, 256), (4097,)], ids=str)
     def test_tree_flatten_keeps_the_layout_tag(self, shape):

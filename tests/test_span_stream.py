@@ -900,7 +900,8 @@ def test_conv_kernel_sites_share_is_declared_as_its_reader_says():
         "nemotron3-nano-30b-a3b-d9.steady", "qwen3-next-80b-a3b-d4.steady",
         "ling-3.0-flash-d7.steady",
     ]
-    assert bench["per_layer"][-1] == {
+    # PR 47's entry, the last until PR 50 appended its two readers
+    assert bench["per_layer"][-3] == {
         "name": "conv.kernel_sites_share", "unit": mod.UNIT,
         "better": "higher", "source": "program_counter",
         "layer": mod.LAYER, "moves": mod.MOVES, "workloads": cells,

@@ -142,7 +142,9 @@ AS_DICT_KEYS = [
     "attn_stream_blocks_rect",
     "attn_stream_blocks_walked", "attn_stream_rect_sites",
     "attn_stream_tri_sites", "attn_tiles_square", "attn_tiles_walked",
-    "attn_tri_sites", "begin_lock_s", "comm_overlap_pct",
+    "attn_tri_sites", "attn_window_blocks_causal",
+    "attn_window_blocks_walked", "begin_lock_s",
+    "comm_overlap_pct",
     "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "conv_kernel_sites", "conv_sites",
     "donated_bytes", "donated_steps",

@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.common import trace_counts
-from trace_counted import CONV, FUSED, GDN, LANES, STREAM, added
+from trace_counted import CONV, FUSED, GDN, LANES, STREAM, WINDOW, added
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
 # (ops/__init__ re-exports it); the module has to be asked for by name
@@ -169,6 +169,97 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
             fa._walk_head_chunk(H, T, D, 2, wide=4, narrow=1),
             fa._walk_head_chunk(H, T, D, 2, wide=7, narrow=2),
         ) == {12: (4, 2), 25: (5, 1)}[H]
+
+
+# the Trinity-Mini cell's attention layers, [B, H, T, D] on 4 key/value
+# heads: (window, block stated or None for the call's own, blocks a head
+# walks, blocks under its diagonal)
+WINDOW_SITES = {
+    # the global layer: the triangle at T = 16384, one-pass backward
+    "global": (None, None, 136, 136),
+    # a window layer as the model calls it: the band in blocks of 1024
+    "window": (2048, None, 45, 136),
+    "window_512": (2048, 512, 150, 528),
+    # a window off the block: two far blocks crossed
+    "window_off_block": (1500, None, 45, 136),
+}
+
+
+@pytest.mark.parametrize("site", list(WINDOW_SITES))
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_window_attention_compiles_at_the_cell(
+    site, direction, one_chip, monkeypatch
+):
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    window, block, walked, under = WINDOW_SITES[site]
+    B, H, Hkv, T, D = 1, 32, 4, 16384, 128
+    qkv = [
+        jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+        for s in ((B, H, T, D), (B, Hkv, T, D), (B, Hkv, T, D))
+    ]
+
+    def attend(q, k, v):
+        return fa.flash_attention(
+            q, k, v, causal=True, force="pallas", layout="bhtd",
+            window=window, block_q=block, block_k=block,
+        )
+
+    before = trace_counts.snapshot()
+    if direction == "fwd":
+        compiled = _compile_for_chip(attend, *qkv)
+    else:
+        compiled = _compile_for_chip(
+            jax.grad(
+                lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            ),
+            *qkv,
+        )
+    assert fa._one_pass_fits(T, D, 2)
+    stem = "flash_attn_window" if window else "flash_attn"
+    want = [f"{stem}_fwd"] + ([f"{stem}_bwd"] if direction == "bwd" else [])
+    text = compiled.as_text()
+    for kernel in want:
+        assert kernel in text, kernel
+    assert ("flash_attn_window" in text) == bool(window)
+    assert "flash_attn_bwd_d" not in text and "window_bwd_d" not in text
+    sites = len(want)
+    n = T // (block or fa._TRI_BLOCK)
+    assert under == n * (n + 1) // 2
+    assert added(before, STREAM) == (sites, 0, walked * sites, n * n * sites)
+    assert added(before, WINDOW) == (
+        (walked * sites, under * sites) if window else (0, 0)
+    )
+
+
+def test_window_attention_compiles_split_beyond_one_pass(
+    one_chip, monkeypatch
+):
+    """A head's float32 dq no longer fits VMEM: the band's dq and dk / dv
+    kernels, at the source's whole context of 131,072 (the 128 blocks a
+    side that the step tables may hold)."""
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    B, H, Hkv, T, D = 1, 8, 1, 131072, 128
+    assert not fa._one_pass_fits(T, D, 2)
+    qkv = [
+        jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+        for s in ((B, H, T, D), (B, Hkv, T, D), (B, Hkv, T, D))
+    ]
+    before = trace_counts.snapshot()
+    text = _compile_for_chip(
+        jax.grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, force="pallas", layout="bhtd",
+                window=2048,
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        ),
+        *qkv,
+    ).as_text()
+    for kernel in ("window_fwd", "window_bwd_dq", "window_bwd_dkv"):
+        assert f"flash_attn_{kernel}" in text, kernel
+    # 128 blocks a side: 1 + 2 + 126 * 3 of 8,256 a kernel, three kernels
+    assert added(before, WINDOW) == (3 * 381, 3 * 8256)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])

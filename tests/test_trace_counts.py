@@ -26,7 +26,7 @@ from dlrover_tpu.models.train import TrainState, build_train_step
 from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
-from trace_counted import CONV, FUSED, GDN, LANES, STREAM
+from trace_counted import CONV, FUSED, GDN, LANES, STREAM, WINDOW
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
 fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
@@ -99,7 +99,9 @@ def test_counting_from_many_threads_loses_nothing(fresh):
 
 def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
-    assert set(GDN + CONV + LANES) <= FIELDS - set(trace_counts.RUNNING_TOTALS)
+    assert set(GDN + CONV + LANES + WINDOW) <= FIELDS - set(
+        trace_counts.RUNNING_TOTALS
+    )
 
 
 # -- the trainer's fold -------------------------------------------------------
@@ -330,6 +332,16 @@ TOYS = {
             **_SMALL, **dict(_MIXERS, positions=""),
         ),
         (GDN, CONV, FUSED, LANES),
+    ),
+    # window and global attention layers in one model, heads grouped: the
+    # streaming kernels, the window layers' on the band
+    "window_and_global_attention": (
+        TransformerConfig(
+            num_layers=3, layer_pattern="W*W", attn_window=16,
+            num_kv_heads=1, positions="window", rmsnorm=True,
+            mixer_out_norm=True, embed_scale=True, **_SMALL,
+        ),
+        (STREAM, WINDOW, LANES),
     ),
 }
 

@@ -20,13 +20,19 @@ one jitted program, forward and forward + backward, host clock around
 - ``stream-floor``: the triangle grid with no compute in its steps
   (fetches, steps and write-backs only);
 - ``tri:<bq>[:<heads fwd>:<heads bwd>]``: the fused walk at another row
-  tile and other heads a program.
+  tile and other heads a program;
+- ``window:<W>[:<n>]``: ``stream-tri`` through a window of ``W`` keys,
+  the band its kernels walk (at blocks of ``n``);
+  ``window-mask:<W>[:<n>]``: the same window as a mask over the whole
+  triangle (every block at or under the diagonal walked, those past the
+  window's far edge wholly masked): what the band saves.
 
 A shape is ``BxHxTxD`` or, with its own key-value head count,
 ``BxHxHkvxTxD``.
 
     python tools/attn_kernel_bench.py 16x12x1024x64 fused square streaming tri:128
     python tools/attn_kernel_bench.py 1x32x2x8192x128 stream-tri stream-rect block:256
+    python tools/attn_kernel_bench.py 1x32x4x16384x128 stream-tri window:2048 window:2048:512 window-mask:2048
 """
 import importlib
 import json
@@ -48,7 +54,7 @@ STREAM = {
     name: getattr(fa, name)
     for name in (
         "_stream_plan", "_ONE_PASS_MAX_BYTES", "_tri_fwd_kernel",
-        "_tri_bwd_kernel",
+        "_tri_bwd_kernel", "_band_blocks",
     )
 }
 LAYERS = 12
@@ -69,12 +75,18 @@ def _no_compute(n_in: int, n_out: int):
 
 def _steer(variant):
     """Module constants of the variant; returns ``allow_fused`` and the
-    entry's block arguments."""
+    entry's block (and window) arguments."""
     fa._TRI_ROW_TILE = ROW_TILE
     fa._walk_head_chunk = HEAD_CHUNK
     for name, was in STREAM.items():
         setattr(fa, name, was)
     kind, *rest = variant.split(":")
+    if kind in ("window", "window-mask"):
+        if kind == "window-mask":  # a band as wide as any triangle
+            fa._band_blocks = lambda window, block: 1 << 20
+        n = int(rest[1]) if len(rest) > 1 else None
+        blocks = {"block_q": n, "block_k": n} if n else {}
+        return False, dict(blocks, window=int(rest[0]))
     if kind in ("streaming", "block") or kind.startswith("stream-"):
         if kind == "stream-rect":
             fa._stream_plan = lambda *a, **kw: None

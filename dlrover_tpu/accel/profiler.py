@@ -149,6 +149,14 @@ class PipelineStats:
     # window was only a mask; 0 / 0 for a model without a window
     attn_window_blocks_walked: int = 0
     attn_window_blocks_causal: int = 0
+    # attention kernel call sites inside a recomputed layer
+    # (``models/transformer.recomputed``, ``cfg.remat``) in the train step
+    # program this process traced last: the wrapper keeps what each one's
+    # kernel read and returned (q, k, v, ``o``, the logsumexp), so its
+    # forward kernel runs once a step and not again in the backward pass.
+    # 0 without ``remat``, and for a layer whose attention is no kernel
+    # call (the jnp path, a ring)
+    attn_kept_sites: int = 0
     # Gated DeltaNet mixers (ops/gated_delta.py) in the train step
     # program this process traced last, and the sequential chunk-state
     # steps one training step runs through them, forward and backward:

@@ -199,7 +199,11 @@ class TransformerConfig:
     # numerics
     dtype: str = "bfloat16"  # activation/compute dtype
     param_dtype: str = "float32"
-    remat: bool = False  # checkpoint each block (HBM <-> FLOPs trade)
+    # every layer is made again in the backward pass from its input (HBM
+    # <-> FLOPs trade); of what a layer computes it keeps what its
+    # attention kernel read and returned (q, k, v, ``o``, the logsumexp)
+    # alone (``models/transformer.recomputed``)
+    remat: bool = False
     # store layer params STACKED ([L, ...] leaves) and run the blocks
     # under ONE lax.scan: the traced graph is O(1) in depth instead of
     # O(L), which is what lets a 48-layer model compile WITH remat

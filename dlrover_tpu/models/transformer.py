@@ -10,9 +10,10 @@ TPU-first design notes:
   and softmax accumulate in fp32.
 - Attention: ring attention over the ``sp`` axis when a mesh is given
   (long-context path), single-device causal attention otherwise.
-- ``cfg.remat`` wraps each block in ``jax.checkpoint`` to trade FLOPs for
-  HBM (the reference's activation-checkpoint optimization,
-  atorch auto/opt_lib checkpoint entry).
+- ``cfg.remat`` wraps each layer in ``recomputed``: a ``jax.checkpoint``
+  that trades FLOPs for HBM (the reference's activation-checkpoint
+  optimization, atorch auto/opt_lib checkpoint entry) and keeps, of what
+  the layer computes, what its attention kernel read and returned alone.
 """
 
 from __future__ import annotations
@@ -33,6 +34,7 @@ from dlrover_tpu.models.config import (
     is_moe_layer,
     num_moe_layers,
 )
+from dlrover_tpu.ops.flash_attention import KEPT, keeping_outputs
 from dlrover_tpu.ops.gated_delta import (
     gated_delta_logical_axes,
     gated_delta_mixer,
@@ -844,6 +846,34 @@ def token_nll(
     return jnp.mean(nll)
 
 
+# ONE policy object: ``jax`` caches a jaxpr's split into what is kept and
+# what is made again by the policy's identity, and a policy a wrapper would
+# split every layer's inner functions anew (twice the functions in the
+# lowered step)
+_KEEP_ATTENTION_OUTPUTS = jax.checkpoint_policies.save_only_these_names(*KEPT)
+
+
+def recomputed(layer_fn):
+    """``layer_fn`` as a layer the backward pass makes again
+    (``cfg.remat``; every site that wraps a layer for it comes here). It
+    keeps its input and, of what it computes, what its attention kernel
+    read and returned alone (``ops/flash_attention.KEPT``: q, k, v after
+    head norm and rotation, ``o`` and the logsumexp, O(T D) bytes that
+    cost O(T^2 D) operations), so the backward pass makes the
+    projections, norms, gates and feed-forward again, runs the forward
+    attention kernel no second time and does not remake the stretch that
+    only feeds it. A layer without such a call (a scan, experts, the jnp
+    attention path, a ring) holds no such name and keeps its input, as a
+    bare ``jax.checkpoint`` does."""
+
+    @functools.wraps(layer_fn)
+    def traced(*args):
+        with keeping_outputs():
+            return layer_fn(*args)
+
+    return jax.checkpoint(traced, policy=_KEEP_ATTENTION_OUTPUTS)
+
+
 def forward(
     params: Params,
     tokens: jnp.ndarray,
@@ -886,8 +916,8 @@ def forward(
         x, aux = _mlp_block(x, layer, cfg, mesh, moe_axis, "norm")
         return x, aux if kind == "E" else None
 
-    if cfg.remat:
-        block = jax.checkpoint(block)
+    if cfg.remat and not cfg.layer_pattern:
+        block = recomputed(block)
     if cfg.layer_pattern:
         loads = []
         for kind, layer in zip(cfg.layer_pattern, params["layers"]):
@@ -898,7 +928,7 @@ def forward(
                 # came out of that cache is not traced, so what a trace
                 # counts (``common/trace_counts``) would be of one layer
                 # of each kind
-                one_layer = jax.checkpoint(one_layer)
+                one_layer = recomputed(one_layer)
             x, aux = one_layer(x, layer)
             if aux is not None:
                 loads.append(aux["load"])

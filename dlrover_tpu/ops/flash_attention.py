@@ -77,6 +77,17 @@ Design:
 - ``layout="bhtd"`` lets callers hand over kernel-native [B, H, T, D]
   tensors (the model emits them straight from its QKV einsums), skipping
   the 25 MB-per-tensor relayout transposes on every call.
+- **What a recomputed layer keeps**: what the forward rule hands the
+  backward rule, q, k and v as the kernel read them (after head norm and
+  rotation), ``o`` and the logsumexp, carries
+  ``jax.ad_checkpoint.checkpoint_name``s (``KEPT``). Outside a
+  ``jax.checkpoint`` whose policy saves those names a name is an identity
+  and lowers to nothing; inside one (``models/transformer.recomputed``)
+  the backward pass makes the layer's norms and projections again as far
+  as their own gradients want them and hands the backward kernel the five
+  arrays the first forward left: O(T D) bytes a head in place of O(T^2 D)
+  operations, no second forward kernel, and no second pass over the
+  stretch that only feeds it.
 
 Mask contract: ``mask_fn(q_pos, k_pos)`` receives broadcastable int32
 position arrays (shapes ``[bq, 1]`` and ``[1, bk]``) and must return an
@@ -85,13 +96,16 @@ elementwise bool mask, e.g. ``lambda q, k: q >= k`` for causal.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -394,6 +408,37 @@ _STREAM = (
     "attn_stream_blocks_walked", "attn_stream_blocks_rect",
 )
 _WINDOW = ("attn_window_blocks_walked", "attn_window_blocks_causal")
+
+# the names the forward rule gives what it hands the backward rule
+# (``_flash_fwd_rule``: q, k, v as the kernel read them, ``o``, the
+# logsumexp): what a ``jax.checkpoint`` around a layer saves of an
+# attention call when its policy is ``save_only_these_names(*KEPT)``
+KEPT = (
+    "flash_attn_q", "flash_attn_k", "flash_attn_v", "flash_attn_o",
+    "flash_attn_lse",
+)
+
+
+class _Keeping(threading.local):
+    # a speculative compile traces on a thread of its own
+    on = False
+
+
+_keeping = _Keeping()
+
+
+@contextlib.contextmanager
+def keeping_outputs():
+    """Around the trace of a function whose ``jax.checkpoint`` saves
+    ``KEPT``: a differentiable kernel call traced inside is a site of
+    ``common/trace_counts`` (``attn_kept_sites``) whose forward kernel
+    the backward pass does not run again."""
+    was = _keeping.on
+    _keeping.on = True
+    try:
+        yield
+    finally:
+        _keeping.on = was
 
 
 def _count_site(names, n: int, kernels: int = 1, walked=None):
@@ -1700,6 +1745,8 @@ def _flash_fwd_rule(
         diagonal=_on_diagonal(*offsets),
         window=window,
     )
+    # one copy of each: the named ``o`` is the primal result too
+    q, k, v, o, lse = map(checkpoint_name, (q, k, v, o, lse), KEPT)
     return o, (q, k, v, o, lse)
 
 
@@ -2214,6 +2261,7 @@ def flash_attention(
             "the differentiable pallas path needs static int offsets; "
             "use flash_attention_fwd/_bwd for traced offsets"
         )
+    trace_counts.count("attn_kept_sites", _keeping.on)
     return _flash_pallas(
         q, k, v, (q_offset, k_offset), causal, mask_fn, scale, bq, bk,
         layout, allow_fused, window

@@ -66,6 +66,7 @@ from dlrover_tpu.models.transformer import (
     init_params,
     lm_head,
     logical_axes,
+    recomputed,
     token_nll,
 )
 from dlrover_tpu.parallel.sharding_rules import (
@@ -299,7 +300,7 @@ def pipeline_forward(
             return y, None
 
         if cfg.remat:
-            body = jax.checkpoint(body)
+            body = recomputed(body)
         x, _ = lax.scan(body, x, stage_layers)
         return x
 
@@ -566,7 +567,7 @@ def pipeline_value_and_grad_1f1b(
             return block(xx, layer), None
 
         if cfg.remat:
-            body = jax.checkpoint(body)
+            body = recomputed(body)
         xx, _ = lax.scan(body, xx, stage_layers)
         return xx
 
@@ -898,7 +899,7 @@ def pipeline_value_and_grad_gpipe_sync(
             return block(xx, layer), None
 
         if cfg.remat:
-            body = jax.checkpoint(body)
+            body = recomputed(body)
         xx, _ = lax.scan(body, xx, stage_layers)
         return xx
 

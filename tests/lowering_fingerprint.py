@@ -20,7 +20,15 @@ recorded the Nemotron and Ling steps anew, their trees as they were: each
 holds a quantised leaf whose rows are no whole blocks of 128 (the experts'
 ``w_up`` ``[8, 2688, 1856]``, the latent layer's ``wq`` ``[2560, 32,
 192]``), and ``ops/quantized_optim._to_blocks`` now pads such a row to
-whole blocks so that no block of int8 moments crosses a row. Made by
+whole blocks so that no block of int8 moments crosses a row. ISSUE 51
+(a recomputed layer is a ``jax.checkpoint`` whose policy keeps what the
+attention kernels' forward rule names, where it was a bare one) left all seven
+entries as they were: the five configurations without ``remat`` never meet
+the wrapper, and on the CPU the attention of ``ling-3.0-flash-d7`` and
+``trinity-mini-d5`` lowers to the jnp path, which holds no such name, so
+their recomputed layers keep their inputs alone as before (the policy is
+one object a process: a policy a wrapper would split every layer's inner
+functions anew and double the functions of the lowered text). Made by
 running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py

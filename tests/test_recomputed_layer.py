@@ -1,0 +1,250 @@
+"""What a recomputed layer keeps (``models/transformer.recomputed``,
+``cfg.remat``): what its attention kernel read and returned, so the
+gradient holds each forward attention kernel once where a bare
+``jax.checkpoint`` holds it twice, over the four kinds of call the kernels'
+``custom_vjp`` rule sees (the streaming triangle, the window's band, the
+fused family, the latent attention's call of two widths), the gradients
+those of the layer without ``remat``, and nothing else saved. Small shapes,
+the kernels interpreted."""
+
+import ast
+import collections
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax._src.ad_checkpoint import saved_residuals
+
+from dlrover_tpu.common import trace_counts
+from dlrover_tpu.models import transformer as tr
+from dlrover_tpu.models.config import TransformerConfig
+from dlrover_tpu.parallel import pipeline
+
+# `dlrover_tpu.ops.flash_attention` the attribute is the function
+fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
+
+T = 64
+_SMALL = dict(
+    vocab_size=64, model_dim=32, num_heads=2, mlp_dim=32, max_seq_len=T,
+    dtype="float32", param_dtype="float32", rmsnorm=True,
+    tie_embeddings=False,
+)
+# a call kind: a toy model whose attention layers make that call, the
+# forward kernel it lowers to, and the width of a head there (q, k, v and
+# ``o`` alike)
+KINDS = {
+    # grouped heads: the streaming kernels, on the triangle path
+    "triangle": (
+        dict(num_layers=3, layer_pattern="*-*", num_kv_heads=1,
+             positions="none", dense_mlp_dim=32),
+        "flash_attn_fwd", 16,
+    ),
+    # the same with a window: the band's kernels
+    "window_band": (
+        dict(num_layers=2, layer_pattern="W*", num_kv_heads=1,
+             attn_window=16, positions="window", mixer_out_norm=True),
+        "flash_attn_window_fwd", 16,
+    ),
+    # as many key heads as query heads at T <= 1024: the fused family
+    "fused_family": (dict(num_layers=2), "flash_attn_fused_fwd", 16),
+    # a latent attention: scores 24 wide, values 16, called 128 wide
+    "two_widths": (
+        dict(num_layers=2, layer_pattern="*-", attn_kind="latent",
+             kv_latent_dim=16, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+             rope=True, qk_norm=True, qk_norm_span="head", dense_mlp_dim=32),
+        "flash_attn_fused_fwd", 128,
+    ),
+}
+
+
+@pytest.fixture
+def kernels(monkeypatch):
+    """The attention kernels' own path, interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret_default", lambda: True)
+
+
+def _cfg(kind, remat):
+    return TransformerConfig(**dict(_SMALL, **KINDS[kind][0]), remat=remat)
+
+
+def _inputs(cfg):
+    params = tr.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, T), 0, 64)
+    return params, tokens, jnp.roll(tokens, -1, axis=1)
+
+
+def _grad(cfg):
+    return jax.grad(lambda p, x, y: tr.loss_fn(p, x, y, cfg))
+
+
+def _kernels_in(jaxpr, found=None):
+    """Every ``pallas_call`` of ``jaxpr`` and of the jaxprs inside it, by
+    the kernel's name."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] += 1
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _kernels_in(inner, found)
+    return found
+
+
+def _attention_layers(cfg):
+    pattern = cfg.layer_pattern or "*" * cfg.num_layers
+    return sum(kind in "*W" for kind in pattern)
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_the_gradient_holds_each_forward_attention_kernel_once(
+    kind, kernels, monkeypatch
+):
+    cfg = _cfg(kind, remat=True)
+    args = _inputs(cfg)
+    before = trace_counts.snapshot()
+    kept = _kernels_in(jax.make_jaxpr(_grad(cfg))(*args).jaxpr)
+    counted = trace_counts.since(before)["attn_kept_sites"]
+    monkeypatch.setattr(tr, "recomputed", jax.checkpoint)
+    before = trace_counts.snapshot()
+    bare = _kernels_in(jax.make_jaxpr(_grad(cfg))(*args).jaxpr)
+    forward = [n for n in bare if n.endswith("_fwd")]
+    backward = [n for n in bare if "_bwd" in n]
+    layers = _attention_layers(cfg)
+    assert KINDS[kind][1] in forward and backward
+    assert sum(kept[n] for n in forward) == layers
+    assert sum(bare[n] for n in forward) == 2 * layers
+    # the backward kernels are there as often either way
+    assert {n: kept[n] for n in backward} == {n: bare[n] for n in backward}
+    # a site is counted where the wrapper keeps its outputs, and only
+    # there: each layer of a pattern, and ONE for a block that a loop calls
+    # (``jax.checkpoint`` traces it once)
+    assert counted == (layers if cfg.layer_pattern else 1)
+    assert trace_counts.since(before)["attn_kept_sites"] == 0
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_gradients_are_those_of_the_layer_without_remat(kind, kernels):
+    plain, again = _cfg(kind, remat=False), _cfg(kind, remat=True)
+    args = _inputs(plain)
+    want = jax.jit(_grad(plain))(*args)
+    got = jax.jit(_grad(again))(*args)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(got),
+        jax.tree_util.tree_leaves(want),
+    ):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 1e-6 * max(np.max(np.abs(b)), 1.0), (
+            jax.tree_util.keystr(path)
+        )
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_the_wrapper_saves_the_named_outputs_and_nothing_else(kind, kernels):
+    """Of one recomputed attention layer: beside its arguments, what the
+    kernel read (q, k, v as it was called) and returned (``o`` and the
+    logsumexp), one copy of each."""
+    cfg = _cfg(kind, remat=True)
+    layer = _inputs(cfg)[0]["layers"][0]
+    letter = (cfg.layer_pattern or "")[:1]
+    positions = jnp.broadcast_to(jnp.arange(T), (1, T))
+    x = jnp.ones((1, T, cfg.model_dim), jnp.float32)
+
+    def one_layer(x, layer):
+        if letter:
+            return tr._attention_block(
+                x, layer, cfg, None, positions, "norm", letter
+            )
+        return tr._attention_block(x, layer, cfg, None, positions)
+
+    def computed(wrap):
+        """What the layer computes and ``wrap`` saves (beside arguments
+        and constants: the positions, the kernels' offsets)."""
+        return sorted(
+            (aval.shape, str(aval.dtype)) for aval, where in saved_residuals(
+                lambda x, layer: jnp.sum(wrap(one_layer)(x, layer)), x, layer
+            )
+            if "from the argument" not in where
+            and "from a constant" not in where
+        )
+
+    heads, width = cfg.num_heads, KINDS[kind][2]
+    # the latent call hands every head its own keys, padded like the rest
+    kv_heads = cfg.num_kv_heads or heads
+    q = o = ((1, heads, T, width), "float32")
+    k = v = ((1, kv_heads, T, width), "float32")
+    assert computed(tr.recomputed) == sorted(
+        [q, k, v, o, ((1, heads, T), "float32")]
+    )
+    assert computed(jax.checkpoint) == []
+
+
+# one helper for every layer wrapped for ``cfg.remat``
+MODELS = {
+    "layer_pattern": dict(KINDS["triangle"][0]),
+    "scan_layers": dict(num_layers=2, scan_layers=True),
+    "plain_blocks": dict(num_layers=2),
+}
+
+
+@pytest.mark.parametrize("model", sorted(MODELS))
+@pytest.mark.parametrize("remat", [True, False])
+def test_every_model_wraps_its_layers_with_the_one_helper(
+    model, remat, monkeypatch
+):
+    wrapped = []
+
+    def spy(layer_fn):
+        wrapped.append(layer_fn)
+        return recomputed(layer_fn)
+
+    recomputed = tr.recomputed
+    monkeypatch.setattr(tr, "recomputed", spy)
+    cfg = TransformerConfig(**dict(_SMALL, **MODELS[model]), remat=remat)
+    params, tokens, targets = jax.eval_shape(lambda: _inputs(cfg))
+    jax.eval_shape(_grad(cfg), params, tokens, targets)
+    # a wrapper a layer of a pattern (the trace counts want each traced),
+    # one for the block that a scan or a loop calls
+    want = len(cfg.layer_pattern) if cfg.layer_pattern else 1
+    assert len(wrapped) == (want if remat else 0)
+
+
+def test_no_layer_is_wrapped_for_remat_outside_the_helper():
+    """``jax.checkpoint`` is called in ``recomputed`` and nowhere else in
+    the two modules that wrap layers; the pipeline's three sites call the
+    helper."""
+    calls = {}
+    for module in (tr, pipeline):
+        with open(module.__file__) as f:
+            tree = ast.parse(f.read())
+        calls[module] = collections.Counter(
+            ast.unparse(node.func) for node in ast.walk(tree)
+            if isinstance(node, ast.Call)
+        )
+    assert calls[tr]["jax.checkpoint"] == 1
+    assert calls[tr]["recomputed"] == 2
+    assert calls[pipeline]["jax.checkpoint"] == 0
+    assert calls[pipeline]["recomputed"] == 3
+    assert pipeline.recomputed is tr.recomputed
+
+
+def test_a_name_lowers_to_nothing_outside_a_policy(kernels, monkeypatch):
+    """The text a gradient through the kernels lowers to holds no trace of
+    the names: with no wrapper around it and under a bare
+    ``jax.checkpoint`` the program is what it was without them."""
+    q = jnp.ones((1, 2, T, 16), jnp.float32)
+
+    def lowered(wrap):
+        def attend(q):
+            return jnp.sum(fa.flash_attention(q, q, q, layout="bhtd") ** 2)
+
+        # the numbers that tell one inner function's copies apart aside
+        text = jax.jit(jax.grad(wrap(attend))).lower(q).as_text()
+        return re.sub(r"(@\w+?)_\d+\b", r"\1", text)
+
+    named = [lowered(wrap) for wrap in (lambda f: f, jax.checkpoint)]
+    assert not any(name in text for name in fa.KEPT for text in named)
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    assert named == [lowered(wrap) for wrap in (lambda f: f, jax.checkpoint)]

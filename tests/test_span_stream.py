@@ -900,8 +900,11 @@ def test_conv_kernel_sites_share_is_declared_as_its_reader_says():
         "nemotron3-nano-30b-a3b-d9.steady", "qwen3-next-80b-a3b-d4.steady",
         "ling-3.0-flash-d7.steady",
     ]
-    # PR 47's entry, the last until PR 50 appended its two readers
-    assert bench["per_layer"][-3] == {
+    # PR 47's entry, by its name: later PRs append theirs behind it
+    (entry,) = [
+        e for e in bench["per_layer"] if e["name"] == "conv.kernel_sites_share"
+    ]
+    assert entry == {
         "name": "conv.kernel_sites_share", "unit": mod.UNIT,
         "better": "higher", "source": "program_counter",
         "layer": mod.LAYER, "moves": mod.MOVES, "workloads": cells,

@@ -134,10 +134,12 @@ def test_rebuild_drops_the_executable(accel):
 # experts' share of the routing (PR 37), the streaming attention kernels'
 # four (PR 38), an incarnation's way up and the restart behind it (PR 40),
 # the shard lock's side of the due saves (PR 42), the Gated DeltaNet
-# mixers' two (PR 43), their sites in the kernels (PR 44) and the
-# convolutions' two (PR 47); how the counted ones are folded:
+# mixers' two (PR 43), their sites in the kernels (PR 44), the
+# convolutions' two (PR 47) and the attention sites whose outputs a
+# recomputed layer keeps (PR 51); how the counted ones are folded:
 # ``test_trace_counts.py``
 AS_DICT_KEYS = [
+    "attn_kept_sites",
     "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
     "attn_stream_blocks_rect",
     "attn_stream_blocks_walked", "attn_stream_rect_sites",

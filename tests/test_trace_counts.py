@@ -26,7 +26,7 @@ from dlrover_tpu.models.train import TrainState, build_train_step
 from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
-from trace_counted import CONV, FUSED, GDN, LANES, STREAM, WINDOW
+from trace_counted import CONV, FUSED, GDN, KEPT, LANES, STREAM, WINDOW
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
 fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
@@ -99,7 +99,7 @@ def test_counting_from_many_threads_loses_nothing(fresh):
 
 def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
-    assert set(GDN + CONV + LANES + WINDOW) <= FIELDS - set(
+    assert set(GDN + CONV + LANES + WINDOW + KEPT) <= FIELDS - set(
         trace_counts.RUNNING_TOTALS
     )
 
@@ -253,6 +253,23 @@ FOLDS = {
         {"conv_sites": 2},
         ("; traced: conv_sites =2", dict(zip(CONV, (2, 0)))),
     ],
+    # a recomputed model of five attention layers, then the same without
+    # ``remat``: every site is asked, so the field falls back to 0
+    "attention_outputs_a_recomputed_layer_keeps": [
+        "step_donating",
+        dict(zip(LANES + KEPT, (640, 640, 5))),
+        (
+            "; traced: attn_score_lanes =640, attn_score_lanes_used =640, "
+            "attn_kept_sites =5",
+            dict(zip(LANES + KEPT, (640, 640, 5))),
+        ),
+        "step_donating",
+        dict(zip(LANES + KEPT, (640, 640, 0))),
+        (
+            "; traced: attn_score_lanes =640, attn_score_lanes_used =640",
+            dict(zip(LANES + KEPT, (640, 640, 0))),
+        ),
+    ],
     # both scopes on one line: the kernels' totals first
     "both_scopes_on_one_line": [
         "step_safe",
@@ -310,8 +327,11 @@ _MIXERS = dict(
 # names a traced train step of it counts under
 TOYS = {
     "dense": (TransformerConfig(num_layers=2, **_SMALL), (FUSED, LANES)),
+    # the block a loop calls is traced once under ``jax.checkpoint``: one
+    # site whose outputs the wrapper keeps
     "dense_remat": (
-        TransformerConfig(num_layers=2, remat=True, **_SMALL), (FUSED, LANES)
+        TransformerConfig(num_layers=2, remat=True, **_SMALL),
+        (FUSED, LANES, KEPT),
     ),
     "grouped_queries": (
         TransformerConfig(num_layers=1, num_kv_heads=1, **_SMALL),

@@ -157,6 +157,15 @@ class PipelineStats:
     # 0 without ``remat``, and for a layer whose attention is no kernel
     # call (the jnp path, a ring)
     attn_kept_sites: int = 0
+    # layers that hold a share of the experts (``parallel/moe._moe_share``)
+    # in the train step program this process traced last: the first round
+    # of each, all its rounds unless the share is overloaded, is
+    # differentiated in line and keeps what its backward pass reads (the
+    # gathered rows, the grouped matmuls' results; under ``remat`` by
+    # name), so its gather, grouped matmuls and activation run once a
+    # step and not again in the backward pass. 0 for a model that holds
+    # every expert, or none
+    moe_share_kept_sites: int = 0
     # Gated DeltaNet mixers (ops/gated_delta.py) in the train step
     # program this process traced last, and the sequential chunk-state
     # steps one training step runs through them, forward and backward:

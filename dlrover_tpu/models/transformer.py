@@ -34,7 +34,10 @@ from dlrover_tpu.models.config import (
     is_moe_layer,
     num_moe_layers,
 )
-from dlrover_tpu.ops.flash_attention import KEPT, keeping_outputs
+from dlrover_tpu.ops.flash_attention import (
+    KEPT as ATTENTION_KEPT,
+    keeping_outputs,
+)
 from dlrover_tpu.ops.gated_delta import (
     gated_delta_logical_axes,
     gated_delta_mixer,
@@ -46,6 +49,7 @@ from dlrover_tpu.ops.mamba2 import (
     mamba2_mixer,
 )
 from dlrover_tpu.parallel.moe import (
+    KEPT as SHARE_KEPT,
     MoEParams,
     init_moe_params,
     moe_layer,
@@ -850,28 +854,32 @@ def token_nll(
 # what is made again by the policy's identity, and a policy a wrapper would
 # split every layer's inner functions anew (twice the functions in the
 # lowered step)
-_KEEP_ATTENTION_OUTPUTS = jax.checkpoint_policies.save_only_these_names(*KEPT)
+KEPT = ATTENTION_KEPT + SHARE_KEPT
+_KEEP_BY_NAME = jax.checkpoint_policies.save_only_these_names(*KEPT)
 
 
 def recomputed(layer_fn):
     """``layer_fn`` as a layer the backward pass makes again
     (``cfg.remat``; every site that wraps a layer for it comes here). It
-    keeps its input and, of what it computes, what its attention kernel
-    read and returned alone (``ops/flash_attention.KEPT``: q, k, v after
-    head norm and rotation, ``o`` and the logsumexp, O(T D) bytes that
-    cost O(T^2 D) operations), so the backward pass makes the
-    projections, norms, gates and feed-forward again, runs the forward
-    attention kernel no second time and does not remake the stretch that
-    only feeds it. A layer without such a call (a scan, experts, the jnp
-    attention path, a ring) holds no such name and keeps its input, as a
-    bare ``jax.checkpoint`` does."""
+    keeps its input and, of what it computes, what the modules named for
+    it alone (``KEPT``): what its attention kernel read and returned
+    (``ops/flash_attention.KEPT``: q, k, v after head norm and rotation,
+    ``o`` and the logsumexp, O(T D) bytes that cost O(T^2 D) operations)
+    and what the first round of a share of the experts gathered and its
+    grouped matmuls returned (``parallel/moe.KEPT``). So the backward
+    pass makes the projections, norms, gates, router and dense
+    feed-forward again, runs the forward attention kernel and the held
+    experts' grouped matmuls no second time and does not remake the
+    stretch that only feeds them. A layer without such a call (a scan,
+    dropless experts, the jnp attention path, a ring) holds no such name
+    and keeps its input, as a bare ``jax.checkpoint`` does."""
 
     @functools.wraps(layer_fn)
     def traced(*args):
         with keeping_outputs():
             return layer_fn(*args)
 
-    return jax.checkpoint(traced, policy=_KEEP_ATTENTION_OUTPUTS)
+    return jax.checkpoint(traced, policy=_KEEP_BY_NAME)
 
 
 def forward(

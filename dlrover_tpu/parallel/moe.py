@@ -29,6 +29,9 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from dlrover_tpu.common import trace_counts
 
 
 class MoEParams(NamedTuple):
@@ -370,6 +373,14 @@ def share_rows(assignments: int, count: int, num_experts: int) -> int:
     return min(rows, -(-assignments // 8) * 8)
 
 
+# the names the first round of a share gives what its backward pass reads
+# and no elementwise pass makes again: the gathered rows, what each grouped
+# matmul hands the activation, what the round returns. What a
+# ``jax.checkpoint`` around a layer saves of it when its policy holds them
+# (``models/transformer.recomputed``); anywhere else a name is an identity
+KEPT = ("moe_share_xs", "moe_share_h", "moe_share_ys")
+
+
 def _moe_share(params: MoEParams, x, idx, gates, counts, activation, held):
     """A chip's share of the experts, ``held = (offset, count)``: the
     router scored all ``E`` experts, the weights are those of ``count``
@@ -391,90 +402,111 @@ def _moe_share(params: MoEParams, x, idx, gates, counts, activation, held):
     ``lax.ragged_dot`` leaves there is undefined on the TPU (zeros at one
     shape, NaN at another), in its result and in the cotangent it hands
     back: every buffer of a round is zeroed there, which zeroes the
-    cotangents too, so nothing undefined reaches a token or a weight."""
+    cotangents too, so nothing undefined reaches a token or a weight.
+
+    The first round, all of them unless the share is overloaded, is
+    differentiated in line: the backward pass reads the gathered rows and
+    the grouped matmuls' results it left (``KEPT``) and runs no forward
+    work for it (a site of ``common/trace_counts``,
+    ``moe_share_kept_sites``). Only the rounds past it are made again in
+    the backward pass (``rounds``), which adds their cotangents onto the
+    first round's."""
     offset, count = held
     T, model = x.shape
     k = idx.shape[1]
     num_experts = counts.shape[0]
     R = share_rows(T * k, count, num_experts)
+    name_xs, name_h, name_ys = KEPT
 
-    def one_round(x, experts, flat_gates, order, starts, ends, lo):
-        """[T, model] float32: what the held rows lo .. lo + R of the
-        sorted assignments add to their tokens."""
+    def one_round(onto, x, experts, flat_gates, order, starts, ends, lo):
+        """``onto`` [T, model] float32 and what the held rows lo .. lo + R
+        of the sorted assignments add to their tokens."""
         n_held = ends[-1]
         with jax.named_scope("scope/layer/moe/dispatch"):
             mine = lax.dynamic_slice(order, (lo,), (R,))
             real = (lo + jnp.arange(R) < n_held)[:, None]
             token = mine // k
-            xs = jnp.where(real, x[token], 0)
+            xs = checkpoint_name(jnp.where(real, x[token], 0), name_xs)
             sizes = jnp.clip(ends, lo, lo + R) - jnp.clip(starts, lo, lo + R)
+
+        def matmul(a, w):
+            out = jnp.where(real, lax.ragged_dot(a, w, sizes), 0)
+            # what the activation reads, or what the round returns
+            return checkpoint_name(out, name_h if a is xs else name_ys)
+
         with jax.named_scope("scope/layer/moe/experts"):
             ys = _expert_ffn(
-                MoEParams(None, *experts),
-                lambda a, w: jnp.where(real, lax.ragged_dot(a, w, sizes), 0),
-                xs,
-                activation,
+                MoEParams(None, *experts), matmul, xs, activation
             )
         with jax.named_scope("scope/layer/moe/combine"):
             weight = jnp.where(real, flat_gates[mine][:, None], 0.0)
-            return jnp.zeros((T, model), jnp.float32).at[token].add(
-                ys.astype(jnp.float32) * weight
-            )
+            return onto.at[token].add(ys.astype(jnp.float32) * weight)
 
     def over_rounds(round_fn, acc, ends):
-        """``acc`` after as many rounds as the held rows need."""
+        """``acc`` after the rounds past the first, as many as the held
+        rows need: none where they fit one round."""
         return lax.fori_loop(
-            0, (ends[-1] + R - 1) // R,
+            1, (ends[-1] + R - 1) // R,
             lambda i, acc: round_fn(acc, i * R), acc,
         )
 
-    # Differentiated by hand: the rounds are a loop of as many trips as
-    # there are held rows to take (which ``jax.grad`` cannot reverse), a
-    # round is made again in the backward pass, and its cotangents are
-    # ADDED into one accumulator each. ``jax.grad`` of a scan over all
-    # possible rounds kept every round's residuals, the expert matrices
-    # among them, and added into the accumulators in skipped rounds too:
-    # 16.5 GiB of temporaries and 125 ms a step (PERF.md, PR 37).
+    # Differentiated by hand: the rounds past the first are a loop of as
+    # many trips as there are held rows to take (which ``jax.grad`` cannot
+    # reverse), a round is made again in the backward pass, and its
+    # cotangents are ADDED into one accumulator each. ``jax.grad`` of a
+    # scan over all possible rounds kept every round's residuals, the
+    # expert matrices among them, and added into the accumulators in
+    # skipped rounds too: 16.5 GiB of temporaries and 125 ms a step
+    # (PERF.md, PR 37). It hands its differentiated arguments on, and the
+    # first round reads them from there: so the first round's cotangents
+    # arrive in the backward rule and ARE the accumulators, and a loop of
+    # no trip neither zeroes nor adds an array the experts' size.
     @jax.custom_vjp
     def rounds(x, experts, flat_gates, order, starts, ends):
-        return over_rounds(
-            lambda out, lo: out + one_round(
-                x, experts, flat_gates, order, starts, ends, lo
+        out = over_rounds(
+            lambda out, lo: one_round(
+                out, x, experts, flat_gates, order, starts, ends, lo
             ),
             jnp.zeros((T, model), jnp.float32), ends,
         )
+        return out, (x, experts, flat_gates)
 
     def rounds_fwd(x, experts, flat_gates, order, starts, ends):
         res = (x, experts, flat_gates, order, starts, ends)
         return rounds(*res), res
 
-    def rounds_bwd(res, d_out):
+    def rounds_bwd(res, cotangents):
         x, experts, flat_gates, order, starts, ends = res
+        d_out, of_first_round = cotangents
+        nothing = jnp.zeros((T, model), jnp.float32)
 
         def pull(acc, lo):
             _, vjp = jax.vjp(
-                lambda *diff: one_round(*diff, order, starts, ends, lo),
+                lambda *diff: one_round(
+                    nothing, *diff, order, starts, ends, lo
+                ),
                 x, experts, flat_gates,
             )
             return jax.tree_util.tree_map(jnp.add, acc, vjp(d_out))
 
-        zeros = jax.tree_util.tree_map(
-            jnp.zeros_like, (x, experts, flat_gates)
-        )
-        return (*over_rounds(pull, zeros, ends), None, None, None)
+        return (*over_rounds(pull, of_first_round, ends), None, None, None)
 
     rounds.defvjp(rounds_fwd, rounds_bwd)
 
+    trace_counts.count("moe_share_kept_sites")
     with jax.named_scope("scope/layer/moe/dispatch"):
         key = ((idx - offset) % num_experts).reshape(T * k)
         order = jnp.argsort(key, stable=True)  # assignment j is token j // k
         order = jnp.pad(order, (0, -(-T * k // R) * R - T * k))
         ends = jnp.cumsum(counts[offset:offset + count])
         starts = ends - counts[offset:offset + count]
-    out = rounds(
+    out, share = rounds(
         x, (params.w_up, params.w_down, params.w_gate),
         gates.reshape(T * k), order, starts, ends,
     )
+    # the first round is every share's, and ``jax.grad``'s to reverse: the
+    # backward pass reads what it left and makes nothing of it again
+    out = one_round(out, *share, order, starts, ends, 0)
     return out.astype(x.dtype)
 
 

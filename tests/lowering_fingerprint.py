@@ -28,8 +28,16 @@ the wrapper, and on the CPU the attention of ``ling-3.0-flash-d7`` and
 ``trinity-mini-d5`` lowers to the jnp path, which holds no such name, so
 their recomputed layers keep their inputs alone as before (the policy is
 one object a process: a policy a wrapper would split every layer's inner
-functions anew and double the functions of the lowered text). Made by
-running this file there:
+functions anew and double the functions of the lowered text). ISSUE 52
+recorded the steps of the four configurations that hold a share of the
+experts anew (``nemotron3-nano-30b-a3b-d9``, ``qwen3-next-80b-a3b-d4``,
+``ling-3.0-flash-d7``, ``trinity-mini-d5``), their trees as they were:
+the first round of ``parallel/moe._moe_share`` is differentiated in line
+and keeps what its backward pass reads, only the rounds past it stay in
+the hand-differentiated loop, and the two recomputing ones' policy saves
+the round's three names beside the attention kernels'. The other three
+entries (no share: ``_moe_dropless`` or no experts) are as they were.
+Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
 

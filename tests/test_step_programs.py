@@ -135,9 +135,10 @@ def test_rebuild_drops_the_executable(accel):
 # four (PR 38), an incarnation's way up and the restart behind it (PR 40),
 # the shard lock's side of the due saves (PR 42), the Gated DeltaNet
 # mixers' two (PR 43), their sites in the kernels (PR 44), the
-# convolutions' two (PR 47) and the attention sites whose outputs a
-# recomputed layer keeps (PR 51); how the counted ones are folded:
-# ``test_trace_counts.py``
+# convolutions' two (PR 47), the attention sites whose outputs a
+# recomputed layer keeps (PR 51) and the share layers whose first round
+# keeps what its backward pass reads (PR 52); how the counted ones are
+# folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
     "attn_kept_sites",
     "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
@@ -155,7 +156,8 @@ AS_DICT_KEYS = [
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",
     "grad_sync_ms", "grad_sync_path", "lock_local_answers",
     "moe_drop_rate_sum",
-    "moe_held_share_sum", "moe_max_load_sum", "moe_reports", "opt_q8_blocks_elems",
+    "moe_held_share_sum", "moe_max_load_sum", "moe_reports",
+    "moe_share_kept_sites", "opt_q8_blocks_elems",
     "opt_q8_tiles_elems", "overlap_pct_measured", "prefetch_hits",
     "prefetch_misses", "prefetch_overlap_pct", "prefetch_reprimes",
     "prefetch_wait_s", "recover_detect_tick_s", "recover_persist_s",

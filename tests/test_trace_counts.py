@@ -26,7 +26,9 @@ from dlrover_tpu.models.train import TrainState, build_train_step
 from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
-from trace_counted import CONV, FUSED, GDN, KEPT, LANES, STREAM, WINDOW
+from trace_counted import (
+    CONV, FUSED, GDN, KEPT, LANES, SHARE, STREAM, WINDOW,
+)
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
 fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
@@ -99,7 +101,7 @@ def test_counting_from_many_threads_loses_nothing(fresh):
 
 def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
-    assert set(GDN + CONV + LANES + WINDOW + KEPT) <= FIELDS - set(
+    assert set(GDN + CONV + LANES + WINDOW + KEPT + SHARE) <= FIELDS - set(
         trace_counts.RUNNING_TOTALS
     )
 
@@ -270,6 +272,27 @@ FOLDS = {
             dict(zip(LANES + KEPT, (640, 640, 0))),
         ),
     ],
+    # four layers that hold a share of the experts beside five attention
+    # layers, recomputed: the reference check's forward pass counted
+    # before the step's build is not in it, and a model that holds every
+    # expert built later resets the field
+    "share_layers_whose_first_round_is_kept": [
+        dict(zip(SHARE, (4,))),
+        "step_donating",
+        dict(zip(LANES + KEPT + SHARE, (640, 640, 5, 4))),
+        (
+            # in the order the names were first counted in
+            "; traced: moe_share_kept_sites =4, attn_score_lanes =640, "
+            "attn_score_lanes_used =640, attn_kept_sites =5",
+            dict(zip(LANES + KEPT + SHARE, (640, 640, 5, 4))),
+        ),
+        "step_donating",
+        dict(zip(LANES, (640, 640))),
+        (
+            "; traced: attn_score_lanes =640, attn_score_lanes_used =640",
+            dict(zip(LANES + KEPT + SHARE, (640, 640, 0, 0))),
+        ),
+    ],
     # both scopes on one line: the kernels' totals first
     "both_scopes_on_one_line": [
         "step_safe",
@@ -323,6 +346,11 @@ _MIXERS = dict(
     gdn_chunk=16, positions="none", rmsnorm=True, tie_embeddings=False,
     dense_mlp_dim=32,
 )
+_SHARE = dict(
+    num_layers=4, layer_pattern="*E*E", num_experts=8, moe_top_k=2,
+    experts_held=2, router="sigmoid", shared_expert_dim=16,
+    positions="none", rmsnorm=True, tie_embeddings=False,
+)
 # one toy a family of the benchmark's configurations, and the families of
 # names a traced train step of it counts under
 TOYS = {
@@ -352,6 +380,15 @@ TOYS = {
             **_SMALL, **dict(_MIXERS, positions=""),
         ),
         (GDN, CONV, FUSED, LANES),
+    ),
+    # two of eight experts held in each of two layers, then the same
+    # recomputed: a share layer is one site either way
+    "a_share_of_the_experts": (
+        TransformerConfig(**_SHARE, **_SMALL), (FUSED, LANES, SHARE),
+    ),
+    "a_share_of_the_experts_remat": (
+        TransformerConfig(remat=True, **_SHARE, **_SMALL),
+        (FUSED, LANES, KEPT, SHARE),
     ),
     # window and global attention layers in one model, heads grouped: the
     # streaming kernels, the window layers' on the band

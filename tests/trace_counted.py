@@ -12,6 +12,7 @@ GDN = ("gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites")
 CONV = ("conv_sites", "conv_kernel_sites")
 LANES = ("attn_score_lanes", "attn_score_lanes_used")
 KEPT = ("attn_kept_sites",)
+SHARE = ("moe_share_kept_sites",)
 
 
 def added(before, names):

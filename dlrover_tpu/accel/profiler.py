@@ -177,13 +177,39 @@ class PipelineStats:
     gdn_chunk_steps: int = 0
     gdn_kernel_sites: int = 0
     # convolution stretches before a scan (``ops/mamba2.conv_silu``: one
-    # a Mamba-2 or Gated DeltaNet mixer) in the train step program this
+    # a Mamba-2, Gated DeltaNet or Mamba-1 mixer) in the train step program
+    # this
     # process traced last, and those among them that were traced into the
     # ``conv_silu_*`` kernels (``ops/conv_kernels.fits``). Both counted at
     # one place, so a layer traced twice under ``jax.checkpoint`` counts
     # twice in both; 0 / 0 for a model without them
     conv_sites: int = 0
     conv_kernel_sites: int = 0
+    # selective scans (``ops/selective_scan.selective_scan``: one a
+    # Mamba-1 mixer) in the train step program this process traced last,
+    # those among them that were traced into the ``sscan_*`` kernels
+    # (``ops/selective_scan.fits``), and the steps one training step's
+    # scans walk in order: T a forward pass, 2 T a backward pass (a
+    # block's states made again, then walked from the end), so a layer
+    # traced twice under ``jax.checkpoint`` counts its forward twice;
+    # 0 / 0 / 0 for a model without the kind
+    sscan_sites: int = 0
+    sscan_kernel_sites: int = 0
+    sscan_serial_steps: int = 0
+    # differential attention (``models/transformer._diff_attention``) in
+    # the train step program this process traced last: the head pairs
+    # summed over its sites, and the attention calls' score heads over
+    # two, summed likewise: equal where each of a pair's two score maps
+    # is computed once (one call at the value pair's width), twice the
+    # pairs where a pair's scores are computed once a value half
+    attn_diff_pairs: int = 0
+    attn_diff_score_calls: int = 0
+    # layers of the train step program this process traced last that read
+    # what another layer computed and not the residual stream alone: gated
+    # memory units (a scan layer's output) and cross-attentions (one
+    # layer's keys and values)
+    xdec_memory_reads: int = 0
+    xdec_kv_reads: int = 0
     # the width of a head's query and key summed over the attention sites
     # of the train step program this process traced last
     # (models/transformer.py): what the attention call was given, and

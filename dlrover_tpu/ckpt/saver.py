@@ -646,7 +646,12 @@ class AsyncCheckpointSaver:
         # must get its chance to publish the tracker
         deadline = time.time() + drain_timeout
         for step, t in list(self._commit_threads.items()):
-            t.join(timeout=max(0.0, deadline - time.time()))
+            try:
+                t.join(timeout=max(0.0, deadline - time.time()))
+            except RuntimeError:
+                # registered by the event loop and not started yet (it
+                # registers first so that the thread can pop itself)
+                continue
             if t.is_alive():
                 logger.warning(
                     f"commit of step {step} still pending at shutdown"

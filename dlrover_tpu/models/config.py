@@ -1,10 +1,18 @@
 """Model-family configs.
 
-Covers the reference's benchmark families: GPT-2 (nanogpt / GPT-2 xl 1.5B
-flash-ckpt benchmarks, BASELINE.md) and Llama-2 (atorch/examples/llama2).
-One config dataclass switches the architectural differences (learned vs
+One config dataclass switches the architectural differences. Without a
+``layer_pattern`` every layer is the attention + FFN block of the
+reference's benchmark families, GPT-2 (nanogpt / GPT-2 xl 1.5B flash-ckpt
+benchmarks, BASELINE.md) and Llama-2 (atorch/examples/llama2): learned vs
 rotary positions, LayerNorm vs RMSNorm, GELU-MLP vs SwiGLU, MHA vs GQA,
-optional MoE blocks).
+optional MoE blocks. With one, every layer is ONE mixer of the kind its
+letter names (``LAYER_KINDS``), which is how the hybrid families are
+written: ``nemotron_h`` (Mamba-2, attention, experts), ``qwen3_next``
+(Gated DeltaNet, gated attention, experts), Ling's (Kimi Delta Attention,
+latent attention), ``afmoe`` (window and global attention) and
+``phi4flash`` (Mamba-1 scans, differential attention through a window or
+whole, and a cross-decoder whose layers read what an earlier layer
+computed).
 """
 
 from __future__ import annotations
@@ -12,16 +20,24 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Optional, Tuple
 
-# layer_pattern's alphabet (nemotron_h's, and "G") -> the key of the
-# layer's one mixer in the parameter tree: Mamba-2, the gated delta rule
-# (Gated DeltaNet's, or with ``gdn_decay`` "channel" Kimi Delta
-# Attention's), attention, experts, the dense feed-forward, and "W":
-# attention through a window of ``attn_window`` keys (the parameters of
-# "*", so its key)
+# layer_pattern's alphabet (nemotron_h's, and "G", "W", "S", "U", "C") ->
+# the key of the layer's one mixer in the parameter tree. Nine kinds:
+# "M" Mamba-2, "G" the gated delta rule (Gated DeltaNet's, or with
+# ``gdn_decay`` "channel" Kimi Delta Attention's), "*" attention, "E"
+# experts, "-" the dense feed-forward, "W" attention through a window of
+# ``attn_window`` keys (the parameters of "*", so its key), "S" a Mamba-1
+# selective scan (``ops/selective_scan.py``), "U" a gated memory unit that
+# reads the scan output of the last "S" before it, and "C" a
+# cross-attention that projects queries only and reads the keys and
+# values of the last "*" before it
 LAYER_KINDS = {
     "M": "ssm", "G": "gdn", "*": "attn", "E": "moe", "-": "mlp",
-    "W": "attn",
+    "W": "attn", "S": "sscan", "U": "gmu", "C": "xattn",
 }
+# the kinds whose layer reads what another layer computed, and the kind
+# of the layer each reads (``TransformerConfig.__post_init__`` refuses a
+# pattern that has no such layer before the reader)
+LAYER_READS = {"U": "S", "C": "*"}
 
 
 @dataclass(frozen=True)
@@ -41,12 +57,22 @@ class TransformerConfig:
     # Mamba-2 layer, "*" an attention layer, "E" an expert layer, and
     # "-" a dense feed-forward layer of ``dense_mlp_dim``, and "G" a
     # Gated DeltaNet layer, "W" an attention layer whose queries see
-    # themselves and the ``attn_window - 1`` keys before them; each layer
+    # themselves and the ``attn_window - 1`` keys before them, "S" a
+    # Mamba-1 selective-scan layer, "U" a gated memory unit and "C" a
+    # cross-attention layer (``LAYER_KINDS``; the pattern alone says
+    # which layer reads which: ``LAYER_READS``); each layer
     # is ONE mixer, ``x + mixer(norm(x))``, so a block of mixer then
     # experts is two entries ("GEGEGE*E": one period of ``qwen3_next``;
-    # "WEWE*EWE": one of ``afmoe``). "" = every layer is the attention +
-    # FFN block (``moe_every`` places the experts).
+    # "WEWE*EWE": one of ``afmoe``; "S-W-S-*-U-C-": ``phi4flash``'s two
+    # periods around its one full attention). "" = every layer is the
+    # attention + FFN block (``moe_every`` places the experts).
     layer_pattern: str = ""
+    # the published index of the pattern's first mixer layer, for what
+    # depends on a layer's place in the whole model (differential
+    # attention's ``lambda_init``): mixer n of the pattern (the layers
+    # that are no "-" and no "E", from 0) is published layer
+    # ``first_layer + n``
+    first_layer: int = 0
     # keys a query of a "W" layer sees, itself among them (a "*" layer
     # sees every key before it); 0 = the pattern has no "W"
     attn_window: int = 0
@@ -94,8 +120,17 @@ class TransformerConfig:
     # query and key are ``qk_nope_dim`` unrotated dims then
     # ``qk_rope_dim`` rotated ones, its value ``v_head_dim`` (MLA,
     # DeepSeek-V2's, the query projected whole); as many key/value heads
-    # as query heads, ``attn_head_dim`` and ``rope_dim`` unused
+    # as query heads, ``attn_head_dim`` and ``rope_dim`` unused.
+    # "diff" => differential attention (arXiv:2410.05258): query heads
+    # ``(2i, 2i+1)`` and key heads ``(2j, 2j+1)`` are pairs, the two
+    # values of a key pair one value twice as wide, and a pair's output
+    # is ``(softmax(q1 k1) - lambda softmax(q2 k2)) v`` through an RMSNorm
+    # of its own (``models/transformer._diff_attention``); even head
+    # counts, no output gate, no q / k norm, no rotation
     attn_kind: str = ""
+    # the projected attention's q, k, v and output projections carry a
+    # bias (the "diff" kind's and the "C" layers' alone)
+    attn_bias: bool = False
     kv_latent_dim: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
@@ -169,6 +204,17 @@ class TransformerConfig:
     ssm_dt_min: float = 1e-3
     ssm_dt_max: float = 0.1
     ssm_dt_floor: float = 1e-4
+    # Mamba-1 layers ("S"): ``sscan_inner`` channels, each with a state of
+    # ``sscan_state`` numbers and a decay matrix ``A[channel, state]``;
+    # the step a channel comes through a bottleneck of ``sscan_dt_rank``;
+    # ``sscan_conv`` taps; the plain statement's chunk. The step's range
+    # at init is ``ssm_dt_min / max / floor``. A "U" layer's width is the
+    # scan's inner width
+    sscan_inner: int = 0
+    sscan_state: int = 16
+    sscan_dt_rank: int = 0
+    sscan_conv: int = 4
+    sscan_chunk: int = 128
     # Gated DeltaNet layers ("G"): value heads of ``gdn_value_dim``, each
     # ``gdn_value_heads / gdn_key_heads`` of them reading one key head of
     # ``gdn_key_dim``; the causal convolution's taps and the chunk of the
@@ -239,6 +285,26 @@ class TransformerConfig:
                 )
         if self.positions not in ("", "none", "window"):
             raise ValueError(f"unknown positions {self.positions!r}")
+        seen = set()
+        for kind in self.layer_pattern:
+            if kind in LAYER_READS and LAYER_READS[kind] not in seen:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r}: a "
+                    f"{kind!r} layer reads what the last "
+                    f"{LAYER_READS[kind]!r} layer before it computed, "
+                    "and there is none"
+                )
+            seen.add(kind)
+        if set("SU") & seen and min(
+            self.sscan_inner, self.sscan_state, self.sscan_dt_rank,
+            self.sscan_conv,
+        ) < 1:
+            raise ValueError(
+                "the \"S\" and \"U\" layers need sscan_inner "
+                f"({self.sscan_inner}), sscan_state ({self.sscan_state}), "
+                f"sscan_dt_rank ({self.sscan_dt_rank}) and sscan_conv "
+                f"({self.sscan_conv})"
+            )
         windowed = "W" in self.layer_pattern
         if isinstance(self.attn_window, bool) or (
             self.attn_window < 1 if windowed else self.attn_window
@@ -248,10 +314,33 @@ class TransformerConfig:
                 f"\"W\" layers of layer_pattern {self.layer_pattern!r}: "
                 "1 or more keys where there are such layers, else 0"
             )
-        if windowed and self.attn_kind:
+        if windowed and self.attn_kind not in ("", "diff"):
             raise ValueError(
-                "a window is of the projected attention: attn_kind "
-                f"{self.attn_kind!r} knows none"
+                "a window is of the projected attention, plain or "
+                f"differential: attn_kind {self.attn_kind!r} knows none"
+            )
+        if "C" in self.layer_pattern and self.attn_kind != "diff":
+            raise ValueError(
+                "a \"C\" layer is a differential cross-attention: "
+                f"attn_kind is {self.attn_kind!r}"
+            )
+        if self.attn_kind == "diff" and (
+            self.num_heads % 2 or self.kv_heads % 2
+            or (self.num_heads // 2) % (self.kv_heads // 2)
+            or self.attn_gate or self.qk_norm or self.rope_dim
+            or not self.layer_pattern
+            or self.position_kind == "rope"
+        ):
+            raise ValueError(
+                "differential attention pairs its heads: an even number "
+                f"of query ({self.num_heads}) and of key/value heads "
+                f"({self.kv_heads}), the query pairs a multiple of the "
+                "key pairs, in a layer_pattern, with no output gate, no "
+                "q / k norm and no rotation"
+            )
+        if self.attn_bias and self.attn_kind != "diff":
+            raise ValueError(
+                "attn_bias is of the differential attention's projections"
             )
         if self.positions == "window" and not windowed:
             raise ValueError(
@@ -273,7 +362,7 @@ class TransformerConfig:
             ("norm_weight", ("", "one_plus")),
             ("qk_norm_span", ("token", "head")),
             ("attn_gate", ("", "sigmoid")),
-            ("attn_kind", ("", "latent")),
+            ("attn_kind", ("", "latent", "diff")),
             ("shared_expert_gate", ("", "sigmoid")),
             ("gdn_decay", ("head", "channel")),
             ("gdn_gate", ("silu", "head_sigmoid")),

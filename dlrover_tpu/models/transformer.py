@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -30,6 +31,7 @@ from jax import lax
 from dlrover_tpu.common import trace_counts
 from dlrover_tpu.models.config import (
     LAYER_KINDS,
+    LAYER_READS,
     TransformerConfig,
     is_moe_layer,
     num_moe_layers,
@@ -55,6 +57,14 @@ from dlrover_tpu.parallel.moe import (
     moe_layer,
     moe_layer_local,
     relu2,
+)
+from dlrover_tpu.ops.selective_scan import (
+    init_memory_unit_params,
+    init_selective_scan_params,
+    memory_unit_logical_axes,
+    memory_unit_mixer,
+    selective_scan_logical_axes,
+    selective_scan_mixer,
 )
 from dlrover_tpu.parallel.ring_attention import ring_self_attention
 
@@ -127,11 +137,39 @@ def init_params(key, cfg: TransformerConfig) -> Params:
             }
         # with an output gate a head's projection is [query | gate]
         q_width = 2 * hd if cfg.attn_gate else hd
-        return {
+        a = {
             "wq": dense(next(keys), (d, h, q_width), d),
             "wk": dense(next(keys), (d, kvh, hd), d),
             "wv": dense(next(keys), (d, kvh, hd), d),
             "wo": dense(next(keys), (h, hd, d), h * hd),
+        }
+        if cfg.attn_kind == "diff":
+            a.update(differential())
+            if cfg.attn_bias:
+                a["bk"] = jnp.zeros((kvh, hd), pd)
+                a["bv"] = jnp.zeros((kvh, hd), pd)
+        return a
+
+    def differential():
+        """What differential attention adds to a layer's projections:
+        the four vectors of its ``lambda`` (normal, 0.1), the RMSNorm of
+        a pair's output, and the biases of the query and output
+        projections where ``attn_bias``."""
+        extra = {
+            name: (0.1 * jax.random.normal(next(keys), (hd,))).astype(pd)
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+        }
+        extra["subln"] = jnp.ones((2 * hd,), pd)
+        if cfg.attn_bias:
+            extra["bq"] = jnp.zeros((h, hd), pd)
+            extra["bo"] = jnp.zeros((d,), pd)
+        return extra
+
+    def cross_attention():
+        return {
+            "wq": dense(next(keys), (d, h, hd), d),
+            "wo": dense(next(keys), (h, hd, d), h * hd),
+            **differential(),
         }
 
     def dense_mlp(width):
@@ -164,6 +202,9 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         "W": attention,
         "E": experts,
         "-": lambda: dense_mlp(cfg.dense_mlp_dim or f),
+        "S": lambda: init_selective_scan_params(next(keys), cfg, pd),
+        "U": lambda: init_memory_unit_params(next(keys), cfg, pd),
+        "C": cross_attention,
     }
 
     def layer_norm():
@@ -244,11 +285,35 @@ def logical_axes(cfg: TransformerConfig) -> Params:
                 "w_kvb": (None, "heads", "head_dim"),
                 "wo": ("heads", "head_dim", "embed"),
             }
-        return {
+        a = {
             "wq": ("embed", "heads", "head_dim"),
             "wk": ("embed", "kv_heads", "head_dim"),
             "wv": ("embed", "kv_heads", "head_dim"),
             "wo": ("heads", "head_dim", "embed"),
+        }
+        if cfg.attn_kind == "diff":
+            a.update(differential())
+            if cfg.attn_bias:
+                a["bk"] = ("kv_heads", "head_dim")
+                a["bv"] = ("kv_heads", "head_dim")
+        return a
+
+    def differential():
+        extra = {
+            name: ("head_dim",)
+            for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+        }
+        extra["subln"] = (None,)
+        if cfg.attn_bias:
+            extra["bq"] = ("heads", "head_dim")
+            extra["bo"] = ("norm",)
+        return extra
+
+    def cross_attention():
+        return {
+            "wq": ("embed", "heads", "head_dim"),
+            "wo": ("heads", "head_dim", "embed"),
+            **differential(),
         }
 
     def dense_mlp():
@@ -299,6 +364,8 @@ def logical_axes(cfg: TransformerConfig) -> Params:
         "M": mamba2_logical_axes,
         "G": lambda: gated_delta_logical_axes(cfg),
         "*": attention, "W": attention, "E": experts, "-": dense_mlp,
+        "S": selective_scan_logical_axes, "U": memory_unit_logical_axes,
+        "C": cross_attention,
     }
 
     def layer_norm():
@@ -513,12 +580,30 @@ def check_window_mesh(cfg: TransformerConfig, mesh):
     """Refuse a model with window layers on a mesh that splits the
     sequence: ring and Ulysses attention know no window and would run
     those layers as full attention (``build_train_step`` asks when a step
-    is built, a window layer when it is traced)."""
-    if cfg.attn_window and mesh is not None and mesh.shape.get("sp", 1) > 1:
+    is built, a window layer when it is traced); likewise the layers
+    that know no split sequence at all: a selective scan, whose state
+    would have to pass from shard to shard, and differential attention."""
+    if mesh is None or mesh.shape.get("sp", 1) <= 1:
+        return
+    if cfg.attn_window:
         raise NotImplementedError(
             f"the window layers (attn_window {cfg.attn_window}) know no "
             f"sequence-parallel scheme: under sp = {mesh.shape['sp']} "
             f"{cfg.sp_scheme} attention would run them as full attention"
+        )
+    if "S" in cfg.layer_pattern:
+        raise NotImplementedError(
+            "a selective scan is a recurrence over the whole row: under "
+            f"sp = {mesh.shape['sp']} every shard would start its scan "
+            "(and its convolution) from zeros and not from the state the "
+            "shard before it ended with"
+        )
+    if cfg.attn_kind == "diff":
+        raise NotImplementedError(
+            "differential attention knows no sequence-parallel scheme: "
+            f"under sp = {mesh.shape['sp']} {cfg.sp_scheme} attention "
+            "takes one width for scores and values and no key pair's "
+            "doubled value"
         )
 
 
@@ -597,13 +682,14 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
 _LANES = 128
 
 
-def _attention_of_two_widths(q, k, v, mesh):
+def _attention_of_two_widths(q, k, v, mesh, window=None):
     """Causal attention [B, H, T, .] whose scores contract another width
-    than its values have (q, k 192 and v 128 wide, say), scaled by the
-    stated score width. The attention kernels take ONE width of whole
-    lane tiles for q, k and v, so the call pads: zeros on q and k leave
-    every score as it is, v is padded and the output sliced; the width
-    called is counted beside the width stated."""
+    than its values have (q, k 192 and v 128 wide, say; or 64 and a
+    differential pair's 128), scaled by the stated score width, through a
+    ``window`` where one is given. The attention kernels take ONE width of
+    whole lane tiles for q, k and v, so the call pads: zeros on q and k
+    leave every score as it is, v is padded and the output sliced; the
+    width called is counted beside the width stated."""
     qk, vd = q.shape[-1], v.shape[-1]
     width = -(-max(qk, vd) // _LANES) * _LANES
     _count_score_lanes(width, qk)
@@ -612,8 +698,117 @@ def _attention_of_two_widths(q, k, v, mesh):
         return jnp.pad(t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
 
     return _causal_attention(
-        pad(q), pad(k), pad(v), mesh, layout="bhtd", sm_scale=qk**-0.5
+        pad(q), pad(k), pad(v), mesh, layout="bhtd", sm_scale=qk**-0.5,
+        window=window,
     )[..., :vd]
+
+
+def diff_lambda_init(published_layer: int) -> float:
+    """Differential attention's ``lambda_init`` of a layer by its index in
+    the whole published model (arXiv:2410.05258)."""
+    return 0.8 - 0.6 * math.exp(-0.3 * published_layer)
+
+
+def _diff_head_order(pairs: int, key_pairs: int):
+    """The order the attention call wants the query heads in. The model
+    states pairs: query heads ``(2i, 2i+1)`` are ``q1_i, q2_i``, key heads
+    ``(2j, 2j+1)`` ``k1_j, k2_j``, and pair ``i`` reads ``j = i // r``, ``r``
+    query pairs a key pair. A grouped-query call gives key head ``m`` the
+    query heads ``[m G, (m + 1) G)``, ``G = r``: so key head ``2j`` must be
+    followed by the ``q1`` of its ``r`` pairs and key head ``2j + 1`` by
+    their ``q2``."""
+    r = pairs // key_pairs
+    return np.array([
+        2 * (j * r + i) + second
+        for j in range(key_pairs) for second in (0, 1) for i in range(r)
+    ])
+
+
+def _diff_keys_values(h, a):
+    """A differential layer's keys ``[B, kv_heads, T, head_dim]`` and the
+    value pairs as the attention call reads them, ``[B, kv_heads, T, 2
+    head_dim]``: key heads ``2j`` and ``2j + 1`` both read ``v_j = [v_2j |
+    v_2j+1]``. What a "*" layer hands the "C" layers above it."""
+    k = jnp.einsum("btd,dhk->bhtk", h, a["wk"].astype(h.dtype))
+    v = jnp.einsum("btd,dhk->bhtk", h, a["wv"].astype(h.dtype))
+    if "bk" in a:
+        k = k + a["bk"].astype(h.dtype)[:, None, :]
+        v = v + a["bv"].astype(h.dtype)[:, None, :]
+    B, kvh, T, hd = v.shape
+    pair = jnp.moveaxis(v.reshape(B, kvh // 2, 2, T, hd), 2, 3)
+    pair = pair.reshape(B, kvh // 2, 1, T, 2 * hd)
+    v2 = jnp.broadcast_to(pair, (B, kvh // 2, 2, T, 2 * hd))
+    return k, v2.reshape(B, kvh, T, 2 * hd)
+
+
+@jax.named_scope("scope/layer/attn")
+def _diff_attention(x, layer, cfg: TransformerConfig, mesh, kind: str,
+                    published: int, shared=None):
+    """``x + attention(norm(x))`` with differential attention
+    (``cfg.attn_kind`` "diff"; arXiv:2410.05258): a pair's output is
+    ``(softmax(q1 k1 / sqrt(hd)) - lambda softmax(q2 k2 / sqrt(hd))) v``
+    with ``v`` the key pair's two values side by side, ``lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init(published)``, then an
+    RMSNorm over the pair's ``2 hd`` with a weight, times ``1 -
+    lambda_init``. A "W" layer attends through ``cfg.attn_window``; a "C"
+    layer projects queries only and reads ``shared``, the keys and value
+    pairs of the last "*" layer (``_diff_keys_values``). Returns ``(x,
+    keys and value pairs)``.
+
+    Both softmaxes of every pair come from ONE attention call, each score
+    computed once: the call has ``num_heads`` query heads of ``hd`` on
+    ``kv_heads`` key heads, in ``_diff_head_order``, and values ``2 hd``
+    wide, through ``_attention_of_two_widths`` (q and k padded to the
+    values' width)."""
+    check_window_mesh(cfg, mesh)
+    cross = kind == "C"
+    a = layer[LAYER_KINDS[kind]]
+    heads, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+    pairs, key_pairs = heads // 2, kvh // 2
+    h = _norm(x, layer["norm"], cfg)
+    order = _diff_head_order(pairs, key_pairs)
+    q = jnp.einsum("btd,dhk->bhtk", h, a["wq"][:, order].astype(h.dtype))
+    if "bq" in a:
+        q = q + a["bq"][order].astype(h.dtype)[:, None, :]
+    if cross:
+        trace_counts.count("xdec_kv_reads")
+        k, v2 = shared
+    else:
+        k, v2 = _diff_keys_values(h, a)
+    trace_counts.count("attn_diff_pairs", pairs)
+    # one call, every pair's two score maps once each
+    trace_counts.count("attn_diff_score_calls", heads // 2)
+    o = _attention_of_two_widths(
+        q, k, v2, mesh, window=cfg.attn_window if kind == "W" else None
+    )
+    with jax.named_scope("scope/layer/attn/diff"):
+        f32 = jnp.float32
+        B, _, T, wide = o.shape
+        o = o.astype(f32).reshape(
+            B, key_pairs, 2, pairs // key_pairs, T, wide
+        )
+        init = diff_lambda_init(published)
+        lam = (
+            jnp.exp(jnp.sum(
+                a["lambda_q1"].astype(f32) * a["lambda_k1"].astype(f32)
+            ))
+            - jnp.exp(jnp.sum(
+                a["lambda_q2"].astype(f32) * a["lambda_k2"].astype(f32)
+            ))
+            + init
+        )
+        o = (o[:, :, 0] - lam * o[:, :, 1]).reshape(B, pairs, T, wide)
+        o = o * jax.lax.rsqrt(
+            jnp.mean(o * o, -1, keepdims=True) + _norm_eps(cfg)
+        )
+        o = (o * a["subln"].astype(f32) * (1.0 - init)).astype(h.dtype)
+    out = jnp.einsum(
+        "bptk,pkd->btd", o,
+        a["wo"].reshape(pairs, 2 * hd, -1).astype(o.dtype),
+    )
+    if "bo" in a:
+        out = out + a["bo"].astype(out.dtype)
+    return _residual(x, out, layer, cfg), (k, v2)
 
 
 def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
@@ -665,6 +860,24 @@ def _ssm_block(x, layer, cfg: TransformerConfig, mesh):
     return _residual(
         x, mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg), mesh), layer,
         cfg,
+    )
+
+
+@jax.named_scope("scope/layer/sscan")
+def _sscan_block(x, layer, cfg: TransformerConfig, mesh):
+    """``(x + mixer(norm(x)), the scan's output before its gate)``."""
+    check_window_mesh(cfg, mesh)
+    h = _norm(x, layer["norm"], cfg)
+    out, memory = selective_scan_mixer(h, layer["sscan"], cfg, mesh)
+    return _residual(x, out, layer, cfg), memory
+
+
+@jax.named_scope("scope/layer/gmu")
+def _gmu_block(x, layer, cfg: TransformerConfig, memory):
+    trace_counts.count("xdec_memory_reads")
+    h = _norm(x, layer["norm"], cfg)
+    return _residual(
+        x, memory_unit_mixer(h, layer["gmu"], memory), layer, cfg
     )
 
 
@@ -910,34 +1123,57 @@ def forward(
         x, aux = _mlp_block(x, layer, cfg, mesh, moe_axis=moe_axis)
         return x, aux
 
-    def mixer_layer(x, layer, kind):
-        """One layer of a ``layer_pattern``: ``x + mixer(norm(x))``."""
+    def mixer_layer(x, layer, read, kind, published):
+        """One layer of a ``layer_pattern``: ``x + mixer(norm(x))``.
+        ``read``: what the layer reads of another layer's (``LAYER_READS``;
+        None for most kinds). Returns ``(x, aux, what the layer hands to
+        layers above it)``."""
         if kind == "M":
-            return _ssm_block(x, layer, cfg, mesh), None
+            return _ssm_block(x, layer, cfg, mesh), None, None
         if kind == "G":
-            return _gdn_block(x, layer, cfg, mesh), None
+            return _gdn_block(x, layer, cfg, mesh), None, None
+        if kind == "S":
+            x, memory = _sscan_block(x, layer, cfg, mesh)
+            return x, None, memory
+        if kind == "U":
+            return _gmu_block(x, layer, cfg, read), None, None
+        if kind in "*WC" and cfg.attn_kind == "diff":
+            x, keys_values = _diff_attention(
+                x, layer, cfg, mesh, kind, published, read
+            )
+            return x, None, keys_values if kind == "*" else None
         if kind in "*W":
             x = _attention_block(
                 x, layer, cfg, mesh, positions, "norm", kind
             )
-            return x, None
+            return x, None, None
         x, aux = _mlp_block(x, layer, cfg, mesh, moe_axis, "norm")
-        return x, aux if kind == "E" else None
+        return x, (aux if kind == "E" else None), None
 
     if cfg.remat and not cfg.layer_pattern:
         block = recomputed(block)
     if cfg.layer_pattern:
         loads = []
+        # what the last layer of each kind handed on (``LAYER_READS``: a
+        # "U" reads the last "S", a "C" the last "*"), carried beside x
+        handed = {}
+        published = cfg.first_layer
         for kind, layer in zip(cfg.layer_pattern, params["layers"]):
-            one_layer = functools.partial(mixer_layer, kind=kind)
+            one_layer = functools.partial(
+                mixer_layer, kind=kind, published=published
+            )
+            published += kind not in "-E"
             if cfg.remat:
                 # a wrapper a layer: ``jax.checkpoint`` keeps the trace of
                 # a function it has seen at these shapes, and a layer that
                 # came out of that cache is not traced, so what a trace
                 # counts (``common/trace_counts``) would be of one layer
-                # of each kind
+                # of each kind. What a layer reads of another is an input
+                # of the recomputed layer like x, and is kept as x is
                 one_layer = recomputed(one_layer)
-            x, aux = one_layer(x, layer)
+            x, aux, handed[kind] = one_layer(
+                x, layer, handed.get(LAYER_READS.get(kind))
+            )
             if aux is not None:
                 loads.append(aux["load"])
                 aux_total = dict(
@@ -999,6 +1235,15 @@ def loss_fn(
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Per-layer K/V buffers [L, B, S, kv_heads, head_dim]. Static shape:
     the whole decode loop stays inside one compiled ``lax.scan``."""
+    if set(cfg.layer_pattern) & set("SUC"):
+        raise NotImplementedError(
+            f"cached decoding knows no layer_pattern {cfg.layer_pattern!r}: "
+            "a selective scan's cache is its state and its convolution's "
+            "last taps, a gated memory unit reads the scan output of the "
+            "token being decoded, and a cross-attention reads ONE layer's "
+            "keys and values, which no cache here holds once for all its "
+            "readers"
+        )
     if cfg.attn_window:
         raise NotImplementedError(
             f"cached decoding knows no window: the \"W\" layers' "

@@ -21,10 +21,11 @@ chunk states are float32; the matmul operands are
 the activation dtype, accumulated in float32. The tests hold it to the
 recurrence itself, one step at a time.
 
-The convolution and its SiLU are ``conv_silu``, which this layer and the
-Gated DeltaNet layer (``ops/gated_delta.py``) both call: the plain statement
-here, or the ``conv_silu_*`` kernels of ``ops/conv_kernels.py`` where their
-rule takes the input.
+The convolution and its SiLU are ``conv_silu``, which three layers call:
+this one, the Gated DeltaNet layer (``ops/gated_delta.py``) and the Mamba-1
+layer (``ops/selective_scan.py``, with a bias): the plain statement here,
+or the ``conv_silu_*`` kernels of ``ops/conv_kernels.py`` where their rule
+takes the input.
 
 The spans of a layer: ``scope/layer/ssm/{in_proj,conv,scan,gate,out_proj}``.
 """

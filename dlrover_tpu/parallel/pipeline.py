@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from dlrover_tpu.models.config import TransformerConfig
+from dlrover_tpu.models.config import LAYER_READS, TransformerConfig
 from dlrover_tpu.models.train import TrainState, opt_state_shardings
 from dlrover_tpu.models.transformer import (
     _attention_block,
@@ -111,6 +111,14 @@ def _microbatch_axes(mesh, mb: int) -> Tuple[str, ...]:
 def _check_pipeline_cfg(
     cfg: TransformerConfig, pp: int, virtual: int = 1
 ) -> None:
+    if set(cfg.layer_pattern) & set(LAYER_READS):
+        raise ValueError(
+            f"pipeline parallelism passes the residual stream alone from "
+            f"stage to stage: the layers of layer_pattern "
+            f"{cfg.layer_pattern!r} that read another layer's scan output "
+            f"or keys and values ({sorted(LAYER_READS)}) could lie a stage "
+            "above the layer they read, and nothing carries it there"
+        )
     if cfg.attn_window:
         raise ValueError(
             f"pipeline parallelism stacks all-alike attention + FFN "

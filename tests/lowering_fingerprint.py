@@ -37,6 +37,11 @@ and keeps what its backward pass reads, only the rounds past it stay in
 the hand-differentiated loop, and the two recomputing ones' policy saves
 the round's three names beside the attention kernels'. The other three
 entries (no share: ``_moe_dropless`` or no experts) are as they were.
+ISSUE 53 brought ``phi4-mini-flash-d6`` (Mamba-1 scans, whose kernels are
+interpreted on the CPU at these widths, differential attention on the jnp
+path, a memory unit and a cross-attention that read another layer's
+values, which ``forward``'s loop now carries beside the residual stream)
+and left the seven entries before it as they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
@@ -55,7 +60,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAMES = (
     "gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2",
     "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
-    "ling-3.0-flash-d7", "trinity-mini-d5",
+    "ling-3.0-flash-d7", "trinity-mini-d5", "phi4-mini-flash-d6",
 )
 
 

@@ -137,9 +137,11 @@ def test_rebuild_drops_the_executable(accel):
 # mixers' two (PR 43), their sites in the kernels (PR 44), the
 # convolutions' two (PR 47), the attention sites whose outputs a
 # recomputed layer keeps (PR 51) and the share layers whose first round
-# keeps what its backward pass reads (PR 52); how the counted ones are
+# keeps what its backward pass reads (PR 52), the selective scans' three,
+# the differential pairs' two and the cross-decoder's two reads (PR 53); how the counted ones are
 # folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
+    "attn_diff_pairs", "attn_diff_score_calls",
     "attn_kept_sites",
     "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
     "attn_stream_blocks_rect",
@@ -167,11 +169,11 @@ AS_DICT_KEYS = [
     "restore_agree_s", "restore_bytes", "restore_h2d_s",
     "restore_lock_wait_s", "restore_shm_verify_s", "restore_source",
     "restore_storage_read_s", "restore_storage_verify_s", "safe_steps",
-    "save_skips",
+    "save_skips", "sscan_kernel_sites", "sscan_serial_steps", "sscan_sites",
     "stage_backlog_bytes", "stage_block_s", "stage_bytes", "stage_chunks",
     "stage_commits", "startup_backend_s", "startup_cache_misses",
     "startup_compile_s", "startup_first_step_s", "startup_import_s",
-    "steps_ahead",
+    "steps_ahead", "xdec_kv_reads", "xdec_memory_reads",
 ]
 # a float is reported to the places it had when each key was written out
 ROUNDED = {

@@ -22,7 +22,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.common import trace_counts
-from trace_counted import CONV, FUSED, GDN, LANES, STREAM, WINDOW, added
+from trace_counted import (
+    CONV, DIFF, FUSED, GDN, LANES, SSCAN, STREAM, WINDOW, added,
+)
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
 # (ops/__init__ re-exports it); the module has to be asked for by name
@@ -505,6 +507,116 @@ def test_attention_of_192_and_128_compiles_as_the_program_calls_it(
     sites = len(want)
     assert added(before, STREAM) == (sites, 0, 136 * sites, 256 * sites)
     assert added(before, LANES) in ((256, 192), (512, 384))
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_selective_scan_kernels_compile_at_the_cell(
+    direction, one_chip, monkeypatch
+):
+    """The Phi-4-mini-flash cell's Mamba-1 recurrence, 1 x 16384 steps of
+    5120 channels with 16 states each: the forward kernel, and under
+    ``grad`` the backward kernel with a block's states made again in
+    VMEM. Nothing of [T, channels, states] is in the program around them:
+    the largest arrays beside the tokens are the state that enters each
+    block of 128 steps and the channel sums still spread over 128 lanes."""
+    from dlrover_tpu.ops import selective_scan as ss
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    B, T, C, N = 1, 16384, 5120, 16
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [
+        sds((B, T, C), jnp.bfloat16), sds((B, T, C)), sds((C, N)),
+        sds((B, T, N), jnp.bfloat16), sds((B, T, N), jnp.bfloat16),
+        sds((C,)),
+    ]
+    assert ss.fits(args[0], args[2])
+    before = trace_counts.snapshot()
+    if direction == "fwd":
+        text = _compile_for_chip(ss.selective_scan, *args).as_text()
+        want, steps = ["sscan_fwd"], T
+    else:
+        text = _compile_for_chip(
+            jax.grad(
+                lambda *a: jnp.sum(
+                    ss.selective_scan(*a).astype(jnp.float32) ** 2
+                ),
+                argnums=range(len(args)),
+            ),
+            *args,
+        ).as_text()
+        want, steps = ["sscan_fwd", "sscan_bwd"], 3 * T
+    for kernel in want:
+        assert kernel in text, kernel
+    for states in (f"{T},{C},{N}]", f"{T},{N},{C}]", f"{C},{N},{T}]"):
+        assert states not in text, states
+    assert f"f32[{B},{T // 128},{N},{C}]" in text  # a state a block
+    assert added(before, SSCAN) == (1, 1, steps)
+
+
+@pytest.mark.parametrize("kind", ["W", "*"])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_differential_attention_compiles_as_the_program_calls_it(
+    kind, direction, one_chip, monkeypatch
+):
+    """The Phi-4-mini-flash cell's differential attention, 40 query heads
+    on 20 key heads of 64 with value pairs of 128 at T = 16384: ONE call a
+    layer, q and k padded to the pairs' width
+    (``models/transformer._diff_attention``), the window layer on the
+    band of a 512-key window in blocks of 1024 (31 of 136 blocks), the
+    full layer on the triangle; 20 pairs, each score map once."""
+    from dlrover_tpu.models import transformer
+    from dlrover_tpu.models.config import TransformerConfig
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = TransformerConfig(
+        vocab_size=128, num_layers=2, layer_pattern=kind + "-",
+        attn_window=512 if kind == "W" else 0, attn_kind="diff",
+        attn_bias=True, positions="none", model_dim=2560, num_heads=40,
+        num_kv_heads=20, attn_head_dim=64, dense_mlp_dim=128,
+        max_seq_len=16384, first_layer=15, swiglu=True,
+    )
+    shapes = jax.eval_shape(
+        lambda: transformer.init_params(jax.random.PRNGKey(0), cfg)
+    )["layers"][0]
+    layer = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shapes,
+    )
+    x = jax.ShapeDtypeStruct((1, 16384, 2560), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def attend(x, layer):
+        return transformer._diff_attention(x, layer, cfg, None, kind, 15)[0]
+
+    before = trace_counts.snapshot()
+    if direction == "fwd":
+        compiled = _compile_for_chip(attend, x, layer)
+    else:
+        compiled = _compile_for_chip(
+            jax.grad(
+                lambda x, layer: attend(x, layer).astype(jnp.float32).sum(),
+                argnums=(0, 1),
+            ),
+            x, layer,
+        )
+    stem = "flash_attn_window" if kind == "W" else "flash_attn"
+    want = [f"{stem}_fwd"] + ([f"{stem}_bwd"] if direction == "bwd" else [])
+    text = compiled.as_text()
+    for kernel in want:
+        assert kernel in text, kernel
+    assert ("flash_attn_window" in text) == (kind == "W")
+    sites = len(want)
+    walked = 31 if kind == "W" else 136
+    assert added(before, STREAM) == (sites, 0, walked * sites, 256 * sites)
+    assert added(before, WINDOW) == (
+        (31 * sites, 136 * sites) if kind == "W" else (0, 0)
+    )
+    assert added(before, LANES) == (128, 64)
+    assert added(before, DIFF) == (20, 20)
 
 
 # the bf16 [50257, 768] leaf compiles too, but takes ~19 s: f32 here

@@ -27,7 +27,7 @@ from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
-    CONV, FUSED, GDN, KEPT, LANES, SHARE, STREAM, WINDOW,
+    CONV, DIFF, FUSED, GDN, KEPT, LANES, SHARE, SSCAN, STREAM, WINDOW, XDEC,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -101,9 +101,9 @@ def test_counting_from_many_threads_loses_nothing(fresh):
 
 def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
-    assert set(GDN + CONV + LANES + WINDOW + KEPT + SHARE) <= FIELDS - set(
-        trace_counts.RUNNING_TOTALS
-    )
+    assert set(
+        GDN + CONV + LANES + WINDOW + KEPT + SHARE + SSCAN + DIFF + XDEC
+    ) <= FIELDS - set(trace_counts.RUNNING_TOTALS)
 
 
 # -- the trainer's fold -------------------------------------------------------
@@ -293,6 +293,29 @@ FOLDS = {
             dict(zip(LANES + KEPT + SHARE, (640, 640, 0, 0))),
         ),
     ],
+    # two Mamba-1 scans in the kernels at T = 64 (a forward of 64 steps
+    # and a backward of 128 each), three differential layers of four
+    # pairs, a memory unit and a cross-attention; then the same under
+    # ``remat``, whose scans walk their forward twice
+    "scans_pairs_and_the_layers_that_read_another": [
+        dict(zip(SSCAN + DIFF + XDEC, (2, 2, 128, 12, 12, 1, 1))),  # check
+        "step_donating",
+        dict(zip(SSCAN + DIFF + XDEC, (2, 2, 384, 12, 12, 1, 1))),
+        (
+            "; traced: sscan_sites =2, sscan_kernel_sites =2, "
+            "sscan_serial_steps =384, attn_diff_pairs =12, "
+            "attn_diff_score_calls =12, xdec_memory_reads =1, "
+            "xdec_kv_reads =1",
+            dict(zip(SSCAN + DIFF + XDEC, (2, 2, 384, 12, 12, 1, 1))),
+        ),
+        "step_donating",
+        dict(zip(SSCAN + DIFF, (2, 0, 512, 12, 24))),
+        (
+            "; traced: sscan_sites =2, sscan_serial_steps =512, "
+            "attn_diff_pairs =12, attn_diff_score_calls =24",
+            dict(zip(SSCAN + DIFF + XDEC, (2, 0, 512, 12, 24, 0, 0))),
+        ),
+    ],
     # both scopes on one line: the kernels' totals first
     "both_scopes_on_one_line": [
         "step_safe",
@@ -351,6 +374,13 @@ _SHARE = dict(
     experts_held=2, router="sigmoid", shared_expert_dim=16,
     positions="none", rmsnorm=True, tie_embeddings=False,
 )
+_PHI = dict(
+    num_layers=12, layer_pattern="S-W-S-*-U-C-", first_layer=14,
+    attn_window=16, attn_kind="diff", attn_bias=True, num_kv_heads=2,
+    attn_head_dim=8, positions="none", swiglu=True, dense_mlp_dim=32,
+    sscan_state=8, sscan_dt_rank=2, sscan_chunk=16,
+    **dict(_SMALL, num_heads=4),
+)
 # one toy a family of the benchmark's configurations, and the families of
 # names a traced train step of it counts under
 TOYS = {
@@ -400,6 +430,17 @@ TOYS = {
         ),
         (STREAM, WINDOW, LANES),
     ),
+    # phi4flash's six kinds in one model, and the same recomputed: two
+    # scans (the plain statement: 96 channels are no lane tile), window,
+    # full and cross differential attention, a memory unit
+    "scans_and_differential_attention": (
+        TransformerConfig(sscan_inner=96, **_PHI),
+        (SSCAN, CONV, DIFF, XDEC, STREAM, WINDOW, LANES),
+    ),
+    "scans_and_differential_attention_remat": (
+        TransformerConfig(sscan_inner=128, remat=True, **_PHI),
+        (SSCAN, CONV, DIFF, XDEC, STREAM, WINDOW, LANES, KEPT),
+    ),
 }
 
 
@@ -439,7 +480,8 @@ def test_every_name_a_traced_step_counts_is_a_stats_field(toy, monkeypatch):
 KERNEL_MODULES = {
     "dlrover_tpu.ops.flash_attention", "dlrover_tpu.ops.gated_delta",
     "dlrover_tpu.ops.gated_delta_kernels", "dlrover_tpu.ops.mamba2",
-    "dlrover_tpu.ops.conv_kernels", "dlrover_tpu.models.transformer",
+    "dlrover_tpu.ops.conv_kernels", "dlrover_tpu.ops.selective_scan",
+    "dlrover_tpu.models.transformer",
 }
 
 

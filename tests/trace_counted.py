@@ -13,6 +13,9 @@ CONV = ("conv_sites", "conv_kernel_sites")
 LANES = ("attn_score_lanes", "attn_score_lanes_used")
 KEPT = ("attn_kept_sites",)
 SHARE = ("moe_share_kept_sites",)
+SSCAN = ("sscan_sites", "sscan_kernel_sites", "sscan_serial_steps")
+DIFF = ("attn_diff_pairs", "attn_diff_score_calls")
+XDEC = ("xdec_memory_reads", "xdec_kv_reads")
 
 
 def added(before, names):

@@ -149,6 +149,18 @@ class PipelineStats:
     # window was only a mask; 0 / 0 for a model without a window
     attn_window_blocks_walked: int = 0
     attn_window_blocks_causal: int = 0
+    # the score tiles in the edge blocks of the streaming attention
+    # kernels' triangle path (the block on the diagonal, and under a
+    # window the blocks its far edge crosses; a tile is a row strip's
+    # height a side, ``ops/flash_attention._edge_strips``) in the train
+    # step program this process traced last, summed over a head's walk and
+    # the kernels as the window counts are, and those of them the kernels
+    # multiply: the backward kernels walk an edge block in row strips and
+    # leave out the tiles that lie wholly over the diagonal or past the
+    # window, the forward kernel computes it whole. 0 / 0 where no call
+    # takes that path
+    attn_edge_tiles_multiplied: int = 0
+    attn_edge_tiles: int = 0
     # attention kernel call sites inside a recomputed layer
     # (``models/transformer.recomputed``, ``cfg.remat``) in the train step
     # program this process traced last: the wrapper keeps what each one's

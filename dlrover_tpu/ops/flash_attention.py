@@ -54,7 +54,20 @@ Design:
   masked, the softmax scale rides in the exponent's argument and on the
   float32 accumulators, and the backward runs in one pass (scores, p, dp
   and ds once; a head's float32 dq resident in VMEM) while that fits,
-  split into the dq and the dk / dv kernel beyond. A caller that states
+  split into the dq and the dk / dv kernel beyond. **An edge block**, one
+  its rows see part of (the block on the diagonal, and under a window
+  each block its far edge crosses), is walked by the backward kernels in
+  ``_EDGE_STRIPS`` row strips, each against the one span of the block's
+  keys that any of its rows sees, rounded outward to the strip's height
+  and masked inside as before (``_edge_strips``: Python integers when the
+  program is traced, so every slice is a static slice of a ref): a strip
+  adds to its own rows of dq and to the rows of dk and dv its span
+  covers, and the score tiles wholly over the diagonal or past the window
+  (6 of a diagonal block's 16) are never multiplied; the grid, the
+  fetches and the blocks walked are what they were. The forward kernel
+  computes an edge block whole and masks it (measured: in strips it
+  loses), and a block seen whole is one strip with no mask. A caller
+  that states
   no block gets 1024 x 1024 there (measured), 512 on the rectangular
   grid, which every other call keeps as it was. ``common/trace_counts``
   holds the kernels lowered, by grid (``attn_stream_tri_sites`` ...).
@@ -70,10 +83,14 @@ Design:
   ``min(n - 1, j + wb)``; the diagonal block keeps its mask and the
   blocks the window's far edge crosses get their own, the rest none. The
   kernels are the triangle's with a static ``window`` (absent, they trace
-  as they did) under names of their own (``flash_attn_window_*``).
+  as they did) under names of their own (``flash_attn_window_*``); a block
+  the far edge crosses is an edge block, its place static in a branch of
+  its own, and the backward walks it in row strips as the diagonal's.
   Everywhere else (the fused family, the rectangular grid, the jnp path)
   the window is an exact mask with no skip, and ``common/trace_counts``
-  says which (``attn_window_blocks_walked`` against ``_causal``).
+  says which (``attn_window_blocks_walked`` against ``_causal``; the
+  score tiles the edge blocks multiply, ``attn_edge_tiles_multiplied`` of
+  ``attn_edge_tiles``).
 - ``layout="bhtd"`` lets callers hand over kernel-native [B, H, T, D]
   tensors (the model emits them straight from its QKV einsums), skipping
   the 25 MB-per-tensor relayout transposes on every call.
@@ -408,6 +425,7 @@ _STREAM = (
     "attn_stream_blocks_walked", "attn_stream_blocks_rect",
 )
 _WINDOW = ("attn_window_blocks_walked", "attn_window_blocks_causal")
+_EDGE = ("attn_edge_tiles_multiplied", "attn_edge_tiles")
 
 # the names the forward rule gives what it hands the backward rule
 # (``_flash_fwd_rule``: q, k, v as the kernel read them, ``o``, the
@@ -473,6 +491,33 @@ def _count_window_site(n: int, block: int, band, kernels: int = 1):
     under = n * (n + 1) // 2
     walked = under if band is None else _band_steps(n, block, band)
     for name, k in zip(_WINDOW, (walked, under)):
+        trace_counts.count(name, kernels * k)
+
+
+def _count_edge_tiles(n: int, block: int, window, strips: int, *,
+                      whole: bool = False, kernels: int = 1):
+    """``kernels`` kernels of one call site on the streaming triangle
+    path, into ``common/trace_counts`` under ``_EDGE``: the score tiles,
+    the height of one of the block's ``strips`` row strips a side, that a
+    head's edge blocks hold (the block on the diagonal ``n`` times and
+    under a ``window`` each block its far edge crosses), and those of them
+    the kernel multiplies: the spans of its strips (``_edge_strips``), or
+    every tile where it computes an edge block ``whole``. Counted beside
+    ``_count_site``, as it counts."""
+    edges = [(n, _edge_limits(block, True, window))]
+    if window is not None:
+        edges += [
+            (max(n - far, 0), _edge_limits(block, False, window, far))
+            for far in _far_edges(block, window)
+        ]
+    rows = block // strips
+    held = sum(visits for visits, _ in edges) * strips * strips
+    multiplied = held if whole else sum(
+        visits * (c1 - c0) // rows
+        for visits, limits in edges
+        for _, _, c0, c1 in _edge_strips(block, *limits, strips)
+    )
+    for name, k in zip(_EDGE, (multiplied, held)):
         trace_counts.count(name, kernels * k)
 
 
@@ -1036,6 +1081,36 @@ def _bwd_dkv_kernel(
 _TRI_MAX_BLOCKS = 128
 
 
+# Row strips the backward kernels walk an edge block in, a block of the
+# triangle path that its rows see part of: the block on the diagonal, and
+# under a window each block its far edge crosses. A strip multiplies its
+# ``block // strips`` query rows against the one span of the block's keys
+# that any of them sees, rounded outward to the strip's height, so no
+# score tile that lies wholly over the diagonal or past the window is
+# computed (6 of a diagonal block's 16); the grid, the fetches and the
+# blocks walked stay as they are. Measured in blocks of 1024, ms a call
+# of forward + backward less the forward alone (v5e, bf16,
+# ``tools/attn_kernel_bench.py``, PERF.md section 6, PR 54), one strip / two
+# / four: the triangle at [1, 32 / 4, 16384, 128] 34.59 / 33.76 / 33.42,
+# its band under a window of 2048 12.51 / 10.89 / 10.30, the band of 512 at
+# [1, 40 / 20, 16384, 128] 11.81 / 7.62 / 6.84. The forward kernel keeps
+# its edge blocks whole: in strips its two matmuls a strip no longer hide
+# its softmax (the same three calls' forward 16.82 / 17.42 / 17.23, 5.46 /
+# 6.28 / 6.26 and 4.24 / 4.26 / 4.26).
+_EDGE_STRIPS = 4
+
+
+def _edge_strip_count(block: int, interpret: bool) -> int:
+    """Strips of an edge block of ``block`` rows: ``_EDGE_STRIPS`` where a
+    strip's rows, which its span of keys is a whole number of, are whole
+    lane tiles, else fewer, or one. Interpreted there is no tile."""
+    tile = 1 if interpret else _LANES
+    strips = _EDGE_STRIPS
+    while strips > 1 and (block % strips or block // strips % tile):
+        strips //= 2
+    return strips
+
+
 def _stream_plan(Tq, Tk, block_q, block_k, *, causal, mask_fn, diagonal):
     """Blocks a side of the triangle path, or None for the rectangular
     grid: the same test ``_fused_plan`` makes for the fused family, and
@@ -1109,45 +1184,98 @@ def _triangle_steps(n: int, by_key: bool, wb=None):
     return jnp.asarray(qi, jnp.int32), jnp.asarray(kj, jnp.int32)
 
 
-def _tri_scores(q_ref, k_ref, on_diagonal: bool, window=None, far=None):
-    """Raw scores ``q k^T`` of one block, float32; the block on the
-    diagonal masked ``row >= col``, any other block whole. With a
-    ``window``, a block the window's far edge may cross is given ``far``,
-    how many blocks it lies before the query block (a scalar of the
-    step), and keeps ``row - col < window - far * block``; the block on
-    the diagonal is such a block too where the window is shorter than
-    it."""
+def _edge_strips(block: int, lo, hi, strips: int):
+    """The plan of an edge block whose visible pairs are ``lo <= row - col
+    < hi`` (None: no such limit), rows and columns counted inside the
+    block: ``((r0, r1, c0, c1), ...)``, a strip of query rows ``[r0, r1)``
+    against the keys ``[c0, c1)``, the span any of its rows sees rounded
+    outward to the strip's height. A strip that sees nothing of the block
+    is not in the plan."""
+    rows = block // strips
+    plan = []
+    for r0 in range(0, block, rows):
+        r1 = r0 + rows
+        # row - hi < col <= row - lo, over the strip's rows
+        c0 = 0 if hi is None else max(0, r0 - hi + 1) // rows * rows
+        c1 = block if lo is None else min(block, -(-(r1 - lo) // rows) * rows)
+        if c0 < c1:
+            plan.append((r0, r1, c0, c1))
+    return tuple(plan)
+
+
+def _far_edges(block: int, window: int):
+    """How many blocks before the query block the blocks lie that a
+    window's far edge crosses: past those it covers whole, up to the last
+    it reaches (one value for 2048 or 512 in blocks of 1024, two for
+    1536)."""
+    return range(max(window // block, 1), _band_blocks(window, block) + 1)
+
+
+def _edge_limits(block: int, on_diagonal: bool, window=None, far=None):
+    """``(lo, hi)`` of a block's visible pairs ``lo <= row - col < hi``,
+    or None for a block seen whole: the block on the diagonal sees ``0 <=
+    row - col`` (``< window`` of it), a block ``far`` blocks before the
+    query block that the window's far edge crosses ``row - col < window -
+    far * block``."""
+    if on_diagonal:
+        return 0, window
+    if far is None:
+        return None
+    return None, window - far * block
+
+
+def _block_strips(block: int, strips: int, on_diagonal, window, far):
+    """``(strip, lo, hi)`` over what a body multiplies of one block: the
+    whole block as one strip with no mask, or an edge block's plan."""
+    limits = _edge_limits(block, on_diagonal, window, far)
+    if limits is None:
+        return (((0, block, 0, block), None, None),)
+    return tuple(
+        (strip, *limits) for strip in _edge_strips(block, *limits, strips)
+    )
+
+
+def _tri_scores(q_ref, k_ref, strip, lo=None, hi=None):
+    """Raw scores ``q k^T`` of one strip ``(r0, r1, c0, c1)`` of a block,
+    float32: query rows ``[r0, r1)`` against keys ``[c0, c1)``, masked to
+    ``lo <= row - col < hi`` wherever a pair of the strip may fail it (a
+    block seen whole is one strip with neither limit)."""
+    r0, r1, c0, c1 = strip
     s = jax.lax.dot_general(
-        q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+        q_ref[0, 0, r0:r1, :], k_ref[0, 0, c0:c1, :],
+        (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    block = s.shape[0]
-    if on_diagonal and (window is None or window >= block):
-        return _mask_diagonal_tile(s, block)
-    if not on_diagonal and far is None:
+    below = lo is not None and r0 - (c1 - 1) < lo
+    past = hi is not None and r1 - 1 - c0 >= hi
+    if not (below or past):
         return s
+    # row - col of the block is that of the strip plus r0 - c0
     ahead = (
         lax.broadcasted_iota(jnp.int32, s.shape, 0)
         - lax.broadcasted_iota(jnp.int32, s.shape, 1)
     )
-    if on_diagonal:
-        seen = (ahead >= 0) & (ahead < window)
+    if below and past:
+        seen = (ahead >= lo - (r0 - c0)) & (ahead < hi - (r0 - c0))
+    elif below:
+        seen = ahead >= lo - (r0 - c0)
     else:
-        seen = ahead < window - far * block
+        seen = ahead < hi - (r0 - c0)
     return jnp.where(seen, s, NEG_INF)
 
 
 def _band_below(i, j, block: int, window: int, body):
     """``body(False[, far])`` on a band step under the diagonal: with no
-    mask where the window covers the whole block, and with ``far`` where
-    its far edge may cross it."""
+    ``far`` where the window covers the whole block, and with each value
+    of ``far``, static in its branch, at which its far edge crosses it."""
     whole = window // block - 1  # blocks back that are covered whole
     far = i - j
     if whole >= 1:
         pl.when((far >= 1) & (far <= whole))(
             functools.partial(body, False)
         )
-    pl.when(far > max(whole, 0))(functools.partial(body, False, far))
+    for edge in _far_edges(block, window):
+        pl.when(far == edge)(functools.partial(body, False, edge))
 
 
 def _band_by_query(i, j, block: int, window: int, body, write):
@@ -1189,7 +1317,10 @@ def _tri_fwd_kernel(
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def _block(on_diagonal, far=None):
-        s = _tri_scores(q_ref, k_ref, on_diagonal, window, far)
+        # whole, and masked where it is an edge block: row strips lose
+        # here what they gain in the backward kernels (``_EDGE_STRIPS``)
+        limits = _edge_limits(block, on_diagonal, window, far) or ()
+        s = _tri_scores(q_ref, k_ref, (0, block, 0, block), *limits)
         m_prev = m_ref[:, :1]  # [b, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a row's first block: exp(scale * (NEG_INF - m_new)) = 0
@@ -1221,17 +1352,31 @@ def _tri_fwd_kernel(
 
 
 def _tri_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
-              sm_scale, on_diagonal, window=None, far=None):
-    """(p, ds / sm_scale) of one block, float32: what both backward
-    kernels recompute. ds is scaled where it has been summed."""
-    s = _tri_scores(q_ref, k_ref, on_diagonal, window, far)
+              sm_scale, strip, lo=None, hi=None):
+    """(p, ds / sm_scale) of one strip of a block (``_tri_scores``),
+    float32: what both backward kernels recompute. ds is scaled where it
+    has been summed."""
+    r0, r1, c0, c1 = strip
+    s = _tri_scores(q_ref, k_ref, strip, lo, hi)
     # lse is finite; a masked score gives exp(NEG_INF - lse) = 0
-    p = jnp.exp(s * sm_scale - lse_ref[0, 0, :, :1])
+    p = jnp.exp(s * sm_scale - lse_ref[0, 0, r0:r1, :1])
     dp = jax.lax.dot_general(
-        do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
+        do_ref[0, 0, r0:r1, :], v_ref[0, 0, c0:c1, :],
+        (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    return p, p * (dp - delta_ref[0, 0, :, :1])
+    return p, p * (dp - delta_ref[0, 0, r0:r1, :1])
+
+
+def _into_rows(acc, c0: int, c1: int, new, old: int):
+    """``new`` into rows ``[c0, c1)`` of an accumulator: added to the
+    first ``old`` of them, which hold a sum, and written to the rest,
+    which hold nothing yet."""
+    if old:
+        head = new if old == c1 - c0 else new[:old]
+        acc[c0:c0 + old, :] = acc[c0:c0 + old, :] + head
+    if old < c1 - c0:
+        acc[c0 + old:c1, :] = new[old:] if old else new
 
 
 def _tri_bwd_dq_kernel(
@@ -1240,6 +1385,7 @@ def _tri_bwd_dq_kernel(
     dq_acc,  # scratch [b, D] f32
     *,
     sm_scale: float,
+    strips: int,
     window=None,
 ):
     step = pl.program_id(2)
@@ -1251,16 +1397,19 @@ def _tri_bwd_dq_kernel(
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def _block(on_diagonal, far=None):
-        _, ds = _tri_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            sm_scale=sm_scale, on_diagonal=on_diagonal,
-            window=window, far=far,
-        )
-        k = k_ref[0, 0]
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        for strip, lo, hi in _block_strips(
+            block, strips, on_diagonal, window, far
+        ):
+            r0, r1, c0, c1 = strip
+            _, ds = _tri_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                sm_scale=sm_scale, strip=strip, lo=lo, hi=hi,
+            )
+            k = k_ref[0, 0, c0:c1, :]
+            dq_acc[r0:r1, :] = dq_acc[r0:r1, :] + jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     def _write():
         dq_ref[0, 0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
@@ -1282,6 +1431,7 @@ def _tri_bwd_kernel(
     *refs,
     sm_scale: float,
     n_blocks: int,
+    strips: int,
     window=None,
 ):
     """dk / dv over the triangle, key block by key block (outputs
@@ -1292,7 +1442,10 @@ def _tri_bwd_kernel(
     matmuls and one exponential pass a block where the split kernels
     run seven and two), dq summed in the float32 block that stays in
     VMEM while the head is swept and written at its last step. With a
-    ``window`` a key block's queries end where the window leaves it."""
+    ``window`` a key block's queries end where the window leaves it. An
+    edge block is walked in ``strips`` row strips (``_edge_strips``):
+    each adds to the rows of dk and dv its span of keys covers, and to
+    its own rows of dq."""
     if len(refs) == 6:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
     else:
@@ -1308,33 +1461,42 @@ def _tri_bwd_kernel(
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def _block(on_diagonal, far=None):
-        p, ds = _tri_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            sm_scale=sm_scale, on_diagonal=on_diagonal,
-            window=window, far=far,
-        )
-        q, do = q_ref[0, 0], do_ref[0, 0]
-        ds_lo = ds.astype(q.dtype)
-        dv = jax.lax.dot_general(  # p^T @ do
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk = jax.lax.dot_general(  # ds^T @ q
-            ds_lo, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if dq_ref is not None:
-            rows = pl.ds(pl.multiple_of(i * block, block), block)
-            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
-                ds_lo, k_ref[0, 0], (((1,), (0,)), ((), ())),
+        # the block on the diagonal is the key block's first step: the
+        # keys below ``written`` have been written by an earlier strip
+        # and are added to, those past it have nothing to add to
+        written = 0 if on_diagonal else block
+        for strip, lo, hi in _block_strips(
+            block, strips, on_diagonal, window, far
+        ):
+            r0, r1, c0, c1 = strip
+            p, ds = _tri_p_ds(
+                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                sm_scale=sm_scale, strip=strip, lo=lo, hi=hi,
+            )
+            q, do = q_ref[0, 0, r0:r1, :], do_ref[0, 0, r0:r1, :]
+            ds_lo = ds.astype(q.dtype)
+            dv = jax.lax.dot_general(  # p^T @ do
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-        if on_diagonal:  # the key block's first step: nothing to add to
-            dv_acc[:] = dv
-            dk_acc[:] = dk
-        else:
-            dv_acc[:] = dv_acc[:] + dv
-            dk_acc[:] = dk_acc[:] + dk
+            dk = jax.lax.dot_general(  # ds^T @ q
+                ds_lo, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            if dq_ref is not None:
+                rows = pl.ds(
+                    pl.multiple_of(i * block + r0, r1 - r0), r1 - r0
+                )
+                dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+                    ds_lo, k_ref[0, 0, c0:c1, :], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            assert c0 <= written <= c1 or written == block, strip
+            old = min(written, c1) - c0  # keys of the span to add to
+            _into_rows(dv_acc, c0, c1, dv, old)
+            _into_rows(dk_acc, c0, c1, dk, old)
+            written = max(written, c1)
+        assert written == block
 
     pl.when(i == j)(functools.partial(_block, True))
     if window is None:
@@ -1422,6 +1584,10 @@ def _tri_fwd_call(qt, kt, vt, *, sm_scale, block, interpret, window=None):
     B, H, T, D = qt.shape
     q_spec, kv_spec, row_spec, _ = _tri_specs(block, D, H // kt.shape[1])
     wb = _band_blocks(window, block)
+    _count_edge_tiles(
+        T // block, block, window, _edge_strip_count(block, interpret),
+        whole=True,
+    )
     return _tri_call(
         functools.partial(_tri_fwd_kernel, sm_scale=sm_scale, window=window),
         _tri_names(window)[0],
@@ -1460,11 +1626,14 @@ def _tri_bwd_call(qt, kt, vt, dot, lse4, delta4, *, sm_scale, block,
     dkv_shape = jax.ShapeDtypeStruct((B, H, T, D), jnp.float32)
     acc = pltpu.VMEM((block, D), jnp.float32)
     by_key = _triangle_steps(n, by_key=True, wb=wb)
+    strips = _edge_strip_count(block, interpret)
     dkv_kernel = functools.partial(
-        _tri_bwd_kernel, sm_scale=sm_scale, n_blocks=n, window=window
+        _tri_bwd_kernel, sm_scale=sm_scale, n_blocks=n, strips=strips,
+        window=window,
     )
     kernels = 1 if _one_pass_fits(T, D, qt.dtype.itemsize) else 2
     _count_site(_STREAM, n, kernels=kernels, walked=walked)
+    _count_edge_tiles(n, block, window, strips, kernels=kernels)
     if kernels == 1:
         whole_head = pl.BlockSpec(
             (1, 1, T, D), lambda b, h, s, qi, kj: (b, h, 0, 0)
@@ -1478,7 +1647,8 @@ def _tri_bwd_call(qt, kt, vt, dot, lse4, delta4, *, sm_scale, block,
         )
     dqt = _tri_call(
         functools.partial(
-            _tri_bwd_dq_kernel, sm_scale=sm_scale, window=window
+            _tri_bwd_dq_kernel, sm_scale=sm_scale, strips=strips,
+            window=window,
         ),
         dq_name, _triangle_steps(n, by_key=False, wb=wb), ins,
         in_specs, q_spec, dq_shape, [acc], interpret=interpret,

@@ -138,10 +138,12 @@ def test_rebuild_drops_the_executable(accel):
 # convolutions' two (PR 47), the attention sites whose outputs a
 # recomputed layer keeps (PR 51) and the share layers whose first round
 # keeps what its backward pass reads (PR 52), the selective scans' three,
-# the differential pairs' two and the cross-decoder's two reads (PR 53); how the counted ones are
+# the differential pairs' two and the cross-decoder's two reads (PR 53),
+# the edge blocks' two tile counts (PR 54); how the counted ones are
 # folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
     "attn_diff_pairs", "attn_diff_score_calls",
+    "attn_edge_tiles", "attn_edge_tiles_multiplied",
     "attn_kept_sites",
     "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
     "attn_stream_blocks_rect",

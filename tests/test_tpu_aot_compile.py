@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.common import trace_counts
 from trace_counted import (
-    CONV, DIFF, FUSED, GDN, LANES, SSCAN, STREAM, WINDOW, added,
+    CONV, DIFF, EDGE, FUSED, GDN, LANES, SSCAN, STREAM, WINDOW, added,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -175,16 +175,25 @@ def test_flash_attention_compiles(name, direction, one_chip, monkeypatch):
 
 # the Trinity-Mini cell's attention layers, [B, H, T, D] on 4 key/value
 # heads: (window, block stated or None for the call's own, blocks a head
-# walks, blocks under its diagonal)
+# walks, blocks under its diagonal, score tiles the backward multiplies of
+# its edge blocks in four row strips, tiles those blocks hold)
 WINDOW_SITES = {
-    # the global layer: the triangle at T = 16384, one-pass backward
-    "global": (None, None, 136, 136),
-    # a window layer as the model calls it: the band in blocks of 1024
-    "window": (2048, None, 45, 136),
-    "window_512": (2048, 512, 150, 528),
-    # a window off the block: two far blocks crossed
-    "window_off_block": (1500, None, 45, 136),
+    # the global layer: the triangle at T = 16384, one-pass backward;
+    # 16 blocks on the diagonal, 10 of 16 tiles each
+    "global": (None, None, 136, 136, 160, 256),
+    # a window layer as the model calls it: the band in blocks of 1024,
+    # and the 14 blocks its far edge crosses, 10 of 16 tiles each too
+    "window": (2048, None, 45, 136, 300, 480),
+    "window_512": (2048, 512, 150, 528, 600, 960),
+    # a window off the block: two far blocks crossed (row - col < 476
+    # fifteen times, 15 of 16 tiles; < -548 fourteen times, 3 of 16)
+    "window_off_block": (1500, None, 45, 136, 427, 720),
+    # the Phi-4-mini-flash cell's window layer, 40 heads on 20: a window
+    # of 512 in blocks of 1024, 9 of 16 tiles on the diagonal and 3 of
+    # 16 in the block before
+    "window_phi": (512, None, 31, 136, 189, 496),
 }
+WINDOW_HEADS = {"window_phi": (40, 20)}
 
 
 @pytest.mark.parametrize("site", list(WINDOW_SITES))
@@ -193,8 +202,9 @@ def test_window_attention_compiles_at_the_cell(
     site, direction, one_chip, monkeypatch
 ):
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
-    window, block, walked, under = WINDOW_SITES[site]
-    B, H, Hkv, T, D = 1, 32, 4, 16384, 128
+    window, block, walked, under, multiplied, held = WINDOW_SITES[site]
+    H, Hkv = WINDOW_HEADS.get(site, (32, 4))
+    B, T, D = 1, 16384, 128
     qkv = [
         jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
         for s in ((B, H, T, D), (B, Hkv, T, D), (B, Hkv, T, D))
@@ -231,6 +241,10 @@ def test_window_attention_compiles_at_the_cell(
     assert added(before, STREAM) == (sites, 0, walked * sites, n * n * sites)
     assert added(before, WINDOW) == (
         (walked * sites, under * sites) if window else (0, 0)
+    )
+    # the forward multiplies every tile, the backward its strips' spans
+    assert added(before, EDGE) == (
+        held + multiplied * (sites - 1), held * sites
     )
 
 

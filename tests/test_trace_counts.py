@@ -27,7 +27,8 @@ from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
-    CONV, DIFF, FUSED, GDN, KEPT, LANES, SHARE, SSCAN, STREAM, WINDOW, XDEC,
+    CONV, DIFF, EDGE, FUSED, GDN, KEPT, LANES, SHARE, SSCAN, STREAM, WINDOW,
+    XDEC,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -102,7 +103,8 @@ def test_counting_from_many_threads_loses_nothing(fresh):
 def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
     assert set(
-        GDN + CONV + LANES + WINDOW + KEPT + SHARE + SSCAN + DIFF + XDEC
+        GDN + CONV + LANES + WINDOW + EDGE + KEPT + SHARE + SSCAN + DIFF
+        + XDEC
     ) <= FIELDS - set(trace_counts.RUNNING_TOTALS)
 
 
@@ -393,7 +395,7 @@ TOYS = {
     ),
     "grouped_queries": (
         TransformerConfig(num_layers=1, num_kv_heads=1, **_SMALL),
-        (STREAM, LANES),
+        (STREAM, EDGE, LANES),
     ),
     "mamba2_and_delta_rule": (
         TransformerConfig(
@@ -428,18 +430,18 @@ TOYS = {
             num_kv_heads=1, positions="window", rmsnorm=True,
             mixer_out_norm=True, embed_scale=True, **_SMALL,
         ),
-        (STREAM, WINDOW, LANES),
+        (STREAM, WINDOW, EDGE, LANES),
     ),
     # phi4flash's six kinds in one model, and the same recomputed: two
     # scans (the plain statement: 96 channels are no lane tile), window,
     # full and cross differential attention, a memory unit
     "scans_and_differential_attention": (
         TransformerConfig(sscan_inner=96, **_PHI),
-        (SSCAN, CONV, DIFF, XDEC, STREAM, WINDOW, LANES),
+        (SSCAN, CONV, DIFF, XDEC, STREAM, WINDOW, EDGE, LANES),
     ),
     "scans_and_differential_attention_remat": (
         TransformerConfig(sscan_inner=128, remat=True, **_PHI),
-        (SSCAN, CONV, DIFF, XDEC, STREAM, WINDOW, LANES, KEPT),
+        (SSCAN, CONV, DIFF, XDEC, STREAM, WINDOW, EDGE, LANES, KEPT),
     ),
 }
 

@@ -8,6 +8,7 @@ from dlrover_tpu.common import trace_counts
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
 _fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
 FUSED, STREAM, WINDOW = _fa._FUSED, _fa._STREAM, _fa._WINDOW
+EDGE = _fa._EDGE
 GDN = ("gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites")
 CONV = ("conv_sites", "conv_kernel_sites")
 LANES = ("attn_score_lanes", "attn_score_lanes_used")

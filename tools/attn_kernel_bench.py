@@ -27,12 +27,22 @@ one jitted program, forward and forward + backward, host clock around
   triangle (every block at or under the diagonal walked, those past the
   window's far edge wholly masked): what the band saves.
 
+A streaming variant that ends in ``@<s>`` walks the edge blocks of its
+backward kernels (the diagonal's, and those a window's far edge crosses)
+in ``s`` row strips and not in the module's ``_EDGE_STRIPS``; ``@1`` is
+the whole block, masked, which every edge block was before the strips
+and the forward kernel's still is. A line says the strips its blocks were
+walked in (``edge_strips``), and its ``counts`` the score tiles the
+kernels multiplied of those the edge blocks hold.
+
 A shape is ``BxHxTxD`` or, with its own key-value head count,
 ``BxHxHkvxTxD``.
 
     python tools/attn_kernel_bench.py 16x12x1024x64 fused square streaming tri:128
     python tools/attn_kernel_bench.py 1x32x2x8192x128 stream-tri stream-rect block:256
     python tools/attn_kernel_bench.py 1x32x4x16384x128 stream-tri window:2048 window:2048:512 window-mask:2048
+    python tools/attn_kernel_bench.py 1x32x4x16384x128 stream-tri stream-tri@2 stream-tri@1 window:2048 window:2048@2 window:2048@1
+    python tools/attn_kernel_bench.py 1x40x20x16384x128 stream-tri window:512 window:512@2 window:512@1
 """
 import importlib
 import json
@@ -54,7 +64,7 @@ STREAM = {
     name: getattr(fa, name)
     for name in (
         "_stream_plan", "_ONE_PASS_MAX_BYTES", "_tri_fwd_kernel",
-        "_tri_bwd_kernel", "_band_blocks",
+        "_tri_bwd_kernel", "_band_blocks", "_EDGE_STRIPS",
     )
 }
 LAYERS = 12
@@ -80,6 +90,9 @@ def _steer(variant):
     fa._walk_head_chunk = HEAD_CHUNK
     for name, was in STREAM.items():
         setattr(fa, name, was)
+    variant, _, strips = variant.partition("@")
+    if strips:
+        fa._EDGE_STRIPS = int(strips)
     kind, *rest = variant.split(":")
     if kind in ("window", "window-mask"):
         if kind == "window-mask":  # a band as wide as any triangle
@@ -152,8 +165,13 @@ def bench(shape, variant):
     t0 = time.perf_counter()
     fwd = _time(jax.jit(chain), q, k, v)
     both = _time(jax.jit(jax.grad(loss, argnums=(0, 1, 2))), q, k, v)
+    block, _ = fa._validate_blocks(
+        q, k, blocks.get("block_q"), blocks.get("block_k"), "bhtd",
+        triangle=True,
+    )
     return {
         "shape": list(shape), "variant": variant,
+        "edge_strips": fa._edge_strip_count(block, False),
         "fwd_ms": round(fwd, 4), "fwd_bwd_ms": round(both, 4),
         "counts": dict(+trace_counts.since(before)),
         "wall_s": round(time.perf_counter() - t0, 1),

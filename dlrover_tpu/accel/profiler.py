@@ -2,15 +2,14 @@
 
 Parity: ATorch ``AProfiler`` (atorch/atorch/utils/prof.py:38 — analytic
 per-module flops formulas at :489-650 plus timed profiles feeding the
-dry-runner) and the TF graph profile extractor. Two sources of truth:
+dry-runner) and the TF graph profile extractor.
 
-- ``profile_model``: analytic per-block accounting from the config (no
-  device needed) — params, fwd/bwd FLOPs, activation bytes. Useful for
-  capacity planning and sanity-checking the compiler numbers.
-- ``measure_step``: wall-clock of a compiled step + achieved TFLOP/s and
-  MFU against the chip's known peak (the number BASELINE.md row 9 is
-  quoted in). XLA's own per-program accounting comes from
-  ``dry_runner.compiled_cost``; this module is the human-facing layer.
+``profile_model``: analytic per-block accounting from the config (no
+device needed) — params, fwd/bwd FLOPs, activation bytes. Useful for
+capacity planning and sanity-checking the compiler numbers. XLA's own
+per-program accounting comes from ``dry_runner.compiled_cost``; measured
+device time by part of the model comes from a profiler trace read by
+``benchmark/scopes.py`` (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -18,8 +17,6 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field, fields
 from typing import Any, Dict, List, Optional
-
-import numpy as np
 
 from dlrover_tpu.models.config import TransformerConfig, is_moe_layer
 
@@ -691,182 +688,3 @@ def profile_model(
     )
     return prof
 
-
-def trace_steps(
-    step_fn, state, args: tuple, trace_dir: str, steps: int = 3
-):
-    """Capture an XLA execution trace of ``steps`` train steps into
-    ``trace_dir`` (TensorBoard/Perfetto-viewable). Parity: atorch's
-    execution tracer (utils/tracer.py) — on TPU the runtime's own
-    profiler already records per-op device timelines, so "tracing" is
-    one context manager, not an interposer."""
-    import jax
-
-    state, metrics = step_fn(state, *args)  # compile outside the trace
-    jax.block_until_ready(jax.tree_util.tree_leaves(metrics))
-    with jax.profiler.trace(trace_dir):
-        for _ in range(steps):
-            state, metrics = step_fn(state, *args)
-        leaf = jax.tree_util.tree_leaves(metrics)[0]
-        float(np.asarray(leaf).ravel()[0])  # force inside the trace
-    return trace_dir
-
-
-@dataclass
-class StepMeasurement:
-    step_seconds: float
-    achieved_tflops: float
-    mfu_pct: Optional[float]
-    device_kind: str
-
-
-def measure_step(
-    step_fn, state, args: tuple, model_flops: float, iters: int = 10
-) -> StepMeasurement:
-    """Time a compiled train step and report achieved TFLOP/s + MFU.
-
-    The (state, metrics) chain is forced by materializing the LAST
-    iteration's metrics on the host, so the timing covers execution and
-    not only dispatch.
-    """
-    import jax
-
-    def _force(metrics):
-        leaf = jax.tree_util.tree_leaves(metrics)[0]
-        return float(np.asarray(leaf).ravel()[0])
-
-    state, metrics = step_fn(state, *args)  # compile + warmup
-    _force(metrics)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        state, metrics = step_fn(state, *args)
-    _force(metrics)  # last metrics depend on every step's params
-    dt = (time.perf_counter() - t0) / iters
-    tflops = model_flops / dt / 1e12
-    dev = jax.devices()[0]
-    peak = chip_peak_tflops(dev)
-    n_dev = len(jax.devices())
-    return StepMeasurement(
-        step_seconds=dt,
-        achieved_tflops=tflops,
-        mfu_pct=(
-            round(100.0 * tflops / (peak * n_dev), 2) if peak else None
-        ),
-        device_kind=getattr(dev, "device_kind", "unknown"),
-    )
-
-
-@dataclass
-class ModuleLatency:
-    name: str
-    ms: float
-    gflops: float  # analytic, per invocation
-    tflops_per_s: Optional[float]  # achieved (None when flops unknown)
-
-
-def module_breakdown(
-    cfg: TransformerConfig,
-    tx,
-    batch: int,
-    seq: int,
-    iters: int = 10,
-) -> List[ModuleLatency]:
-    """MEASURED per-module latency — the "why is my step slow" view
-    (parity: AProfiler's per-module flops/latency/memory tables,
-    atorch utils/prof.py:489-650).
-
-    Each module is compiled and timed in isolation on the current
-    default device: embedding lookup, ONE transformer block fwd and
-    fwd+bwd, the LM head fwd+bwd (the vocab matmul + softmax NLL), and
-    the optimizer update over the full parameter tree. Isolation
-    overstates HBM traffic relative to a fused step (boundaries
-    materialize), so read the numbers as per-module ROOFLINES: a module
-    whose isolated time already dominates the measured whole-step time
-    is the bottleneck.
-    """
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from dlrover_tpu.models.transformer import (
-        _attention_block,
-        _mlp_block,
-        embed_tokens,
-        init_params,
-        lm_head,
-        token_nll,
-    )
-
-    params = jax.jit(lambda k: init_params(k, cfg))(jax.random.PRNGKey(0))
-    jax.block_until_ready(params)
-    prof = profile_model(cfg, batch, seq)
-    by_name = {m.name: m for m in prof.modules}
-    tokens = jnp.zeros((batch, seq), jnp.int32)
-    positions = jnp.broadcast_to(jnp.arange(seq), (batch, seq))
-    x = jnp.zeros((batch, seq, cfg.model_dim), jnp.dtype(cfg.dtype))
-    layer0 = (
-        jax.tree_util.tree_map(lambda x: x[0], params["layers"])
-        if cfg.scan_layers
-        else params["layers"][0]
-    )
-
-    def _block_fwd(layer, x):
-        h = _attention_block(x, layer, cfg, None, positions)
-        h, _ = _mlp_block(h, layer, cfg, None)
-        return h
-
-    def _block_loss(layer, x):
-        return jnp.sum(_block_fwd(layer, x).astype(jnp.float32))
-
-    def _head_loss(p, x):
-        return token_nll(lm_head(p, x, cfg), tokens)
-
-    grads = jax.tree_util.tree_map(
-        lambda a: jnp.ones_like(a) * 1e-4, params
-    )
-    opt_state = jax.jit(tx.init)(params)
-
-    def _opt(p, o, g):
-        u, o = tx.update(g, o, p)
-        return optax.apply_updates(p, u), o
-
-    block_fwd_flops = (
-        by_name["block0.attn"].fwd_flops + by_name["block0.mlp"].fwd_flops
-    )
-    cases = [
-        ("embed", jax.jit(lambda p, t: embed_tokens(p, t, cfg)),
-         (params, tokens), 0.0),
-        ("block_fwd", jax.jit(_block_fwd), (layer0, x), block_fwd_flops),
-        ("block_fwd_bwd", jax.jit(jax.grad(_block_loss, argnums=(0, 1))),
-         (layer0, x), 3.0 * block_fwd_flops),
-        ("lm_head_fwd_bwd", jax.jit(jax.grad(_head_loss)),
-         (params, x), 3.0 * by_name["lm_head"].fwd_flops),
-        ("optimizer_update", jax.jit(_opt),
-         (params, opt_state, grads), 0.0),
-    ]
-
-    out: List[ModuleLatency] = []
-    for name, fn, args, flops in cases:
-        r = fn(*args)  # compile + warmup
-        jax.block_until_ready(r)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            r = fn(*args)
-        # close the timing with a scalar readback that depends on the
-        # result. The slice happens DEVICE-side: a np.asarray(leaf)
-        # here would drag the whole leaf over the d2h link and bill it
-        # to the module being timed
-        leaf = jax.tree_util.tree_leaves(r)[0]
-        float(jnp.ravel(leaf)[0].astype(jnp.float32))
-        dt = (time.perf_counter() - t0) / iters
-        out.append(
-            ModuleLatency(
-                name=name,
-                ms=round(dt * 1e3, 3),
-                gflops=round(flops / 1e9, 4),
-                tflops_per_s=(
-                    round(flops / dt / 1e12, 2) if flops else None
-                ),
-            )
-        )
-    return out

@@ -185,6 +185,23 @@ class PipelineStats:
     gdn_sites: int = 0
     gdn_chunk_steps: int = 0
     gdn_kernel_sites: int = 0
+    # Gated DeltaNet mixers traced as the primal of a recomputed layer
+    # (``models/transformer.recomputed``, ``cfg.remat``) in the train step
+    # program this process traced last: the wrapper keeps what the serial
+    # pass read and returned, the rule's ``o`` and the ``[q | k | v]``
+    # the convolution reads (``ops/gated_delta.KEPT``), so the
+    # ``gdn_*_wy_fwd`` / ``gdn_*_read_fwd`` kernels, the pass's forward
+    # loop and the projection run once a step and not again in the
+    # backward pass, and ``gdn_chunk_steps`` holds no step of that trace.
+    # 0 without ``remat``. So ``gdn_chunk_steps`` is the serial depth of
+    # the DIFFERENTIATED train step, which is what is traced here: the
+    # flag says "traced inside ``recomputed``", not "differentiated", and a
+    # forward-only program of a ``remat`` configuration (an evaluation, a
+    # pipeline stage's forward) would run the pass and count no step. That
+    # the second pass is gone from the step that runs is read from the
+    # device, not from this count (the benchmark's
+    # ``gdn.fwd_kernel_runs_per_step``, ``step.mixer_scan_ms``)
+    gdn_kept_sites: int = 0
     # convolution stretches before a scan (``ops/mamba2.conv_silu``: one
     # a Mamba-2, Gated DeltaNet or Mamba-1 mixer) in the train step program
     # this

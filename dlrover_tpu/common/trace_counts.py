@@ -22,10 +22,17 @@ Two scopes, kept as they were when each module held a tally of its own:
 A new count is one ``count(...)`` where the fact is known and one field on
 ``PipelineStats`` (``tests/test_trace_counts.py`` holds every name seen to
 be such a field).
+
+One fact a module cannot see from its arguments is whether the layer it is
+traced into is one the backward pass makes again and that keeps what the
+module names for it (``models/transformer.recomputed``): the helper says so
+around the trace (``keeping_outputs()``), and a module that counts such
+sites asks ``keeping()``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from collections import Counter
 
@@ -43,6 +50,33 @@ RUNNING_TOTALS = (
 # a speculative compile traces on a thread of its own
 _lock = threading.Lock()
 _counts: Counter = Counter()
+
+
+class _Keeping(threading.local):
+    on = False
+
+
+_keeping = _Keeping()
+
+
+@contextlib.contextmanager
+def keeping_outputs():
+    """Around the trace of a function whose ``jax.checkpoint`` saves what
+    the modules named for it (``models/transformer.KEPT``): a call traced
+    inside is a site whose named arrays the backward pass reads and does
+    not make again (``attn_kept_sites``, ``gdn_kept_sites``). A
+    ``custom_vjp``'s forward rule is traced later, when the wrapper is
+    differentiated, and outside this."""
+    was = _keeping.on
+    _keeping.on = True
+    try:
+        yield
+    finally:
+        _keeping.on = was
+
+
+def keeping() -> bool:
+    return _keeping.on
 
 
 def count(name: str, n: int = 1):

@@ -36,11 +36,9 @@ from dlrover_tpu.models.config import (
     is_moe_layer,
     num_moe_layers,
 )
-from dlrover_tpu.ops.flash_attention import (
-    KEPT as ATTENTION_KEPT,
-    keeping_outputs,
-)
+from dlrover_tpu.ops.flash_attention import KEPT as ATTENTION_KEPT
 from dlrover_tpu.ops.gated_delta import (
+    KEPT as DELTA_RULE_KEPT,
     gated_delta_logical_axes,
     gated_delta_mixer,
     init_gated_delta_params,
@@ -1067,7 +1065,7 @@ def token_nll(
 # what is made again by the policy's identity, and a policy a wrapper would
 # split every layer's inner functions anew (twice the functions in the
 # lowered step)
-KEPT = ATTENTION_KEPT + SHARE_KEPT
+KEPT = ATTENTION_KEPT + SHARE_KEPT + DELTA_RULE_KEPT
 _KEEP_BY_NAME = jax.checkpoint_policies.save_only_these_names(*KEPT)
 
 
@@ -1077,19 +1075,23 @@ def recomputed(layer_fn):
     keeps its input and, of what it computes, what the modules named for
     it alone (``KEPT``): what its attention kernel read and returned
     (``ops/flash_attention.KEPT``: q, k, v after head norm and rotation,
-    ``o`` and the logsumexp, O(T D) bytes that cost O(T^2 D) operations)
-    and what the first round of a share of the experts gathered and its
-    grouped matmuls returned (``parallel/moe.KEPT``). So the backward
-    pass makes the projections, norms, gates, router and dense
-    feed-forward again, runs the forward attention kernel and the held
-    experts' grouped matmuls no second time and does not remake the
-    stretch that only feeds them. A layer without such a call (a scan,
-    dropless experts, the jnp attention path, a ring) holds no such name
-    and keeps its input, as a bare ``jax.checkpoint`` does."""
+    ``o`` and the logsumexp, O(T D) bytes that cost O(T^2 D) operations),
+    what the first round of a share of the experts gathered and its
+    grouped matmuls returned (``parallel/moe.KEPT``), and of a delta-rule
+    mixer what its serial pass read and returned, the rule's ``o`` and
+    the projection's ``[q | k | v]`` that its convolution reads
+    (``ops/gated_delta.KEPT``). So the backward pass makes the
+    projections, norms, gates, router and dense feed-forward again, runs
+    the forward attention kernel, the held experts' grouped matmuls and
+    the delta rule's forward kernels and serial pass no second time and
+    does not remake the stretch that only feeds them. A layer without such
+    a call (a Mamba scan, dropless experts, the jnp attention path, a
+    ring) holds no such name and keeps its input, as a bare
+    ``jax.checkpoint`` does."""
 
     @functools.wraps(layer_fn)
     def traced(*args):
-        with keeping_outputs():
+        with trace_counts.keeping_outputs():
             return layer_fn(*args)
 
     return jax.checkpoint(traced, policy=_KEEP_BY_NAME)

@@ -113,9 +113,7 @@ elementwise bool mask, e.g. ``lambda q, k: q >= k`` for causal.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 from typing import Callable, Optional
 
 import jax
@@ -435,28 +433,6 @@ KEPT = (
     "flash_attn_q", "flash_attn_k", "flash_attn_v", "flash_attn_o",
     "flash_attn_lse",
 )
-
-
-class _Keeping(threading.local):
-    # a speculative compile traces on a thread of its own
-    on = False
-
-
-_keeping = _Keeping()
-
-
-@contextlib.contextmanager
-def keeping_outputs():
-    """Around the trace of a function whose ``jax.checkpoint`` saves
-    ``KEPT``: a differentiable kernel call traced inside is a site of
-    ``common/trace_counts`` (``attn_kept_sites``) whose forward kernel
-    the backward pass does not run again."""
-    was = _keeping.on
-    _keeping.on = True
-    try:
-        yield
-    finally:
-        _keeping.on = was
 
 
 def _count_site(names, n: int, kernels: int = 1, walked=None):
@@ -2431,7 +2407,7 @@ def flash_attention(
             "the differentiable pallas path needs static int offsets; "
             "use flash_attention_fwd/_bwd for traced offsets"
         )
-    trace_counts.count("attn_kept_sites", _keeping.on)
+    trace_counts.count("attn_kept_sites", trace_counts.keeping())
     return _flash_pallas(
         q, k, v, (q_offset, k_offset), causal, mask_fn, scale, bq, bk,
         layout, allow_fused, window

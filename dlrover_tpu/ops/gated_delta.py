@@ -44,6 +44,28 @@ mixer of a program), their sequential steps, a backward pass counted with
 its forward (``gdn_chunk_steps``), and the sites whose chunk-local work
 went into the kernels (``gdn_kernel_sites``).
 
+**What a recomputed layer keeps.** The pass's forward rule names what it
+hands its backward rule (``W``, the keys as the pass took them, ``delta``,
+``a``, and its two results ``V'`` and the entered states, which the
+read-out reads too), ``_delta_rule`` names the rule's ``o``, and the mixer
+the projection's ``[q | k | v]``, which its convolution reads
+(``jax.ad_checkpoint.checkpoint_name``, ``KEPT``). Outside a
+``jax.checkpoint`` whose policy saves those names a name is an identity and
+lowers to nothing. Inside one (``models/transformer.recomputed``) nothing
+the backward pass reads depends on a second ``wy``, a second pass or a
+second read-out any more: it makes the norm, the small projections, the
+convolution, the unit-length q and k, the decays and the gate again from
+the layer's input and the kept arrays, and runs the backward kernels and
+the reversed pass on what the first forward left (``gdn_kept_sites``;
+``gdn_chunk_steps`` then holds one forward pass a site and not the two the
+layer is traced as: the count of a differentiated step, since the flag it
+asks says where the site was traced and not whether it is differentiated).
+What is named is also what the step's non-donating twin, the one that runs
+while a flash save is staged, must hold beside two copies of the state:
+the convolution's result has no name for that (``PERF.md`` PR 56). For
+either kind of decay and either way to execute the chunk-local work: the
+names sit where both meet.
+
 ``cfg.gdn_decay`` "channel" is Kimi Delta Attention's rule (arXiv:
 2510.26692): the decay is a vector over the key's channels, the transition
 ``(I - beta_t k_t k_t^T) Diag(alpha_t)``, and ``gamma`` a row ``[C, d_k]``
@@ -76,6 +98,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.common import trace_counts
 from dlrover_tpu.ops import gated_delta_kernels as kernels
@@ -84,6 +107,23 @@ from dlrover_tpu.ops.mamba2 import conv_silu, gated_group_rmsnorm
 L2_EPS = 1e-6  # of the unit-length q and k (the source's ``l2norm``)
 SUB_BLOCK = kernels.SUB_BLOCK  # the one bound of both ways to execute
 DT_SHARE = (0.002, 0.2)  # of ``sigmoid(dt_bias)`` at init, vector decay
+
+# what a ``jax.checkpoint`` around a layer saves of a delta-rule mixer when
+# its policy holds these names (``models/transformer.recomputed``; the
+# module's docstring): what the pass's forward rule hands its backward rule
+# (``W``, the keys, ``delta``, ``a``, and its results ``V'`` and the entered
+# states), the rule's result ``o`` ...
+_PASS_KEPT = (
+    "gdn_pass_w", "gdn_pass_k", "gdn_pass_delta", "gdn_pass_a",
+    "gdn_pass_vn", "gdn_pass_s_in",
+)
+_RULE_KEPT = "gdn_rule_o"
+# ... and of the stretch before the rule, named at the mixer's own call:
+# the projection's ``[q | k | v]``, what the convolution reads (its result
+# is made again from it: that array kept as well bought 1.6 ms of a 603 ms
+# step for 1.13 GiB, ``PERF.md`` PR 56)
+_IN_KEPT = "gdn_in_qkv"
+KEPT = _PASS_KEPT + (_RULE_KEPT, _IN_KEPT)
 
 
 def init_gated_delta_params(key, cfg, dtype):
@@ -268,8 +308,14 @@ def _decay_rows(delta, V):
 
 def _pass_forward(U, W, K, delta, a):
     f32, act = jnp.float32, W.dtype
+    # the primal trace of a recomputed layer that keeps ``KEPT``: its
+    # backward pass reads what the forward rule left and runs no forward
+    # pass again, so the steps that run are those of the rule's own trace
+    # (made when the layer is differentiated, and not inside ``keeping``)
+    kept = trace_counts.keeping()
     trace_counts.count("gdn_sites")
-    trace_counts.count("gdn_chunk_steps", U.shape[0])
+    trace_counts.count("gdn_kept_sites", kept)
+    trace_counts.count("gdn_chunk_steps", 0 if kept else U.shape[0])
 
     def step(S, x):
         U, W, K, delta, a = x
@@ -306,7 +352,10 @@ def chunk_state_pass(U, W, K, delta, a):
 
 def _chunk_state_pass_fwd(U, W, K, delta, a):
     Vn, S_in = _pass_forward(U, W, K, delta, a)
-    return (Vn, S_in), (W, K, delta, a, Vn, S_in)
+    # one copy of each: the named ``V'`` and states are the results too
+    # (a ``delta`` of None, the vector kind's, is an empty tree to a name)
+    res = tuple(map(checkpoint_name, (W, K, delta, a, Vn, S_in), _PASS_KEPT))
+    return res[4:], res
 
 
 def _chunk_state_pass_bwd(res, cts):
@@ -628,7 +677,7 @@ def _delta_rule(q, k, v, beta, g, chunk: int, mesh):
     from jax.sharding import PartitionSpec as P
 
     def rule(*a):
-        return gated_delta_chunked(*a, chunk)
+        return checkpoint_name(gated_delta_chunked(*a, chunk), _RULE_KEPT)
 
     args = (q, k, v, beta, g)
     if not kernels.fits(q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype):
@@ -680,7 +729,7 @@ def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
     f32 = jnp.float32
     channel = cfg.gdn_decay == "channel"
     with jax.named_scope("scope/layer/gdn/in_proj"):
-        qkv = u @ p["w_qkv"].astype(act)
+        qkv = checkpoint_name(u @ p["w_qkv"].astype(act), _IN_KEPT)
         z = u @ p["w_z"].astype(act)
         if channel:
             b = jnp.dot(u, p["w_b"].astype(act), preferred_element_type=f32)

@@ -41,7 +41,23 @@ ISSUE 53 brought ``phi4-mini-flash-d6`` (Mamba-1 scans, whose kernels are
 interpreted on the CPU at these widths, differential attention on the jnp
 path, a memory unit and a cross-attention that read another layer's
 values, which ``forward``'s loop now carries beside the residual stream)
-and left the seven entries before it as they were.
+and left the seven entries before it as they were. ISSUE 56 recorded the
+steps of ``qwen3-next-80b-a3b-d4`` and ``ling-3.0-flash-d7`` anew, their
+trees as they were: the delta rule's serial pass names what its forward
+rule hands its backward rule, ``_delta_rule`` its result and the mixer the
+``[q | k | v]`` its convolution reads (``ops/gated_delta.KEPT``). What
+both steps were before is kept beside them as ``step_without_names``, and
+``without_names`` gives it still: the step lowered with those names taken
+off. In the Qwen3-Next step (no ``remat``) a name lowers to nothing and
+the text is the old one operation for operation; each name is an equation
+all the same, which the lowering emits as a function of its own before it
+inlines it, and that moves the numbers it appends to an inner function's
+name to tell its copies apart (``@triu_68`` -> ``@triu_73``): with those
+cut back to the name (``inner_numbers_off``) the text with the names is
+the text without them, which ``test_nemotron_h.py`` holds it to. The Ling
+step (``remat``) is another program with the names: its recomputed KDA
+layers keep the named arrays and hold each forward kernel and the pass
+once. The other six entries are as they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
@@ -51,9 +67,11 @@ that is meant to leave those configurations alone leaves both alone. A PR
 that means to change their step records the file anew and says so.
 """
 
+import functools
 import hashlib
 import json
 import os
+import re
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -64,7 +82,24 @@ NAMES = (
 )
 
 
-def fingerprint(name: str) -> dict:
+def inner_numbers_off(text: str) -> str:
+    """A lowered text with the numbers cut off that tell the copies of an
+    inner function apart (``@triu_73`` -> ``@triu``): they count every
+    equation lowered before, those that lower to nothing too."""
+    return re.sub(r"(@\w+?)_\d+\b", r"\1", text)
+
+
+def configuration(name: str) -> dict:
+    with open(
+        os.path.join(ROOT, "benchmark", "configs", f"{name}.json")
+    ) as f:
+        return json.load(f)
+
+
+@functools.cache  # a step lowers in 20 to 50 s, and two tests read it
+def lowered(name: str) -> tuple:
+    """The parameter tree (paths, shapes, dtypes) and the text of the
+    lowered train step of a benchmark configuration."""
     import jax
     import jax.numpy as jnp
 
@@ -74,10 +109,7 @@ def fingerprint(name: str) -> dict:
     from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
     from dlrover_tpu.trainer.elastic.optimizer import build_optimizer
 
-    with open(
-        os.path.join(ROOT, "benchmark", "configs", f"{name}.json")
-    ) as f:
-        config = json.load(f)
+    config = configuration(name)
     cfg = TransformerConfig(**config["model"])
     opt = dict(config["optimizer"])
     tx = build_optimizer(opt.pop("name"), **opt)
@@ -98,13 +130,37 @@ def fingerprint(name: str) -> dict:
         )
     )
     x = jax.ShapeDtypeStruct((1, 1024), jnp.int32)
-    text = build_train_step(cfg, mesh, tx).lower(abstract, x, x).as_text()
-    return {
-        "tree": hashlib.sha256(tree.encode()).hexdigest(),
-        "step": hashlib.sha256(text.encode()).hexdigest(),
-    }
+    step = build_train_step(cfg, mesh, tx).lower(abstract, x, x)
+    return tree, step.as_text()
+
+
+def without_names(name: str) -> str:
+    """The step's text with the delta rule's names taken off
+    (``ops/gated_delta.KEPT``: ``checkpoint_name`` an identity while it is
+    traced): the program the configuration had before ISSUE 56."""
+    from dlrover_tpu.ops import gated_delta
+
+    name_of = gated_delta.checkpoint_name
+    gated_delta.checkpoint_name = lambda x, name: x
+    try:
+        return lowered.__wrapped__(name)[1]
+    finally:
+        gated_delta.checkpoint_name = name_of
+
+
+def _sha(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def fingerprint(name: str) -> dict:
+    tree, step = lowered(name)
+    return {"tree": _sha(tree), "step": _sha(step)}
 
 
 if __name__ == "__main__":
-    json.dump({n: fingerprint(n) for n in NAMES}, sys.stdout, indent=1)
+    record = {n: fingerprint(n) for n in NAMES}
+    for n in NAMES:
+        if "G" in (configuration(n)["model"].get("layer_pattern") or ""):
+            record[n]["step_without_names"] = _sha(without_names(n))
+    json.dump(record, sys.stdout, indent=1)
     print()

@@ -18,6 +18,7 @@ one step at a time, sorted grouped matmuls against every expert on every
 token, flash attention's jnp path against a plain softmax).
 """
 
+import hashlib
 import importlib.util
 import json
 import os
@@ -36,6 +37,7 @@ from dlrover_tpu.models.transformer import (
     logical_axes,
     loss_fn,
 )
+from dlrover_tpu.ops import gated_delta
 from dlrover_tpu.ops.mamba2 import (
     causal_conv1d,
     gated_group_rmsnorm,
@@ -50,7 +52,13 @@ from dlrover_tpu.parallel.moe import (
     route,
 )
 from dlrover_tpu.trainer.elastic.trainer import build_optimizer
-from lowering_fingerprint import fingerprint
+from lowering_fingerprint import (
+    configuration,
+    fingerprint,
+    inner_numbers_off,
+    lowered,
+    without_names,
+)
 
 RTOL = 2e-5
 GRAD_RTOL = 5e-5  # a gradient sums more terms in another order
@@ -595,4 +603,28 @@ def test_existing_configurations_lower_to_the_step_they_had(name):
     """``tests/lowering_fingerprint.py``: the parameter tree and the
     lowered train step of the configurations the benchmark had before
     ISSUE 37, against what the commit before it gave."""
-    assert fingerprint(name) == RECORDED[name]
+    want = {k: RECORDED[name][k] for k in ("tree", "step")}
+    assert fingerprint(name) == want
+
+
+WITHOUT_NAMES = sorted(
+    n for n in RECORDED if "step_without_names" in RECORDED[n]
+)
+
+
+@pytest.mark.parametrize("name", WITHOUT_NAMES)
+def test_a_delta_rule_step_without_its_names_is_the_step_it_was(name):
+    """The names the delta rule gives a recomputed layer to keep
+    (``ops/gated_delta.KEPT``, ISSUE 56) taken off, a step lowers byte
+    for byte to the text recorded before they came; and where nothing
+    recomputes, the step with the names is that text but for the numbers
+    that tell an inner function's copies apart: a name lowers to nothing
+    outside a policy."""
+    plain = without_names(name)
+    sha = hashlib.sha256(plain.encode()).hexdigest()
+    assert sha == RECORDED[name]["step_without_names"]
+    named = lowered(name)[1]
+    assert not any(kept in named for kept in gated_delta.KEPT)
+    remat = configuration(name)["model"].get("remat", False)
+    same = inner_numbers_off(named) == inner_numbers_off(plain)
+    assert same == (not remat)

@@ -139,7 +139,8 @@ def test_rebuild_drops_the_executable(accel):
 # recomputed layer keeps (PR 51) and the share layers whose first round
 # keeps what its backward pass reads (PR 52), the selective scans' three,
 # the differential pairs' two and the cross-decoder's two reads (PR 53),
-# the edge blocks' two tile counts (PR 54); how the counted ones are
+# the edge blocks' two tile counts (PR 54), the delta-rule mixers whose
+# pass a recomputed layer keeps (PR 56); how the counted ones are
 # folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
     "attn_diff_pairs", "attn_diff_score_calls",
@@ -155,7 +156,8 @@ AS_DICT_KEYS = [
     "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "conv_kernel_sites", "conv_sites",
     "donated_bytes", "donated_steps",
-    "gdn_chunk_steps", "gdn_kernel_sites", "gdn_sites", "grad_bytes_raw", "grad_bytes_wire",
+    "gdn_chunk_steps", "gdn_kept_sites", "gdn_kernel_sites", "gdn_sites",
+    "grad_bytes_raw", "grad_bytes_wire",
     "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",
     "grad_sync_ms", "grad_sync_path", "lock_local_answers",

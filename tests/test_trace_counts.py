@@ -27,8 +27,8 @@ from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
-    CONV, DIFF, EDGE, FUSED, GDN, KEPT, LANES, SHARE, SSCAN, STREAM, WINDOW,
-    XDEC,
+    CONV, DIFF, EDGE, FUSED, GDN, GDN_KEPT, KEPT, LANES, SHARE, SSCAN,
+    STREAM, WINDOW, XDEC,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -104,7 +104,7 @@ def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
     assert set(
         GDN + CONV + LANES + WINDOW + EDGE + KEPT + SHARE + SSCAN + DIFF
-        + XDEC
+        + XDEC + GDN_KEPT
     ) <= FIELDS - set(trace_counts.RUNNING_TOTALS)
 
 
@@ -274,6 +274,26 @@ FOLDS = {
             dict(zip(LANES + KEPT, (640, 640, 0))),
         ),
     ],
+    # six KDA mixers in the kernels at T = 8192, recomputed: each is traced
+    # as the primal and through the rule, the primal's forward pass is one
+    # the backward pass does not run and holds no step; then the same
+    # without ``remat``
+    "delta_rule_mixers_a_recomputed_layer_keeps": [
+        "step_donating",
+        dict(zip(GDN + GDN_KEPT, (12, 1536, 12, 6))),
+        (
+            "; traced: gdn_sites =12, gdn_chunk_steps =1536, "
+            "gdn_kernel_sites =12, gdn_kept_sites =6",
+            dict(zip(GDN + GDN_KEPT, (12, 1536, 12, 6))),
+        ),
+        "step_donating",
+        dict(zip(GDN, (6, 1536, 6))),
+        (
+            "; traced: gdn_sites =6, gdn_chunk_steps =1536, "
+            "gdn_kernel_sites =6",
+            dict(zip(GDN + GDN_KEPT, (6, 1536, 6, 0))),
+        ),
+    ],
     # four layers that hold a share of the experts beside five attention
     # layers, recomputed: the reference check's forward pass counted
     # before the step's build is not in it, and a model that holds every
@@ -412,6 +432,17 @@ TOYS = {
             **_SMALL, **dict(_MIXERS, positions=""),
         ),
         (GDN, CONV, FUSED, LANES),
+    ),
+    # the same recomputed: a mixer the wrapper keeps the pass's arrays of
+    "vector_decay_and_latent_attention_remat": (
+        TransformerConfig(
+            num_layers=2, layer_pattern="G*", gdn_decay="channel",
+            gdn_decay_bound=-5.0, remat=True,
+            gdn_gate="head_sigmoid", attn_kind="latent", kv_latent_dim=16,
+            qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope=True,
+            **_SMALL, **dict(_MIXERS, positions=""),
+        ),
+        (GDN, CONV, FUSED, LANES, KEPT, GDN_KEPT),
     ),
     # two of eight experts held in each of two layers, then the same
     # recomputed: a share layer is one site either way

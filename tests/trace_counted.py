@@ -13,6 +13,7 @@ GDN = ("gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites")
 CONV = ("conv_sites", "conv_kernel_sites")
 LANES = ("attn_score_lanes", "attn_score_lanes_used")
 KEPT = ("attn_kept_sites",)
+GDN_KEPT = ("gdn_kept_sites",)
 SHARE = ("moe_share_kept_sites",)
 SSCAN = ("sscan_sites", "sscan_kernel_sites", "sscan_serial_steps")
 DIFF = ("attn_diff_pairs", "attn_diff_score_calls")

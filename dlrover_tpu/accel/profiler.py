@@ -244,6 +244,28 @@ class PipelineStats:
     # attention
     attn_score_lanes: int = 0
     attn_score_lanes_used: int = 0
+    # a looped model (``cfg.ut_steps`` > 1, models/transformer.py: the
+    # whole stack applied several times over the same weights) in the
+    # train step program this process traced last: the passes one step
+    # runs; the layer bodies one forward pass of the step runs, in
+    # ONE-MIXER LAYERS (entries of the ``layer_pattern``, so a published
+    # block of attention then feed-forward is two) summed over the passes;
+    # and the exits through the head. The passes are a Python loop in
+    # ``forward`` (``ut_passes``): every pass's layers are traced and
+    # counted for themselves, so these and the attention kernels' site,
+    # tile and kept counters above are of a whole step, L x ``ut_steps``
+    # sites. 0 / 0 / 0 for a model that runs its layers once
+    ut_steps: int = 0
+    ut_layer_passes: int = 0
+    ut_exit_heads: int = 0
+    # ... and its exits in the steps reported at the log cadence (as the
+    # routers' above): how many reports, their summed mean entropy of a
+    # token's stopping distribution over the passes (nats; ln ut_steps =
+    # even, 0 = the gate has collapsed onto one pass) and their summed
+    # mean expected pass of stopping (from 1)
+    ut_reports: int = 0
+    ut_entropy_sum: float = _reported_to(6)
+    ut_exit_step_sum: float = _reported_to(6)
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was
@@ -659,12 +681,16 @@ def profile_model(
     d, f, v = cfg.model_dim, cfg.ffn_dim, cfg.vocab_size
     h, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     T, B = seq, batch
-    tok = B * T
+    # a looped model (``cfg.ut_steps``) runs every block and the head
+    # once a pass over the same weights: a token's operations and
+    # activations are ``ut_steps`` tokens' of a plain model, the
+    # parameters are held once, the embedding is read once
+    tok = B * T * cfg.ut_steps
     prof = ModelProfile(batch=batch, seq=seq)
 
     emb_params = v * d + (0 if cfg.rope else cfg.max_seq_len * d)
     prof.modules.append(
-        ModuleProfile("embed", emb_params, 0.0, tok * d * act_bytes)
+        ModuleProfile("embed", emb_params, 0.0, B * T * d * act_bytes)
     )
 
     for i in range(cfg.num_layers):
@@ -672,8 +698,8 @@ def profile_model(
         attn_flops = 2.0 * tok * d * (h + 2 * kvh) * hd  # projections
         attn_flops += 2.0 * tok * h * hd * d  # output proj
         # qk^T and softmax*v have identical causal structure: half each
-        attn_flops += 2.0 * B * h * T * T * hd / 2
-        attn_flops += 2.0 * B * h * T * T * hd / 2
+        attn_flops += 2.0 * tok * h * T * hd / 2
+        attn_flops += 2.0 * tok * h * T * hd / 2
         attn_act = tok * (h + 2 * kvh) * hd * act_bytes + tok * d * act_bytes
         prof.modules.append(
             ModuleProfile(

@@ -80,6 +80,18 @@ class TransformerConfig:
     # norm_out(mixer(norm(x)))`` (``layer_pattern`` models; "out_norm" in
     # a layer's parameters)
     mixer_out_norm: bool = False
+    # how often the whole stack of a ``layer_pattern`` is applied to the
+    # residual stream, with the same weights every pass and the final
+    # norm after each (a looped language model, arXiv:2510.25741; the
+    # source's ``total_ut_steps``): pass ``t`` reads the normed stream of
+    # pass ``t - 1``, every pass exits through the one head, and a
+    # learned gate (``exit_gate`` in the parameters) says how much of a
+    # token's probability of stopping falls on each pass. 1 => a plain
+    # model: no gate, one exit
+    ut_steps: int = 1
+    # the weight of the entropy of that stopping distribution in the
+    # loss: ``mean(sum_t p_t nll_t - ut_entropy_weight * H(p))``
+    ut_entropy_weight: float = 0.0
     # the token embedding is multiplied by ``sqrt(model_dim)`` as it
     # enters the residual stream (and nothing else is: an untied head
     # reads its own table)
@@ -355,6 +367,31 @@ class TransformerConfig:
             raise ValueError(
                 "mixer_out_norm is of the one-mixer layers of a "
                 "layer_pattern"
+            )
+        if self.ut_steps < 1 or self.ut_entropy_weight < 0:
+            raise ValueError(
+                f"ut_steps {self.ut_steps} is a count of passes from 1, "
+                f"ut_entropy_weight {self.ut_entropy_weight} no less "
+                "than 0"
+            )
+        if self.ut_steps == 1 and self.ut_entropy_weight:
+            raise ValueError(
+                "ut_entropy_weight is of the stopping distribution over "
+                "several passes: ut_steps is 1"
+            )
+        if self.ut_steps > 1 and not self.layer_pattern:
+            raise ValueError(
+                f"ut_steps {self.ut_steps}: the stack that is looped is "
+                "a layer_pattern's walk; the attention + FFN blocks "
+                "(and their scan_layers form, which scans over the "
+                "layers' leaves and not over passes of the same leaves) "
+                "run once"
+            )
+        if self.ut_steps > 1 and self.num_experts:
+            raise ValueError(
+                f"ut_steps {self.ut_steps} with experts: a router's load "
+                "and drop rate, and the rule that moves its selection "
+                "bias, are of one visit a step"
             )
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")

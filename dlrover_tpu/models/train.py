@@ -827,6 +827,14 @@ def build_train_step(
             n_moe = max(num_moe_layers(cfg), 1)
             metrics["moe_expert_load"] = aux["load"] / n_moe
             metrics["moe_drop_rate"] = aux["drop"] / n_moe
+        if cfg.ut_steps > 1:
+            # a looped model's exits (``models/transformer.ut_exits``):
+            # the mean entropy of the stopping distribution (nats), the
+            # mean expected pass of stopping (from 1), and each pass's
+            # mean NLL (a [ut_steps] vector: consumers that report
+            # scalars must pop it, as ``moe_expert_load``)
+            for name in ("ut_entropy", "ut_exit_step", "ut_exit_nll"):
+                metrics[name] = aux[name]
         return (
             TrainState(
                 step=state.step + 1,
@@ -847,6 +855,22 @@ def build_train_step(
         donate_argnums=donate_argnums,
         out_shardings=(st_sh, None),
     )
+
+
+def fold_exit_report(metrics, stats) -> str:
+    """Fold a reported step's own ``ut_entropy`` / ``ut_exit_step`` into
+    ``PipelineStats.ut_*`` and say each pass's mean NLL for the log line;
+    nothing and "" for a model that runs its layers once. Copies to the
+    host of a step already waited for, as ``parallel/moe.
+    fold_routing_report``."""
+    if "ut_entropy" not in metrics:
+        return ""
+    entropy = float(metrics["ut_entropy"])
+    stats.ut_reports += 1
+    stats.ut_entropy_sum += entropy
+    stats.ut_exit_step_sum += float(metrics["ut_exit_step"])
+    each = ", ".join(f"{float(n):.4f}" for n in metrics["ut_exit_nll"])
+    return f" ut_exit_nll=[{each}] ut_entropy={entropy:.4f}"
 
 
 def shard_batch(batch, mesh):

@@ -242,6 +242,11 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         params["layers"].append(layer)
     if cfg.scan_layers:
         params["layers"] = stack_layer_params(params["layers"])
+    if cfg.ut_steps > 1:
+        # the exit gate: one linear map with a bias on the normed stream
+        params["exit_gate"] = {
+            "w": dense(next(keys), (d,), d), "b": jnp.zeros((1,), pd),
+        }
     return params
 
 
@@ -406,6 +411,8 @@ def logical_axes(cfg: TransformerConfig) -> Params:
             is_leaf=lambda x: isinstance(x, tuple)
             and all(a is None or isinstance(a, str) for a in x),
         )
+    if cfg.ut_steps > 1:
+        axes["exit_gate"] = {"w": ("norm",), "b": (None,)}
     return axes
 
 
@@ -583,6 +590,14 @@ def check_window_mesh(cfg: TransformerConfig, mesh):
     would have to pass from shard to shard, and differential attention."""
     if mesh is None or mesh.shape.get("sp", 1) <= 1:
         return
+    if cfg.ut_steps > 1:
+        raise NotImplementedError(
+            f"a looped model (ut_steps {cfg.ut_steps}) calls every "
+            f"attention layer {cfg.ut_steps} times a step over the same "
+            f"weights: under sp = {mesh.shape['sp']} {cfg.sp_scheme} "
+            "attention's exchange and its by-hand backward rule are not "
+            "shown to hold there"
+        )
     if cfg.attn_window:
         raise NotImplementedError(
             f"the window layers (attn_window {cfg.attn_window}) know no "
@@ -904,6 +919,11 @@ def _zero_aux(cfg: Optional[TransformerConfig] = None):
             aux["layer_load"] = jnp.zeros(
                 (num_moe_layers(cfg), cfg.num_experts), jnp.float32
             )
+    if cfg is not None and cfg.ut_steps > 1:
+        # what ``loss_fn`` says of a looped model's exits (``ut_exits``)
+        aux["ut_entropy"] = jnp.float32(0.0)
+        aux["ut_exit_step"] = jnp.float32(0.0)
+        aux["ut_exit_nll"] = jnp.zeros((cfg.ut_steps,), jnp.float32)
     return aux
 
 
@@ -1022,8 +1042,12 @@ def _final_norm(params: Params, x: jnp.ndarray, cfg: TransformerConfig):
 
 def lm_head(params: Params, x: jnp.ndarray, cfg: TransformerConfig):
     """final residual [B,T,D] → logits [B,T,vocab] fp32 (incl. final norm)."""
+    return _head_logits(params, _final_norm(params, x, cfg), cfg)
+
+
+def _head_logits(params: Params, x: jnp.ndarray, cfg: TransformerConfig):
+    """normed stream [B,T,D] → logits [B,T,vocab] fp32."""
     dt = _dtype(cfg)
-    x = _final_norm(params, x, cfg)
     with jax.named_scope("scope/lm_head"):
         if cfg.tie_embeddings:
             w = params["embed"]["tokens"].astype(dt)
@@ -1038,6 +1062,13 @@ def lm_head(params: Params, x: jnp.ndarray, cfg: TransformerConfig):
     return logits
 
 
+def _nll_each(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
+    """[B,T,V], [B,T] → each token's negative log-likelihood [B,T]."""
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return lse - tgt
+
+
 @jax.named_scope("scope/xent")
 def token_nll(
     logits: jnp.ndarray, targets: jnp.ndarray, row_weights=None
@@ -1049,9 +1080,7 @@ def token_nll(
     ``log_softmax``: the log_softmax form materializes a second
     [B, T, vocab] fp32 tensor for the backward (3.3 GB of avoidable HBM
     traffic a step of the 124M model at batch 32)."""
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = lse - tgt
+    nll = _nll_each(logits, targets)
     if row_weights is not None:
         # weighted mean over rows (micro-batch rebalance: padded rows
         # carry weight 0, real rows batch_padded/batch_real — see
@@ -1097,6 +1126,86 @@ def recomputed(layer_fn):
     return jax.checkpoint(traced, policy=_KEEP_BY_NAME)
 
 
+def ut_passes(one_pass, x, steps: int):
+    """The stream ``x`` through ``one_pass`` ``steps`` times, the output
+    of each pass into the next: every pass's output, stacked [steps, ...].
+    A Python loop, so the step holds ``steps`` copies of the pass (each
+    layer under its own ``recomputed`` wrapper, each site traced and
+    counted for itself) and not a ``lax.scan`` over one: at the Ouro
+    cell's size the compiler gives the scan's stacked residuals a second
+    home (the donating step 20.2 GiB against 12.7, ``benchmark/tests/
+    aot_sizes.py``) for a compile of 48 s against 86."""
+    passes = []
+    for _ in range(steps):
+        x = one_pass(x)
+        passes.append(x)
+    return jnp.stack(passes)
+
+
+def ut_stopping(g):
+    """The exit gate's logits ``g`` [R, ...] → ``log p`` [R, ...] of the
+    stopping distribution over the R passes: ``p_t = sigmoid(g_t)
+    prod_{j<t} (1 - sigmoid(g_j))`` for ``t < R`` and ``p_R`` the rest
+    (``g_R`` is unused), every factor's log by ``log_sigmoid``."""
+    ahead = jnp.zeros_like(g[0])  # log prod_{j<t} (1 - lambda_j)
+    log_p = []
+    for t in range(g.shape[0] - 1):
+        log_p.append(jax.nn.log_sigmoid(g[t]) + ahead)
+        ahead = ahead + jax.nn.log_sigmoid(-g[t])
+    return jnp.stack(log_p + [ahead])
+
+
+def ut_exits(params: Params, passes, targets, cfg: TransformerConfig,
+             row_weights=None):
+    """The loss of a looped model from every pass's normed stream
+    ``passes`` [R,B,T,D], and what it reports of the exits.
+
+    Each pass exits through the one head: ``nll_t`` a token's negative
+    log-likelihood at pass ``t``, ``g_t = h_t . w + b`` the exit gate's
+    logit, ``lambda_t = sigmoid(g_t)``. A token's stopping distribution
+    is ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for ``t < R`` and
+    ``p_R = prod_{j<R} (1 - lambda_j)`` (``lambda_R`` is unused), from
+    ``log_sigmoid`` and never from the log of a product. The loss is
+    ``mean(sum_t p_t nll_t - ut_entropy_weight * H(p))``, ``row_weights``
+    weighing a row's bracket as they weigh its NLL in ``token_nll``.
+
+    One exit is a unit the backward pass makes again from ``h_t``
+    (``jax.checkpoint``), and the exits run one after the other in a
+    ``lax.map``: the step holds one exit's [B,T,V] logits (and their
+    cotangent) at a time, and keeps [R,B,T] of them."""
+    steps = passes.shape[0]
+    gate = params["exit_gate"]
+
+    @jax.checkpoint
+    def one_exit(h):
+        logits = _head_logits(params, h, cfg)
+        with jax.named_scope("scope/xent"):
+            # float32 and no matmul: a product on the MXU would round h
+            # and w to bfloat16 at the default precision
+            g = jnp.sum(
+                h.astype(jnp.float32) * gate["w"].astype(jnp.float32), -1
+            ) + gate["b"].astype(jnp.float32)
+            return _nll_each(logits, targets), g
+
+    trace_counts.count("ut_exit_heads", steps)
+    with jax.named_scope("scope/lm_head"):  # the loop itself
+        nll, g = lax.map(one_exit, passes)
+    with jax.named_scope("scope/xent"):
+        log_p = ut_stopping(g)
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, 0)  # p = 0 at a finite log p: 0
+        each = jnp.sum(p * nll, 0) - cfg.ut_entropy_weight * entropy
+        if row_weights is not None:
+            each = row_weights[:, None].astype(each.dtype) * each
+        at = jnp.arange(1, steps + 1, dtype=jnp.float32)[:, None, None]
+        said = {
+            "ut_entropy": jnp.mean(entropy),
+            "ut_exit_step": jnp.mean(jnp.sum(at * p, 0)),
+            "ut_exit_nll": jnp.mean(nll, (1, 2)),
+        }
+        return jnp.mean(each), said
+
+
 def forward(
     params: Params,
     tokens: jnp.ndarray,
@@ -1104,10 +1213,18 @@ def forward(
     mesh=None,
     return_hidden: bool = False,
     moe_axis=None,
+    return_passes: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """tokens [B,T] int32 → (logits [B,T,vocab] fp32, moe aux dict
     {"balance": load-balance loss, "z": router z-loss} — zeros for dense
     models).
+
+    A looped model (``cfg.ut_steps`` > 1) runs the pattern's walk that
+    many times over the same ``params["layers"]``, the final norm after
+    every pass and the normed stream into the next; logits and
+    ``return_hidden`` are the last pass's, and ``return_passes=True``
+    returns every pass's normed stream [ut_steps,B,T,D] (what the exits
+    of ``loss_fn`` read).
 
     ``return_hidden=True`` returns the final-norm'd residual stream
     [B,T,D] instead of logits and skips the vocab projection entirely —
@@ -1154,7 +1271,10 @@ def forward(
 
     if cfg.remat and not cfg.layer_pattern:
         block = recomputed(block)
-    if cfg.layer_pattern:
+
+    def pattern_walk(x, aux_total):
+        """The stream through every layer of the ``layer_pattern`` once:
+        ``(x, aux_total and the layers' aux)``."""
         loads = []
         # what the last layer of each kind handed on (``LAYER_READS``: a
         # "U" reads the last "S", a "C" the last "*"), carried beside x
@@ -1183,6 +1303,26 @@ def forward(
                 )
         if loads:
             aux_total["layer_load"] = jnp.stack(loads)
+        return x, aux_total
+
+    if cfg.ut_steps > 1:
+        # no experts in a looped model (``TransformerConfig``): the walk's
+        # aux is zeros, and the loss's terms of the exits are its own
+        def one_pass(stream):
+            trace_counts.count("ut_steps")
+            trace_counts.count("ut_layer_passes", len(cfg.layer_pattern))
+            return _final_norm(
+                params, pattern_walk(stream, aux_total)[0], cfg
+            )
+
+        passes = ut_passes(one_pass, x, cfg.ut_steps)
+        if return_passes:
+            return passes, aux_total
+        if return_hidden:
+            return passes[-1], aux_total
+        return _head_logits(params, passes[-1], cfg), aux_total
+    if cfg.layer_pattern:
+        x, aux_total = pattern_walk(x, aux_total)
     elif cfg.scan_layers:
         # one scanned block: the traced/compiled graph is O(1) in depth
         # — 48-layer remat compiles where the unrolled graph cannot
@@ -1216,8 +1356,13 @@ def loss_fn(
     row_weights=None,
 ):
     """Mean NLL + weighted MoE aux losses (load balance at
-    ``moe_aux_weight``, router z at ``cfg.router_z_weight``).
+    ``moe_aux_weight``, router z at ``cfg.router_z_weight``); of a looped
+    model (``cfg.ut_steps`` > 1) the exits' loss (``ut_exits``).
     ``return_aux=True`` → (loss, aux dict) for metric surfacing."""
+    if cfg.ut_steps > 1:
+        passes, aux = forward(params, tokens, cfg, mesh, return_passes=True)
+        loss, said = ut_exits(params, passes, targets, cfg, row_weights)
+        return (loss, dict(aux, **said)) if return_aux else loss
     logits, aux = forward(params, tokens, cfg, mesh, moe_axis=moe_axis)
     if cfg.router_balance_weight is not None:
         moe_aux_weight = cfg.router_balance_weight
@@ -1234,9 +1379,22 @@ def loss_fn(
 # ---------------------------------------------------------------------------
 # cached autoregressive decoding (generation / RLHF rollouts)
 # ---------------------------------------------------------------------------
+def _refuse_cached_loop(cfg: TransformerConfig):
+    if cfg.ut_steps > 1:
+        raise NotImplementedError(
+            f"cached decoding knows one visit of a layer a token: a "
+            f"looped model (ut_steps {cfg.ut_steps}) keeps one set of "
+            f"keys and values a layer AND pass ({cfg.ut_steps} x "
+            f"{cfg.num_layers} layers of cache) and leaves the loop where "
+            "a token's accumulated stopping probability passes a "
+            "threshold, which no cache or decode step here does"
+        )
+
+
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int):
     """Per-layer K/V buffers [L, B, S, kv_heads, head_dim]. Static shape:
     the whole decode loop stays inside one compiled ``lax.scan``."""
+    _refuse_cached_loop(cfg)
     if set(cfg.layer_pattern) & set("SUC"):
         raise NotImplementedError(
             f"cached decoding knows no layer_pattern {cfg.layer_pattern!r}: "
@@ -1357,6 +1515,7 @@ def forward_step(
     math as ``forward`` — attention just reads K/V from the cache buffer
     instead of recomputing them, the standard decode memory/FLOPs trade.
     """
+    _refuse_cached_loop(cfg)
     dt = _dtype(cfg)
     B, t = tokens.shape
     S = cache["k"].shape[2]
@@ -1405,6 +1564,7 @@ def forward_step_ragged(
     entries from a slot's PREVIOUS occupant need no clearing: position
     ``i`` is rewritten before any later query can attend to it.
     """
+    _refuse_cached_loop(cfg)
     dt = _dtype(cfg)
     S_slots = tokens.shape[0]
     T = cache["k"].shape[2]

@@ -119,6 +119,14 @@ def _check_pipeline_cfg(
             f"or keys and values ({sorted(LAYER_READS)}) could lie a stage "
             "above the layer they read, and nothing carries it there"
         )
+    if cfg.ut_steps > 1:
+        raise ValueError(
+            f"pipeline parallelism sends a microbatch through the stages "
+            f"once: a looped model (ut_steps {cfg.ut_steps}) would send "
+            f"the stream through every stage {cfg.ut_steps} times over "
+            "the stage's own weights, with the final norm and an exit "
+            "between visits, and the schedule knows one visit"
+        )
     if cfg.attn_window:
         raise ValueError(
             f"pipeline parallelism stacks all-alike attention + FFN "

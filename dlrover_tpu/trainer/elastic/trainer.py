@@ -37,7 +37,7 @@ from dlrover_tpu.common import faults, trace_counts
 from dlrover_tpu.ckpt.checkpointer import FlashCheckpointer, StorageType
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.config import TransformerConfig
-from dlrover_tpu.models.train import shard_batch
+from dlrover_tpu.models.train import fold_exit_report, shard_batch
 from dlrover_tpu.obs.flight_recorder import (
     ProfilerCapture,
     default_recorder,
@@ -2087,6 +2087,8 @@ class ElasticTrainer:
             fold_routing_report(
                 metrics, self.pipeline_stats, self.cfg.held_experts
             )
+            # of a looped model, its exits
+            exits = fold_exit_report(metrics, self.pipeline_stats)
         with span("report"):
             scalars = {"loss": loss}
             lr = self._lr_value(lr_parts)
@@ -2099,7 +2101,8 @@ class ElasticTrainer:
             rate = (step - start_step) / max(time.time() - t0, 1e-9)
             lr_s = f" lr={lr:.2e}" if lr is not None else ""
             logger.info(
-                f"step {step}: loss={loss:.4f}{lr_s} ({rate:.2f} it/s)"
+                f"step {step}: loss={loss:.4f}{lr_s}{exits} "
+                f"({rate:.2f} it/s)"
             )
 
     def _train_loop(self, num_steps: int, t0, start_step) -> Any:

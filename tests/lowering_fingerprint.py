@@ -57,7 +57,12 @@ cut back to the name (``inner_numbers_off``) the text with the names is
 the text without them, which ``test_nemotron_h.py`` holds it to. The Ling
 step (``remat``) is another program with the names: its recomputed KDA
 layers keep the named arrays and hold each forward kernel and the pass
-once. The other six entries are as they were.
+once. The other six entries are as they were. ISSUE 57 brought
+``ouro-2.6b-d6`` (a looped model: ``forward`` runs a pattern's walk
+``ut_steps`` times as a ``lax.scan`` over the same leaves, and ``loss_fn``
+takes an exit through the head after every pass) and left the eight entries
+before it as they were: ``ut_steps`` 1 is the walk once, no gate leaf, no
+exit arithmetic.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
@@ -79,6 +84,7 @@ NAMES = (
     "gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2",
     "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
     "ling-3.0-flash-d7", "trinity-mini-d5", "phi4-mini-flash-d6",
+    "ouro-2.6b-d6",
 )
 
 

@@ -140,7 +140,8 @@ def test_rebuild_drops_the_executable(accel):
 # keeps what its backward pass reads (PR 52), the selective scans' three,
 # the differential pairs' two and the cross-decoder's two reads (PR 53),
 # the edge blocks' two tile counts (PR 54), the delta-rule mixers whose
-# pass a recomputed layer keeps (PR 56); how the counted ones are
+# pass a recomputed layer keeps (PR 56), a looped model's three counts and
+# what is folded of its exits (PR 57); how the counted ones are
 # folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
     "attn_diff_pairs", "attn_diff_score_calls",
@@ -177,7 +178,9 @@ AS_DICT_KEYS = [
     "stage_backlog_bytes", "stage_block_s", "stage_bytes", "stage_chunks",
     "stage_commits", "startup_backend_s", "startup_cache_misses",
     "startup_compile_s", "startup_first_step_s", "startup_import_s",
-    "steps_ahead", "xdec_kv_reads", "xdec_memory_reads",
+    "steps_ahead", "ut_entropy_sum", "ut_exit_heads", "ut_exit_step_sum",
+    "ut_layer_passes", "ut_reports", "ut_steps",
+    "xdec_kv_reads", "xdec_memory_reads",
 ]
 # a float is reported to the places it had when each key was written out
 ROUNDED = {
@@ -192,6 +195,7 @@ ROUNDED = {
     "startup_first_step_s": 4, "startup_compile_s": 4,
     "recover_detect_tick_s": 4, "recover_persist_s": 4,
     "recover_respawn_s": 4, "begin_lock_s": 4,
+    "ut_entropy_sum": 6, "ut_exit_step_sum": 6,
 }
 
 

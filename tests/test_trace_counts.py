@@ -28,7 +28,7 @@ from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
     CONV, DIFF, EDGE, FUSED, GDN, GDN_KEPT, KEPT, LANES, SHARE, SSCAN,
-    STREAM, WINDOW, XDEC,
+    STREAM, UT, WINDOW, XDEC,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -473,6 +473,17 @@ TOYS = {
     "scans_and_differential_attention_remat": (
         TransformerConfig(sscan_inner=128, remat=True, **_PHI),
         (SSCAN, CONV, DIFF, XDEC, STREAM, WINDOW, EDGE, LANES, KEPT),
+    ),
+    # a looped model, recomputed: two sandwich-normed blocks run three
+    # times over the same weights, an exit after every pass
+    "layers_run_several_times_remat": (
+        TransformerConfig(
+            num_layers=4, layer_pattern="*-*-", mixer_out_norm=True,
+            rmsnorm=True, rope=True, swiglu=True, dense_mlp_dim=32,
+            tie_embeddings=False, ut_steps=3, ut_entropy_weight=0.05,
+            remat=True, **_SMALL,
+        ),
+        (FUSED, LANES, KEPT, UT),
     ),
 }
 

@@ -254,10 +254,15 @@ class PipelineStats:
     # ``forward`` (``ut_passes``): every pass's layers are traced and
     # counted for themselves, so these and the attention kernels' site,
     # tile and kept counters above are of a whole step, L x ``ut_steps``
-    # sites. 0 / 0 / 0 for a model that runs its layers once
+    # sites. ``ut_exit_fused_heads``: the exits whose gradients the
+    # forward rule of ``exits_nll`` makes beside their losses (counted
+    # where that rule is traced: every exit of a step that is
+    # differentiated, none of a program that only evaluates). 0 / 0 / 0 /
+    # 0 for a model that runs its layers once
     ut_steps: int = 0
     ut_layer_passes: int = 0
     ut_exit_heads: int = 0
+    ut_exit_fused_heads: int = 0
     # ... and its exits in the steps reported at the log cadence (as the
     # routers' above): how many reports, their summed mean entropy of a
     # token's stopping distribution over the passes (nats; ln ut_steps =

@@ -1090,6 +1090,118 @@ def token_nll(
     return jnp.mean(nll)
 
 
+# -- a looped model's exits: the head and its loss, one rule -----------------
+
+
+def _exit_nll(h, w, targets, cfg: TransformerConfig):
+    """One exit, from its normed stream ``h`` [B,T,D] and the head ``w`` in
+    the activation dtype as its leaf holds it (the table [V,D] where the
+    embeddings are tied, else [D,V]): ``(z, lse, nll)``, each token's
+    negative log-likelihood [B,T] beside the float32 logits
+    (``_head_logits``'s arithmetic) and their logsumexp it came from."""
+    with jax.named_scope("scope/lm_head"):
+        spec = "btd,vd->btv" if cfg.tie_embeddings else "btd,dv->btv"
+        z = jnp.einsum(spec, h, w).astype(jnp.float32)
+        if cfg.mup_output_mult != 1.0:
+            z = z * cfg.mup_output_mult
+    with jax.named_scope("scope/xent"):
+        lse = jax.scipy.special.logsumexp(z, axis=-1)
+        tgt = jnp.take_along_axis(z, targets[..., None], axis=-1)[..., 0]
+        return z, lse, lse - tgt
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _exits_nll(cfg: TransformerConfig, passes, w_head, targets, a):
+    """``exits_nll`` where no gradient is asked: one exit at a time."""
+    dt = _dtype(cfg)
+    w = w_head.astype(dt)
+    with jax.named_scope("scope/lm_head"):  # the loop itself
+        nll = lax.map(lambda h: _exit_nll(h, w, targets, cfg)[2], passes)
+    with jax.named_scope("scope/xent"):
+        return jnp.sum(a * nll), nll
+
+
+def _exits_nll_fwd(cfg: TransformerConfig, passes, w_head, targets, a):
+    """The forward rule makes the gradients: an exit's logits are there
+    once, so ``U = softmax(z) - onehot`` is made from them in the
+    activation dtype beside the logsumexp and both gradient products read
+    it. An exit's weight ``a_t`` rides on the [B,T,D] side of each
+    product, in float32 after the one and before the rounding of ``h`` in
+    the other, never on [B,T,V]. Kept for the backward rule: ``dh``
+    [R,B,T,D], ``dW`` (float32, the leaf's layout) and ``nll``.
+
+    The exits are a ``lax.scan`` that carries ``dW``, and not R unrolled
+    bodies: at the Ouro cell's size the unrolled form compiles to less
+    (the donating step 12.15 GiB against 12.40, ``benchmark/tests/
+    aot_sizes.py``) and yet held more on the chip (13.67 GB at the peak
+    against 13.20) for a head 3.7 ms a step slower."""
+    dt = _dtype(cfg)
+    trace_counts.count("ut_exit_fused_heads", passes.shape[0])
+    w = w_head.astype(dt)
+    to_h, to_w = (
+        ("btv,vd->btd", "btv,btd->vd") if cfg.tie_embeddings
+        else ("btv,dv->btd", "btd,btv->dv")
+    )
+
+    def one_exit(dw, exit_):
+        h, a_t = exit_
+        z, lse, nll = _exit_nll(h, w, targets, cfg)
+        with jax.named_scope("scope/lm_head"):
+            hit = lax.broadcasted_iota(jnp.int32, z.shape, 2) == (
+                targets[..., None]
+            )
+            soft = jnp.exp(z - lse[..., None])
+            u = jnp.where(hit, soft - 1.0, soft).astype(dt)
+            scale = a_t[..., None] * cfg.mup_output_mult
+            dh = scale * jnp.einsum(
+                to_h, u, w, preferred_element_type=jnp.float32
+            )
+            weighed = (scale * h.astype(jnp.float32)).astype(dt)
+            operands = (u, weighed) if cfg.tie_embeddings else (weighed, u)
+            dw = dw + jnp.einsum(
+                to_w, *operands, preferred_element_type=jnp.float32
+            )
+        return dw, (dh.astype(h.dtype), nll)
+
+    with jax.named_scope("scope/lm_head"):  # the loop itself
+        dw, (dh, nll) = lax.scan(
+            one_exit, jnp.zeros(w_head.shape, jnp.float32), (passes, a)
+        )
+    with jax.named_scope("scope/xent"):
+        total = jnp.sum(a * nll)
+    return (total, nll), (dh, dw.astype(w_head.dtype), nll)
+
+
+def _exits_nll_bwd(cfg: TransformerConfig, kept, cotangents):
+    dh, dw, nll = kept
+    c, _ = cotangents  # ``exits_nll`` stops the gradient of the second
+    with jax.named_scope("scope/lm_head"):
+        return (c.astype(dh.dtype) * dh, c.astype(dw.dtype) * dw, None,
+                c * nll)
+
+
+_exits_nll.defvjp(_exits_nll_fwd, _exits_nll_bwd)
+
+
+def exits_nll(passes, w_head, targets, a, cfg: TransformerConfig):
+    """The exits of a looped model through the one head, as one function
+    with its own backward rule: every pass's normed stream ``passes``
+    [R,B,T,D], the head's leaf ``w_head`` as it is held (float32; the
+    table [V,D] where the embeddings are tied), ``targets`` [B,T] and each
+    token's weight at each exit ``a`` [R,B,T] →
+    ``(sum(a * nll), nll [R,B,T])``, the second under ``stop_gradient``
+    (a report: the gradient to ``a`` is the first's).
+
+    ``d nll / d z = softmax(z) - onehot`` does not depend on the
+    cotangent, so the forward rule makes it where the logits are
+    (``_exits_nll_fwd``): no exit's logits are made a second time, and no
+    float32 [B,T,V] cotangent is written, cast or copied. The step holds
+    one exit's [B,T,V] at a time (a ``lax.scan`` that carries ``dW``) and
+    keeps nothing of the vocabulary's width but ``dW`` itself."""
+    total, nll = _exits_nll(cfg, passes, w_head, targets, a)
+    return total, lax.stop_gradient(nll)
+
+
 # ONE policy object: ``jax`` caches a jaxpr's split into what is kept and
 # what is made again by the policy's identity, and a policy a wrapper would
 # split every layer's inner functions anew (twice the functions in the
@@ -1169,41 +1281,41 @@ def ut_exits(params: Params, passes, targets, cfg: TransformerConfig,
     ``mean(sum_t p_t nll_t - ut_entropy_weight * H(p))``, ``row_weights``
     weighing a row's bracket as they weigh its NLL in ``token_nll``.
 
-    One exit is a unit the backward pass makes again from ``h_t``
-    (``jax.checkpoint``), and the exits run one after the other in a
-    ``lax.map``: the step holds one exit's [B,T,V] logits (and their
-    cotangent) at a time, and keeps [R,B,T] of them."""
-    steps = passes.shape[0]
+    The stopping distribution comes first, from all R gate logits (float32
+    products and sums, no matmul): a token's weight at exit ``t`` is then
+    known, ``a_t = p_t * row_weight / (B T)``, and the exits go through the
+    head in ``exits_nll``, one at a time, whose forward rule makes their
+    gradients beside their losses. The gradient to the gate flows through
+    ``a`` (its cotangent is ``nll``) and through the entropy term."""
+    steps, rows, length = passes.shape[:3]
     gate = params["exit_gate"]
-
-    @jax.checkpoint
-    def one_exit(h):
-        logits = _head_logits(params, h, cfg)
-        with jax.named_scope("scope/xent"):
-            # float32 and no matmul: a product on the MXU would round h
-            # and w to bfloat16 at the default precision
-            g = jnp.sum(
-                h.astype(jnp.float32) * gate["w"].astype(jnp.float32), -1
-            ) + gate["b"].astype(jnp.float32)
-            return _nll_each(logits, targets), g
-
-    trace_counts.count("ut_exit_heads", steps)
-    with jax.named_scope("scope/lm_head"):  # the loop itself
-        nll, g = lax.map(one_exit, passes)
     with jax.named_scope("scope/xent"):
+        # float32 and no matmul: a product on the MXU would round h and w
+        # to bfloat16 at the default precision
+        g = jnp.sum(
+            passes.astype(jnp.float32) * gate["w"].astype(jnp.float32), -1
+        ) + gate["b"].astype(jnp.float32)
         log_p = ut_stopping(g)
         p = jnp.exp(log_p)
         entropy = -jnp.sum(p * log_p, 0)  # p = 0 at a finite log p: 0
-        each = jnp.sum(p * nll, 0) - cfg.ut_entropy_weight * entropy
-        if row_weights is not None:
-            each = row_weights[:, None].astype(each.dtype) * each
+        weights = 1.0 if row_weights is None else (
+            row_weights[:, None].astype(jnp.float32)
+        )
+        a = p * (weights / (rows * length))
+        entropy_term = jnp.mean(weights * entropy)
+    trace_counts.count("ut_exit_heads", steps)
+    w_head = (
+        params["embed"]["tokens"] if cfg.tie_embeddings else params["lm_head"]
+    )
+    weighed, nll = exits_nll(passes, w_head, targets, a, cfg)
+    with jax.named_scope("scope/xent"):
         at = jnp.arange(1, steps + 1, dtype=jnp.float32)[:, None, None]
         said = {
             "ut_entropy": jnp.mean(entropy),
             "ut_exit_step": jnp.mean(jnp.sum(at * p, 0)),
             "ut_exit_nll": jnp.mean(nll, (1, 2)),
         }
-        return jnp.mean(each), said
+        return weighed - cfg.ut_entropy_weight * entropy_term, said
 
 
 def forward(

@@ -62,7 +62,13 @@ once. The other six entries are as they were. ISSUE 57 brought
 ``ut_steps`` times as a ``lax.scan`` over the same leaves, and ``loss_fn``
 takes an exit through the head after every pass) and left the eight entries
 before it as they were: ``ut_steps`` 1 is the walk once, no gate leaf, no
-exit arithmetic.
+exit arithmetic. ISSUE 58 recorded the ``ouro-2.6b-d6`` step anew, its tree
+as it was: the exits' head and loss are one function with its own backward
+rule (``models/transformer.exits_nll``: the stopping distribution first,
+then a ``lax.scan`` over the exits that makes softmax minus one-hot beside
+each loss and both gradient products from it, where a ``lax.map`` of
+``jax.checkpoint``ed exits stood). The other eight entries, whose models
+run their layers once and never meet ``ut_exits``, are as they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py

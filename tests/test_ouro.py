@@ -5,12 +5,15 @@ the CPU: the program held to ``benchmark/references/ouro.py`` (loss and
 every gradient leaf); a shared leaf's gradient the sum over the passes;
 the stopping distribution and its two saturated limits; ``ut_steps = 1``
 the plain model; ``remat``; the unrolled loop against a scan; a lower
-precision refused; the counters of a whole step; the step's one logits
-array; and what refuses a looped model."""
+precision refused; the exits' own backward rule (``exits_nll``) against
+``jax.grad`` of the plain form; the counters of a whole step; the step's
+one logits array and its three products an exit; and what refuses a looped
+model."""
 
 import importlib
 import importlib.util
 import os
+import re
 from dataclasses import replace
 
 import jax
@@ -238,6 +241,86 @@ def test_row_weights_weigh_a_rows_bracket():
     assert abs(padded - real) <= RTOL * real
 
 
+# -- the exits' own backward rule ---------------------------------------------
+
+
+def _plain_exits(passes, w_head, targets, a, cfg):
+    """``sum(a * nll)`` through ``_head_logits`` and ``_nll_each``, for
+    ``jax.grad`` to differentiate as it finds it."""
+    params = (
+        {"embed": {"tokens": w_head}} if cfg.tie_embeddings
+        else {"lm_head": w_head}
+    )
+    nll = jnp.stack([
+        transformer._nll_each(transformer._head_logits(params, h, cfg),
+                              targets)
+        for h in passes
+    ])
+    return jnp.sum(a * nll), nll
+
+
+@pytest.mark.parametrize("weighed", [False, True], ids=["even", "rows"])
+@pytest.mark.parametrize("mult", [1.0, 0.5])
+@pytest.mark.parametrize("tied", [False, True], ids=["untied", "tied"])
+def test_the_exits_rule_matches_the_plain_forms_gradients(
+    tied, mult, weighed
+):
+    """``exits_nll``'s value, its reported NLLs and its gradients to the
+    passes, the head (the table [V, D] where tied) and the weights ``a``
+    are ``jax.grad``'s of the plain form to float32 rounding, under a
+    cotangent that is not 1 and with ``a`` read a second time beside it."""
+    cfg = _cfg(tie_embeddings=tied, mup_output_mult=mult)
+    rows, width, vocab = 3, cfg.model_dim, cfg.vocab_size
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    passes = jax.random.normal(keys[0], (PASSES, rows, T, width))
+    shape = (vocab, width) if tied else (width, vocab)
+    w_head = 0.2 * jax.random.normal(keys[1], shape)
+    targets = jax.random.randint(keys[2], (rows, T), 0, vocab)
+    a = jax.nn.softmax(jax.random.normal(keys[3], (PASSES, rows, T)), 0)
+    if weighed:
+        a = a * jnp.asarray([1.5, 1.5, 0.0])[:, None]
+    a = a / (rows * T)
+
+    def scaled(exits):
+        def fn(passes, w_head, a):
+            total, nll = exits(passes, w_head, targets, a, cfg)
+            return 3.0 * total + jnp.sum(a * a), nll
+        return jax.jit(jax.value_and_grad(fn, (0, 1, 2), has_aux=True))
+
+    (got, nll_got), g_got = scaled(transformer.exits_nll)(passes, w_head, a)
+    (want, nll_want), g_want = scaled(_plain_exits)(passes, w_head, a)
+    assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
+    assert _rel(nll_got, nll_want) <= 1e-6
+    for name, x, y in zip(("passes", "head", "a"), g_got, g_want):
+        assert x.shape == y.shape and x.dtype == y.dtype, name
+        assert _rel(x, y) <= 2e-6, name
+    # no gradient asked: the primal, one exit at a time
+    total, nll = jax.jit(
+        lambda *args: transformer.exits_nll(*args, cfg)
+    )(passes, w_head, targets, a)
+    assert abs(3.0 * float(total) + float(jnp.sum(a * a)) - float(want)) <= (
+        1e-6 * abs(float(want))
+    )
+    assert _rel(nll, nll_want) <= 1e-6
+
+
+def test_the_reported_nll_carries_no_gradient():
+    """The second output is a report: a loss that reads it alone has no
+    gradient, and the rule's backward pass is given none for it."""
+    cfg = _cfg()
+    passes = jax.random.normal(jax.random.PRNGKey(1), (PASSES, 1, T, 64))
+    w_head = 0.2 * jax.random.normal(jax.random.PRNGKey(2), (64, 256))
+    targets = jnp.zeros((1, T), jnp.int32)
+    a = jnp.full((PASSES, 1, T), 0.25 / T)
+    grads = jax.grad(
+        lambda h, w, a: jnp.sum(
+            transformer.exits_nll(h, w, targets, a, cfg)[1]
+        ), (0, 1, 2),
+    )(passes, w_head, a)
+    for g in grads:
+        assert float(jnp.max(jnp.abs(g))) == 0.0
+
+
 # -- one pass is the plain model ---------------------------------------------
 
 
@@ -373,32 +456,86 @@ def test_the_counters_are_of_a_whole_step(kernels):
     before = trace_counts.snapshot()
     _lower_step(plain)
     once = added(before, names)
-    assert added(before, UT) == (0, 0, 0)
+    assert added(before, UT) == (0, 0, 0, 0)
     before = trace_counts.snapshot()
     _lower_step(looped)
     assert added(before, names) == tuple(PASSES * n for n in once)
     assert added(before, KEPT) == (BLOCKS * PASSES,)
     assert once[0] > 0 and once[names.index("attn_edge_tiles")] > 0
-    # passes, one-mixer layers summed over the passes, exits
-    assert added(before, UT) == (PASSES, 2 * BLOCKS * PASSES, PASSES)
+    # passes, one-mixer layers summed over the passes, exits, and the exits
+    # whose gradients the head's forward rule makes: a step's, every one
+    assert added(before, UT) == (
+        PASSES, 2 * BLOCKS * PASSES, PASSES, PASSES
+    )
+    # a program that only evaluates traces no forward rule
+    before = trace_counts.snapshot()
+    x = jax.ShapeDtypeStruct((1, looped.max_seq_len), jnp.int32)
+    jax.jit(lambda p, x: loss_fn(p, x, x, looped, None)).lower(
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), looped)), x
+    )
+    assert added(before, UT)[2:] == (PASSES, 0)
     fields = set(PipelineStats.__dataclass_fields__)
     folded = {"ut_reports", "ut_entropy_sum", "ut_exit_step_sum"}
     assert set(UT) | folded <= fields
 
 
-def test_the_step_holds_one_exits_logits_at_a_time():
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_step_holds_one_exits_logits_at_a_time(dtype):
     """A vocabulary wide enough that an exit's [B, T, V] float32 logits
     outweigh everything else the toy step holds: the compiled step's
-    temporaries stay under three such arrays (one exit's logits, their
-    cotangent and a copy), where four exits held together with their
-    cotangents would need eight."""
+    temporaries stay under 2.9 such arrays (it reads 2.65 in either dtype
+    here: one exit's logits, the softmax and softmax minus one-hot; the
+    checkpointed map this form replaced read 2.64 in float32 and 3.62 in
+    bfloat16, its logits, their float32 cotangent, its cast and a copy),
+    where four exits held together with their cotangents would need
+    eight. No array of any dtype holds all four exits' [B, T, V]."""
     cfg = _cfg(vocab_size=16384, model_dim=32, num_heads=2, num_kv_heads=2,
-               dense_mlp_dim=32, remat=True)
+               dense_mlp_dim=32, remat=True, dtype=dtype)
     logits = 2 * T * cfg.vocab_size * 4
     text = _lower_step(cfg, rows=2)
-    assert f"tensor<{PASSES}x2x{T}x{cfg.vocab_size}xf32>" not in text.as_text()
+    assert not re.search(
+        rf"tensor<{PASSES}x2x{T}x{cfg.vocab_size}x\w+>", text.as_text()
+    )
     temp = text.compile().memory_analysis().temp_size_in_bytes
-    assert logits < temp < 3 * logits, (temp, logits)
+    assert logits < temp < 2.9 * logits, (temp, logits)
+
+
+def test_an_exit_is_three_products_and_one_rounding():
+    """The lowered step of a bfloat16 toy: the exits' loop body, which
+    runs ``ut_steps`` times, holds the three products of the vocabulary's
+    width (the logits, and the two gradient products: R + 2R a step, where
+    the checkpointed map made the logits again for 4R), all with bfloat16
+    operands. The one [B, T, V] float32 array a ``convert`` reads is
+    softmax minus one-hot on its way to bfloat16, and both gradient
+    products read what it writes: no float32 cotangent of the logits is
+    cast. The only other float32 operand of that width is the head's leaf
+    itself, cast once."""
+    vocab, rows = 1000, 2
+    cfg = _cfg(vocab_size=vocab, remat=True, dtype="bfloat16")
+    lines = _lower_step(cfg, rows=rows).as_text().splitlines()
+    wide = re.compile(rf"x{vocab}x(bf16|f32)>")
+    products = [
+        l for l in lines if "stablehlo.dot_general" in l and wide.search(l)
+    ]
+    assert len(products) == 3, products
+    logits = f"tensor<{rows}x{T}x{vocab}x"
+    (forward,) = [l for l in products if f"-> {logits}bf16>" in l]
+    backward = [l for l in products if l is not forward]
+    casts = [
+        l for l in lines
+        if "stablehlo.convert" in l and f": ({logits}f32>)" in l
+    ]
+    assert len(casts) == 1, casts
+    rounded = casts[0].split("=")[0].strip()
+    assert f"-> {logits}bf16>" in casts[0]
+    for product in backward:
+        assert re.search(rf"{rounded}\b", product.split(":")[0]), product
+        assert "f32>, " not in product.split(" : ")[1]
+    leaf = [
+        l for l in lines if "stablehlo.convert" in l
+        and f": (tensor<{cfg.model_dim}x{vocab}xf32>)" in l
+    ]
+    assert len(leaf) == 1, leaf
 
 
 # -- the analytic step cost ---------------------------------------------------

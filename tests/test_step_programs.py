@@ -178,8 +178,8 @@ AS_DICT_KEYS = [
     "stage_backlog_bytes", "stage_block_s", "stage_bytes", "stage_chunks",
     "stage_commits", "startup_backend_s", "startup_cache_misses",
     "startup_compile_s", "startup_first_step_s", "startup_import_s",
-    "steps_ahead", "ut_entropy_sum", "ut_exit_heads", "ut_exit_step_sum",
-    "ut_layer_passes", "ut_reports", "ut_steps",
+    "steps_ahead", "ut_entropy_sum", "ut_exit_fused_heads", "ut_exit_heads",
+    "ut_exit_step_sum", "ut_layer_passes", "ut_reports", "ut_steps",
     "xdec_kv_reads", "xdec_memory_reads",
 ]
 # a float is reported to the places it had when each key was written out

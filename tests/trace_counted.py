@@ -18,7 +18,7 @@ SHARE = ("moe_share_kept_sites",)
 SSCAN = ("sscan_sites", "sscan_kernel_sites", "sscan_serial_steps")
 DIFF = ("attn_diff_pairs", "attn_diff_score_calls")
 XDEC = ("xdec_memory_reads", "xdec_kv_reads")
-UT = ("ut_steps", "ut_layer_passes", "ut_exit_heads")
+UT = ("ut_steps", "ut_layer_passes", "ut_exit_heads", "ut_exit_fused_heads")
 
 
 def added(before, names):

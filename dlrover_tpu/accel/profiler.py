@@ -244,6 +244,23 @@ class PipelineStats:
     # attention
     attn_score_lanes: int = 0
     attn_score_lanes_used: int = 0
+    # latent-attention sites of the train step program this process
+    # traced last whose query passes a latent of its own
+    # (``cfg.q_latent_dim``: down-projection, norm, up-projection in place
+    # of the whole ``wq``), and attention sites whose rotation reads a
+    # scaled table (``cfg.rope_scaling``: YaRN's frequencies and not
+    # ``theta^(-2j/D)``); 0 / 0 for a model with neither
+    attn_q_latent_sites: int = 0
+    rope_scaled_sites: int = 0
+    # the query rows of a step that a position scale multiplies
+    # (``cfg.attn_pos_scale_beta``, models/transformer._pos_scale), summed
+    # over the attention sites of the train step program this process
+    # traced last, and those of them at positions from
+    # ``cfg.rope_original_len`` on, where the scale is not 1: from the
+    # static row length, a row's positions from 0. 0 of N where the rows
+    # are no longer than the unscaled table; 0 / 0 without the scale
+    attn_pos_scaled_rows: int = 0
+    attn_pos_rows: int = 0
     # a looped model (``cfg.ut_steps`` > 1, models/transformer.py: the
     # whole stack applied several times over the same weights) in the
     # train step program this process traced last: the passes one step
@@ -700,11 +717,23 @@ def profile_model(
 
     for i in range(cfg.num_layers):
         qkv_params = d * (h + 2 * kvh) * hd + h * hd * d
-        attn_flops = 2.0 * tok * d * (h + 2 * kvh) * hd  # projections
-        attn_flops += 2.0 * tok * h * hd * d  # output proj
+        score_width = value_width = hd
+        if cfg.attn_kind == "latent":
+            # keys and values through their latent; the query projected
+            # whole, or through a latent of its own (two projections in
+            # place of the one)
+            score_width, value_width = cfg.qk_head_dim, cfg.v_head_dim
+            rq, rkv = cfg.q_latent_dim, cfg.kv_latent_dim
+            qkv_params = (
+                (d * rq + rq * h * score_width) if rq
+                else d * h * score_width
+            ) + d * (rkv + cfg.qk_rope_dim) + rkv * h * (
+                cfg.qk_nope_dim + value_width
+            ) + h * value_width * d
+        attn_flops = 2.0 * tok * qkv_params  # projections, output proj
         # qk^T and softmax*v have identical causal structure: half each
-        attn_flops += 2.0 * tok * h * T * hd / 2
-        attn_flops += 2.0 * tok * h * T * hd / 2
+        attn_flops += 2.0 * tok * h * T * score_width / 2
+        attn_flops += 2.0 * tok * h * T * value_width / 2
         attn_act = tok * (h + 2 * kvh) * hd * act_bytes + tok * d * act_bytes
         prof.modules.append(
             ModuleProfile(

@@ -107,6 +107,31 @@ class TransformerConfig:
     # the leading dims of each head that are rotated (pairs ``(i, i +
     # rope_dim / 2)``), the rest passing untouched; 0 => the whole head
     rope_dim: int = 0
+    # how the rotary table's frequencies are stretched past the length
+    # they were trained for: "" => ``theta^(-2j/D)`` as they are; "yarn"
+    # => YaRN's blend (arXiv:2309.00071, ``models/transformer.
+    # yarn_frequencies``): a pair that turns more than ``rope_beta_fast``
+    # times over ``rope_original_len`` positions keeps its frequency, one
+    # that turns fewer than ``rope_beta_slow`` times is slowed
+    # ``rope_factor`` times, a linear ramp over the pairs between
+    rope_scaling: str = ""
+    rope_factor: float = 1.0
+    # the positions the unscaled table was trained over; 0 => not stated
+    # (YaRN and ``attn_pos_scale_beta`` need it)
+    rope_original_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    # YaRN's ``mscale_all_dim``: the softmax scale of a latent attention
+    # is multiplied by ``(0.1 * rope_mscale_all_dim * ln(rope_factor) +
+    # 1)^2`` (DeepSeek-V3's reading of the key); 0 => by 1
+    rope_mscale_all_dim: float = 0.0
+    # which dims of the rotated stretch make a pair: "" => ``(i, i +
+    # D/2)`` (rotate-half); "interleaved" => ``(2i, 2i + 1)``
+    rope_pairs: str = ""
+    # a query is multiplied by ``1 + attn_pos_scale_beta * ln(1 +
+    # floor(pos / rope_original_len))`` after its rotation (Llama 4's
+    # position-dependent scale): 1 below ``rope_original_len``; 0 => none
+    attn_pos_scale_beta: float = 0.0
     rmsnorm: bool = False
     # how an RMSNorm's weight enters (the residual stream's norms, the
     # final norm and a head's q / k norm): "" => ``x * w``, w from 1;
@@ -131,7 +156,8 @@ class TransformerConfig:
     # rotated key of ``qk_rope_dim`` that every head shares: a head's
     # query and key are ``qk_nope_dim`` unrotated dims then
     # ``qk_rope_dim`` rotated ones, its value ``v_head_dim`` (MLA,
-    # DeepSeek-V2's, the query projected whole); as many key/value heads
+    # DeepSeek-V2's; the query projected whole, or through a latent of
+    # its own where ``q_latent_dim``); as many key/value heads
     # as query heads, ``attn_head_dim`` and ``rope_dim`` unused.
     # "diff" => differential attention (arXiv:2410.05258): query heads
     # ``(2i, 2i+1)`` and key heads ``(2j, 2j+1)`` are pairs, the two
@@ -144,6 +170,11 @@ class TransformerConfig:
     # bias (the "diff" kind's and the "C" layers' alone)
     attn_bias: bool = False
     kv_latent_dim: int = 0
+    # a latent attention's query comes from a latent too: down-projection
+    # to this width, RMSNorm, up-projection to the heads (``w_qa``,
+    # ``q_latent_norm``, ``w_qb`` in place of ``wq``); 0 => the query
+    # projected whole
+    q_latent_dim: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0
     v_head_dim: int = 0
@@ -403,6 +434,8 @@ class TransformerConfig:
             ("shared_expert_gate", ("", "sigmoid")),
             ("gdn_decay", ("head", "channel")),
             ("gdn_gate", ("silu", "head_sigmoid")),
+            ("rope_scaling", ("", "yarn")),
+            ("rope_pairs", ("", "interleaved")),
         ):
             if getattr(self, name) not in kinds:
                 raise ValueError(
@@ -447,6 +480,51 @@ class TransformerConfig:
                 "qk_rope_dim and v_head_dim, as many key/value heads as "
                 "query heads, no output gate, no rope_dim and a q / k norm "
                 "a head"
+            )
+        if self.q_latent_dim < 0 or (
+            self.q_latent_dim and self.attn_kind != "latent"
+        ):
+            raise ValueError(
+                f"q_latent_dim {self.q_latent_dim} is the width of a latent "
+                f"attention's query latent: attn_kind is {self.attn_kind!r}"
+            )
+        rotary = self.position_kind in ("rope", "window")
+        if (
+            self.rope_scaling or self.rope_pairs or self.attn_pos_scale_beta
+        ) and not rotary:
+            raise ValueError(
+                f"rope_scaling {self.rope_scaling!r}, rope_pairs "
+                f"{self.rope_pairs!r} and attn_pos_scale_beta "
+                f"{self.attn_pos_scale_beta} are of rotary positions: "
+                f"position_kind is {self.position_kind!r}"
+            )
+        if self.rope_scaling == "yarn" and (
+            self.rope_factor < 1 or self.rope_original_len < 1
+            or not 0 < self.rope_beta_slow < self.rope_beta_fast
+        ):
+            raise ValueError(
+                f"YaRN stretches a table made for rope_original_len "
+                f"({self.rope_original_len}) positions rope_factor "
+                f"({self.rope_factor}) times, 1 or more, between "
+                f"rope_beta_slow ({self.rope_beta_slow}) and a larger "
+                f"rope_beta_fast ({self.rope_beta_fast}) turns"
+            )
+        if self.rope_mscale_all_dim < 0 or (
+            self.rope_mscale_all_dim
+            and (self.rope_scaling != "yarn" or self.attn_kind != "latent")
+        ):
+            raise ValueError(
+                f"rope_mscale_all_dim {self.rope_mscale_all_dim} scales a "
+                "latent attention's softmax under YaRN: rope_scaling is "
+                f"{self.rope_scaling!r}, attn_kind {self.attn_kind!r}"
+            )
+        if self.attn_pos_scale_beta < 0 or (
+            self.attn_pos_scale_beta and self.rope_original_len < 1
+        ):
+            raise ValueError(
+                f"attn_pos_scale_beta {self.attn_pos_scale_beta} scales a "
+                "query by how many times its position passes "
+                f"rope_original_len, which is {self.rope_original_len}"
             )
         if self.router_groups < 1 or not (
             1 <= self.router_groups_kept <= self.router_groups

@@ -124,8 +124,17 @@ def init_params(key, cfg: TransformerConfig) -> Params:
         if cfg.attn_kind == "latent":
             latent, nope = cfg.kv_latent_dim, cfg.qk_nope_dim
             rope, vd = cfg.qk_rope_dim, cfg.v_head_dim
+            rq = cfg.q_latent_dim
+            # the query projected whole, or through a latent of its own
+            query = {"wq": dense(next(keys), (d, h, nope + rope), d)}
+            if rq:
+                query = {
+                    "w_qa": dense(next(keys), (d, rq), d),
+                    "q_latent_norm": {"scale": norm_scale((rq,))},
+                    "w_qb": dense(next(keys), (rq, h, nope + rope), rq),
+                }
             return {
-                "wq": dense(next(keys), (d, h, nope + rope), d),
+                **query,
                 # [latent | the one rotated key every head shares]
                 "w_kva": dense(next(keys), (d, latent + rope), d),
                 "kv_norm": {"scale": norm_scale((latent,))},
@@ -281,8 +290,15 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 
     def attention():
         if cfg.attn_kind == "latent":
+            query = {"wq": ("embed", "heads", "head_dim")}
+            if cfg.q_latent_dim:
+                query = {
+                    "w_qa": ("embed", None),
+                    "q_latent_norm": {"scale": (None,)},
+                    "w_qb": (None, "heads", "head_dim"),
+                }
             return {
-                "wq": ("embed", "heads", "head_dim"),
+                **query,
                 "w_kva": ("embed", None),
                 "kv_norm": {"scale": (None,)},
                 "w_kvb": (None, "heads", "head_dim"),
@@ -463,15 +479,114 @@ def _qk_norm(x, p, cfg: TransformerConfig, layout: str = "bthd"):
     return (xf * jax.lax.rsqrt(ms + _norm_eps(cfg)) * scale).astype(x.dtype)
 
 
-def _rope(x, positions, theta: float, layout: str = "bthd", dims: int = 0):
+def yarn_frequencies(dims: int, theta: float, factor: float,
+                     original_len: int, beta_fast: float, beta_slow: float):
+    """YaRN's rotary table (arXiv:2309.00071; the form of ``transformers``'
+    ``_compute_yarn_parameters``) for ``dims`` rotated dims: pair ``j`` of
+    ``dims / 2`` turns by ``pos * f_j``. ``e_j = theta^(-2j/dims)`` is the
+    table made for ``original_len`` positions; the pair that turns ``n``
+    times over them is ``corr(n) = dims ln(original_len / (2 pi n)) / (2 ln
+    theta)``; pairs up to ``floor(corr(beta_fast))`` keep ``e_j``, pairs
+    from ``ceil(corr(beta_slow))`` on turn ``factor`` times slower, and a
+    linear ramp blends the pairs between. float32 [dims / 2], made on the
+    host while the program is traced."""
+    def corr(turns):
+        return dims * math.log(original_len / (2 * math.pi * turns)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dims - 1)
+    j = np.arange(dims // 2, dtype=np.float32)
+    # a ramp between equal ends is a step (``transformers`` does the same)
+    ramp = np.clip((j - low) / max(high - low, 1e-3), 0.0, 1.0)
+    e = np.float32(theta) ** (-2.0 * j / np.float32(dims))
+    return (e * (1.0 - ramp) + e / np.float32(factor) * ramp).astype(
+        np.float32
+    )
+
+
+def yarn_softmax_mscale(cfg: TransformerConfig) -> float:
+    """What a latent attention's softmax scale is multiplied by under
+    YaRN: ``m^2``, ``m = 0.1 * rope_mscale_all_dim * ln(rope_factor) + 1``
+    (DeepSeek-V3's reading of ``mscale_all_dim``); 1 where the
+    configuration states none."""
+    if not cfg.rope_mscale_all_dim:
+        return 1.0
+    return (
+        0.1 * cfg.rope_mscale_all_dim * math.log(cfg.rope_factor) + 1.0
+    ) ** 2
+
+
+def _rope_table(cfg: TransformerConfig, dims: int) -> dict:
+    """``_rope``'s ``freqs`` and ``pairs`` for ``dims`` rotated dims as the
+    configuration states them: nothing for the plain table on rotate-half
+    pairs, which ``_rope`` makes from ``theta`` as it always did. A site
+    rotated by a scaled table is counted (``rope_scaled_sites``)."""
+    table = {}
+    if cfg.rope_scaling == "yarn":
+        trace_counts.count("rope_scaled_sites")
+        table["freqs"] = yarn_frequencies(
+            dims, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_len,
+            cfg.rope_beta_fast, cfg.rope_beta_slow,
+        )
+    if cfg.rope_pairs:
+        table["pairs"] = cfg.rope_pairs
+    return table
+
+
+def _pos_scale(q, positions, cfg: TransformerConfig, layout: str = "bthd"):
+    """The query times ``1 + beta * ln(1 + floor(pos / L))``, ``beta``
+    ``cfg.attn_pos_scale_beta`` and ``L`` ``cfg.rope_original_len``, in
+    float32 (Llama 4's position-dependent scale, after the rotation): 1
+    below ``L``. q: [B,T,H,D] or [B,H,T,D] per layout. The site's query
+    rows, and those of them past ``L`` (a row's positions run from 0), are
+    counted (``attn_pos_rows``, ``attn_pos_scaled_rows``)."""
+    if not cfg.attn_pos_scale_beta:
+        return q
+    batch, rows = positions.shape
+    trace_counts.count("attn_pos_rows", batch * rows)
+    trace_counts.count(
+        "attn_pos_scaled_rows",
+        batch * max(rows - cfg.rope_original_len, 0),
+    )
+    scale = 1.0 + cfg.attn_pos_scale_beta * jnp.log1p(
+        jnp.floor(positions.astype(jnp.float32) / cfg.rope_original_len)
+    )
+    scale = scale[:, None, :, None] if layout == "bhtd" else (
+        scale[:, :, None, None]
+    )
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
+def _rotate(q, k, positions, cfg: TransformerConfig, layout: str = "bthd",
+            dims: int = 0):
+    """A projected attention's q and k rotated as the configuration states
+    (``_rope`` by ``_rope_table``), the query then scaled by its position
+    (``_pos_scale``)."""
+    table = _rope_table(cfg, dims or q.shape[-1])
+    q = _rope(q, positions, cfg.rope_theta, layout, dims, **table)
+    k = _rope(k, positions, cfg.rope_theta, layout, dims, **table)
+    return _pos_scale(q, positions, cfg, layout), k
+
+
+def _rope(x, positions, theta: float, layout: str = "bthd", dims: int = 0,
+          freqs=None, pairs: str = ""):
     """Rotate pairs (d, d+D/2). x: [B,T,H,D] or [B,H,T,D] per layout.
     ``dims`` (0 = D): only the leading ``dims`` of a head are rotated, in
-    pairs (d, d+dims/2); the rest pass as they are."""
+    pairs (d, d+dims/2); the rest pass as they are. ``freqs`` (float32
+    [dims / 2]): the pairs' frequencies where the table is no plain
+    ``theta^(-2j/dims)`` (``_rope_table``). ``pairs`` "interleaved": the
+    pairs are ``(2j, 2j + 1)``, and the rotated dims come out as
+    ``[every pair's first | every pair's second]``: the one permutation
+    for a query and its keys, which their scores cannot see, so nothing
+    puts them back."""
     rotated = dims or x.shape[-1]
     half = rotated // 2
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
-    )
+    if freqs is None:
+        freqs = 1.0 / (
+            theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
+        )
     ang = positions[:, :, None].astype(jnp.float32) * freqs  # [B,T,half]
     if layout == "bhtd":
         cos = jnp.cos(ang)[:, None, :, :]
@@ -479,7 +594,10 @@ def _rope(x, positions, theta: float, layout: str = "bthd", dims: int = 0):
     else:
         cos = jnp.cos(ang)[:, :, None, :]
         sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:rotated]
+    if pairs == "interleaved":
+        x1, x2 = x[..., 0:rotated:2], x[..., 1:rotated:2]
+    else:
+        x1, x2 = x[..., :half], x[..., half:rotated]
     parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
     if rotated < x.shape[-1]:
         parts.append(x[..., rotated:])
@@ -658,8 +776,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
         q = _qk_norm(q, layer["q_norm"], cfg, layout)
         k = _qk_norm(k, layer["k_norm"], cfg, layout)
     if cfg.layer_positions(kind) == "rope":
-        q = _rope(q, positions, cfg.rope_theta, layout, cfg.rope_dim)
-        k = _rope(k, positions, cfg.rope_theta, layout, cfg.rope_dim)
+        q, k = _rotate(q, k, positions, cfg, layout, cfg.rope_dim)
     if cfg.mup_attn_scale is not None:
         # muP 1/d attention: fold the deviation from the kernels' builtin
         # 1/sqrt(d) into q, so flash and ring paths need no new plumbing
@@ -695,11 +812,12 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
 _LANES = 128
 
 
-def _attention_of_two_widths(q, k, v, mesh, window=None):
+def _attention_of_two_widths(q, k, v, mesh, window=None, mscale=1.0):
     """Causal attention [B, H, T, .] whose scores contract another width
     than its values have (q, k 192 and v 128 wide, say; or 64 and a
-    differential pair's 128), scaled by the stated score width, through a
-    ``window`` where one is given. The attention kernels take ONE width of
+    differential pair's 128), scaled by the stated score width (times
+    ``mscale``), through a ``window`` where one is given. The attention
+    kernels take ONE width of
     whole lane tiles for q, k and v, so the call pads: zeros on q and k
     leave every score as it is, v is padded and the output sliced; the
     width called is counted beside the width stated."""
@@ -711,8 +829,8 @@ def _attention_of_two_widths(q, k, v, mesh, window=None):
         return jnp.pad(t, ((0, 0),) * 3 + ((0, width - t.shape[-1]),))
 
     return _causal_attention(
-        pad(q), pad(k), pad(v), mesh, layout="bhtd", sm_scale=qk**-0.5,
-        window=window,
+        pad(q), pad(k), pad(v), mesh, layout="bhtd",
+        sm_scale=qk**-0.5 * mscale, window=window,
     )[..., :vd]
 
 
@@ -829,10 +947,15 @@ def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
     """``x + attention(norm(x))`` with keys and values from a latent
     (``cfg.attn_kind`` "latent"): ``[c | k_rope] = h W_kva``, ``[k_nope |
     v] = RMSNorm(c) W_kvb`` a head, the one ``k_rope`` shared by every
-    head; a head's q and k are ``[nope | rope]``, normed whole where
-    ``qk_norm`` and then rotated on the rope dims alone; causal softmax
-    over ``qk_nope_dim + qk_rope_dim`` wide scores, values ``v_head_dim``
-    wide (``_attention_of_two_widths``)."""
+    head; the query projected whole, ``q = h W_q``, or where
+    ``cfg.q_latent_dim`` through a latent of its own, ``q = RMSNorm(h
+    W_qa) W_qb``; a head's q and k are ``[nope | rope]``, normed whole
+    where ``qk_norm`` and then rotated on the rope dims alone (by the
+    table and pairs ``_rope_table`` gives), the query then scaled by its
+    position (``_pos_scale``); causal softmax over ``qk_nope_dim +
+    qk_rope_dim`` wide scores, scaled by YaRN's ``m^2`` too where the
+    configuration states one (``yarn_softmax_mscale``), values
+    ``v_head_dim`` wide (``_attention_of_two_widths``)."""
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         raise NotImplementedError(
             "latent attention knows no sequence-parallel scheme"
@@ -841,7 +964,12 @@ def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     latent = cfg.kv_latent_dim
     h = _norm(x, layer[norm], cfg)
-    q = jnp.einsum("btd,dhk->bhtk", h, a["wq"].astype(h.dtype))
+    if cfg.q_latent_dim:
+        trace_counts.count("attn_q_latent_sites")
+        cq = _norm(h @ a["w_qa"].astype(h.dtype), a["q_latent_norm"], cfg)
+        q = jnp.einsum("btc,chk->bhtk", cq, a["w_qb"].astype(h.dtype))
+    else:
+        q = jnp.einsum("btd,dhk->bhtk", h, a["wq"].astype(h.dtype))
     with jax.named_scope("scope/layer/attn/kv_down"):
         down = h @ a["w_kva"].astype(h.dtype)
     c = _norm(down[..., :latent], a["kv_norm"], cfg)
@@ -856,11 +984,15 @@ def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
         q = _qk_norm(q, layer["q_norm"], cfg, "bhtd")
         k = _qk_norm(k, layer["k_norm"], cfg, "bhtd")
     if cfg.position_kind == "rope":
+        table = _rope_table(cfg, rope)
         q, k = (jnp.concatenate([
             t[..., :nope],
-            _rope(t[..., nope:], positions, cfg.rope_theta, "bhtd"),
+            _rope(t[..., nope:], positions, cfg.rope_theta, "bhtd", **table),
         ], axis=-1) for t in (q, k))
-    o = _attention_of_two_widths(q, k, v, mesh)
+        q = _pos_scale(q, positions, cfg, "bhtd")
+    o = _attention_of_two_widths(
+        q, k, v, mesh, mscale=yarn_softmax_mscale(cfg)
+    )
     return _residual(
         x, jnp.einsum("bhtk,hkd->btd", o, a["wo"].astype(o.dtype)), layer,
         cfg,
@@ -1558,8 +1690,7 @@ def _cached_decode_layer(
         q = _qk_norm(q, layer["q_norm"], cfg)
         k = _qk_norm(k, layer["k_norm"], cfg)
     if cfg.position_kind == "rope":
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q, k = _rotate(q, k, positions, cfg)
     if cfg.mup_attn_scale is not None:
         # same muP 1/d fold as _attention_block — decode must score
         # with the training attention math

@@ -69,6 +69,12 @@ then a ``lax.scan`` over the exits that makes softmax minus one-hot beside
 each loss and both gradient products from it, where a ``lax.map`` of
 ``jax.checkpoint``ed exits stood). The other eight entries, whose models
 run their layers once and never meet ``ut_exits``, are as they were.
+ISSUE 59 brought ``mistral-small-4-119b-d4`` (a latent attention whose
+query passes a latent of its own, rotated by a YaRN table on interleaved
+pairs, the query scaled by its position) and left the nine entries before
+it as they were: ``q_latent_dim`` 0 is the whole ``wq``, and with no
+``rope_scaling``, ``rope_pairs`` or ``attn_pos_scale_beta`` ``_rope`` makes
+``theta``'s own table on rotate-half pairs from the same operations.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
@@ -90,7 +96,7 @@ NAMES = (
     "gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2",
     "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
     "ling-3.0-flash-d7", "trinity-mini-d5", "phi4-mini-flash-d6",
-    "ouro-2.6b-d6",
+    "ouro-2.6b-d6", "mistral-small-4-119b-d4",
 )
 
 

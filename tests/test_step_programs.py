@@ -147,6 +147,7 @@ AS_DICT_KEYS = [
     "attn_diff_pairs", "attn_diff_score_calls",
     "attn_edge_tiles", "attn_edge_tiles_multiplied",
     "attn_kept_sites",
+    "attn_pos_rows", "attn_pos_scaled_rows", "attn_q_latent_sites",
     "attn_score_lanes", "attn_score_lanes_used", "attn_square_sites",
     "attn_stream_blocks_rect",
     "attn_stream_blocks_walked", "attn_stream_rect_sites",
@@ -173,7 +174,8 @@ AS_DICT_KEYS = [
     "resize_downtime_ms", "resize_idle_ranks", "resize_mb_pad",
     "restore_agree_s", "restore_bytes", "restore_h2d_s",
     "restore_lock_wait_s", "restore_shm_verify_s", "restore_source",
-    "restore_storage_read_s", "restore_storage_verify_s", "safe_steps",
+    "restore_storage_read_s", "restore_storage_verify_s",
+    "rope_scaled_sites", "safe_steps",
     "save_skips", "sscan_kernel_sites", "sscan_serial_steps", "sscan_sites",
     "stage_backlog_bytes", "stage_block_s", "stage_bytes", "stage_chunks",
     "stage_commits", "startup_backend_s", "startup_cache_misses",

@@ -27,8 +27,8 @@ from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
-    CONV, DIFF, EDGE, FUSED, GDN, GDN_KEPT, KEPT, LANES, SHARE, SSCAN,
-    STREAM, UT, WINDOW, XDEC,
+    CONV, DIFF, EDGE, FUSED, GDN, GDN_KEPT, KEPT, LANES, SCALED, SHARE,
+    SSCAN, STREAM, UT, WINDOW, XDEC,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -484,6 +484,20 @@ TOYS = {
             remat=True, **_SMALL,
         ),
         (FUSED, LANES, KEPT, UT),
+    ),
+    # latent attention whose query passes a latent, rotated by a YaRN
+    # table on interleaved pairs, the query scaled past 16 positions,
+    # before a share of the experts; recomputed
+    "a_latent_query_and_a_scaled_table_remat": (
+        TransformerConfig(
+            attn_kind="latent", q_latent_dim=16, kv_latent_dim=16,
+            qk_nope_dim=8, qk_rope_dim=8, v_head_dim=16, rope=True,
+            rope_scaling="yarn", rope_factor=8.0, rope_original_len=16,
+            rope_mscale_all_dim=1.0, rope_pairs="interleaved",
+            attn_pos_scale_beta=0.1, remat=True,
+            **dict(_SHARE, router="softmax", positions=""), **_SMALL,
+        ),
+        (FUSED, LANES, KEPT, SHARE, SCALED),
     ),
 }
 

@@ -18,6 +18,10 @@ SHARE = ("moe_share_kept_sites",)
 SSCAN = ("sscan_sites", "sscan_kernel_sites", "sscan_serial_steps")
 DIFF = ("attn_diff_pairs", "attn_diff_score_calls")
 XDEC = ("xdec_memory_reads", "xdec_kv_reads")
+SCALED = (
+    "attn_q_latent_sites", "rope_scaled_sites", "attn_pos_scaled_rows",
+    "attn_pos_rows",
+)
 UT = ("ut_steps", "ut_layer_passes", "ut_exit_heads", "ut_exit_fused_heads")
 
 

@@ -288,6 +288,18 @@ class PipelineStats:
     ut_reports: int = 0
     ut_entropy_sum: float = _reported_to(6)
     ut_exit_step_sum: float = _reported_to(6)
+    # counted while the step was traced, as the kernels' sites above:
+    # elements of the optimizer's int8 moments (both moments, as
+    # ``opt_q8_tiles_elems`` above counts them) whose leaf's step the
+    # train step that was built runs as the one-pass kernel
+    # (ops/quantized_optim.py ``_q8_adam_step``: gradient, parameter,
+    # codes and scales read once where they lie, parameter and moments
+    # written in place; counted where the leaf's call is traced): what the
+    # built program does, where ``opt_q8_tiles_elems`` says what the state
+    # would allow. 0 off the TPU, on a mesh of several devices, with the
+    # state offloaded, in a step that does not donate, and for a
+    # transformation without the ``update_and_apply`` entry
+    opt_q8_kernel_elems: int = 0
     # -- overlap-scheduled gradient sync (parallel/grad_sync.py) -------
     # which gradient-sync schedule the current mesh runs: "explicit"
     # (the bucketed scheduler engaged) or "gspmd" (fallback — was

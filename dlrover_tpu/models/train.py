@@ -768,6 +768,16 @@ def build_train_step(
             gnorm = optax.global_norm(grads)
         return loss, aux, grads, gnorm, None
 
+    from dlrover_tpu.ops.quantized_optim import in_place_entry
+
+    # a transformation that can write a parameter where it lies
+    # (``ops/quantized_optim.InPlaceTransformation``), where this step
+    # may let it: else None, and the two lines below
+    update_and_apply = in_place_entry(
+        tx, devices=mesh.size, donate=donate,
+        resident=not offload_opt_state,
+    )
+
     def train_step(state: TrainState, tokens, targets):
         dev_norms = None
         if plan is not None and getattr(plan, "kind", "") == "ep":
@@ -790,14 +800,22 @@ def build_train_step(
             opt_state = fetch_tree(opt_state, opt_sh)
         # stable names on the device (see models/transformer.py): the
         # optimizer pass here holds whatever the chain clips by too
-        with jax.named_scope("scope/optimizer"):
-            updates, new_opt = tx.update(grads, opt_state, state.params)
-        if offload_opt_state:
-            from dlrover_tpu.ops.host_offload import offload_tree
+        if update_and_apply is not None:
+            with jax.named_scope("scope/optimizer"):
+                new_params, new_opt = update_and_apply(
+                    grads, opt_state, state.params
+                )
+        else:
+            with jax.named_scope("scope/optimizer"):
+                updates, new_opt = tx.update(
+                    grads, opt_state, state.params
+                )
+            if offload_opt_state:
+                from dlrover_tpu.ops.host_offload import offload_tree
 
-            new_opt = offload_tree(new_opt, opt_sh)
-        with jax.named_scope("scope/optimizer"):
-            new_params = optax.apply_updates(state.params, updates)
+                new_opt = offload_tree(new_opt, opt_sh)
+            with jax.named_scope("scope/optimizer"):
+                new_params = optax.apply_updates(state.params, updates)
         if "layer_load" in aux and cfg.router == "sigmoid":
             from dlrover_tpu.parallel.moe import move_router_bias
 

@@ -11,17 +11,42 @@ leaf, the same in every layout below (a row whose width is no multiple
 of 128 is padded to whole blocks, ``_to_blocks``: a block never holds
 the end of one row and the start of the next). What runs today:
 
-- ``adamw_8bit`` with ``use_pallas=False`` (the benchmark's OLMoE cell,
-  and every backend but the TPU by default): plain jnp that XLA fuses. A
-  leaf whose last two dimensions are whole (8, 128) tiles keeps its
-  moments in ``TILES`` layout, the leaf's own tile order in HBM: the
-  update then views the gradient and hands back delta through a reshape +
-  transpose that the TPU compiler takes as a bitcast, the per-block
-  maximum is a lane reduce over the last axis, and decay and
-  ``apply_updates`` fuse onto delta, which never exists in HBM. XLA still
-  makes four passes a leaf (two block-maximum reduces, the parameter,
-  the requantise): 24 bytes an element with a bf16 gradient, against 14
-  for one pass. Any other leaf (1-D, odd widths) takes ``BLOCKS``:
+- ``adamw_8bit`` with ``use_pallas=False`` (the benchmark's eight int8
+  cells, and every backend but the TPU by default). A leaf whose last two
+  dimensions are whole (8, 128) tiles keeps its moments in ``TILES``
+  layout, the leaf's own tile order in HBM (codes ``[..., R/8, C/128, 8,
+  128]``, scales ``[..., R/8, C/128, 8]``), and has two ways to run:
+  - ``update`` (and then ``optax.apply_updates``), the statement: plain
+    jnp that XLA fuses. It views the gradient and hands back delta
+    through a reshape + transpose that the TPU compiler takes as a
+    bitcast, the per-block maximum is a lane reduce over the last axis,
+    and decay and ``apply_updates`` fuse onto delta, which never exists in
+    HBM. XLA still makes four passes a leaf (two block-maximum reduces,
+    the parameter, the requantise): 24 bytes an element with a bf16
+    gradient, against 14 for one pass. What every backend but the TPU
+    runs, and on the TPU a mesh of several devices, an offloaded state,
+    a step that does not donate and every caller that wants the updates.
+  - ``update_and_apply`` (``InPlaceTransformation``: ``(grads, state,
+    params) -> (new_params, new_state)``), which on a TPU gives such a
+    leaf to ONE kernel, ``_q8_adam_step`` (the Pallas call
+    ``q8_adam_step``): gradient, parameter, codes and scales read once
+    where they lie, dequantise, Adam, decay and scale, requantise in
+    registers through the functions the statement calls, parameter and
+    moments written in place (``input_output_aliases``): 14 bytes an
+    element (16 with a float32 gradient) and one pass. ``takes_kernel`` is
+    the rule, read from the leaf and the backend; ``in_place_entry`` says
+    where a step may call the entry (``models/train.build_train_step``,
+    ``parallel/pipeline.py``). The kernel's vector code is written for one
+    strip of ``_STRIP`` tiles and looped, its block is ``_STEP_TILES``
+    tiles whatever the leaf's width (``_step_blocking``), and every call
+    goes through one ``jax.jit`` whose per-step numbers ride in SMEM, so a
+    program holds one lowered function a (shape, gradient dtype) and a
+    small executable a kernel: a call site adds little to a program's
+    first step (PERF.md §6, PR 61; PR 60's kernel, unrolled over a block
+    32 quantization blocks wide, cost the Nemotron cell 12 s there). The
+    state at rest is the statement's, so the two may take turns on one
+    state (a checkpoint of either restores under the other).
+  Any other leaf (1-D, odd widths) takes ``BLOCKS`` in either entry:
   ``[nblocks, 128]`` rows, padded; on the TPU that flattening is a
   physical relayout of the gradient and another of delta (an (8, 128)
   tiled ``[..., 1024]`` array does not lie in rows of 128): 22 of the 81 ms
@@ -33,6 +58,9 @@ the end of one row and the start of the next). What runs today:
 - ``adamw_8bit`` with ``use_pallas=True`` (the default on the TPU): the
   tree kernel, one ``pallas_call`` a leaf over ``BLOCKS`` rows (g, codes,
   scales in; codes', scales', delta out), between the same two relayouts.
+  ``use_pallas`` means this kernel and nothing else: it decides the
+  layout of every leaf at ``init`` (``BLOCKS`` for all where it is on),
+  and a ``TILES`` leaf's one-pass kernel asks for no option.
 - ``adamw_8bit_flat``: big leaves packed into a few flat buffers, one
   aliased Pallas pass a group with dense ("wide") scales (no benchmark
   configuration names it). ``bits=4``: jnp only, over ``BLOCKS`` rows.
@@ -82,6 +110,18 @@ BLOCKS = "blocks"  # codes [nblocks, BLOCK], scales [nblocks, 1]
 # codes [..., R/8, C/BLOCK, 8, BLOCK], scales [..., R/8, C/BLOCK, 8]
 TILES = "tiles"
 _SUBLANES = 8  # rows of one f32 (8, 128) tile
+# the one-pass step of a TILES leaf (``_q8_adam_step``). Tiles of one
+# strip: the kernel's vector code is emitted for one strip and looped
+# over the VMEM block, so a kernel's size does not go with its block or
+# its leaf; sixteen tiles that do not depend on each other are what hides
+# the lane reduces' and the square roots' latency (stand-alone on a
+# [64, 2048, 1024] leaf PR 60 read 6.04 ms with 4 tiles in flight, 4.76
+# with 8, 4.25 with 16 and 4.39 with 32; this kernel 5.61 ms with 8,
+# 4.72 with 16, 4.36 with 32 and 4.41 with 64: PERF.md §6)
+_STRIP = 32
+# tiles a grid step: 262k elements, the size that put the flat kernel on
+# the HBM roofline (see ``_FLAT_ROWS``), whatever the leaf's width
+_STEP_TILES = 256
 
 
 @jax.tree_util.register_pytree_node_class
@@ -194,20 +234,24 @@ def _sqrt_map_quant(x, signed, qmax):
     up through the eps denominator. Purely elementwise (no codebook
     gather), so it stays on the VPU.
     """
+    # the codes of clip(round(sign(y) sqrt|y| qmax)), y = x / safe, in
+    # fewer vector operations and bit for bit (the one-pass kernel is bound
+    # by them): |y| is |x| / safe, a rounded product keeps its sign, no
+    # finite |y| passes 1, and an unsigned block holds nothing below 0
     if signed:
-        scale = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
+        a = jnp.abs(x)
+        scale = jnp.max(a, axis=-1, keepdims=True)
     else:
+        a = jnp.maximum(x, 0.0)
         scale = jnp.max(x, axis=-1, keepdims=True)
     safe = jnp.maximum(scale, 1e-30)
-    y = x / safe
-    codes = jnp.round(jnp.sign(y) * jnp.sqrt(jnp.abs(y)) * qmax)
-    lo = -float(qmax) if signed else 0.0
-    return jnp.clip(codes, lo, float(qmax)), scale
+    t = jnp.round(jnp.sqrt(a / safe) * qmax)
+    return (jnp.where(x < 0, -t, t) if signed else t), scale
 
 
 def _sqrt_map_dequant(codes_f, scales, qmax):
     c = codes_f / qmax
-    return jnp.sign(c) * c * c * scales
+    return jnp.abs(c) * c * scales  # sign(c) c c, one operation less
 
 
 def _quant_block_math(x, signed):
@@ -429,6 +473,258 @@ def _adam8_update_jnp(
         Quantized8(mc, ms, mq.shape, True, mq.layout),
         Quantized8(vc, vs, vq.shape, False, vq.layout),
         delta,
+    )
+
+
+# ---------------------------------------------------------------------------
+# the one-pass step of a TILES leaf
+# ---------------------------------------------------------------------------
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    """The one-pass kernel is compiled where the backend is a TPU and
+    interpreted where a test asks for it anywhere else."""
+    return jax.default_backend() != "tpu"
+
+
+def _lane_dim(dims) -> int:
+    """Which dimension of a ``TILES`` leaf's tile grid ``[..., R/8,
+    C/128]`` lies along the lanes where the TPU keeps its scales
+    ``[..., R/8, C/128, 8]``: the chip lays such a narrow-ended array out
+    with the eight rows of a tile along the sublanes and, along the lanes,
+    the dimension that pads least to whole lane rows, the later of two
+    that pad alike (``{1,3,2,0}`` for ``[64, 256, 8, 8]``, ``{1,2,0}`` for
+    ``[336, 128, 8]``, ``{0,3,2,1}`` for ``[2048, 2, 1, 8]``: seen in
+    compiles for a v5e). The one-pass kernel reads the scales in that
+    order, so the view is a bitcast; were the chip to choose otherwise it
+    is a copy of 1/128 of the leaf, and nothing else changes."""
+    return min(
+        range(len(dims)),
+        key=lambda k: (-(-dims[k] // BLOCK) * BLOCK / dims[k], -k),
+    )
+
+
+def _step_blocking(dims) -> tuple:
+    """``(a, b, T, J)`` for a tile grid ``dims``: one program of the
+    one-pass kernel takes ``T`` tiles along dimension ``a`` (the scales'
+    lanes, ``_lane_dim``; at most one lane row) by ``J`` along ``b``, the
+    other dimension that best fills ``_STEP_TILES`` (the later of two that
+    do alike: neighbours in a row of tiles are neighbours in HBM); every
+    other dimension is the grid's. The same rule for every leaf, so no
+    width gets a block, or a kernel, of its own size."""
+    a = _lane_dim(dims)
+    T = min(dims[a], BLOCK)
+    want = max(_STEP_TILES // T, 1)
+    b = max(
+        (k for k in range(len(dims)) if k != a),
+        key=lambda k: (min(dims[k], want), k),
+    )
+    return a, b, T, min(dims[b], want)
+
+
+def _scales_by_lane(s, a: int):
+    """``[..., R/8, C/128, 8]`` (a tile's eight rows last, as the block
+    math makes them and the state keeps them) with dimension ``a`` moved
+    behind the eight: the view in which the one-pass kernel reads a
+    ``TILES`` leaf's scales, a tile a lane (see ``_lane_dim``)."""
+    return jnp.moveaxis(s, a, -1)
+
+
+def _adam8_step_kernel(
+    scalar_ref,  # SMEM [5]: lrA, invbc2, eps, lr * weight_decay, scale
+    g_ref,  # the block's data: see ``by_rows``
+    p_ref,
+    mc_ref,
+    ms_ref,  # [J, 8, T] f32: tile (i, j)'s eight scales at [j, :, i]
+    vc_ref,
+    vs_ref,
+    p_out,
+    mc_out,
+    ms_out,
+    vc_out,
+    vs_out,
+    *,
+    b1: float,
+    b2: float,
+    classic_eps: bool,
+    by_rows: bool,
+    lanes_first: bool,
+):
+    """One program: ``T`` by ``J`` tiles of one leaf (``_step_blocking``).
+    The body is written for ONE strip of ``_STRIP`` tiles (neighbours
+    along ``T``) and looped over the VMEM block, so the vector code is as
+    long as a strip and not as the block, and the strip is one array to
+    the tracer, so the traced body is as long as one tile's. A strip goes
+    through the module's shared math in registers: dequantise, Adam, decay
+    and scale, the parameter, requantise; a tile's scales are one lane of
+    the block's ``[8, T]`` scales, taken out by a masked lane sum and put
+    back by a select.
+
+    ``by_rows``: the data are ``[8 T, 128 J]``, rows of the leaf (``T``
+    runs along the leaf's rows), and a strip ``[8 _STRIP, 128]``: the int8
+    codes then fill their vector registers (32 rows each). Else they are
+    ``[T, J, 8, 128]`` (``[J, T, 8, 128]`` unless ``lanes_first``), tiles
+    of any two dimensions of the leaf, and a strip ``[_STRIP, 8, 128]``, a
+    quarter of a register a tile of codes; there a last strip that would
+    hang over starts earlier and makes a few tiles twice (inputs and
+    outputs are different VMEM buffers)."""
+    lrA, invbc2, eps = scalar_ref[0], scalar_ref[1], scalar_ref[2]
+    decay, scale = scalar_ref[3], scalar_ref[4]
+    blocks, _, tiles = ms_ref.shape
+    strip = min(_STRIP, tiles)
+    lanes = lax.broadcasted_iota(jnp.int32, (strip, _SUBLANES, tiles), 2)
+    nth = lax.broadcasted_iota(jnp.int32, (strip, _SUBLANES, tiles), 0)
+    lane = lanes[0]
+
+    def one_block(j, carry):
+        ms, vs = ms_ref[j], vs_ref[j]  # [8, T]
+
+        def one_strip(s, new):
+            first = jnp.minimum(s * strip, tiles - strip)
+            if by_rows:
+                rows = strip * _SUBLANES
+                at = (
+                    pl.ds(pl.multiple_of(first * _SUBLANES, rows), rows),
+                    pl.ds(pl.multiple_of(j * BLOCK, BLOCK), BLOCK),
+                )
+            else:
+                at = pl.ds(first, strip)
+                at = (at, j) if lanes_first else (j, at)
+            own = lanes == first + nth  # tile k of the strip: its lane
+            here = (lane >= first) & (lane < first + strip)
+
+            def taken(scales):  # [8, T] -> a strip's, a row a sublane
+                mine = jnp.sum(
+                    jnp.where(own, scales[None], 0.0), axis=2, keepdims=True
+                )
+                return mine.reshape(-1, 1) if by_rows else mine
+
+            def put(mine, scales):  # a strip's new scales into [8, T]
+                mine = mine.reshape(strip, _SUBLANES, 1)
+                return jnp.where(
+                    here, jnp.sum(jnp.where(own, mine, 0.0), axis=0), scales
+                )
+
+            p, g = p_ref[at], g_ref[at]
+            m = _dequant_block_math(mc_ref[at], taken(ms))
+            v = _dequant_block_math(vc_ref[at], taken(vs))
+            m, v, delta = _adam8_block_math(
+                g.astype(jnp.float32), m, v, lrA, invbc2, eps, b1, b2,
+                classic_eps,
+            )
+            # the statement hands delta back in the gradient's dtype
+            delta = delta.astype(g.dtype).astype(jnp.float32)
+            # no decay is a 0 and no scale a 1: exact in float32
+            p_out[at] = p + scale * (delta - decay * p)
+            mc_out[at], m_scale = _quant_block_math(m, signed=True)
+            vc_out[at], v_scale = _quant_block_math(v, signed=False)
+            return put(m_scale, new[0]), put(v_scale, new[1])
+
+        ms_out[j], vs_out[j] = lax.fori_loop(
+            0, pl.cdiv(tiles, strip), one_strip, (ms, vs)
+        )
+        return carry
+
+    lax.fori_loop(0, blocks, one_block, 0)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("b1", "b2", "classic_eps", "interpret")
+)
+def _q8_adam_step(
+    scalars, g, p, mc, ms, vc, vs, *, b1, b2, classic_eps, interpret
+):
+    """``(p', mc', ms', vc', vs')`` of one ``TILES`` leaf in one pass over
+    HBM: gradient, parameter, codes and scales read once where they lie
+    (``_to_tiles`` / ``_from_tiles``, ``_scales_by_lane``: views the TPU
+    compiler takes as bitcasts), parameter, codes and scales written in
+    place (``input_output_aliases``: a donating step holds nothing new).
+    Codes and scales come and go as the state keeps them.
+
+    One jit for every call site: what differs between two steps or two
+    leaves of one shape rides in ``scalars``, so a program lowers this
+    function once a (shape, gradient dtype) and calls it once a leaf. The
+    block is ``_STEP_TILES`` tiles whatever the leaf's shape
+    (``_step_blocking``); a last block that hangs over the leaf is
+    computed and not written."""
+    shape = p.shape
+    dims = (*shape[:-2], shape[-2] // _SUBLANES, shape[-1] // BLOCK)
+    n = len(dims)
+    a, b, T, J = _step_blocking(dims)
+    rest = [k for k in range(n) if k != a]
+    # the scales' lanes run along the leaf's rows, its blocks beside them,
+    # in whole strips: the data as the leaf's rows, else as its tiles
+    by_rows = (a, b) == (n - 2, n - 1) and T % _STRIP == 0
+
+    def blocked(k):
+        return T if k == a else J if k == b else None
+
+    def as_rows(x):
+        return _from_tiles(x, shape)
+
+    def as_is(x):
+        return x
+
+    # how the leaf's own arrays (gradient, parameter) and the codes, which
+    # rest in the tile view, enter the kernel, and how they leave it
+    if by_rows:
+        data = pl.BlockSpec(
+            (*(None,) * (n - 2), T * _SUBLANES, J * BLOCK), lambda *at: at
+        )
+        held = shape
+        leaf_in, codes_in, leaf_out, codes_out = (
+            as_is, as_rows, as_is, _to_tiles
+        )
+    else:
+        data = pl.BlockSpec(
+            (*map(blocked, range(n)), _SUBLANES, BLOCK),
+            lambda *at: (*at, 0, 0),
+        )
+        held = (*dims, _SUBLANES, BLOCK)
+        leaf_in, codes_in, leaf_out, codes_out = (
+            _to_tiles, as_is, as_rows, as_is
+        )
+    scale = pl.BlockSpec(
+        (*map(blocked, rest), _SUBLANES, T),
+        lambda *at: (*(at[k] for k in rest), 0, at[a]),
+    )
+    codes = jax.ShapeDtypeStruct(held, jnp.int8)
+    scales = jax.ShapeDtypeStruct(
+        (*(dims[k] for k in rest), _SUBLANES, dims[a]), jnp.float32
+    )
+    p_new, mc, ms, vc, vs = pl.pallas_call(
+        functools.partial(
+            _adam8_step_kernel, b1=b1, b2=b2, classic_eps=classic_eps,
+            by_rows=by_rows, lanes_first=a < b,
+        ),
+        grid=tuple(
+            pl.cdiv(dims[k], blocked(k) or 1) for k in range(n)
+        ),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            data, data, data, scale, data, scale,
+        ],
+        out_specs=[data, data, scale, data, scale],
+        out_shape=[
+            jax.ShapeDtypeStruct(held, jnp.float32),
+            codes, scales, codes, scales,
+        ],
+        input_output_aliases={2: 0, 3: 1, 4: 2, 5: 3, 6: 4},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * n,
+            vmem_limit_bytes=48 << 20,
+        ),
+        name="q8_adam_step",
+        interpret=interpret,
+    )(
+        scalars, leaf_in(g), leaf_in(p), codes_in(mc),
+        _scales_by_lane(ms, a), codes_in(vc), _scales_by_lane(vs, a),
+    )
+    return (
+        leaf_out(p_new), codes_out(mc), jnp.moveaxis(ms, -1, a),
+        codes_out(vc), jnp.moveaxis(vs, -1, a),
     )
 
 
@@ -685,7 +981,7 @@ def adamw_8bit(
             return False
         if use_pallas is not None:
             return use_pallas
-        return jax.default_backend() == "tpu"
+        return _on_tpu()
 
     def init_fn(params):
         # the Pallas tree kernel and the 4-bit update take [nblocks, BLOCK]
@@ -715,66 +1011,167 @@ def adamw_8bit(
             nu=jax.tree.map(_init_v, params),
         )
 
-    def update_fn(grads, state, params=None):
-        count = state.count + 1
+    def _bias_corrected(count):
         cf = count.astype(jnp.float32)
         lrA = jnp.asarray(learning_rate, jnp.float32) / (1.0 - b1**cf)
-        invbc2 = 1.0 / (1.0 - b2**cf)
-        scalars = jnp.stack([lrA, invbc2, jnp.float32(eps_val)])
+        return lrA, 1.0 / (1.0 - b2**cf)
 
-        def _one(g, m, v):
-            if not isinstance(m, (Quantized8, Quantized4)):
-                # small tensor: plain fp32 adam, same eps placement as
-                # the kernel so small and big leaves share semantics
-                m_new, v_new, delta = _adam8_block_math(
-                    g, m, v, lrA, invbc2, eps_val, b1, b2, classic
-                )
-                return delta.astype(g.dtype), m_new, v_new
-            # the gradient in the moments' own view, delta back through
-            # its inverse. For TILES both are bitcasts on the TPU: decay,
-            # later scales and apply_updates fuse onto delta, which then
-            # never exists in HBM; _to_blocks moves every byte, twice
-            tiles = isinstance(m, Quantized8) and m.layout == TILES
-            g32 = g.astype(jnp.float32)
-            g_view = _to_tiles(g32) if tiles else _to_blocks(g32)
-            if isinstance(m, Quantized4):
-                mq, vq, delta = _adam4_update_jnp(
-                    g_view, m, v, scalars, b1, b2, classic
-                )
-            elif _pallas_enabled() and not tiles:
-                mq, vq, delta = _adam8_update_pallas(
-                    g_view, m, v, scalars, b1, b2, interpret=False,
-                    classic_eps=classic,
-                )
-            else:
-                mq, vq, delta = _adam8_update_jnp(
-                    g_view, m, v, scalars, b1, b2, classic
-                )
-            if tiles:
-                delta = _from_tiles(delta, g.shape)
-            else:
-                delta = _from_blocks(delta, g.shape)
-            return delta.astype(g.dtype), mq, vq
-
-        flat_g, treedef = jax.tree.flatten(grads)
-        flat_m = treedef.flatten_up_to(state.mu)
-        flat_v = treedef.flatten_up_to(state.nu)
-        results = [
-            _one(g, m, v) for g, m, v in zip(flat_g, flat_m, flat_v)
-        ]
-        updates = treedef.unflatten([r[0] for r in results])
-        mu = treedef.unflatten([r[1] for r in results])
-        nu = treedef.unflatten([r[2] for r in results])
-
-        if weight_decay and params is not None:
-            updates = jax.tree.map(
-                lambda u, p: u - learning_rate * weight_decay * p,
-                updates,
-                params,
+    def _one(g, m, v, lrA, invbc2):
+        """``(delta, m', v')`` of one leaf: the statement every path is
+        held to."""
+        if not isinstance(m, (Quantized8, Quantized4)):
+            # small tensor: plain fp32 adam, same eps placement as
+            # the kernel so small and big leaves share semantics
+            m_new, v_new, delta = _adam8_block_math(
+                g, m, v, lrA, invbc2, eps_val, b1, b2, classic
             )
+            return delta.astype(g.dtype), m_new, v_new
+        scalars = jnp.stack([lrA, invbc2, jnp.float32(eps_val)])
+        # the gradient in the moments' own view, delta back through
+        # its inverse. For TILES both are bitcasts on the TPU: decay,
+        # later scales and apply_updates fuse onto delta, which then
+        # never exists in HBM; _to_blocks moves every byte, twice
+        tiles = isinstance(m, Quantized8) and m.layout == TILES
+        g32 = g.astype(jnp.float32)
+        g_view = _to_tiles(g32) if tiles else _to_blocks(g32)
+        if isinstance(m, Quantized4):
+            mq, vq, delta = _adam4_update_jnp(
+                g_view, m, v, scalars, b1, b2, classic
+            )
+        elif _pallas_enabled() and not tiles:
+            mq, vq, delta = _adam8_update_pallas(
+                g_view, m, v, scalars, b1, b2, interpret=False,
+                classic_eps=classic,
+            )
+        else:
+            mq, vq, delta = _adam8_update_jnp(
+                g_view, m, v, scalars, b1, b2, classic
+            )
+        if tiles:
+            delta = _from_tiles(delta, g.shape)
+        else:
+            delta = _from_blocks(delta, g.shape)
+        return delta.astype(g.dtype), mq, vq
+
+    def _decayed(u, p):
+        return u - learning_rate * weight_decay * p
+
+    def _leaves(grads, state):
+        flat_g, treedef = jax.tree.flatten(grads)
+        return (
+            treedef, flat_g, treedef.flatten_up_to(state.mu),
+            treedef.flatten_up_to(state.nu),
+        )
+
+    def update_fn(grads, state, params=None):
+        count = state.count + 1
+        lrA, invbc2 = _bias_corrected(count)
+        treedef, flat_g, flat_m, flat_v = _leaves(grads, state)
+        results = [
+            _one(g, m, v, lrA, invbc2)
+            for g, m, v in zip(flat_g, flat_m, flat_v)
+        ]
+        updates, mu, nu = (
+            treedef.unflatten([r[i] for r in results]) for i in range(3)
+        )
+        if weight_decay and params is not None:
+            updates = jax.tree.map(_decayed, updates, params)
         return updates, Adam8State(count=count, mu=mu, nu=nu)
 
-    return optax.GradientTransformation(init_fn, update_fn)
+    def update_and_apply_fn(grads, state, params, scale=None):
+        """``(new_params, new_state)``: what ``update``, ``scale`` times
+        the whole update (decay included, as ``optax.scale`` after this
+        transformation) and ``optax.apply_updates`` make, leaf by leaf.
+        A ``TILES`` leaf on a TPU takes the one-pass kernel, which writes
+        the parameter and the moments where they lie; any other leaf and
+        any other backend the statement, as ``update`` does."""
+        from dlrover_tpu.common import trace_counts
+
+        count = state.count + 1
+        lrA, invbc2 = _bias_corrected(count)
+        scalars = jnp.stack([
+            lrA, invbc2, jnp.float32(eps_val),
+            jnp.asarray(learning_rate * weight_decay, jnp.float32),
+            jnp.asarray(1.0 if scale is None else scale, jnp.float32),
+        ])
+
+        def _step(g, p, m, v):
+            if takes_kernel(p, m):
+                # both moments, as ``int8_moments_on`` counts them
+                trace_counts.count("opt_q8_kernel_elems", 2 * p.size)
+                p_new, mc, ms, vc, vs = _q8_adam_step(
+                    scalars, g, p, m.codes, m.scales, v.codes, v.scales,
+                    b1=b1, b2=b2, classic_eps=classic,
+                    interpret=_interpret(),
+                )
+                return (
+                    p_new,
+                    Quantized8(mc, ms, m.shape, True, TILES),
+                    Quantized8(vc, vs, v.shape, False, TILES),
+                )
+            u, m, v = _one(g, m, v, lrA, invbc2)
+            if weight_decay:
+                u = _decayed(u, p)
+            if scale is not None:
+                u = scale * u
+            return optax.apply_updates(p, u), m, v
+
+        treedef, flat_g, flat_m, flat_v = _leaves(grads, state)
+        results = [
+            _step(g, p, m, v)
+            for g, p, m, v in zip(
+                flat_g, treedef.flatten_up_to(params), flat_m, flat_v
+            )
+        ]
+        new_params, mu, nu = (
+            treedef.unflatten([r[i] for r in results]) for i in range(3)
+        )
+        return new_params, Adam8State(count=count, mu=mu, nu=nu)
+
+    return InPlaceTransformation(init_fn, update_fn, update_and_apply_fn)
+
+
+def takes_kernel(p, m) -> bool:
+    """THE rule for how ``update_and_apply`` executes a leaf's step, read
+    from the leaf and the backend: the one-pass kernel where the moments
+    are ``Quantized8`` in ``TILES``, the parameter is float32 and the
+    backend is a TPU; the statement (``update`` and
+    ``optax.apply_updates``) everywhere else. Whether one device owns the
+    program is the caller's to know (``in_place_entry``): GSPMD refuses to
+    partition a Mosaic call."""
+    return (
+        isinstance(m, Quantized8)
+        and m.layout == TILES
+        and p.dtype == jnp.float32
+        and _on_tpu()
+    )
+
+
+class InPlaceTransformation(optax.GradientTransformationExtraArgs):
+    """``init`` and ``update`` as optax has them (still the pair every
+    optax caller unpacks), and one more entry, ``update_and_apply(grads,
+    state, params) -> (new_params, new_state)``: what ``update`` and
+    ``optax.apply_updates`` make, for a transformation that can write a
+    parameter where it lies instead of handing back an update for XLA to
+    apply (``adamw_8bit``'s one-pass kernel)."""
+
+    def __new__(cls, init, update, update_and_apply):
+        self = super().__new__(cls, init, update)
+        self.update_and_apply = update_and_apply
+        return self
+
+
+def in_place_entry(tx, *, devices: int, donate: bool, resident: bool = True):
+    """``tx.update_and_apply`` where a step may call it in place of
+    ``tx.update`` and ``optax.apply_updates``, else None: the
+    transformation has the entry, one device owns the program, and the
+    state is on it and is the step's to overwrite. A step that does not
+    donate keeps the two lines: in place there means a copy of every leaf
+    first (1.1 to 3.0 GiB more in the compile of the eight int8 steps,
+    PERF.md §6, PR 60)."""
+    if devices == 1 and donate and resident:
+        return getattr(tx, "update_and_apply", None)
+    return None
 
 
 class Adam8FlatState(NamedTuple):
@@ -908,7 +1305,7 @@ def adamw_8bit_flat(
     def _pallas_enabled():
         if use_pallas is not None:
             return use_pallas
-        return jax.default_backend() == "tpu"
+        return _on_tpu()
 
     def init_fn(params):
         leaves = jax.tree.flatten(params)[0]

@@ -1119,6 +1119,13 @@ def build_pipeline_train_step(
             )
             note_gspmd_fallback(sizes, reason=reason)
 
+    from dlrover_tpu.ops.quantized_optim import in_place_entry
+
+    # as ``models/train.build_train_step``: None on any mesh of stages
+    update_and_apply = in_place_entry(
+        tx, devices=mesh.size, donate=donate
+    )
+
     def train_step(state: TrainState, tokens, targets):
         gnorm = None
         if sync_plan is not None:
@@ -1146,8 +1153,15 @@ def build_pipeline_train_step(
                 )
 
             loss, grads = jax.value_and_grad(lf)(state.params)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        if update_and_apply is not None:
+            new_params, new_opt = update_and_apply(
+                grads, state.opt_state, state.params
+            )
+        else:
+            updates, new_opt = tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         return (
             TrainState(
                 step=state.step + 1, params=new_params, opt_state=new_opt

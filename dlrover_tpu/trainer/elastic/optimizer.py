@@ -106,6 +106,36 @@ def build_optimizer(
             )
         return optax.chain(opt, optax.scale(retune_scale))
 
-    return optax.inject_hyperparams(make)(
+    tx = optax.inject_hyperparams(make)(
         learning_rate=lr_fn, retune_scale=1.0
     )
+    if name != "adamw_8bit":
+        return tx
+    from dlrover_tpu.ops.quantized_optim import (
+        InPlaceTransformation,
+        adamw_8bit,
+    )
+
+    def make_in_place(learning_rate, retune_scale):
+        """``make``'s chain with ``update`` and ``optax.apply_updates`` as
+        one entry: what comes back in the updates' place is the new
+        parameters, ``retune_scale`` times the whole update inside the
+        entry as ``optax.scale`` after it. The state is the chain's."""
+        opt = adamw_8bit(learning_rate, weight_decay=weight_decay, **kwargs)
+
+        def update_and_apply(grads, state, params):
+            new_params, inner = opt.update_and_apply(
+                grads, state[0], params, scale=retune_scale
+            )
+            return new_params, (inner, *state[1:])
+
+        return optax.GradientTransformation(
+            make(learning_rate, retune_scale).init, update_and_apply
+        )
+
+    # a second ``inject_hyperparams`` over the same state: both knobs are
+    # read, and the schedule's count kept, by the code that ``update`` runs
+    in_place = optax.inject_hyperparams(make_in_place)(
+        learning_rate=lr_fn, retune_scale=1.0
+    )
+    return InPlaceTransformation(tx.init, tx.update, in_place.update)

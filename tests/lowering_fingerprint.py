@@ -75,6 +75,18 @@ pairs, the query scaled by its position) and left the nine entries before
 it as they were: ``q_latent_dim`` 0 is the whole ``wq``, and with no
 ``rope_scaling``, ``rope_pairs`` or ``attn_pos_scale_beta`` ``_rope`` makes
 ``theta``'s own table on rotate-half pairs from the same operations.
+ISSUE 61 recorded the steps of the eight configurations that train with
+``adamw_8bit`` anew (and the two ``step_without_names`` beside them, which
+are the same steps with the delta rule's names taken off), their trees as
+they were, and the state's too: ``build_train_step`` calls the
+transformation's second entry (``InPlaceTransformation.update_and_apply``)
+where one device owns a donating step, which on the CPU is ``update``, the
+scale and ``apply_updates`` leaf by leaf in place of tree by tree, no
+kernel (a ``TILES`` leaf's one-pass kernel ``_q8_adam_step`` is a TPU's),
+and the shared ``_sqrt_map_quant`` / ``_sqrt_map_dequant`` make the same
+codes and values from fewer operations.
+The two GPT-2 entries (fp32 ``adamw``: no second entry, the two lines the
+step had) are byte for byte what they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py

@@ -166,7 +166,8 @@ AS_DICT_KEYS = [
     "moe_drop_rate_sum",
     "moe_held_share_sum", "moe_max_load_sum", "moe_reports",
     "moe_share_kept_sites", "opt_q8_blocks_elems",
-    "opt_q8_tiles_elems", "overlap_pct_measured", "prefetch_hits",
+    "opt_q8_kernel_elems", "opt_q8_tiles_elems", "overlap_pct_measured",
+    "prefetch_hits",
     "prefetch_misses", "prefetch_overlap_pct", "prefetch_reprimes",
     "prefetch_wait_s", "recover_detect_tick_s", "recover_persist_s",
     "recover_respawn_s", "reshard_bytes_device",

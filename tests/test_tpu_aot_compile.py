@@ -727,6 +727,64 @@ def test_adam8_tile_view_stays_a_bitcast(shape, one_chip):
     assert len(leaf_f32) == 1, results
 
 
+# leaves of the benchmark's int8 configurations: the experts (rows of
+# tiles along the scales' lanes), a head 21 blocks wide of rows no whole
+# lane row, the head whose blocks lie along the lanes, a leaf of many
+# single tiles (the leading dimension along the lanes), and a wide one
+ONE_PASS_LEAVES = [
+    (64, 2048, 1024), (3712, 2688), (2688, 16384), (2048, 16, 128),
+    (2048, 50304),
+]
+
+
+@pytest.mark.parametrize("shape", ONE_PASS_LEAVES, ids=str)
+def test_adam8_one_pass_step_compiles_in_place(shape, one_chip, monkeypatch):
+    """``adamw_8bit(use_pallas=False).update_and_apply`` on a whole-tile
+    leaf for the chip: one ``q8_adam_step`` call, the parameter, codes and
+    scales aliased onto its results (the program needs no temporary of
+    the leaf's size), nothing of the leaf's size moved around it and the
+    scales, which the kernel reads a tile a lane, not copied either: the
+    view is the order the chip keeps them in (``_lane_dim``). The
+    kernel's code is as long as one strip: no longer for a wider leaf."""
+    import math
+
+    from dlrover_tpu.ops import quantized_optim
+
+    monkeypatch.setattr(quantized_optim, "_on_tpu", lambda: True)
+    monkeypatch.setattr(quantized_optim, "_interpret", lambda: False)
+    tx = quantized_optim.adamw_8bit(
+        3e-4, weight_decay=0.1, min_quantized_size=4096, use_pallas=False
+    )
+    params = {"w": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)}
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(tx.init, params),
+    )
+
+    def step(p, g, st):
+        return tx.update_and_apply(g, st, p, scale=jnp.float32(0.5))
+
+    lowered = jax.jit(step, donate_argnums=(0, 2)).lower(
+        params, params, state
+    )
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "q8_adam_step" in text
+    n = math.prod(shape)
+    moved = [
+        (op, arrays) for op, arrays in _entry_results(text)
+        if op in ("reshape", "copy", "transpose", "fusion")
+        and any(count >= n // 128 for _, count in arrays)
+    ]
+    assert not moved, moved
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < n // 128
+    # parameter (4 B), two moments' codes (1 B each) and their scales
+    assert memory.alias_size_in_bytes >= 6 * n
+    assert memory.generated_code_size_in_bytes < 256 << 10
+
+
 @pytest.mark.parametrize("dim", [64, 128])
 @pytest.mark.parametrize("op", ["gather", "scatter"])
 def test_device_tier_kernel_compiles(op, dim, one_chip, monkeypatch):

@@ -17,7 +17,6 @@ from dlrover_tpu.ops.optimizers import agd, make_wsam_grad_fn  # noqa: F401
 from dlrover_tpu.ops.quantized_optim import (  # noqa: F401
     adamw_4bit,
     adamw_8bit,
-    adamw_8bit_flat,
     dequantize_4bit,
     dequantize_8bit,
     quantize_4bit,

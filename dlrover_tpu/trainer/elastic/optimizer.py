@@ -62,14 +62,21 @@ def build_optimizer(
     else:
         raise ValueError(f"unknown lr schedule {schedule!r}")
 
-    if name not in (
-        "adamw", "adam", "sgd", "agd", "adamw_8bit", "adamw_8bit_flat"
-    ):
+    if name not in ("adamw", "adam", "sgd", "agd", "adamw_8bit"):
         raise ValueError(f"unknown optimizer {name!r}")
+    # ``use_pallas`` is retired: ``adamw_8bit`` reads its layout and its
+    # step from the leaf and the backend. The benchmark's configuration
+    # files still write ``"use_pallas": false``; this handling goes with
+    # the ``benchmark`` PR that removes that line (ROADMAP.md, Design 7(b))
+    if name == "adamw_8bit" and kwargs.pop("use_pallas", False):
+        raise ValueError(
+            "use_pallas is retired and selects nothing: adamw_8bit reads "
+            "its layout and its step from the leaf and the backend"
+        )
 
     def make(learning_rate, retune_scale):
         # weight_decay applies to EVERY optimizer: decoupled (after the
-        # adaptive direction) for adamw/adam/agd/8bit, classic
+        # adaptive direction) for adamw/adam/agd/adamw_8bit, classic
         # L2-into-update for sgd. add_decayed_weights(0.0) is a no-op.
         if name == "adamw":
             opt = optax.adamw(
@@ -91,12 +98,6 @@ def build_optimizer(
             from dlrover_tpu.ops.quantized_optim import adamw_8bit
 
             opt = adamw_8bit(
-                learning_rate, weight_decay=weight_decay, **kwargs
-            )
-        elif name == "adamw_8bit_flat":
-            from dlrover_tpu.ops.quantized_optim import adamw_8bit_flat
-
-            opt = adamw_8bit_flat(
                 learning_rate, weight_decay=weight_decay, **kwargs
             )
         else:

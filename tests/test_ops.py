@@ -28,7 +28,6 @@ fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
 from dlrover_tpu.ops.optimizers import apply_wsam_sharpness
 from dlrover_tpu.ops.quantized_optim import (
     _adam8_update_jnp,
-    _adam8_update_pallas,
     _to_blocks,
 )
 from trace_counted import FUSED, STREAM, added
@@ -911,34 +910,6 @@ class TestQuantizedOptim:
         assert isinstance(st.mu["small"], jnp.ndarray)
         assert not isinstance(st.mu["big"], jnp.ndarray)
 
-    def test_pallas_matches_jnp_path(self):
-        rng = np.random.default_rng(2)
-        g = _to_blocks(
-            jnp.asarray(rng.normal(size=(4096,)), jnp.float32)
-        )
-        mq = quantize_8bit(
-            jnp.asarray(rng.normal(size=(4096,)) * 0.01, jnp.float32), True
-        )
-        vq = quantize_8bit(
-            jnp.asarray(
-                np.abs(rng.normal(size=(4096,))) * 1e-3, jnp.float32
-            ),
-            False,
-        )
-        # new scalar layout: [lrA = lr/bc1, invbc2 = 1/bc2, eps_root]
-        sc = jnp.stack(
-            [
-                jnp.float32(1e-2 / 0.9),
-                jnp.float32(1.0 / 0.99),
-                jnp.float32(1e-8),
-            ]
-        )
-        a = _adam8_update_pallas(g, mq, vq, sc, 0.9, 0.999, interpret=True)
-        b = _adam8_update_jnp(g, mq, vq, sc, 0.9, 0.999)
-        assert bool((a[0].codes == b[0].codes).all())
-        assert bool((a[1].codes == b[1].codes).all())
-        np.testing.assert_allclose(a[2], b[2], atol=1e-7)
-
     def test_update_is_jittable(self):
         p = {"w": jnp.zeros((8192,))}
         tx = adamw_8bit(1e-3)
@@ -951,96 +922,12 @@ class TestQuantizedOptim:
         u, st2 = step({"w": jnp.ones((8192,))}, st, p)
         assert u["w"].shape == (8192,)
 
-    def test_flat_matches_tree_form(self):
-        """adamw_8bit_flat must produce the SAME trajectory as the
-        per-leaf adamw_8bit (leaves padded to BLOCK boundaries inside
-        the flat buffer → identical quantization blocks), across a
-        mixed pytree of big (quantized) and small (fp32) leaves."""
-        from dlrover_tpu.ops.quantized_optim import adamw_8bit_flat
-
-        rng = np.random.default_rng(3)
-        # 5000 is deliberately NOT a multiple of 128: exercises the
-        # per-leaf padding inside the flat buffer
-        p_tree = {
-            "a": jnp.asarray(rng.normal(size=(5000,)), jnp.float32),
-            "b": jnp.asarray(rng.normal(size=(64, 128)), jnp.float32),
-            "norm": jnp.asarray(rng.normal(size=(32,)), jnp.float32),
-        }
-        p_flat = jax.tree.map(lambda x: x, p_tree)
-        txt = adamw_8bit(1e-2, weight_decay=0.01, use_pallas=False)
-        # group_elems=6000 forces the two big leaves into SEPARATE
-        # groups — exercises the multi-group packing path
-        txf = adamw_8bit_flat(
-            1e-2, weight_decay=0.01, use_pallas=False, group_elems=6000
-        )
-        st, sf = txt.init(p_tree), txf.init(p_flat)
-
-        def loss(p):
-            return (
-                jnp.sum((p["a"] - 1.0) ** 2)
-                + jnp.sum(p["b"] ** 2)
-                + jnp.sum((p["norm"] - 0.5) ** 2)
-            )
-
-        for _ in range(20):
-            ut, st = txt.update(jax.grad(loss)(p_tree), st, p_tree)
-            p_tree = optax.apply_updates(p_tree, ut)
-            uf, sf = txf.update(jax.grad(loss)(p_flat), sf, p_flat)
-            p_flat = optax.apply_updates(p_flat, uf)
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6
-            ),
-            p_tree,
-            p_flat,
-        )
-
-    def test_flat_groups_are_dtype_homogeneous(self):
-        """A mixed f32/bf16 tree must not round f32 grads through a
-        bf16 group buffer — flat and tree trajectories stay identical
-        per-leaf (code-review r4 finding)."""
-        from dlrover_tpu.ops.quantized_optim import adamw_8bit_flat
-
-        rng = np.random.default_rng(7)
-        p_tree = {
-            "a": jnp.asarray(rng.normal(size=(8192,)), jnp.bfloat16),
-            "b": jnp.asarray(rng.normal(size=(8192,)), jnp.float32),
-        }
-        p_flat = jax.tree.map(lambda x: x, p_tree)
-        txt = adamw_8bit(1e-2, use_pallas=False)
-        txf = adamw_8bit_flat(1e-2, use_pallas=False)
-        st, sf = txt.init(p_tree), txf.init(p_flat)
-        assert len(sf.mu) == 2  # one group per dtype
-
-        def loss(p):
-            return sum(
-                jnp.sum((x.astype(jnp.float32) - 1.0) ** 2)
-                for x in jax.tree.leaves(p)
-            )
-
-        for _ in range(5):
-            ut, st = txt.update(jax.grad(loss)(p_tree), st, p_tree)
-            p_tree = optax.apply_updates(p_tree, ut)
-            uf, sf = txf.update(jax.grad(loss)(p_flat), sf, p_flat)
-            p_flat = optax.apply_updates(p_flat, uf)
-        jax.tree_util.tree_map(
-            lambda a, b: np.testing.assert_array_equal(
-                np.asarray(a), np.asarray(b)
-            ),
-            p_tree,
-            p_flat,
-        )
-
     def test_eps_conventions(self):
         """eps (classic, outside sqrt) must track optax.adamw exactly on
         fp32 leaves; eps_root is the optax eps_root convention; both at
         once is an error."""
-        from dlrover_tpu.ops.quantized_optim import adamw_8bit_flat
-
         with pytest.raises(ValueError, match="either eps"):
             adamw_8bit(eps=1e-8, eps_root=1e-8)
-        with pytest.raises(ValueError, match="either eps"):
-            adamw_8bit_flat(eps=1e-8, eps_root=1e-8)
         # small (fp32) leaves use the shared math: classic eps must
         # reproduce optax.adamw bit-for-bit over several steps
         p8 = {"w": jnp.asarray(np.linspace(-1, 1, 64), jnp.float32)}
@@ -1057,118 +944,6 @@ class TestQuantizedOptim:
         np.testing.assert_allclose(
             np.asarray(p8["w"]), np.asarray(pf["w"]), rtol=1e-6
         )
-
-    def test_flat_rejected_on_sharded_strategy(self):
-        """The trainer refuses adamw_8bit_flat with model-sharded
-        meshes (it would silently defeat ZeRO/TP sharding)."""
-        from dlrover_tpu.accel.strategy import Strategy
-        from dlrover_tpu.ops.quantized_optim import adamw_8bit_flat
-        from dlrover_tpu.parallel.mesh import MeshConfig
-        from dlrover_tpu.trainer.elastic.trainer import (
-            ElasticTrainer,
-            TrainerConfig,
-        )
-
-        class _Toks:
-            def __len__(self):
-                return 16
-
-            def __getitem__(self, i):
-                z = np.zeros(33, np.int32)
-                return {"x": z[:-1], "y": z[1:]}
-
-        from dlrover_tpu.models import tiny
-
-        with pytest.raises(ValueError, match="adamw_8bit_flat"):
-            ElasticTrainer(
-                model_cfg=tiny(),
-                tx=adamw_8bit_flat(1e-3),
-                dataset=_Toks(),
-                trainer_cfg=TrainerConfig(
-                    batch_size=8, seq_len=32, report_metrics=False
-                ),
-                strategy=Strategy(
-                    mesh=MeshConfig(fsdp=8), dtype="float32"
-                ),
-            )
-
-    def test_flat_pallas_kernel_matches_jnp(self):
-        """The aliased one-pass flat kernel (interpret mode) must agree
-        with the jnp math bit-for-bit on codes."""
-        from dlrover_tpu.ops.quantized_optim import (
-            _FLAT_ROWS,
-            Quantized8,
-            _adam8_update_jnp,
-            _adam8_update_pallas_flat,
-            _quant_block_math_wide,
-        )
-
-        rng = np.random.default_rng(5)
-        n = 2 * _FLAT_ROWS * 128  # exactly 2 grid chunks, as the packer emits
-        g = _to_blocks(jnp.asarray(rng.normal(size=(n,)), jnp.float32))
-
-        def wideq(x, signed):
-            c, s = _quant_block_math_wide(_to_blocks(x), signed)
-            return Quantized8(c, s, (n,), signed)
-
-        mq = wideq(
-            jnp.asarray(rng.normal(size=(n,)) * 0.01, jnp.float32), True
-        )
-        vq = wideq(
-            jnp.asarray(np.abs(rng.normal(size=(n,))) * 1e-3, jnp.float32),
-            False,
-        )
-        # new scalar layout: [lrA = lr/bc1, invbc2 = 1/bc2, eps_root]
-        sc = jnp.stack(
-            [
-                jnp.float32(1e-2 / 0.9),
-                jnp.float32(1.0 / 0.99),
-                jnp.float32(1e-8),
-            ]
-        )
-        a = _adam8_update_pallas_flat(
-            g, mq, vq, sc, 0.9, 0.999, interpret=True
-        )
-        b = _adam8_update_jnp(g, mq, vq, sc, 0.9, 0.999)
-        # codes may differ by +-1 on exact rounding-boundary ties
-        # (compiler fp ordering); anything more is a real math bug
-        for x, y in ((a[0], b[0]), (a[1], b[1])):
-            d = np.abs(
-                np.asarray(x.codes, np.int32) - np.asarray(y.codes, np.int32)
-            )
-            assert d.max() <= 1 and (d > 0).mean() < 1e-4, (
-                d.max(), (d > 0).mean(),
-            )
-        np.testing.assert_allclose(a[2], b[2], atol=1e-6)
-
-    def test_flat_is_jittable_and_compact(self):
-        """The flat state is ONE quantized buffer pair + one small f32
-        pair regardless of leaf count, and updates under jit."""
-        from dlrover_tpu.ops.quantized_optim import (
-            Adam8FlatState,
-            adamw_8bit_flat,
-        )
-
-        p = {f"w{i}": jnp.zeros((8192,)) for i in range(6)}
-        p["tiny"] = jnp.zeros((8,))
-        tx = adamw_8bit_flat(1e-3, use_pallas=False)
-        st = tx.init(p)
-        assert isinstance(st, Adam8FlatState)
-        # all six big leaves land in ONE group (<< group_elems),
-        # padded up to one BLOCK*_FLAT_ROWS grid chunk
-        assert len(st.mu) == 1
-        assert st.mu[0].codes.shape[0] * 128 == 2048 * 128
-        assert st.mu_small.shape == (8,)
-
-        @jax.jit
-        def step(g, st, p):
-            return tx.update(g, st, p)
-
-        g = jax.tree.map(jnp.ones_like, p)
-        u, st2 = step(g, st, p)
-        assert u["w0"].shape == (8192,)
-        assert u["tiny"].shape == (8,)
-        assert int(st2.count) == 1
 
     @pytest.mark.parametrize(
         "shape", [(16, 256), (4, 8, 384), (6288, 256)], ids=str
@@ -1196,7 +971,7 @@ class TestQuantizedOptim:
 
         tx = adamw_8bit(
             lr, b1=b1, b2=b2, eps=eps, weight_decay=wd,
-            min_quantized_size=0, use_pallas=False,
+            min_quantized_size=0,
         )
         st = tx.init({"w": p0})
         assert st.mu["w"].layout == st.nu["w"].layout == TILES
@@ -1264,7 +1039,7 @@ class TestQuantizedOptim:
         rng = np.random.default_rng(8)
         g = jnp.asarray(rng.normal(size=shape), jnp.float32)
         p = jnp.asarray(rng.normal(size=shape), jnp.float32)
-        tx = adamw_8bit(1e-2, min_quantized_size=0, use_pallas=False)
+        tx = adamw_8bit(1e-2, min_quantized_size=0)
         st = tx.init({"w": p})
         for q in (st.mu["w"], st.nu["w"]):
             assert q.layout == BLOCKS
@@ -1312,7 +1087,7 @@ class TestQuantizedOptim:
             back, dequantize_8bit(quantize_8bit(x, True, BLOCKS))
         )
 
-    @pytest.mark.parametrize("make", ["tree", "pallas", "flat", "4bit"])
+    @pytest.mark.parametrize("make", ["8bit", "4bit"])
     def test_no_block_crosses_a_row(self, make):
         """A head ``[d, vocab]`` whose vocabulary is one and a half lane
         tiles wide, its first ids frequent and its last ones rare: were
@@ -1322,12 +1097,7 @@ class TestQuantizedOptim:
         zero under the block's scale while their first moments did not,
         and their update would be lr * m / eps (the Trinity-Mini cell at
         25,024 rows lost its loss so: PERF.md, Findings PR 50)."""
-        from dlrover_tpu.ops.quantized_optim import (
-            _adam8_update_pallas,
-            _from_blocks,
-            adamw_4bit,
-            adamw_8bit_flat,
-        )
+        from dlrover_tpu.ops.quantized_optim import _from_blocks, adamw_4bit
 
         shape, lr = (64, 192), 1e-3
         rng = np.random.default_rng(11)
@@ -1342,30 +1112,14 @@ class TestQuantizedOptim:
         blocks = np.asarray(_to_blocks(grads[0])).reshape(64, 2, 128)
         assert (np.abs(blocks[:, 1, :64]).max(-1) < 1e-3).all()
         assert (blocks[:, 1, 64:] == 0).all()
-        if make == "pallas":
-            q0 = quantize_8bit(jnp.zeros(shape), True)
-            mq, vq = q0, quantize_8bit(jnp.zeros(shape), False)
-            for i, g in enumerate(grads, 1):
-                sc = jnp.asarray(
-                    [lr / (1 - 0.9**i), 1 / (1 - 0.999**i), 1e-8],
-                    jnp.float32,
-                )
-                mq, vq, delta = _adam8_update_pallas(
-                    _to_blocks(g), mq, vq, sc, 0.9, 0.999, interpret=True
-                )
-            u = _from_blocks(delta, shape)
-        else:
-            tx = {
-                "tree": adamw_8bit, "flat": adamw_8bit_flat,
-                "4bit": adamw_4bit,
-            }[make](
-                learning_rate=lr, min_quantized_size=4096, use_pallas=False
-            )
-            p = {"head": jnp.zeros(shape, jnp.float32)}
-            st = tx.init(p)
-            for g in grads:
-                u, st = tx.update({"head": g}, st, p)
-            u = u["head"]
+        tx = {"8bit": adamw_8bit, "4bit": adamw_4bit}[make](
+            learning_rate=lr, min_quantized_size=4096
+        )
+        p = {"head": jnp.zeros(shape, jnp.float32)}
+        st = tx.init(p)
+        for g in grads:
+            u, st = tx.update({"head": g}, st, p)
+        u = u["head"]
         assert u.shape == shape
         # Adam moves no entry much further than lr a step; m / eps is 1e4 lr
         assert float(jnp.abs(u).max()) < 20 * lr

@@ -137,7 +137,7 @@ def test_kernel_is_update_then_apply(
     eps = {"eps": 1e-8} if classic_eps else {"eps": 0.0, "eps_root": 1e-12}
     tx = q8.adamw_8bit(
         1e-2, weight_decay=decay, min_quantized_size=1024,
-        use_pallas=False, **eps,
+        **eps,
     )
     params = {"w": 0.05 * _leaf(shape)}
     before = trace_counts.snapshot()
@@ -175,7 +175,7 @@ def test_kernel_at_the_edges_of_its_blocks(
     monkeypatch.setattr(q8, "_STRIP", strip)
     q8._q8_adam_step.clear_cache()
     tx = q8.adamw_8bit(
-        1e-2, weight_decay=0.1, min_quantized_size=1024, use_pallas=False
+        1e-2, weight_decay=0.1, min_quantized_size=1024
     )
     params = {"w": 0.05 * _leaf(shape)}
     grad = jnp.bfloat16 if len(shape) == 3 else jnp.float32
@@ -211,8 +211,7 @@ def test_blocking_is_one_rule_for_every_width():
 def test_kernel_writes_parameter_and_moments_in_place(on_tpu):
     """One Pallas call a leaf, its parameter, codes and scales aliased
     onto its results: nothing new is held."""
-    tx = q8.adamw_8bit(1e-3, weight_decay=0.1, min_quantized_size=1024,
-                       use_pallas=False)
+    tx = q8.adamw_8bit(1e-3, weight_decay=0.1, min_quantized_size=1024)
     params = {"a": _leaf((64, 256)), "b": _leaf((2, 32, 128))}
     calls = _calls(
         lambda g, st, p: tx.update_and_apply(g, st, p, scale=0.5),
@@ -253,8 +252,9 @@ def test_other_leaves_are_left_bit_identical(bits, on_tpu):
     ``bits=4`` state keep the statement inside ``update_and_apply``, bit
     for bit what ``update`` + ``apply_updates`` make of them, and the
     counter counts what took the kernel."""
-    tx = q8.adamw_8bit(1e-2, weight_decay=0.1, min_quantized_size=1024,
-                       use_pallas=False, bits=bits)
+    tx = q8.adamw_8bit(
+        1e-2, weight_decay=0.1, min_quantized_size=1024, bits=bits
+    )
     params = _mixed_tree()
     if bits == 8:
         del params["tiles"]
@@ -305,8 +305,7 @@ def test_another_backend_takes_the_statement():
     """Off the TPU no leaf takes the kernel: ``update_and_apply`` is
     ``update`` + ``apply_updates`` leaf by leaf, and counts nothing."""
     assert jax.default_backend() != "tpu"
-    tx = q8.adamw_8bit(1e-2, weight_decay=0.1, min_quantized_size=1024,
-                       use_pallas=False)
+    tx = q8.adamw_8bit(1e-2, weight_decay=0.1, min_quantized_size=1024)
     params = _mixed_tree()
     before = trace_counts.snapshot()
     assert not _calls(tx.update_and_apply, params, tx.init(params), params)
@@ -316,6 +315,80 @@ def test_another_backend_takes_the_statement():
     )
 
 
+@pytest.mark.parametrize(
+    "shape, bits, want",
+    [
+        ((256, 256), 8, q8.TILES), ((4, 64, 256), 8, q8.TILES),
+        ((48, 100), 8, q8.BLOCKS), ((8192,), 8, q8.BLOCKS),
+        ((256, 256), 4, q8.BLOCKS),
+    ],
+    ids=str,
+)
+def test_layout_follows_the_leaf_on_a_tpu_too(shape, bits, want, on_tpu):
+    """``adamw_8bit()`` as a caller writes it: on a TPU as anywhere the
+    moments of a leaf lie where ``_layout_for`` says, from its shape alone
+    (whole (8, 128) tiles in ``TILES``: no backend makes the leaf's layout
+    another); the 4-bit state, whose update reads rows, keeps ``BLOCKS``."""
+    state = q8.adamw_8bit(bits=bits).init({"w": jnp.zeros(shape)})
+    assert state.nu["w"].layout == want
+    if bits == 8:
+        assert state.mu["w"].layout == want == q8._layout_for(shape)
+    else:
+        assert isinstance(state.mu["w"], q8.Quantized4)
+
+
+@pytest.mark.parametrize(
+    "shape, dtype, tpu, want",
+    [
+        ((32, 256), "float32", True, True),
+        ((32, 256), "bfloat16", True, False),  # the kernel writes float32
+        ((32, 200), "float32", True, False),  # BLOCKS
+        ((32, 256), "float32", False, False),
+    ],
+    ids=str,
+)
+def test_the_rule_reads_the_leaf_and_the_backend(
+    shape, dtype, tpu, want, monkeypatch
+):
+    monkeypatch.setattr(q8, "_on_tpu", lambda: tpu)
+    p = jnp.zeros(shape, dtype)
+    m = q8.adamw_8bit(min_quantized_size=1024).init({"w": p}).mu["w"]
+    assert q8.takes_kernel(p, m) is want
+
+
+def test_a_4bit_state_never_takes_the_kernel(on_tpu):
+    """``bits=4`` on a TPU, a whole-tile float32 leaf: the rule answers no
+    for the nibble-packed first moment, and ``update_and_apply`` is the
+    statement, bit for bit."""
+    tx = q8.adamw_4bit(learning_rate=1e-2, min_quantized_size=1024)
+    params = {"w": _leaf((32, 256))}
+    state = _random_state(tx, params)
+    assert not q8.takes_kernel(params["w"], state.mu["w"])
+    assert not q8.takes_kernel(params["w"], state.nu["w"])  # BLOCKS
+    assert not _calls(tx.update_and_apply, params, state, params)
+    g = jax.tree.map(jnp.sin, params)
+    u, want_st = jax.jit(tx.update)(g, state, params)
+    got_p, got_st = jax.jit(tx.update_and_apply)(g, state, params)
+    _bit_identical((got_p, got_st), (optax.apply_updates(params, u), want_st))
+
+
+@pytest.mark.parametrize("mesh", [{"dp": 8}, {"fsdp": 8}], ids=str)
+def test_moments_are_counted_by_layout_on_any_mesh(mesh):
+    """``int8_moments_on``: both moments of every ``Quantized8`` leaf by
+    its layout tag, whole leaves whatever the mesh shards; fp32 moments
+    count for nothing."""
+    from dlrover_tpu.parallel.mesh import MeshConfig
+
+    params = _mixed_tree()
+    state = q8.adamw_8bit(min_quantized_size=1024).init(params)
+    assert q8.int8_moments_on(state, MeshConfig(**mesh)) == (
+        2 * params["tiles"].size,
+        2 * (params["blocks_odd_width"].size + params["blocks_1d"].size),
+    )
+    fp32 = optax.adamw(1e-3).init(params)
+    assert q8.int8_moments_on(fp32, MeshConfig(**mesh)) == (0, 0)
+
+
 def test_state_at_rest_is_the_parents_and_round_trips(on_tpu):
     """The kernel hands back codes ``[..., R/8, C/128, 8, 128]`` and scales
     ``[..., R/8, C/128, 8]``, the layout the statement keeps: a checkpoint
@@ -323,8 +396,7 @@ def test_state_at_rest_is_the_parents_and_round_trips(on_tpu):
     from it as the statement does (and the other way round)."""
     from dlrover_tpu.ckpt.sharding import host_shard_records, restore_state
 
-    tx = q8.adamw_8bit(1e-2, weight_decay=0.1, min_quantized_size=1024,
-                       use_pallas=False)
+    tx = q8.adamw_8bit(1e-2, weight_decay=0.1, min_quantized_size=1024)
     shape = (3, 16, 384)
     params = {"w": 0.05 * _leaf(shape), "b": _leaf((256,))}
     fused = jax.jit(tx.update_and_apply)
@@ -370,7 +442,6 @@ def test_built_optimizer_reads_its_knobs_as_update_does(
     tx = build_optimizer(
         "adamw_8bit", lr=1e-2, schedule=schedule, warmup_steps=warmup,
         total_steps=50, weight_decay=0.1, min_quantized_size=1024,
-        use_pallas=False,
     )
     assert isinstance(tx, q8.InPlaceTransformation)
     init, update = tx  # still the pair optax unpacks
@@ -411,13 +482,37 @@ def test_built_optimizer_reads_its_knobs_as_update_does(
     assert float(jnp.abs(got_p["tiles"] - params["tiles"]).max()) > 0
 
 
-@pytest.mark.parametrize(
-    "name", ["adamw", "adam", "agd", "sgd", "adamw_8bit_flat"]
-)
+@pytest.mark.parametrize("name", ["adamw", "adam", "agd", "sgd"])
 def test_other_optimizers_have_no_second_entry(name):
     tx = build_optimizer(name, lr=1e-3)
     assert not hasattr(tx, "update_and_apply")
     assert q8.in_place_entry(tx, devices=1, donate=True) is None
+
+
+def test_retired_key_is_dropped_where_false():
+    """The benchmark's configurations still write ``"use_pallas": false``:
+    ``build_optimizer`` builds what it builds without the key."""
+    params = _mixed_tree()
+    with_key, without = (
+        build_optimizer(
+            "adamw_8bit", lr=1e-2, min_quantized_size=1024, **kwargs
+        ).init(params)
+        for kwargs in ({"use_pallas": False}, {})
+    )
+    _bit_identical(with_key, without)  # the layout tags too: aux data
+
+
+@pytest.mark.parametrize(
+    "name, kwargs, message",
+    [
+        ("adamw_8bit", {"use_pallas": True}, "use_pallas is retired"),
+        ("adamw_8bit_flat", {}, "unknown optimizer 'adamw_8bit_flat'"),
+    ],
+    ids=["use_pallas", "adamw_8bit_flat"],
+)
+def test_what_went_is_refused_by_name(name, kwargs, message):
+    with pytest.raises(ValueError, match=message):
+        build_optimizer(name, lr=1e-3, **kwargs)
 
 
 def _tiny_step(tx, mesh_devices=1, **kwargs):
@@ -452,8 +547,7 @@ def test_train_step_calls_the_entry_where_it_is(on_tpu):
     ``apply_updates``, and both train the same model."""
     def make():
         return build_optimizer(
-            "adamw_8bit", lr=1e-2, weight_decay=0.1,
-            min_quantized_size=4096, use_pallas=False,
+            "adamw_8bit", lr=1e-2, weight_decay=0.1, min_quantized_size=4096
         )
 
     tx = make()
@@ -483,9 +577,7 @@ def test_train_step_keeps_its_two_lines(kwargs, on_tpu):
     the non-donating twin (in place would mean a copy of every leaf
     first) and a mesh of several devices (GSPMD refuses to partition a
     Mosaic call): the step holds no Pallas call of the update."""
-    tx = build_optimizer(
-        "adamw_8bit", lr=1e-2, min_quantized_size=4096, use_pallas=False
-    )
+    tx = build_optimizer("adamw_8bit", lr=1e-2, min_quantized_size=4096)
     step, state = _tiny_step(tx, **kwargs)
     x = jnp.zeros((2, 32), jnp.int32)
     before = trace_counts.snapshot()
@@ -528,7 +620,6 @@ def test_sites_of_one_shape_share_one_lowered_function(monkeypatch):
     )
     tx = build_optimizer(
         "adamw_8bit", lr=1e-3, weight_decay=0.01, min_quantized_size=4096,
-        use_pallas=False,
     )
     mesh = build_mesh(MeshConfig(), jax.devices()[:1])
 

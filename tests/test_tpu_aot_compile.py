@@ -641,11 +641,13 @@ ADAM_LEAVES = {
 
 
 @pytest.mark.parametrize("leaf", list(ADAM_LEAVES))
-@pytest.mark.parametrize("variant", ["adamw_8bit", "adamw_8bit_flat"])
-def test_adam8_kernel_compiles(leaf, variant, one_chip):
-    from dlrover_tpu.ops import quantized_optim
+def test_adam8_update_compiles(leaf, one_chip):
+    """``adamw_8bit``'s ``update``, the statement, for the chip: over a
+    ``BLOCKS`` leaf (50257 rows are no whole tiles) and a ``TILES`` one,
+    neither with a Pallas call."""
+    from dlrover_tpu.ops.quantized_optim import BLOCKS, TILES, adamw_8bit
 
-    tx = getattr(quantized_optim, variant)(1e-3, use_pallas=True)
+    tx = adamw_8bit(1e-3)
     params = {
         "w": jax.ShapeDtypeStruct(
             ADAM_LEAVES[leaf], jnp.float32, sharding=one_chip
@@ -655,7 +657,11 @@ def test_adam8_kernel_compiles(leaf, variant, one_chip):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         jax.eval_shape(tx.init, params),
     )
-    _compile_for_chip(tx.update, params, state, params)
+    want = {"gpt2_wte": BLOCKS, "gpt2_xl_mlp": TILES}[leaf]
+    assert state.mu["w"].layout == state.nu["w"].layout == want
+    lowered = jax.jit(tx.update).lower(params, state, params)
+    assert "tpu_custom_call" not in lowered.as_text()
+    assert lowered.compile().memory_analysis() is not None
 
 
 def _entry_results(hlo_text):
@@ -681,7 +687,7 @@ def _entry_results(hlo_text):
 
 @pytest.mark.parametrize("shape", [(8, 2048, 1024), (2048, 2048)], ids=str)
 def test_adam8_tile_view_stays_a_bitcast(shape, one_chip):
-    """``adamw_8bit(use_pallas=False)`` on a leaf of whole (8, 128)
+    """``adamw_8bit``'s ``update`` on a leaf of whole (8, 128)
     tiles: the moments' view of the gradient, and delta's way back, must
     cost nothing on the chip. No ``reshape`` / ``copy`` / ``transpose``
     of the leaf's size in the entry computation (the [nblocks, 128]
@@ -693,9 +699,7 @@ def test_adam8_tile_view_stays_a_bitcast(shape, one_chip):
 
     from dlrover_tpu.ops.quantized_optim import TILES, adamw_8bit
 
-    tx = adamw_8bit(
-        3e-4, weight_decay=0.1, min_quantized_size=4096, use_pallas=False
-    )
+    tx = adamw_8bit(3e-4, weight_decay=0.1, min_quantized_size=4096)
 
     def step(p, g, st):
         u, st = tx.update(g, st, p)
@@ -739,7 +743,7 @@ ONE_PASS_LEAVES = [
 
 @pytest.mark.parametrize("shape", ONE_PASS_LEAVES, ids=str)
 def test_adam8_one_pass_step_compiles_in_place(shape, one_chip, monkeypatch):
-    """``adamw_8bit(use_pallas=False).update_and_apply`` on a whole-tile
+    """``adamw_8bit``'s ``update_and_apply`` on a whole-tile
     leaf for the chip: one ``q8_adam_step`` call, the parameter, codes and
     scales aliased onto its results (the program needs no temporary of
     the leaf's size), nothing of the leaf's size moved around it and the
@@ -753,7 +757,7 @@ def test_adam8_one_pass_step_compiles_in_place(shape, one_chip, monkeypatch):
     monkeypatch.setattr(quantized_optim, "_on_tpu", lambda: True)
     monkeypatch.setattr(quantized_optim, "_interpret", lambda: False)
     tx = quantized_optim.adamw_8bit(
-        3e-4, weight_decay=0.1, min_quantized_size=4096, use_pallas=False
+        3e-4, weight_decay=0.1, min_quantized_size=4096
     )
     params = {"w": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)}
     state = jax.tree.map(

@@ -2,8 +2,8 @@
 one-pass kernel to the plain statement there.
 
 ``REPEATS`` chained steps (a step's parameter and moments are the next
-one's, donated) of ``adamw_8bit(use_pallas=False).update_and_apply`` on
-one float32 leaf with decay and a scale, host clock around ``block_until_ready``:
+one's, donated) of ``adamw_8bit``'s ``update_and_apply`` on one float32
+leaf with decay and a scale, host clock around ``block_until_ready``:
 milliseconds a step, and GB/s at the 14 bytes an element one pass needs
 (gradient read, parameter read and written, two codes read and written;
 the scales are 1/128 of that), to be read against the chip's 819 GB/s.
@@ -62,9 +62,7 @@ def _select(variant: str):
 
 
 def _program():
-    tx = q8.adamw_8bit(
-        3e-4, weight_decay=0.01, min_quantized_size=4096, use_pallas=False
-    )
+    tx = q8.adamw_8bit(3e-4, weight_decay=0.01, min_quantized_size=4096)
 
     def step(p, st, g):  # a new function a variant: jit keeps what it traced
         return tx.update_and_apply(g, st, p, scale=jnp.float32(0.9))

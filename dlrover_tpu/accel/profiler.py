@@ -211,6 +211,13 @@ class PipelineStats:
     # twice in both; 0 / 0 for a model without them
     conv_sites: int = 0
     conv_kernel_sites: int = 0
+    # gated norms after a scan (``ops/mamba2.gated_norm``: one a Mamba-2
+    # or Gated DeltaNet mixer) in the train step program this process
+    # traced last, and those among them that were traced into the
+    # ``gated_norm_*`` kernels (``ops/gated_norm_kernels.fits``). Counted
+    # as the convolution's pair is; 0 / 0 for a model without them
+    gate_sites: int = 0
+    gate_kernel_sites: int = 0
     # selective scans (``ops/selective_scan.selective_scan``: one a
     # Mamba-1 mixer) in the train step program this process traced last,
     # those among them that were traced into the ``sscan_*`` kernels

@@ -102,7 +102,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.common import trace_counts
 from dlrover_tpu.ops import gated_delta_kernels as kernels
-from dlrover_tpu.ops.mamba2 import conv_silu, gated_group_rmsnorm
+from dlrover_tpu.ops.mamba2 import (
+    conv_silu, gated_group_rmsnorm, gated_norm,
+)
 
 L2_EPS = 1e-6  # of the unit-length q and k (the source's ``l2norm``)
 SUB_BLOCK = kernels.SUB_BLOCK  # the one bound of both ways to execute
@@ -765,12 +767,13 @@ def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
         o = o.reshape(Bsz, T, Hv * dv)
     with jax.named_scope("scope/layer/gdn/gate"):
         if cfg.gdn_gate == "head_sigmoid":
-            o = jax.checkpoint(lambda o, z, w: head_gated_rmsnorm(
-                o, z, w, eps
-            ).astype(act))(o, z, p["norm"])
+            def statement(o, z, w):
+                return head_gated_rmsnorm(o, z, w, eps).astype(act)
         else:
-            o = jax.checkpoint(lambda o, z, w: gated_group_rmsnorm(
-                o, z, jnp.tile(w, Hv), Hv, eps, norm_before_gate=True
-            ).astype(act))(o, z, p["norm"])
+            def statement(o, z, w):
+                return gated_group_rmsnorm(
+                    o, z, jnp.tile(w, Hv), Hv, eps, norm_before_gate=True
+                ).astype(act)
+        o = gated_norm(statement, o, z, p["norm"], dv, eps, mesh=mesh)
     with jax.named_scope("scope/layer/gdn/out_proj"):
         return o @ p["w_out"].astype(act)

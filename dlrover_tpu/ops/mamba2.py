@@ -25,7 +25,10 @@ The convolution and its SiLU are ``conv_silu``, which three layers call:
 this one, the Gated DeltaNet layer (``ops/gated_delta.py``) and the Mamba-1
 layer (``ops/selective_scan.py``, with a bias): the plain statement here,
 or the ``conv_silu_*`` kernels of ``ops/conv_kernels.py`` where their rule
-takes the input.
+takes the input. The gated norm after the scan is ``gated_norm`` in the
+same way, for this layer and both gates of the Gated DeltaNet layer: the
+plain statement (``gated_group_rmsnorm`` here, ``head_gated_rmsnorm``
+there), or the ``gated_norm_*`` kernels of ``ops/gated_norm_kernels.py``.
 
 The spans of a layer: ``scope/layer/ssm/{in_proj,conv,scan,gate,out_proj}``.
 """
@@ -39,7 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.common import trace_counts
-from dlrover_tpu.ops import conv_kernels
+from dlrover_tpu.ops import conv_kernels, gated_norm_kernels
 
 
 def init_mamba2_params(key, cfg, dtype):
@@ -219,10 +222,33 @@ def gated_group_rmsnorm(y, z, weight, groups: int, eps: float,
     return out * gate if norm_before_gate else out
 
 
+def gated_norm(statement, o, z, weight, width: int, eps: float,
+               inside: bool = False, mesh=None):
+    """The gated norm after a mixer's scan, rounded once to ``o``'s dtype:
+    ``statement(o, z, weight)``, which is ``RMSNorm(o) * weight * gate(z)``
+    over each ``width`` channels of o [B, T, C] (``inside``: the gate inside
+    the norm) with z [B, T, C] for ``silu(z)`` a channel or [B, T, C /
+    width] for ``sigmoid(z)`` a group, and a weight of C channels or of one
+    group's. Where ``gated_norm_kernels.fits`` takes the input, forward and
+    backward are the ``gated_norm_*`` kernels; everywhere else the plain
+    statement, float32 inside and made again in the backward pass: what
+    either keeps is its inputs. ``mesh`` as ``conv_silu``'s. A call is a
+    site of ``common/trace_counts`` (``gate_sites``, and
+    ``gate_kernel_sites`` where the kernels take it), both counted here, so
+    a layer traced twice under ``jax.checkpoint`` counts twice in both."""
+    in_kernels = gated_norm_kernels.fits(o, z, width, mesh)
+    trace_counts.count("gate_sites")
+    trace_counts.count("gate_kernel_sites", in_kernels)
+    if in_kernels:
+        every = jnp.tile(weight, o.shape[-1] // weight.shape[0])
+        return gated_norm_kernels.gated_norm(o, z, every, width, eps, inside)
+    return jax.checkpoint(statement)(o, z, weight)
+
+
 def mamba2_mixer(u, p, cfg, eps: float, mesh=None):
     """u [B, T, d] (already normed) -> [B, T, d]. ``mesh``: the mesh the
     step is sharded over, or None inside a region that names its own axes
-    (``conv_silu``)."""
+    (``conv_silu``, ``gated_norm``)."""
     Bsz, T, _ = u.shape
     H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state
     d_in = H * P
@@ -255,8 +281,12 @@ def mamba2_mixer(u, p, cfg, eps: float, mesh=None):
         y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
         y = y.astype(dt_act).reshape(Bsz, T, d_in)
     with jax.named_scope("scope/layer/ssm/gate"):
-        y = jax.checkpoint(
-            lambda y, z, w: gated_group_rmsnorm(y, z, w, G, eps).astype(dt_act)
-        )(y, z, p["norm"])
+        def statement(y, z, w):
+            return gated_group_rmsnorm(y, z, w, G, eps).astype(dt_act)
+
+        y = gated_norm(
+            statement, y, z, p["norm"], d_in // G, eps, inside=True,
+            mesh=mesh,
+        )
     with jax.named_scope("scope/layer/ssm/out_proj"):
         return y @ p["w_out"].astype(dt_act)

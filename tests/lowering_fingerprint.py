@@ -87,6 +87,14 @@ and the shared ``_sqrt_map_quant`` / ``_sqrt_map_dequant`` make the same
 codes and values from fewer operations.
 The two GPT-2 entries (fp32 ``adamw``: no second entry, the two lines the
 step had) are byte for byte what they were.
+ISSUE 63 recorded the steps of ``nemotron3-nano-30b-a3b-d9``,
+``qwen3-next-80b-a3b-d4`` and ``ling-3.0-flash-d7`` anew (and the two
+``step_without_names`` beside them), their trees as they were: the gated
+norm after their mixers' scans goes through ``ops/mamba2.gated_norm``,
+which at these widths (4096 channels in groups of 512 or heads of 128)
+takes the ``gated_norm_*`` kernels of ``ops/gated_norm_kernels.py``,
+interpreted on the CPU. The seven configurations without an ``M`` or ``G``
+layer never reach it and are byte for byte what they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py

@@ -147,11 +147,15 @@ def test_the_gradient_holds_each_forward_attention_kernel_once(
     if delta_rule:
         # each of the rule's two kernels and the pass's forward loop; the
         # convolution before them is made again (the mixer names what it
-        # read, not what it returned)
+        # read, not what it returned), and so is the gated norm after them
+        # (its result is not kept: 64 MiB a layer of the Ling cell)
         assert set(KINDS[kind][1]) <= set(forward)
         forward.append("pass_forward")
         backward.append("pass_backward")
-        again = [n for n in forward if n.startswith("conv_")]
+        again = [
+            n for n in forward if n.startswith(("conv_", "gated_norm_"))
+        ]
+        assert "gated_norm_fwd" in again
         assert all(kept[n] == 2 * layers for n in again)
         assert all(kept[n] == layers for n in forward if n not in again)
         assert all(bare[n] == 2 * layers for n in forward)

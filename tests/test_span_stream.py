@@ -860,28 +860,38 @@ def test_two_threads_publish_one_file(tmp_path):
     assert os.listdir(tmp_path) == ["runtime_metrics.json"]
 
 
-CONV_SHARE_RUNS = {
-    "every_site_in_the_kernel": ("MEM*", {"conv_sites": 4, "conv_kernel_sites": 4}, 100.0),
-    "every_site_of_a_recomputed_step": ("G-GEGE*E", {"conv_sites": 12, "conv_kernel_sites": 12}, 100.0),
-    "one_site_of_three_plain": ("GEGE", {"conv_sites": 3, "conv_kernel_sites": 2}, 200 / 3),
-    "no_site_in_the_kernel": ("GEGE", {"conv_sites": 3, "conv_kernel_sites": 0}, 0.0),
+# the two site counters' shares, one reader each: ``conv.kernel_sites_share``
+# (ISSUE 47) and ``gate.kernel_sites_share`` (ISSUE 63) read the pair of
+# their prefix alike
+SITES_SHARES = {"conv.kernel_sites_share": "conv", "gate.kernel_sites_share": "gate"}
+SITES_SHARE_RUNS = {
+    "every_site_in_the_kernel": ("MEM*", {"{p}_sites": 4, "{p}_kernel_sites": 4}, 100.0),
+    "every_site_of_a_recomputed_step": ("G-GEGE*E", {"{p}_sites": 18, "{p}_kernel_sites": 18}, 100.0),
+    "one_site_of_three_plain": ("GEGE", {"{p}_sites": 3, "{p}_kernel_sites": 2}, 200 / 3),
+    "no_site_in_the_kernel": ("GEGE", {"{p}_sites": 3, "{p}_kernel_sites": 0}, 0.0),
+    "the_mamba_form_left_plain": ("MEME", {"{p}_sites": 8, "{p}_kernel_sites": 0}, 0.0),
     "a_program_without_the_counter": ("GEGE", {"gdn_sites": 3, "gdn_kernel_sites": 3}, None),
-    "a_program_with_half_the_counter": ("MEM*", {"conv_sites": 4}, None),
-    "no_step_was_traced": ("MEM*", {"conv_sites": 0, "conv_kernel_sites": 0}, None),
+    "a_program_with_half_the_counter": ("MEM*", {"{p}_sites": 4}, None),
+    "no_step_was_traced": ("MEM*", {"{p}_sites": 0, "{p}_kernel_sites": 0}, None),
     "a_window_without_the_record": ("GEGE", None, None),
-    "a_configuration_without_the_kind": ("E*E*", {"conv_sites": 2, "conv_kernel_sites": 2}, None),
-    "the_old_blocks": ("", {"conv_sites": 3, "conv_kernel_sites": 3}, None),
+    "a_configuration_without_the_kind": ("E*E*", {"{p}_sites": 2, "{p}_kernel_sites": 2}, None),
+    "the_old_blocks": ("", {"{p}_sites": 3, "{p}_kernel_sites": 3}, None),
 }
 
 
-@pytest.mark.parametrize("case", sorted(CONV_SHARE_RUNS))
-def test_conv_kernel_sites_share_reads_the_two_counters(case):
-    """``conv.kernel_sites_share`` (ISSUE 47): 100 x ``conv_kernel_sites``
-    over ``conv_sites`` of the window's ``pipeline`` record; nothing where
-    the program keeps no such counter (the parent's traced run prints what
-    it printed) or the configuration no layer with a convolution."""
-    pattern, pipeline, want = CONV_SHARE_RUNS[case]
-    mod = _reader("conv.kernel_sites_share")
+@pytest.mark.parametrize("case", sorted(SITES_SHARE_RUNS))
+@pytest.mark.parametrize("name", sorted(SITES_SHARES))
+def test_a_sites_share_reads_the_two_counters(name, case):
+    """100 x ``<prefix>_kernel_sites`` over ``<prefix>_sites`` of the
+    window's ``pipeline`` record; nothing where the program keeps no such
+    counter (the parent's traced run prints what it printed) or the
+    configuration no Mamba-2 or Gated DeltaNet layer."""
+    pattern, pipeline, want = SITES_SHARE_RUNS[case]
+    if pipeline is not None:
+        pipeline = {
+            k.format(p=SITES_SHARES[name]): v for k, v in pipeline.items()
+        }
+    mod = _reader(name)
     run = types.SimpleNamespace(
         config={"model": {"layer_pattern": pattern}},
         window={} if pipeline is None else {"pipeline": pipeline},
@@ -890,8 +900,9 @@ def test_conv_kernel_sites_share_reads_the_two_counters(case):
     assert got is None if want is None else got == pytest.approx(want)
 
 
-def test_conv_kernel_sites_share_is_declared_as_its_reader_says():
-    mod = _reader("conv.kernel_sites_share")
+@pytest.mark.parametrize("name", sorted(SITES_SHARES))
+def test_a_sites_share_is_declared_as_its_reader_says(name):
+    mod = _reader(name)
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     # the cells whose configuration has a Mamba-2 or a Gated DeltaNet /
@@ -900,12 +911,10 @@ def test_conv_kernel_sites_share_is_declared_as_its_reader_says():
         "nemotron3-nano-30b-a3b-d9.steady", "qwen3-next-80b-a3b-d4.steady",
         "ling-3.0-flash-d7.steady",
     ]
-    # PR 47's entry, by its name: later PRs append theirs behind it
-    (entry,) = [
-        e for e in bench["per_layer"] if e["name"] == "conv.kernel_sites_share"
-    ]
+    # the entry by its name: later PRs append theirs behind it
+    (entry,) = [e for e in bench["per_layer"] if e["name"] == name]
     assert entry == {
-        "name": "conv.kernel_sites_share", "unit": mod.UNIT,
+        "name": name, "unit": mod.UNIT,
         "better": "higher", "source": "program_counter",
         "layer": mod.LAYER, "moves": mod.MOVES, "workloads": cells,
     }
@@ -916,4 +925,3 @@ def test_conv_kernel_sites_share_is_declared_as_its_reader_says():
             cell = json.load(f)
         assert mod.CELLS(cell) == (w["name"] in cells)
     assert mod.CELLS({"config": "no-such-configuration"}) is True
-

@@ -141,7 +141,8 @@ def test_rebuild_drops_the_executable(accel):
 # the differential pairs' two and the cross-decoder's two reads (PR 53),
 # the edge blocks' two tile counts (PR 54), the delta-rule mixers whose
 # pass a recomputed layer keeps (PR 56), a looped model's three counts and
-# what is folded of its exits (PR 57); how the counted ones are
+# what is folded of its exits (PR 57), the gated norms after a scan and
+# those in the kernels (PR 63); how the counted ones are
 # folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
     "attn_diff_pairs", "attn_diff_score_calls",
@@ -157,7 +158,7 @@ AS_DICT_KEYS = [
     "comm_overlap_pct",
     "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "conv_kernel_sites", "conv_sites",
-    "donated_bytes", "donated_steps",
+    "donated_bytes", "donated_steps", "gate_kernel_sites", "gate_sites",
     "gdn_chunk_steps", "gdn_kept_sites", "gdn_kernel_sites", "gdn_sites",
     "grad_bytes_raw", "grad_bytes_wire",
     "grad_bytes_wire_vs_raw",

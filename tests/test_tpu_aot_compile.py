@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.common import trace_counts
 from trace_counted import (
-    CONV, DIFF, EDGE, FUSED, GDN, LANES, SSCAN, STREAM, WINDOW, added,
+    CONV, DIFF, EDGE, FUSED, GATE, GDN, LANES, SSCAN, STREAM, WINDOW, added,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -417,6 +417,60 @@ def test_convolution_kernels_compile_at_the_cells(
         assert f"f32[{shape[0]},{shape[1]},{C}]" not in text
     assert f"f32[{shape[0]},{shape[1] + 3},{C}]" not in text
     assert added(before, CONV) == (1, 1)
+
+
+# the gated norm after a scan at the three hybrid cells: 1 x 8192 x 4096
+# in bfloat16; the group's width, the gate's width, the gate inside
+GATED_NORM_SHAPES = {
+    "ling_sigmoid_a_head": (128, 32, False),
+    "qwen3_next_silu_outside": (128, 4096, False),
+    "nemotron_silu_inside": (512, 4096, True),
+}
+
+
+@pytest.mark.parametrize("name", list(GATED_NORM_SHAPES))
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_gated_norm_kernels_compile_at_the_cells(
+    name, direction, one_chip, monkeypatch
+):
+    """``weight * RMSNorm(o) * gate(z)`` at the three hybrid cells' shapes:
+    the forward kernel, and under ``grad`` the backward kernel with the
+    weight's gradient sums inside it; no float32 copy of the tokens is
+    left in the program around them."""
+    from dlrover_tpu.ops import gated_norm_kernels, mamba2
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    width, gates, inside = GATED_NORM_SHAPES[name]
+    B, T, C = 1, 8192, 4096
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((B, T, C)), sds((B, T, gates)), sds((C,), jnp.float32)]
+    assert gated_norm_kernels.fits(*args[:2], width)
+
+    def site(o, z, w):
+        return mamba2.gated_norm(None, o, z, w, width, 1e-6, inside)
+
+    before = trace_counts.snapshot()
+    if direction == "fwd":
+        text = _compile_for_chip(site, *args).as_text()
+        want = ["gated_norm_fwd"]
+    else:
+        text = _compile_for_chip(
+            jax.grad(
+                lambda *a: jnp.sum(site(*a).astype(jnp.float32) ** 2),
+                argnums=(0, 1, 2),
+            ),
+            *args,
+        ).as_text()
+        want = ["gated_norm_fwd", "gated_norm_bwd"]
+    for kernel in want:
+        assert kernel in text, kernel
+    if direction == "fwd":  # (the test's own loss is a float32 fusion)
+        assert f"f32[{B},{T},{C}]" not in text
+    assert f"f32[{B},{T},{C // width},{width}]" not in text
+    assert added(before, GATE) == (1, 1)
 
 
 CHANNEL_KERNELS = [

@@ -27,7 +27,7 @@ from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
-    CONV, DIFF, EDGE, FUSED, GDN, GDN_KEPT, KEPT, LANES, SCALED, SHARE,
+    CONV, DIFF, EDGE, FUSED, GATE, GDN, GDN_KEPT, KEPT, LANES, SCALED, SHARE,
     SSCAN, STREAM, UT, WINDOW, XDEC,
 )
 
@@ -103,7 +103,7 @@ def test_counting_from_many_threads_loses_nothing(fresh):
 def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
     assert set(
-        GDN + CONV + LANES + WINDOW + EDGE + KEPT + SHARE + SSCAN + DIFF
+        GDN + CONV + GATE + LANES + WINDOW + EDGE + KEPT + SHARE + SSCAN + DIFF
         + XDEC + GDN_KEPT
     ) <= FIELDS - set(trace_counts.RUNNING_TOTALS)
 
@@ -421,7 +421,7 @@ TOYS = {
         TransformerConfig(
             num_layers=3, layer_pattern="MG*", **_SMALL, **_MIXERS
         ),
-        (GDN, CONV, FUSED, LANES),
+        (GDN, CONV, GATE, FUSED, LANES),
     ),
     "vector_decay_and_latent_attention": (
         TransformerConfig(
@@ -431,7 +431,7 @@ TOYS = {
             qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope=True,
             **_SMALL, **dict(_MIXERS, positions=""),
         ),
-        (GDN, CONV, FUSED, LANES),
+        (GDN, CONV, GATE, FUSED, LANES),
     ),
     # the same recomputed: a mixer the wrapper keeps the pass's arrays of
     "vector_decay_and_latent_attention_remat": (
@@ -442,7 +442,7 @@ TOYS = {
             qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16, rope=True,
             **_SMALL, **dict(_MIXERS, positions=""),
         ),
-        (GDN, CONV, FUSED, LANES, KEPT, GDN_KEPT),
+        (GDN, CONV, GATE, FUSED, LANES, KEPT, GDN_KEPT),
     ),
     # two of eight experts held in each of two layers, then the same
     # recomputed: a share layer is one site either way

@@ -185,6 +185,25 @@ class PipelineStats:
     gdn_sites: int = 0
     gdn_chunk_steps: int = 0
     gdn_kernel_sites: int = 0
+    # the lanes of a key and a value head summed over the delta-rule sites
+    # of the train step program this process traced last whose chunk-local
+    # work is in the kernels: what a kernel's blocks hold (a head's width
+    # in whole 128-lane tiles, ``ops/gated_delta_kernels.head_lanes``), and
+    # what the model states (``gdn_key_dim + gdn_value_dim``). They differ
+    # where a head is no whole tiles (96 + 192 stated, 128 + 256 held);
+    # 0 / 0 without such a site
+    gdn_head_lanes: int = 0
+    gdn_head_lanes_used: int = 0
+    # delta-rule mixers of the train step program this process traced last
+    # whose write strength is scaled (``cfg.gdn_beta_scale`` != 1: beta up
+    # to 2, the transition's eigenvalue along the key down to ``-alpha``);
+    # 0 for a model that states none
+    gdn_beta_scaled_sites: int = 0
+    # entries of a ``layer_pattern`` in the train step program this process
+    # traced last that read the residual stream with no norm before the
+    # mixer and norm its output (``cfg.reordered_norm_kinds``); 0 for a
+    # model whose layers are all pre-norm
+    reordered_norm_sites: int = 0
     # Gated DeltaNet mixers traced as the primal of a recomputed layer
     # (``models/transformer.recomputed``, ``cfg.remat``) in the train step
     # program this process traced last: the wrapper keeps what the serial
@@ -714,6 +733,36 @@ class ModelProfile:
         return "\n".join(lines)
 
 
+def _gdn_profile(cfg: TransformerConfig, i: int, tok: int,
+                 act_bytes: int) -> ModuleProfile:
+    """A Gated DeltaNet mixer (``ops/gated_delta.py``): parameters, forward
+    operations of ``tok`` tokens and what it keeps, at a key head of
+    ``gdn_key_dim`` and a value head of ``gdn_value_dim`` (they need not
+    be equal). The chunked rule, a value head and chunk of ``C`` steps:
+    ``K K^T``, ``W`` and the masked ``Q K^T`` (``2 C^2 d_k`` each), ``U``
+    and the read-out (``2 C^2 d_v`` each), the triangle's inverse
+    (``2 (log2 C - 1)`` products of ``2 C^3``) and the three products
+    with the carried state (``2 C d_k d_v`` each)."""
+    d, Hv, Hk = cfg.model_dim, cfg.gdn_value_heads, cfg.gdn_key_heads
+    dk, dv, C = cfg.gdn_key_dim, cfg.gdn_value_dim, cfg.gdn_chunk
+    key_w, val_w = Hk * dk, Hv * dv
+    gate_w = Hv if cfg.gdn_gate == "head_sigmoid" else val_w
+    small = (
+        d * (Hv + key_w) + key_w + Hv if cfg.gdn_decay == "channel"
+        else d * 2 * Hv + 2 * Hv
+    )
+    matrices = d * (2 * key_w + val_w + gate_w) + val_w * d
+    conv = cfg.gdn_conv * (2 * key_w + val_w)
+    params = matrices + small + conv + dv
+    levels = max(C.bit_length() - 2, 0)
+    rule = Hv * (
+        2.0 * C * (3 * dk + 2 * dv) + 4.0 * C * C * levels + 6.0 * dk * dv
+    )
+    flops = tok * (2.0 * (matrices + small + conv) + rule)
+    kept = tok * (2 * key_w + 2 * val_w + d) * act_bytes
+    return ModuleProfile(f"block{i}.gdn", params, flops, kept)
+
+
 def profile_model(
     cfg: TransformerConfig, batch: int, seq: int, act_bytes: int = 2
 ) -> ModelProfile:
@@ -735,6 +784,10 @@ def profile_model(
     )
 
     for i in range(cfg.num_layers):
+        if cfg.layer_pattern[i:i + 1] == "G":
+            # a one-mixer entry: the delta rule at its own head widths
+            prof.modules.append(_gdn_profile(cfg, i, tok, act_bytes))
+            continue
         qkv_params = d * (h + 2 * kvh) * hd + h * hd * d
         score_width = value_width = hd
         if cfg.attn_kind == "latent":

@@ -80,6 +80,14 @@ class TransformerConfig:
     # norm_out(mixer(norm(x)))`` (``layer_pattern`` models; "out_norm" in
     # a layer's parameters)
     mixer_out_norm: bool = False
+    # the kinds of mixer (letters of ``layer_pattern``) whose PUBLISHED
+    # layer has its norms on the sub-layers' outputs and none on their
+    # inputs (Olmo 3's reordered norm): the mixer's entry and the
+    # feed-forward entry ("-" or "E") right after it are ``x +
+    # norm_out(f(x))``, with an "out_norm" and no "norm" in their
+    # parameters; every other entry stays what ``mixer_out_norm`` says.
+    # "" => no entry is reordered (``reordered_norm_entries``)
+    reordered_norm_kinds: str = ""
     # how often the whole stack of a ``layer_pattern`` is applied to the
     # residual stream, with the same weights every pass and the final
     # norm after each (a looped language model, arXiv:2510.25741; the
@@ -280,6 +288,13 @@ class TransformerConfig:
     # the output gate: "silu" => ``silu`` of a projection a channel;
     # "head_sigmoid" => ``sigmoid`` of one projection a head
     gdn_gate: str = "silu"
+    # the scale of the write strength, ``beta = gdn_beta_scale *
+    # sigmoid(b)`` in ``(0, gdn_beta_scale)``: 1 => Gated DeltaNet's; 2 =>
+    # the transition ``alpha (I - beta k k^T)`` has eigenvalues down to
+    # ``-alpha`` along the key (the source's ``allow_neg_eigval``), and
+    # the chunk's unit triangle is inverted by halves, not as a product
+    # (``ops/gated_delta.py``)
+    gdn_beta_scale: float = 1.0
     # sequence-parallel attention scheme when the mesh has sp > 1:
     # "ring" (P2P pipeline, any head count) or "ulysses" (two
     # all-to-alls; needs (heads/tp) % sp == 0) — parallel/{ring_
@@ -399,6 +414,13 @@ class TransformerConfig:
                 "mixer_out_norm is of the one-mixer layers of a "
                 "layer_pattern"
             )
+        mixers = set(self.layer_pattern) - set("-E")
+        if set(self.reordered_norm_kinds) - mixers:
+            raise ValueError(
+                f"reordered_norm_kinds {self.reordered_norm_kinds!r} names "
+                f"a kind of mixer that layer_pattern "
+                f"{self.layer_pattern!r} lacks"
+            )
         if self.ut_steps < 1 or self.ut_entropy_weight < 0:
             raise ValueError(
                 f"ut_steps {self.ut_steps} is a count of passes from 1, "
@@ -457,6 +479,12 @@ class TransformerConfig:
                 f"Gated DeltaNet layers need gdn_value_heads "
                 f"({self.gdn_value_heads}) a multiple of gdn_key_heads "
                 f"({self.gdn_key_heads}) and both head widths"
+            )
+        if not 0.0 < self.gdn_beta_scale <= 2.0:
+            raise ValueError(
+                f"gdn_beta_scale {self.gdn_beta_scale} is outside (0, 2]: "
+                "past 2 the delta rule's transition along the key is "
+                "larger than the decay"
             )
         if self.gdn_decay == "channel" and "G" in self.layer_pattern and (
             self.gdn_value_heads != self.gdn_key_heads
@@ -587,6 +615,20 @@ class TransformerConfig:
         if self.positions == "window":
             return "rope" if kind == "W" else "none"
         return self.position_kind
+
+    @property
+    def reordered_norm_entries(self) -> Tuple[bool, ...]:
+        """For every entry of ``layer_pattern``, whether it is ``x +
+        norm_out(f(x))`` with no input norm (``reordered_norm_kinds``): a
+        mixer of a named kind, and the feed-forward entry right after
+        one."""
+        named, entries = self.reordered_norm_kinds, []
+        for i, kind in enumerate(self.layer_pattern):
+            entries.append(
+                kind in named if kind not in "-E"
+                else i > 0 and self.layer_pattern[i - 1] in named
+            )
+        return tuple(entries)
 
     @property
     def held_experts(self) -> Tuple[int, int]:

@@ -220,11 +220,13 @@ def init_params(key, cfg: TransformerConfig) -> Params:
             norm["bias"] = jnp.zeros((d,), pd)
         return norm
 
-    for kind in cfg.layer_pattern:
+    for kind, reordered in zip(
+        cfg.layer_pattern, cfg.reordered_norm_entries
+    ):
         # one mixer a layer behind one norm (and before one, where
-        # ``mixer_out_norm``)
-        layer = {"norm": layer_norm()}
-        if cfg.mixer_out_norm:
+        # ``mixer_out_norm``); a reordered-norm entry has the one after
+        layer = {} if reordered else {"norm": layer_norm()}
+        if cfg.mixer_out_norm or reordered:
             layer["out_norm"] = layer_norm()
         layer[LAYER_KINDS[kind]] = mixers[kind]()
         if kind in "*W" and cfg.qk_norm:
@@ -393,9 +395,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
             norm["bias"] = ("norm",)
         return norm
 
-    for kind in cfg.layer_pattern:
-        layer = {"norm": layer_norm()}
-        if cfg.mixer_out_norm:
+    for kind, reordered in zip(
+        cfg.layer_pattern, cfg.reordered_norm_entries
+    ):
+        layer = {} if reordered else {"norm": layer_norm()}
+        if cfg.mixer_out_norm or reordered:
             layer["out_norm"] = layer_norm()
         layer[LAYER_KINDS[kind]] = mixers[kind]()
         if kind in "*W" and cfg.qk_norm:
@@ -738,9 +742,19 @@ def check_window_mesh(cfg: TransformerConfig, mesh):
         )
 
 
+def _normed(x, layer, cfg: TransformerConfig, norm: str = "norm"):
+    """What a layer's mixer reads: ``norm(x)``, or ``x`` itself where the
+    layer has no input norm (an entry of ``cfg.reordered_norm_entries``)."""
+    if norm not in layer:
+        trace_counts.count("reordered_norm_sites")
+        return x
+    return _norm(x, layer[norm], cfg)
+
+
 def _residual(x, out, layer, cfg: TransformerConfig):
     """``x + out``, the mixer's output through the layer's output norm
-    first where it has one (``cfg.mixer_out_norm``)."""
+    first where it has one (``cfg.mixer_out_norm``, and an entry of
+    ``cfg.reordered_norm_entries``)."""
     if "out_norm" in layer:
         with jax.named_scope("scope/layer/out_norm"):
             out = _norm(out, layer["out_norm"], cfg)
@@ -756,7 +770,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
     ``cfg.attn_window``, and ``cfg.layer_positions`` may differ by it."""
     if cfg.attn_kind == "latent":
         return _latent_attention(x, layer, cfg, mesh, positions, norm)
-    h = _norm(x, layer[norm], cfg)
+    h = _normed(x, layer, cfg, norm)
     sp = mesh is not None and mesh.shape.get("sp", 1) > 1
     window = cfg.attn_window if kind == "W" else None
     if window:
@@ -896,7 +910,7 @@ def _diff_attention(x, layer, cfg: TransformerConfig, mesh, kind: str,
     a = layer[LAYER_KINDS[kind]]
     heads, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
     pairs, key_pairs = heads // 2, kvh // 2
-    h = _norm(x, layer["norm"], cfg)
+    h = _normed(x, layer, cfg)
     order = _diff_head_order(pairs, key_pairs)
     q = jnp.einsum("btd,dhk->bhtk", h, a["wq"][:, order].astype(h.dtype))
     if "bq" in a:
@@ -963,7 +977,7 @@ def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
     a = layer["attn"]
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     latent = cfg.kv_latent_dim
-    h = _norm(x, layer[norm], cfg)
+    h = _normed(x, layer, cfg, norm)
     if cfg.q_latent_dim:
         trace_counts.count("attn_q_latent_sites")
         cq = _norm(h @ a["w_qa"].astype(h.dtype), a["q_latent_norm"], cfg)
@@ -1001,7 +1015,7 @@ def _latent_attention(x, layer, cfg: TransformerConfig, mesh, positions,
 
 @jax.named_scope("scope/layer/ssm")
 def _ssm_block(x, layer, cfg: TransformerConfig, mesh):
-    h = _norm(x, layer["norm"], cfg)
+    h = _normed(x, layer, cfg)
     return _residual(
         x, mamba2_mixer(h, layer["ssm"], cfg, _norm_eps(cfg), mesh), layer,
         cfg,
@@ -1012,7 +1026,7 @@ def _ssm_block(x, layer, cfg: TransformerConfig, mesh):
 def _sscan_block(x, layer, cfg: TransformerConfig, mesh):
     """``(x + mixer(norm(x)), the scan's output before its gate)``."""
     check_window_mesh(cfg, mesh)
-    h = _norm(x, layer["norm"], cfg)
+    h = _normed(x, layer, cfg)
     out, memory = selective_scan_mixer(h, layer["sscan"], cfg, mesh)
     return _residual(x, out, layer, cfg), memory
 
@@ -1020,7 +1034,7 @@ def _sscan_block(x, layer, cfg: TransformerConfig, mesh):
 @jax.named_scope("scope/layer/gmu")
 def _gmu_block(x, layer, cfg: TransformerConfig, memory):
     trace_counts.count("xdec_memory_reads")
-    h = _norm(x, layer["norm"], cfg)
+    h = _normed(x, layer, cfg)
     return _residual(
         x, memory_unit_mixer(h, layer["gmu"], memory), layer, cfg
     )
@@ -1028,7 +1042,7 @@ def _gmu_block(x, layer, cfg: TransformerConfig, memory):
 
 @jax.named_scope("scope/layer/gdn")
 def _gdn_block(x, layer, cfg: TransformerConfig, mesh):
-    h = _norm(x, layer["norm"], cfg)
+    h = _normed(x, layer, cfg)
     return _residual(
         x, gated_delta_mixer(h, layer["gdn"], cfg, _norm_eps(cfg), mesh),
         layer, cfg,
@@ -1062,7 +1076,7 @@ def _zero_aux(cfg: Optional[TransformerConfig] = None):
 @jax.named_scope("scope/layer/mlp")
 def _mlp_block(x, layer, cfg: TransformerConfig, mesh, moe_axis=None,
                norm: str = "mlp_norm"):
-    h = _norm(x, layer[norm], cfg)
+    h = _normed(x, layer, cfg, norm)
     if "moe" in layer:
         kw = dict(
             capacity_factor=cfg.capacity_factor,

@@ -4,14 +4,18 @@ gated delta rule computed in chunks, gated per-head RMSNorm, out-projection.
 
 The recurrence, for one value head with state ``S [d_k, d_v]`` from 0, decay
 ``alpha_t = exp(g_t)``, ``g_t = -exp(A_log) * softplus(a_t + dt_bias)``,
-write strength ``beta_t = sigmoid(b_t)``, unit-length ``k_t`` and ``q_t``
-(``q`` over ``sqrt(d_k)`` besides):
+write strength ``beta_t = gdn_beta_scale * sigmoid(b_t)`` (the scale 1, or
+up to 2: ``olmo_hybrid``'s ``linear_allow_neg_eigval``), unit-length
+``k_t`` and ``q_t`` (``q`` over ``sqrt(d_k)`` besides), ``d_k`` and ``d_v``
+each the model's own (128 / 128, 96 / 192):
 
     S <- alpha_t S
     S <- S + k_t (outer) (beta_t (v_t - S^T k_t))
     o_t = S^T q_t
 
-so the transition ``alpha_t (I - beta_t k_t k_t^T)`` is a matrix, and the
+so the transition ``alpha_t (I - beta_t k_t k_t^T)`` is a matrix (its
+eigenvalue along ``k_t`` is ``alpha_t (1 - beta_t)``: negative where the
+write strength passes 1), and the
 pass over chunk states has no closed form in scalar decays as Mamba-2's
 has (``ops/mamba2.py``). ``gated_delta_chunked`` computes it in chunks of
 ``chunk`` steps (the WY / UT transform). With ``gamma_i`` the running sum
@@ -88,7 +92,21 @@ and ``_read_out_channel`` are the statement those are held to and what runs
 at every other shape. Such a layer's decays start slow, and where a chunk's
 keys are nearly parallel and hardly decay ``unit_lower_inverse``'s product
 form loses the inverse to cancellation: this kind inverts by halves
-(``unit_lower_inverse_blocked``), in the kernels too.
+(``unit_lower_inverse_blocked``), in the kernels too. So does the scalar
+kind where the write strength is scaled past 1 (``halves``): ``A``'s
+entries double with beta and the product form's powers with them, and on
+keys that share a direction without decay it is off by 1e-2 at a mean
+``k_i . k_j`` of 0.2 where beta under 1 leaves 1e-5, and by 1e4 at 0.5
+(PERF.md, Findings PR 64); by halves the error stays under 1e-5.
+
+Which shapes run in the kernels is said in ONE place,
+``gated_delta_kernels.fits``: heads of whole 128-lane tiles token-major
+as the arrays lie, any other head of whole quarter tiles (96 / 192)
+head-major at its stated width; this module only lays the arrays out as
+that rule says (``gated_delta_chunked``); a site's count says what its blocks
+hold against what the model states (``gdn_head_lanes``,
+``gdn_head_lanes_used``, counted with the kernels' site;
+``gdn_beta_scaled_sites`` a scaled site).
 
 The spans of a layer: ``scope/layer/gdn/{in_proj,conv,scan,gate,out_proj}``.
 """
@@ -446,14 +464,15 @@ def _decays(g, diagonal: bool):
     return gamma, decay
 
 
-def _wy(k, v, beta, g):
+def _wy(k, v, beta, g, halves: bool = False):
     """What a chunk computes before the pass, for all chunks at once: from
     k [n, b, g, C, d_k], v [n, b, g, r, C, d_v] and beta, g
-    [n, b, g, r, C] the pass's ``U, W, delta, a``."""
+    [n, b, g, r, C] the pass's ``U, W, delta, a``; the triangle's inverse
+    by halves where ``halves``."""
     f32, act = jnp.float32, k.dtype
     gamma, decay = _decays(g, diagonal=False)
     kk = jnp.einsum("nbgid,nbgjd->nbgij", k, k, preferred_element_type=f32)
-    T = unit_lower_inverse(
+    T = (unit_lower_inverse_blocked if halves else unit_lower_inverse)(
         -(beta[..., :, None] * kk[:, :, :, None] * decay)
     )
     U = jnp.einsum(
@@ -597,7 +616,7 @@ def _chunked_channel(q, k, v, beta, g, chunk: int):
     return o.astype(k.dtype)
 
 
-def gated_delta_chunked(q, k, v, beta, g, chunk: int):
+def gated_delta_chunked(q, k, v, beta, g, chunk: int, halves: bool = False):
     """The gated delta rule in chunks of ``chunk`` steps: q, k
     [B, T, H_k, d_k] (unit length, q over sqrt(d_k) besides) and v
     [B, T, H_v, d_v] in the activation dtype, beta and g [B, T, H_v]
@@ -605,7 +624,9 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     rounded once to the activation dtype. Value head ``h`` reads key head
     ``h // (H_v // H_k)``. T must be whole chunks. A decay that is a
     vector over the key's channels comes as g [B, T, H_v, d_k], bounded
-    below as the module's docstring says, with ``H_v == H_k``.
+    below as the module's docstring says, with ``H_v == H_k``. ``halves``:
+    the scalar kind's triangle inverted by halves too (a write strength
+    past 1, the module's docstring).
 
     The two stretches around the pass are made again in the backward pass
     and not kept: their [C, C] squares a value head a chunk (decays, ``T``,
@@ -628,7 +649,7 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
             f"a decay a key channel needs as many key heads ({Hk}) as "
             f"value heads ({Hv})"
         )
-    in_kernels = kernels.fits(dk, dv, chunk, T, k.dtype)
+    in_kernels = kernels.fits(dk, dv, chunk, T, k.dtype, g.ndim == 4)
     if g.ndim == 4:  # a site of the kernels is counted by ``wy_channel``
         if not in_kernels:
             return _chunked_channel(q, k, v, beta, g, chunk)
@@ -646,19 +667,34 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
         )
 
     beta, g = per_head(beta), per_head(g)
-    if in_kernels:
-        trace_counts.count("gdn_kernel_sites")
-        q, k = q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk)
+    if in_kernels:  # a site of the kernels is counted by ``wy``
+        # token-major as they lie, or head-major at the stated widths
+        # where a head is no whole tiles (``kernels.fits``); q, k, then
+        # g, then v: the order the recorded steps were lowered in
+        tiles = kernels.whole_tiles(dk, dv)
+        if tiles:
+            q, k = q.reshape(B, T, Hk * dk), k.reshape(B, T, Hk * dk)
+        else:
+            q, k = (jnp.transpose(x, (0, 2, 1, 3)) for x in (q, k))
         rows = (nc, B, Hk, 1, r * chunk)
         g = g.reshape(rows)
+        if tiles:
+            v = v.reshape(B, T, Hv * dv)
+        else:
+            v = jnp.transpose(v.reshape(B, T, Hk, r, dv), (0, 2, 3, 1, 4))
         U, W, kc, delta, a = kernels.wy(
-            k, v.reshape(B, T, Hv * dv), beta.reshape(rows), g, Hk, r, chunk
+            k, v, beta.reshape(rows), g, Hk, r, chunk, halves
         )
         Vn, S_in = chunk_state_pass(U, W, kc, delta, a)
-        return kernels.read_out(q, k, g, Vn, S_in).reshape(B, T, Hv, dv)
+        o = kernels.read_out(q, k, g, Vn, S_in)
+        if o.ndim == 5:  # [b, g, r, T, d_v]
+            o = jnp.transpose(o, (0, 3, 1, 2, 4))
+        return o.reshape(B, T, Hv, dv)
     qc, kc = _chunks(q, nc, chunk), _chunks(k, nc, chunk)
     vc = _chunks(v, nc, chunk).reshape(nc, B, Hk, r, chunk, dv)
-    U, W, delta, a = jax.checkpoint(_wy)(kc, vc, beta, g)
+    U, W, delta, a = jax.checkpoint(_wy, static_argnums=(4,))(
+        kc, vc, beta, g, halves
+    )
     Vn, S_in = chunk_state_pass(U, W, kc, delta, a)
     o = jax.checkpoint(_read_out)(qc, kc, g, Vn, S_in)
     # [n, b, g, r, C, d_v] -> [b, (n, C), (g, r), d_v]
@@ -666,7 +702,7 @@ def gated_delta_chunked(q, k, v, beta, g, chunk: int):
     return o.astype(k.dtype)
 
 
-def _delta_rule(q, k, v, beta, g, chunk: int, mesh):
+def _delta_rule(q, k, v, beta, g, chunk: int, mesh, halves: bool = False):
     """``gated_delta_chunked`` as the mixer calls it. Where the chunk-local
     work goes into kernels and GSPMD still owns a mesh axis, the call runs
     under ``shard_map``, batch over the data axes and heads over tp: for
@@ -679,10 +715,14 @@ def _delta_rule(q, k, v, beta, g, chunk: int, mesh):
     from jax.sharding import PartitionSpec as P
 
     def rule(*a):
-        return checkpoint_name(gated_delta_chunked(*a, chunk), _RULE_KEPT)
+        return checkpoint_name(
+            gated_delta_chunked(*a, chunk, halves), _RULE_KEPT
+        )
 
     args = (q, k, v, beta, g)
-    if not kernels.fits(q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype):
+    if not kernels.fits(
+        q.shape[3], v.shape[3], chunk, q.shape[1], q.dtype, g.ndim == 4
+    ):
         return rule(*args)
 
     def specs(batch, heads):
@@ -757,13 +797,19 @@ def gated_delta_mixer(u, p, cfg, eps: float, mesh=None):
             g = -jnp.exp(p["A_log"].astype(jnp.float32)) * jax.nn.softplus(
                 ba[..., Hv:] + p["dt_bias"].astype(jnp.float32)
             )
+        if cfg.gdn_beta_scale != 1.0:
+            trace_counts.count("gdn_beta_scaled_sites")
+            beta = cfg.gdn_beta_scale * beta
         q = qkv[..., :key_w].reshape(Bsz, T, Hk, dk)
         k = qkv[..., key_w:2 * key_w].reshape(Bsz, T, Hk, dk)
         v = qkv[..., 2 * key_w:].reshape(Bsz, T, Hv, dv)
         q, k = jax.checkpoint(lambda q, k: (
             (l2norm(q) * dk**-0.5).astype(act), l2norm(k).astype(act)
         ))(q, k)
-        o = _delta_rule(q, k, v, beta, g, min(cfg.gdn_chunk, T), mesh)
+        o = _delta_rule(
+            q, k, v, beta, g, min(cfg.gdn_chunk, T), mesh,
+            cfg.gdn_beta_scale > 1.0,
+        )
         o = o.reshape(Bsz, T, Hv * dv)
     with jax.named_scope("scope/layer/gdn/gate"):
         if cfg.gdn_gate == "head_sigmoid":

@@ -15,7 +15,15 @@ Layouts are the neighbours': ``q, k, v`` are read token-major
 ``[B, T, heads * d]`` in blocks of ``(C, d)`` (a head is a lane tile),
 ``U, W, K, delta, a`` are written chunk-major as ``chunk_state_pass`` takes
 them, ``o`` token-major as the gate does. ``beta`` and ``g`` (and their
-cotangents) travel as rows ``[n, B, H_k, 1, r C]``.
+cotangents) travel as rows ``[n, B, H_k, 1, r C]``. A head that is no
+whole lane tiles (96 / 192) is no lane block Mosaic takes, and comes
+head-major instead, ``[B, H_k, T, d_k]`` and ``[B, H_k, r, T, d_v]``, a
+head's width the block's whole minor dimension; the chunk-major arrays
+hold the stated widths as they are. ``fits`` is the one place that says
+which shapes run here and in which layout; the kernels' bodies read either
+through ``_head`` and are otherwise one code. The scalar kind's inverse is
+the product form, or (``halves``: a write strength scaled past 1) the one
+by halves of the second half of this file.
 
 A decay that is a vector over the key's channels (Kimi Delta Attention,
 ``g [B, T, H d_k]``) has the same two stretches as kernels of its own,
@@ -62,21 +70,48 @@ _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 _CHUNKS_A_PROGRAM = (8, 4, 2, 1)
 
 
-def fits(d_k: int, d_v: int, chunk: int, T: int, dtype) -> bool:
+_QUARTER = _LANES // 4
+
+
+def whole_tiles(d_k: int, d_v: int) -> bool:
+    """A key and a value head are whole 128-lane tiles: the kernels read
+    ``[B, T, heads * d]`` as it lies, a head a lane block."""
+    return d_k % _LANES == 0 and d_v % _LANES == 0
+
+
+def head_lanes(d_k: int, d_v: int) -> int:
+    """The lanes a key and a value head take in a kernel's blocks: their
+    widths in whole tiles, which is what Mosaic makes of a block's minor
+    dimension (96 + 192 stated are 128 + 256 held)."""
+    return sum(-(-d // _LANES) * _LANES for d in (d_k, d_v))
+
+
+def fits(d_k: int, d_v: int, chunk: int, T: int, dtype,
+         channel: bool = False) -> bool:
     """THE rule for which way the chunk-local work is executed, read from
     the shapes alone: the kernels where a key and a value head are whole
-    128-lane tiles, a chunk is whole sublane tiles of the activation dtype
-    (8 rows of float32, 16 of bfloat16) and the sequence is whole chunks;
-    the plain ``jax.numpy`` statement everywhere else. The same shapes
-    serve both kinds of decay, each with kernels of its own: one scalar a
-    head and step (``wy``, ``read_out``: squares from ``exp(gamma_i -
-    gamma_j)`` of scalars) or a vector over the key's channels, ``g [B, T,
-    H, d_k]`` (``wy_channel``, ``read_out_channel``)."""
+    quarters of a 128-lane tile, a chunk is whole sublane tiles of the
+    activation dtype (8 rows of float32, 16 of bfloat16) and the sequence
+    is whole chunks; the plain ``jax.numpy`` statement everywhere else.
+    Heads of whole tiles (``whole_tiles``) are read token-major, ``[B, T,
+    heads * d]`` in lane blocks of a head. Any other width (96 / 192) is
+    no lane block Mosaic takes: the caller hands ``q, k, v`` head-major,
+    ``[B, H_k, T, d_k]`` and ``[B, H_k, r, T, d_v]``, a head's width the
+    block's whole minor dimension, and takes ``o`` and the cotangents
+    back so; every array keeps the stated widths in HBM as XLA sees it,
+    and a block's lanes past them are Mosaic's own padding in VMEM
+    (``head_lanes``). The same shapes serve both kinds of decay, each
+    with kernels of its own: one scalar a head and step (``wy``,
+    ``read_out``: squares from ``exp(gamma_i - gamma_j)`` of scalars) or
+    a vector over the key's channels (``channel``), ``g [B, T, H, d_k]``
+    (``wy_channel``, ``read_out_channel``: heads of whole tiles only,
+    token-major)."""
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    return (
-        d_k % _LANES == 0 and d_v % _LANES == 0
-        and chunk % sublanes == 0 and T % chunk == 0
+    widths = (
+        whole_tiles(d_k, d_v) if channel
+        else d_k % _QUARTER == 0 and d_v % _QUARTER == 0
     )
+    return widths and chunk % sublanes == 0 and T % chunk == 0
 
 
 def _dot(a, b, dims=_NN, precision=None):
@@ -146,10 +181,23 @@ def _unit_lower_inverses(As, geo, C: int):
     return Ts
 
 
+def _width(ref, r: int) -> int:
+    """A value head's width in a block of a key head's ``r`` value heads:
+    token-major ``[rows, r d]`` or head-major ``[r, rows, d]``."""
+    return ref.shape[-1] if ref.ndim == 3 else ref.shape[-1] // r
+
+
+def _head(ref, rows, j: int, d: int):
+    """Where value head ``j``'s ``rows`` lie in such a block."""
+    if ref.ndim == 3:
+        return (j, rows, slice(None))
+    return (rows, slice(j * d, (j + 1) * d))
+
+
 def _stack(ref, rows, r: int, d: int):
-    """A token-major block's ``r`` heads ``[C, r d]`` -> ``[r C, d]``."""
+    """A block's ``r`` value heads -> ``[r C, d]``."""
     return jnp.concatenate(
-        [ref[rows, j * d:(j + 1) * d] for j in range(r)], axis=0
+        [ref[_head(ref, rows, j, d)] for j in range(r)], axis=0
     )
 
 
@@ -171,9 +219,10 @@ class _WySquares(NamedTuple):
     c_row: jax.Array  # beta * exp(gamma)
 
 
-def _wy_squares(k_ref, v_ref, beta_ref, g_ref, geo, C, r, m):
-    """The squares of each of a program's ``m`` chunks."""
-    d_v = v_ref.shape[-1] // r
+def _wy_squares(k_ref, v_ref, beta_ref, g_ref, geo, C, r, m, halves):
+    """The squares of each of a program's ``m`` chunks; the inverse by
+    halves (``_inverses_by_halves``) where ``halves``."""
+    d_v = _width(v_ref, r)
     made = []
     for c in range(m):
         rows = slice(c * C, (c + 1) * C)
@@ -188,7 +237,11 @@ def _wy_squares(k_ref, v_ref, beta_ref, g_ref, geo, C, r, m):
             _as_col(g_row, geo), gamma_row, E, kk, -((beta_col * kk) * E),
             None, beta_row * jnp.exp(gamma_row),
         ))
-    Ts = _unit_lower_inverses([s.A for s in made], geo, C)
+    As = [s.A for s in made]
+    if halves:
+        Ts = _inverses_by_halves(As, _channel_geometry(C, r), C)
+    else:
+        Ts = _unit_lower_inverses(As, geo, C)
     return [s._replace(T=T) for s, T in zip(made, Ts)]
 
 
@@ -201,10 +254,13 @@ def _left_and_total(g_col, geo):
 
 
 def _wy_fwd_kernel(k_ref, v_ref, beta_ref, g_ref,
-                   u_ref, w_ref, kc_ref, delta_ref, a_ref, *, C, r, m):
+                   u_ref, w_ref, kc_ref, delta_ref, a_ref,
+                   *, C, r, m, halves):
     act = k_ref.dtype
     geo = _geometry(C, r)
-    squares = _wy_squares(k_ref, v_ref, beta_ref, g_ref, geo, C, r, m)
+    squares = _wy_squares(
+        k_ref, v_ref, beta_ref, g_ref, geo, C, r, m, halves
+    )
     for c, s in enumerate(squares):
         U = _dot(s.T.astype(act), (s.v2 * s.beta_col).astype(act))
         W = _dot((s.T * s.c_row).astype(act), s.k2).astype(act)
@@ -219,11 +275,13 @@ def _wy_fwd_kernel(k_ref, v_ref, beta_ref, g_ref,
 
 def _wy_bwd_kernel(k_ref, v_ref, beta_ref, g_ref,
                    du_ref, dw_ref, dkc_ref, ddelta_ref, da_ref,
-                   dk_ref, dv_ref, dbeta_ref, dg_ref, *, C, r, m):
+                   dk_ref, dv_ref, dbeta_ref, dg_ref, *, C, r, m, halves):
     act = k_ref.dtype
-    d_v = v_ref.shape[-1] // r
+    d_v = _width(v_ref, r)
     geo = _geometry(C, r)
-    squares = _wy_squares(k_ref, v_ref, beta_ref, g_ref, geo, C, r, m)
+    squares = _wy_squares(
+        k_ref, v_ref, beta_ref, g_ref, geo, C, r, m, halves
+    )
     for c, s in enumerate(squares):
         rows = slice(c * C, (c + 1) * C)
         dU = jnp.concatenate([du_ref[c, j] for j in range(r)], 0).astype(act)
@@ -270,7 +328,7 @@ def _wy_bwd_kernel(k_ref, v_ref, beta_ref, g_ref,
         ).astype(act)
         dv = (dvb * s.beta_col).astype(act)
         for j in range(r):
-            dv_ref[rows, j * d_v:(j + 1) * d_v] = dv[j * C:(j + 1) * C]
+            dv_ref[_head(dv_ref, rows, j, d_v)] = dv[j * C:(j + 1) * C]
         dbeta_ref[c] = dbeta_row + _as_row(dbeta_col, geo)
         dg_ref[c] = dg_row
 
@@ -309,7 +367,7 @@ def _read_fwd_kernel(q_ref, k_ref, g_ref, vn_ref, s_ref, o_ref, *, C, r, m):
         o = _dot((s.qk * s.D).astype(act), s.Vn2) + s.entered * s.eg_col
         o = o.astype(act)
         for j in range(r):
-            o_ref[rows, j * d_v:(j + 1) * d_v] = o[j * C:(j + 1) * C]
+            o_ref[_head(o_ref, rows, j, d_v)] = o[j * C:(j + 1) * C]
 
 
 def _read_bwd_kernel(q_ref, k_ref, g_ref, vn_ref, s_ref, do_ref,
@@ -362,6 +420,9 @@ class _Shape(NamedTuple):
     d_k: int
     d_v: int
     m: int  # chunks a program
+    # ``q, k`` [B, H_k, T, d_k] and ``v, o`` [B, H_k, r, T, d_v] (heads
+    # that are no whole tiles, ``fits``), not [B, T, heads * d]
+    head_major: bool = False
 
     @property
     def grid(self):
@@ -374,16 +435,43 @@ class _Shape(NamedTuple):
 
 
 def _shape(k, Hk: int, r: int, C: int, d_v: int) -> _Shape:
-    B, T, key_lanes = k.shape
+    if k.ndim == 4:
+        B, _, T, d_k = k.shape
+    else:
+        B, T, key_lanes = k.shape
+        d_k = key_lanes // Hk
     n = T // C
     m = next(m for m in _CHUNKS_A_PROGRAM if n % m == 0)
-    return _Shape(B, n, Hk, r, C, key_lanes // Hk, d_v, m)
+    return _Shape(B, n, Hk, r, C, d_k, d_v, m, k.ndim == 4)
+
+
+def _value_width(v, Hk: int, r: int) -> int:
+    return v.shape[-1] if v.ndim == 5 else v.shape[-1] // (Hk * r)
 
 
 def _tokens(sh: _Shape, d: int):
     """A ``[B, T, heads * d]`` array: a program's run of chunks of one
     key head's (or its value heads') lanes."""
     return pl.BlockSpec((None, sh.m * sh.C, d), lambda b, h, i: (b, i, h))
+
+
+def _keys(sh: _Shape):
+    """``q`` or ``k``: a program's run of chunks of its key head."""
+    if not sh.head_major:
+        return _tokens(sh, sh.d_k)
+    return pl.BlockSpec(
+        (None, None, sh.m * sh.C, sh.d_k), lambda b, h, i: (b, h, i, 0)
+    )
+
+
+def _values(sh: _Shape):
+    """``v`` or ``o``: ... of its key head's ``r`` value heads."""
+    if not sh.head_major:
+        return _tokens(sh, sh.r * sh.d_v)
+    return pl.BlockSpec(
+        (None, None, sh.r, sh.m * sh.C, sh.d_v),
+        lambda b, h, i: (b, h, 0, i, 0),
+    )
 
 
 def _chunk_major(sh: _Shape, *tail):
@@ -398,9 +486,10 @@ def _like(*arrays):
     return [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in arrays]
 
 
-def _call(kernel, name, sh: _Shape, in_specs, out_specs, out_shape, *ins):
+def _call(kernel, name, sh: _Shape, in_specs, out_specs, out_shape, *ins,
+          **static):
     return pl.pallas_call(
-        functools.partial(kernel, C=sh.C, r=sh.r, m=sh.m),
+        functools.partial(kernel, C=sh.C, r=sh.r, m=sh.m, **static),
         name=name,
         grid=sh.grid,
         in_specs=in_specs,
@@ -418,7 +507,7 @@ def _wy_specs(sh: _Shape):
     its backward's cotangents in)."""
     r, C, row = sh.r, sh.C, _chunk_major(sh, 1, sh.r * sh.C)
     return (
-        [_tokens(sh, sh.d_k), _tokens(sh, r * sh.d_v), row, row],
+        [_keys(sh), _values(sh), row, row],
         [
             _chunk_major(sh, r, C, sh.d_v), _chunk_major(sh, r, C, sh.d_k),
             _chunk_major(sh, C, sh.d_k), row, row,
@@ -426,8 +515,15 @@ def _wy_specs(sh: _Shape):
     )
 
 
-def _wy_call(k, v, beta, g, Hk, r, C):
-    sh = _shape(k, Hk, r, C, v.shape[-1] // (Hk * r))
+def _wy_call(k, v, beta, g, Hk, r, C, halves):
+    sh = _shape(k, Hk, r, C, _value_width(v, Hk, r))
+    # once a trace of ``wy``'s forward, the primal and the ``custom_vjp``
+    # rule alike: where ``gated_delta._pass_forward`` counts a site
+    # (``_channel_wy_call``'s reason), with the lanes a key and a value
+    # head take in the blocks and those the model states
+    trace_counts.count("gdn_kernel_sites")
+    trace_counts.count("gdn_head_lanes", head_lanes(sh.d_k, sh.d_v))
+    trace_counts.count("gdn_head_lanes_used", sh.d_k + sh.d_v)
     ins, outs = _wy_specs(sh)
     lead = sh.rows[:3]
     U, W, Kc, delta, a = _call(
@@ -439,33 +535,36 @@ def _wy_call(k, v, beta, g, Hk, r, C):
             jax.ShapeDtypeStruct(sh.rows, _F32),
             jax.ShapeDtypeStruct(sh.rows, _F32),
         ],
-        k, v, beta, g,
+        k, v, beta, g, halves=halves,
     )
     return U, W, Kc, delta.reshape(lead + (r, C)), a[..., 0, ::C]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def wy(k, v, beta, g, Hk: int, r: int, C: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def wy(k, v, beta, g, Hk: int, r: int, C: int, halves: bool = False):
     """What a chunk computes before the pass. ``k`` [B, T, H_k d_k] and
-    ``v`` [B, T, H_v d_v] in the activation dtype, ``beta`` and ``g``
-    [n, B, H_k, 1, r C] float32 -> ``chunk_state_pass``'s arguments ``U,
-    W, K, delta, a``, chunk axis first."""
-    return _wy_call(k, v, beta, g, Hk, r, C)
+    ``v`` [B, T, H_v d_v] in the activation dtype (head-major where
+    ``fits`` says so: [B, H_k, T, d_k] and [B, H_k, r, T, d_v]), ``beta``
+    and ``g`` [n, B, H_k, 1, r C] float32 -> ``chunk_state_pass``'s
+    arguments ``U, W, K, delta, a``, chunk axis first. ``halves``: the
+    unit triangle's inverse by halves and not as a product
+    (``gated_delta.unit_lower_inverse_blocked``)."""
+    return _wy_call(k, v, beta, g, Hk, r, C, halves)
 
 
-def _wy_fwd(k, v, beta, g, Hk, r, C):
-    return _wy_call(k, v, beta, g, Hk, r, C), (k, v, beta, g)
+def _wy_fwd(k, v, beta, g, Hk, r, C, halves):
+    return _wy_call(k, v, beta, g, Hk, r, C, halves), (k, v, beta, g)
 
 
-def _wy_bwd(Hk, r, C, res, cts):
+def _wy_bwd(Hk, r, C, halves, res, cts):
     k, v, beta, g = res
     dU, dW, dKc, ddelta, da = cts
-    sh = _shape(k, Hk, r, C, v.shape[-1] // (Hk * r))
+    sh = _shape(k, Hk, r, C, _value_width(v, Hk, r))
     ins, outs = _wy_specs(sh)
     return _call(
         _wy_bwd_kernel, "gdn_chunk_wy_bwd", sh, ins + outs, ins, _like(*res),
         *res, dU, dW, dKc, ddelta.reshape(sh.rows),
-        jnp.repeat(da, C, axis=-1).reshape(sh.rows),
+        jnp.repeat(da, C, axis=-1).reshape(sh.rows), halves=halves,
     )
 
 
@@ -476,7 +575,7 @@ def _read_specs(q, k, g, Vn, S_in):
     """The shape and the block specs of ``read_out``'s arguments."""
     _, _, Hk, r, C, d_v = Vn.shape
     sh = _shape(k, Hk, r, C, d_v)
-    keys = _tokens(sh, sh.d_k)
+    keys = _keys(sh)
     return sh, [
         keys, keys, _chunk_major(sh, 1, r * C),
         _chunk_major(sh, r, C, d_v), _chunk_major(sh, r, sh.d_k, d_v),
@@ -485,22 +584,24 @@ def _read_specs(q, k, g, Vn, S_in):
 
 def _read_call(q, k, g, Vn, S_in):
     sh, ins = _read_specs(q, k, g, Vn, S_in)
-    lanes = sh.Hk * sh.r * sh.d_v
+    T = sh.n * sh.C
+    shape = (
+        (sh.B, sh.Hk, sh.r, T, sh.d_v) if sh.head_major
+        else (sh.B, T, sh.Hk * sh.r * sh.d_v)
+    )
     return _call(
-        _read_fwd_kernel, "gdn_chunk_read_fwd", sh, ins,
-        _tokens(sh, sh.r * sh.d_v),
-        jax.ShapeDtypeStruct((sh.B, sh.n * sh.C, lanes), k.dtype),
-        q, k, g, Vn, S_in,
+        _read_fwd_kernel, "gdn_chunk_read_fwd", sh, ins, _values(sh),
+        jax.ShapeDtypeStruct(shape, k.dtype), q, k, g, Vn, S_in,
     )
 
 
 @jax.custom_vjp
 def read_out(q, k, g, Vn, S_in):
-    """What every position reads. ``q, k`` [B, T, H_k d_k], ``g``
-    [n, B, H_k, 1, r C], ``V'`` [n, B, H_k, r, C, d_v] and the entered
-    states [n, B, H_k, r, d_k, d_v] as the pass returns them -> ``o``
-    [B, T, H_v d_v], accumulated in float32 and rounded once to the
-    activation dtype."""
+    """What every position reads. ``q, k`` [B, T, H_k d_k] (head-major: [B,
+    H_k, T, d_k]), ``g`` [n, B, H_k, 1, r C], ``V'`` [n, B, H_k, r, C, d_v]
+    and the entered states [n, B, H_k, r, d_k, d_v] as the pass returns
+    them -> ``o`` [B, T, H_v d_v] (head-major: [B, H_k, r, T, d_v]),
+    accumulated in float32 and rounded once to the activation dtype."""
     return _read_call(q, k, g, Vn, S_in)
 
 
@@ -512,7 +613,7 @@ def _read_bwd(res, do):
     sh, ins = _read_specs(*res)
     return _call(
         _read_bwd_kernel, "gdn_chunk_read_bwd", sh,
-        ins + [_tokens(sh, sh.r * sh.d_v)], ins, _like(*res), *res, do,
+        ins + [_values(sh)], ins, _like(*res), *res, do,
     )
 
 

@@ -7,7 +7,10 @@ statement's ``jax.checkpoint`` keeps.
 
 The three forms are one algorithm with three parameters that a call's own
 arguments state: the group's width (a head's 128 lanes in the two
-delta-rule forms, 512 in the Mamba-2 layer's), ``z``'s shape (``[B, T, C]``:
+delta-rule forms, 512 in the Mamba-2 layer's; a head of 192 lanes, no
+whole tiles, is walked two groups a span of three tiles, each group's mean
+square a sum under a mask of its lanes: ``fits`` is the one place that
+says which shapes run here), ``z``'s shape (``[B, T, C]``:
 ``silu(z)`` a channel; ``[B, T, C / group]``: ``sigmoid(z)`` a group) and
 whether the gate is inside the norm (``RMSNorm(o * gate) * weight``) or
 outside it (``RMSNorm(o) * weight * gate``).
@@ -63,6 +66,8 @@ _ROWS = 64
 # gate for the scheduler to overlap (PERF.md §6, PR 63: 32 rows of one head
 # a trip took 2.5 times as long)
 _TILES = 4
+# the widest span of groups that are no whole tiles (``_span``)
+_SPAN_TILES = 4
 _ROW_BLOCKS = (512, 256, 128, 64, 32)
 # the largest block of one array a program holds (twice: it comes in
 # while the one before is worked on); the backward kernel holds five
@@ -78,21 +83,32 @@ def _row_block(o) -> int:
     return next((s for s in _ROW_BLOCKS if T % s == 0 and s <= room), 0)
 
 
+def _span(group: int) -> int:
+    """The lanes the walk takes at once: the fewest whole groups that are
+    whole 128-lane tiles (a group of whole tiles: itself; 192: two groups,
+    three tiles)."""
+    return math.lcm(group, _LANES)
+
+
 def fits(o, z, group: int, mesh=None) -> bool:
     """THE rule for which way the stretch is executed, read from its
-    input: the kernels where the channels and the group's width are whole
-    128-lane tiles, the rows are whole row blocks, ``z`` is a gate a
-    channel or a gate a group in ``o``'s dtype, bfloat16 or float32, and
-    one device owns the program (GSPMD refuses to partition a Mosaic
-    call); the plain statement everywhere else."""
+    input: the kernels where the channels are whole spans (``_span``: a
+    group of whole 128-lane tiles, or a group wider than a tile with the
+    few that make whole tiles together, at most ``_SPAN_TILES`` of them;
+    such a group only with a gate a channel), the rows are whole row
+    blocks, ``z`` is a gate a channel or a gate a group in ``o``'s dtype,
+    bfloat16 or float32, and one device owns the program (GSPMD refuses to
+    partition a Mosaic call); the plain statement everywhere else."""
     if o.ndim != 3 or group <= 0 or o.shape[2] % group:
         return False
     per_group = (*o.shape[:2], o.shape[2] // group)
+    whole = group % _LANES == 0
     return (
         jnp.dtype(o.dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(_F32))
         and z.dtype == o.dtype
-        and z.shape in (o.shape, per_group)
-        and group % _LANES == 0
+        and z.shape in ((o.shape, per_group) if whole else (o.shape,))
+        and (whole or _LANES < group <= _span(group) <= _SPAN_TILES * _LANES)
+        and o.shape[2] % _span(group) == 0
         and _row_block(o) > 0
         and _one_device(o, mesh)
     )
@@ -118,9 +134,25 @@ def _spread(zs, g):
     )
 
 
-def _rsqrt_mean_square(x, eps: float):
-    """[rows, group] float32 -> [rows, 1]."""
-    return lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+def _group_mean(x, group: int):
+    """The mean over each group's lanes of ``x`` [rows, span] float32:
+    [rows, 1] where the span is one group, else [rows, span], a lane
+    holding its own group's (sums under a mask of the group's lanes)."""
+    span = x.shape[-1]
+    if group == span:
+        return jnp.mean(x, axis=-1, keepdims=True)
+    lane = lax.broadcasted_iota(jnp.int32, (1, span), 1)
+    mean = jnp.zeros_like(x)
+    for u in range(span // group):
+        inside = (lane >= u * group) & (lane < (u + 1) * group)
+        total = jnp.sum(jnp.where(inside, x, 0.0), axis=-1, keepdims=True)
+        mean = jnp.where(inside, total / group, mean)
+    return mean
+
+
+def _rsqrt_mean_square(x, eps: float, group: int):
+    """[rows, span] float32 -> [rows, 1] (``_group_mean``'s shape)."""
+    return lax.rsqrt(_group_mean(x * x, group) + eps)
 
 
 def _group(o_ref, z_ref, w_ref, rows, lanes, g, inside: bool):
@@ -167,12 +199,12 @@ def _walk(bt: int, groups: int, width: int, body, carry0=0, done=None):
     lax.fori_loop(0, bt // sub, sub_block, 0)
 
 
-def _fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, width, eps, inside):
+def _fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, width, group, eps, inside):
     bt, C = o_ref.shape[1:]
 
     def body(rows, lanes, g, carry):
         _, gate, _, w, x = _group(o_ref, z_ref, w_ref, rows, lanes, g, inside)
-        y = x * _rsqrt_mean_square(x, eps) * w
+        y = x * _rsqrt_mean_square(x, eps, group) * w
         if not inside:
             y = y * gate
         y_ref[0, rows, lanes] = y.astype(y_ref.dtype)
@@ -182,8 +214,8 @@ def _fwd_kernel(o_ref, z_ref, w_ref, y_ref, *, width, eps, inside):
 
 
 def _bwd_kernel(
-    o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, width, eps,
-    inside,
+    o_ref, z_ref, w_ref, dy_ref, do_ref, dz_ref, dw_ref, *, width, group,
+    eps, inside,
 ):
     bt, C = o_ref.shape[1:]
     sub = _sub_block(bt)
@@ -199,7 +231,7 @@ def _bwd_kernel(
             o_ref, z_ref, w_ref, rows, lanes, g, inside
         )
         dy = dy_ref[0, rows, lanes].astype(_F32)
-        r = _rsqrt_mean_square(x, eps)
+        r = _rsqrt_mean_square(x, eps, group)
         n = x * r
         if inside:
             dw, dn = dy * n, dy * w
@@ -208,7 +240,7 @@ def _bwd_kernel(
         dw_ref[0, :, lanes] += dw.reshape(
             sub // _SUBLANES, _SUBLANES, width
         ).sum(axis=0)
-        dx = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
+        dx = r * (dn - n * _group_mean(dn * n, group))
         if inside:
             do, dz = dx * gate, dx * o * dgate_dz
         else:
@@ -262,7 +294,10 @@ def _fwd_call(o, z, w, *, width, eps, inside, interpret):
     (shape, form) and calls it once a site."""
     grid, tokens, gates, row = _specs(o, z)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, width=width, eps=eps, inside=inside),
+        functools.partial(
+            _fwd_kernel, width=_span(width), group=width, eps=eps,
+            inside=inside,
+        ),
         name="gated_norm_fwd",
         grid=grid,
         in_specs=[tokens, gates, row],
@@ -277,7 +312,10 @@ def _bwd_call(o, z, w, dy, *, width, eps, inside, interpret):
     B, _, C = o.shape
     grid, tokens, gates, row = _specs(o, z)
     do, dz, dw = pl.pallas_call(
-        functools.partial(_bwd_kernel, width=width, eps=eps, inside=inside),
+        functools.partial(
+            _bwd_kernel, width=_span(width), group=width, eps=eps,
+            inside=inside,
+        ),
         name="gated_norm_bwd",
         grid=grid,
         in_specs=[tokens, gates, row, tokens],

@@ -95,6 +95,14 @@ which at these widths (4096 channels in groups of 512 or heads of 128)
 takes the ``gated_norm_*`` kernels of ``ops/gated_norm_kernels.py``,
 interpreted on the CPU. The seven configurations without an ``M`` or ``G``
 layer never reach it and are byte for byte what they were.
+ISSUE 64 brought ``olmo-hybrid-7b-d4`` (delta-rule heads of 96 / 192, no
+whole lane tiles, which the ``gdn_chunk_*`` kernels read head-major and
+invert by halves at a write strength up to 2; a gated norm over groups of
+192; an attention layer and its feed-forward with their norms on the
+output) and left the ten entries before it byte for byte as they were:
+``gdn_beta_scale`` 1 is no product and the product form, no
+``reordered_norm_kinds`` is a ``norm`` leaf an entry, heads of whole tiles
+are read token-major as before.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
@@ -116,7 +124,7 @@ NAMES = (
     "gpt2-124m", "gpt2-xl-d12", "olmoe-1b-7b-d2",
     "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
     "ling-3.0-flash-d7", "trinity-mini-d5", "phi4-mini-flash-d6",
-    "ouro-2.6b-d6", "mistral-small-4-119b-d4",
+    "ouro-2.6b-d6", "mistral-small-4-119b-d4", "olmo-hybrid-7b-d4",
 )
 
 

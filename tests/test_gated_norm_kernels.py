@@ -30,7 +30,10 @@ EPS = 1e-5
 # tile, or two of two tiles where the gate is inside the norm
 B, T, C = 2, 1536, 512
 # the form -> the group's width
-FORMS = {"head": 128, "outside": 128, "inside": 256}
+FORMS = {"head": 128, "outside": 128, "inside": 256, "outside_192": 192}
+# a head of a tile and a half (Olmo-Hybrid's value head): four groups, two
+# spans of three tiles
+SHAPES = {"outside_192": (B, T, 768)}
 
 
 def statement(form, width):
@@ -42,15 +45,21 @@ def statement(form, width):
         else:
             y = gated_group_rmsnorm(
                 o, z, w, o.shape[-1] // width, EPS,
-                norm_before_gate=form == "outside",
+                norm_before_gate=form.startswith("outside"),
             )
         return y.astype(o.dtype)
 
     return plain
 
 
-def _inputs(form, dtype, seed=0, shape=(B, T, C)):
+def _two_spans(form):
+    """Channels of the short cases: 256, or two groups of 192."""
+    return 384 if FORMS[form] == 192 else 256
+
+
+def _inputs(form, dtype, seed=0, shape=None):
     width = FORMS[form]
+    shape = shape or SHAPES.get(form, (B, T, C))
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     o = (2.0 * jax.random.normal(ks[0], shape)).astype(dtype)
     gates = (*shape[:2], shape[2] // width) if form == "head" else shape
@@ -88,6 +97,9 @@ def _both(form, dtype):
     args = _inputs(form, jnp.dtype(dtype))
     assert gated_norm_kernels.fits(args[0], args[1], FORMS[form])
     assert gated_norm_kernels._row_block(args[0]) == 512
+    assert gated_norm_kernels._span(FORMS[form]) == (
+        384 if form == "outside_192" else FORMS[form]
+    )
     return (
         _grads(kernel(form), *args),
         _grads(statement(form, FORMS[form]), *args),
@@ -123,7 +135,7 @@ def test_kernels_give_what_the_plain_statement_gives(form, dtype, what):
 def test_a_sequence_of_one_row_block(form, rows):
     """One sub-block; two; and three blocks of one (96 is whole blocks of
     32 alone)."""
-    args = _inputs(form, F32, seed=rows, shape=(1, rows, 256))
+    args = _inputs(form, F32, seed=rows, shape=(1, rows, _two_spans(form)))
     assert gated_norm_kernels._row_block(args[0]) == (64 if rows == 64 else 32)
     got = _grads(kernel(form), *args)
     want = _grads(statement(form, FORMS[form]), *args)
@@ -133,16 +145,19 @@ def test_a_sequence_of_one_row_block(form, rows):
 
 @pytest.mark.parametrize("form", sorted(FORMS))
 def test_the_rows_of_a_batch_do_not_see_each_other(form):
-    o, z, w, dy = _inputs(form, F32, seed=4, shape=(2, 64, 256))
+    o, z, w, dy = _inputs(form, F32, seed=4, shape=(2, 64, _two_spans(form)))
     both = _grads(kernel(form), o, z, w, dy)
     alone = [
         _grads(kernel(form), o[i:i + 1], z[i:i + 1], w, dy[i:i + 1])
         for i in range(2)
     ]
     for n in ("y", "do", "dz"):
-        np.testing.assert_array_equal(
-            both[n], np.concatenate([a[n] for a in alone])
-        )
+        apart = np.concatenate([a[n] for a in alone])
+        if FORMS[form] % 128:
+            # a masked sum's order is the interpreter's to choose a program
+            np.testing.assert_allclose(both[n], apart, rtol=2e-6, atol=2e-6)
+        else:
+            np.testing.assert_array_equal(both[n], apart)
     # summed over the batch outside the kernel
     np.testing.assert_allclose(
         both["dw"], sum(a["dw"] for a in alone), rtol=1e-5, atol=1e-5
@@ -197,7 +212,12 @@ def test_a_program_lowers_the_kernel_once_a_shape_and_form():
 
 REFUSED = {
     "a_group_of_no_whole_lane_tiles": ((1, 128, 256), 64, None),
-    "a_group_of_a_tile_and_a_half": ((1, 128, 384), 192, None),
+    "a_gate_a_group_of_a_tile_and_a_half": (
+        (1, 128, 384), 192, (1, 128, 2)
+    ),
+    "a_tile_and_a_half_in_channels_of_no_whole_spans": (
+        (1, 128, 576), 192, None
+    ),
     "channels_that_are_no_whole_groups": ((1, 128, 384), 256, None),
     "channels_of_toy_width": ((1, 128, 48), 16, None),
     "rows_that_are_no_whole_blocks": ((1, 1000, 256), 128, None),

@@ -436,7 +436,8 @@ def test_the_kernels_take_a_vector_decay_where_the_shapes_allow(
     """``fits`` reads the shapes alone, and a site with a vector decay goes
     the way it says: traced at the shapes, the site is counted as the
     kernels' or not."""
-    assert kernels.fits(d_k, d_v, chunk, T, dtype) is kernel
+    # the vector kind's kernels read lane blocks of a head: whole tiles
+    assert kernels.fits(d_k, d_v, chunk, T, dtype, channel=True) is kernel
     before = trace_counts.snapshot()
     shape = lambda *s: jax.ShapeDtypeStruct(s, dtype)  # noqa: E731
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)  # noqa: E731

@@ -483,8 +483,10 @@ def test_chunked_rule_through_the_kernels_is_the_recurrence(regime):
     (128, 256, 16, 64, "bfloat16", True),
     (128, 128, 8, 64, "float32", True),
     (128, 128, 8, 64, "bfloat16", False),  # half a bfloat16 sublane tile
-    (64, 128, 64, 8192, "bfloat16", False),  # a key head of half a tile
-    (128, 192, 64, 8192, "bfloat16", False),
+    (64, 128, 64, 8192, "bfloat16", True),  # half a tile: head-major
+    (128, 192, 64, 8192, "bfloat16", True),
+    (72, 128, 64, 8192, "bfloat16", False),  # no whole quarter tiles
+    (128, 200, 64, 8192, "bfloat16", False),
     (128, 128, 64, 8192 + 32, "bfloat16", False),  # a ragged sequence
     (128, 128, 40, 40, "float32", True),  # one short chunk, whole tiles
     (128, 128, 20, 20, "float32", False),
@@ -495,9 +497,9 @@ def test_the_shapes_decide_which_way_a_chunk_is_computed(
     assert kernels.fits(d_k, d_v, chunk, T, dtype) is kernel
 
 
-@pytest.mark.parametrize("d,T", [(64, 128), (128, 20)])
+@pytest.mark.parametrize("d,T", [(72, 128), (128, 20)])
 def test_a_site_the_kernels_cannot_take_is_plain_and_says_so(d, T):
-    """A head of 64, or a sequence that is one chunk of no whole tiles:
+    """A head of 72, or a sequence that is one chunk of no whole tiles:
     the plain statement runs, to the recurrence's result, and the counts
     are a site and none in the kernel."""
     before = trace_counts.snapshot()

@@ -811,8 +811,12 @@ def test_kernel_sites_share_is_declared_as_its_reader_says():
         bench = json.load(f)
     # the cells whose configuration has the gated delta rule: Gated
     # DeltaNet's (PR 43) and, since PR 45, Kimi Delta Attention's, whose
-    # vector decay the kernels do not take (the share reads 0 there)
-    cells = ["qwen3-next-80b-a3b-d4.steady", "ling-3.0-flash-d7.steady"]
+    # vector decay the kernels do not take (the share reads 0 there);
+    # since PR 64 Olmo-Hybrid's, at heads of 96 / 192
+    cells = [
+        "qwen3-next-80b-a3b-d4.steady", "ling-3.0-flash-d7.steady",
+        "olmo-hybrid-7b-d4.steady",
+    ]
     (entry,) = [
         m for m in bench["per_layer"] if m["name"] == "gdn.kernel_sites_share"
     ]
@@ -909,7 +913,7 @@ def test_a_sites_share_is_declared_as_its_reader_says(name):
     # Kimi Delta Attention layer
     cells = [
         "nemotron3-nano-30b-a3b-d9.steady", "qwen3-next-80b-a3b-d4.steady",
-        "ling-3.0-flash-d7.steady",
+        "ling-3.0-flash-d7.steady", "olmo-hybrid-7b-d4.steady",
     ]
     # the entry by its name: later PRs append theirs behind it
     (entry,) = [e for e in bench["per_layer"] if e["name"] == name]

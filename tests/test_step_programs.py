@@ -159,7 +159,8 @@ AS_DICT_KEYS = [
     "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "conv_kernel_sites", "conv_sites",
     "donated_bytes", "donated_steps", "gate_kernel_sites", "gate_sites",
-    "gdn_chunk_steps", "gdn_kept_sites", "gdn_kernel_sites", "gdn_sites",
+    "gdn_beta_scaled_sites", "gdn_chunk_steps", "gdn_head_lanes",
+    "gdn_head_lanes_used", "gdn_kept_sites", "gdn_kernel_sites", "gdn_sites",
     "grad_bytes_raw", "grad_bytes_wire",
     "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",
@@ -171,7 +172,7 @@ AS_DICT_KEYS = [
     "prefetch_hits",
     "prefetch_misses", "prefetch_overlap_pct", "prefetch_reprimes",
     "prefetch_wait_s", "recover_detect_tick_s", "recover_persist_s",
-    "recover_respawn_s", "reshard_bytes_device",
+    "recover_respawn_s", "reordered_norm_sites", "reshard_bytes_device",
     "reshard_bytes_device_vs_host", "reshard_bytes_host", "resize_count",
     "resize_downtime_ms", "resize_idle_ranks", "resize_mb_pad",
     "restore_agree_s", "restore_bytes", "restore_h2d_s",

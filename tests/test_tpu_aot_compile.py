@@ -323,29 +323,41 @@ def test_streaming_attention_compiles_at_heads_of_256(
     assert added(before, STREAM) == (sites, 0, 136 * sites, 256 * sites)
 
 
+# T, key heads, value heads, key width, value width, inverse by halves
+DELTA_RULE_CELLS = {
+    # token-major lane blocks of a head, the product form
+    "qwen3_next": (8192, 16, 32, 128, 128, False),
+    # heads of no whole tiles, head-major; beta to 2, by halves
+    "olmo_hybrid": (16384, 30, 30, 96, 192, True),
+}
+
+
+@pytest.mark.parametrize("cell", list(DELTA_RULE_CELLS))
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_delta_rule_chunk_kernels_compile_at_the_cell(
-    direction, one_chip, monkeypatch
+    direction, cell, one_chip, monkeypatch
 ):
     """The Qwen3-Next cell's Gated DeltaNet layer, 1 x 8192 at 16 key / 32
-    value heads of 128 in chunks of 64, bfloat16: the two kernels around
+    value heads of 128 in chunks of 64, bfloat16, and the Olmo-Hybrid
+    cell's, 1 x 16384 at 30 heads of 96 / 192: the two kernels around
     the serial pass forward, all four under ``grad``; no [64, 64] square
     of a value head and chunk is left in the program around them."""
     from dlrover_tpu.ops import gated_delta
 
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
-    B, T, Hk, Hv, d, C = 1, 8192, 16, 32, 128, 64
+    T, Hk, Hv, dk, dv, halves = DELTA_RULE_CELLS[cell]
+    B, C = 1, 64
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     args = [
-        sds((B, T, Hk, d)), sds((B, T, Hk, d)), sds((B, T, Hv, d)),
+        sds((B, T, Hk, dk)), sds((B, T, Hk, dk)), sds((B, T, Hv, dv)),
         sds((B, T, Hv), jnp.float32), sds((B, T, Hv), jnp.float32),
     ]
 
     def rule(*a):
-        return gated_delta.gated_delta_chunked(*a, C)
+        return gated_delta.gated_delta_chunked(*a, C, halves)
 
     before = trace_counts.snapshot()
     if direction == "fwd":
@@ -419,12 +431,14 @@ def test_convolution_kernels_compile_at_the_cells(
     assert added(before, CONV) == (1, 1)
 
 
-# the gated norm after a scan at the three hybrid cells: 1 x 8192 x 4096
-# in bfloat16; the group's width, the gate's width, the gate inside
+# the gated norm after a scan at the hybrid cells: 1 x 8192 x 4096 in
+# bfloat16 (the Olmo-Hybrid cell: 1 x 16384 x 5760, groups of a tile and a
+# half); the group's width, the gate's width, the gate inside
 GATED_NORM_SHAPES = {
     "ling_sigmoid_a_head": (128, 32, False),
     "qwen3_next_silu_outside": (128, 4096, False),
     "nemotron_silu_inside": (512, 4096, True),
+    "olmo_hybrid_silu_outside_192": (192, 5760, False),
 }
 
 
@@ -441,7 +455,7 @@ def test_gated_norm_kernels_compile_at_the_cells(
 
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
     width, gates, inside = GATED_NORM_SHAPES[name]
-    B, T, C = 1, 8192, 4096
+    B, T, C = (1, 16384, 5760) if width == 192 else (1, 8192, 4096)
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -366,6 +366,6 @@ def test_the_edge_tile_readers_cells_are_the_streaming_ones():
         with open(path) as f:
             if reader.CELLS(json.load(f)):
                 taken.append(cell["name"])
-    assert taken == entry["workloads"] and len(taken) == 8
+    assert taken == entry["workloads"] and len(taken) == 9
     assert reader.FUSED_MAX_T == fa._FUSED_MAX_T
     assert not reader.CELLS({}) and reader.CELLS({"seq": 4096})

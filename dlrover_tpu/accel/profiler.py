@@ -181,10 +181,16 @@ class PipelineStats:
     # the step's serial depth in that layer kind; 0 / 0 for a model
     # without the kind. ``gdn_kernel_sites``: the mixers among them whose
     # chunk-local work (the [C, C] squares around the pass) was traced
-    # into the ``gdn_chunk_*`` kernels (``ops/gated_delta_kernels.fits``)
+    # into the ``gdn_chunk_*`` kernels (``ops/gated_delta_kernels.fits``);
+    # ``gdn_pass_kernel_sites``: those whose serial pass itself was traced
+    # into the ``delta_state_pass`` kernels, the state in VMEM across a
+    # head's chunks (the same rule, counted where ``gdn_sites`` is);
+    # ``gdn_chunk_steps`` counts the chunk states walked in order whoever
+    # walks them
     gdn_sites: int = 0
     gdn_chunk_steps: int = 0
     gdn_kernel_sites: int = 0
+    gdn_pass_kernel_sites: int = 0
     # the lanes of a key and a value head summed over the delta-rule sites
     # of the train step program this process traced last whose chunk-local
     # work is in the kernels: what a kernel's blocks hold (a head's width

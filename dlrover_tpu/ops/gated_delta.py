@@ -37,16 +37,24 @@ float32; matmul operands are the activation dtype, accumulated in float32.
 No decay is ever divided by: every exponent above is <= 0.
 
 The pass is the layer's serial depth: ``T / chunk`` steps forward and as
-many backward, each two small matmuls a head; everything that does not
-depend on the carried state (``U``, ``W``, the read-out, and in the
-backward pass the cotangents of ``W``, ``K`` and the decays) is batched
-over all chunks outside it. It is differentiated by hand for that reason
-(``jax.grad`` of the forward loop carries all of a step's matmuls through
-the reversed loop and keeps the float32 state of every chunk).
+many backward, each two small matmuls a head; ``U``, ``W`` and the read-out
+do not depend on the carried state and are batched over all chunks outside
+it. It is differentiated by hand (``jax.grad`` of the forward loop carries
+all of a step's matmuls through the reversed loop and keeps the float32
+state of every chunk). It is executed one of two ways, chosen from its own
+arguments by the rule of the chunk-local work around it
+(``gated_delta_kernels.fits``): as two kernels that keep a head's state in
+VMEM across a layer's chunks and make the cotangents of ``U``, ``W``, ``K``
+and the decays in the reversed step that holds their operands
+(``gated_delta_kernels.state_pass`` / ``state_pass_rev``), or, at every
+other shape, as a ``lax.scan`` each way with those cotangents as einsums
+over all chunks after the reversed one (``_pass_scan`` / ``_pass_scan_bwd``:
+the statement the kernels are held to).
 ``common/trace_counts`` holds the passes traced (``gdn_sites``, one a
 mixer of a program), their sequential steps, a backward pass counted with
-its forward (``gdn_chunk_steps``), and the sites whose chunk-local work
-went into the kernels (``gdn_kernel_sites``).
+its forward (``gdn_chunk_steps``: the chunk states walked in order, whoever
+walks them), the sites whose chunk-local work went into the kernels
+(``gdn_kernel_sites``) and those whose pass did (``gdn_pass_kernel_sites``).
 
 **What a recomputed layer keeps.** The pass's forward rule names what it
 hands its backward rule (``W``, the keys as the pass took them, ``delta``,
@@ -326,8 +334,16 @@ def _decay_rows(delta, V):
     return V if delta is None else delta[..., None] * V
 
 
+def _pass_in_kernels(U, W, delta) -> bool:
+    """Which way the pass is executed, by the one rule of the chunk-local
+    work around it (``gated_delta_kernels.fits``), read from the pass's own
+    arguments: a sequence is the chunks it came in."""
+    n, C, d_k = W.shape[0], W.shape[-2], W.shape[-1]
+    return kernels.fits(d_k, U.shape[-1], C, n * C, W.dtype, delta is None)
+
+
 def _pass_forward(U, W, K, delta, a):
-    f32, act = jnp.float32, W.dtype
+    """The pass's forward, counted where both ways to execute it pass."""
     # the primal trace of a recomputed layer that keeps ``KEPT``: its
     # backward pass reads what the forward rule left and runs no forward
     # pass again, so the steps that run are those of the rule's own trace
@@ -336,6 +352,17 @@ def _pass_forward(U, W, K, delta, a):
     trace_counts.count("gdn_sites")
     trace_counts.count("gdn_kept_sites", kept)
     trace_counts.count("gdn_chunk_steps", 0 if kept else U.shape[0])
+    if _pass_in_kernels(U, W, delta):
+        trace_counts.count("gdn_pass_kernel_sites")
+        return tuple(kernels.state_pass(U, W, K, delta, a))
+    return _pass_scan(U, W, K, delta, a)
+
+
+def _pass_scan(U, W, K, delta, a):
+    """The plain statement: a ``lax.scan`` over the chunks with the float32
+    state its carry. What runs at every shape ``kernels.fits`` refuses, and
+    what the pass's kernels are held to."""
+    f32, act = jnp.float32, W.dtype
 
     def step(S, x):
         U, W, K, delta, a = x
@@ -379,16 +406,24 @@ def _chunk_state_pass_fwd(U, W, K, delta, a):
 
 
 def _chunk_state_pass_bwd(res, cts):
-    """The reversed pass carries the cotangent of the state alone: a step
-    is ``dV'`` of the state's update (one matmul) and the state's own
-    cotangent (one more). What the steps leave behind (the cotangent of
-    every chunk's leaving state, of its decayed ``V'``) gives the
-    cotangents of ``W``, ``K`` and the decays in matmuls over all chunks
-    at once, after the loop."""
+    """The reversed pass, counted where both ways to execute it pass."""
+    W, K, delta, a, Vn, S_in = res
+    trace_counts.count("gdn_chunk_steps", W.shape[0])
+    if _pass_in_kernels(Vn, W, delta):
+        return kernels.state_pass_rev(*res, *cts)
+    return _pass_scan_bwd(res, cts)
+
+
+def _pass_scan_bwd(res, cts):
+    """The plain statement of the reversed pass. It carries the cotangent
+    of the state alone: a step is ``dV'`` of the state's update (one
+    matmul) and the state's own cotangent (one more). What the steps leave
+    behind (the cotangent of every chunk's leaving state, of its decayed
+    ``V'``) gives the cotangents of ``W``, ``K`` and the decays in matmuls
+    over all chunks at once, after the loop."""
     W, K, delta, a, Vn, S_in = res
     dVn, dS_in = cts
     f32, act = jnp.float32, W.dtype
-    trace_counts.count("gdn_chunk_steps", W.shape[0])
 
     def step(dS, x):  # dS: of the state that LEFT this chunk
         W, K, delta, a, dVn, dS_in = x

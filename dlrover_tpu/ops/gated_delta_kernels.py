@@ -33,6 +33,10 @@ stacks two consecutive chunks of a head, the decayed operands of a masked
 square are made in VMEM in row blocks of 16 steps, and the inverse is the
 one by halves.
 
+The serial pass between the two stretches, either kind's, is two kernels of
+its own (``state_pass`` / ``state_pass_rev``, the third part of this file):
+a head's float32 state stays in VMEM across a layer's chunks.
+
 The precision is the plain statement's: decays, their sums and the inverse
 float32, the inverse's products at ``Precision.HIGHEST``; every other
 matmul takes the activation dtype and accumulates in float32 (a float32
@@ -1130,3 +1134,266 @@ def _channel_read_bwd(res, do):
 
 
 read_out_channel.defvjp(_channel_read_fwd, _channel_read_bwd)
+
+
+# -- the serial pass over the chunk states -----------------------------------
+#
+# ``gated_delta.chunk_state_pass`` as two kernels, ``delta_state_pass`` and
+# ``delta_state_pass_rev`` (names that no reader of the ``gdn_chunk`` or
+# ``gdn_*_fwd`` kernels takes: the pass is in neither side of their
+# roofline). A program is one ``(batch, run of key heads, run of chunks)``,
+# the chunk runs walked in order (the grid's last dimension is serial) and a
+# run's chunks by a ``fori_loop``; the float32 state of each of the
+# program's heads stays in a VMEM scratch from a head's first chunk to its
+# last, so a step reads and writes what the pass's contract names and
+# nothing else. A chunk's two products wait for each other, another head's
+# do not: a program holds all ``r`` value heads of a key head and as many
+# key heads as ``_pass_block`` says. The reversed kernel walks the same grid
+# from the last chunk with the state's cotangent in the scratch, and makes
+# ``dU``, ``dW``, ``dK`` and the decays' cotangents in the step that holds
+# their operands: the cotangent of a chunk's leaving state and of its
+# decayed ``V'`` are never written. Both kinds of decay run the one body,
+# told apart as ``chunk_state_pass`` tells them: ``delta`` None and ``a`` a
+# row over the key's channels, or ``delta`` a row a value head and ``a`` a
+# scalar. The arithmetic is the plain statement's.
+
+_PASS_VMEM = 64 << 20  # what Mosaic may take; the blocks stay well under
+_PASS_BLOCK_BYTES = 24 << 20  # a program's blocks, both buffers of each
+_PASS_HEADS = (4, 3, 2, 1)  # key heads a program
+
+
+def _pass_block(n: int, g: int, r: int, C: int, d_k: int, d_v: int,
+                itemsize: int):
+    """``(key heads, chunks)`` a program of the pass, from the shapes: the
+    chunks a program of the chunk kernels takes, and the most key heads
+    whose blocks (the reversed kernel's, the larger set; a head's width in
+    whole lane tiles, as VMEM holds it) stay inside ``_PASS_BLOCK_BYTES``."""
+    m = next(m for m in _CHUNKS_A_PROGRAM if n % m == 0)
+    lk, lv = (-(-d // _LANES) * _LANES for d in (d_k, d_v))
+    a_head = (
+        (2 * r + 2) * C * lk * itemsize  # W, dW, K, dK
+        + 2 * r * (C + d_k) * lv * itemsize  # V', dV', the states, theirs
+        + r * C * lv * 4  # dU
+    )
+    fit = _PASS_BLOCK_BYTES // (2 * m * a_head)
+    return next((p for p in _PASS_HEADS if g % p == 0 and p <= fit), 1), m
+
+
+def _turning(w_ref, scalar: bool) -> _Geometry:
+    """The masks that turn a decay's row into a column (``_as_col``): a
+    chunk's positions for a scalar decay's ``delta``, the key's channels
+    where ``a`` is a row over them."""
+    return _geometry(w_ref.shape[-2] if scalar else w_ref.shape[-1], 1)
+
+
+def _pass_fwd_kernel(*refs, r, m, p, scalar):
+    if scalar:
+        u_ref, w_ref, k_ref, delta_ref, a_ref, vn_ref, sin_ref, s_ref = refs
+    else:
+        u_ref, w_ref, k_ref, a_ref, vn_ref, sin_ref, s_ref = refs
+    act = w_ref.dtype
+    geo = _turning(w_ref, scalar)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    def chunk(c, carry):
+        for h in range(p):
+            K = k_ref[c, h]
+            for j in range(r):
+                S = s_ref[h, j]
+                Sb = S.astype(act)
+                sin_ref[c, h, j] = Sb
+                Vn = u_ref[c, h, j] - _dot(w_ref[c, h, j], Sb)
+                Vb = Vn.astype(act)
+                vn_ref[c, h, j] = Vb
+                if scalar:
+                    delta = _as_col(delta_ref[c, h, pl.ds(j, 1), :], geo)
+                    Vb = (delta * Vn).astype(act)
+                    a = a_ref[c, h][:, j:j + 1]
+                else:
+                    a = _as_col(a_ref[c, h], geo)
+                s_ref[h, j] = a * S + _dot(K, Vb, _TN)
+        return carry
+
+    lax.fori_loop(0, m, chunk, 0)
+
+
+def _pass_bwd_kernel(*refs, r, m, p, scalar):
+    if scalar:
+        (w_ref, k_ref, delta_ref, a_ref, vn_ref, sin_ref, dvn_ref, dsin_ref,
+         du_ref, dw_ref, dk_ref, ddelta_ref, da_ref, ds_ref) = refs
+    else:
+        (w_ref, k_ref, a_ref, vn_ref, sin_ref, dvn_ref, dsin_ref,
+         du_ref, dw_ref, dk_ref, da_ref, ds_ref) = refs
+    act = w_ref.dtype
+    geo = _turning(w_ref, scalar)
+    lane = lax.broadcasted_iota(jnp.int32, (1, r), 1)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+
+    def chunk(t, carry):
+        c = m - 1 - t
+        for h in range(p):
+            K = k_ref[c, h]
+            dK, da_row = None, jnp.zeros((1, r), _F32)
+            for j in range(r):
+                dS = ds_ref[h, j]  # of the state that LEFT this chunk
+                dSb = dS.astype(act)
+                S_in, Vb = sin_ref[c, h, j], vn_ref[c, h, j]
+                dVd = _dot(K, dSb)
+                dU = dvn_ref[c, h, j].astype(_F32)
+                if scalar:
+                    delta = _as_col(delta_ref[c, h, pl.ds(j, 1), :], geo)
+                    Vf = Vb.astype(_F32)
+                    dU = dU + delta * dVd
+                    Vb = (delta * Vf).astype(act)
+                    ddelta_ref[c, h, pl.ds(j, 1), :] = _as_row(
+                        jnp.sum(dVd * Vf, axis=1, keepdims=True), geo
+                    )
+                else:
+                    dU = dU + dVd
+                du_ref[c, h, j] = dU
+                dUb = dU.astype(act)
+                dw_ref[c, h, j] = (-_dot(dUb, S_in, _NT)).astype(act)
+                dK_j = _dot(Vb, dSb, _NT)
+                dK = dK_j if dK is None else dK + dK_j
+                # the state's decay: against the state that entered
+                over_v = jnp.sum(
+                    dSb.astype(_F32) * S_in.astype(_F32), axis=1,
+                    keepdims=True,
+                )
+                if scalar:
+                    da_row = jnp.where(
+                        lane == j, jnp.sum(over_v, axis=0, keepdims=True),
+                        da_row,
+                    )
+                    a = a_ref[c, h][:, j:j + 1]
+                else:
+                    da_ref[c, h] = _as_row(over_v, geo)
+                    a = _as_col(a_ref[c, h], geo)
+                ds_ref[h, j] = (
+                    a * dS + dsin_ref[c, h, j].astype(_F32)
+                    - _dot(w_ref[c, h, j], dUb, _TN)
+                )
+            dk_ref[c, h] = dK.astype(act)
+            if scalar:
+                da_ref[c, h] = da_row
+        return carry
+
+    lax.fori_loop(0, m, chunk, 0)
+
+
+def _pass_call(kernel, name, W, block, interpret: bool, reverse: bool,
+               scalar: bool, ins, tails, out_tails, out_dtypes, d_v: int):
+    """One of the pass's two kernels over chunk-major arrays ``[n, b, g,
+    *tail]``: every block a program's run of ``m`` chunks of its run of
+    ``p`` key heads (``block``), the runs walked from the last where
+    ``reverse``."""
+    n, b, g, r, _, d_k = W.shape
+    p, m = block
+    last = n // m - 1
+
+    def spec(tail):
+        zeros = (0,) * len(tail)
+        if reverse:
+            return pl.BlockSpec(
+                (m, None, p) + tail, lambda b, h, i: (last - i, b, h) + zeros
+            )
+        return pl.BlockSpec(
+            (m, None, p) + tail, lambda b, h, i: (i, b, h) + zeros
+        )
+
+    return pl.pallas_call(
+        functools.partial(kernel, r=r, m=m, p=p, scalar=scalar),
+        name=name,
+        grid=(b, g // p, n // m),
+        in_specs=[spec(t) for t in tails],
+        out_specs=[spec(t) for t in out_tails],
+        out_shape=[
+            jax.ShapeDtypeStruct((n, b, g) + t, dt)
+            for t, dt in zip(out_tails, out_dtypes)
+        ],
+        scratch_shapes=[pltpu.VMEM((p, r, d_k, d_v), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_PASS_VMEM,
+        ),
+        interpret=interpret,
+    )(*ins)
+
+
+def _decay_blocks(delta, a):
+    """The decays as the pass's kernels take them, and their blocks'
+    tails: ``delta`` [n, b, g, r, C] as it is and ``a`` [n, b, g, r] as a
+    row a key head, or (``delta`` None) ``a`` [n, b, g, 1, d_k] alone."""
+    if delta is None:
+        return [a], [a.shape[3:]]
+    return [delta, a[:, :, :, None]], [delta.shape[3:], (1, a.shape[3])]
+
+
+_PASS_STATIC = ("block", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_PASS_STATIC)
+def _state_pass(U, W, K, delta, a, *, block, interpret):
+    """One jit for every call site (``gated_norm_kernels._fwd_call``'s
+    reason): a program traces and lowers the kernel once a shape and calls
+    it once a layer."""
+    r, C, d_k = W.shape[3:]
+    d_v, act = U.shape[-1], W.dtype
+    decays, tails = _decay_blocks(delta, a)
+    return _pass_call(
+        _pass_fwd_kernel, "delta_state_pass", W, block, interpret, False,
+        delta is not None, [U, W, K, *decays],
+        [(r, C, d_v), (r, C, d_k), (C, d_k), *tails],
+        [(r, C, d_v), (r, d_k, d_v)], [act, act], d_v,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=_PASS_STATIC)
+def _state_pass_rev(W, K, delta, a, Vn, S_in, dVn, dS_in, *, block,
+                    interpret):
+    r, C, d_k = W.shape[3:]
+    d_v, act = Vn.shape[-1], W.dtype
+    decays, tails = _decay_blocks(delta, a)
+    rows, states = (r, C, d_v), (r, d_k, d_v)
+    dU, dW, dK, *ddecays = _pass_call(
+        _pass_bwd_kernel, "delta_state_pass_rev", W, block, interpret, True,
+        delta is not None, [W, K, *decays, Vn, S_in, dVn, dS_in],
+        [(r, C, d_k), (C, d_k), *tails, rows, states, rows, states],
+        [rows, (r, C, d_k), (C, d_k), *tails],
+        [_F32, act, act] + [_F32] * len(tails), d_v,
+    )
+    if delta is None:
+        return dU, dW, dK, None, ddecays[0]
+    return dU, dW, dK, ddecays[0], ddecays[1][:, :, :, 0]
+
+
+def _pass_static(W, d_v: int):
+    """What the pass's jits are keyed by beside their arguments' shapes."""
+    n, _, g, r, C, d_k = W.shape
+    return dict(
+        block=_pass_block(n, g, r, C, d_k, d_v, W.dtype.itemsize),
+        interpret=_flash._interpret_default(),
+    )
+
+
+def state_pass(U, W, K, delta, a):
+    """``gated_delta.chunk_state_pass``'s forward, its arguments and its
+    two results, with the state in VMEM across a head's chunks."""
+    return _state_pass(U, W, K, delta, a, **_pass_static(W, U.shape[-1]))
+
+
+def state_pass_rev(W, K, delta, a, Vn, S_in, dVn, dS_in):
+    """The reversed pass: from what the forward read and returned and the
+    cotangents of its two results, the cotangents of ``U, W, K, delta, a``
+    in ``gated_delta._pass_scan_bwd``'s dtypes (``delta``'s None where
+    ``delta`` is)."""
+    return _state_pass_rev(
+        W, K, delta, a, Vn, S_in, dVn, dS_in,
+        **_pass_static(W, Vn.shape[-1]),
+    )

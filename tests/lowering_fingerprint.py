@@ -103,6 +103,14 @@ output) and left the ten entries before it byte for byte as they were:
 ``gdn_beta_scale`` 1 is no product and the product form, no
 ``reordered_norm_kinds`` is a ``norm`` leaf an entry, heads of whole tiles
 are read token-major as before.
+ISSUE 65 recorded the steps of ``qwen3-next-80b-a3b-d4``,
+``ling-3.0-flash-d7`` and ``olmo-hybrid-7b-d4`` anew (and the
+``step_without_names`` beside each), their trees as they were: where the
+chunk-local work runs in kernels the serial pass between its two stretches
+does too (``delta_state_pass`` / ``delta_state_pass_rev``, interpreted on
+the CPU), where it was a ``lax.scan`` each way and einsums over all chunks
+after the reversed one. The eight configurations without a ``G`` layer
+never reach ``chunk_state_pass`` and are byte for byte what they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py

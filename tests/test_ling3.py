@@ -62,6 +62,7 @@ from dlrover_tpu.parallel.moe import (
 )
 from dlrover_tpu.trainer.elastic.trainer import build_optimizer
 from lowering_fingerprint import inner_numbers_off
+from pass_parity import check_pass
 from trace_counted import GDN, GDN_KEPT, LANES, added
 
 RTOL = 2e-5
@@ -509,6 +510,16 @@ def test_vector_decay_kernels_are_the_plain_statement(dtype, regime):
     ):
         assert a.shape == b.shape and np.all(np.isfinite(a)), name
         assert np.max(np.abs(a - b)) <= t * np.max(np.abs(b)), name
+
+
+@pytest.mark.parametrize("n,blocks", [(6, 3), (5, 5)])
+@pytest.mark.parametrize("dtype", sorted(KERNEL_TOL))
+def test_the_pass_kernels_take_a_vector_decay(dtype, n, blocks):
+    """The serial pass with the state in VMEM (ISSUE 65) where ``a`` is a
+    row over the key's channels and the keys come decayed (``delta`` None):
+    four heads of 128 / 128, the state carried across 3 and 5 runs of
+    chunks, forward and reversed, against the plain scan."""
+    check_pass(n, 1, 4, 1, 16, 128, 128, True, dtype, blocks)
 
 
 @pytest.mark.parametrize("regime", sorted(REGIMES))

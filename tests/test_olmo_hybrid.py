@@ -44,6 +44,7 @@ from dlrover_tpu.ops import gated_delta_kernels as kernels
 from dlrover_tpu.ops.gated_delta import gated_delta_chunked, l2norm
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import build_optimizer
+from pass_parity import check_pass
 from test_qwen3_next import _cotangents, _within, delta_rule_sequential
 from trace_counted import GDN, added
 
@@ -452,6 +453,15 @@ def test_chunk_kernels_at_96_and_192_are_the_plain_statement(stretch, dtype):
     for a, b in zip(vjp_got(cts), vjp_want(cts)):
         assert a.shape == b.shape and a.dtype == b.dtype
         assert _within(a, b, grad_tol)
+
+
+@pytest.mark.parametrize("n,blocks", [(6, 3), (5, 5)])
+@pytest.mark.parametrize("dtype", sorted(KERNEL_TOL))
+def test_the_pass_kernels_at_96_and_192_are_the_plain_scan(dtype, n, blocks):
+    """The serial pass with the state in VMEM (ISSUE 65) at heads of 96 /
+    192, a head's width a block's whole minor dimension: three heads, the
+    state carried across 3 and 5 runs of chunks, against the plain scan."""
+    check_pass(n, 1, 3, 1, 16, 96, 192, False, dtype, blocks)
 
 
 @pytest.mark.parametrize("d_k,d_v,channel,kernel", [

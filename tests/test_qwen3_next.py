@@ -53,7 +53,8 @@ from dlrover_tpu.ops.mamba2 import gated_group_rmsnorm
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.parallel.moe import init_moe_params, moe_layer_local, route
 from dlrover_tpu.trainer.elastic.trainer import build_optimizer
-from trace_counted import GDN, added
+from pass_parity import check_pass, pass_inputs
+from trace_counted import GDN, PASS, added
 
 RTOL = 2e-5
 GRAD_RTOL = 2e-4  # a gradient sums more terms in another order
@@ -449,6 +450,35 @@ def test_chunk_kernels_are_the_plain_statement(stretch, dtype, regime):
     for a, b in zip(vjp_got(cts), vjp_want(cts)):
         assert a.shape == b.shape and a.dtype == b.dtype
         assert _within(a, b, grad_tol)
+
+
+@pytest.mark.parametrize("n,blocks", [(6, 3), (5, 5)])
+@pytest.mark.parametrize("dtype", sorted(KERNEL_TOL))
+def test_the_pass_kernels_are_the_plain_scan(dtype, n, blocks):
+    """The serial pass over the chunk states with the state in VMEM (ISSUE
+    65), ``interpret``: two key heads of two value heads of 128 / 128, the
+    state carried across 3 and 5 runs of chunks, forward and reversed,
+    against ``_pass_scan`` / ``_pass_scan_bwd``."""
+    check_pass(n, 1, 2, 2, 16, 128, 128, False, dtype, blocks)
+
+
+def test_a_pass_the_kernels_cannot_take_is_the_scan():
+    """At a shape ``fits`` refuses (a head of 72) ``chunk_state_pass`` is
+    the ``lax.scan`` it was, forward and reversed, and counts no kernel."""
+    args = pass_inputs(4, 1, 2, 2, 16, 72, 72, False, jnp.float32)
+    before = trace_counts.snapshot()
+
+    def loss(*a):
+        Vn, S_in = gated_delta.chunk_state_pass(*a)
+        return jnp.sum(Vn) + jnp.sum(S_in)
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=range(5)))(*args))
+    assert text.count("scan[") == 2 and "pallas_call" not in text
+    assert added(before, GDN + PASS) == (1, 8, 0, 0)
+    kernel = pass_inputs(4, 1, 2, 2, 16, 128, 128, False, jnp.float32)
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=range(5)))(*kernel))
+    assert text.count("pallas_call") == 2
+    assert added(before, GDN + PASS) == (2, 16, 0, 1)
 
 
 @pytest.mark.parametrize("regime", sorted(KERNEL_REGIMES))

@@ -106,16 +106,14 @@ def _grad(cfg):
 
 def _kernels_in(jaxpr, found=None):
     """Every ``pallas_call`` of ``jaxpr`` and of the jaxprs inside it, by
-    the kernel's name; and under ``pass_forward`` / ``pass_backward`` the
-    loops of the delta rule's serial pass (as many steps as chunks)."""
+    the kernel's name (the delta rule's serial pass is two of them,
+    ``delta_state_pass`` and ``delta_state_pass_rev``); a kernel's own
+    body is not looked into."""
     found = collections.Counter() if found is None else found
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             found[eqn.params["name"]] += 1
-        if eqn.primitive.name == "scan" and eqn.params["length"] == T // CHUNK:
-            found[
-                "pass_backward" if eqn.params["reverse"] else "pass_forward"
-            ] += 1
+            continue
         for inner in jax.core.jaxprs_in_params(eqn.params):
             _kernels_in(inner, found)
     return found
@@ -141,17 +139,16 @@ def test_the_gradient_holds_each_forward_attention_kernel_once(
     monkeypatch.setattr(tr, "recomputed", jax.checkpoint)
     before = trace_counts.snapshot()
     bare = _kernels_in(jax.make_jaxpr(_grad(cfg))(*args).jaxpr)
-    forward = [n for n in bare if n.endswith("_fwd")]
-    backward = [n for n in bare if "_bwd" in n]
+    forward = [n for n in bare if n.endswith(("_fwd", "_pass"))]
+    backward = [n for n in bare if n.endswith(("_bwd", "_rev"))]
     layers = _mixing_layers(cfg)
     if delta_rule:
-        # each of the rule's two kernels and the pass's forward loop; the
+        # each of the rule's two kernels and the pass's forward kernel; the
         # convolution before them is made again (the mixer names what it
         # read, not what it returned), and so is the gated norm after them
         # (its result is not kept: 64 MiB a layer of the Ling cell)
-        assert set(KINDS[kind][1]) <= set(forward)
-        forward.append("pass_forward")
-        backward.append("pass_backward")
+        assert set(KINDS[kind][1]) | {"delta_state_pass"} <= set(forward)
+        assert "delta_state_pass_rev" in backward
         again = [
             n for n in forward if n.startswith(("conv_", "gated_norm_"))
         ]

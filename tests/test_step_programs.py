@@ -160,7 +160,8 @@ AS_DICT_KEYS = [
     "compile_cache_misses", "conv_kernel_sites", "conv_sites",
     "donated_bytes", "donated_steps", "gate_kernel_sites", "gate_sites",
     "gdn_beta_scaled_sites", "gdn_chunk_steps", "gdn_head_lanes",
-    "gdn_head_lanes_used", "gdn_kept_sites", "gdn_kernel_sites", "gdn_sites",
+    "gdn_head_lanes_used", "gdn_kept_sites", "gdn_kernel_sites",
+    "gdn_pass_kernel_sites", "gdn_sites",
     "grad_bytes_raw", "grad_bytes_wire",
     "grad_bytes_wire_vs_raw",
     "grad_sync_dcn_ms", "grad_sync_explicit", "grad_sync_ici_ms",

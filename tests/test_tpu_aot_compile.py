@@ -23,7 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.common import trace_counts
 from trace_counted import (
-    CONV, DIFF, EDGE, FUSED, GATE, GDN, LANES, SSCAN, STREAM, WINDOW, added,
+    CONV, DIFF, EDGE, FUSED, GATE, GDN, LANES, PASS, SSCAN, STREAM, WINDOW,
+    added,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -323,6 +324,9 @@ def test_streaming_attention_compiles_at_heads_of_256(
     assert added(before, STREAM) == (sites, 0, 136 * sites, 256 * sites)
 
 
+# the serial pass between a kind's two stretches, forward and reversed
+PASS_KERNELS = ["delta_state_pass", "delta_state_pass_rev"]
+
 # T, key heads, value heads, key width, value width, inverse by halves
 DELTA_RULE_CELLS = {
     # token-major lane blocks of a head, the product form
@@ -363,6 +367,7 @@ def test_delta_rule_chunk_kernels_compile_at_the_cell(
     if direction == "fwd":
         text = _compile_for_chip(rule, *args).as_text()
         want, steps = ["gdn_chunk_wy_fwd", "gdn_chunk_read_fwd"], T // C
+        want += PASS_KERNELS[:1]
     else:
         text = _compile_for_chip(
             jax.grad(lambda *a: jnp.sum(rule(*a) ** 2), argnums=range(5)),
@@ -370,13 +375,14 @@ def test_delta_rule_chunk_kernels_compile_at_the_cell(
         ).as_text()
         want = [
             "gdn_chunk_wy_fwd", "gdn_chunk_read_fwd",
-            "gdn_chunk_wy_bwd", "gdn_chunk_read_bwd",
+            "gdn_chunk_wy_bwd", "gdn_chunk_read_bwd", *PASS_KERNELS,
         ]
         steps = 2 * T // C
     for kernel in want:
         assert kernel in text, kernel
     assert f"f32[{T // C},{B},{Hk},{Hv // Hk},{C},{C}]" not in text
-    assert added(before, GDN) == (1, steps, 1)
+    assert "while(" not in text  # the pass is no loop of XLA's any more
+    assert added(before, GDN + PASS) == (1, steps, 1, 1)
 
 
 CONV_SHAPES = {
@@ -522,20 +528,21 @@ def test_vector_decay_chunk_kernels_compile_at_the_cell(
     before = trace_counts.snapshot()
     if direction == "fwd":
         text = _compile_for_chip(rule, *args).as_text()
-        want, steps = CHANNEL_KERNELS[:2], T // C
+        want, steps = CHANNEL_KERNELS[:2] + PASS_KERNELS[:1], T // C
     else:
         text = _compile_for_chip(
             jax.grad(lambda *a: jnp.sum(rule(*a) ** 2), argnums=range(5)),
             *args,
         ).as_text()
-        want, steps = CHANNEL_KERNELS, 2 * T // C
+        want, steps = CHANNEL_KERNELS + PASS_KERNELS, 2 * T // C
     for kernel in want:
         assert kernel in text, kernel
     assert "gdn_chunk_" not in text  # the scalar kind's are not this site's
+    assert "while(" not in text  # the pass is no loop of XLA's any more
     n = T // C
     assert f"f32[{n},{B},{H},{C},{C}]" not in text
     assert f"bf16[{n},{B},{H},{C // 16},{C},{d}]" not in text
-    assert added(before, GDN) == (1, steps, 1)
+    assert added(before, GDN + PASS) == (1, steps, 1, 1)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -947,7 +954,7 @@ def test_sharded_delta_rule_compiles_for_four_chips(topo, monkeypatch):
         ),
         *args,
     ).as_text()
-    for kernel in ("gdn_chunk_wy_bwd", "gdn_chunk_read_bwd"):
+    for kernel in ("gdn_chunk_wy_bwd", "gdn_chunk_read_bwd", *PASS_KERNELS):
         assert kernel in text, kernel
     with pytest.raises(ValueError, match="do not divide dp\\*fsdp=2"):
         gated_delta._delta_rule(
@@ -992,7 +999,7 @@ def test_sharded_vector_decay_rule_compiles_for_four_chips(topo, monkeypatch):
         ),
         *args,
     ).as_text()
-    for kernel in CHANNEL_KERNELS:
+    for kernel in CHANNEL_KERNELS + PASS_KERNELS:
         assert kernel in text, kernel
     # a device's share: one batch element, 16 heads
     assert f"f32[1,{T},{16 * d}]" in text

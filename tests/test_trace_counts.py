@@ -27,8 +27,8 @@ from dlrover_tpu.models.transformer import init_params
 from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
-    CONV, DIFF, EDGE, FUSED, GATE, GDN, GDN_KEPT, KEPT, LANES, SCALED, SHARE,
-    SSCAN, STREAM, UT, WINDOW, XDEC,
+    CONV, DIFF, EDGE, FUSED, GATE, GDN, GDN_KEPT, KEPT, LANES, PASS, SCALED,
+    SHARE, SSCAN, STREAM, UT, WINDOW, XDEC, added,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -207,10 +207,11 @@ FOLDS = {
     # a step whose mixers have heads of whole lane tiles
     "delta_rule_in_the_kernels": [
         "step_donating",
-        dict(zip(GDN, (1, 8, 1))),
+        dict(zip(GDN + PASS, (1, 8, 1, 1))),
         (
-            "; traced: gdn_sites =1, gdn_chunk_steps =8, gdn_kernel_sites =1",
-            dict(zip(GDN, (1, 8, 1))),
+            "; traced: gdn_sites =1, gdn_chunk_steps =8, "
+            "gdn_kernel_sites =1, gdn_pass_kernel_sites =1",
+            dict(zip(GDN + PASS, (1, 8, 1, 1))),
         ),
     ],
     # a layer traced twice under ``jax.checkpoint`` counts twice in both,
@@ -561,3 +562,113 @@ def test_the_loop_imports_no_module_that_counts():
             named |= {f"{node.module}.{a.name}" for a in node.names}
     assert "dlrover_tpu.common.trace_counts" in named  # the walk sees them
     assert not named & KERNEL_MODULES
+
+
+# -- the delta rule's serial pass as kernels (ISSUE 65) ----------------------
+
+PASS_METRIC = "gdn.pass_kernel_sites_share"
+
+
+def _reader(metric):
+    path = os.path.join(ROOT, "benchmark", "layer_metrics", metric + ".py")
+    spec = importlib.util.spec_from_file_location("reader_under_test", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _delta_rule_grad(kind):
+    """``gated_delta_chunked`` under ``grad`` at shapes the kernels take,
+    traced and not run, as a jaxpr: two key heads of 128 / 128 (each of
+    two value heads where the decay is a scalar), four chunks of 16."""
+    from dlrover_tpu.ops import gated_delta
+
+    B, T, Hk, d, C = 1, 64, 2, 128, 16
+    Hv = Hk if kind == "channel" else 2 * Hk
+    f32 = jnp.float32
+    args = [
+        jax.ShapeDtypeStruct(shape, f32) for shape in (
+            (B, T, Hk, d), (B, T, Hk, d), (B, T, Hv, d), (B, T, Hv),
+            (B, T, Hv, d) if kind == "channel" else (B, T, Hv),
+        )
+    ]
+    return jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(gated_delta.gated_delta_chunked(*a, C)),
+        argnums=range(5),
+    ))(*args).jaxpr
+
+
+@pytest.mark.parametrize("kind", ["head", "channel"])
+def test_no_reader_of_the_chunk_kernels_takes_a_kernel_of_the_pass(kind):
+    """``kernel.gdn_roofline`` sums every kernel whose name holds its
+    ``NAME`` against work that counts the four chunk kernels alone, and
+    ``gdn.fwd_kernel_runs_per_step`` counts names that hold its ``NAME``
+    and ``FORWARD``: the pass's two kernels are neither's."""
+    from test_recomputed_layer import _kernels_in
+
+    names = _kernels_in(_delta_rule_grad(kind))
+    of_the_pass = sorted(n for n in names if "gdn_" not in n)
+    assert of_the_pass == ["delta_state_pass", "delta_state_pass_rev"]
+    assert len(names) == 6  # the kind's four around them, once each
+    assert set(names.values()) == {1}
+    roofline = _reader("kernel.gdn_roofline")
+    runs = _reader("gdn.fwd_kernel_runs_per_step")
+    for name in of_the_pass:
+        assert roofline.NAME not in name.lower()
+        assert not (
+            runs.NAME in name.lower() and runs.FORWARD in name.lower()
+        )
+
+
+@pytest.mark.parametrize("kind", ["head", "channel"])
+def test_the_chunk_steps_count_the_same_whoever_walks_them(kind, monkeypatch):
+    """A site of the kernels counts its pass in the kernels too, and the
+    chunk states walked in order are the plain way's number."""
+    from dlrover_tpu.ops import gated_delta_kernels
+
+    before = trace_counts.snapshot()
+    _delta_rule_grad(kind)
+    assert added(before, GDN + PASS) == (1, 8, 1, 1)
+    monkeypatch.setattr(gated_delta_kernels, "fits", lambda *a, **k: False)
+    before = trace_counts.snapshot()
+    _delta_rule_grad(kind)
+    assert added(before, GDN + PASS) == (1, 8, 0, 0)
+
+
+@pytest.mark.parametrize("model,pipeline,reads", [
+    ({"layer_pattern": "GGG*"}, None, None),
+    # the parent of PR 65: sites, and no such counter
+    ({"layer_pattern": "GGG*"}, {"gdn_sites": 6, "gdn_kernel_sites": 6}, None),
+    ({"layer_pattern": "GGG*"}, {"gdn_pass_kernel_sites": 0}, None),
+    ({"layer_pattern": "GGG*"},
+     {"gdn_sites": 6, "gdn_pass_kernel_sites": 6}, 100.0),
+    ({"layer_pattern": "GGG*"},
+     {"gdn_sites": 12, "gdn_pass_kernel_sites": 3}, 25.0),
+    ({"layer_pattern": "M*"},
+     {"gdn_sites": 6, "gdn_pass_kernel_sites": 6}, None),
+], ids=["no_stats", "no_counter", "no_site", "all", "some", "no_such_layer"])
+def test_the_pass_share_reader_reads_the_two_counts(model, pipeline, reads):
+    run = types.SimpleNamespace(
+        config={"model": model}, window={"pipeline": pipeline}
+    )
+    assert _reader(PASS_METRIC).read(run) == reads
+
+
+def test_the_pass_share_is_listed_in_the_cells_its_rule_takes():
+    """The metric's ``workloads``, found by its name and not by its place
+    in ``per_layer``, are the cells its ``CELLS`` rule takes: the three
+    whose configuration names a Gated DeltaNet layer."""
+    import json
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == PASS_METRIC]
+    reader = _reader(PASS_METRIC)
+    assert (entry["unit"], entry["layer"], entry["moves"]) == (
+        reader.UNIT, reader.LAYER, reader.MOVES
+    )
+    taken = [w["name"] for w in bench["workloads"] if reader.CELLS(w)]
+    assert entry["workloads"] == taken == [
+        "qwen3-next-80b-a3b-d4.steady", "ling-3.0-flash-d7.steady",
+        "olmo-hybrid-7b-d4.steady",
+    ]

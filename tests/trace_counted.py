@@ -10,6 +10,7 @@ _fa = importlib.import_module("dlrover_tpu.ops.flash_attention")
 FUSED, STREAM, WINDOW = _fa._FUSED, _fa._STREAM, _fa._WINDOW
 EDGE = _fa._EDGE
 GDN = ("gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites")
+PASS = ("gdn_pass_kernel_sites",)
 CONV = ("conv_sites", "conv_kernel_sites")
 GATE = ("gate_sites", "gate_kernel_sites")
 LANES = ("attn_score_lanes", "attn_score_lanes_used")

@@ -243,6 +243,13 @@ class PipelineStats:
     # as the convolution's pair is; 0 / 0 for a model without them
     gate_sites: int = 0
     gate_kernel_sites: int = 0
+    # Mamba-2 chunked scans (``ops/mamba2.mamba2_mixer``: one a mixer) in
+    # the train step program this process traced last, and those among
+    # them that were traced into the ``ssd_scan_*`` kernels
+    # (``ops/ssd_kernels.fits``). Counted as the convolution's pair is;
+    # 0 / 0 for a model without them
+    ssd_sites: int = 0
+    ssd_kernel_sites: int = 0
     # selective scans (``ops/selective_scan.selective_scan``: one a
     # Mamba-1 mixer) in the train step program this process traced last,
     # those among them that were traced into the ``sscan_*`` kernels

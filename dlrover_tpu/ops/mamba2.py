@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.common import trace_counts
-from dlrover_tpu.ops import conv_kernels, gated_norm_kernels
+from dlrover_tpu.ops import conv_kernels, gated_norm_kernels, ssd_kernels
 
 
 def init_mamba2_params(key, cfg, dtype):
@@ -272,14 +272,30 @@ def mamba2_mixer(u, p, cfg, eps: float, mesh=None):
     with jax.named_scope("scope/layer/ssm/scan"):
         dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(p["A_log"].astype(jnp.float32))
-        # the [Q, Q] decay squares of every head are recomputed in the
-        # backward pass and not kept: at 8192 tokens they are the layer's
-        # largest residuals by far and cost a few percent of its time
-        y = jax.checkpoint(ssd_chunked, static_argnums=(5,))(
-            x, dt, a, Bm, Cm, min(cfg.ssm_chunk, T)
-        )
-        y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-        y = y.astype(dt_act).reshape(Bsz, T, d_in)
+        chunk = min(cfg.ssm_chunk, T)
+        # a site of ``common/trace_counts``, counted as ``conv_silu``
+        # counts its own
+        in_kernels = ssd_kernels.fits(x, dt, Bm, Cm, chunk, mesh)
+        trace_counts.count("ssd_sites")
+        trace_counts.count("ssd_kernel_sites", in_kernels)
+        if in_kernels:
+            # the ``ssd_scan_*`` kernels: the decay squares live in VMEM,
+            # the backward makes them again there, and the ``D`` skip and
+            # the rounding are the forward kernel's last lines
+            y = ssd_kernels.ssd(
+                x, dt, a, Bm, Cm, p["D"].astype(jnp.float32), chunk
+            )
+        else:
+            # the [Q, Q] decay squares of every head are recomputed in the
+            # backward pass and not kept: at 8192 tokens they are the
+            # layer's largest residuals by far and cost a few percent of
+            # its time
+            y = jax.checkpoint(ssd_chunked, static_argnums=(5,))(
+                x, dt, a, Bm, Cm, chunk
+            )
+            y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+            y = y.astype(dt_act)
+        y = y.reshape(Bsz, T, d_in)
     with jax.named_scope("scope/layer/ssm/gate"):
         def statement(y, z, w):
             return gated_group_rmsnorm(y, z, w, G, eps).astype(dt_act)

@@ -111,6 +111,12 @@ does too (``delta_state_pass`` / ``delta_state_pass_rev``, interpreted on
 the CPU), where it was a ``lax.scan`` each way and einsums over all chunks
 after the reversed one. The eight configurations without a ``G`` layer
 never reach ``chunk_state_pass`` and are byte for byte what they were.
+ISSUE 66 recorded the step of ``nemotron3-nano-30b-a3b-d9`` anew, its tree
+as it was: at these widths (64 heads of 64 in 8 groups, a state of 128,
+chunks of 128) its four Mamba-2 scans take the ``ssd_scan_*`` kernels of
+``ops/ssd_kernels.py``, interpreted on the CPU, where they were
+``ssd_chunked`` under a ``jax.checkpoint``. The ten configurations without
+an ``M`` layer never reach the rule and are byte for byte what they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py

@@ -142,7 +142,8 @@ def test_rebuild_drops_the_executable(accel):
 # the edge blocks' two tile counts (PR 54), the delta-rule mixers whose
 # pass a recomputed layer keeps (PR 56), a looped model's three counts and
 # what is folded of its exits (PR 57), the gated norms after a scan and
-# those in the kernels (PR 63); how the counted ones are
+# those in the kernels (PR 63), the Mamba-2 chunked scans and those in the
+# kernels (PR 66); how the counted ones are
 # folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
     "attn_diff_pairs", "attn_diff_score_calls",
@@ -181,6 +182,7 @@ AS_DICT_KEYS = [
     "restore_storage_read_s", "restore_storage_verify_s",
     "rope_scaled_sites", "safe_steps",
     "save_skips", "sscan_kernel_sites", "sscan_serial_steps", "sscan_sites",
+    "ssd_kernel_sites", "ssd_sites",
     "stage_backlog_bytes", "stage_block_s", "stage_bytes", "stage_chunks",
     "stage_commits", "startup_backend_s", "startup_cache_misses",
     "startup_compile_s", "startup_first_step_s", "startup_import_s",

@@ -23,8 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.common import trace_counts
 from trace_counted import (
-    CONV, DIFF, EDGE, FUSED, GATE, GDN, LANES, PASS, SSCAN, STREAM, WINDOW,
-    added,
+    CONV, DIFF, EDGE, FUSED, GATE, GDN, LANES, PASS, SSCAN, SSD, STREAM,
+    WINDOW, added,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -491,6 +491,75 @@ def test_gated_norm_kernels_compile_at_the_cells(
         assert f"f32[{B},{T},{C}]" not in text
     assert f"f32[{B},{T},{C // width},{width}]" not in text
     assert added(before, GATE) == (1, 1)
+
+
+# the Mamba-2 chunked scan at the Nemotron cell: 64 heads of 64 in 8
+# groups, a state of 128, chunks of 128, 1 x 8192 tokens
+SSD_SHAPES = {
+    "nemotron3_nano_bf16": jnp.bfloat16,
+    "nemotron3_nano_f32": jnp.float32,
+}
+
+
+@pytest.mark.parametrize("name", list(SSD_SHAPES))
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_ssd_scan_kernels_compile_at_the_cell(
+    name, direction, one_chip, monkeypatch
+):
+    """The scan as the Nemotron mixer makes it, the kernel way: the
+    forward kernel, and under ``grad`` the reversed kernel beside it; no
+    ``[Q, Q]`` decay square and no float32 copy of the tokens is an array
+    of the program around them."""
+    from dlrover_tpu.models.config import TransformerConfig
+    from dlrover_tpu.ops import mamba2, ssd_kernels
+
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    act = SSD_SHAPES[name]
+    B, T, H, P, G, N, Q = 1, 8192, 64, 64, 8, 128, 128
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=1, layer_pattern="M", model_dim=256,
+        num_heads=2, mlp_dim=64, max_seq_len=T, ssm_heads=H,
+        ssm_head_dim=P, ssm_state=N, ssm_groups=G, ssm_chunk=Q,
+    )
+
+    def sds(shape, dtype=act):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = jax.eval_shape(
+        lambda: mamba2.init_mamba2_params(jax.random.PRNGKey(0), cfg, act)
+    )
+    p = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), p)
+    u = sds((B, T, cfg.model_dim))
+    assert ssd_kernels.fits(
+        sds((B, T, H, P)), sds((B, T, H), jnp.float32), sds((B, T, G, N)),
+        sds((B, T, G, N)), Q,
+    )
+
+    def site(p, u):
+        return mamba2.mamba2_mixer(u, p, cfg, 1e-5)
+
+    before = trace_counts.snapshot()
+    if direction == "fwd":
+        text = _compile_for_chip(site, p, u).as_text()
+        want = ["ssd_scan_fwd"]
+    else:
+        text = _compile_for_chip(
+            jax.grad(
+                lambda *a: jnp.sum(site(*a).astype(jnp.float32) ** 2),
+                argnums=(0, 1),
+            ),
+            p, u,
+        ).as_text()
+        want = ["ssd_scan_fwd", "ssd_scan_bwd"]
+    for kernel in want:
+        assert kernel in text, kernel
+    # no head's decay square outside the kernels
+    assert f"{G},{H // G},{Q},{Q}]" not in text
+    if act == jnp.bfloat16 and direction == "fwd":
+        # (the test's own loss is a float32 fusion)
+        assert f"f32[{B},{T},{H * P}]" not in text
+        assert f"f32[{B},{T},{H},{P}]" not in text
+    assert added(before, SSD) == (1, 1)
 
 
 CHANNEL_KERNELS = [

@@ -28,7 +28,7 @@ from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh
 from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer, build_optimizer
 from trace_counted import (
     CONV, DIFF, EDGE, FUSED, GATE, GDN, GDN_KEPT, KEPT, LANES, PASS, SCALED,
-    SHARE, SSCAN, STREAM, UT, WINDOW, XDEC, added,
+    SHARE, SSCAN, SSD, STREAM, UT, WINDOW, XDEC, added,
 )
 
 # `dlrover_tpu.ops.flash_attention` the attribute is the function
@@ -104,7 +104,7 @@ def test_the_running_totals_are_stats_fields():
     assert set(trace_counts.RUNNING_TOTALS) == set(FUSED + STREAM) <= FIELDS
     assert set(
         GDN + CONV + GATE + LANES + WINDOW + EDGE + KEPT + SHARE + SSCAN + DIFF
-        + XDEC + GDN_KEPT
+        + XDEC + GDN_KEPT + SSD
     ) <= FIELDS - set(trace_counts.RUNNING_TOTALS)
 
 
@@ -422,7 +422,7 @@ TOYS = {
         TransformerConfig(
             num_layers=3, layer_pattern="MG*", **_SMALL, **_MIXERS
         ),
-        (GDN, CONV, GATE, FUSED, LANES),
+        (GDN, CONV, GATE, SSD, FUSED, LANES),
     ),
     "vector_decay_and_latent_attention": (
         TransformerConfig(
@@ -540,6 +540,7 @@ KERNEL_MODULES = {
     "dlrover_tpu.ops.flash_attention", "dlrover_tpu.ops.gated_delta",
     "dlrover_tpu.ops.gated_delta_kernels", "dlrover_tpu.ops.mamba2",
     "dlrover_tpu.ops.conv_kernels", "dlrover_tpu.ops.selective_scan",
+    "dlrover_tpu.ops.ssd_kernels",
     "dlrover_tpu.models.transformer",
 }
 
@@ -671,4 +672,138 @@ def test_the_pass_share_is_listed_in_the_cells_its_rule_takes():
     assert entry["workloads"] == taken == [
         "qwen3-next-80b-a3b-d4.steady", "ling-3.0-flash-d7.steady",
         "olmo-hybrid-7b-d4.steady",
+    ]
+
+
+# -- the Mamba-2 chunked scan as kernels (ISSUE 66) ---------------------------
+
+SSD_METRIC = "ssd.kernel_sites_share"
+# every reader that picks kernels out of a trace by a part of their names
+NAMED = [
+    "kernel.attn_roofline", "kernel.attn_window_roofline",
+    "kernel.gdn_roofline", "kernel.sscan_roofline",
+    "kernel.moe_gmm_roofline", "attn.fwd_kernel_runs_per_step",
+    "gdn.fwd_kernel_runs_per_step", "moe.gmm_runs_per_step",
+]
+
+
+def _ssd_grad():
+    """``ssd_kernels.ssd`` under ``grad`` at the smallest shapes its rule
+    takes, traced and not run, as a jaxpr."""
+    from dlrover_tpu.ops import ssd_kernels
+
+    B, T, H, P, G, N = 1, 256, 2, 64, 1, 128
+    f32 = jnp.float32
+    args = [
+        jax.ShapeDtypeStruct(shape, f32) for shape in (
+            (B, T, H, P), (B, T, H), (H,), (B, T, G, N), (B, T, G, N), (H,),
+        )
+    ]
+    return jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(ssd_kernels.ssd(*a, 128)), argnums=range(6),
+    ))(*args).jaxpr
+
+
+@pytest.mark.parametrize("metric", NAMED)
+def test_no_reader_of_another_kernel_takes_a_kernel_of_the_scan(metric):
+    """The scan's two kernels, once each under ``grad``, hold no part of a
+    name by which a reader sums or counts another family's kernels."""
+    from test_recomputed_layer import _kernels_in
+
+    names = _kernels_in(_ssd_grad())
+    assert names == {"ssd_scan_fwd": 1, "ssd_scan_bwd": 1}
+    reader = _reader(metric)
+    parts = [
+        getattr(reader, attr).lower().lstrip("%")
+        for attr in ("NAME", "PREFIX") if hasattr(reader, attr)
+    ]
+    assert parts, metric
+    for name in names:
+        assert not any(part in name.lower() for part in parts)
+
+
+def test_a_scan_is_a_site_once_on_either_way(monkeypatch):
+    from dlrover_tpu.ops import mamba2, ssd_kernels
+
+    fits = TransformerConfig(
+        num_layers=1, layer_pattern="M", **dict(
+            _MIXERS, ssm_head_dim=64, ssm_state=128, ssm_chunk=128,
+        ), **_SMALL,
+    )
+    toy = TransformerConfig(
+        num_layers=1, layer_pattern="M", **_SMALL, **_MIXERS
+    )
+    for cfg, T, want in ((fits, 256, (1, 1)), (toy, 64, (1, 0))):
+        p = init_params(jax.random.PRNGKey(0), cfg)["layers"][0]["ssm"]
+        u = jax.ShapeDtypeStruct((1, T, cfg.model_dim), jnp.float32)
+        before = trace_counts.snapshot()
+        jax.make_jaxpr(lambda u: mamba2.mamba2_mixer(u, p, cfg, 1e-5))(u)
+        assert added(before, SSD) == want
+    monkeypatch.setattr(ssd_kernels, "fits", lambda *a: False)
+    before = trace_counts.snapshot()
+    p = init_params(jax.random.PRNGKey(0), fits)["layers"][0]["ssm"]
+    u = jax.ShapeDtypeStruct((1, 256, fits.model_dim), jnp.float32)
+    jax.make_jaxpr(lambda u: mamba2.mamba2_mixer(u, p, fits, 1e-5))(u)
+    assert added(before, SSD) == (1, 0)
+
+
+@pytest.mark.parametrize("model,pipeline,reads", [
+    ({"layer_pattern": "MEM*"}, None, None),
+    # the parent of PR 66: no such counter
+    ({"layer_pattern": "MEM*"}, {"conv_sites": 4, "gate_sites": 4}, None),
+    ({"layer_pattern": "MEM*"}, {"ssd_sites": 0, "ssd_kernel_sites": 0}, None),
+    ({"layer_pattern": "MEM*"}, {"ssd_sites": 4}, None),
+    ({"layer_pattern": "MEM*"}, {"ssd_sites": 4, "ssd_kernel_sites": 4}, 100.0),
+    ({"layer_pattern": "MEM*"}, {"ssd_sites": 4, "ssd_kernel_sites": 1}, 25.0),
+    ({"layer_pattern": "GGG*"}, {"ssd_sites": 4, "ssd_kernel_sites": 4}, None),
+], ids=["no_stats", "no_counter", "no_site", "half_a_counter", "all", "some",
+        "no_such_layer"])
+def test_the_scan_share_reader_reads_the_two_counts(model, pipeline, reads):
+    run = types.SimpleNamespace(
+        config={"model": model}, window={"pipeline": pipeline}
+    )
+    assert _reader(SSD_METRIC).read(run) == reads
+
+
+def test_the_scan_share_reads_100_on_a_toy_that_fits():
+    """The two counts of a traced step whose one Mamba-2 layer fits the
+    kernels, through the trainer's fold and the reader."""
+    cfg = TransformerConfig(
+        num_layers=1, layer_pattern="M", **dict(
+            _MIXERS, ssm_head_dim=64, ssm_state=128, ssm_chunk=128,
+        ), **dict(_SMALL, max_seq_len=256),
+    )
+    tx = build_optimizer("adamw", lr=1e-3)
+    mesh = build_mesh(MeshConfig(), jax.devices()[:1])
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(lambda p: TrainState(
+        step=jnp.zeros((), jnp.int32), params=p, opt_state=tx.init(p),
+    ), params)
+    x = jax.ShapeDtypeStruct((1, 256), jnp.int32)
+    trainer = _trainer()
+    ElasticTrainer._first_build(trainer, "step_donating")
+    build_train_step(cfg, mesh, tx, donate=False).lower(state, x, x)
+    ElasticTrainer._fold_trace_counts(trainer)
+    stats = trainer.pipeline_stats
+    assert (stats.ssd_sites, stats.ssd_kernel_sites) == (1, 1)
+    run = types.SimpleNamespace(
+        config={"model": {"layer_pattern": cfg.layer_pattern}},
+        window={"pipeline": stats.as_dict()},
+    )
+    assert _reader(SSD_METRIC).read(run) == 100.0
+
+
+def test_the_scan_share_is_listed_in_the_cells_its_rule_takes():
+    import json
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == SSD_METRIC]
+    reader = _reader(SSD_METRIC)
+    assert (entry["unit"], entry["layer"], entry["moves"]) == (
+        reader.UNIT, reader.LAYER, reader.MOVES
+    )
+    taken = [w["name"] for w in bench["workloads"] if reader.CELLS(w)]
+    assert entry["workloads"] == taken == [
+        "nemotron3-nano-30b-a3b-d9.steady"
     ]

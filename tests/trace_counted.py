@@ -13,6 +13,7 @@ GDN = ("gdn_sites", "gdn_chunk_steps", "gdn_kernel_sites")
 PASS = ("gdn_pass_kernel_sites",)
 CONV = ("conv_sites", "conv_kernel_sites")
 GATE = ("gate_sites", "gate_kernel_sites")
+SSD = ("ssd_sites", "ssd_kernel_sites")
 LANES = ("attn_score_lanes", "attn_score_lanes_used")
 KEPT = ("attn_kept_sites",)
 GDN_KEPT = ("gdn_kept_sites",)

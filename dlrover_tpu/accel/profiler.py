@@ -327,6 +327,33 @@ class PipelineStats:
     ut_reports: int = 0
     ut_entropy_sum: float = _reported_to(6)
     ut_exit_step_sum: float = _reported_to(6)
+    # a model trained by diffusion over blocks (``cfg.objective``
+    # "block_diffusion", models/transformer.py: a row's noised copy fed
+    # before the clean one) in the train step program this process traced
+    # last: the attention sites under the block-diffusion rule; the blocks
+    # a head's attention kernels walk there, forward and backward, and
+    # the blocks of the whole ``2L x 2L`` grid at the same block size,
+    # summed over the kernels as the window's pair is
+    # (``ops/flash_attention._count_bd_site``: equal where the rule ran
+    # as a mask over everything, the rectangular grid; 0 / 0 on the jnp
+    # path, which has no blocks); the positions the stack sees a step and
+    # the data tokens among them (``loss_fn``: twice the batch's tokens,
+    # and the tokens; the head and the loss see the latter). 0s for a
+    # model trained by next-token prediction
+    attn_bd_sites: int = 0
+    attn_bd_blocks_walked: int = 0
+    attn_bd_blocks_square: int = 0
+    diffusion_positions: int = 0
+    diffusion_data_tokens: int = 0
+    # ... and its noise in the steps reported at the log cadence (as the
+    # exits' above): how many reports, their summed share of the data
+    # tokens that were masked (E[t] = (1 + t_min) / 2 a report; 0 or 1
+    # says the noise is dead) and their summed mean loss weight a data
+    # token (``1 / t`` on a masked position, 0 on the rest: 1 in
+    # expectation)
+    diffusion_reports: int = 0
+    diffusion_masked_sum: float = _reported_to(6)
+    diffusion_weight_sum: float = _reported_to(6)
     # counted while the step was traced, as the kernels' sites above:
     # elements of the optimizer's int8 moments (both moments, as
     # ``opt_q8_tiles_elems`` above counts them) whose leaf's step the
@@ -789,6 +816,16 @@ def profile_model(
     # activations are ``ut_steps`` tokens' of a plain model, the
     # parameters are held once, the embedding is read once
     tok = B * T * cfg.ut_steps
+    # trained by diffusion over blocks (``cfg.objective``) a row of ``seq``
+    # data tokens passes the stack twice, noised and clean: 2 T positions
+    # through every block, T through the head, and a head's attention
+    # over T^2 + T * block visible pairs a row in place of the
+    # triangle's T^2 / 2
+    head_tok = tok
+    pairs = B * cfg.ut_steps * T * T / 2
+    if cfg.objective:
+        tok *= 2
+        pairs = B * T * (T + cfg.diffusion_block)
     prof = ModelProfile(batch=batch, seq=seq)
 
     emb_params = v * d + (0 if cfg.rope else cfg.max_seq_len * d)
@@ -816,9 +853,8 @@ def profile_model(
                 cfg.qk_nope_dim + value_width
             ) + h * value_width * d
         attn_flops = 2.0 * tok * qkv_params  # projections, output proj
-        # qk^T and softmax*v have identical causal structure: half each
-        attn_flops += 2.0 * tok * h * T * score_width / 2
-        attn_flops += 2.0 * tok * h * T * value_width / 2
+        # qk^T and softmax*v over the same visible pairs
+        attn_flops += 2.0 * pairs * h * (score_width + value_width)
         attn_act = tok * (h + 2 * kvh) * hd * act_bytes + tok * d * act_bytes
         prof.modules.append(
             ModuleProfile(
@@ -844,8 +880,8 @@ def profile_model(
     head_params = 0 if cfg.tie_embeddings else d * v
     prof.modules.append(
         ModuleProfile(
-            "lm_head", head_params, 2.0 * tok * d * v,
-            tok * v * 4,  # logits are fp32
+            "lm_head", head_params, 2.0 * head_tok * d * v,
+            head_tok * v * 4,  # logits are fp32
         )
     )
     return prof

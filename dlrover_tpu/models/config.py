@@ -295,6 +295,30 @@ class TransformerConfig:
     # the chunk's unit triangle is inverted by halves, not as a product
     # (``ops/gated_delta.py``)
     gdn_beta_scale: float = 1.0
+    # what a row of data is trained to do: "" => next-token prediction
+    # under the causal mask (``loss_fn`` embeds ``tokens`` and scores
+    # ``targets``); "block_diffusion" => diffusion over blocks (BD3-LM,
+    # arXiv:2503.09573, over MDLM's masked objective): the stack sees
+    # the row twice, a noised copy ``x_t`` before the clean one ``x_0``
+    # (2 L positions, both halves at positions 0..L-1), attention runs
+    # under the block-diffusion rule (``ops/flash_attention.
+    # block_diffusion_attention``), the head sees the noised half alone
+    # and the loss is the masked positions' cross-entropy on their own
+    # token, weighted by ``1 / t`` (``models/transformer.diffusion_noise``)
+    objective: str = ""
+    # positions a block of the diffusion: a noised position sees its own
+    # block's noised copies and the clean blocks before it, a clean one
+    # the clean blocks up to its own. 0 => no such objective
+    diffusion_block: int = 0
+    # the id a noised position reads; None => the table's last row
+    diffusion_mask_id: Optional[int] = None
+    # the least noise level: a block's ``t`` is uniform in [t_min, 1),
+    # and its masked positions weigh ``1 / t`` in the loss; the default
+    # is MDLM's
+    diffusion_t_min: float = 1e-3
+    # the seed of the noise, which is a pure function of it, of the row
+    # and, in a train step, of the step's number; 0 is a seed like any other
+    diffusion_noise_seed: int = 0
     # sequence-parallel attention scheme when the mesh has sp > 1:
     # "ring" (P2P pipeline, any head count) or "ulysses" (two
     # all-to-alls; needs (heads/tp) % sp == 0) — parallel/{ring_
@@ -448,6 +472,7 @@ class TransformerConfig:
             )
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
+        self._check_objective()
         for name, kinds in (
             ("norm_weight", ("", "one_plus")),
             ("qk_norm_span", ("token", "head")),
@@ -587,6 +612,74 @@ class TransformerConfig:
                 "(num_experts > 0) makes every moe_every-th block a "
                 "different pytree"
             )
+
+    def _check_objective(self):
+        """Refuse what the diffusion over blocks cannot mean: its fields
+        without the objective, a block that does not divide the row, a
+        mask id outside the table, a layer that carries state from token
+        to token or sees through a window (over a row fed twice neither
+        means anything), a looped stack, learned absolute positions (the
+        table has one row a position of ONE copy) and an attention that is
+        not the plain projected one."""
+        if self.objective not in ("", "block_diffusion"):
+            raise ValueError(f"unknown objective {self.objective!r}")
+        if not self.objective:
+            if self.diffusion_block:
+                raise ValueError(
+                    f"diffusion_block {self.diffusion_block} is of the "
+                    "objective \"block_diffusion\": objective is \"\""
+                )
+            return
+        block = self.diffusion_block
+        if isinstance(block, bool) or not isinstance(block, int) or (
+            block < 1 or self.max_seq_len % block
+        ):
+            raise ValueError(
+                f"diffusion_block {block!r} does not divide a row of "
+                f"max_seq_len {self.max_seq_len} into whole blocks"
+            )
+        if not 0 <= self.mask_id < self.vocab_size:
+            raise ValueError(
+                f"diffusion_mask_id {self.diffusion_mask_id} is outside "
+                f"the table's {self.vocab_size} rows"
+            )
+        if not 0.0 < self.diffusion_t_min < 1.0:
+            raise ValueError(
+                f"diffusion_t_min {self.diffusion_t_min} is outside (0, 1)"
+            )
+        carried = sorted(set(self.layer_pattern) & set("MGSUCW"))
+        if carried:
+            raise ValueError(
+                f"objective {self.objective!r} feeds a row twice, the "
+                f"noised copy before the clean one: a layer of kind "
+                f"{carried} carries state from token to token or sees "
+                "through a window, and over the doubled row that means "
+                "nothing"
+            )
+        if self.ut_steps > 1:
+            raise ValueError(
+                f"objective {self.objective!r} with ut_steps "
+                f"{self.ut_steps}: the exits' loss is next-token "
+                "prediction's"
+            )
+        if self.position_kind == "learned":
+            raise ValueError(
+                f"objective {self.objective!r} with learned absolute "
+                "positions: both copies of a row stand at positions "
+                "0..L-1, which the table's slice [:T] cannot say"
+            )
+        if self.attn_kind:
+            raise ValueError(
+                f"objective {self.objective!r} is of the plain projected "
+                f"attention: attn_kind is {self.attn_kind!r}"
+            )
+
+    @property
+    def mask_id(self) -> int:
+        """The id a noised position reads (``diffusion_mask_id``)."""
+        if self.diffusion_mask_id is None:
+            return self.vocab_size - 1
+        return self.diffusion_mask_id
 
     @property
     def kv_heads(self) -> int:

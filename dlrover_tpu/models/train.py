@@ -399,16 +399,23 @@ def build_train_step(
             return None
         return jnp.asarray(pad_row_weights(B - batch_pad, B))
 
-    def grads_and_loss(params, tokens, targets):
+    def _noise_step(state):
+        """What a step hands ``loss_fn`` beside the batch: its own number
+        where the objective draws noise (a row met again in a later step
+        is noised anew), nothing otherwise."""
+        return (state.step,) if cfg.objective else ()
+
+    def grads_and_loss(params, tokens, targets, *step):
         def lf(p):
             return loss_fn(
                 p, tokens, targets, cfg, mesh, return_aux=True,
                 row_weights=_row_w(tokens.shape[0]),
+                noise_step=step[0] if step else None,
             )
 
         return jax.value_and_grad(lf, has_aux=True)(params)
 
-    def local_grads_and_loss(params, tokens, targets):
+    def local_grads_and_loss(params, tokens, targets, *step):
         """Per-device UNsynchronized grads under ``shard_map``: each
         device differentiates the loss of its own batch shard
         (mesh=None inside — no sharding constraints in a manual
@@ -439,13 +446,14 @@ def build_train_step(
         else:
             batch_spec = P(("dp", "fsdp"), "sp")
 
-        def body(p, x, y, w):
+        def body(p, x, y, w, *step):
             def lf(pp):
                 return loss_fn(
                     pp, x, y, cfg, None, return_aux=True,
                     # replicated dummy when unpadded (batch_pad is a
                     # build-time constant)
                     row_weights=w if batch_pad else None,
+                    noise_step=step[0] if step else None,
                 )
 
             (loss, aux), g = jax.value_and_grad(lf, has_aux=True)(p)
@@ -465,7 +473,8 @@ def build_train_step(
         return shard_map(
             body,
             mesh=mesh,
-            in_specs=(P(), batch_spec, batch_spec, w_spec),
+            in_specs=(P(), batch_spec, batch_spec, w_spec)
+            + (P(),) * len(step),
             out_specs=(stacked, stacked, stacked),
             check_vma=False,
             **kw,
@@ -474,6 +483,7 @@ def build_train_step(
             tokens,
             targets,
             w if w is not None else jnp.zeros((1,), jnp.float32),
+            *step,
         )
 
     def _microbatches(tokens, targets):
@@ -525,7 +535,7 @@ def build_train_step(
         param_specs = tuple(_leaf_spec(i) for i in range(len(p_leaves)))
         batch_spec = P(("dp",))
 
-        def body(leaves_in, x, y, w):
+        def body(leaves_in, x, y, w, *step):
             params = jax.tree_util.tree_unflatten(
                 p_def, list(leaves_in)
             )
@@ -539,6 +549,7 @@ def build_train_step(
                     # strategy is unpadded (batch_pad is a build-time
                     # constant)
                     row_weights=w if batch_pad else None,
+                    noise_step=step[0] if step else None,
                 )
                 seed = (ep_idx == 0).astype(loss.dtype)
                 return loss * seed, (loss, aux)
@@ -588,7 +599,7 @@ def build_train_step(
                 batch_spec,
                 batch_spec,
                 P(("dp",)) if w is not None else P(),
-            ),
+            ) + (P(),) * len(_noise_step(state)),
             out_specs=(param_specs, P(), aux_specs, P()),
             check_vma=False,
         )(
@@ -596,6 +607,7 @@ def build_train_step(
             tokens,
             targets,
             w if w is not None else jnp.zeros((1,), jnp.float32),
+            *_noise_step(state),
         )
         grads = jax.tree_util.tree_unflatten(
             p_def, list(grads_leaves)
@@ -625,7 +637,7 @@ def build_train_step(
             def body(carry, xy):
                 g_acc, loss_acc, aux_acc = carry
                 loss_s, aux_s, g_s = local_grads_and_loss(
-                    state.params, *xy
+                    state.params, *xy, *_noise_step(state)
                 )
                 g_acc = jax.tree_util.tree_map(
                     lambda a, g: a + g.astype(jnp.float32), g_acc, g_s
@@ -653,7 +665,7 @@ def build_train_step(
             aux = jax.tree_util.tree_map(lambda a: a / k, aux_sum)
         else:
             loss_s, aux_s, g_stacked = local_grads_and_loss(
-                state.params, tokens, targets
+                state.params, tokens, targets, *_noise_step(state)
             )
             loss = jnp.mean(loss_s)
             aux = jax.tree_util.tree_map(jnp.mean, aux_s)
@@ -736,7 +748,9 @@ def build_train_step(
 
             def body(carry, xy):
                 g_acc, loss_acc, aux_acc = carry
-                (loss, aux), g = grads_and_loss(state.params, *xy)
+                (loss, aux), g = grads_and_loss(
+                    state.params, *xy, *_noise_step(state)
+                )
                 g_acc = jax.tree_util.tree_map(
                     lambda a, gg: a + gg.astype(jnp.float32), g_acc, g
                 )
@@ -762,7 +776,7 @@ def build_train_step(
             aux = jax.tree_util.tree_map(lambda a: a / k, aux_sum)
         else:
             (loss, aux), grads = grads_and_loss(
-                state.params, tokens, targets
+                state.params, tokens, targets, *_noise_step(state)
             )
         with jax.named_scope("scope/grad_norm"):
             gnorm = optax.global_norm(grads)
@@ -853,6 +867,13 @@ def build_train_step(
             # scalars must pop it, as ``moe_expert_load``)
             for name in ("ut_entropy", "ut_exit_step", "ut_exit_nll"):
                 metrics[name] = aux[name]
+        if cfg.objective:
+            # a step's noise (``models/transformer.diffusion_noise``): the
+            # share of the data tokens that were masked, and the mean of
+            # the weight a position's cross-entropy carries (1 / t where
+            # masked, 0 elsewhere: 1 in expectation)
+            for name in ("diffusion_masked_share", "diffusion_mean_weight"):
+                metrics[name] = aux[name]
         return (
             TrainState(
                 step=state.step + 1,
@@ -889,6 +910,21 @@ def fold_exit_report(metrics, stats) -> str:
     stats.ut_exit_step_sum += float(metrics["ut_exit_step"])
     each = ", ".join(f"{float(n):.4f}" for n in metrics["ut_exit_nll"])
     return f" ut_exit_nll=[{each}] ut_entropy={entropy:.4f}"
+
+
+def fold_diffusion_report(metrics, stats) -> str:
+    """Fold a reported step's own ``diffusion_masked_share`` /
+    ``diffusion_mean_weight`` into ``PipelineStats.diffusion_*`` and say
+    them for the log line; nothing and "" for a model trained by
+    next-token prediction. As ``fold_exit_report``."""
+    if "diffusion_masked_share" not in metrics:
+        return ""
+    masked = float(metrics["diffusion_masked_share"])
+    weight = float(metrics["diffusion_mean_weight"])
+    stats.diffusion_reports += 1
+    stats.diffusion_masked_sum += masked
+    stats.diffusion_weight_sum += weight
+    return f" masked={masked:.4f} weight={weight:.4f}"
 
 
 def shard_batch(batch, mesh):

@@ -621,9 +621,13 @@ def _count_score_lanes(called: int, used: int):
 
 def _causal_attention(q, k, v, mesh=None, layout: str = "bthd",
                       sm_scale: Optional[float] = None,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None,
+                      diffusion_block: Optional[int] = None):
     """Single-shard causal attention, [B,T,H,D] or [B,H,T,D]; with a
     ``window`` a query sees itself and the ``window - 1`` keys before it.
+    With a ``diffusion_block`` the row is a noised copy before a clean
+    one and the rule is block diffusion's, not the triangle
+    (``ops/flash_attention.block_diffusion_attention``).
 
     Dispatches to the Pallas flash-attention kernel on TPU (fused
     single-program kernels at short seq, block-tiled streaming beyond)
@@ -647,9 +651,17 @@ def _causal_attention(q, k, v, mesh=None, layout: str = "bthd",
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from dlrover_tpu.ops.flash_attention import flash_attention
+    from dlrover_tpu.ops.flash_attention import (
+        block_diffusion_attention,
+        flash_attention,
+    )
 
     def attend(q, k, v):
+        if diffusion_block:
+            return block_diffusion_attention(
+                q, k, v, block_len=diffusion_block, layout=layout,
+                sm_scale=sm_scale,
+            )
         scope = (
             jax.named_scope("scope/layer/attn/window") if window
             else contextlib.nullcontext()
@@ -709,9 +721,17 @@ def check_window_mesh(cfg: TransformerConfig, mesh):
     those layers as full attention (``build_train_step`` asks when a step
     is built, a window layer when it is traced); likewise the layers
     that know no split sequence at all: a selective scan, whose state
-    would have to pass from shard to shard, and differential attention."""
+    would have to pass from shard to shard, differential attention, and
+    the doubled row of diffusion over blocks."""
     if mesh is None or mesh.shape.get("sp", 1) <= 1:
         return
+    if cfg.objective:
+        raise NotImplementedError(
+            f"objective {cfg.objective!r} knows no sequence-parallel "
+            f"scheme: under sp = {mesh.shape['sp']} {cfg.sp_scheme} "
+            "attention would run the doubled row under the causal "
+            "triangle, where a noised position sees the answer"
+        )
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             f"a looped model (ut_steps {cfg.ut_steps}) calls every "
@@ -773,8 +793,10 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
     h = _normed(x, layer, cfg, norm)
     sp = mesh is not None and mesh.shape.get("sp", 1) > 1
     window = cfg.attn_window if kind == "W" else None
-    if window:
+    if window or cfg.objective:
         check_window_mesh(cfg, mesh)
+    if cfg.objective:
+        trace_counts.count("attn_bd_sites")
     _count_score_lanes(cfg.head_dim, cfg.head_dim)
     # single-shard path: kernel-native [B,H,T,D] straight from the
     # projection einsums — no relayout transposes around the attention
@@ -796,7 +818,10 @@ def _attention_block(x, layer, cfg: TransformerConfig, mesh, positions,
         # 1/sqrt(d) into q, so flash and ring paths need no new plumbing
         q = q * (cfg.mup_attn_scale * cfg.head_dim**0.5)
     if not sp:
-        o = _causal_attention(q, k, v, mesh, layout="bhtd", window=window)
+        o = _causal_attention(
+            q, k, v, mesh, layout="bhtd", window=window,
+            diffusion_block=cfg.diffusion_block or None,
+        )
     elif cfg.sp_scheme == "ulysses":
         from dlrover_tpu.parallel.ulysses import ulysses_self_attention
 
@@ -1070,6 +1095,10 @@ def _zero_aux(cfg: Optional[TransformerConfig] = None):
         aux["ut_entropy"] = jnp.float32(0.0)
         aux["ut_exit_step"] = jnp.float32(0.0)
         aux["ut_exit_nll"] = jnp.zeros((cfg.ut_steps,), jnp.float32)
+    if cfg is not None and cfg.objective:
+        # what ``loss_fn`` says of a step's noise (``diffusion_noise``)
+        aux["diffusion_masked_share"] = jnp.float32(0.0)
+        aux["diffusion_mean_weight"] = jnp.float32(0.0)
     return aux
 
 
@@ -1217,9 +1246,12 @@ def _nll_each(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
 
 @jax.named_scope("scope/xent")
 def token_nll(
-    logits: jnp.ndarray, targets: jnp.ndarray, row_weights=None
+    logits: jnp.ndarray, targets: jnp.ndarray, row_weights=None,
+    token_weights=None,
 ) -> jnp.ndarray:
-    """Mean next-token negative log-likelihood.
+    """Mean next-token negative log-likelihood; with ``token_weights``
+    [B,T] each token's own weight on its term of that mean (0 leaves a
+    token out; the divisor stays B T).
 
     Written as ``logsumexp(logits) - logits[target]`` (identical math
     and gradient — softmax minus one-hot) instead of gathering from
@@ -1227,6 +1259,8 @@ def token_nll(
     [B, T, vocab] fp32 tensor for the backward (3.3 GB of avoidable HBM
     traffic a step of the 124M model at batch 32)."""
     nll = _nll_each(logits, targets)
+    if token_weights is not None:
+        nll = token_weights.astype(nll.dtype) * nll
     if row_weights is not None:
         # weighted mean over rows (micro-batch rebalance: padded rows
         # carry weight 0, real rows batch_padded/batch_real — see
@@ -1234,6 +1268,54 @@ def token_nll(
         # padded batch then equals the mean over the real rows)
         return jnp.mean(row_weights[:, None].astype(nll.dtype) * nll)
     return jnp.mean(nll)
+
+
+# -- diffusion over blocks: the noise of a row -------------------------------
+
+
+@jax.named_scope("scope/embed")
+def diffusion_noise(tokens: jnp.ndarray, cfg: TransformerConfig, step=None):
+    """The noise of ``cfg.objective`` "block_diffusion" on rows ``x_0``
+    [B,L]: ``(x_t, masked, weight)``, each [B,L]. A pure function of a row
+    and ``cfg.diffusion_noise_seed``, in integers and float32: a row's key
+    is ``fold_in(PRNGKey(seed), sum(x_0) mod 2^31)``; each block of
+    ``cfg.diffusion_block`` positions draws ``t = t_min + (1 - t_min) U``,
+    ``U ~ U[0, 1)`` from the key's first half, each position ``u ~ U[0,
+    1)`` from its second, and a position is masked where ``u < t`` of its
+    block (MDLM's linear schedule as BD3-LM trains it); ``x_t`` reads
+    ``cfg.mask_id`` there and ``x_0`` elsewhere, and ``weight`` is ``1 /
+    t`` on the masked positions and 0 on the rest: what the loss
+    multiplies a position's cross-entropy by. ``step`` (a train step's
+    own number, ``models/train``) is folded into the seed's key, so that a
+    row met again in a later step or epoch is noised anew; without it the
+    noise is the row's alone, which is what a reference that is handed
+    ``(params, tokens, targets)`` can draw again."""
+    length, block = tokens.shape[1], cfg.diffusion_block
+    if length % block:
+        raise ValueError(
+            f"a row of {length} positions is no whole number of "
+            f"diffusion blocks of {block}"
+        )
+    seed = jax.random.PRNGKey(cfg.diffusion_noise_seed)
+    if step is not None:
+        seed = jax.random.fold_in(seed, step)
+
+    def one_row(row):
+        total = jnp.sum(row.astype(jnp.uint32)) & jnp.uint32(0x7FFFFFFF)
+        by_block, by_position = jax.random.split(
+            jax.random.fold_in(seed, total)
+        )
+        t_min = jnp.float32(cfg.diffusion_t_min)
+        t = t_min + (1.0 - t_min) * jax.random.uniform(
+            by_block, (length // block,), jnp.float32
+        )
+        t = jnp.repeat(t, block)
+        masked = jax.random.uniform(by_position, (length,), jnp.float32) < t
+        return masked, jnp.where(masked, 1.0 / t, 0.0)
+
+    masked, weight = jax.vmap(one_row)(tokens)
+    noised = jnp.where(masked, jnp.asarray(cfg.mask_id, tokens.dtype), tokens)
+    return noised, masked, weight
 
 
 # -- a looped model's exits: the head and its loss, one rule -----------------
@@ -1488,10 +1570,25 @@ def forward(
     [B,T,D] instead of logits and skips the vocab projection entirely —
     the trunk for value heads / probes (the RLHF critic uses this, so
     trunk math can never drift from the LM path).
+
+    Under ``cfg.objective`` "block_diffusion" ``tokens`` is the doubled
+    row ``[x_t ; x_0]`` [B,2L] (``diffusion_noise`` makes ``x_t``): both
+    halves stand at positions 0..L-1, every layer sees all 2L positions,
+    and the final norm and the head see the first L alone, so logits and
+    ``return_hidden`` are [B,L,.]: the clean half never reaches the head.
     """
     B, T = tokens.shape
     x = embed_tokens(params, tokens, cfg, mesh)
-    positions = jnp.broadcast_to(jnp.arange(T), (B, T))
+    if cfg.objective:
+        if T % 2 or (T // 2) % cfg.diffusion_block:
+            raise ValueError(
+                f"objective {cfg.objective!r}: {T} positions are no two "
+                f"copies of a row of whole blocks of {cfg.diffusion_block}"
+            )
+        half = jnp.arange(T // 2)
+        positions = jnp.broadcast_to(jnp.concatenate([half, half]), (B, T))
+    else:
+        positions = jnp.broadcast_to(jnp.arange(T), (B, T))
 
     aux_total = _zero_aux(cfg)
 
@@ -1597,6 +1694,8 @@ def forward(
             x, aux = block(x, layer)
             aux_total = jax.tree_util.tree_map(jnp.add, aux_total, aux)
 
+    if cfg.objective:
+        x = x[:, :T // 2]
     if return_hidden:
         return _final_norm(params, x, cfg), aux_total
     return lm_head(params, x, cfg), aux_total
@@ -1612,20 +1711,46 @@ def loss_fn(
     return_aux: bool = False,
     moe_axis=None,
     row_weights=None,
+    noise_step=None,
 ):
     """Mean NLL + weighted MoE aux losses (load balance at
     ``moe_aux_weight``, router z at ``cfg.router_z_weight``); of a looped
     model (``cfg.ut_steps`` > 1) the exits' loss (``ut_exits``).
-    ``return_aux=True`` → (loss, aux dict) for metric surfacing."""
+    ``return_aux=True`` → (loss, aux dict) for metric surfacing.
+
+    Under ``cfg.objective`` "block_diffusion" ``tokens`` [B,L] is the row
+    of data ``x_0``: it is noised (``diffusion_noise``), fed as ``[x_t ;
+    x_0]``, and the loss is ``mean(weight * nll)`` over its B L positions,
+    a noised position's logits scoring that position's own token;
+    ``targets`` (the row shifted by one) is not read; ``noise_step`` is
+    ``diffusion_noise``'s ``step`` (a train step hands its own number)."""
     if cfg.ut_steps > 1:
         passes, aux = forward(params, tokens, cfg, mesh, return_passes=True)
         loss, said = ut_exits(params, passes, targets, cfg, row_weights)
         return (loss, dict(aux, **said)) if return_aux else loss
+    token_weights = None
+    if cfg.objective:
+        noised, masked, token_weights = diffusion_noise(
+            tokens, cfg, noise_step
+        )
+        trace_counts.count("diffusion_positions", 2 * tokens.size)
+        trace_counts.count("diffusion_data_tokens", tokens.size)
+        targets = tokens
+        tokens = jnp.concatenate([noised, tokens], axis=1)
     logits, aux = forward(params, tokens, cfg, mesh, moe_axis=moe_axis)
+    if cfg.objective:
+        aux = dict(
+            aux,
+            diffusion_masked_share=jnp.mean(masked.astype(jnp.float32)),
+            diffusion_mean_weight=jnp.mean(token_weights),
+        )
     if cfg.router_balance_weight is not None:
         moe_aux_weight = cfg.router_balance_weight
     loss = (
-        token_nll(logits, targets, row_weights=row_weights)
+        token_nll(
+            logits, targets, row_weights=row_weights,
+            token_weights=token_weights,
+        )
         + moe_aux_weight * aux["balance"]
         + cfg.router_z_weight * aux["z"]
     )
@@ -1638,6 +1763,14 @@ def loss_fn(
 # cached autoregressive decoding (generation / RLHF rollouts)
 # ---------------------------------------------------------------------------
 def _refuse_cached_loop(cfg: TransformerConfig):
+    if cfg.objective:
+        raise NotImplementedError(
+            f"cached decoding yields one token a sequence a step: a model "
+            f"trained by {cfg.objective!r} generates a block of "
+            f"{cfg.diffusion_block} positions by denoising it over "
+            "several forward passes against a cache of the finished "
+            "blocks, which no cache or decode step here does"
+        )
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             f"cached decoding knows one visit of a layer a token: a "

@@ -91,6 +91,30 @@ Design:
   says which (``attn_window_blocks_walked`` against ``_causal``; the
   score tiles the edge blocks multiply, ``attn_edge_tiles_multiplied`` of
   ``attn_edge_tiles``).
+- **Block diffusion** (``block_diffusion_attention``, an entry of its own):
+  a row fed twice, its noised copy before the clean one, under a rule
+  that is neither triangle nor band (a noised query sees the noised keys
+  of its own block of ``block_len`` and the clean keys of the blocks
+  before it, a clean query the clean keys up to its own block's end). The
+  ``flash_attn_bd_*`` kernels are the triangle path's with a third table:
+  the steps list the blocks of the ``2n x 2n`` grid that hold a visible
+  pair and no other (80 of 256 at L = 8192 in blocks of 1024), each with
+  the rule that masks it, a query block's own block first; the forward
+  walks the noised x noised blocks on the diagonal in row strips of their
+  own span, the one-pass backward every edge block in strips.
+- **Which call walks what**, in one place. *The triangle*: causal alone,
+  static equal offsets (``_sees_triangle``): the fused family's row tiles
+  at T <= 1024 and H = H_kv, else the streaming kernels' ``n (n + 1) /
+  2`` blocks (square blocks over one sequence, ``_stream_plan``). *The
+  band*: the same with a ``window``, on the streaming triangle path alone
+  (``_band_steps``). *Block diffusion*: ``block_diffusion_attention``
+  where ``_bd_block`` finds a block (it divides the row, the tables hold
+  the grid, a head's float32 dq fits the one pass). *The rectangle*
+  (every block of the grid, a causal call skipping invisible ones at run
+  time): everything else, a ``mask_fn``, ``causal=False``, traced or
+  unequal offsets (a ring hop), unequal blocks, and a window or the
+  block-diffusion rule where their walks cannot run (each then an exact
+  mask); the jnp path walks nothing and masks everything.
 - ``layout="bhtd"`` lets callers hand over kernel-native [B, H, T, D]
   tensors (the model emits them straight from its QKV einsums), skipping
   the 25 MB-per-tensor relayout transposes on every call.
@@ -1507,14 +1531,15 @@ def _one_pass_fits(T: int, D: int, itemsize: int) -> bool:
 
 def _tri_call(kernel, name, steps, ins, in_specs, out_specs, out_shape,
               scratch, *, interpret, vmem_limit=None):
-    """One kernel over the triangle grid ``(B, H, steps)``, the two
-    step -> block tables as scalar prefetch."""
+    """One kernel over the triangle grid ``(B, H, steps)``, the step ->
+    block tables (two, or the block-diffusion walk's three) as scalar
+    prefetch."""
     B, H = ins[0].shape[:2]
     return pl.pallas_call(
         kernel,
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(steps),
             grid=(B, H, steps[0].shape[0]),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -1532,18 +1557,18 @@ def _tri_call(kernel, name, steps, ins, in_specs, out_specs, out_shape,
 def _tri_specs(block: int, D: int, group: int):
     """Block specs of the triangle grid: blocks of query rows and of
     key rows, each by its table."""
-    rows = lambda b, h, s, qi, kj: (b, h, qi[s], 0)  # noqa: E731
+    rows = lambda b, h, s, qi, kj, *_: (b, h, qi[s], 0)  # noqa: E731
     return (
         pl.BlockSpec((1, 1, block, D), rows),
         pl.BlockSpec(
             (1, 1, block, D),
-            lambda b, h, s, qi, kj: (b, h // group, kj[s], 0),
+            lambda b, h, s, qi, kj, *_: (b, h // group, kj[s], 0),
         ),
         # minor dim 1 == full array dim: a legal tile (see _fwd_pallas)
         pl.BlockSpec((1, 1, block, 1), rows),
         # dk / dv come out per QUERY head
         pl.BlockSpec(
-            (1, 1, block, D), lambda b, h, s, qi, kj: (b, h, kj[s], 0)
+            (1, 1, block, D), lambda b, h, s, qi, kj, *_: (b, h, kj[s], 0)
         ),
     )
 
@@ -2203,6 +2228,512 @@ def _call_blocks(q, k, block_q, block_k, layout, causal, mask_fn,
             causal, mask_fn, _on_diagonal(q_offset, k_offset)
         ),
     )
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion walk: a row fed twice, its noised copy before the
+# clean one, under a rule that is neither triangle nor band
+# ---------------------------------------------------------------------------
+# Positions ``[0, 2 L)``: ``i < L`` is the noised copy of position ``i``,
+# ``i >= L`` the clean copy of ``i - L``; ``b(i) = (i mod L) // B`` is a
+# position's block of ``B``. A query sees a key iff
+#   noised query, noised key:  b(key) == b(query)   ("eq")
+#   noised query, clean key:   b(key) <  b(query)   ("lt")
+#   clean query,  clean key:   b(key) <= b(query)   ("le")
+# and a clean query sees no noised key. Every query sees its own position.
+# Which blocks of the kernels' ``2n x 2n`` grid hold a visible pair is known
+# when the program is traced, as the triangle's and the band's are: the
+# step -> block tables list those blocks and no other (at L = 8192, B = 4 in
+# blocks of 1024: 36 clean x clean, 36 noised x clean and the 8 noised x
+# noised blocks on the diagonal, 80 of 256), and a third table says of each
+# step which rule masks it (none where every pair is visible) and whether it
+# is a row's first or last.
+_BD = ("attn_bd_blocks_walked", "attn_bd_blocks_square")
+_BD_WHOLE, _BD_LE, _BD_LT, _BD_EQ = 0, 1, 2, 3
+_BD_FIRST, _BD_LAST = 4, 8
+# Row strips of a block on the noised x noised diagonal, where a strip's
+# rows see keys of the strip's own span alone (8: 128 x 128 tiles in a
+# block of 1024, an eighth of the block)
+_BD_EQ_STRIPS = 8
+
+
+@functools.lru_cache(maxsize=None)
+def block_diffusion_mask(seq: int, block_len: int) -> MaskFn:
+    """The block-diffusion rule as a ``mask_fn`` over positions ``[0, 2
+    seq)``: what the walk is wherever no walk is made (the jnp path, the
+    rectangular grid), and what the tests hold the walk to. One function
+    a ``(seq, block_len)``, so that it can ride as a static argument."""
+
+    def mask(q_pos, k_pos):
+        q_clean, k_clean = q_pos >= seq, k_pos >= seq
+        qb = jnp.where(q_clean, q_pos - seq, q_pos) // block_len
+        kb = jnp.where(k_clean, k_pos - seq, k_pos) // block_len
+        # and / or / not alone: Mosaic takes no select between masks
+        return (
+            (q_clean & k_clean & (kb <= qb))
+            | (~q_clean & k_clean & (kb < qb))
+            | (~q_clean & ~k_clean & (kb == qb))
+        )
+
+    return mask
+
+
+@functools.lru_cache(maxsize=None)
+def _bd_blocks(seq: int, block_len: int, blk: int):
+    """Every block ``(qi, kj, kind)`` of the ``2n x 2n`` grid, ``n = seq /
+    blk``, that holds a visible pair: ``kind`` the rule that masks it, or
+    ``_BD_WHOLE`` where all its pairs are visible."""
+    n = seq // blk
+
+    def ids(b):  # the first and last diffusion block a kernel block holds
+        return b * blk // block_len, ((b + 1) * blk - 1) // block_len
+
+    blocks = []
+    for i in range(n):
+        r0, r1 = ids(i)
+        for j in range(n):
+            c0, c1 = ids(j)
+            for qi, kj, rule, some, whole in (
+                (i, j, _BD_EQ, c0 <= r1 and r0 <= c1, r0 == r1 == c0 == c1),
+                (i, n + j, _BD_LT, c0 < r1, c1 < r0),
+                (n + i, n + j, _BD_LE, c0 <= r1, c1 <= r0),
+            ):
+                if some:
+                    blocks.append((qi, kj, _BD_WHOLE if whole else rule))
+    return tuple(blocks)
+
+
+def _bd_steps(seq: int, block_len: int, blk: int, by_key: bool):
+    """step -> (query block, key block, code) in the order a kernel
+    accumulates: a query block's keys, the block that holds its own
+    positions first (every row sees itself there, so the running maximum
+    is finite before a block some of its rows see nothing of), or with
+    ``by_key`` a key block's queries. ``code`` is the step's mask kind,
+    plus ``_BD_FIRST`` / ``_BD_LAST`` on a row's first and last step."""
+    row = (lambda b: b[1]) if by_key else (lambda b: b[0])
+    col = (lambda b: b[0]) if by_key else (lambda b: b[1])
+    ordered = sorted(
+        _bd_blocks(seq, block_len, blk),
+        key=lambda b: (row(b), col(b) != row(b), col(b)),
+    )
+    steps = []
+    for at, (qi, kj, kind) in enumerate(ordered):
+        first = at == 0 or row(ordered[at - 1]) != row(ordered[at])
+        last = at == len(ordered) - 1 or (
+            row(ordered[at + 1]) != row(ordered[at])
+        )
+        steps.append((qi, kj, kind + _BD_FIRST * first + _BD_LAST * last))
+    return tuple(
+        jnp.asarray(column, jnp.int32) for column in zip(*steps)
+    )
+
+
+def _bd_strips(blk: int, block_len: int, kind: int, strips: int):
+    """The plan of an edge block of the walk, ``((r0, r1, c0, c1), ...)``:
+    a strip of query rows against the one span of the block's keys that
+    any of them sees, as ``_edge_strips``. Where diffusion blocks divide
+    the kernel's, an edge block lies on a diagonal and its pattern is the
+    same wherever it lies: a noised x noised block's strip sees its own
+    span, a clean key block's strip every key up to its last row's. A
+    block that diffusion blocks cross is one strip, masked by position."""
+    while strips > 1 and (blk % strips or blk // strips % block_len):
+        strips //= 2
+    if blk % block_len or strips == 1:
+        return ((0, blk, 0, blk),)
+    rows = blk // strips
+    return tuple(
+        (r, r + rows, r if kind == _BD_EQ else 0, r + rows)
+        for r in range(0, blk, rows)
+    )
+
+
+def _bd_strip_counts(blk: int, block_len: int, interpret: bool):
+    """``(strips of an "eq" block, strips of an "le" / "lt" block)``."""
+    tile = 1 if interpret else _LANES
+    eq = _BD_EQ_STRIPS
+    while eq > 1 and (blk % eq or blk // eq % tile):
+        eq //= 2
+    return eq, _edge_strip_count(blk, interpret)
+
+
+def _bd_plan(blk: int, block_len: int, kind: int, strips, forward: bool):
+    """What a kernel multiplies of one block of mask ``kind``: a block
+    seen whole is one strip; the forward computes a clean key block's edge
+    whole and masks it, as the triangle's forward does (``_EDGE_STRIPS``),
+    and a noised x noised block in strips of its own span; the backward
+    walks every edge block in strips. ``strips`` is ``_bd_strip_counts``'."""
+    if kind == _BD_WHOLE or (forward and kind != _BD_EQ):
+        return ((0, blk, 0, blk),)
+    eq, edge = strips
+    return _bd_strips(blk, block_len, kind, eq if kind == _BD_EQ else edge)
+
+
+def _bd_block_ids(pos, block_len: int):
+    """The diffusion block of each position (whole numbers from 0)."""
+    if block_len & (block_len - 1) == 0:
+        return lax.shift_right_logical(
+            pos, jnp.int32(block_len.bit_length() - 1)
+        )
+    return lax.div(pos, jnp.int32(block_len))
+
+
+def _bd_scores(q_ref, k_ref, strip, kind: int, i, j, *, seq, block_len):
+    """Raw scores ``q k^T`` of one strip of block ``(i, j)``, float32,
+    masked by the rule ``kind`` names on the positions the strip holds."""
+    r0, r1, c0, c1 = strip
+    s = jax.lax.dot_general(
+        q_ref[0, 0, r0:r1, :], k_ref[0, 0, c0:c1, :],
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if kind == _BD_WHOLE:
+        return s
+    blk = q_ref.shape[2]
+    n = seq // blk
+    # a block's place in its copy of the row
+    row0 = jnp.where(i >= n, i - n, i) * blk + r0
+    col0 = jnp.where(j >= n, j - n, j) * blk + c0
+    rb = _bd_block_ids(
+        row0 + lax.broadcasted_iota(jnp.int32, (r1 - r0, 1), 0), block_len
+    )
+    cb = _bd_block_ids(
+        col0 + lax.broadcasted_iota(jnp.int32, (1, c1 - c0), 1), block_len
+    )
+    seen = cb <= rb if kind == _BD_LE else (
+        cb < rb if kind == _BD_LT else cb == rb
+    )
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _bd_each_kind(code, body):
+    """``body(kind)`` under the branch of the step's mask kind, each kind
+    static in its own."""
+    kind = code & 3
+    for static in (_BD_WHOLE, _BD_LE, _BD_LT, _BD_EQ):
+        pl.when(kind == static)(functools.partial(body, static))
+
+
+def _bd_fwd_kernel(
+    qi_ref, kj_ref, code_ref,  # SMEM [steps] each
+    q_ref, k_ref, v_ref,  # VMEM [1, 1, b, D]
+    o_ref, lse_ref,  # VMEM [1, 1, b, D], [1, 1, b, 1]
+    acc_ref, m_ref, l_ref,  # scratch, as ``_tri_fwd_kernel``'s
+    *, sm_scale: float, seq: int, block_len: int, strips,
+):
+    """One step of a query block's walk over the key blocks it can see
+    (``_bd_steps``), the triangle kernel's arithmetic: the running maximum
+    is of raw scores, the scale rides in the exponent."""
+    step = pl.program_id(2)
+    i, j, code = qi_ref[step], kj_ref[step], code_ref[step]
+    blk = q_ref.shape[2]
+
+    @pl.when((code & _BD_FIRST) != 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def _block(kind):
+        for strip in _bd_plan(blk, block_len, kind, strips, True):
+            r0, r1, c0, c1 = strip
+            s = _bd_scores(
+                q_ref, k_ref, strip, kind, i, j, seq=seq,
+                block_len=block_len,
+            )
+            m_prev = m_ref[r0:r1, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp((m_prev - m_new) * sm_scale)
+            p = jnp.exp((s - m_new) * sm_scale)
+            l_new = l_ref[r0:r1, :1] * alpha + jnp.sum(
+                p, axis=-1, keepdims=True
+            )
+            acc_ref[r0:r1, :] = acc_ref[r0:r1, :] * alpha + (
+                jax.lax.dot_general(
+                    p.astype(v_ref.dtype), v_ref[0, 0, c0:c1, :],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            )
+            m_ref[r0:r1, :] = jnp.broadcast_to(m_new, (r1 - r0, _LANES))
+            l_ref[r0:r1, :] = jnp.broadcast_to(l_new, (r1 - r0, _LANES))
+
+    _bd_each_kind(code, _block)
+
+    @pl.when((code & _BD_LAST) != 0)
+    def _write():
+        l = l_ref[:, :1]
+        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[:, :1] * sm_scale + jnp.log(l)
+
+
+def _bd_bwd_kernel(
+    qi_ref, kj_ref, code_ref,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dq_ref,  # out [1, 1, 2 L, D]: a head's, resident while it is swept
+    dk_ref, dv_ref,  # out [1, 1, b, D] per QUERY head, float32
+    dq_acc, dk_acc, dv_acc,  # scratch f32 [2 L, D], [b, D], [b, D]
+    *, sm_scale: float, seq: int, block_len: int, strips,
+):
+    """The backward in one pass, key block by key block, as
+    ``_tri_bwd_kernel`` with its ``dq`` output: scores, p, dp and ds once a
+    block. An edge block is walked in row strips (``_bd_strips``)."""
+    step = pl.program_id(2)
+    i, j, code = qi_ref[step], kj_ref[step], code_ref[step]
+    blk = q_ref.shape[2]
+
+    @pl.when(step == 0)
+    def _init_head():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when((code & _BD_FIRST) != 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def _block(kind):
+        for strip in _bd_plan(blk, block_len, kind, strips, False):
+            r0, r1, c0, c1 = strip
+            s = _bd_scores(
+                q_ref, k_ref, strip, kind, i, j, seq=seq,
+                block_len=block_len,
+            )
+            # lse is finite (every row sees itself); a masked score gives
+            # exp(NEG_INF - lse) = 0
+            p = jnp.exp(s * sm_scale - lse_ref[0, 0, r0:r1, :1])
+            q, do = q_ref[0, 0, r0:r1, :], do_ref[0, 0, r0:r1, :]
+            dp = jax.lax.dot_general(
+                do, v_ref[0, 0, c0:c1, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds_lo = (p * (dp - delta_ref[0, 0, r0:r1, :1])).astype(q.dtype)
+            dv_acc[c0:c1, :] = dv_acc[c0:c1, :] + jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dk_acc[c0:c1, :] = dk_acc[c0:c1, :] + jax.lax.dot_general(
+                ds_lo, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            rows = pl.ds(pl.multiple_of(i * blk + r0, r1 - r0), r1 - r0)
+            dq_acc[rows, :] = dq_acc[rows, :] + jax.lax.dot_general(
+                ds_lo, k_ref[0, 0, c0:c1, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
+    _bd_each_kind(code, _block)
+
+    @pl.when((code & _BD_LAST) != 0)
+    def _write():
+        dk_ref[0, 0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(step == pl.num_programs(2) - 1)
+    def _write_head():
+        dq_ref[0, 0] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _count_bd_site(seq: int, block_len: int, blk: int, strips, *,
+                   forward: bool):
+    """One kernel of the walk into ``common/trace_counts``: the blocks a
+    head walks against the ``2n x 2n`` grid's (``_BD``), and the score
+    tiles its edge blocks hold and multiply (``_EDGE``, as
+    ``_count_edge_tiles``: a tile the height of a strip a side)."""
+    blocks = _bd_blocks(seq, block_len, blk)
+    for name, k in zip(_BD, (len(blocks), (2 * seq // blk) ** 2)):
+        trace_counts.count(name, k)
+    held = multiplied = 0
+    for _, _, kind in blocks:
+        if kind == _BD_WHOLE:
+            continue
+        # tiles the height of one of the block's backward strips a side
+        tiles = len(_bd_plan(blk, block_len, kind, strips, False))
+        rows = blk // tiles
+        held += tiles * tiles
+        multiplied += sum(
+            (r1 - r0) // rows * ((c1 - c0) // rows)
+            for r0, r1, c0, c1 in _bd_plan(
+                blk, block_len, kind, strips, forward
+            )
+        )
+    for name, k in zip(_EDGE, (multiplied, held)):
+        trace_counts.count(name, k)
+
+
+def _bd_fwd_call(qt, kt, vt, *, seq, block_len, sm_scale, blk, interpret):
+    """[B,H,2L,D] in -> (o [B,H,2L,D], lse4 [B,H,2L,1])."""
+    B, H, T, D = qt.shape
+    q_spec, kv_spec, row_spec, _ = _tri_specs(blk, D, H // kt.shape[1])
+    strips = _bd_strip_counts(blk, block_len, interpret)
+    _count_bd_site(seq, block_len, blk, strips, forward=True)
+    return _tri_call(
+        functools.partial(
+            _bd_fwd_kernel, sm_scale=sm_scale, seq=seq,
+            block_len=block_len, strips=strips,
+        ),
+        "flash_attn_bd_fwd",
+        _bd_steps(seq, block_len, blk, by_key=False),
+        (qt, kt, vt),
+        [q_spec, kv_spec, kv_spec],
+        [q_spec, row_spec],
+        [
+            jax.ShapeDtypeStruct((B, H, T, D), qt.dtype),
+            jax.ShapeDtypeStruct((B, H, T, 1), jnp.float32),
+        ],
+        [
+            pltpu.VMEM((blk, D), jnp.float32),
+            pltpu.VMEM((blk, _LANES), jnp.float32),
+            pltpu.VMEM((blk, _LANES), jnp.float32),
+        ],
+        interpret=interpret,
+    )
+
+
+def _bd_bwd_call(qt, kt, vt, dot, lse4, delta4, *, seq, block_len,
+                 sm_scale, blk, interpret):
+    """[B,H,2L,D] in -> (dq in q's dtype, dk, dv float32 per QUERY
+    head), as ``_tri_bwd_call``'s one pass."""
+    B, H, T, D = qt.shape
+    q_spec, kv_spec, row_spec, kv_out_spec = _tri_specs(
+        blk, D, H // kt.shape[1]
+    )
+    strips = _bd_strip_counts(blk, block_len, interpret)
+    _count_bd_site(seq, block_len, blk, strips, forward=False)
+    whole_head = pl.BlockSpec((1, 1, T, D), lambda b, h, s, *_: (b, h, 0, 0))
+    dkv_shape = jax.ShapeDtypeStruct((B, H, T, D), jnp.float32)
+    acc = pltpu.VMEM((blk, D), jnp.float32)
+    return _tri_call(
+        functools.partial(
+            _bd_bwd_kernel, sm_scale=sm_scale, seq=seq,
+            block_len=block_len, strips=strips,
+        ),
+        "flash_attn_bd_bwd",
+        _bd_steps(seq, block_len, blk, by_key=True),
+        (qt, kt, vt, dot, lse4, delta4),
+        [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        [whole_head, kv_out_spec, kv_out_spec],
+        [jax.ShapeDtypeStruct((B, H, T, D), qt.dtype), dkv_shape, dkv_shape],
+        [pltpu.VMEM((T, D), jnp.float32), acc, acc],
+        interpret=interpret, vmem_limit=_FUSED_VMEM_LIMIT,
+    )
+
+
+def _bd_block(seq: int, D: int, itemsize: int, block):
+    """The block of the walk's kernels over a doubled row of ``2 seq``, or
+    None where they cannot run it: the stated ``block`` (1024 where it
+    divides ``seq`` and the head is no wider than measured, else 512), if
+    it divides the row into no more blocks than the tables hold and the
+    one-pass backward's float32 dq of a head fits."""
+    if block is None:
+        wide = seq % _TRI_BLOCK == 0 and D <= _LANES
+        block = min(_TRI_BLOCK if wide else _BLOCK, seq)
+    if seq % block or block % 8 or 2 * seq // block > _TRI_MAX_BLOCKS:
+        return None
+    return block if _one_pass_fits(2 * seq, D, itemsize) else None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _bd_pallas(q, k, v, seq, block_len, sm_scale, blk, interpret):
+    o, _ = _bd_fwd_call(
+        q, k, v, seq=seq, block_len=block_len, sm_scale=sm_scale, blk=blk,
+        interpret=interpret,
+    )
+    return o
+
+
+def _bd_fwd_rule(q, k, v, seq, block_len, sm_scale, blk, interpret):
+    o, lse4 = _bd_fwd_call(
+        q, k, v, seq=seq, block_len=block_len, sm_scale=sm_scale, blk=blk,
+        interpret=interpret,
+    )
+    # what a recomputed layer keeps, under the names every call's carry
+    # (the logsumexp as [B,H,2L]: a minor dimension of 1 is a lane tile)
+    q, k, v, o, lse = map(
+        checkpoint_name, (q, k, v, o, lse4[..., 0]), KEPT
+    )
+    return o, (q, k, v, o, lse)
+
+
+def _bd_bwd_rule(seq, block_len, sm_scale, blk, interpret, res, do):
+    q, k, v, o, lse = res
+    B, H, T, D = q.shape
+    Hkv = k.shape[1]
+    delta4 = jnp.einsum(
+        "bhqd,bhqd->bhq", do.astype(jnp.float32), o.astype(jnp.float32)
+    )[..., None]
+    dq, dk, dv = _bd_bwd_call(
+        q, k, v, do, lse[..., None], delta4, seq=seq, block_len=block_len,
+        sm_scale=sm_scale, blk=blk, interpret=interpret,
+    )
+    if H != Hkv:
+        dk = dk.reshape(B, Hkv, H // Hkv, T, D).sum(2)
+        dv = dv.reshape(B, Hkv, H // Hkv, T, D).sum(2)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_bd_pallas.defvjp(_bd_fwd_rule, _bd_bwd_rule)
+
+
+def block_diffusion_attention(
+    q,
+    k,
+    v,
+    *,
+    block_len: int,
+    sm_scale: Optional[float] = None,
+    layout: str = "bthd",
+    block: Optional[int] = None,
+    force: Optional[str] = None,
+    interpret: Optional[bool] = None,
+):
+    """Attention over a row fed twice, ``q:[B,2L,H,D] k,v:[B,2L,Hkv,D]``
+    (or ``[B,H,2L,D]`` with ``layout="bhtd"``): the noised copy of a row
+    of ``L`` positions before the clean one, under the block-diffusion
+    rule over blocks of ``block_len`` (``block_diffusion_mask``): a noised
+    query sees the noised keys of its own block and the clean keys of the
+    blocks before it, a clean query the clean keys up to its own block's
+    end. Differentiable.
+
+    On the TPU (``force="pallas"`` elsewhere) the ``flash_attn_bd_*``
+    kernels walk only the blocks of the ``2L x 2L`` grid that hold a
+    visible pair, forward and in a one-pass backward, and the clean
+    half's keys and values are read by both halves' queries where they
+    lie. Where those kernels cannot run the shape (``_bd_block``) the rule
+    is a ``mask_fn`` over the rectangular grid, and on the jnp path over
+    the materialized scores: exact, and nothing skipped
+    (``attn_bd_blocks_walked`` then equals ``attn_bd_blocks_square``)."""
+    seq_axis = 2 if layout == "bhtd" else 1
+    T, D = q.shape[seq_axis], q.shape[-1]
+    if T % 2 or (T // 2) % block_len or k.shape[seq_axis] != T:
+        raise ValueError(
+            f"a doubled row of {T} positions against "
+            f"{k.shape[seq_axis]} keys is no two copies of whole blocks "
+            f"of {block_len}"
+        )
+    seq = T // 2
+    scale = sm_scale if sm_scale is not None else D**-0.5
+    mode = force
+    if mode is None:
+        mode = "pallas" if jax.default_backend() == "tpu" else "reference"
+    blk = _bd_block(seq, D, q.dtype.itemsize, block)
+    if mode == "reference" or blk is None:
+        if mode != "reference":
+            # forward, dq and dk / dv kernels over the whole square
+            square = (T // min(_BLOCK, T)) ** 2
+            for name in _BD:
+                trace_counts.count(name, 3 * square)
+        return flash_attention(
+            q, k, v, causal=False, sm_scale=scale, layout=layout,
+            mask_fn=block_diffusion_mask(seq, block_len),
+            force="reference" if mode == "reference" else force,
+        )
+    if layout != "bhtd":
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    trace_counts.count("attn_kept_sites", trace_counts.keeping())
+    o = _bd_pallas(
+        q, k, v, seq, block_len, scale, blk,
+        _interpret_default() if interpret is None else interpret,
+    )
+    return o if layout == "bhtd" else o.transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------------------------------

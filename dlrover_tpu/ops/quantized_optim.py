@@ -15,7 +15,9 @@ reduction along lanes. Quantization goes through a sqrt map
 max): on TPU a nonlinear 256-entry codebook lookup per element (the
 reference's dynamic map) would serialize into gathers; the sqrt map keeps
 the whole update elementwise on the VPU and keeps small second moments
-from rounding to zero. Nothing a caller sets selects a code path:
+from rounding to zero; a second moment more than 254^2 times under its
+block's largest, which the map alone would still round to zero, reads
+the least code, 1. Nothing a caller sets selects a code path:
 
 **The layout follows the leaf** (``_layout_for``, from the shape alone:
 the leaf is what the gradient, the parameter and the apply already are).
@@ -240,20 +242,34 @@ def _sqrt_map_quant(x, signed, qmax):
     toward zero, so the smallest representable nonzero value is
     scale/qmax^2 instead of scale/qmax — without it Adam's second moment
     underflows to 0 for small-magnitude coordinates and the update blows
-    up through the eps denominator. Purely elementwise (no codebook
-    gather), so it stays on the VPU.
+    up through the eps denominator. An unsigned block's (second moments')
+    least code is 1 for the same reason.
+    Purely elementwise (no codebook gather), so it stays on the VPU.
     """
     # the codes of clip(round(sign(y) sqrt|y| qmax)), y = x / safe, in
     # fewer vector operations and bit for bit (the one-pass kernel is bound
     # by them): |y| is |x| / safe, a rounded product keeps its sign, no
     # finite |y| passes 1, and an unsigned block holds nothing below 0
+    # (nor, since PR 67, below the least code's value)
     if signed:
         a = jnp.abs(x)
         scale = jnp.max(a, axis=-1, keepdims=True)
+        safe = jnp.maximum(scale, 1e-30)
     else:
-        a = jnp.maximum(x, 0.0)
+        # a second moment's least code is 1: one element whose gradient
+        # is 254 times its neighbours' (a rare token's column of the head
+        # under a loss weight of 1 / t: PERF.md §6, PR 67) would round
+        # theirs to 0 beside first moments that are not, and their next
+        # update would be m / eps, thousands of learning rates. Rounded up
+        # the update errs small instead. The floor stands where the guard
+        # against values below 0 stood (a block's own scalar, no vector
+        # operation more): y >= (0.75 / qmax)^2 rounds to 1 or above. An
+        # element that never met a gradient reads scale / qmax^2 too: its
+        # first moment is 0, so it moves as little as before, and a block
+        # of such elements has scale 0
         scale = jnp.max(x, axis=-1, keepdims=True)
-    safe = jnp.maximum(scale, 1e-30)
+        safe = jnp.maximum(scale, 1e-30)
+        a = jnp.maximum(x, safe * (0.75 / qmax) ** 2)
     t = jnp.round(jnp.sqrt(a / safe) * qmax)
     return (jnp.where(x < 0, -t, t) if signed else t), scale
 

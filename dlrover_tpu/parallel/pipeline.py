@@ -119,6 +119,14 @@ def _check_pipeline_cfg(
             f"or keys and values ({sorted(LAYER_READS)}) could lie a stage "
             "above the layer they read, and nothing carries it there"
         )
+    if cfg.objective:
+        raise ValueError(
+            f"pipeline parallelism feeds a stage the row of data and "
+            f"scores the next token: objective {cfg.objective!r} feeds a "
+            "row twice, its noised copy first, and scores the noised "
+            "positions' own tokens under a weight a token, which no "
+            "stage's schedule here does"
+        )
     if cfg.ut_steps > 1:
         raise ValueError(
             f"pipeline parallelism sends a microbatch through the stages "

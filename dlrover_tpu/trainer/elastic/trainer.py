@@ -37,7 +37,11 @@ from dlrover_tpu.common import faults, trace_counts
 from dlrover_tpu.ckpt.checkpointer import FlashCheckpointer, StorageType
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.models.config import TransformerConfig
-from dlrover_tpu.models.train import fold_exit_report, shard_batch
+from dlrover_tpu.models.train import (
+    fold_diffusion_report,
+    fold_exit_report,
+    shard_batch,
+)
 from dlrover_tpu.obs.flight_recorder import (
     ProfilerCapture,
     default_recorder,
@@ -2089,6 +2093,8 @@ class ElasticTrainer:
             )
             # of a looped model, its exits
             exits = fold_exit_report(metrics, self.pipeline_stats)
+            # of a model trained by diffusion over blocks, its noise
+            exits += fold_diffusion_report(metrics, self.pipeline_stats)
         with span("report"):
             scalars = {"loss": loss}
             lr = self._lr_value(lr_parts)

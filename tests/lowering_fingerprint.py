@@ -117,6 +117,22 @@ chunks of 128) its four Mamba-2 scans take the ``ssd_scan_*`` kernels of
 ``ops/ssd_kernels.py``, interpreted on the CPU, where they were
 ``ssd_chunked`` under a ``jax.checkpoint``. The ten configurations without
 an ``M`` layer never reach the rule and are byte for byte what they were.
+ISSUE 67 brought ``sdar-30b-a3b-d8`` (a model trained by diffusion over
+blocks: ``loss_fn`` noises the row, feeds it twice and weighs a token's
+cross-entropy; on the CPU its attention lowers to the jnp path with the
+block-diffusion rule as a mask) and left the eleven entries before it byte
+for byte as they were: with no ``objective`` ``loss_fn`` takes no other
+branch, ``token_nll`` without ``token_weights`` multiplies nothing, and
+``forward`` makes ``arange(T)`` as before. After review it recorded the
+steps of the nine configurations that train with ``adamw_8bit`` anew (and
+the three ``step_without_names`` beside them), their trees as they were:
+``ops/quantized_optim._sqrt_map_quant`` gives a second moment the least
+code 1 where it rounded to 0 (the floor stands where the guard against
+values below 0 stood, one product a block more; the first moments' codes
+are made as before),
+because the new cell's 1 / t loss weights showed what code 0 beside a
+live first moment does (PERF.md §6, PR 67). The two GPT-2 entries (fp32
+``adamw``) are byte for byte what they were.
 Made by running this file there:
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python tests/lowering_fingerprint.py
@@ -139,6 +155,7 @@ NAMES = (
     "nemotron3-nano-30b-a3b-d9", "qwen3-next-80b-a3b-d4",
     "ling-3.0-flash-d7", "trinity-mini-d5", "phi4-mini-flash-d6",
     "ouro-2.6b-d6", "mistral-small-4-119b-d4", "olmo-hybrid-7b-d4",
+    "sdar-30b-a3b-d8",
 )
 
 

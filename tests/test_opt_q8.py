@@ -653,3 +653,46 @@ def test_sites_of_one_shape_share_one_lowered_function(monkeypatch):
     assert trace_counts.since(before)["opt_q8_kernel_elems"] == (
         2 * sum(math.prod(shape) for shape in tiles)
     )
+
+
+# -- a second moment that is not zero keeps a code ---------------------------
+
+
+def test_a_second_moment_never_rounds_to_zero():
+    """One element 1000 times its block's others: theirs would round to
+    code 0 (sqrt(v / max) 127 < 0.5) and read the least code, 1; a block
+    of zeros still reads zeros (its scale is 0), and no other code moves."""
+    v = jnp.full((3, q8.BLOCK), 1e-6).at[0, 3].set(1.0).at[0, 7].set(0.0)
+    v = v.at[2].set(0.0)
+    codes, scale = q8._quant_block_math(v, signed=False)
+    assert int(codes[0, 3]) == 127
+    assert set(np.asarray(codes[0]).tolist()) == {1, 127}
+    assert set(np.asarray(codes[1]).tolist()) == {127}
+    back = q8._dequant_block_math(codes, scale)
+    assert float(back[0, 0]) == pytest.approx(1.0 / 127**2)
+    assert not np.asarray(back[2]).any()
+    # a first moment rounds to the nearest code as before, zero among them
+    m, _ = q8._quant_block_math(v.at[0, 3].set(-1.0), signed=True)
+    assert set(np.asarray(m[0]).tolist()) == {-127, 0}
+
+
+@pytest.mark.parametrize("name", ["adamw_8bit", "adamw"])
+def test_a_spike_beside_small_gradients_moves_no_element_far(name):
+    """A block whose one element takes a gradient 1000 times its
+    neighbours' (a rare token's column of the head under a loss weight of
+    1 / t), and steps after it in which the neighbours' gradient is all
+    but nothing (the token is absent): their updates stay within Adam's
+    few learning rates, where a second moment rounded to 0 beside a live
+    first moment gave 1,770 of them."""
+    lr = 1e-3
+    kwargs = {"min_quantized_size": 0} if name == "adamw_8bit" else {}
+    tx = build_optimizer(name, lr=lr, weight_decay=0.0, **kwargs)
+    params = {"w": jnp.zeros((8, 256), jnp.float32)}
+    state = tx.init(params)
+    small = 1e-3 * jax.random.normal(jax.random.PRNGKey(0), (8, 256))
+    spike = small.at[:, 5].set(1.0)
+    worst = 0.0
+    for g in (small, small, spike, 1e-3 * small, 1e-3 * small):
+        updates, state = tx.update({"w": g}, state, params)
+        worst = max(worst, float(jnp.max(jnp.abs(updates["w"]))))
+    assert worst < 2 * lr

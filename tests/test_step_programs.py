@@ -143,9 +143,11 @@ def test_rebuild_drops_the_executable(accel):
 # pass a recomputed layer keeps (PR 56), a looped model's three counts and
 # what is folded of its exits (PR 57), the gated norms after a scan and
 # those in the kernels (PR 63), the Mamba-2 chunked scans and those in the
-# kernels (PR 66); how the counted ones are
+# kernels (PR 66), the block-diffusion walk's three, the doubled row's two
+# and what is folded of its noise (PR 67); how the counted ones are
 # folded: ``test_trace_counts.py``
 AS_DICT_KEYS = [
+    "attn_bd_blocks_square", "attn_bd_blocks_walked", "attn_bd_sites",
     "attn_diff_pairs", "attn_diff_score_calls",
     "attn_edge_tiles", "attn_edge_tiles_multiplied",
     "attn_kept_sites",
@@ -159,6 +161,8 @@ AS_DICT_KEYS = [
     "comm_overlap_pct",
     "compile_cache_hit_pct", "compile_cache_hits",
     "compile_cache_misses", "conv_kernel_sites", "conv_sites",
+    "diffusion_data_tokens", "diffusion_masked_sum", "diffusion_positions",
+    "diffusion_reports", "diffusion_weight_sum",
     "donated_bytes", "donated_steps", "gate_kernel_sites", "gate_sites",
     "gdn_beta_scaled_sites", "gdn_chunk_steps", "gdn_head_lanes",
     "gdn_head_lanes_used", "gdn_kept_sites", "gdn_kernel_sites",
@@ -204,6 +208,7 @@ ROUNDED = {
     "recover_detect_tick_s": 4, "recover_persist_s": 4,
     "recover_respawn_s": 4, "begin_lock_s": 4,
     "ut_entropy_sum": 6, "ut_exit_step_sum": 6,
+    "diffusion_masked_sum": 6, "diffusion_weight_sum": 6,
 }
 
 

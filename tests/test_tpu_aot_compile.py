@@ -249,6 +249,54 @@ def test_window_attention_compiles_at_the_cell(
     )
 
 
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_block_diffusion_attention_compiles_at_the_cell(
+    direction, one_chip
+):
+    """The SDAR cell's attention site: a doubled row of 2 x 8192 positions
+    at 32 / 4 heads of 128 under the block-diffusion rule over blocks of
+    4, the ``flash_attn_bd_*`` kernels in blocks of 1024 (80 of the
+    grid's 256), the backward in one pass."""
+    B, H, Hkv, T, D = 1, 32, 4, 16384, 128
+    qkv = [
+        jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+        for s in ((B, H, T, D), (B, Hkv, T, D), (B, Hkv, T, D))
+    ]
+
+    def attend(q, k, v):
+        return fa.block_diffusion_attention(
+            q, k, v, block_len=4, layout="bhtd", force="pallas",
+            interpret=False,
+        )
+
+    before = trace_counts.snapshot()
+    if direction == "fwd":
+        compiled = _compile_for_chip(attend, *qkv)
+    else:
+        compiled = _compile_for_chip(
+            jax.grad(
+                lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            ),
+            *qkv,
+        )
+    want = ["flash_attn_bd_fwd"] + (
+        ["flash_attn_bd_bwd"] if direction == "bwd" else []
+    )
+    text = compiled.as_text()
+    for kernel in want:
+        assert kernel in text, kernel
+    assert "flash_attn_fwd" not in text and "flash_attn_bwd" not in text
+    sites = len(want)
+    assert added(before, fa._BD) == (80 * sites, 256 * sites)
+    # the forward multiplies its clean edges whole (16 x 16 tiles) and its
+    # noised x noised blocks in 8 strips; the backward every edge in strips
+    assert added(before, EDGE) == (
+        (8 * 8 + 16 * 16) + (8 * 8 + 16 * 10) * (sites - 1),
+        (8 * 64 + 16 * 16) * sites,
+    )
+
+
 def test_window_attention_compiles_split_beyond_one_pass(
     one_chip, monkeypatch
 ):

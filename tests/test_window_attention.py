@@ -347,9 +347,10 @@ def test_the_edge_tile_reader_reads_the_two_counts(pipeline, reads):
 
 def test_the_edge_tile_readers_cells_are_the_streaming_ones():
     """Its rule on a cell's fields names the cells ``BENCHMARK.json`` lists
-    for it: the eight whose rows are longer than the fused family takes
+    for it: the ten whose rows are longer than the fused family takes
     (six at PR 54, the looped configuration's cell since PR 57, the Mistral
-    cell since PR 59)."""
+    cell since PR 59, the Olmo-Hybrid cell since PR 64, the SDAR cell since
+    PR 67)."""
     reader = _edge_reader()
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
@@ -366,6 +367,6 @@ def test_the_edge_tile_readers_cells_are_the_streaming_ones():
         with open(path) as f:
             if reader.CELLS(json.load(f)):
                 taken.append(cell["name"])
-    assert taken == entry["workloads"] and len(taken) == 9
+    assert taken == entry["workloads"] and len(taken) == 10
     assert reader.FUSED_MAX_T == fa._FUSED_MAX_T
     assert not reader.CELLS({}) and reader.CELLS({"seq": 4096})

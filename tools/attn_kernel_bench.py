@@ -25,7 +25,14 @@ one jitted program, forward and forward + backward, host clock around
   the band its kernels walk (at blocks of ``n``);
   ``window-mask:<W>[:<n>]``: the same window as a mask over the whole
   triangle (every block at or under the diagonal walked, those past the
-  window's far edge wholly masked): what the band saves.
+  window's far edge wholly masked): what the band saves;
+- ``bd:<B>[:<n>[:<s>]]``: the shape's T is a DOUBLED row (a noised copy of
+  T / 2 positions before the clean one) under the block-diffusion rule
+  over blocks of ``B``, the blocks its ``flash_attn_bd_*`` kernels walk
+  (at blocks of ``n``; the noised x noised blocks on the diagonal in
+  ``s`` row strips and not the module's ``_BD_EQ_STRIPS``);
+  ``bd-mask:<B>``: the same rule as a ``mask_fn`` over the rectangular
+  grid (every block of the square walked): what the walk saves.
 
 A streaming variant that ends in ``@<s>`` walks the edge blocks of its
 backward kernels (the diagonal's, and those a window's far edge crosses)
@@ -43,6 +50,7 @@ A shape is ``BxHxTxD`` or, with its own key-value head count,
     python tools/attn_kernel_bench.py 1x32x4x16384x128 stream-tri window:2048 window:2048:512 window-mask:2048
     python tools/attn_kernel_bench.py 1x32x4x16384x128 stream-tri stream-tri@2 stream-tri@1 window:2048 window:2048@2 window:2048@1
     python tools/attn_kernel_bench.py 1x40x20x16384x128 stream-tri window:512 window:512@2 window:512@1
+    python tools/attn_kernel_bench.py 1x32x4x16384x128 stream-tri bd:4 bd:4:512 bd:4:1024:4 bd-mask:4
 """
 import importlib
 import json
@@ -64,7 +72,7 @@ STREAM = {
     name: getattr(fa, name)
     for name in (
         "_stream_plan", "_ONE_PASS_MAX_BYTES", "_tri_fwd_kernel",
-        "_tri_bwd_kernel", "_band_blocks", "_EDGE_STRIPS",
+        "_tri_bwd_kernel", "_band_blocks", "_EDGE_STRIPS", "_BD_EQ_STRIPS",
     )
 }
 LAYERS = 12
@@ -94,6 +102,14 @@ def _steer(variant):
     if strips:
         fa._EDGE_STRIPS = int(strips)
     kind, *rest = variant.split(":")
+    if kind in ("bd", "bd-mask"):
+        if len(rest) > 2:
+            fa._BD_EQ_STRIPS = int(rest[2])
+        block = int(rest[1]) if len(rest) > 1 else None
+        return False, {
+            "diffusion": int(rest[0]), "mask": kind == "bd-mask",
+            "block": block,
+        }
     if kind in ("window", "window-mask"):
         if kind == "window-mask":  # a band as wide as any triangle
             fa._band_blocks = lambda window, block: 1 << 20
@@ -144,9 +160,27 @@ def _time(fn, *args):
 def bench(shape, variant):
     allow_fused, blocks = _steer(variant)
     B, H, Hkv, T, D = shape
+    diffusion = blocks.pop("diffusion", None)
+    if diffusion:
+        by_mask, stated = blocks.pop("mask"), blocks.pop("block")
+        if stated:
+            blocks = {"block_q": stated, "block_k": stated}
+
+    def attend(q, k, v):
+        if by_mask:
+            return fa.flash_attention(
+                q, k, v, causal=False, layout="bhtd", force="pallas",
+                mask_fn=fa.block_diffusion_mask(T // 2, diffusion),
+            )
+        return fa.block_diffusion_attention(
+            q, k, v, block_len=diffusion, layout="bhtd", block=stated,
+        )
 
     def chain(q, k, v):
         for _ in range(LAYERS):
+            if diffusion:
+                q = attend(q, k, v)
+                continue
             q = fa.flash_attention(
                 q, k, v, causal=True, layout="bhtd",
                 allow_fused=allow_fused, **blocks,
